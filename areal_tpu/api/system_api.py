@@ -309,7 +309,7 @@ class GenServerConfig:
     # decode-pipeline depth: max chunks dispatched-but-unharvested (the
     # engine's in-flight ring).  2 overlaps each chunk's output fetch
     # with the next chunk's device time; raise it when the fetch RTT
-    # exceeds a chunk's device time (high-latency tunnels).  1 =
+    # exceeds a chunk's device time.  1 =
     # unpipelined baseline.
     pipeline_depth: int = 2
     # measured dispatch-table overrides for cache_mode="auto" (None =

@@ -206,6 +206,9 @@ def main(argv=None) -> int:
         help="tokenize prompts raw even when the tokenizer has a chat template",
     )
     args = p.parse_args(argv)
+    from areal_tpu.base.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     result = evaluate_checkpoint(
         args.ckpt,
         args.dataset,

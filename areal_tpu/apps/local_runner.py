@@ -55,8 +55,16 @@ def register_impls():
 def run_experiment_local(
     cfg: system_api.ExperimentConfig,
     timeout: Optional[float] = None,
+    before_exit=None,
 ) -> MasterWorker:
-    """Run to completion in this process; returns the master (stats inside)."""
+    """Run to completion in this process; returns the master (stats inside).
+
+    ONE process owns every chip of the host and every worker is a thread
+    of it — the one-host way to run (a chip belongs to one process at a
+    time).  ``before_exit(workers)``, when given, is called once the
+    master has finished and before any worker is told to exit, with every
+    worker object the runner started (model workers, generation servers,
+    manager, rollout workers) — engines are still live and placed."""
     register_impls()
     constants.set_experiment_trial_names(cfg.experiment_name, cfg.trial_name)
 
@@ -131,6 +139,8 @@ def run_experiment_local(
         eval_stop.set()
         if evaluator is not None:
             evaluator.shutdown()
+    if before_exit is not None:
+        before_exit(workers + aux_workers)
     for w in workers + aux_workers:
         w.exit()
     for t in threads + aux_threads:

@@ -55,6 +55,94 @@ def _worker_specs(cfg: system_api.ExperimentConfig) -> List[Tuple[str, int, str]
     return specs
 
 
+#: workers that never run a model.  Started with the CPU pin so they never
+#: open (and so hold) a chip: a TPU belongs to ONE process at a time, and
+#: the first process to touch jax would take every chip of the host.
+CHIPLESS_WORKERS = ("master", "gserver_manager", "rollout_worker", "gateway")
+CPU_PIN = {"AREAL_JAX_PLATFORM": "cpu", "JAX_PLATFORMS": "cpu"}
+
+
+def worker_device_env(
+    cfg: system_api.ExperimentConfig,
+    specs: List[Tuple[str, int, str]],
+    base_env: Dict[str, str],
+    mode: str = "local",
+) -> Dict[str, Dict[str, str]]:
+    """{worker_name: extra env} giving every worker process its devices.
+
+    Chipless workers always get the CPU pin.  Device-owning workers
+    (model workers, generation servers) inherit the platform — unless
+    several of them share this host (``local`` mode), where each gets its
+    own chip through ``TPU_VISIBLE_DEVICES`` (the way
+    ``scheduler/evaluator.py`` places the evaluator), one chip per worker,
+    with ``AREAL_DEVICE_BASE`` telling the child which global device index
+    its first visible chip has.  What cannot be arranged that way is
+    REFUSED here with a message — a child must never be left to fail or
+    hang on the chip's lock.  A launch env that already pins a platform
+    (``AREAL_JAX_PLATFORM``, e.g. ``cpu`` for CPU-mesh runs) is honoured
+    as is: no chip is involved."""
+    out: Dict[str, Dict[str, str]] = {
+        wname: dict(CPU_PIN)
+        for wtype, _, wname in specs
+        if wtype in CHIPLESS_WORKERS
+    }
+    owners = [s for s in specs if s[0] not in CHIPLESS_WORKERS]
+    if base_env.get("AREAL_JAX_PLATFORM") or mode != "local" or len(owners) < 2:
+        # pinned by the caller / one process per host under slurm / a
+        # single device owner that takes the whole host
+        return out
+    hint = (
+        "run the one-host case in ONE process instead (the threaded "
+        "runner: training/main_*.py, or quickstart --mode threads), or "
+        "export AREAL_JAX_PLATFORM=cpu for a CPU-mesh run"
+    )
+    taken: Dict[int, str] = {}
+    for wtype, idx, wname in owners:
+        if wtype == "model_worker":
+            if len(cfg.model_workers) > 1:
+                raise ValueError(
+                    f"--mode processes cannot place {len(cfg.model_workers)} "
+                    f"model workers on one host; {hint}"
+                )
+            world = max(
+                (
+                    s.mesh_spec.world_size
+                    for s in cfg.model_workers[idx].shards
+                ),
+                default=1,
+            )
+            start = 0
+        else:
+            wcfg = cfg.gen_servers[idx]
+            world = wcfg.mesh_spec.world_size
+            start = wcfg.device_idx
+            if start is None:
+                raise ValueError(
+                    f"{wname} has no device_idx (gen_device_start unset): "
+                    "it would open the same chips as the trainer and one "
+                    f"of them would fail on the chip's lock; {hint}"
+                )
+        if world != 1:
+            raise ValueError(
+                f"{wname} spans {world} chips: the process launcher only "
+                "knows how to give a worker ONE visible chip on a shared "
+                f"host; {hint}"
+            )
+        if start in taken:
+            raise ValueError(
+                f"{wname} and {taken[start]} are both placed on chip "
+                f"{start}: two processes cannot share a chip; {hint}"
+            )
+        taken[start] = wname
+        out[wname] = {
+            "TPU_VISIBLE_DEVICES": str(start),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "AREAL_DEVICE_BASE": str(start),
+        }
+    return out
+
+
 def launch_experiment(
     cfg: system_api.ExperimentConfig,
     mode: str = "local",
@@ -133,8 +221,9 @@ def _launch_once(
             logger.warning(
                 "ignoring non-numeric AREAL_METRICS_PORT_BASE=%r", raw_base
             )
+    device_env = worker_device_env(cfg, specs, wenv, mode=mode)
     for seq, (wtype, idx, wname) in enumerate(specs):
-        worker_env = dict(wenv)
+        worker_env = {**wenv, **device_env.get(wname, {})}
         if metrics_base is not None:
             worker_env["AREAL_METRICS_PORT"] = str(metrics_base + seq)
         sched.submit(

@@ -24,6 +24,7 @@ import sys
 from areal_tpu.api import system_api
 from areal_tpu.api.cli_args import dump_config, parse_cli
 from areal_tpu.base import constants, logging_
+from areal_tpu.base.compile_cache import setup_compile_cache
 
 logger = logging_.getLogger("quickstart")
 
@@ -52,6 +53,7 @@ def main(argv=None) -> int:
         mode = argv[i + 1]
         del argv[i : i + 2]
 
+    setup_compile_cache()
     cls = system_api.experiment_cls(cmd)
     exp = parse_cli(cls, argv=argv)
     exp.apply_device_overrides()

@@ -72,9 +72,11 @@ def run_worker(
     from areal_tpu.base import constants, name_resolve
     from areal_tpu.system.worker_base import AsyncWorker, make_server
 
-    # hermetic platform pinning for CPU-mesh tests and mixed fleets: the env
-    # var alone can lose to an eagerly-registered platform plugin, so also
-    # set jax.config (same pattern as tests/conftest.py)
+    # platform pinning for workers that own no chip (the process launcher
+    # sets AREAL_JAX_PLATFORM=cpu for them) and for CPU-mesh runs
+    from areal_tpu.base.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     platform = os.environ.get("AREAL_JAX_PLATFORM")
     if platform:
         import jax
@@ -105,6 +107,11 @@ def run_worker(
         from areal_tpu.system.generation_server import GenerationServerWorker
 
         cls, wcfg = GenerationServerWorker, cfg.gen_servers[worker_index]
+        base = os.environ.get("AREAL_DEVICE_BASE")
+        if base and wcfg.device_idx is not None:
+            # the launcher gave this process its own visible chip(s),
+            # which jax numbers from 0 (apps/main.worker_device_env)
+            wcfg.device_idx -= int(base)
     elif worker_type == "gserver_manager":
         from areal_tpu.system.gserver_manager import GserverManager
 

@@ -1,8 +1,11 @@
 """Loader for the in-repo C++ helpers (csrc/).
 
-Compiles ``csrc/*.cpp`` into a shared library on first use (g++, cached
-next to the sources with an mtime check) and binds it via ctypes — no
-pybind11 dependency.  Every native entry point has a pure-Python fallback
+Compiles ``csrc/datapack.cpp`` into a shared library on first use (g++)
+and binds it via ctypes — no pybind11 dependency.  The library's file name
+carries a hash of the SOURCE'S CONTENT, so a copy or checkout of the tree
+(which preserves no mtimes and ships no binary — ``csrc/*.so`` is ignored
+by git) rebuilds exactly when the source it holds differs from what the
+cached library was built from.  Every native entry point has a pure-Python fallback
 in its caller, so a missing/failed toolchain degrades gracefully
 (AREAL_NATIVE=0 forces the fallbacks).
 """
@@ -10,6 +13,8 @@ in its caller, so a missing/failed toolchain degrades gracefully
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -65,13 +70,18 @@ def get_lib() -> Optional[ctypes.CDLL]:
         src = os.path.join(_CSRC, "datapack.cpp")
         if not os.path.isfile(src):
             return None
-        out = os.path.join(_CSRC, "libdatapack.so")
-        if (
-            not os.path.isfile(out)
-            or os.path.getmtime(out) < os.path.getmtime(src)
-        ):
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        out = os.path.join(_CSRC, f"libdatapack-{digest}.so")
+        if not os.path.isfile(out):
             if not _build(src, out):
                 return None
+            for stale in glob.glob(os.path.join(_CSRC, "libdatapack*.so")):
+                if stale != out:
+                    try:
+                        os.unlink(stale)
+                    except OSError:
+                        pass
         try:
             lib = ctypes.CDLL(out)
         except OSError as e:
@@ -90,6 +100,12 @@ def get_lib() -> Optional[ctypes.CDLL]:
         _lib = lib
         logger.debug("native datapack loaded from %s", out)
         return _lib
+
+
+def backend() -> str:
+    """"native" when the C++ library is loaded, else "python" (the
+    callers' pure-Python fallbacks are in use)."""
+    return "native" if get_lib() is not None else "python"
 
 
 def _as_i64(a) -> np.ndarray:

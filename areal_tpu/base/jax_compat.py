@@ -1,86 +1,17 @@
-"""Shims over the jax API drift this repo straddles (0.4.x images vs the
-0.5+/0.6 spellings newer code was written against).
-
-Rules of the module: resolve the modern name when it exists, translate to
-the old one otherwise, NEVER fork behavior beyond the rename — so call
-sites read like current jax and the shim disappears when the image moves.
-"""
+"""Small helpers over the installed jax (0.9) that several engines share."""
 
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: public top-level shard_map with axis_names/check_vma
-    from jax import shard_map as _shard_map_new
-
-    _OLD_SHARD_MAP = None
-except ImportError:  # jax 0.4.x: experimental, axis-set via `auto`
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    _shard_map_new = None
-    _OLD_SHARD_MAP = _old_shard_map
-
-
-def shard_map(
-    f,
-    *,
-    mesh,
-    in_specs,
-    out_specs,
-    axis_names=None,
-    check_vma=None,
-    **kwargs,
-):
-    """``jax.shard_map`` signature on every supported jax.
-
-    On 0.4.x, ``axis_names`` (the MANUAL axes) becomes the complementary
-    ``auto`` set and ``check_vma`` maps to ``check_rep``.
-    """
-    if _shard_map_new is not None:
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return _shard_map_new(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    return _OLD_SHARD_MAP(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
-
-def partial_auto_shard_map_supported() -> bool:
-    """True when shard_map may be manual over a SUBSET of mesh axes with
-    the rest auto (the pipeline's mode).  jax 0.4.x's experimental ``auto``
-    cannot lower ``axis_index`` inside such a region (XLA rejects the
-    PartitionId op under SPMD partitioning), so the pipeline path requires
-    the jax >= 0.5 shard_map."""
-    return _shard_map_new is not None
-
-
-def pallas_tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (jax >= 0.6 spelling) or the 0.4.x
-    ``TPUCompilerParams`` — identical fields, renamed class."""
-    import jax.experimental.pallas.tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
 
 
 def start_host_copies(arrs) -> bool:
     """Start async device->host copies for every ``jax.Array`` in
     ``arrs`` (``copy_to_host_async``), so a later blocking conversion
     finds the data already host-resident instead of paying one serial
-    tunnel/PCIe round-trip per array.  Returns True iff copies were
-    started; backends without the method (or arrays that reject it) are
-    a silent no-op — the eventual ``device_get`` still fetches, just
-    unhidden."""
+    PCIe round-trip per array.  Returns True iff copies were started;
+    arrays that reject the call are a silent no-op — the eventual
+    ``device_get`` still fetches, just unhidden."""
     try:
         started = False
         for x in arrs:

@@ -118,7 +118,12 @@ def clear_time_marks():
 # ---------------------------------------------------------------------------
 
 #: dense bf16 peak TFLOP/s per chip, keyed by substrings of
-#: ``device.device_kind`` (the MFU denominators bench.py also uses)
+#: ``device.device_kind`` ("TPU v5 lite" is how a v5e reports itself).
+#: THE table for every MFU denominator in the repo (bench.py reads it
+#: through :func:`device_peak_flops`).  Source: Google Cloud TPU
+#: documentation, "System architecture" page of each generation
+#: (cloud.google.com/tpu/docs/v5e: 197 TFLOP/s bf16, 16 GB HBM at
+#: 819 GB/s; .../v4: 275; .../v5p: 459; .../v6e: 918; .../v3: 123).
 PEAK_TFLOPS_BF16 = {
     "v3": 123,
     "v4": 275,
@@ -132,13 +137,20 @@ PEAK_TFLOPS_BF16 = {
 
 
 def device_peak_flops(device) -> float:
-    """Peak bf16 FLOP/s of one device, or 0.0 when unknown (CPU backends;
-    MFU gauges are skipped then rather than reporting nonsense)."""
+    """Peak bf16 FLOP/s of one device.  A non-TPU device (the CPU backend
+    of the tests) has no published peak: 0.0, and MFU gauges are skipped
+    rather than reporting nonsense.  A TPU whose ``device_kind`` is not in
+    the table RAISES — an unknown chip is an error, never a default."""
+    if getattr(device, "platform", None) != "tpu":
+        return 0.0
     kind = getattr(device, "device_kind", "").lower()
     for name, tf in PEAK_TFLOPS_BF16.items():
         if name in kind:
             return tf * 1e12
-    return 0.0
+    raise ValueError(
+        f"no published bf16 peak for TPU device_kind {device.device_kind!r}; "
+        "add it (with its source) to areal_tpu.base.monitor.PEAK_TFLOPS_BF16"
+    )
 
 
 def _host_stats() -> Dict[str, float]:

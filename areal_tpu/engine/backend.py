@@ -27,6 +27,35 @@ from areal_tpu.models.config import TransformerConfig, tiny_config
 logger = logging_.getLogger("backend")
 
 
+def host_device():
+    """The host-memory (CPU backend) device model weights are BUILT on.
+
+    A freshly loaded or randomly initialized tree is whole and float32;
+    built on the default accelerator it would sit entire on chip 0 before
+    any mesh, ``device_idx`` or serving dtype is applied (a trainer and a
+    generation server sharing one chip would each do so at once).  Built
+    here, each engine's own placement (``tree_put_global`` /
+    ``device_put`` onto its shardings) is the first time the weights
+    touch an accelerator, already cut to that engine's shards."""
+    return jax.local_devices(backend="cpu")[0]
+
+
+def cast_floating(params, dtype):
+    """Cast a host-resident param tree's floating leaves to ``dtype``
+    without leaving the host (a serving copy is placed at model dtype,
+    never as float32 masters)."""
+    import jax.numpy as jnp
+
+    dtype = jnp.dtype(dtype)
+    with jax.default_device(host_device()):
+        return jax.tree.map(
+            lambda x: x.astype(dtype)
+            if jnp.issubdtype(x.dtype, jnp.floating)
+            else x,
+            params,
+        )
+
+
 def make_model(
     cfg: ModelAbstraction,
     name: ModelName,
@@ -66,7 +95,10 @@ def make_model(
         load_overrides = {
             k: v for k, v in cfg.args.items() if k in ("is_critic", "dtype")
         }
-        model_cfg, params = load_hf_model(cfg.args["path"], **load_overrides)
+        with jax.default_device(host_device()):
+            model_cfg, params = load_hf_model(
+                cfg.args["path"], **load_overrides
+            )
         post = {
             k: v
             for k, v in cfg.args.items()
@@ -88,7 +120,8 @@ def make_model(
             model_cfg = tiny_config(**args)
         from areal_tpu.models.transformer import init_params
 
-        params = init_params(model_cfg, jax.random.PRNGKey(seed))
+        with jax.default_device(host_device()):
+            params = init_params(model_cfg, jax.random.PRNGKey(seed))
         backend_name = "llama"
     else:
         raise ValueError(f"unknown model abstraction {cfg.type_}")

@@ -248,7 +248,7 @@ def generate_tokens(
     )
     # start all four device->host copies before the first blocking
     # conversion: sequential np.asarray calls would each pay a full
-    # tunnel/PCIe round-trip, serialized
+    # PCIe round-trip, serialized
     jax_compat.start_host_copies((out_tokens, out_logps, n_gen, no_eos))
     out_tokens = np.asarray(out_tokens)
     out_logps = np.asarray(out_logps)

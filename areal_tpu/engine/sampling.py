@@ -160,9 +160,7 @@ def sample_logits_keyed(
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
 
-            from areal_tpu.base import jax_compat
-
-            gen_gumbel = jax_compat.shard_map(
+            gen_gumbel = jax.shard_map(
                 gen_gumbel,
                 mesh=mesh,
                 in_specs=(P(None), P(None)),

@@ -144,20 +144,14 @@ class CommonExperimentConfig(system_api.Experiment):
         )
 
     def apply_device_overrides(self):
+        """``force_cpu_devices=N``: run on N virtual CPU devices.  Must be
+        called before the first use of jax (the device count is fixed at
+        backend init)."""
         if self.force_cpu_devices:
             import jax
 
-            if (
-                jax.devices()[0].platform != "cpu"
-                or len(jax.devices()) < self.force_cpu_devices
-            ):
-                import jax.extend.backend as jeb
-
-                jeb.clear_backends()
-                jax.config.update("jax_platforms", "cpu")
-                jax.config.update(
-                    "jax_num_cpu_devices", self.force_cpu_devices
-                )
+            jax.config.update("jax_platforms", "cpu")
+            jax.config.update("jax_num_cpu_devices", self.force_cpu_devices)
 
     def model_worker_names(self) -> List[str]:
         return [f"model_worker_{i}" for i in range(self.n_model_workers)]

@@ -7,7 +7,7 @@ gateway backend (bench/dryrun), and inside unit tests unchanged.  Every
 time-dependent method takes an explicit ``now`` so the refill math is
 deterministic under test; production callers pass ``time.monotonic()``.
 
-Reject taxonomy (stable, wire-visible — the gateway maps them onto
+Reject catalogue (stable, wire-visible — the gateway maps them onto
 HTTP statuses and the manager stamps them into the labeled
 ``areal_gateway_admission_rejects_total{reason}`` counter):
 

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from areal_tpu.base import jax_compat
 from areal_tpu.models import quantize
 from areal_tpu.models.config import TransformerConfig
 
@@ -123,7 +122,7 @@ def _ep_expert_compute(
         return jax.lax.psum(out[inv_order], "expert")
 
     w_spec = P("expert", None, None)
-    fn = jax_compat.shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(None, None), P(None, None), w_spec, w_spec, w_spec),

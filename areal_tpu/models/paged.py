@@ -164,6 +164,12 @@ def quantize_kv(vals: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
+def kernel_interpret() -> bool:
+    """Whether the Pallas paged kernels run in interpret mode: compiled
+    on a TPU backend, interpreted (tests only) anywhere else."""
+    return jax.default_backend() != "tpu"
+
+
 def _prefix_partials(
     q, k_pool, v_pool, tables, lengths, layer, use_kernel,
     mesh=None, kv_axis=None, deep=False, k_scale=None, v_scale=None,
@@ -185,79 +191,56 @@ def _prefix_partials(
         kernel_fn = (
             paged_flash_attention_deep if deep else paged_flash_attention
         )
-        interp = jax.default_backend() != "tpu"
-        if mesh is not None:
-            from jax.experimental.shard_map import shard_map
-            from jax.sharding import PartitionSpec as P
+        interp = kernel_interpret()
+        if mesh is None:
+            return kernel_fn(
+                q, k_pool, v_pool, tables, lengths, layer=layer,
+                interpret=interp, k_scale=k_scale, v_scale=v_scale,
+            )
+        from jax.sharding import PartitionSpec as P
 
-            layered = k_pool.ndim == 5
-            pool_spec = (
-                P(None, None, kv_axis, None, None)
-                if layered
-                else P(None, kv_axis, None, None)
+        layered = k_pool.ndim == 5
+        pool_spec = (
+            P(None, None, kv_axis, None, None)
+            if layered
+            else P(None, kv_axis, None, None)
+        )
+        scale_spec = (
+            P(None, None, kv_axis, None)
+            if layered
+            else P(None, kv_axis, None)
+        )
+        scales = () if k_scale is None else (k_scale, v_scale)
+
+        def kern(qq, kk, vv, tb, ln, ly, *sc):
+            ks, vs = sc if sc else (None, None)
+            return kernel_fn(
+                qq, kk, vv, tb, ln, layer=ly, interpret=interp,
+                k_scale=ks, v_scale=vs,
             )
-            scale_spec = (
-                P(None, None, kv_axis, None)
-                if layered
-                else P(None, kv_axis, None)
+
+        fn = jax.shard_map(
+            kern,
+            mesh=mesh,
+            in_specs=(
+                P(None, None, kv_axis, None),
+                pool_spec,
+                pool_spec,
+                P(None, None),
+                P(None),
+                P(None),
             )
-            out_specs = (
+            + (scale_spec,) * len(scales),
+            out_specs=(
                 P(None, None, kv_axis, None),
                 P(None, None, kv_axis),
                 P(None, None, kv_axis),
-            )
-            common = dict(mesh=mesh, out_specs=out_specs, check_rep=False)
-            if k_scale is None:
-
-                def kern(qq, kk, vv, tb, ln, ly):
-                    return kernel_fn(
-                        qq, kk, vv, tb, ln, layer=ly, interpret=interp
-                    )
-
-                fn = shard_map(
-                    kern,
-                    in_specs=(
-                        P(None, None, kv_axis, None),
-                        pool_spec,
-                        pool_spec,
-                        P(None, None),
-                        P(None),
-                        P(None),
-                    ),
-                    **common,
-                )
-                return fn(
-                    q, k_pool, v_pool, tables, lengths,
-                    jnp.asarray(layer, jnp.int32).reshape(1),
-                )
-
-            def kern_q(qq, kk, vv, ks, vs, tb, ln, ly):
-                return kernel_fn(
-                    qq, kk, vv, tb, ln, layer=ly, interpret=interp,
-                    k_scale=ks, v_scale=vs,
-                )
-
-            fn = shard_map(
-                kern_q,
-                in_specs=(
-                    P(None, None, kv_axis, None),
-                    pool_spec,
-                    pool_spec,
-                    scale_spec,
-                    scale_spec,
-                    P(None, None),
-                    P(None),
-                    P(None),
-                ),
-                **common,
-            )
-            return fn(
-                q, k_pool, v_pool, k_scale, v_scale, tables, lengths,
-                jnp.asarray(layer, jnp.int32).reshape(1),
-            )
-        return kernel_fn(
-            q, k_pool, v_pool, tables, lengths, layer=layer,
-            interpret=interp, k_scale=k_scale, v_scale=v_scale,
+            ),
+            check_vma=False,
+        )
+        return fn(
+            q, k_pool, v_pool, tables, lengths,
+            jnp.asarray(layer, jnp.int32).reshape(1), *scales,
         )
     kl = jax.lax.dynamic_index_in_dim(k_pool, layer, 0, keepdims=False)
     vl = jax.lax.dynamic_index_in_dim(v_pool, layer, 0, keepdims=False)

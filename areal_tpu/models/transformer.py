@@ -429,11 +429,40 @@ def _attention_dispatch(
         and jax.default_backend() == "tpu"
         and fa.supported(q.shape[1], k.shape[1], cfg.sliding_window)
     ):
-        return fa.flash_attention(q, k, v, seg_ids)
+        return _flash_attention(q, k, v, seg_ids, cfg)
     _warn_dense_fallback(
         q.shape[1], k.shape[1], cfg.sliding_window, seg_ids is None
     )
     return reference_attention(q, k, v, mask)
+
+
+def _flash_attention(q, k, v, seg_ids, cfg: TransformerConfig):
+    """The Pallas flash kernel, per shard on a multi-device trainer mesh.
+
+    A Mosaic kernel has no SPMD partitioning rule ("Mosaic kernels cannot
+    be automatically partitioned"), so under the engine's sharded jit it
+    runs inside a ``shard_map``: rows split over the data-parallel axes
+    (the engine pads rows to their product), heads over ``model`` when
+    the kv heads divide, everything else replicated.  Attention never
+    mixes rows or heads, so no collective is needed inside."""
+    from jax.sharding import PartitionSpec as P
+
+    from areal_tpu.ops import flash_attention as fa
+
+    mesh = _AMBIENT_MESH
+    if mesh is None or mesh.devices.size == 1 or _pipe_mesh() is not None:
+        return fa.flash_attention(q, k, v, seg_ids)
+    batch_axes = tuple(a for a in ("data", "fsdp") if a in mesh.shape)
+    tp = mesh.shape.get("model", 1)
+    head_axis = "model" if tp > 1 and cfg.n_kv_heads % tp == 0 else None
+    qkv_spec = P(batch_axes, None, head_axis, None)
+    return jax.shard_map(
+        fa.flash_attention,
+        mesh=mesh,
+        in_specs=(qkv_spec, qkv_spec, qkv_spec, P(batch_axes, None)),
+        out_specs=qkv_spec,
+        check_vma=False,
+    )(q, k, v, seg_ids)
 
 
 _warned_dense = set()

@@ -45,7 +45,7 @@ class SubsystemSpec:
     help: str
 
 
-#: the subsystem tag taxonomy — the ``subsystem`` label vocabulary of
+#: the subsystem tag catalogue — the ``subsystem`` label vocabulary of
 #: ``areal_hbm_ledger_bytes``/``areal_hbm_ledger_peak_bytes``.  The docs
 #: table renders from here; add new seams here first.
 SUBSYSTEM_TABLE = [

@@ -33,7 +33,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from areal_tpu.base.jax_compat import pallas_tpu_compiler_params
 
 DEFAULT_BLOCK = 256
 _NEG_INF = -1e30
@@ -199,7 +198,7 @@ def flash_decode(
             jax.ShapeDtypeStruct((B, Hkv, r, 128), jnp.float32),
             jax.ShapeDtypeStruct((B, Hkv, r, 128), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

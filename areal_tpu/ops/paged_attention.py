@@ -43,7 +43,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from areal_tpu.base.jax_compat import pallas_tpu_compiler_params
 
 from areal_tpu.ops.decode_attention import (
     softmax_block_update,
@@ -64,11 +63,82 @@ _NEG_INF = -1e30
 PAGE_GROUP = 4
 
 
-#: cap on query rows (Q*r) per grid cell: bounds the f32 scratch at
-#: ~Hkv * 512 * (hd + 256) * 4 bytes (~1.6 MB at Hkv=2, hd=128) so
-#: prefill-chunk shapes (Q up to prefill_chunk_tokens) tile the query
-#: axis instead of blowing VMEM (code-review r5 #3)
+#: cap on query rows (Q*r) per grid cell, so prefill-chunk shapes (Q up
+#: to prefill_chunk_tokens) tile the query axis; :func:`_plan_tiles`
+#: lowers it further when the shapes need more VMEM than the budget
 MAX_Q_ROWS = 512
+
+#: scoped-VMEM limit stated to Mosaic for both paged kernels.  The
+#: compiler's default scope is 16 MiB, which four double-buffered
+#: 1024-token pages of 4 bf16 KV heads (Qwen2.5-7B: 16 MiB of page
+#: buffers alone) already exceed; v5e/v6e have 128 MiB of VMEM per core
+#: and v5p 96 MiB, so 48 MiB is safe on each.
+VMEM_LIMIT_BYTES = 48 << 20
+
+#: what :func:`vmem_bytes_needed` may reach: the limit less a margin for
+#: what the estimate cannot see (Mosaic's own spills and relayouts)
+VMEM_BUDGET_BYTES = 40 << 20
+
+
+def vmem_bytes_needed(
+    Hkv: int, BS: int, hd: int, kv_itemsize: int, quantized: bool,
+    page_group: int, q_rows: int,
+) -> int:
+    """VMEM one grid cell of :func:`paged_flash_attention` needs, from its
+    shapes: double-buffered page/scale/q/output tiles, the f32 scratch,
+    and the per-head temporaries of :func:`softmax_block_update` (scores
+    and probabilities [q_rows, BS], the page's f32 copies, and the bf16
+    splits HIGHEST precision makes of each dot operand)."""
+    page = Hkv * BS * hd * kv_itemsize
+    pages = 2 * page_group * 2 * page  # k+v, G streams, double-buffered
+    if quantized:
+        pages += 2 * page_group * 2 * Hkv * BS * 4  # scale tiles
+        pages += 2 * Hkv * BS * hd * 4  # the dequantized page, f32
+    q_tile = 2 * Hkv * q_rows * hd * 2
+    state = Hkv * q_rows * (hd + 256) * 4  # acc + m + l
+    outs_and_scratch = 3 * state  # double-buffered outs + scratch
+    temps = 3 * q_rows * BS * 4 + 4 * BS * hd * 4 + 2 * q_rows * hd * 4
+    return pages + q_tile + outs_and_scratch + temps
+
+
+def _plan_tiles(
+    Q: int, r: int, Hkv: int, BS: int, hd: int, kv_itemsize: int,
+    quantized: bool, MB: int,
+) -> Tuple[int, int]:
+    """(page_group G, query tokens per cell QT) for these shapes: start
+    from PAGE_GROUP pages and MAX_Q_ROWS rows and give up query rows,
+    then pages, until :func:`vmem_bytes_needed` fits the budget.  Raises
+    when even one page and one sublane tile of queries do not fit —
+    the caller must not quietly take another path."""
+    # QT*r must be a multiple of the 8-row sublane tile unless one cell
+    # holds the whole (short) query axis
+    step = 8 // np.gcd(8, r)
+    G = max(1, min(PAGE_GROUP, MB))
+    while True:
+        QT = min(Q, MAX_Q_ROWS // r)
+        if QT < Q:
+            QT = max(step, QT // step * step)
+        while True:
+            if (
+                vmem_bytes_needed(
+                    Hkv, BS, hd, kv_itemsize, quantized, G, QT * r
+                )
+                <= VMEM_BUDGET_BYTES
+            ):
+                return G, QT
+            if QT <= step:
+                break
+            QT = max(step, (QT // 2) // step * step)
+        if G == 1:
+            raise ValueError(
+                "paged_flash_attention: one page of "
+                f"[Hkv={Hkv}, page={BS}, head_dim={hd}] x {kv_itemsize} B "
+                f"with {step * r} query rows needs "
+                f"{vmem_bytes_needed(Hkv, BS, hd, kv_itemsize, quantized, 1, step * r)} "
+                f"bytes of VMEM, over the {VMEM_BUDGET_BYTES}-byte budget; "
+                "use a smaller page_size or shard kv heads over more chips"
+            )
+        G //= 2
 
 
 def _kernel(
@@ -151,12 +221,11 @@ def _paged_kv_map(b, qb, j, lengths_ref, tables_ref, layer_ref, *,
 
 
 
-def _group_queries(q, Hkv, r):
+def _group_queries(q, Hkv, r, QT):
     """Pad + regroup [B, Q, Hq, hd] queries into per-(kv-head) row tiles
-    [B, QB, Hkv, QT*r, hd] (QT bounded by MAX_Q_ROWS); returns
-    (qg, QT, QB, Qp)."""
+    [B, QB, Hkv, QT*r, hd] of ``QT`` query tokens each; returns
+    (qg, QB)."""
     B, Q, Hq, hd = q.shape
-    QT = max(1, min(Q, MAX_Q_ROWS // r))
     QB = -(-Q // QT)
     Qp = QB * QT
     q_pad = (
@@ -169,7 +238,7 @@ def _group_queries(q, Hkv, r):
         .transpose(0, 1, 3, 2, 4, 5)
         .reshape(B, QB, Hkv, QT * r, hd)
     )
-    return qg, QT, QB, Qp
+    return qg, QB
 
 
 def _ungroup_outputs(acc, m, l, B, QB, QT, Hkv, r, Q, Hq, hd):
@@ -240,12 +309,15 @@ def paged_flash_attention(
     if layered:
         assert layer is not None, "layer index required for a stacked pool"
     r = Hq // Hkv
-    # tile the query axis: QT tokens per grid cell, QT*r rows of scratch
-    qg, QT, QB, Qp = _group_queries(q, Hkv, r)
+    quantized = k_scale is not None
+    # tile the query axis (QT tokens per grid cell, QT*r rows of scratch)
+    # and pick the page group from the VMEM these shapes need
+    G, QT = _plan_tiles(
+        Q, r, Hkv, BS, hd, jnp.dtype(k_pool.dtype).itemsize, quantized, MB
+    )
+    qg, QB = _group_queries(q, Hkv, r, QT)
     layer_arr = _layer_scalar(layer)
 
-    G = min(PAGE_GROUP, MB)
-    quantized = k_scale is not None
     grid = (B, QB, -(-MB // G))
     kv_block = (1, 1, Hkv, BS, hd) if layered else (1, Hkv, BS, hd)
     kv_specs = [
@@ -325,8 +397,9 @@ def paged_flash_attention(
             jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, 128), jnp.float32),
             jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, 128), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(
@@ -491,14 +564,15 @@ def paged_flash_attention_deep(
         assert layer is not None
     r = Hq // Hkv
     quantized = k_scale is not None
-    qg, QT, QB, Qp = _group_queries(q, Hkv, r)
+    # same VMEM plan as the default kernel: its G double-buffered page
+    # streams cost what a ring of 2G pages costs here
+    G, QT = _plan_tiles(
+        Q, r, Hkv, BS, hd, jnp.dtype(k_pool.dtype).itemsize, quantized,
+        DEEP_BUFFERS // 2,
+    )
+    nbuf = 2 * G
+    qg, QB = _group_queries(q, Hkv, r, QT)
     layer_arr = _layer_scalar(layer)
-    # ring depth bounded by a ~12 MB VMEM budget for the page rings
-    # (int8 pools add a small f32 scale tile per page)
-    tile_bytes = Hkv * BS * hd * jnp.dtype(k_pool.dtype).itemsize
-    if quantized:
-        tile_bytes += Hkv * BS * 4
-    nbuf = int(max(2, min(DEEP_BUFFERS, (6 << 20) // max(tile_bytes, 1))))
     grid = (B, QB)
     scratch = [
         pltpu.VMEM((nbuf, Hkv, BS, hd), k_pool.dtype),
@@ -558,8 +632,9 @@ def paged_flash_attention_deep(
             jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, 128), jnp.float32),
             jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, 128), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
     )(

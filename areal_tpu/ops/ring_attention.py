@@ -125,8 +125,6 @@ def ring_attention(
 ) -> jax.Array:
     """shard_map wrapper: batch over ``batch_axes``, sequence over ``axis``,
     heads over ``head_axis``; XLA only moves KV blocks over the ring."""
-    from areal_tpu.base.jax_compat import shard_map
-
     bspec = P(batch_axes)
     qkv_spec = P(batch_axes, axis, head_axis, None)
     tok_spec = P(batch_axes, axis)
@@ -135,7 +133,7 @@ def ring_attention(
         axis_name=axis,
         sliding_window=sliding_window,
     )
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, tok_spec, tok_spec),

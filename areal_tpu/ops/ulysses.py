@@ -119,8 +119,6 @@ def ulysses_attention(
     sliding_window: Optional[int] = None,
 ) -> jax.Array:
     """shard_map wrapper mirroring :func:`ring_attention.ring_attention`."""
-    from areal_tpu.base.jax_compat import shard_map
-
     n = mesh.shape.get(axis, 1)
     tp = mesh.shape.get(head_axis, 1) if head_axis else 1
     local_hq = q.shape[2] // max(tp, 1)
@@ -136,7 +134,7 @@ def ulysses_attention(
         axis_name=axis,
         sliding_window=sliding_window,
     )
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, tok_spec, tok_spec),

@@ -36,7 +36,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from areal_tpu.base.jax_compat import shard_map as _shard_map
 
 Aux = Any
 # stage_fn(local_stacked_params, {"x": [B,T,D], **side_inputs}) -> (y, aux)
@@ -128,7 +127,7 @@ def pipeline_apply(
     has_aux = aux_zero is not None
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             jax.sharding.PartitionSpec("pipe"),
@@ -243,7 +242,7 @@ def pipeline_apply_1f1b(
         return out
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("pipe"), P(), P()),
         out_specs=P("pipe"),
@@ -272,7 +271,7 @@ def pipeline_apply_1f1b(
         return outs
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("pipe"), P(), P(), P()),
         # dxs banks live ONLY on stage 0 — concatenate over pipe and let

@@ -206,7 +206,7 @@ class GenerationServerWorker(worker_base.Worker):
         self.worker_name = config.worker_name
         self.logger = logging_.getLogger(self.worker_name)
 
-        from areal_tpu.engine.backend import make_model
+        from areal_tpu.engine.backend import cast_floating, make_model
         from areal_tpu.engine.dispatch import resolve_dispatch_table
         from areal_tpu.engine.inference_server import ContinuousBatchingEngine
         from areal_tpu.engine.sampling import SamplingParams
@@ -287,9 +287,22 @@ class GenerationServerWorker(worker_base.Worker):
                 )
             devices = jax.devices()[start : start + world]
             mesh = config.mesh_spec.make_mesh(devices)
-        elif config.device_idx is not None:
-            device = jax.devices()[config.device_idx % len(jax.devices())]
+        else:
+            idx = config.device_idx or 0
+            if idx >= len(jax.devices()):
+                raise ValueError(
+                    f"gen server {config.worker_name} is placed on device "
+                    f"{idx} but only {len(jax.devices())} exist — set "
+                    "gen_device_start/device_idx to a chip this host has"
+                )
+            device = jax.devices()[idx]
+        # weights are built on the host (engine/backend.host_device) and
+        # cast there to the serving dtype, so the engine's placement is
+        # the first and only copy on its chip(s)
         model = make_model(config.model, None, None, tokenizer=tokenizer)
+        model.init_params = cast_floating(
+            model.init_params, model.model_cfg.dtype
+        )
         sampling = SamplingParams(
             temperature=config.temperature,
             greedy=getattr(config, "greedy", False),

@@ -14,6 +14,9 @@ import numpy as np
 
 
 def main():
+    from areal_tpu.base.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     import jax
     import jax.numpy as jnp
 

@@ -102,15 +102,28 @@ def test_utilization_monitor_publishes_gauges():
 
 
 def test_device_peak_flops_table():
+    import pytest
+
     from areal_tpu.base.monitor import device_peak_flops
 
     class _D:
-        device_kind = "TPU v5e"
+        platform = "tpu"
+        device_kind = "TPU v5 lite"  # how a v5e reports itself
 
     assert device_peak_flops(_D()) == 197e12
 
     class _C:
+        platform = "cpu"
         device_kind = "cpu"
 
+    # no published peak off-TPU: MFU is skipped, not invented
     assert device_peak_flops(_C()) == 0.0
     assert device_peak_flops(object()) == 0.0
+
+    class _Unknown:
+        platform = "tpu"
+        device_kind = "TPU v9 mega"
+
+    # an unknown chip is an error, never a default peak
+    with pytest.raises(ValueError, match="v9 mega"):
+        device_peak_flops(_Unknown())

@@ -12,9 +12,9 @@ insert.  This file pins, on CPU:
   scales, weight-swap flushes dropping scale-bearing host payloads with
   the blocks;
 * the serving smokes tier-1 keeps (one per integration, per the
-  headroom budget): a quant paged decode wave with the measured greedy
-  divergence pin vs the fp arm, and a spilled-prefix swap-in arm over
-  an int8 pool;
+  headroom budget): a quant paged decode wave pinned to the fp arm at
+  the logit level (teacher-forced log-probabilities; greedy flips only
+  at near-ties), and a spilled-prefix swap-in arm over an int8 pool;
 * ``kv_cache_dtype="auto"`` parity: the quantization plumbing must
   leave the unquantized path token-identical to the dense engine (the
   acceptance criterion's pre-PR-behavior pin);
@@ -244,22 +244,87 @@ def _replay(eng, n_sessions=3, turns=2, seed=0, max_new=8, user_len=6):
     return streams
 
 
+def _forced_replay(
+    fp, q, n_sessions=3, turns=2, seed=0, max_new=8, user_len=6
+):
+    """The multi-turn replay TEACHER-FORCED: both engines answer the same
+    conversation every turn (it grows by the fp arm's tokens), so one
+    flipped token cannot rewrite every later prompt.  Per request:
+    ``(n_tokens, agreed_prefix, max |dlogp| over the prefix, logprob gap
+    at the first flip or None)`` — the gap is fp's logprob of ITS token
+    minus the quantized arm's logprob of its own."""
+    rng = np.random.default_rng(seed)
+    convs = [list(rng.integers(6, 60, (24,))) for _ in range(n_sessions)]
+    rows = []
+    for t in range(turns):
+        for s in range(n_sessions):
+            qid = f"s{s}t{t}"
+            outs = []
+            for eng in (fp, q):
+                eng.submit(_req(qid, convs[s], max_new))
+                run_until_done(eng, max_steps=3000)
+                outs.append(eng.drain_results()[qid])
+            a, b = outs
+            ta, tb = list(a.output_ids), list(b.output_ids)
+            n = min(len(ta), len(tb))
+            k = next((i for i in range(n) if ta[i] != tb[i]), n)
+            la = np.asarray(a.output_logprobs, np.float64)
+            lb = np.asarray(b.output_logprobs, np.float64)
+            rows.append(
+                (
+                    n,
+                    k,
+                    float(np.abs(la[:k] - lb[:k]).max()) if k else 0.0,
+                    float(la[k] - lb[k]) if k < n else None,
+                )
+            )
+            convs[s] = (
+                convs[s] + ta + list(rng.integers(6, 60, (user_len,)))
+            )
+    return rows
+
+
+def _assert_only_near_ties_flip(rows, logp_tol):
+    """The quantized arm's error is storage rounding and nothing else:
+    wherever the two arms emit the same tokens their log-probabilities
+    agree to ``logp_tol``, and a greedy flip happens only where the two
+    candidates' log-probabilities were within that same error (a
+    near-tie of the tiny random model, which ANY rounding change flips
+    — a different jax release's reduction order included).  Returns the
+    number of requests that flipped."""
+    assert any(k > 0 for _, k, _, _ in rows), rows
+    for n, k, prefix_err, gap in rows:
+        assert prefix_err <= logp_tol, rows
+        assert gap is None or abs(gap) <= logp_tol, rows
+    return sum(1 for n, k, _, _ in rows if k < n)
+
+
+#: |fp logprob - int8-KV logprob| on identical prefixes.  Measured on the
+#: tiny-config replay: 0.005 at worst (logprobs near -4.1), the size of
+#: absmax/127 rounding of K and V; flips sat at gaps of 1e-4 and 1.6e-3.
+KV_INT8_LOGP_TOL = 0.02
+
+
 def test_int8_divergence_pin_on_multi_turn_replay():
-    """The quant paged decode smoke + the divergence-rate pin: the int8
-    arm's greedy streams on the multi-turn replay stay within the
-    measured bar of the fp arm — asserted, not eyeballed — and the
-    check lands in the engine's kv_quant divergence counters."""
-    fp, *_ = make_engine()
-    q, *_ = make_engine(kv_cache_dtype="int8")
+    """The quant paged decode smoke + the quality pin, at the LOGIT level:
+    greedy streams of a tiny random model flip at near-ties under any
+    rounding change (the free-running greedy-stream statistic moved from
+    ~0.2 to 0.46 on a jax upgrade with the int8 path untouched), so the
+    pin holds the int8 arm's log-probabilities to the fp arm's on
+    teacher-forced prefixes and allows a flip only at a near-tie.  The
+    flips still land in the engine's kv_quant divergence counters."""
+    # prefix cache off in both arms: this is a numerics pin, and a cached
+    # prefix's KV was computed under another chunk layout.  (The cache +
+    # int8 integration stays covered by the swap-in smokes below.)
+    fp, *_ = make_engine(prefix_cache=False)
+    q, *_ = make_engine(kv_cache_dtype="int8", prefix_cache=False)
     fp.park_ttl_steps = q.park_ttl_steps = 0
-    ref = _replay(fp)
-    got = _replay(q)
-    rate, n_div = _lcp_divergence(ref, got)
-    q.note_kv_divergence_check(len(ref), n_div)
-    assert rate <= DIVERGENCE_BAR, (rate, ref, got)
+    rows = _forced_replay(fp, q)
+    n_div = _assert_only_near_ties_flip(rows, KV_INT8_LOGP_TOL)
+    q.note_kv_divergence_check(len(rows), n_div)
     st = q.kv_quant_stats()
     assert st["quantized"] == 1 and st["storage_bits"] == 8
-    assert st["divergence_checks_total"] == len(ref)
+    assert st["divergence_checks_total"] == len(rows)
     assert st["divergence_diverged_total"] == n_div
     # storage really is quantized + scales: half-or-less block bytes
     assert q._pool_block_bytes() < fp._pool_block_bytes() / 1.8

@@ -44,7 +44,11 @@ from bench import lcp_divergence as _lcp_divergence
 
 from areal_tpu.models import quantize, transformer
 
-from tests.engine.test_kv_quant import _replay
+from tests.engine.test_kv_quant import (
+    _assert_only_near_ties_flip,
+    _forced_replay,
+    _replay,
+)
 from tests.engine.test_prefix_cache import (
     _req,
     make_engine,
@@ -184,24 +188,31 @@ def test_serving_pspecs_cover_quant_leaves():
 # -- tier-1 serving smokes ----------------------------------------------------
 
 
+#: |fp logprob - int8-weight logprob| on identical prefixes.  Measured on
+#: the tiny-config replay: 0.011 at worst, flips at gaps up to 0.015 —
+#: per-output-channel absmax/127 rounding of every matmul weight.
+WEIGHT_INT8_LOGP_TOL = 0.04
+
+
 def test_int8_weight_divergence_pin_paged_prefix_and_dense():
-    """THE tier-1 quantized decode smoke: int8 serving weights on the
-    paged + prefix-cache multi-turn replay stay within the measured
-    divergence bar of the full-precision arm (check folded into the
-    engine's weight_quant counters), and the DENSE int8 arm passes the
-    same pin — the acceptance matrix's dense leg."""
-    fp, *_ = make_engine()
-    q, *_ = make_engine(serving_weight_dtype="int8")
+    """THE tier-1 quantized decode smoke, pinned at the LOGIT level (see
+    test_kv_quant.test_int8_divergence_pin_on_multi_turn_replay for why
+    not greedy streams): int8 serving weights on the paged multi-turn
+    replay keep the fp arm's log-probabilities
+    to rounding on teacher-forced prefixes, flips only at near-ties
+    (folded into the engine's weight_quant counters), and the DENSE
+    int8 arm passes the same pin — the acceptance matrix's dense leg."""
+    # prefix cache off in both arms, as in the kv-quant pin (see there)
+    fp, *_ = make_engine(prefix_cache=False)
+    q, *_ = make_engine(serving_weight_dtype="int8", prefix_cache=False)
     fp.park_ttl_steps = q.park_ttl_steps = 0
-    ref = _replay(fp)
-    got = _replay(q)
-    rate, n_div = _lcp_divergence(ref, got)
-    q.note_weight_divergence_check(len(ref), n_div)
-    assert rate <= DIVERGENCE_BAR, (rate, ref, got)
+    rows = _forced_replay(fp, q)
+    n_div = _assert_only_near_ties_flip(rows, WEIGHT_INT8_LOGP_TOL)
+    q.note_weight_divergence_check(len(rows), n_div)
     st = q.weight_quant_stats()
     assert st["quantized"] == 1 and st["storage_bits"] == 8
     assert st["quantized_leaves"] > 0
-    assert st["divergence_checks_total"] == len(ref)
+    assert st["divergence_checks_total"] == len(rows)
     assert st["divergence_diverged_total"] == n_div
     # resident tree really is ~half the bytes
     fp_bytes = fp.weight_quant_stats()["param_bytes"]
@@ -210,10 +221,9 @@ def test_int8_weight_divergence_pin_paged_prefix_and_dense():
     fpd, *_ = make_engine(cache_mode="dense")
     qd, *_ = make_engine(cache_mode="dense", serving_weight_dtype="int8")
     fpd.park_ttl_steps = qd.park_ttl_steps = 0
-    rate_d, _ = _lcp_divergence(
-        _replay(fpd, turns=1), _replay(qd, turns=1)
+    _assert_only_near_ties_flip(
+        _forced_replay(fpd, qd, turns=1), WEIGHT_INT8_LOGP_TOL
     )
-    assert rate_d <= DIVERGENCE_BAR, rate_d
 
 
 def test_auto_arm_token_identical_to_dense():

@@ -1,5 +1,5 @@
 """Tenant admission plane unit tests: token-bucket refill math, the
-typed reject taxonomy (rate_limited / budget_exhausted /
+typed reject catalogue (rate_limited / budget_exhausted /
 request_too_large), budget terminality, the unknown-tenant default
 policy, and settle-time refunds.  All pure host-side Python with an
 explicit clock — no jax, no sockets."""
@@ -63,7 +63,7 @@ def test_bucket_burst_defaults_to_one_second_of_rate():
         TokenBucket(rate_tokens_per_s=0.0)
 
 
-# -- reject taxonomy ----------------------------------------------------------
+# -- reject catalogue ----------------------------------------------------------
 
 
 def _plane(**policy_kw):
@@ -158,7 +158,7 @@ def test_reject_counters_and_stats_accumulate_per_reason():
     assert st["token_budget"] == 50.0
 
 
-def test_http_status_map_covers_the_whole_taxonomy():
+def test_http_status_map_covers_the_whole_catalogue():
     assert REJECT_HTTP_STATUS == {
         REJECT_RATE_LIMITED: 429,
         REJECT_BUDGET_EXHAUSTED: 403,

@@ -1,6 +1,6 @@
 """HbmLedger unit contract: handle lifecycle, clamping, watermarks,
 publish/reconcile export, leak audit, the disabled no-op mode, and the
-tag taxonomy's agreement with the metric-label docs."""
+tag catalogue's agreement with the metric-label docs."""
 
 import threading
 
@@ -152,7 +152,7 @@ def test_concurrent_resizes_stay_consistent():
     assert led.snapshot()["stream_buffers"] == 8 * 13
 
 
-def test_taxonomy_matches_metric_label_docs():
+def test_catalogue_matches_metric_label_docs():
     """Every canonical tag renders into the published gauge exactly once
     — the docs table in observability.md is generated from this
     vocabulary, and the fleet merge keys on it."""

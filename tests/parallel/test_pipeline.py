@@ -27,15 +27,6 @@ from areal_tpu.parallel.pipeline import pick_microbatches
 
 from tests.engine.test_train_engine import make_sample
 
-from areal_tpu.base.jax_compat import partial_auto_shard_map_supported
-
-requires_partial_auto_shard_map = pytest.mark.skipif(
-    not partial_auto_shard_map_supported(),
-    reason="pipeline shard_map is manual over only `pipe` (partial-auto); "
-    "jax 0.4.x cannot lower axis_index in such a region (PartitionId)",
-)
-
-
 
 def _batch(cfg, B=8, T=16, seed=0):
     rng = np.random.default_rng(seed)
@@ -57,7 +48,6 @@ def test_pick_microbatches():
 
 
 @pytest.mark.parametrize("spec", ["p2d2m2", "p4d2", "p2f2"])
-@requires_partial_auto_shard_map
 def test_pipelined_forward_matches_scan(spec):
     # stage count must divide the layer count
     n_layers = 4 if "p4" in spec else 2
@@ -85,7 +75,6 @@ def test_pipelined_forward_matches_scan(spec):
     assert err < 2e-4, err
 
 
-@requires_partial_auto_shard_map
 def test_pipelined_forward_rows_not_divisible():
     """Row counts that don't divide the micro-batch count get padded
     inside the pipelined path and sliced back."""
@@ -116,7 +105,6 @@ def test_pipelined_forward_rows_not_divisible():
 @pytest.mark.parametrize(
     "remat,remat_policy", [(False, "none"), (True, "qkv_attn")]
 )
-@requires_partial_auto_shard_map
 def test_pipelined_train_step_matches_plain(remat, remat_policy):
     """One optimizer step on a p2 mesh == the same step unpipelined —
     with and without per-layer remat (jax.checkpoint must survive AD
@@ -159,7 +147,6 @@ def test_pipelined_train_step_matches_plain(remat, remat_policy):
         )
 
 
-@requires_partial_auto_shard_map
 def test_pipelined_moe_aux_losses_flow():
     """MoE router losses survive the pipeline (psum over stages)."""
     from areal_tpu.interfaces.sft_interface import sft_loss_fn as loss_fn
@@ -209,7 +196,6 @@ def test_pipelined_moe_aux_losses_flow():
     assert np.isclose(ref_stats["loss"], pp_stats["loss"], atol=5e-3)
 
 
-@requires_partial_auto_shard_map
 def test_ppo_actor_train_under_pipeline():
     """The RL path composes with PP: the PPO actor loss (per-token extras,
     GAE prep, chunked logprob head) runs on a pipe mesh and reproduces the
@@ -262,7 +248,6 @@ def test_pipe_times_seq_rejected():
         transformer.set_ambient_mesh(None)
 
 
-@requires_partial_auto_shard_map
 def test_1f1b_train_step_matches_gpipe_and_plain():
     """The 1F1B custom-VJP schedule computes the SAME optimizer step as
     GPipe-by-AD and the unpipelined engine (round-4 verdict #4)."""

@@ -51,7 +51,7 @@ def cluster(tmp_path, monkeypatch):
             "AREAL_NAME_RESOLVE_ROOT": nr_root,
             "JAX_PLATFORMS": "cpu",
             "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
-            "PYTHONPATH": REPO_ROOT,  # hermetic: drop sitecustomize plugins
+            "PYTHONPATH": REPO_ROOT,  # hermetic: this checkout only
         }
         procs.append(
             subprocess.Popen(

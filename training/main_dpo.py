@@ -16,12 +16,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from areal_tpu.api.cli_args import dump_config, parse_cli
 from areal_tpu.apps.local_runner import register_impls, run_experiment_local
 from areal_tpu.base import constants, logging_
+from areal_tpu.base.compile_cache import setup_compile_cache
 from areal_tpu.experiments.dpo_exp import DPOExperiment
 
 logger = logging_.getLogger("main_dpo")
 
 
 def main():
+    setup_compile_cache()
     register_impls()
     exp: DPOExperiment = parse_cli(DPOExperiment)
     exp.apply_device_overrides()
