@@ -1,0 +1,10 @@
+"""The benchmark's own tests run on the CPU at a toy size: they guard
+paths, arguments and arithmetic, never a speed."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
