@@ -84,7 +84,8 @@ from areal_tpu.observability.hbm_ledger import (
     tree_nbytes,
 )
 from areal_tpu.observability.latency import LatencyDigest, LatencyRecord
-from areal_tpu.observability.tracing import get_tracer
+from areal_tpu.observability.table import ENGINE_PHASES
+from areal_tpu.observability.tracing import PhaseClock, get_tracer
 
 #: back-compat alias: the auto dense/paged crossover now lives in the
 #: (config-overridable, bench-derivable) dispatch table — see
@@ -862,15 +863,17 @@ class ContinuousBatchingEngine:
         self.prefix_peer_pulls_total = 0
         self.prefix_peer_pull_bytes_total = 0
         self.prefix_peer_pull_rejects: Dict[str, int] = {}
-        # decode-loop time attribution (cumulative seconds): host = admit/
-        # bookkeeping/dispatch-enqueue, device = blocked waiting for chunk
-        # compute, fetch = device->host transfer after completion.  The
-        # split answers "is the decode gap the fetch or host bookkeeping?"
-        # — surfaced at /metrics and in bench.py's decode sub-rows.
-        self.time_host_s = 0.0
-        self.time_device_s = 0.0
-        self.time_fetch_s = 0.0
+        # what the engine's thread is doing: every part of a step is a
+        # phase span (observability/tracing.phase: in the profiler's
+        # trace when one is being taken) whose self seconds also add up
+        # here, always.  ``timing_split()`` reads its host/device/fetch
+        # split off these totals.
+        self._phases = PhaseClock(ENGINE_PHASES)
         self.chunks_total = 0
+        #: every token handed to a row, first tokens included, counted
+        #: where it is handed over (``gen_tokens_total`` moves only when
+        #: a row finishes)
+        self.tokens_emitted_total = 0
         # async-fetch accounting: chunks whose outputs started a
         # device->host copy at dispatch, and harvests that found the
         # oldest chunk already complete (its fetch fully overlapped)
@@ -1263,6 +1266,25 @@ class ContinuousBatchingEngine:
     @property
     def free_pool_blocks(self) -> int:
         return len(self._free_blocks)
+
+    @property
+    def pages_total(self) -> int:
+        """Blocks of the paged KV pool (0 with the dense cache)."""
+        return self.n_blocks if self.paged else 0
+
+    @property
+    def pages_live(self) -> int:
+        """Pool blocks referenced by rows that are decoding or filling,
+        each block once however many siblings share it.  Parked rows and
+        what the prefix cache holds are not live: ``free_pool_blocks``
+        counts both as held."""
+        if not self.paged:
+            return 0
+        live = set()
+        for row_id, row in enumerate(self.rows):
+            if row is not None and not row.parked:
+                live.update(self._row_blocks[row_id])
+        return len(live)
 
     def _alloc_blocks_reclaiming(
         self, n: int, keep_qids=(), protect_step: Optional[int] = None
@@ -2782,6 +2804,10 @@ class ContinuousBatchingEngine:
         # counters alone can't show the overlap).  Swap roots are
         # synthetic ("swap-v{n}") and force-sampled — a weight swap is
         # fleet-wide, never a per-rollout event the hash slice covers.
+        with self._phases.phase("areal.engine.swap") as span:
+            self._apply_weights(peek_version, span)
+
+    def _apply_weights(self, peek_version, span):
         swap_root = f"swap-v{peek_version}" if peek_version is not None \
             else f"swap-v{self.version + 1}"
         self.tracer.force(swap_root)
@@ -2920,6 +2946,7 @@ class ContinuousBatchingEngine:
         self.swaps_total += 1
         self.swap_recomputed_rows_total += len(entries)
         self.swap_applying = False
+        span.set_metadata(version=self.version, rows_recomputed=len(entries))
         if pre_sharded:
             self.swaps_staged_total += 1
         if self._slo_enabled:
@@ -3004,7 +3031,10 @@ class ContinuousBatchingEngine:
         )
         self.prefill_calls += 1
         self.prefill_tokens_total += int(lens[:m].sum())
-        return np.asarray(tok)[:n], np.asarray(logp)[:n]
+        with self._phases.phase(
+            "areal.engine.fill.first_token_wait", rows=n
+        ):
+            return np.asarray(tok)[:n], np.asarray(logp)[:n]
 
     def _try_resume(self, req: model_api.APIGenerateInput) -> bool:
         """Resume a parked row whose resident KV matches this continuation:
@@ -3092,30 +3122,34 @@ class ContinuousBatchingEngine:
             return [], [], None
         C = bucket_len(max(take for _, take in batch))
         F_pad = 1 << (len(batch) - 1).bit_length()
-        toks = np.zeros((F_pad, C), np.int32)
-        starts = np.zeros((F_pad,), np.int32)
-        cls = np.zeros((F_pad,), np.int32)
-        tables = np.zeros((F_pad, self.blocks_per_row), np.int32)
-        for i, (f, take) in enumerate(batch):
-            toks[i, :take] = f.tokens[f.fill_pos : f.fill_pos + take]
-            starts[i] = f.fill_pos
-            cls[i] = take
-            tables[i, : len(f.blocks)] = f.blocks
-        out = paged.paged_fill_chunk(
-            self.params,
-            self.k_pool,
-            self.v_pool,
-            self.cfg,
-            jnp.asarray(toks),
-            jnp.asarray(starts),
-            jnp.asarray(cls),
-            jnp.asarray(tables),
-            use_kernel=self._use_paged_kernel,
-            mesh=self.mesh,
-            kv_axis=getattr(self, "_kv_axis", None),
-            k_scale=self.k_scale,
-            v_scale=self.v_scale,
-        )
+        with self._phases.phase(
+            "areal.engine.fill.dispatch", prompts=len(batch), f_pad=F_pad,
+            c=C, tokens=sum(take for _, take in batch),
+        ):
+            toks = np.zeros((F_pad, C), np.int32)
+            starts = np.zeros((F_pad,), np.int32)
+            cls = np.zeros((F_pad,), np.int32)
+            tables = np.zeros((F_pad, self.blocks_per_row), np.int32)
+            for i, (f, take) in enumerate(batch):
+                toks[i, :take] = f.tokens[f.fill_pos : f.fill_pos + take]
+                starts[i] = f.fill_pos
+                cls[i] = take
+                tables[i, : len(f.blocks)] = f.blocks
+            out = paged.paged_fill_chunk(
+                self.params,
+                self.k_pool,
+                self.v_pool,
+                self.cfg,
+                jnp.asarray(toks),
+                jnp.asarray(starts),
+                jnp.asarray(cls),
+                jnp.asarray(tables),
+                use_kernel=self._use_paged_kernel,
+                mesh=self.mesh,
+                kv_axis=getattr(self, "_kv_axis", None),
+                k_scale=self.k_scale,
+                v_scale=self.v_scale,
+            )
         if self._kv_quant:
             (logits, self.k_pool, self.v_pool, self.k_scale,
              self.v_scale) = out
@@ -3192,7 +3226,33 @@ class ContinuousBatchingEngine:
         (refcount) and receive a COPY of the partial tail block (their
         generated tokens diverge inside it).  Fresh targets sample their
         first token from the shared final logits; preempted targets
-        restore their saved decode state with zero sampling."""
+        restore their saved decode state with zero sampling.
+
+        Three spans one after the other, not nested: a profiler session
+        that starts inside the fetch loses every span open around it, and
+        what follows the fetch is where the device waits for the host."""
+        with self._phases.phase("areal.engine.fill.activate"):
+            sample_targets, activation, sampled = self._share_fill_blocks(
+                fills, idxs, logits
+            )
+        toks = logps = np.zeros((0,))
+        if sample_targets:
+            n = len(sample_targets)
+            # a BLOCKING fetch: the sampled tokens exist once the fill
+            # program has run, behind every decode chunk queued before it
+            with self._phases.phase(
+                "areal.engine.fill.first_token_wait", rows=n
+            ):
+                toks = np.asarray(sampled[0])[:n]
+                logps = np.asarray(sampled[1])[:n]
+            self.tokens_emitted_total += n
+        with self._phases.phase("areal.engine.fill.activate"):
+            self._activate_filled_rows(sample_targets, toks, logps, activation)
+
+    def _share_fill_blocks(self, fills: List[_Fill], idxs, logits):
+        """The part of ``_distribute_fills`` before the fetch.  Returns
+        (fresh targets to sample for, rows to activate as they are, the
+        sampled tokens and log-probabilities still on the device)."""
         copy_src, copy_dst = [], []
         sample_targets: List[Tuple[_Fill, _FillTarget, int]] = []
         activation: List[Tuple[int, int, int, int]] = []  # rid,cur,budget,len
@@ -3258,6 +3318,7 @@ class ContinuousBatchingEngine:
             src[: len(copy_src)] = copy_src
             dst[: len(copy_dst)] = copy_dst
             self._copy_pool_blocks(src, dst)
+        sampled = None
         if sample_targets:
             n = len(sample_targets)
             n_pad = 1 << (n - 1).bit_length()
@@ -3268,7 +3329,7 @@ class ContinuousBatchingEngine:
                 src_idx[i] = li
                 tgt_seeds[i] = _qid_seed(tgt_i.req.qid)
                 tgt_pos[i] = len(f_i.tokens)
-            toks, logps = _sample_rows(
+            sampled = _sample_rows(
                 logits,
                 jnp.asarray(src_idx),
                 jnp.asarray(tgt_seeds),
@@ -3277,8 +3338,12 @@ class ContinuousBatchingEngine:
                 self.sampling,
                 mesh=self.mesh,
             )
-            toks = np.asarray(toks)[:n]
-            logps = np.asarray(logps)[:n]
+        return sample_targets, activation, sampled
+
+    def _activate_filled_rows(self, sample_targets, toks, logps, activation):
+        """The part of ``_distribute_fills`` after the fetch: first tokens
+        handed to their rows, rows activated on the device."""
+        if sample_targets:
             t_first = time.monotonic()  # fill's first tokens on host
             for (f, tgt, _), tok_i, logp in zip(
                 sample_targets, toks.tolist(), logps.tolist()
@@ -3351,9 +3416,12 @@ class ContinuousBatchingEngine:
             self.kv_lengths = self.kv_lengths.at[ids].set(lens)
             self.row_seeds = self.row_seeds.at[ids].set(seeds)
 
-    def _admit_paged(self):
+    def _admit_paged(self) -> Tuple[int, int]:
+        """Returns (rows admitted, those whose fill starts behind a
+        cached prefix)."""
         if self.hold_admissions:
-            return
+            return 0, 0
+        admitted = prefix_hits = 0
         for row_id, row in enumerate(self.rows):
             if row is not None and row.parked and (
                 self._step_seq - row.park_step > self.park_ttl_steps
@@ -3405,6 +3473,8 @@ class ContinuousBatchingEngine:
             self._set_row_blocks(rid, fill.blocks)
             row.filling = True
             self.rows[rid] = row
+            admitted += 1
+            prefix_hits += fill.fill_pos > 0
             self.tracer.event(
                 row.req.qid, "engine.admit", row=rid,
                 prompt_len=len(seq), cached_tokens=fill.fill_pos,
@@ -3469,6 +3539,7 @@ class ContinuousBatchingEngine:
                     break
                 self._filling.append(fill)
                 self._set_row_blocks(rid, fill.blocks)
+                prefix_hits += fill.fill_pos > 0
                 # canonical blocks live in target 0's table; refcount
                 # stays 1 until extra targets share them
                 self.tracer.event(
@@ -3493,13 +3564,16 @@ class ContinuousBatchingEngine:
             )
             self._slo_admitted(row)
             self.rows[rid] = row
+            admitted += 1
+        return admitted, prefix_hits
 
-    def _ensure_decode_blocks(self):
+    def _ensure_decode_blocks(self) -> Tuple[int, int]:
         """Every ACTIVE row's table must cover ``length + chunk`` slots
         before a decode dispatch (the chunk allocates nothing device-side).
         Under pool pressure: evict parked rows, then PREEMPT the youngest
         active rows (recompute-on-readmit, the deterministic analogue of
-        vLLM's recompute preemption)."""
+        vLLM's recompute preemption).  Returns (blocks allocated, rows
+        preempted)."""
         W = self.chunk_size
         if self._spec is not None:
             # a speculative verify window may write up to max_draft + 1
@@ -3516,6 +3590,7 @@ class ContinuousBatchingEngine:
         for ch in self._ring:
             for rid, _ in ch.snapshot:
                 pend_counts[rid] = pend_counts.get(rid, 0) + 1
+        allocated, preempted0 = 0, self.preempted_total
         for row_id in range(self.max_batch):
             row = self.rows[row_id]
             if row is None or row.parked or row.filling:
@@ -3531,6 +3606,7 @@ class ContinuousBatchingEngine:
                     self._set_row_blocks(
                         row_id, self._row_blocks[row_id] + blocks
                     )
+                    allocated += deficit
                     break
                 # reclamation tiers: prefix-cache entries (recompute
                 # insurance only — always yield to a live row), then
@@ -3567,6 +3643,7 @@ class ContinuousBatchingEngine:
                     -(-(host_len + W) // self.page_size),
                     self.blocks_per_row,
                 )
+        return allocated, self.preempted_total - preempted0
 
     def _row_priority(self, row: _Row) -> str:
         """The admission plane's priority class, stamped into request
@@ -3645,11 +3722,36 @@ class ContinuousBatchingEngine:
                 )
         return longest + len(self._ring) * self.chunk_size >= thr
 
+    def _count_dispatch(self, span, snapshot, chunk_size: int):
+        """The counts of a decode dispatch's span: the context the host
+        knows the dispatched rows to have (prompt + generated so far;
+        chunks still in the ring are not in it, so it is a floor) and the
+        pages that context spans.  Read only while a profiler session
+        records them."""
+        if not span.is_enabled():
+            return
+        ctx = [
+            len(self.rows[i].prompt) + len(self.rows[i].generated)
+            for i, _ in snapshot
+        ]
+        page = self.page_size if self.paged else self.kv_cache_len
+        span.set_metadata(
+            rows=len(snapshot),
+            ctx_tokens_sum=sum(ctx),
+            chunk_size=chunk_size,
+            pages_attended=sum(-(-c // page) for c in ctx),
+        )
+
     def _dispatch_chunk_paged(self):
         snapshot = [
             (i, r.epoch) for i, r in enumerate(self.rows)
             if r is not None and not r.parked and not r.filling
         ]
+        with self._phases.phase("areal.engine.decode.dispatch") as span:
+            self._count_dispatch(span, snapshot, self.chunk_size)
+            self._dispatch_paged(snapshot)
+
+    def _dispatch_paged(self, snapshot):
         if self._tables_dirty:
             self._tables = self._upload_tables()
             self._tables_dirty = False
@@ -3767,7 +3869,10 @@ class ContinuousBatchingEngine:
                 spec_won and rid in drafts, self._step_seq
             )
         if spec_won:
-            self._dispatch_verify_chunk(live, drafts)
+            # a verify window attends each row's context once
+            with self._phases.phase("areal.engine.decode.dispatch") as span:
+                self._count_dispatch(span, [(i, 0) for i in live], 1)
+                self._dispatch_verify_chunk(live, drafts)
         else:
             self._dispatch_chunk_paged()
         return True
@@ -3870,9 +3975,10 @@ class ContinuousBatchingEngine:
         self._spec_accept_samples.clear()
         return out
 
-    def _admit(self):
+    def _admit(self) -> int:
+        """Returns the rows admitted."""
         if self.hold_admissions:
-            return
+            return 0
         # expired parked rows first: a row parked past the TTL is likely
         # abandoned (rollout dropped, or the group finished elsewhere)
         for row_id, row in enumerate(self.rows):
@@ -3922,7 +4028,7 @@ class ContinuousBatchingEngine:
                 max_new = max(1, self.kv_cache_len - len(prompt))
             to_admit.append((free.pop(0), req, prompt, max_new))
         if not to_admit:
-            return
+            return 0
         for rid, req, prompt, _ in to_admit:
             self.tracer.event(
                 req.qid, "engine.admit", row=rid,
@@ -3934,6 +4040,7 @@ class ContinuousBatchingEngine:
             seeds=[_qid_seed(req.qid) for _, req, _, _ in to_admit],
         )
         t_first = time.monotonic()  # first tokens materialized on host
+        self.tokens_emitted_total += len(to_admit)
         started_ids, started_curs, started_budgets = [], [], []
         started_seeds = []
         for (row_id, req, prompt, max_new), tok_i, logp in zip(
@@ -3974,6 +4081,7 @@ class ContinuousBatchingEngine:
             self.row_seeds = self.row_seeds.at[ids].set(
                 np.array(started_seeds, np.int32)
             )
+        return len(to_admit)
 
     def _finish(
         self, row_id: int, row: _Row, started: bool = True, park: bool = False
@@ -4045,6 +4153,11 @@ class ContinuousBatchingEngine:
             (i, r.epoch) for i, r in enumerate(self.rows)
             if r is not None and not r.parked
         ]
+        with self._phases.phase("areal.engine.decode.dispatch") as span:
+            self._count_dispatch(span, snapshot, self.chunk_size)
+            self._dispatch_dense(snapshot)
+
+    def _dispatch_dense(self, snapshot):
         (
             self.cache,
             out_t,
@@ -4117,28 +4230,35 @@ class ContinuousBatchingEngine:
         if not self._ring:
             return 0
         chunk = self._ring.popleft()
-        arrs, snapshot = chunk.arrs, chunk.snapshot
+        arrs = chunk.arrs
         # time attribution: block_until_ready isolates the wait for device
         # compute from the device_get transfer that follows (the transfer
         # is the PCIe cost the async dispatch-time copy hides)
-        tik = time.perf_counter()
-        try:
-            ready = all(
-                x.is_ready() for x in arrs if isinstance(x, jax.Array)
-            )
-        except Exception:  # noqa: BLE001 - readiness probe is telemetry
-            ready = False  # only; never load-bearing (SPMD determinism)
-        if ready:
-            self.fetch_ready_total += 1
-        for x in arrs:
-            if isinstance(x, jax.Array):
-                x.block_until_ready()
-        t_ready = time.perf_counter()
-        out_t, out_l, emitted, active, cur = jax.device_get(arrs)
-        t_fetched = time.perf_counter()
-        self.time_device_s += t_ready - tik
-        self.time_fetch_s += t_fetched - t_ready
+        with self._phases.phase("areal.engine.harvest.wait"):
+            try:
+                ready = all(
+                    x.is_ready() for x in arrs if isinstance(x, jax.Array)
+                )
+            except Exception:  # noqa: BLE001 - readiness probe is telemetry
+                ready = False  # only; never load-bearing (SPMD determinism)
+            if ready:
+                self.fetch_ready_total += 1
+            for x in arrs:
+                if isinstance(x, jax.Array):
+                    x.block_until_ready()
+        with self._phases.phase("areal.engine.harvest.fetch"):
+            fetched = jax.device_get(arrs)
         self.chunks_total += 1
+        with self._phases.phase("areal.engine.harvest.fold") as span:
+            n_tokens = self._fold_chunk(chunk, fetched)
+            span.set_metadata(tokens=n_tokens)
+        return n_tokens
+
+    def _fold_chunk(self, chunk: _InflightChunk, fetched) -> int:
+        """Fold a fetched chunk's outputs into the host rows; returns the
+        tokens it handed them."""
+        out_t, out_l, emitted, active, cur = fetched
+        snapshot = chunk.snapshot
         n_tokens = 0
         t_harvest = time.monotonic()  # chunk's tokens reach the host NOW
         spec_meta = chunk.spec_meta
@@ -4198,6 +4318,7 @@ class ContinuousBatchingEngine:
             else:
                 row.cur_token = int(cur[row_id])
         self._tokens_harvested_total += n_tokens
+        self.tokens_emitted_total += n_tokens
         return n_tokens
 
     def _worth_dispatching(self) -> bool:
@@ -4226,13 +4347,26 @@ class ContinuousBatchingEngine:
                 return True
         return False
 
+    def phase_seconds(self) -> Dict[str, float]:
+        """Cumulative self seconds of the engine thread by phase span
+        (``table.ENGINE_PHASES``): they add up to the wall time of the
+        steps, the paused branch's sleep excluded."""
+        return dict(self._phases.seconds)
+
     def timing_split(self) -> Dict[str, float]:
-        """Cumulative decode-loop time attribution (see the counters set in
-        ``__init__``/``_harvest_oldest``)."""
+        """Cumulative decode-loop time attribution: device = blocked
+        waiting for a chunk's compute (``harvest.wait``), fetch = its
+        outputs' device->host transfer (``harvest.fetch``), host = every
+        other phase (admit, bookkeeping, dispatch-enqueue — and any
+        blocked sync outside the harvest, which ``phase_seconds()`` tells
+        apart)."""
+        sec = self.phase_seconds()
+        device_s = sec.pop("areal.engine.harvest.wait")
+        fetch_s = sec.pop("areal.engine.harvest.fetch")
         return {
-            "host_s": self.time_host_s,
-            "device_s": self.time_device_s,
-            "fetch_s": self.time_fetch_s,
+            "host_s": sum(sec.values()),
+            "device_s": device_s,
+            "fetch_s": fetch_s,
             "chunks": self.chunks_total,
         }
 
@@ -4254,56 +4388,88 @@ class ContinuousBatchingEngine:
         self._step_seq += 1
         h0 = self._tokens_harvested_total
         if self._paused.is_set():
-            # drain the whole ring so pause means quiesced (untimed: the
-            # idle-pause sleep would otherwise read as host overhead)
-            n = self._drain_ring()
+            # drain the whole ring so pause means quiesced (the idle-pause
+            # sleep is outside the span: it would read as host overhead)
+            with self._phases.phase("areal.engine.step") as span:
+                n = self._drain_ring()
+                self._count_step(span)
             if n == 0:
                 time.sleep(0.01)
             return n
-        # host time = everything in this step that is neither the blocked
-        # device wait nor the output fetch (both accumulated in the
-        # harvest)
-        tik = time.perf_counter()
-        d0, f0 = self.time_device_s, self.time_fetch_s
-        try:
-            self._apply_pending_weights()
-            if self.paged:
-                self._admit_paged()
-                self._advance_fill()
-                self._process_deferred_cancels()
-                self._ensure_decode_blocks()
-                dispatched = False
-                if (
-                    self.n_decoding > 0
-                    and len(self._ring) < self.pipeline_depth
-                    and self._worth_dispatching()
-                ):
-                    if self._spec is not None:
-                        dispatched = self._dispatch_spec_step()
-                    else:
-                        self._dispatch_chunk_paged()
+        # the step span's SELF time is what no child span covers: host
+        # bookkeeping, like every child but the harvest's wait and fetch
+        with self._phases.phase("areal.engine.step") as span:
+            try:
+                self._apply_pending_weights()
+                if self.paged:
+                    with self._phases.phase("areal.engine.admit") as sp:
+                        admitted, hits = self._admit_paged()
+                        sp.set_metadata(
+                            rows_admitted=admitted, prefix_hits=hits
+                        )
+                    self._advance_fill()
+                    self._process_deferred_cancels()
+                    with self._phases.phase(
+                        "areal.engine.ensure_blocks"
+                    ) as sp:
+                        allocated, preempted = self._ensure_decode_blocks()
+                        if sp.is_enabled():
+                            sp.set_metadata(
+                                blocks_allocated=allocated,
+                                rows_preempted=preempted,
+                                pages_live=self.pages_live,
+                                pages_total=self.pages_total,
+                            )
+                    dispatched = False
+                    if (
+                        self.n_decoding > 0
+                        and len(self._ring) < self.pipeline_depth
+                        and self._worth_dispatching()
+                    ):
+                        if self._spec is not None:
+                            dispatched = self._dispatch_spec_step()
+                        else:
+                            self._dispatch_chunk_paged()
+                            dispatched = True
+                else:
+                    with self._phases.phase("areal.engine.admit") as sp:
+                        sp.set_metadata(
+                            rows_admitted=self._admit(), prefix_hits=0
+                        )
+                    dispatched = False
+                    if (
+                        self.n_decoding > 0
+                        and len(self._ring) < self.pipeline_depth
+                        and self._worth_dispatching()
+                    ):
+                        self._dispatch_chunk()
                         dispatched = True
-            else:
-                self._admit()
-                dispatched = False
-                if (
-                    self.n_decoding > 0
-                    and len(self._ring) < self.pipeline_depth
-                    and self._worth_dispatching()
+                if len(self._ring) >= self.pipeline_depth or (
+                    not dispatched and self._ring
                 ):
-                    self._dispatch_chunk()
-                    dispatched = True
-            if len(self._ring) >= self.pipeline_depth or (
-                not dispatched and self._ring
-            ):
-                self._harvest_oldest()
-            return self._tokens_harvested_total - h0
-        finally:
-            self._ledger_sync_host_buffers()
-            dt = time.perf_counter() - tik
-            self.time_host_s += max(
-                0.0,
-                dt
-                - (self.time_device_s - d0)
-                - (self.time_fetch_s - f0),
-            )
+                    self._harvest_oldest()
+                return self._tokens_harvested_total - h0
+            finally:
+                self._ledger_sync_host_buffers()
+                self._count_step(span)
+
+    def _count_step(self, span):
+        """The engine's state at the end of a step, as the step span's
+        counts (read only while a profiler session records them)."""
+        if not span.is_enabled():
+            return
+        decoding = filling = 0
+        for r in self.rows:
+            if r is not None and not r.parked:
+                if r.filling:
+                    filling += 1
+                else:
+                    decoding += 1
+        span.set_metadata(
+            step=self._step_seq,
+            rows_decoding=decoding,
+            rows_filling=filling,
+            pending=len(self._pending),
+            ring=len(self._ring),
+            tokens_emitted_total=self.tokens_emitted_total,
+        )

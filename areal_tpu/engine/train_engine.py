@@ -37,6 +37,7 @@ from areal_tpu.engine import batching
 from areal_tpu.engine.optimizer import OptimizerConfig, make_optimizer
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import param_pspecs
+from areal_tpu.observability.tracing import phase
 
 logger = logging_.getLogger("train_engine")
 
@@ -234,7 +235,7 @@ class TrainEngine:
                 )(params)
                 return grads, loss_sum, denom, stats
 
-            def step(params, opt_state, batch):
+            def train_step(params, opt_state, batch):
                 if n_mbs == 1:
                     mb = jax.tree.map(lambda x: x[0], batch)
                     grads, loss_sum, denom, stats = grad_of(params, mb)
@@ -272,14 +273,14 @@ class TrainEngine:
                 return params, opt_state, out
 
             self._train_step_cache[key] = (
-                jax.jit(step, donate_argnums=(0, 1)),
+                jax.jit(train_step, donate_argnums=(0, 1)),
                 loss_fn,
             )
         return self._train_step_cache[key][0]
 
     def _stack_batches(self, mbs, token_key: str):
         """Lay every micro-batch out at a common [B, T] and stack to
-        [n, B, T].
+        [n, B, T], in numpy: ``(stacked, row count, padded batches)``.
 
         Padded mode: one sequence per row, T = the GLOBAL max bucket —
         one 8k-token trace in a batch of short rows pads every stacked
@@ -344,6 +345,10 @@ class TrainEngine:
         stacked = {
             k: np.stack([b[k] for b in batches]) for k in batches[0]
         }
+        return stacked, rows, pbs
+
+    def _upload_stacked(self, stacked, rows: int):
+        """The stacked micro-batches on the devices."""
         out = {}
         for k, v in stacked.items():
             if v.ndim >= 3:
@@ -354,7 +359,7 @@ class TrainEngine:
                 spec = P()
             sharding = NamedSharding(self.mesh, P(None, *spec))
             out[k] = self._dist.put_global(v, sharding)
-        return out, pbs
+        return out
 
     def train_batch(
         self,
@@ -368,25 +373,38 @@ class TrainEngine:
 
         assert self.tx is not None, "engine built without an optimizer"
         tik = time.perf_counter()
-        mbs, *_ = sample.split(mb_spec)
-        batch, pbs = self._stack_batches(mbs, token_key)
-        n_mbs = next(iter(batch.values())).shape[0]  # bucketed count
-        # padding waste of this step's device layout: stacked [n, B, T]
-        # slots (INCLUDING all-zero bucketing micro-batches — they burn
-        # the same compute) vs real tokens
-        slots = n_mbs * pbs[0].padded_slots
-        real_tokens = sum(
-            int(l) for per_id in sample.seqlens[token_key] for l in per_id
-        )
-        self.last_padded_slots = slots
-        self.last_padding_frac = 1.0 - real_tokens / max(slots, 1)
-        self._m_pad_frac.set(self.last_padding_frac, model=self.name)
-        step = self._get_train_step(loss_fn, n_mbs)
-        self.params, self.opt_state, out = step(
-            self.params, self.opt_state, batch
-        )
-        self.version += 1
-        out = jax.device_get(out)  # ONE host sync per train step
+        with phase("areal.train.batch") as span:
+            with phase("areal.train.pack"):
+                mbs, *_ = sample.split(mb_spec)
+                stacked, rows, pbs = self._stack_batches(mbs, token_key)
+            with phase("areal.train.upload"):
+                batch = self._upload_stacked(stacked, rows)
+            n_mbs = next(iter(batch.values())).shape[0]  # bucketed count
+            # padding waste of this step's device layout: stacked
+            # [n, B, T] slots (INCLUDING all-zero bucketing micro-batches
+            # — they burn the same compute) vs real tokens
+            slots = n_mbs * pbs[0].padded_slots
+            real_tokens = sum(
+                int(l)
+                for per_id in sample.seqlens[token_key]
+                for l in per_id
+            )
+            self.last_padded_slots = slots
+            self.last_padding_frac = 1.0 - real_tokens / max(slots, 1)
+            self.padded_slots_total += slots
+            self.real_tokens_total += real_tokens
+            self._m_pad_frac.set(self.last_padding_frac, model=self.name)
+            span.set_metadata(
+                real_tokens=real_tokens, padded_slots=slots, n_mbs=n_mbs
+            )
+            step = self._get_train_step(loss_fn, n_mbs)
+            with phase("areal.train.dispatch"):
+                self.params, self.opt_state, out = step(
+                    self.params, self.opt_state, batch
+                )
+            self.version += 1
+            with phase("areal.train.sync"):
+                out = jax.device_get(out)  # ONE host sync per train step
         elapsed = time.perf_counter() - tik
         denom_f = float(out["denom"])
         self._record_step_metrics(sample, token_key, elapsed, denom_f)
@@ -414,6 +432,11 @@ class TrainEngine:
     last_mfu: float = 0.0
     last_padding_frac: float = 0.0
     last_padded_slots: int = 0
+    #: cumulative over every train_batch call (every minibatch of a step,
+    #: not its last): padding share = 1 - real_tokens_total /
+    #: padded_slots_total
+    padded_slots_total: int = 0
+    real_tokens_total: int = 0
 
     def _record_step_metrics(
         self,

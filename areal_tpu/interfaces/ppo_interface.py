@@ -30,6 +30,7 @@ from areal_tpu.base import logging_, stats_tracker
 from areal_tpu.engine import batching
 from areal_tpu.interfaces import ppo_functional
 from areal_tpu.models.transformer import head_weight, hidden_states
+from areal_tpu.observability.tracing import phase
 from areal_tpu.ops.gae import gae_advantages_returns
 from areal_tpu.ops.loss import per_token_logprobs_entropy
 
@@ -262,15 +263,18 @@ class PPOActorInterface(model_api.ModelInterface):
         mb_spec: MicroBatchSpec,
     ) -> Dict:
         engine = model.engine
-        prep_stats = self._prepare_batch(data)
-
-        mbs, *_ = data.split(MicroBatchSpec(n_mbs=self.n_minibatches))
-        all_stats = _aggregate_minibatch_stats(
-            engine.train_batch(
-                mb, self._loss_fn, mb_spec, token_key=self.token_key
+        with phase(
+            "areal.train.step", step=model.version.global_step,
+            n_minibatches=self.n_minibatches,
+        ):
+            prep_stats = self._prepare_batch(data)
+            mbs, *_ = data.split(MicroBatchSpec(n_mbs=self.n_minibatches))
+            all_stats = _aggregate_minibatch_stats(
+                engine.train_batch(
+                    mb, self._loss_fn, mb_spec, token_key=self.token_key
+                )
+                for mb in mbs
             )
-            for mb in mbs
-        )
         all_stats["actor_clip_frac"] = all_stats.pop("clip_frac", 0.0)
         self.kl_controller.update(
             prep_stats["kl"], int(prep_stats["n_response_tokens"])
@@ -460,15 +464,19 @@ class PPOCriticInterface(model_api.ModelInterface):
         mb_spec: MicroBatchSpec,
     ) -> Dict:
         engine = model.engine
-        if "returns" not in data.keys:
-            self._prep._prepare_batch(data)
-        mbs, *_ = data.split(MicroBatchSpec(n_mbs=self.n_minibatches))
-        all_stats = _aggregate_minibatch_stats(
-            engine.train_batch(
-                mb, self._loss_fn, mb_spec, token_key=self.token_key
+        with phase(
+            "areal.train.step", step=model.version.global_step,
+            n_minibatches=self.n_minibatches,
+        ):
+            if "returns" not in data.keys:
+                self._prep._prepare_batch(data)
+            mbs, *_ = data.split(MicroBatchSpec(n_mbs=self.n_minibatches))
+            all_stats = _aggregate_minibatch_stats(
+                engine.train_batch(
+                    mb, self._loss_fn, mb_spec, token_key=self.token_key
+                )
+                for mb in mbs
             )
-            for mb in mbs
-        )
         all_stats["value_clip_frac"] = all_stats.pop("clip_frac", 0.0)
         model.version.advance(
             model.ft_spec.steps_per_epoch if model.ft_spec else int(1e9)

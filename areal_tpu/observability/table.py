@@ -63,7 +63,29 @@ METRIC_TABLE = [
     MetricSpec(
         "areal_inference_generated_tokens_total",
         "counter",
-        "New tokens emitted by the engine",
+        "New tokens handed to rows, first tokens included; moves as "
+        "tokens are emitted (the engine's tokens_emitted_total), not "
+        "when a row finishes",
+    ),
+    MetricSpec(
+        "areal_inference_phase_seconds_total",
+        "counter",
+        "Engine-thread self seconds by phase span (the areal.engine.* "
+        "names of the trace table): they add up to the wall time of the "
+        "engine's steps",
+        ("phase",),
+    ),
+    MetricSpec(
+        "areal_inference_kv_pages_live",
+        "gauge",
+        "Pool blocks referenced by rows that are decoding or filling, "
+        "each block once however many siblings share it; parked rows "
+        "and prefix-cache holdings are not live",
+    ),
+    MetricSpec(
+        "areal_inference_kv_pages_total",
+        "gauge",
+        "Blocks of the paged KV pool (0 on a dense-cache engine)",
     ),
     MetricSpec(
         "areal_inference_prefill_tokens_total",
@@ -780,12 +802,15 @@ METRIC_TABLE = [
 
 @dataclasses.dataclass(frozen=True)
 class TraceSpec:
-    """One canonical flight-recorder span/event name.  ``kind`` is
-    "span" (recorded via span_begin/span_end/span — a duration) or
-    "event" (instant)."""
+    """One canonical trace name.  ``kind`` is "span" (the flight
+    recorder's span_begin/span_end/span — a duration of one SAMPLE),
+    "event" (instant) or "phase" (``tracing.phase`` — what a THREAD is
+    doing, a ``jax.profiler.TraceAnnotation`` in the profiler's own
+    trace; the name starts with ``areal.`` and the help text names the
+    counts the span carries)."""
 
     name: str
-    kind: str  # "span" | "event"
+    kind: str  # "span" | "event" | "phase"
     help: str
 
 
@@ -1002,7 +1027,178 @@ TRACE_TABLE = [
         "n new cache entries, the caller-provided shape/dtype "
         "signature, secs when jax.monitoring reported a duration)",
     ),
+    # -- phase spans: generation server thread --------------------------------
+    TraceSpec(
+        "areal.gserver.poll",
+        "phase",
+        "One poll of the leading generation server: commands in, one "
+        "engine step, replies and metrics out",
+    ),
+    TraceSpec(
+        "areal.gserver.serve_api",
+        "phase",
+        "Client requests drained into this poll's command batch, with "
+        "the stale-stream and prefix-pull pumps (counts: commands)",
+    ),
+    TraceSpec(
+        "areal.gserver.apply_commands",
+        "phase",
+        "The command batch applied to the engine (counts: commands)",
+    ),
+    TraceSpec(
+        "areal.gserver.reply",
+        "phase",
+        "Finished and staged results sent back to their clients, "
+        "handoff streams pumped (counts: replies)",
+    ),
+    TraceSpec(
+        "areal.gserver.export_metrics",
+        "phase",
+        "The engine's counters copied into the metrics registry, the "
+        "HBM ledger published, the jitted caches diffed",
+    ),
+    # -- phase spans: the engine's step, on the same thread -------------------
+    TraceSpec(
+        "areal.engine.step",
+        "phase",
+        "One engine step, the paused branch's sleep excluded (counts at "
+        "its end: step, rows_decoding, rows_filling, pending, ring, "
+        "tokens_emitted_total)",
+    ),
+    TraceSpec(
+        "areal.engine.swap",
+        "phase",
+        "A pending weight version applied: ring drain, flip or reload, "
+        "prefix flush, in-flight rows recomputed (counts: version, "
+        "rows_recomputed)",
+    ),
+    TraceSpec(
+        "areal.engine.admit",
+        "phase",
+        "Admission: parked rows expired, preempted rows re-admitted, "
+        "pending requests given a row and a fill (counts: "
+        "rows_admitted, prefix_hits)",
+    ),
+    TraceSpec(
+        "areal.engine.fill.dispatch",
+        "phase",
+        "One batched prefill chunk built on the host and dispatched "
+        "(counts: prompts, f_pad, c, tokens)",
+    ),
+    TraceSpec(
+        "areal.engine.fill.first_token_wait",
+        "phase",
+        "The blocking fetch of a completed prefill's sampled first "
+        "tokens: it waits for the fill program behind every decode "
+        "chunk already queued on the device (counts: rows)",
+    ),
+    TraceSpec(
+        "areal.engine.fill.activate",
+        "phase",
+        "A completed fill handed to its rows, in two spans beside the "
+        "first-token fetch: blocks shared, tail pages copied and "
+        "sampling dispatched before it, rows activated after it",
+    ),
+    TraceSpec(
+        "areal.engine.ensure_blocks",
+        "phase",
+        "Every decoding row's table extended to cover the next chunk "
+        "(counts: blocks_allocated, rows_preempted, and pages_live and "
+        "pages_total after it)",
+    ),
+    TraceSpec(
+        "areal.engine.decode.dispatch",
+        "phase",
+        "One decode chunk (or verify window) dispatched (counts: rows, "
+        "ctx_tokens_sum = prompt + generated known to the host over the "
+        "dispatched rows, chunk_size, pages_attended)",
+    ),
+    TraceSpec(
+        "areal.engine.harvest.wait",
+        "phase",
+        "Blocked until the oldest dispatched chunk's outputs are "
+        "computed (timing_split's device_s)",
+    ),
+    TraceSpec(
+        "areal.engine.harvest.fetch",
+        "phase",
+        "The oldest chunk's outputs copied to the host (timing_split's "
+        "fetch_s)",
+    ),
+    TraceSpec(
+        "areal.engine.harvest.fold",
+        "phase",
+        "The fetched chunk folded into the host rows, finished rows "
+        "parked or released (counts: tokens)",
+    ),
+    # -- phase spans: what the profiler would drop ----------------------------
+    TraceSpec(
+        "areal.phase.begin",
+        "phase",
+        "No length: a phase of a PhaseClock (the areal.engine.* spans) "
+        "begins on this thread.  The profiler drops a span still open "
+        "when its session stops; this one survives (counts: of = the "
+        "phase's name)",
+    ),
+    TraceSpec(
+        "areal.phase.end",
+        "phase",
+        "No length: a phase of a PhaseClock has ended on this thread; "
+        "survives where the phase began before the session (counts: of "
+        "= the phase's name, seconds = how long it lasted)",
+    ),
+    # -- phase spans: gserver manager thread ----------------------------------
+    TraceSpec(
+        "areal.manager.schedule",
+        "phase",
+        "One routing decision (the schedule RPC's time inside the "
+        "manager, against what its client waits)",
+    ),
+    # -- phase spans: trainer thread ------------------------------------------
+    TraceSpec(
+        "areal.train.step",
+        "phase",
+        "One PPO train_step of an interface: batch prepared, "
+        "minibatches trained, statistics gathered (counts: step, "
+        "n_minibatches)",
+    ),
+    TraceSpec(
+        "areal.train.batch",
+        "phase",
+        "One TrainEngine.train_batch call (counts: real_tokens, "
+        "padded_slots, n_mbs)",
+    ),
+    TraceSpec(
+        "areal.train.pack",
+        "phase",
+        "The sample split into micro-batches and laid out as stacked "
+        "numpy arrays",
+    ),
+    TraceSpec(
+        "areal.train.upload",
+        "phase",
+        "The stacked arrays placed on the devices",
+    ),
+    TraceSpec(
+        "areal.train.dispatch",
+        "phase",
+        "The jitted train step called (asynchronous: returns when the "
+        "program is enqueued)",
+    ),
+    TraceSpec(
+        "areal.train.sync",
+        "phase",
+        "The step's one device_get: blocks until the program has run",
+    ),
 ]
+
+#: names of the engine thread's phases: ``engine.phase_seconds()`` and
+#: ``areal_inference_phase_seconds_total{phase=}`` carry exactly these
+ENGINE_PHASES = tuple(
+    s.name
+    for s in TRACE_TABLE
+    if s.kind == "phase" and s.name.startswith("areal.engine.")
+)
 
 
 @dataclasses.dataclass(frozen=True)

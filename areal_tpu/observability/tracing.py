@@ -32,6 +32,15 @@ Span/event names are a canonical, linted vocabulary: every literal passed
 to ``event``/``span_begin``/``span_end``/``span`` must appear exactly
 once in ``observability/table.py`` ``TRACE_TABLE``
 (``scripts/check_metric_names.py``, run in tier-1).
+
+**Phase spans** (:func:`phase`, :class:`PhaseClock`) are the other half:
+what a THREAD is doing (the engine's step, the trainer's batch), not
+where a sample is.  They are ``jax.profiler.TraceAnnotation``s, so they
+land in the ``/host:CPU`` plane of the profiler's own trace, on the clock
+of the device operations, whenever a profiler session is live (``GET
+/profile``, a benchmark's traced slice) and cost a flag check when none
+is.  Their names carry the prefix ``areal.`` and are declared in the same
+table (kind ``"phase"``).
 """
 
 from __future__ import annotations
@@ -299,6 +308,96 @@ class Tracer:
             self._open_roots.clear()
             self._decisions.clear()
             self._forced.clear()
+
+
+# -- phase spans: what a thread is doing, on the profiler's clock -------------
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str, counts):
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(name, **counts)
+
+
+def _recording() -> bool:
+    """Whether a profiler session is live (the flag an annotation checks)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation.is_enabled()
+
+
+def phase(name: str, **counts):
+    """``with phase("areal.engine.step", step=3):`` — one span of the
+    calling thread in the profiler's trace, with its counts as the
+    event's stats.  Recorded while any profiler session is live, a flag
+    check otherwise.  ``__enter__`` returns the annotation:
+    ``set_metadata(**counts)`` adds counts known only at the end."""
+    return _annotation(name, counts)
+
+
+class _TimedPhase:
+    """One phase of a :class:`PhaseClock`.  The profiler drops a span that
+    is open when its session starts or stops, and under load a thread
+    spends a second or more in one blocked phase, so a capture of a few
+    seconds begins and ends inside one.  Each phase therefore also says
+    when it begins and when it has ended, in two spans of no length that
+    survive: a reader that finds one without the other knows which phase
+    the session's edge cut, from the trace alone."""
+
+    __slots__ = ("_clock", "_name", "_span", "_t0", "_children_s")
+
+    def __init__(self, clock: "PhaseClock", name: str, counts):
+        self._clock = clock
+        self._name = name
+        # an annotation's time starts when it is made: the mark first
+        if _recording():
+            with phase("areal.phase.begin", of=name):
+                pass
+        self._span = _annotation(name, counts)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._children_s = 0.0
+        self._clock._open.append(self)
+        self._t0 = time.perf_counter()
+        return self._span
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        clock = self._clock
+        clock._open.pop()
+        clock.seconds[self._name] += dt - self._children_s
+        if clock._open:
+            clock._open[-1]._children_s += dt
+        self._span.__exit__(*exc)
+        if _recording():
+            with phase("areal.phase.end", of=self._name, seconds=dt):
+                pass
+        return False
+
+
+class PhaseClock:
+    """Phase spans of ONE thread that also keep, for runs nobody traces,
+    the cumulative SELF seconds of each phase (a span's time less its
+    child spans'), so the totals of nested phases add up to the wall time
+    of the outermost.  ``names`` are all declared up front: ``seconds``
+    never changes size, and another thread may copy it at any time."""
+
+    def __init__(self, names):
+        self.seconds: Dict[str, float] = {n: 0.0 for n in names}
+        self._open: List[_TimedPhase] = []
+
+    def phase(self, name: str, **counts) -> _TimedPhase:
+        return _TimedPhase(self, name, counts)
+
+    def reset(self):
+        """Zero the totals (a benchmark arm that times one stretch)."""
+        for name in self.seconds:
+            self.seconds[name] = 0.0
 
 
 _default_lock = threading.Lock()

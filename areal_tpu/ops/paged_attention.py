@@ -402,6 +402,9 @@ def paged_flash_attention(
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
+        # the trace names the Mosaic call after this: one query row a
+        # sequence is a decode step, a query tile a prefill chunk
+        name="paged_attn_decode" if Q == 1 else "paged_attn_fill",
     )(
         lengths.astype(jnp.int32),
         tables.astype(jnp.int32),
@@ -637,6 +640,7 @@ def paged_flash_attention_deep(
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
+        name="paged_attn_deep",
     )(
         lengths.astype(jnp.int32),
         tables.astype(jnp.int32),

@@ -23,6 +23,7 @@ import zmq
 
 from areal_tpu.api import dataset_api, system_api
 from areal_tpu.base import constants, logging_, name_resolve, names, network
+from areal_tpu.observability.tracing import phase
 from areal_tpu.system import worker_base
 
 logger = logging_.getLogger("generation_server")
@@ -627,6 +628,8 @@ class GenerationServerWorker(worker_base.Worker):
                 "areal_inference_weight_swaps_staged_total"
             ),
             "inflight": reg.gauge("areal_inference_inflight_rows"),
+            "pages_live": reg.gauge("areal_inference_kv_pages_live"),
+            "pages_total": reg.gauge("areal_inference_kv_pages_total"),
             "pending": reg.gauge("areal_inference_pending_requests"),
             "version": reg.gauge("areal_inference_weight_version"),
             "ring_depth": reg.gauge("areal_inference_ring_depth"),
@@ -650,6 +653,10 @@ class GenerationServerWorker(worker_base.Worker):
             ),
             "mesh_devices": reg.gauge("areal_inference_mesh_devices"),
         }
+        # the engine thread's self seconds by phase span, mirrored as
+        # per-phase counter deltas
+        self._obs_phase = reg.counter("areal_inference_phase_seconds_total")
+        self._obs_phase_last: Dict[str, float] = {}
         # handoff import rejects carry a reason label (version skew vs
         # layout vs capacity); mirrored as per-reason counter deltas
         self._obs_handoff_rejects = reg.counter(
@@ -703,12 +710,13 @@ class GenerationServerWorker(worker_base.Worker):
         wstats = eng.weight_quant_stats()
         hstats = eng.handoff_stats()
         fstats = eng.prefix_peer_stats()
+        split = eng.timing_split()
         totals = {
             "chunks": float(eng.chunks_total),
-            "host": eng.time_host_s,
-            "device": eng.time_device_s,
-            "fetch": eng.time_fetch_s,
-            "gen_tokens": float(eng.gen_tokens_total),
+            "host": split["host_s"],
+            "device": split["device_s"],
+            "fetch": split["fetch_s"],
+            "gen_tokens": float(eng.tokens_emitted_total),
             "prefill_tokens": float(eng.prefill_tokens_total),
             "async_fetches": float(eng.async_fetches_total),
             "fetch_ready": float(eng.fetch_ready_total),
@@ -761,6 +769,11 @@ class GenerationServerWorker(worker_base.Worker):
             if delta > 0:
                 self._obs[key].inc(delta)
                 self._obs_last[key] = total
+        for name, total in eng.phase_seconds().items():
+            delta = total - self._obs_phase_last.get(name, 0.0)
+            if delta > 0:
+                self._obs_phase.inc(delta, phase=name)
+                self._obs_phase_last[name] = total
         for reason, total in hstats["import_rejects"].items():
             delta = total - self._obs_handoff_rejects_last.get(reason, 0)
             if delta > 0:
@@ -789,6 +802,8 @@ class GenerationServerWorker(worker_base.Worker):
             if rec.tpot_s is not None:
                 self._obs_slo["tpot_s"].observe(rec.tpot_s, workload=w)
         self._obs["inflight"].set(eng.n_inflight)
+        self._obs["pages_live"].set(eng.pages_live)
+        self._obs["pages_total"].set(eng.pages_total)
         self._obs["pending"].set(eng.n_pending)
         self._obs["version"].set(eng.version)
         self._obs["ring_depth"].set(eng.pipeline_depth)
@@ -1614,38 +1629,8 @@ class GenerationServerWorker(worker_base.Worker):
 
     def _poll(self) -> worker_base.PollResult:
         if self._is_leader:
-            batch = self._serve_api()
-            # dead-gateway-client backstop: a stream nobody drained for
-            # stream_stale_steps engine steps auto-cancels — the cancel
-            # rides THIS batch so followers release the row in lockstep
-            for qid in self.engine.stale_stream_qids():
-                self.logger.warning(
-                    "auto-cancelling stale gateway stream %s", qid
-                )
-                self._open_streams.discard(qid)
-                batch.append(("stream_cancel", {"qid": qid}))
-            # fleet KV fabric: start owner RPCs for new pull intents and
-            # append finished pulls' segments (or failure markers) to
-            # THIS batch — they ride the publish below, so follower
-            # controllers replay the identical import stream
-            self._pump_prefix_pulls()
-            batch.extend(self._drain_pull_commands())
-            if self._ctrl_pub is not None:
-                # publish BEFORE applying: followers must dispatch their
-                # part of this step's device programs (TP collectives span
-                # all controllers) while the leader runs its own
-                self._ctrl_seq += 1
-                self._ctrl_pub.send(pickle.dumps((self._ctrl_seq, batch)))
-            self._apply_commands(batch)
-            n = self.engine.step()
-            # streamed handoff: new export segments must enter their
-            # queues (and gate their replies) BEFORE _reply_finished
-            # looks at this step's results
-            self._pump_handoff_streams()
-            self._reply_finished()
-            self._reply_staged()
-            self._export_engine_metrics()
-            return worker_base.PollResult(sample_count=n)
+            with phase("areal.gserver.poll"):
+                return self._poll_leader()
         # follower: lockstep replay of the leader's command stream — one
         # engine.step() per published message, so chunk dispatches pair up
         if not self._ctrl_sub.poll(timeout=100):
@@ -1664,9 +1649,60 @@ class GenerationServerWorker(worker_base.Worker):
         self._export_engine_metrics()
         return worker_base.PollResult(sample_count=n)
 
+    def _poll_leader(self) -> worker_base.PollResult:
+        with phase("areal.gserver.serve_api") as span:
+            batch = self._serve_api()
+            # dead-gateway-client backstop: a stream nobody drained for
+            # stream_stale_steps engine steps auto-cancels — the cancel
+            # rides THIS batch so followers release the row in lockstep
+            for qid in self.engine.stale_stream_qids():
+                self.logger.warning(
+                    "auto-cancelling stale gateway stream %s", qid
+                )
+                self._open_streams.discard(qid)
+                batch.append(("stream_cancel", {"qid": qid}))
+            # fleet KV fabric: start owner RPCs for new pull intents and
+            # append finished pulls' segments (or failure markers) to
+            # THIS batch — they ride the publish below, so follower
+            # controllers replay the identical import stream
+            self._pump_prefix_pulls()
+            batch.extend(self._drain_pull_commands())
+            span.set_metadata(commands=len(batch))
+        with phase("areal.gserver.apply_commands", commands=len(batch)):
+            if self._ctrl_pub is not None:
+                # publish BEFORE applying: followers must dispatch their
+                # part of this step's device programs (TP collectives span
+                # all controllers) while the leader runs its own
+                self._ctrl_seq += 1
+                self._ctrl_pub.send(pickle.dumps((self._ctrl_seq, batch)))
+            self._apply_commands(batch)
+        n = self.engine.step()
+        with phase("areal.gserver.reply") as span:
+            waiting = len(self._waiting)
+            # streamed handoff: new export segments must enter their
+            # queues (and gate their replies) BEFORE _reply_finished
+            # looks at this step's results
+            self._pump_handoff_streams()
+            self._reply_finished()
+            self._reply_staged()
+            span.set_metadata(replies=waiting - len(self._waiting))
+        with phase("areal.gserver.export_metrics"):
+            self._export_engine_metrics()
+        return worker_base.PollResult(sample_count=n)
+
     def _exit_hook(self):
         eng = getattr(self, "engine", None)
         if eng is not None:
+            # in every run's output, traced or not: a slow run can be laid
+            # beside a normal one of the same schedule, phase by phase
+            self.logger.info(
+                "engine phases after %d steps (self seconds): %s",
+                eng._step_seq,
+                ", ".join(
+                    f"{name}={sec:.3f}"
+                    for name, sec in eng.phase_seconds().items()
+                ),
+            )
             # releases the ledger attributions (and logs the leak audit:
             # a quiesced server returns the process ledger to baseline)
             eng.close()

@@ -38,6 +38,7 @@ import zmq
 from areal_tpu.api import system_api
 from areal_tpu.base import constants, logging_, name_resolve, names, network
 from areal_tpu.gateway.admission import DEFAULT_BULK_TENANT, AdmissionPlane
+from areal_tpu.observability.tracing import phase
 from areal_tpu.system import worker_base
 from areal_tpu.system.generation_server import GenServerClient
 
@@ -921,6 +922,12 @@ class GserverManager(worker_base.Worker):
         straight to the decode server.  A saturated prefill pool SHEDS
         the request instead: it serves unified-style on its decode
         owner (``pd_shed`` marks the response)."""
+        with phase("areal.manager.schedule"):
+            return self._route_request(qid, prompt_len, new_token_budget)
+
+    def _route_request(
+        self, qid: str, prompt_len: int, new_token_budget: int
+    ) -> Dict:
         sticky = qid in self._qid_server  # before _schedule registers it
         # snapshot the session's hot-prefix records BEFORE scheduling:
         # _schedule_inner optimistically records this turn's whole

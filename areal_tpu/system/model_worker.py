@@ -571,31 +571,17 @@ class ModelWorker(worker_base.Worker):
             return {"stats": res, "elapsed": 0.0}
         data = self._data_manager.get_batch(ids, input_keys)
 
-        # optional per-MFC profiling (reference: the torch.profiler wrap in
-        # realhf/system/model_worker.py:829 __maybe_profile_rpc); set
-        # AREAL_PROFILE_DIR to collect an xplane trace per MFC kind
-        profile_dir = os.environ.get("AREAL_PROFILE_DIR")
-        prof_ctx = None
-        if profile_dir:
-            prof_ctx = jax.profiler.trace(
-                os.path.join(profile_dir, rpc_name)
-            )
-            prof_ctx.__enter__()
         tik = time.monotonic()
         res: Any = None
-        try:
-            if handle == "train_step":
-                res = interface.train_step(model, data, mb_spec)
-                self._trace_train_consumption(model_name, model, ids)
-            elif handle == "inference":
-                res = interface.inference(model, data, mb_spec)
-            elif handle == "generate":
-                res = interface.generate(model, data, mb_spec)
-            else:
-                raise ValueError(f"unknown MFC handle {handle}")
-        finally:
-            if prof_ctx is not None:
-                prof_ctx.__exit__(None, None, None)
+        if handle == "train_step":
+            res = interface.train_step(model, data, mb_spec)
+            self._trace_train_consumption(model_name, model, ids)
+        elif handle == "inference":
+            res = interface.inference(model, data, mb_spec)
+        elif handle == "generate":
+            res = interface.generate(model, data, mb_spec)
+        else:
+            raise ValueError(f"unknown MFC handle {handle}")
         elapsed = time.monotonic() - tik
 
         reply: Dict = {"elapsed": elapsed}

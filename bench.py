@@ -296,7 +296,7 @@ def bench_generation(
     t_prefill = time.perf_counter() - t0
     # zero the timing counters so the split covers ONLY the timed decode
     # phase (warmup compiles + admission would otherwise dominate host_s)
-    eng.time_host_s = eng.time_device_s = eng.time_fetch_s = 0.0
+    eng._phases.reset()
     eng.chunks_total = 0
     t0 = time.perf_counter()
     n_decoded = drain(eng)
@@ -337,7 +337,7 @@ def bench_generation(
         submit_wave(eng, cfg, n_reqs, prompt_len, ab_new, f"rk{K}")
         eng._admit()
         int(np.asarray(eng.cache.lengths)[0])
-        eng.time_host_s = eng.time_device_s = eng.time_fetch_s = 0.0
+        eng._phases.reset()
         eng.chunks_total = 0
         t0 = time.perf_counter()
         n = drain(eng)

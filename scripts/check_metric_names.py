@@ -8,8 +8,10 @@ table.py).
 ``.counter("...")`` / ``.gauge("...")`` / ``.histogram("...")`` call, or
 as the SECOND argument (the first is the trace id) of a
 ``.event(tid, "...")`` / ``.span_begin(...)`` / ``.span_end(...)`` /
-``.span(...)`` call, anywhere under ``areal_tpu/`` or in ``bench.py`` /
-``__graft_entry__.py`` — found by AST walk (so formatting/aliasing of
+``.span(...)`` call, or as the FIRST argument of a phase span
+(``phase("areal....")``, ``clock.phase("areal....")``), anywhere under
+``areal_tpu/`` or in ``bench.py`` / ``__graft_entry__.py`` — found by
+AST walk (so formatting/aliasing of
 the registry/tracer object doesn't matter, and dynamically computed
 names are rejected by construction: names must be literals or the
 scrape/trace vocabulary becomes unauditable).
@@ -17,8 +19,9 @@ scrape/trace vocabulary becomes unauditable).
 The human-facing tables in ``docs/observability.md`` are diffed against
 the canonical tables too (both directions): docs cannot silently drift
 when a metric or span is added, renamed, or retired.  Metric names are
-``areal_*`` identifiers; trace names are dotted ``layer.name`` pairs —
-disjoint vocabularies, one doc page.
+``areal_*`` identifiers; trace names are dotted ``layer.name`` pairs,
+phase spans dotted names under the prefix ``areal.`` — disjoint
+vocabularies, one doc page.
 
 Exit code 0 = clean; 1 = violations (each printed, one per line).  Run in
 tier-1 via tests/observability/test_metric_names_lint.py.
@@ -38,6 +41,11 @@ _REGISTRY_METHODS = ("counter", "gauge", "histogram")
 #: tracer recording methods: first arg is the trace id, SECOND is the
 #: canonical span/event name
 _TRACER_METHODS = ("event", "span_begin", "span_end", "span")
+#: phase spans (observability/tracing.phase, PhaseClock.phase): the name
+#: is the FIRST argument, and the call may be a bare ``phase(...)``
+_PHASE_FUNCTIONS = ("phase",)
+#: what keeps a phase span's name apart from the flight recorder's
+PHASE_PREFIX = "areal."
 
 #: files whose registry-shaped calls are not metric emissions; currently
 #: none — even registry.py's own set_stats emission (areal_stats) is real
@@ -58,32 +66,44 @@ def _iter_source_files() -> List[str]:
     return sorted(out)
 
 
-def _collect(methods: Tuple[str, ...], arg_idx: int) -> Dict[str, List[Tuple[str, int]]]:
+def _collect(
+    methods: Tuple[str, ...],
+    arg_idx: int,
+    bare: bool = False,
+    sources: Dict[str, str] | None = None,
+) -> Dict[str, List[Tuple[str, int]]]:
     """{name: [(rel_path, lineno), ...]} of string literals at position
-    ``arg_idx`` of ``.method(...)`` calls, plus non-literal call sites
-    under the sentinel key ``<non-literal>``."""
+    ``arg_idx`` of ``.method(...)`` calls (and, with ``bare``, of plain
+    ``method(...)`` calls), plus non-literal call sites under the
+    sentinel key ``<non-literal>``.  ``sources`` ({rel_path: text})
+    stands in for the repository's files (the lint's own test)."""
     emitted: Dict[str, List[Tuple[str, int]]] = {}
-    for path in _iter_source_files():
-        rel = os.path.relpath(path, REPO_ROOT)
+    if sources is None:
+        sources = {}
+        for path in _iter_source_files():
+            with open(path) as f:
+                sources[os.path.relpath(path, REPO_ROOT)] = f.read()
+    for rel, text in sorted(sources.items()):
         if rel in _SKIP_FILES:
             continue
-        with open(path) as f:
-            try:
-                tree = ast.parse(f.read(), filename=rel)
-            except SyntaxError as e:
-                emitted.setdefault("<syntax-error>", []).append(
-                    (rel, e.lineno or 0)
-                )
-                continue
+        try:
+            tree = ast.parse(text, filename=rel)
+        except SyntaxError as e:
+            emitted.setdefault("<syntax-error>", []).append(
+                (rel, e.lineno or 0)
+            )
+            continue
         for node in ast.walk(tree):
             if not isinstance(node, ast.Call):
                 continue
             fn = node.func
-            if (
-                not isinstance(fn, ast.Attribute)
-                or fn.attr not in methods
-                or len(node.args) <= arg_idx
-            ):
+            if isinstance(fn, ast.Attribute):
+                called = fn.attr
+            elif bare and isinstance(fn, ast.Name):
+                called = fn.id
+            else:
+                continue
+            if called not in methods or len(node.args) <= arg_idx:
                 continue
             arg = node.args[arg_idx]
             if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
@@ -105,6 +125,53 @@ def collect_trace_names() -> Dict[str, List[Tuple[str, int]]]:
     return _collect(_TRACER_METHODS, 1)
 
 
+def collect_phase_names(
+    sources: Dict[str, str] | None = None,
+) -> Dict[str, List[Tuple[str, int]]]:
+    """Phase span name literals: the first argument of ``phase(...)`` /
+    ``<clock>.phase(...)``."""
+    return _collect(_PHASE_FUNCTIONS, 0, bare=True, sources=sources)
+
+
+def phase_vocabulary_problems(
+    phases: Dict[str, List[Tuple[str, int]]], table
+) -> List[str]:
+    """Phase spans against TRACE_TABLE's ``"phase"`` entries, both ways:
+    every literal at a ``phase(...)`` site is declared with that kind and
+    carries the prefix, and every declared phase is recorded somewhere.
+    A pure function of its inputs, so the tier-1 test can feed it a
+    fabricated site."""
+    problems: List[str] = []
+    declared = {spec.name for spec in table if spec.kind == "phase"}
+    for name in sorted(declared):
+        if not name.startswith(PHASE_PREFIX):
+            problems.append(
+                f"phase span {name} in TRACE_TABLE lacks the prefix "
+                f"{PHASE_PREFIX!r} that keeps it apart from the flight "
+                "recorder's names"
+            )
+    for name, sites in sorted(phases.items()):
+        where = ", ".join(f"{p}:{ln}" for p, ln in sites)
+        if name == "<non-literal>":
+            problems.append(
+                f"non-literal phase span name at {where} — phase names "
+                "must be string literals so the table lint can see them"
+            )
+        elif name != "<syntax-error>" and name not in declared:
+            problems.append(
+                f"phase span {name} ({where}) is missing from "
+                "areal_tpu/observability/table.py TRACE_TABLE (kind "
+                '"phase")'
+            )
+    for name in sorted(declared - set(phases)):
+        problems.append(
+            f"trace table entry {name} (phase) is never recorded "
+            "anywhere under areal_tpu/, bench.py, or __graft_entry__.py "
+            "(dead vocabulary — remove it or wire the span)"
+        )
+    return problems
+
+
 DOCS_TABLE = os.path.join(REPO_ROOT, "docs", "observability.md")
 
 #: a documented metric: a backticked `areal_*` name inside a markdown
@@ -114,9 +181,10 @@ DOCS_TABLE = os.path.join(REPO_ROOT, "docs", "observability.md")
 _DOC_NAME_RE = re.compile(r"`(areal_[a-z0-9_]+)`")
 
 #: a documented trace span/event: a backticked dotted `layer.name` inside
-#: a markdown table row (trace names always contain exactly one dot;
-#: metric names never do, so the vocabularies cannot collide)
-_DOC_TRACE_RE = re.compile(r"`([a-z_]+\.[a-z_]+)`")
+#: a markdown table row (flight-recorder names contain exactly one dot,
+#: phase spans two or three; metric names never do, so the vocabularies
+#: cannot collide)
+_DOC_TRACE_RE = re.compile(r"`([a-z_]+(?:\.[a-z_]+)+)`")
 
 
 def collect_documented_names(path: str = DOCS_TABLE) -> Set[str]:
@@ -406,6 +474,11 @@ def run_lint() -> List[str]:
                 f"trace table: {name} appears {n} times in TRACE_TABLE "
                 "(must be exactly once)"
             )
+    # phase spans: the same table, their own call shape
+    problems.extend(
+        phase_vocabulary_problems(collect_phase_names(), TRACE_TABLE)
+    )
+    recorder = {s.name for s in TRACE_TABLE if s.kind != "phase"}
     traced = collect_trace_names()
     for name, sites in sorted(traced.items()):
         where = ", ".join(f"{p}:{ln}" for p, ln in sites)
@@ -418,13 +491,13 @@ def run_lint() -> List[str]:
             continue
         if name == "<syntax-error>":
             continue  # already reported by the metric pass
-        if tcounts.get(name, 0) == 0:
+        if name not in recorder:
             problems.append(
                 f"recorded trace name {name} ({where}) is missing from "
                 "areal_tpu/observability/table.py TRACE_TABLE"
             )
     traced_names = set(traced) - {"<non-literal>", "<syntax-error>"}
-    for name in sorted(set(tcounts) - traced_names):
+    for name in sorted(recorder - traced_names):
         problems.append(
             f"trace table entry {name} is never recorded anywhere under "
             "areal_tpu/, bench.py, or __graft_entry__.py (dead "
