@@ -312,13 +312,10 @@ class GenServerConfig:
     # exceeds a chunk's device time.  1 =
     # unpipelined baseline.
     pipeline_depth: int = 2
-    # measured dispatch-table overrides for cache_mode="auto" (None =
-    # builtin defaults / bench-derived values from engine/dispatch.py):
-    # paged_min_cache_len switches dense->paged by kv_cache_len;
-    # deep_kernel_min_context switches the paged decode kernel to the
-    # deep DMA-ring variant once the batch's longest context crosses it
+    # measured dispatch-table override for cache_mode="auto" (None =
+    # builtin default / bench-derived value from engine/dispatch.py):
+    # paged_min_cache_len switches dense->paged by kv_cache_len
     paged_min_cache_len: Optional[int] = None
-    deep_kernel_min_context: Optional[int] = None
     # recompile sentinel (observability/compile_watch.py): engine steps
     # after which the serving loop is declared steady-state — any
     # decode/fill-path XLA compile from then on fires

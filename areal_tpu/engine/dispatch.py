@@ -1,30 +1,28 @@
 """Measured dispatch table for the serving engine's KV-cache paths.
 
-The engine has three ways to run decode attention — the bucketed dense
-cache (XLA einsum at roofline for short uniform rows), the paged
-block-pool kernel (ops/paged_attention.paged_flash_attention), and the
-deep-pipelined DMA-ring variant (``paged_flash_attention_deep``, which
-issues its own page copies so up to 8 are in flight).  Which one wins is
-a *hardware measurement*, not a constant: the crossover moved every time
-the kernels changed (G=1 0.70x dense -> G=4 + 1k pages 0.93x on v5e),
-yet ``cache_mode="auto"`` shipped for two rounds on a hardcoded >=2k
-cutoff.
+The engine has two ways to run decode attention — the bucketed dense
+cache (XLA einsum at roofline for short uniform rows) and the paged
+block-pool kernel (ops/paged_attention.paged_flash_attention).  Which one
+wins is a *hardware measurement*, not a constant: the crossover moved
+every time the kernels changed (G=1 0.70x dense -> G=4 + 1k pages 0.93x
+on v5e), yet ``cache_mode="auto"`` shipped for two rounds on a hardcoded
+>=2k cutoff.  (A third, deep DMA-ring paged kernel lost to the BlockSpec
+kernel at every shape measured and went in PR 25: PERF.md section 6.)
 
 This module makes the dispatch decision data-driven:
 
-* :class:`PagedDispatchTable` — the two thresholds ``auto`` mode consults
-  (dense->paged by ``kv_cache_len``, standard->deep paged kernel by the
-  batch's longest live context), plus a ``source`` tag so a scrape or a
-  bench blob can tell a measured table from the builtin fallback;
-* :func:`derive_dispatch_table` — turns bench.py's 3-column decode A/B
-  (dense / paged / paged-deep tok/s by context length) into thresholds;
-  bench.py emits the result in its summary so the recipe configs can
-  pin what the hardware actually measured;
-* :func:`resolve_dispatch_table` — config plumbing: explicit overrides
-  win, unset fields keep the defaults below.
+* :class:`PagedDispatchTable` — the threshold ``auto`` mode consults
+  (dense->paged by ``kv_cache_len``), plus a ``source`` tag so a scrape
+  or a bench blob can tell a measured table from the builtin fallback;
+* :func:`derive_dispatch_table` — turns bench.py's decode A/B (dense /
+  paged tok/s by context length) into the threshold; bench.py emits the
+  result in its summary so the recipe configs can pin what the hardware
+  actually measured;
+* :func:`resolve_dispatch_table` — config plumbing: an explicit override
+  wins, an unset field keeps the default below.
 
-The defaults reproduce the pre-table behavior (paged at >=2k, deep
-never) so an unconfigured engine changes nothing.
+The default reproduces the pre-table behavior (paged at >=2k) so an
+unconfigured engine changes nothing.
 """
 
 from __future__ import annotations
@@ -36,13 +34,6 @@ from typing import Mapping, Optional
 #: prefixes amortize no paging; measured crossover on v5e — see the
 #: bench.py decode A/B this default came from)
 DEFAULT_PAGED_MIN_CACHE_LEN = 2048
-
-#: context length at/above which the deep DMA-ring kernel replaces the
-#: standard paged kernel.  NEVER until a bench proves it faster: the
-#: BlockSpec pipeline's 1-deep lookahead caps the standard kernel at
-#: ~350 GB/s on v5e, but the deep variant's win has to be measured, not
-#: assumed (VERDICT r5 #3).
-DISPATCH_NEVER = 1 << 30
 
 #: speculative decoding's per-row spec-on/spec-off threshold: the
 #: acceptance-rate EMA below which a row's drafts are judged not worth
@@ -71,9 +62,6 @@ class PagedDispatchTable:
 
     #: dense cache below, paged block pool at/above (by ``kv_cache_len``)
     paged_min_cache_len: int = DEFAULT_PAGED_MIN_CACHE_LEN
-    #: standard paged kernel below, deep DMA-ring kernel at/above (by the
-    #: longest live context in the batch at dispatch time)
-    deep_min_context: int = DISPATCH_NEVER
     #: provenance: "builtin-default" | "config" | "bench(...)"
     source: str = "builtin-default"
 
@@ -86,32 +74,16 @@ class PagedDispatchTable:
 #: paged path's capacity/mixed-length advantages break the tie
 PARITY_MARGIN = 0.95
 
-#: the deep kernel must clear the standard kernel by this factor before
-#: the table flips to it (a recompile boundary is not worth noise)
-DEEP_MARGIN = 1.02
-
 
 def resolve_dispatch_table(
     paged_min_cache_len: Optional[int] = None,
-    deep_min_context: Optional[int] = None,
 ) -> PagedDispatchTable:
-    """Build the engine's table from config fields; ``None`` fields keep
-    the builtin defaults (so configs only pin what they measured)."""
-    if paged_min_cache_len is None and deep_min_context is None:
+    """Build the engine's table from the config field; ``None`` keeps
+    the builtin default (so configs only pin what they measured)."""
+    if paged_min_cache_len is None:
         return PagedDispatchTable()
-    base = PagedDispatchTable()
     return PagedDispatchTable(
-        paged_min_cache_len=(
-            base.paged_min_cache_len
-            if paged_min_cache_len is None
-            else int(paged_min_cache_len)
-        ),
-        deep_min_context=(
-            base.deep_min_context
-            if deep_min_context is None
-            else int(deep_min_context)
-        ),
-        source="config",
+        paged_min_cache_len=int(paged_min_cache_len), source="config"
     )
 
 
@@ -139,18 +111,16 @@ def spec_break_even_accept_rate(
 def derive_dispatch_table(
     rows: Mapping[int, Mapping[str, Optional[float]]],
 ) -> PagedDispatchTable:
-    """Derive thresholds from a measured 3-column decode A/B.
+    """Derive the threshold from a measured decode A/B.
 
-    ``rows`` maps context length -> ``{"dense": tok/s, "paged": tok/s,
-    "deep": tok/s}`` with ``None`` for cells that could not run (OOM).
-    A threshold is the smallest measured context from which the
-    contender wins at EVERY larger measured context too (one noisy
-    mid-table cell must not carve a dense island out of the paged
-    range).  A dense OOM counts as a paged win — capacity is the point.
-    If paged never wins, the paged threshold is pushed past the measured
-    range (2x the largest context: beyond what was measured, capacity
-    arguments take over); if deep never beats standard paged, deep stays
-    at ``DISPATCH_NEVER``.
+    ``rows`` maps context length -> ``{"dense": tok/s, "paged": tok/s}``
+    with ``None`` for cells that could not run (OOM).  The threshold is
+    the smallest measured context from which paged wins at EVERY larger
+    measured context too (one noisy mid-table cell must not carve a
+    dense island out of the paged range).  A dense OOM counts as a paged
+    win — capacity is the point.  If paged never wins, the threshold is
+    pushed past the measured range (2x the largest context: beyond what
+    was measured, capacity arguments take over).
     """
     ctxs = sorted(int(c) for c in rows)
     if not ctxs:
@@ -161,44 +131,22 @@ def derive_dispatch_table(
         return float(v) if isinstance(v, (int, float)) else None
 
     def paged_wins(ctx):
-        dense = cell(ctx, "dense")
-        best_paged = max(
-            (v for v in (cell(ctx, "paged"), cell(ctx, "deep"))
-             if v is not None),
-            default=None,
-        )
+        dense, paged = cell(ctx, "dense"), cell(ctx, "paged")
         if dense is None:
             return True  # dense OOM: paged is the only option
-        if best_paged is None:
+        if paged is None:
             return False
-        return best_paged >= PARITY_MARGIN * dense
+        return paged >= PARITY_MARGIN * dense
 
-    def deep_wins(ctx):
-        deep, std = cell(ctx, "deep"), cell(ctx, "paged")
-        if deep is None:
-            return False
-        if std is None:
-            return True  # standard kernel OOM'd, deep ran
-        return deep >= DEEP_MARGIN * std
-
-    def suffix_threshold(wins):
-        """Smallest ctx such that wins() holds for it and all larger."""
-        thr = None
-        for ctx in reversed(ctxs):
-            if wins(ctx):
-                thr = ctx
-            else:
-                break
-        return thr
-
-    paged_thr = suffix_threshold(paged_wins)
-    deep_thr = suffix_threshold(deep_wins)
+    # smallest ctx such that paged wins at it and at all larger
+    paged_thr = None
+    for ctx in reversed(ctxs):
+        if not paged_wins(ctx):
+            break
+        paged_thr = ctx
     return PagedDispatchTable(
         paged_min_cache_len=(
             paged_thr if paged_thr is not None else 2 * ctxs[-1]
-        ),
-        deep_min_context=(
-            deep_thr if deep_thr is not None else DISPATCH_NEVER
         ),
         source=f"bench({ctxs[0]}..{ctxs[-1]})",
     )

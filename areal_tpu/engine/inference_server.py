@@ -444,9 +444,7 @@ class ContinuousBatchingEngine:
         KV; "paged" uses a shared block pool + block tables (capacity in
         ``page_size``-token pages, chunked prefill, block-shared group
         prompts); "auto" consults ``dispatch_table`` (default: paged at
-        ``kv_cache_len >= 2048``) for global-attention models, and the
-        same table picks the deep DMA-ring paged kernel once the batch's
-        longest context crosses its measured threshold.
+        ``kv_cache_len >= 2048``) for global-attention models.
 
         ``pipeline_depth``: max decode chunks dispatched-but-unharvested
         (the in-flight ring).  K=1 is the unpipelined baseline (dispatch
@@ -3702,32 +3700,15 @@ class ContinuousBatchingEngine:
             row_id, row.req.qid, len(row.prompt) + len(row.generated),
         )
 
-    def _use_deep_kernel(self) -> bool:
-        """Dispatch-table decision: route this chunk through the deep
-        DMA-ring paged kernel when the batch's longest live context (plus
-        the un-harvested ring allowance) crosses the measured threshold.
-        Host-deterministic (SPMD-safe); at most two compiled variants
-        exist, so threshold crossings cost one compile each, once."""
-        if not self._use_paged_kernel:
-            return False
-        thr = self.dispatch_table.deep_min_context
-        longest = 0
-        for row in self.rows:
-            # filling rows are excluded just like the dispatch snapshot
-            # excludes them: a 16k prompt mid-prefill must not route the
-            # short decoding rows' chunk onto the deep kernel
-            if row is not None and not row.parked and not row.filling:
-                longest = max(
-                    longest, len(row.prompt) + len(row.generated) + 1
-                )
-        return longest + len(self._ring) * self.chunk_size >= thr
-
     def _count_dispatch(self, span, snapshot, chunk_size: int):
         """The counts of a decode dispatch's span: the context the host
         knows the dispatched rows to have (prompt + generated so far;
-        chunks still in the ring are not in it, so it is a floor) and the
-        pages that context spans.  Read only while a profiler session
-        records them."""
+        chunks still in the ring are not in it, so it is a floor), the
+        pages that context spans, and the page slots of the whole batch
+        (every slot's whole table: what a kernel that followed capacity
+        would stream; 1 - pages_attended / page_slots is the share the
+        paged kernel skips).  Read only while a profiler session records
+        them."""
         if not span.is_enabled():
             return
         ctx = [
@@ -3740,6 +3721,8 @@ class ContinuousBatchingEngine:
             ctx_tokens_sum=sum(ctx),
             chunk_size=chunk_size,
             pages_attended=sum(-(-c // page) for c in ctx),
+            page_slots=len(self.rows)
+            * (self.blocks_per_row if self.paged else 1),
         )
 
     def _dispatch_chunk_paged(self):
@@ -3775,7 +3758,6 @@ class ContinuousBatchingEngine:
             max_len=self.kv_cache_len,
             mesh=self.mesh,
             kv_axis=getattr(self, "_kv_axis", None),
-            deep_kernel=self._use_deep_kernel(),
             row_seeds=self.row_seeds,
             k_scale=self.k_scale,
             v_scale=self.v_scale,

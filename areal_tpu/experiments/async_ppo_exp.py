@@ -64,11 +64,10 @@ class AsyncPPOMathExperiment(PPOMathExperiment):
     gen_kv_pool_tokens: Optional[int] = None
     gen_prefill_chunk_tokens: int = 1024
     # decode-pipeline ring depth (chunks in flight; 1 = unpipelined) and
-    # measured dispatch-table overrides (None = engine/dispatch.py
-    # defaults; pin values a bench.py decode A/B derived for this chip)
+    # the measured dispatch-table override (None = engine/dispatch.py
+    # default; pin the value a bench.py decode A/B derived for this chip)
     gen_pipeline_depth: int = 2
     gen_paged_min_cache_len: Optional[int] = None
-    gen_deep_kernel_min_context: Optional[int] = None
     # device index hosting each gen server's engine (trainer/gen split)
     gen_device_start: Optional[int] = None
     success_rate_lb: float = 0.0
@@ -158,7 +157,6 @@ class AsyncPPOMathExperiment(PPOMathExperiment):
                 prefill_chunk_tokens=self.gen_prefill_chunk_tokens,
                 pipeline_depth=self.gen_pipeline_depth,
                 paged_min_cache_len=self.gen_paged_min_cache_len,
-                deep_kernel_min_context=self.gen_deep_kernel_min_context,
                 device_idx=(
                     self.gen_device_start + i * gen_tp
                     if self.gen_device_start is not None

@@ -65,8 +65,9 @@ from areal_tpu.models.transformer import (
     rope_tables,
 )
 from areal_tpu.ops.paged_attention import (
+    page_group,
     paged_flash_attention,
-    paged_flash_attention_deep,
+    plan_pages,
     reference_paged_partials,
 )
 
@@ -170,12 +171,35 @@ def kernel_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _prefix_plan(
+    n_queries, n_q_heads, k_pool, tables, lengths, use_kernel,
+    mesh=None, kv_axis=None, quantized=False,
+):
+    """The paged kernel's page plan for every :func:`_prefix_partials`
+    call over these ``tables`` and ``lengths``: made ONCE, before the
+    layer scan (and the decode chunk's step loop), because XLA leaves it
+    inside them otherwise.  None without the kernel."""
+    if not use_kernel:
+        return None
+    shards = (
+        mesh.shape[kv_axis] if mesh is not None and kv_axis is not None
+        else 1
+    )
+    Hkv, BS, hd = k_pool.shape[-3:]
+    group = page_group(
+        n_queries, n_q_heads // shards, (Hkv // shards, BS, hd),
+        k_pool.dtype, quantized, tables.shape[1],
+    )
+    return plan_pages(tables, lengths, BS, group)
+
+
 def _prefix_partials(
     q, k_pool, v_pool, tables, lengths, layer, use_kernel,
-    mesh=None, kv_axis=None, deep=False, k_scale=None, v_scale=None,
+    mesh=None, kv_axis=None, k_scale=None, v_scale=None, plan=None,
 ):
     """Paged-attention partials over each row's cached prefix.  ``q`` is
     [B, Q, Hq, hd]; returns (acc, m, l) with Q query tokens per row.
+    ``plan`` is :func:`_prefix_plan` of the same arguments.
 
     ``k_scale``/``v_scale`` mark an int8-quantized pool: both the kernel
     and the jnp reference dequantize (multiply by the per-(block, head,
@@ -186,16 +210,15 @@ def _prefix_partials(
     so it runs under an explicit ``shard_map``: the pool's kv-head axis
     and q's head axis split over ``kv_axis`` (or fully replicated when
     the head count doesn't divide), each shard streaming only its own
-    heads' pages (code-review r5 #2)."""
+    heads' pages (code-review r5 #2); tables, lengths and the plan are
+    replicated."""
     if use_kernel:
-        kernel_fn = (
-            paged_flash_attention_deep if deep else paged_flash_attention
-        )
         interp = kernel_interpret()
         if mesh is None:
-            return kernel_fn(
+            return paged_flash_attention(
                 q, k_pool, v_pool, tables, lengths, layer=layer,
                 interpret=interp, k_scale=k_scale, v_scale=v_scale,
+                plan=plan,
             )
         from jax.sharding import PartitionSpec as P
 
@@ -212,11 +235,11 @@ def _prefix_partials(
         )
         scales = () if k_scale is None else (k_scale, v_scale)
 
-        def kern(qq, kk, vv, tb, ln, ly, *sc):
+        def kern(qq, kk, vv, tb, ln, ly, pp, *sc):
             ks, vs = sc if sc else (None, None)
-            return kernel_fn(
+            return paged_flash_attention(
                 qq, kk, vv, tb, ln, layer=ly, interpret=interp,
-                k_scale=ks, v_scale=vs,
+                k_scale=ks, v_scale=vs, plan=pp,
             )
 
         fn = jax.shard_map(
@@ -229,6 +252,7 @@ def _prefix_partials(
                 P(None, None),
                 P(None),
                 P(None),
+                P(),  # the plan's arrays (or None): replicated
             )
             + (scale_spec,) * len(scales),
             out_specs=(
@@ -240,7 +264,7 @@ def _prefix_partials(
         )
         return fn(
             q, k_pool, v_pool, tables, lengths,
-            jnp.asarray(layer, jnp.int32).reshape(1), *scales,
+            jnp.asarray(layer, jnp.int32).reshape(1), plan, *scales,
         )
     kl = jax.lax.dynamic_index_in_dim(k_pool, layer, 0, keepdims=False)
     vl = jax.lax.dynamic_index_in_dim(v_pool, layer, 0, keepdims=False)
@@ -312,6 +336,10 @@ def paged_window_forward(
     off = positions % BS
     seg_ids = valid.astype(jnp.int32)
     scale = 1.0 / np.sqrt(hd)
+    plan = _prefix_plan(
+        C, cfg.n_q_heads, k_pool, tables, read_lens, use_kernel,
+        mesh=mesh, kv_axis=kv_axis, quantized=k_scale is not None,
+    )
 
     def body(carry, xs):
         x, k_pool, v_pool, k_scale, v_scale = carry
@@ -321,6 +349,7 @@ def paged_window_forward(
         acc_p, m_p, l_p = _prefix_partials(
             q, k_pool, v_pool, tables, read_lens, l, use_kernel,
             mesh=mesh, kv_axis=kv_axis, k_scale=k_scale, v_scale=v_scale,
+            plan=plan,
         )
         # in-chunk causal scores (C <= ~1k keeps [F,Hq,C,C] small)
         qg = q.reshape(F, C, Hkv, r, hd)
@@ -435,7 +464,7 @@ def paged_fill_chunk(
     jax.jit,
     static_argnames=(
         "cfg", "chunk_size", "use_kernel", "max_len", "sample_fn",
-        "stop_fn", "mesh", "kv_axis", "deep_kernel",
+        "stop_fn", "mesh", "kv_axis",
     ),
     donate_argnums=(1, 2),
     donate_argnames=("k_scale", "v_scale"),
@@ -458,7 +487,6 @@ def paged_decode_chunk(
     max_len: int,
     mesh=None,
     kv_axis=None,
-    deep_kernel: bool = False,
     row_seeds: Optional[jax.Array] = None,  # [B] per-request sampler keys
     k_scale: Optional[jax.Array] = None,  # [L, NB, Hkv, BS] (int8 pool)
     v_scale: Optional[jax.Array] = None,
@@ -493,6 +521,10 @@ def paged_decode_chunk(
     # dead rows stream nothing (parked/freed rows keep their lengths)
     read_lens = jnp.where(active, base_lens, 0)
     scale = 1.0 / np.sqrt(hd)
+    plan = _prefix_plan(
+        1, cfg.n_q_heads, k_pool, tables, read_lens, use_kernel,
+        mesh=mesh, kv_axis=kv_axis, quantized=k_scale is not None,
+    )
 
     win_dtype = (
         jnp.dtype(cfg.dtype) if k_scale is not None else k_pool.dtype
@@ -538,8 +570,8 @@ def paged_decode_chunk(
             s_win = jnp.where(mask_win, s_win, _NEG_INF)  # [B,Hkv,r,1,W]
             acc, m_main, l_main = _prefix_partials(
                 q, k_pool, v_pool, tables, read_lens, l, use_kernel,
-                mesh=mesh, kv_axis=kv_axis, deep=deep_kernel,
-                k_scale=k_scale, v_scale=v_scale,
+                mesh=mesh, kv_axis=kv_axis,
+                k_scale=k_scale, v_scale=v_scale, plan=plan,
             )
             acc = acc.reshape(B, Hkv, r, hd)
             m_main = m_main.reshape(B, Hkv, r)

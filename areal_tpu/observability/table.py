@@ -1111,7 +1111,8 @@ TRACE_TABLE = [
         "phase",
         "One decode chunk (or verify window) dispatched (counts: rows, "
         "ctx_tokens_sum = prompt + generated known to the host over the "
-        "dispatched rows, chunk_size, pages_attended)",
+        "dispatched rows, chunk_size, pages_attended, page_slots = "
+        "batch slots x pages a slot's table holds)",
     ),
     TraceSpec(
         "areal.engine.harvest.wait",

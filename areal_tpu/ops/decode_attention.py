@@ -9,10 +9,13 @@ over that row's cache prefix ``[0, length)``:
   TPU, so online-softmax state (m/l/acc) lives in VMEM scratch across blocks
   and the normalized output is emitted at the last block;
 * ``lengths`` rides scalar prefetch: the K/V index maps CLAMP the block
-  index to the last valid block of each row, so trailing blocks re-address
-  the same tile and the pipeline's revisiting logic skips their HBM->VMEM
-  copies — short rows stream only the KV they own, which is the entire
-  point: decode is HBM-bandwidth-bound on the KV stream;
+  index to the last valid block of each row.  The trailing blocks are
+  later grid steps of the SAME operand, so they re-address the tile of
+  the step before and the pipeline skips their HBM->VMEM copies: short
+  rows stream only the KV they own.  (That holds for one operand walked
+  along the grid's minor axis, as here; the paged kernel's G page
+  streams are operands of their own and need
+  ``paged_attention.stream_page_ids``);
 * GQA is grouped: the query head group ``r = Hq // Hkv`` shares one KV head
   per grid cell, so the cache is read once per KV head (never
   repeat-materialized).
@@ -46,6 +49,37 @@ def softmax_scratch_init(s_acc, s_m, s_l):
     s_l[:] = jnp.zeros_like(s_l)
 
 
+def _split_bf16(x):
+    """``x`` (float32) as three bf16 arrays that sum to it, largest
+    first: 8 significant bits each hold all 24 of a float32, so the sum
+    is exact."""
+    parts = []
+    for _ in range(3):
+        hi = x.astype(jnp.bfloat16)
+        parts.append(hi)
+        x = x - hi.astype(jnp.float32)
+    return parts
+
+
+def _dot_f32(a, b, dims):
+    """float32 x float32 on the MXU at HIGHEST precision (six bf16
+    passes): the path of every operand that is not stored as bf16."""
+    return jax.lax.dot_general(
+        a.astype(jnp.float32), b.astype(jnp.float32), (dims, ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _dot_bf16(a, b, dims):
+    """bf16 x bf16 in ONE MXU pass, accumulated in float32.  A product of
+    two bf16 numbers has 16 significant bits, so it is exact in float32:
+    this is the number HIGHEST gives for the same operands."""
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())), preferred_element_type=jnp.float32
+    )
+
+
 def softmax_block_update(
     q, k, v, s_acc, s_m, s_l, *, base, length, scale
 ):
@@ -54,22 +88,29 @@ def softmax_block_update(
     the contiguous (flash_decode) and paged kernels.  ``q``/``k``/``v``
     are already-loaded VMEM tiles: (rows, hd), (BS, hd), (BS, hd).
 
-    HIGHEST precision on both dots: f32 MXU dots default to single-pass
-    bf16 rounding (measured 0.1 abs output error at 4k lengths vs 6e-5
-    with 3-pass) and decode is HBM-bound, so the extra passes are free.
+    Float32 accuracy on both dots, by the cheapest route the operands'
+    dtypes allow (a plain float32 MXU dot rounds its operands to bf16:
+    0.1 abs output error at 4k lengths against 6e-5):
+
+    * q, K and V all bf16 (a bf16 pool under a bf16 model): ``q k^T``
+      is one bf16 pass, which is exact; the float32 probabilities are
+      split into three bf16 terms (exact) that ride ONE dot against the
+      bf16 V tile, stacked along the row axis.  No operand is widened.
+    * anything else (an int8 pool dequantized to float32, a float32
+      pool, float32 queries): float32 operands at HIGHEST precision.
+
+    Decode is NOT so HBM-bound that HIGHEST's passes are free: widening
+    every bf16 K and V tile to float32 and splitting it back into bf16
+    terms held the paged kernel to 458 GB/s on a v5e with every page
+    useful, against 731 with the bf16 operands (PERF.md section 6,
+    PR 25).  The stacked dot loads each 128x128 tile of V into the MXU
+    once for all three terms; that is worth 3%, the rest is the
+    widening.
     """
-    q = q.astype(jnp.float32)  # (rows, hd)
-    k = k.astype(jnp.float32)  # (BS, hd)
-    s = (
-        jax.lax.dot_general(
-            q,
-            k,
-            (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32,
-        )
-        * scale
-    )  # (rows, BS)
+    nt = ((1,), (1,))  # (rows, hd) x (BS, hd) -> (rows, BS)
+    nn = ((1,), (0,))  # (rows, BS) x (BS, hd) -> (rows, hd)
+    bf16 = all(x.dtype == jnp.bfloat16 for x in (q, k, v))
+    s = (_dot_bf16 if bf16 else _dot_f32)(q, k, nt) * scale  # (rows, BS)
     pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(pos < length, s, _NEG_INF)
 
@@ -77,12 +118,12 @@ def softmax_block_update(
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
     alpha = jnp.exp(m_prev - m_cur)
     p = jnp.exp(s - m_cur[:, None])  # (rows, BS)
-    v = v.astype(jnp.float32)  # (BS, hd)
-    pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    )  # (rows, hd)
+    if bf16:
+        rows = p.shape[0]
+        stacked = _dot_bf16(jnp.concatenate(_split_bf16(p), axis=0), v, nn)
+        pv = stacked[:rows] + stacked[rows : 2 * rows] + stacked[2 * rows :]
+    else:
+        pv = _dot_f32(p, v, nn)  # (rows, hd)
     s_acc[:] = s_acc[:] * alpha[:, None] + pv
     s_l[:] = s_l[:] * alpha[:, None] + jnp.sum(p, axis=1)[:, None]
     s_m[:] = jnp.broadcast_to(m_cur[:, None], s_m.shape)

@@ -17,11 +17,19 @@ Kernel shape:
   axis so VMEM scratch stays bounded at prefill-chunk shapes; the minor
   axis iterates sequentially on TPU so online-softmax state (m/l/acc)
   lives in VMEM scratch across blocks;
-* the K/V index maps ride TWO scalar-prefetch operands: ``lengths``
-  clamps the block index to each row's last valid block (trailing grid
-  steps re-address the same tile and the pipeline skips their HBM->VMEM
-  copies — short rows stream only the KV they own), and ``tables``
-  translates the clamped logical block index into a pool block id;
+* each of the G page streams is an OPERAND of its own, and the pipeline
+  skips an operand's HBM->VMEM copy only when its block index is the one
+  of the grid step before.  So the K/V index maps read a scalar-prefetch
+  table of page ids made outside the kernel (:func:`stream_page_ids`):
+  a stream's own page where that page holds valid KV, and otherwise the
+  page the stream fetched last.  Each valid page is copied once; dead
+  rows and the pages past a short row's length start no copy.  (Clamping
+  a stream to its row's LAST valid page instead re-reads that page once
+  a stream and row: 256 MiB a call for 69 MiB of valid pages at 64 slots
+  x 4 pages, PERF.md section 6);
+* the grid visits the rows in falling order of their page counts
+  (:func:`visit_order`; q and the outputs are addressed through it), so
+  that the copies of the next row overlap the dots of this one;
 * queries are GQA-grouped AND chunk-grouped: ``q`` carries Q query
   tokens per row (Q=1 for decode; Q=chunk for chunked prefill's
   prefix attention) and every query row of a (b, qb) cell shares one
@@ -35,7 +43,7 @@ in-flight window, or a prefill chunk's causal self-attention).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,20 +54,20 @@ from jax.experimental.pallas import tpu as pltpu
 
 from areal_tpu.ops.decode_attention import (
     softmax_block_update,
-    softmax_emit,
     softmax_scratch_init,
 )
 
-DEFAULT_BLOCK = 256
 _NEG_INF = -1e30
 
 
-#: logical pages streamed per grid step.  The kernel is DMA-LATENCY-bound
-#: at one small page per step (~1us fixed cost per HBM->VMEM copy caps it
-#: at ~200 GB/s on v5e); issuing G page copies per step overlaps their
-#: latencies.  Measured on v5e at 8k ctx (1.5B arch, B=16, 256-token
-#: pages): G=1 0.70x of the dense-einsum path, G=4 0.78x, and G=4 with
-#: 1024-token pages 0.93x — G=8 regresses (0.83x), so 4 it is.
+#: logical pages streamed per grid step, each as an operand stream of its
+#: own.  A row of up to G pages is one grid step: its 2 G tile copies are
+#: in flight together while the row before it computes.  G was chosen
+#: against the dense-einsum path on v5e at 8k context (1.5B heads, B=16):
+#: G=1 0.70x and G=4 0.78x with 256-token pages, G=4 0.93x and G=8 0.83x
+#: with 1024-token pages.  What G overlaps is whole rows' copies with
+#: whole rows' dots, not copy latencies: with 1024-token pages and every
+#: copy useful the pipeline reaches 733 GB/s of 819 (PERF.md, PR 25).
 PAGE_GROUP = 4
 
 
@@ -142,9 +150,10 @@ def _plan_tiles(
 
 
 def _kernel(
-    lengths_ref,  # scalar prefetch [B]
-    tables_ref,  # scalar prefetch [B, MB]
+    lengths_ref,  # scalar prefetch [B], in the order the grid visits
+    ids_ref,  # scalar prefetch [B, MB']: stream_page_ids, for the maps
     layer_ref,  # scalar prefetch [1] (0 when the pool is per-layer)
+    order_ref,  # scalar prefetch [B]: visit_order, for the maps
     q_ref,  # (1, 1, Hkv, QR, hd)
     *refs,  # G k-page refs, G v-page refs, [2G scale refs], 3 outs, 3 scratch
     block_size: int,
@@ -185,8 +194,8 @@ def _kernel(
             v_all = v_refs[g][...].reshape(n_kv_heads, block_size, hd)
             if quantized:
                 # in-kernel dequant: multiply the int8 page by its
-                # per-(head, slot) scales right after the gather, so the
-                # attention dots below run in f32 like the fp path
+                # per-(head, slot) scales right after the gather; the
+                # dots below then take float32 operands (HIGHEST)
                 ks = ks_refs[g][...].reshape(n_kv_heads, block_size)
                 vs = vs_refs[g][...].reshape(n_kv_heads, block_size)
                 k_all = k_all.astype(jnp.float32) * ks[:, :, None]
@@ -205,20 +214,105 @@ def _kernel(
         l_ref[0, 0] = s_l[...]
 
 
-def _paged_kv_map(b, qb, j, lengths_ref, tables_ref, layer_ref, *,
-                  block_size, layered, group, offset):
-    # page ``j * group + offset``, clamped to the last LOGICAL block
-    # holding valid KV for row b (trailing steps re-address that tile and
-    # the pipeline skips their copies), then translated through the row's
-    # block table into a pool block id
-    last = jnp.maximum(
-        (lengths_ref[b] + block_size - 1) // block_size - 1, 0
-    )
-    pid = tables_ref[b, jnp.minimum(j * group + offset, last)]
-    if layered:
-        return (layer_ref[0], pid, 0, 0, 0)
-    return (pid, 0, 0, 0)
+def stream_page_ids(tables, lengths, block_size: int, group: int):
+    """Pool page id that stream ``g`` of the kernel addresses at grid
+    step ``(b, ., j)``, as ``[B, ceil(MB/group) * group]`` int32 indexed
+    ``[b, j * group + g]``: the row's own page where that page holds
+    valid KV (``(j * group + g) * block_size < lengths[b]``), and
+    otherwise the page the stream fetched LAST, in the order the grid
+    visits its steps (a forward fill of the valid ids down each of the
+    ``group`` columns).
 
+    The BlockSpec pipeline skips an operand's HBM->VMEM copy when its
+    block index is the one of the step before, so a stream that has
+    nothing to fetch must repeat ITS OWN last index.  Every row visits
+    the same ``j`` sequence under each ``qb``, and a column with no
+    valid page in a row is constant over that row, so the fill over
+    ``(b, j)`` holds for any number of query tiles.  Rows that share a
+    page (siblings of one prompt) in consecutive slots skip its copy
+    too.
+    """
+    B, MB = tables.shape
+    n_j = -(-MB // group)
+    width = n_j * group
+    tables = tables.astype(jnp.int32)
+    if width != MB:
+        tables = jnp.pad(tables, ((0, 0), (0, width - MB)))
+    page = jnp.arange(width, dtype=jnp.int32)
+    valid = page[None, :] * block_size < lengths.astype(jnp.int32)[:, None]
+    # flatten (b, j) into the order the grid visits: one line a step,
+    # one column a stream
+    step = jnp.arange(B * n_j, dtype=jnp.int32)[:, None]
+    last_valid_step = jax.lax.cummax(
+        jnp.where(valid.reshape(B * n_j, group), step, 0), axis=0
+    )
+    ids = jnp.take_along_axis(
+        tables.reshape(B * n_j, group), last_valid_step, axis=0
+    )
+    return ids.reshape(B, width)
+
+
+def visit_order(lengths, block_size: int):
+    """The batch row each grid step ``b`` works on: rows in falling order
+    of the valid pages they hold, dead rows last.  The pipeline fetches
+    step ``b + 1``'s pages while step ``b`` computes, one step ahead and
+    no further, so a step lasts as long as the LONGER of this row's dots
+    and the next row's copies: rows of equal page counts side by side
+    keep both busy, and dead rows in a block at the end start no copy
+    between two live rows."""
+    pages = -(-lengths.astype(jnp.int32) // block_size)
+    return jnp.argsort(-pages, stable=True).astype(jnp.int32)
+
+
+class PagePlan(NamedTuple):
+    """What the kernel's index maps read, made from one (tables,
+    lengths) pair for one page group."""
+
+    lengths: jax.Array  # [B] valid prefix per row, in visiting order
+    page_ids: jax.Array  # [B, MB'] stream_page_ids, in visiting order
+    order: jax.Array  # [B] visit_order
+
+
+def plan_pages(tables, lengths, block_size: int, group: int) -> PagePlan:
+    """The plan :func:`paged_flash_attention` makes for itself unless it
+    is handed one.  A caller that runs the kernel many times over the
+    same rows (every layer of every step of a decode chunk) makes it
+    once, with the ``group`` :func:`page_group` names for its shapes:
+    XLA does not hoist the sort and the scan out of those loops (about
+    9 us a call on a v5e, beside a kernel of 80-120)."""
+    order = visit_order(lengths, block_size)
+    lengths = lengths.astype(jnp.int32)[order]
+    ids = stream_page_ids(tables[order], lengths, block_size, group)
+    return PagePlan(lengths, ids, order)
+
+
+def page_group(
+    n_queries: int, n_q_heads: int, pool_shape, kv_dtype, quantized: bool,
+    max_blocks: int,
+) -> int:
+    """Pages a grid step streams for a call of these shapes (``pool_shape``
+    as the kernel sees it: one shard's, under a TP mesh)."""
+    Hkv, BS, hd = pool_shape[-3:]
+    return _plan_tiles(
+        n_queries, n_q_heads // Hkv, Hkv, BS, hd,
+        jnp.dtype(kv_dtype).itemsize, quantized, max_blocks,
+    )[0]
+
+
+def _row_map(b, qb, j, lengths_ref, ids_ref, layer_ref, order_ref):
+    """Query and output tiles of step (b, qb, .): those of the row this
+    step works on."""
+    return (order_ref[b], qb, 0, 0, 0)
+
+
+def _paged_page_map(b, qb, j, lengths_ref, ids_ref, layer_ref, order_ref, *,
+                    layered, group, offset, rank):
+    """Block index (of ``rank`` axes: a KV page's or its int8 scales') of
+    stream ``offset`` at step (b, qb, j): the page :func:`stream_page_ids`
+    assigned it, whole."""
+    pid = ids_ref[b, j * group + offset]
+    head = (layer_ref[0], pid) if layered else (pid,)
+    return head + (0,) * (rank - len(head))
 
 
 def _group_queries(q, Hkv, r, QT):
@@ -277,6 +371,7 @@ def paged_flash_attention(
     interpret: bool = False,
     k_scale: jax.Array | None = None,  # [(L,) NB, Hkv, BS] int8-pool scales
     v_scale: jax.Array | None = None,
+    plan: Optional[PagePlan] = None,  # plan_pages(tables, lengths, ...)
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Un-normalized online-softmax attention partials over paged KV.
 
@@ -300,6 +395,10 @@ def paged_flash_attention(
     scale tile streams beside its KV tile through the same index map and
     the kernel dequantizes in VMEM right after the gather (the
     storage-only quantization contract).
+
+    ``plan``: what :func:`plan_pages` made of these ``tables`` and
+    ``lengths`` (which are then not read), for a caller that makes many
+    calls over the same rows.
     """
     B, Q, Hq, hd = q.shape
     layered = k_pool.ndim == 5
@@ -317,38 +416,33 @@ def paged_flash_attention(
     )
     qg, QB = _group_queries(q, Hkv, r, QT)
     layer_arr = _layer_scalar(layer)
-
     grid = (B, QB, -(-MB // G))
+    # lengths and page ids in the order the grid visits the rows; q and
+    # the outputs stay where they are and are addressed through the order
+    if plan is None:
+        plan = plan_pages(tables, lengths, BS, G)
+    assert plan.page_ids.shape == (B, grid[2] * G), (
+        plan.page_ids.shape, B, MB, G,
+    )
     kv_block = (1, 1, Hkv, BS, hd) if layered else (1, Hkv, BS, hd)
-    kv_specs = [
-        pl.BlockSpec(
-            kv_block,
-            functools.partial(
-                _paged_kv_map,
-                block_size=BS,
-                layered=layered,
-                group=G,
-                offset=g,
-            ),
-        )
-        for g in range(G)
-    ]
     # int8 pools: each page's scale tile (one f32 per head x slot) rides
-    # the same clamped index map as its KV tile
+    # the same page ids as its KV tile
     scale_block = (1, 1, Hkv, BS) if layered else (1, Hkv, BS)
-    scale_specs = [
-        pl.BlockSpec(
-            scale_block,
-            functools.partial(
-                _paged_scale_map,
-                block_size=BS,
-                layered=layered,
-                group=G,
-                offset=g,
-            ),
-        )
-        for g in range(G)
-    ]
+
+    def page_specs(block):
+        return [
+            pl.BlockSpec(
+                block,
+                functools.partial(
+                    _paged_page_map, layered=layered, group=G, offset=g,
+                    rank=len(block),
+                ),
+            )
+            for g in range(G)
+        ]
+
+    kv_specs = page_specs(kv_block)
+    scale_specs = page_specs(scale_block) if quantized else []
     acc, m, l = pl.pallas_call(
         functools.partial(
             _kernel,
@@ -359,31 +453,32 @@ def paged_flash_attention(
             quantized=quantized,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=grid,
             in_specs=(
                 [
                     pl.BlockSpec(
                         (1, 1, Hkv, QT * r, hd),
-                        lambda b, qb, j, L, T, Y: (b, qb, 0, 0, 0),
+                        _row_map,
                     )
                 ]
                 + kv_specs  # G k-page streams
                 + kv_specs  # G v-page streams (same maps, v operands)
-                + (scale_specs + scale_specs if quantized else [])
+                + scale_specs  # int8 pools: G k-scale streams,
+                + scale_specs  # G v-scale streams
             ),
             out_specs=[
                 pl.BlockSpec(
                     (1, 1, Hkv, QT * r, hd),
-                    lambda b, qb, j, L, T, Y: (b, qb, 0, 0, 0),
+                    _row_map,
                 ),
                 pl.BlockSpec(
                     (1, 1, Hkv, QT * r, 128),
-                    lambda b, qb, j, L, T, Y: (b, qb, 0, 0, 0),
+                    _row_map,
                 ),
                 pl.BlockSpec(
                     (1, 1, Hkv, QT * r, 128),
-                    lambda b, qb, j, L, T, Y: (b, qb, 0, 0, 0),
+                    _row_map,
                 ),
             ],
             scratch_shapes=[
@@ -406,249 +501,14 @@ def paged_flash_attention(
         # sequence is a decode step, a query tile a prefill chunk
         name="paged_attn_decode" if Q == 1 else "paged_attn_fill",
     )(
-        lengths.astype(jnp.int32),
-        tables.astype(jnp.int32),
+        plan.lengths,
+        plan.page_ids,
         layer_arr,
+        plan.order,
         qg,
         *([k_pool] * G),
         *([v_pool] * G),
         *(([k_scale] * G + [v_scale] * G) if quantized else []),
-    )
-
-    return _ungroup_outputs(acc, m, l, B, QB, QT, Hkv, r, Q, Hq, hd)
-
-
-def _paged_scale_map(b, qb, j, lengths_ref, tables_ref, layer_ref, *,
-                     block_size, layered, group, offset):
-    """Scale-pool twin of :func:`_paged_kv_map` (one fewer trailing dim)."""
-    last = jnp.maximum(
-        (lengths_ref[b] + block_size - 1) // block_size - 1, 0
-    )
-    pid = tables_ref[b, jnp.minimum(j * group + offset, last)]
-    if layered:
-        return (layer_ref[0], pid, 0, 0)
-    return (pid, 0, 0)
-
-
-#: in-flight page DMAs of the deep-pipelined kernel (see
-#: paged_flash_attention_deep); 8 x ~0.5 MB tiles keep the HBM stream
-#: saturated where the BlockSpec pipeline's 1-deep lookahead cannot
-DEEP_BUFFERS = 8
-
-
-def _deep_kernel(
-    lengths_ref,  # scalar prefetch [B]
-    tables_ref,  # scalar prefetch [B, MB]
-    layer_ref,  # scalar prefetch [1]
-    q_ref,  # (1, 1, Hkv, QR, hd) VMEM
-    *refs,  # k_hbm, v_hbm, [ks_hbm, vs_hbm], 3 outs, bufs, scratch, sems
-    block_size: int,
-    scale: float,
-    n_kv_heads: int,
-    layered: bool,
-    max_blocks: int,
-    n_buffers: int,
-    quantized: bool = False,
-):
-    if quantized:
-        (k_hbm, v_hbm, ks_hbm, vs_hbm, acc_ref, m_ref, l_ref,
-         kbuf, vbuf, ksbuf, vsbuf, s_acc, s_m, s_l,
-         k_sems, v_sems, ks_sems, vs_sems) = refs
-    else:
-        (k_hbm, v_hbm, acc_ref, m_ref, l_ref, kbuf, vbuf,
-         s_acc, s_m, s_l, k_sems, v_sems) = refs
-    NBUF = n_buffers
-    b = pl.program_id(0)
-    length = lengths_ref[b]
-    n_blocks = jnp.minimum(
-        jnp.maximum((length + block_size - 1) // block_size, 0), max_blocks
-    )
-    lay = layer_ref[0]
-
-    softmax_scratch_init(s_acc, s_m, s_l)
-
-    def src(j):
-        pid = tables_ref[b, jnp.minimum(j, max_blocks - 1)]
-        if layered:
-            return lambda r: r.at[lay, pid]
-        return lambda r: r.at[pid]
-
-    def dma_group(j, slot):
-        sel = src(j)
-        copies = [
-            pltpu.make_async_copy(sel(k_hbm), kbuf.at[slot], k_sems.at[slot]),
-            pltpu.make_async_copy(sel(v_hbm), vbuf.at[slot], v_sems.at[slot]),
-        ]
-        if quantized:
-            # the page's scale tiles ride the same DMA ring slot — the
-            # in-kernel-dequant half of the int8 storage format
-            copies.append(
-                pltpu.make_async_copy(
-                    sel(ks_hbm), ksbuf.at[slot], ks_sems.at[slot]
-                )
-            )
-            copies.append(
-                pltpu.make_async_copy(
-                    sel(vs_hbm), vsbuf.at[slot], vs_sems.at[slot]
-                )
-            )
-        return copies
-
-    # warm-up: fill the buffer ring
-    def warm(j, _):
-        @pl.when(j < n_blocks)
-        def _():
-            for c in dma_group(j, j % NBUF):
-                c.start()
-        return 0
-
-    jax.lax.fori_loop(0, NBUF, warm, 0)
-
-    def body(j, _):
-        slot = j % NBUF
-        for c in dma_group(j, slot):
-            c.wait()
-        k_all = kbuf[slot]
-        v_all = vbuf[slot]
-        if quantized:
-            k_all = k_all.astype(jnp.float32) * ksbuf[slot][:, :, None]
-            v_all = v_all.astype(jnp.float32) * vsbuf[slot][:, :, None]
-        for h in range(n_kv_heads):
-            softmax_block_update(
-                q_ref[0, 0, h], k_all[h], v_all[h],
-                s_acc.at[h], s_m.at[h], s_l.at[h],
-                base=j * block_size, length=length, scale=scale,
-            )
-        # refill this slot with the page NBUF ahead
-        nxt = j + NBUF
-
-        @pl.when(nxt < n_blocks)
-        def _():
-            for c in dma_group(nxt, slot):
-                c.start()
-        return 0
-
-    jax.lax.fori_loop(0, n_blocks, body, 0)
-
-    acc_ref[0, 0] = s_acc[...]
-    m_ref[0, 0] = s_m[...]
-    l_ref[0, 0] = s_l[...]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_flash_attention_deep(
-    q: jax.Array,  # [B, Q, Hq, hd]
-    k_pool: jax.Array,  # [NB, Hkv, BS, hd] or [L, NB, Hkv, BS, hd]
-    v_pool: jax.Array,
-    tables: jax.Array,  # [B, MB]
-    lengths: jax.Array,  # [B]
-    layer: jax.Array | None = None,
-    interpret: bool = False,
-    k_scale: jax.Array | None = None,  # [(L,) NB, Hkv, BS] int8-pool scales
-    v_scale: jax.Array | None = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Deep-pipelined variant of :func:`paged_flash_attention`: the pool
-    stays in HBM and the kernel issues its own page DMAs with a
-    ``DEEP_BUFFERS``-deep ring, so up to 8 page copies are in flight —
-    the BlockSpec pipeline's single-step lookahead is what caps the
-    default kernel at ~350 GB/s on v5e (DMA-latency-bound).  Same
-    (acc, m, l) contract; rows stream only their valid pages.
-
-    EXPERIMENTAL: numerics are parity-tested (interpret mode + TPU), but
-    until it is measured FASTER on hardware the engine keeps the default
-    kernel (bench.py's decode A/B reports both).
-    """
-    B, Q, Hq, hd = q.shape
-    layered = k_pool.ndim == 5
-    NB, Hkv, BS, _ = k_pool.shape[-4:]
-    MB = tables.shape[1]
-    assert Hq % Hkv == 0
-    if layered:
-        assert layer is not None
-    r = Hq // Hkv
-    quantized = k_scale is not None
-    # same VMEM plan as the default kernel: its G double-buffered page
-    # streams cost what a ring of 2G pages costs here
-    G, QT = _plan_tiles(
-        Q, r, Hkv, BS, hd, jnp.dtype(k_pool.dtype).itemsize, quantized,
-        DEEP_BUFFERS // 2,
-    )
-    nbuf = 2 * G
-    qg, QB = _group_queries(q, Hkv, r, QT)
-    layer_arr = _layer_scalar(layer)
-    grid = (B, QB)
-    scratch = [
-        pltpu.VMEM((nbuf, Hkv, BS, hd), k_pool.dtype),
-        pltpu.VMEM((nbuf, Hkv, BS, hd), v_pool.dtype),
-    ]
-    if quantized:
-        scratch += [
-            pltpu.VMEM((nbuf, Hkv, BS), jnp.float32),
-            pltpu.VMEM((nbuf, Hkv, BS), jnp.float32),
-        ]
-    scratch += [
-        pltpu.VMEM((Hkv, QT * r, hd), jnp.float32),
-        pltpu.VMEM((Hkv, QT * r, 128), jnp.float32),
-        pltpu.VMEM((Hkv, QT * r, 128), jnp.float32),
-    ]
-    scratch += [pltpu.SemaphoreType.DMA((nbuf,))] * (4 if quantized else 2)
-    acc, m, l = pl.pallas_call(
-        functools.partial(
-            _deep_kernel,
-            block_size=BS,
-            scale=1.0 / np.sqrt(hd),
-            n_kv_heads=Hkv,
-            layered=layered,
-            max_blocks=MB,
-            n_buffers=nbuf,
-            quantized=quantized,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec(
-                    (1, 1, Hkv, QT * r, hd),
-                    lambda b, qb, L, T, Y: (b, qb, 0, 0, 0),
-                ),
-            ]
-            + [pl.BlockSpec(memory_space=pl.ANY)]
-            * (4 if quantized else 2),
-            out_specs=[
-                pl.BlockSpec(
-                    (1, 1, Hkv, QT * r, hd),
-                    lambda b, qb, L, T, Y: (b, qb, 0, 0, 0),
-                ),
-                pl.BlockSpec(
-                    (1, 1, Hkv, QT * r, 128),
-                    lambda b, qb, L, T, Y: (b, qb, 0, 0, 0),
-                ),
-                pl.BlockSpec(
-                    (1, 1, Hkv, QT * r, 128),
-                    lambda b, qb, L, T, Y: (b, qb, 0, 0, 0),
-                ),
-            ],
-            scratch_shapes=scratch,
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, 128), jnp.float32),
-            jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, 128), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES,
-        ),
-        interpret=interpret,
-        name="paged_attn_deep",
-    )(
-        lengths.astype(jnp.int32),
-        tables.astype(jnp.int32),
-        layer_arr,
-        qg,
-        k_pool,
-        v_pool,
-        *((k_scale, v_scale) if quantized else ()),
     )
 
     return _ungroup_outputs(acc, m, l, B, QB, QT, Hkv, r, Q, Hq, hd)

@@ -328,8 +328,7 @@ class GenerationServerWorker(worker_base.Worker):
             prefill_chunk_tokens=config.prefill_chunk_tokens,
             pipeline_depth=config.pipeline_depth,
             dispatch_table=resolve_dispatch_table(
-                config.paged_min_cache_len,
-                config.deep_kernel_min_context,
+                config.paged_min_cache_len
             ),
             prefix_cache=config.prefix_cache,
             prefix_cache_capacity_frac=config.prefix_cache_capacity_frac,

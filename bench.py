@@ -40,8 +40,7 @@ Round 6 adds the train-MFU lever sweep: `train_remat_moment_sweep` runs
 remat presets from models/remat.py x bf16/factored Adam moments from
 OptimizerConfig), reporting per cell tok/s/TFLOP and XLA's peak-temp
 allocation, with would-OOM cells reported from the memory analysis instead
-of crashed; and the decode A/B's `paged_flash_attention_deep` column is
-now unconditional.
+of crashed.
 
 Round 7 measures the deep-pipelined serving hot path: the generation
 section reports `engine_over_jit` (engine decode vs the isolated
@@ -52,7 +51,7 @@ host/device/fetch split per K, and a `prefill_ab` section attributing the
 round-5 prefill regression (jit ceiling vs engine dense admit vs paged
 chunked admit, repeated so run-to-run variance is visible as spread).  The
 decode A/B additionally derives a `PagedDispatchTable` (engine/dispatch.py)
-from its own 3-column rows, and the whole round's diffable numbers are
+from its own dense / paged rows, and the whole round's diffable numbers are
 duplicated into a compact top-level `summary` object so BENCH_rNN.json's
 `parsed` field carries them even when `detail` is huge.
 
@@ -3840,8 +3839,7 @@ _SECTION_STATUS = {}
 _SECTION_FAILURES = []
 
 #: default per-section time limit; generous because a cold section may
-#: pay multiple fresh XLA compiles (the decode A/B's deep-kernel cells
-#: run ~30-40s of compile EACH)
+#: pay multiple fresh XLA compiles
 SECTION_TIMEOUT_S = 900.0
 
 
@@ -4010,7 +4008,6 @@ def build_summary(
                 k: [
                     row.get("dense_toks_per_sec"),
                     row.get("paged_toks_per_sec"),
-                    row.get("paged_deep_toks_per_sec"),
                 ]
                 for k, row in decode_ab.items()
                 if isinstance(row, dict) and k.startswith("ctx")
@@ -4103,7 +4100,7 @@ def bench_decode_ab(cfg15, params15, cases=None, page=1024, chunk=64,
         del cache, kd
         return B * W / min(times[2:])
 
-    def run_paged(L, B, kv_cache_len=None, deep=False):
+    def run_paged(L, B, kv_cache_len=None):
         S = bucket(L + 2 * W + 8)
         MB = -(-(kv_cache_len or S) // BS)
         used = -(-(L + 2 * W + 8) // BS)
@@ -4130,7 +4127,6 @@ def bench_decode_ab(cfg15, params15, cases=None, page=1024, chunk=64,
                     params15, kp, vp, cfg15, tables, lengths, cur_h,
                     active, budgets, rng, W, greedy, no_stop,
                     use_kernel=True, max_len=(kv_cache_len or S),
-                    deep_kernel=deep,
                 )
             )
             cur_h = jnp.asarray(np.asarray(out_t[:, -1]))
@@ -4151,24 +4147,16 @@ def bench_decode_ab(cfg15, params15, cases=None, page=1024, chunk=64,
     for L, B in (cases or ((2048, 16), (8192, 16), (16384, 16), (32768, 8))):
         d = safe(run_dense, L, B)
         p = safe(run_paged, L, B)
-        # the manual-DMA-ring "deep" kernel is the UNCONDITIONAL third
-        # column: it shipped OFF-by-default for two rounds with no hardware
-        # numbers, so every default row now records dense vs paged vs deep
-        # side by side (each deep cell is a fresh ~30-40s compile — that is
-        # the price of finally measuring it)
-        pd = safe(run_paged, L, B, deep=True)
         row = {
             "dense_toks_per_sec": round(d, 1) if d else "OOM",
             "paged_toks_per_sec": round(p, 1) if p else "OOM",
-            "paged_deep_toks_per_sec": round(pd, 1) if pd else "OOM",
             "paged_over_dense": round(p / d, 3) if (p and d) else None,
-            "deep_over_dense": round(pd / d, 3) if (pd and d) else None,
         }
         rows[f"ctx{L}_b{B}"] = row
-        measured[L] = {"dense": d, "paged": p, "deep": pd}
-    # turn the 3-column A/B into the thresholds cache_mode="auto" should
-    # dispatch on; recipe configs pin these once a hardware round fills
-    # them in (GenServerConfig.paged_min_cache_len / deep_kernel_min_context)
+        measured[L] = {"dense": d, "paged": p}
+    # turn the A/B into the threshold cache_mode="auto" should dispatch
+    # on; recipe configs pin it once a hardware round fills it in
+    # (GenServerConfig.paged_min_cache_len)
     from areal_tpu.engine.dispatch import derive_dispatch_table
 
     rows["derived_dispatch_table"] = derive_dispatch_table(

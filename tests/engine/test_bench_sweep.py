@@ -461,8 +461,7 @@ def test_summary_schema_round_trips_with_required_keys(spec_ab):
         },
         decode_ab={
             "ctx2048_b16": {"dense_toks_per_sec": 1.0,
-                            "paged_toks_per_sec": 2.0,
-                            "paged_deep_toks_per_sec": 3.0},
+                            "paged_toks_per_sec": 2.0},
             "derived_dispatch_table": {"paged_min_cache_len": 2048},
         },
         gateway_ab={
@@ -499,7 +498,7 @@ def test_summary_schema_round_trips_with_required_keys(spec_ab):
     assert blob["spec_decode_ab"]["b2"]["spec_on"]["verify_chunks"] > 0
     assert blob["decode"]["b2"]["decode_toks_per_sec"] == 2.0
     assert blob["decode"]["b4"]["decode_toks_per_sec"] is None
-    assert blob["paged_decode_ab"]["ctx2048_b16"] == [1.0, 2.0, 3.0]
+    assert blob["paged_decode_ab"]["ctx2048_b16"] == [1.0, 2.0]
     assert blob["dispatch_table"] == {"paged_min_cache_len": 2048}
     assert blob["sharded_serving"]["moe_ep"]["expert_shard_ok"] is True
     assert blob["slo_report"]["multi_turn"]["fleet"]["ttft_s"]["p99"] == 0.5

@@ -24,7 +24,6 @@ from areal_tpu.api.model_api import (
     GenerationHyperparameters,
 )
 from areal_tpu.engine.dispatch import (
-    DISPATCH_NEVER,
     PagedDispatchTable,
     derive_dispatch_table,
     resolve_dispatch_table,
@@ -285,48 +284,40 @@ def test_async_fetch_counters(mode):
 def test_dispatch_table_defaults_reproduce_old_behavior():
     t = PagedDispatchTable()
     assert t.paged_min_cache_len == 2048
-    assert t.deep_min_context == DISPATCH_NEVER
-    assert resolve_dispatch_table(None, None) == t
-    over = resolve_dispatch_table(4096, 8192)
+    assert resolve_dispatch_table(None) == t
+    over = resolve_dispatch_table(4096)
     assert over.paged_min_cache_len == 4096
-    assert over.deep_min_context == 8192
     assert over.source == "config"
-    # partial override keeps the other default
-    part = resolve_dispatch_table(None, 8192)
-    assert part.paged_min_cache_len == 2048
-    assert part.deep_min_context == 8192
 
 
 def test_derive_dispatch_table_from_bench_rows():
     rows = {
-        2048: {"dense": 4000.0, "paged": 3000.0, "deep": 2900.0},
-        8192: {"dense": 1400.0, "paged": 1380.0, "deep": 1500.0},
-        16384: {"dense": 700.0, "paged": 760.0, "deep": 900.0},
-        32768: {"dense": None, "paged": 400.0, "deep": 520.0},  # dense OOM
+        2048: {"dense": 4000.0, "paged": 3000.0},
+        8192: {"dense": 1400.0, "paged": 1380.0},
+        16384: {"dense": 700.0, "paged": 760.0},
+        32768: {"dense": None, "paged": 400.0},  # dense OOM
     }
     t = derive_dispatch_table(rows)
-    # paged reaches parity from 8k up (0.95 margin); deep wins from 8k up
+    # paged reaches parity from 8k up (0.95 margin)
     assert t.paged_min_cache_len == 8192
-    assert t.deep_min_context == 8192
     assert t.source.startswith("bench(")
 
 
 def test_derive_dispatch_table_no_paged_win_and_noisy_island():
     # paged never reaches parity: threshold pushed past the measured
-    # range (capacity arguments take over beyond it), deep stays NEVER
+    # range (capacity arguments take over beyond it)
     rows = {
-        2048: {"dense": 4000.0, "paged": 2000.0, "deep": 1900.0},
-        8192: {"dense": 1400.0, "paged": 900.0, "deep": 880.0},
+        2048: {"dense": 4000.0, "paged": 2000.0},
+        8192: {"dense": 1400.0, "paged": 900.0},
     }
     t = derive_dispatch_table(rows)
     assert t.paged_min_cache_len == 2 * 8192
-    assert t.deep_min_context == DISPATCH_NEVER
     # a noisy mid-table dense win must not carve a dense island: the
     # threshold is the start of the WINNING SUFFIX only
     rows = {
-        2048: {"dense": 4000.0, "paged": 3950.0, "deep": None},
-        8192: {"dense": 1400.0, "paged": 1000.0, "deep": None},
-        16384: {"dense": 700.0, "paged": 760.0, "deep": None},
+        2048: {"dense": 4000.0, "paged": 3950.0},
+        8192: {"dense": 1400.0, "paged": 1000.0},
+        16384: {"dense": 700.0, "paged": 760.0},
     }
     t = derive_dispatch_table(rows)
     assert t.paged_min_cache_len == 16384
@@ -351,44 +342,3 @@ def test_auto_mode_consults_dispatch_table():
     assert paged_eng.paged  # measured table moved the crossover
 
 
-def test_deep_kernel_threshold_is_context_driven():
-    """_use_deep_kernel flips on the batch's longest live context (plus
-    the un-harvested ring allowance), not on kv_cache_len."""
-    cfg = tiny_config(vocab_size=64, max_position_embeddings=256)
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    eng = ContinuousBatchingEngine(
-        cfg, params, max_batch=2, kv_cache_len=128, chunk_size=4,
-        cache_mode="paged", page_size=16,
-        sampling=SamplingParams(greedy=True),
-        dispatch_table=PagedDispatchTable(
-            paged_min_cache_len=64, deep_min_context=40, source="config"
-        ),
-    )
-    eng._use_paged_kernel = True  # decision logic only; no TPU dispatch
-    assert not eng._use_deep_kernel()  # no rows yet
-    eng.submit(APIGenerateInput(
-        qid="q0", prompt_ids=list(range(7, 57)), input_ids=list(range(7, 57)),
-        gconfig=GenerationHyperparameters(max_new_tokens=4, greedy=True),
-    ))
-    eng._use_paged_kernel = False  # run the wave on the reference path
-    run_until_done(eng)
-    eng._use_paged_kernel = True
-    # a 50-token context row would cross the 40-token deep threshold
-    class _Row50:
-        prompt = list(range(50))
-        generated = []
-        parked = False
-        filling = False
-    eng.rows[0] = _Row50()
-    assert eng._use_deep_kernel()
-
-    # a long prompt still chunk-FILLING is not part of the decode batch
-    # and must not route the short decoding rows onto the deep kernel
-    class _FillingRow:
-        prompt = list(range(50))
-        generated = []
-        parked = False
-        filling = True
-    eng.rows[0] = _FillingRow()
-    assert not eng._use_deep_kernel()
-    eng.rows[0] = None

@@ -175,6 +175,7 @@ def test_engine_spans_in_a_cpu_profile(tmp_path):
         c["chunk_size"] == 8 and c["rows"] > 0
         and c["ctx_tokens_sum"] >= 20 * c["rows"]
         and c["pages_attended"] >= c["rows"]
+        and c["page_slots"] >= c["pages_attended"]
         for c in disp
     )
     folded = sum(c["tokens"] for c in counts("areal.engine.harvest.fold"))

@@ -6,19 +6,26 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from areal_tpu.models.paged import quantize_kv
 from areal_tpu.ops.paged_attention import (
+    PAGE_GROUP,
     gather_paged_kv,
+    page_group,
     paged_flash_attention,
+    plan_pages,
     reference_paged_partials,
+    stream_page_ids,
+    visit_order,
 )
 
 BS = 128
 
 
 def _setup(B=4, Q=1, Hq=8, Hkv=4, MB=4, NB=32, hd=128, seed=0,
-           lengths=None, dtype=jnp.bfloat16):
+           lengths=None, dtype=jnp.bfloat16, q_dtype=jnp.float32,
+           engine_tables=False):
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    q = jax.random.normal(ks[0], (B, Q, Hq, hd), jnp.float32)
+    q = jax.random.normal(ks[0], (B, Q, Hq, hd), jnp.float32).astype(q_dtype)
     k_pool = jax.random.normal(
         ks[1], (NB, Hkv, BS, hd), jnp.float32
     ).astype(dtype)
@@ -31,18 +38,16 @@ def _setup(B=4, Q=1, Hq=8, Hkv=4, MB=4, NB=32, hd=128, seed=0,
     if lengths is None:
         lengths = [MB * BS] * B
     lens = jnp.asarray(lengths, jnp.int32)
+    if engine_tables:
+        # as the engine writes them: zeros past a row's blocks, a dead
+        # row's table all zeros
+        held = jnp.arange(MB)[None, :] * BS < lens[:, None]
+        tables = jnp.where(held, tables, 0)
     return q, k_pool, v_pool, tables, lens
 
 
-@pytest.mark.parametrize(
-    "lengths",
-    [[512, 512, 512, 512], [1, 130, 256, 511], [0, 512, 37, 300]],
-)
-def test_paged_attention_matches_reference(lengths):
-    q, kp, vp, tables, lens = _setup(lengths=lengths)
-    acc, m, l = paged_flash_attention(q, kp, vp, tables, lens, interpret=True)
-    acc_r, m_r, l_r = reference_paged_partials(q, kp, vp, tables, lens)
-
+def _assert_matches_reference(got, want, lens, tol=3e-3):
+    (acc, m, l), (acc_r, m_r, l_r) = got, want
     valid = np.asarray(lens) > 0
     np.testing.assert_allclose(
         np.asarray(m)[valid], np.asarray(m_r)[valid], rtol=1e-5, atol=1e-5
@@ -50,13 +55,141 @@ def test_paged_attention_matches_reference(lengths):
     np.testing.assert_allclose(
         np.asarray(l)[valid], np.asarray(l_r)[valid], rtol=2e-3, atol=2e-3
     )
-    out = np.asarray(acc)[valid] / np.asarray(l)[valid][..., None, None]
-    out_r = np.asarray(acc_r)[valid] / np.asarray(l_r)[valid][..., None, None]
-    np.testing.assert_allclose(out, out_r, rtol=3e-3, atol=3e-3)
-    empty = ~valid
-    if empty.any():
-        assert (np.asarray(l)[empty] == 0).all()
-        assert (np.asarray(acc)[empty] == 0).all()
+    out = np.asarray(acc)[valid] / np.asarray(l)[valid][..., None]
+    out_r = np.asarray(acc_r)[valid] / np.asarray(l_r)[valid][..., None]
+    np.testing.assert_allclose(out, out_r, rtol=tol, atol=tol)
+    # dead rows: nothing attended, nothing accumulated
+    assert (np.asarray(l)[~valid] == 0).all()
+    assert (np.asarray(acc)[~valid] == 0).all()
+
+
+#: what a decode batch looks like in the middle of a rollout: dead slots
+#: between live ones, rows of one page beside rows of four, a length on a
+#: page's edge, a dead first slot, a dead tail
+RAGGED = {
+    "dead_between_live": [300, 0, 0, 512, 0, 77, 0, 130],
+    "one_page_beside_four": [100, 512, 128, 400, 1, 511, 90, 385],
+    "on_a_page_edge": [128, 256, 384, 512, 129, 257, 0, 127],
+    "dead_first_and_last": [0, 0, 512, 1, 0, 200, 0, 0],
+}
+
+
+@pytest.mark.parametrize("pool", ["bf16", "float32", "int8"])
+@pytest.mark.parametrize("case", sorted(RAGGED))
+def test_ragged_batch_matches_reference(case, pool):
+    """The copy rule (each valid page once, nothing for dead rows or past
+    a row's blocks) changes no number: engine-written tables (zeros past
+    a row's blocks) over every pool format, bf16 queries as the model
+    hands them (so the bf16 pool takes the bf16-operand dots and the
+    other two the float32 path)."""
+    lengths = RAGGED[case]
+    q, kp, vp, tables, lens = _setup(
+        B=len(lengths), Hq=4, Hkv=2, NB=40, lengths=lengths, seed=21,
+        dtype=jnp.float32 if pool == "float32" else jnp.bfloat16,
+        q_dtype=jnp.bfloat16, engine_tables=True,
+    )
+    scales = {}
+    if pool == "int8":
+        kp, ks = quantize_kv(kp)
+        vp, vs = quantize_kv(vp)
+        scales = dict(k_scale=ks, v_scale=vs)
+    got = paged_flash_attention(
+        q, kp, vp, tables, lens, interpret=True, **scales
+    )
+    want = reference_paged_partials(q, kp, vp, tables, lens, **scales)
+    _assert_matches_reference(got, want, lens)
+
+
+def test_bf16_operand_dots_equal_float32_highest_at_4k():
+    """bf16 q, K and V straight to the MXU (one exact pass for q k^T,
+    the probabilities split into three bf16 terms for p v) against the
+    float32 HIGHEST dots on the SAME bf16 values, at 4k of context."""
+    MB = 4096 // BS
+    q, kp, vp, tables, lens = _setup(
+        B=2, Hq=4, Hkv=2, MB=MB, NB=2 * MB, lengths=[4096, 4096 - 37],
+        seed=31, q_dtype=jnp.bfloat16,
+    )
+    acc, m, l = paged_flash_attention(q, kp, vp, tables, lens, interpret=True)
+    # the same numbers as float32 operands: the float32 path
+    acc_f, m_f, l_f = paged_flash_attention(
+        q.astype(jnp.float32), kp.astype(jnp.float32),
+        vp.astype(jnp.float32), tables, lens, interpret=True,
+    )
+    np.testing.assert_allclose(np.asarray(m), np.asarray(m_f), rtol=1e-6)
+    out = np.asarray(acc) / np.asarray(l)[..., None]
+    out_f = np.asarray(acc_f) / np.asarray(l_f)[..., None]
+    assert np.max(np.abs(out - out_f)) <= 1e-5 * np.max(np.abs(out_f))
+
+
+@pytest.mark.parametrize("group", [1, 2, PAGE_GROUP])
+@pytest.mark.parametrize("case", sorted(RAGGED))
+def test_stream_page_ids_change_once_a_valid_page(case, group):
+    """Down each stream's column the forward-filled ids change exactly
+    as many times as the rows hold valid pages (the pipeline copies a
+    tile when its index changes), and every valid page keeps its own
+    id."""
+    lengths = np.asarray(RAGGED[case])
+    B, MB = len(lengths), 4
+    tables = np.arange(1, B * MB + 1, dtype=np.int32).reshape(B, MB)
+    held = np.arange(MB)[None, :] * BS < lengths[:, None]
+    tables = np.where(held, tables, 0)  # distinct ids, zeros past a row
+    ids = np.asarray(
+        stream_page_ids(jnp.asarray(tables), jnp.asarray(lengths), BS, group)
+    )
+    assert ids.shape == (B, MB)
+    np.testing.assert_array_equal(ids[held], tables[held])
+    cols = ids.reshape(B * MB // group, group)
+    # the copy before the first step fetches whatever the first line
+    # names; it is a useful copy only where that page is valid
+    changes = (cols[1:] != cols[:-1]).sum() + held.reshape(cols.shape)[0].sum()
+    assert changes == held.sum()
+
+
+def test_visit_order_puts_long_rows_first_and_dead_rows_last():
+    lens = jnp.asarray([0, 130, 512, 0, 1, 129, 384, 128])
+    order = np.asarray(visit_order(lens, BS))
+    # 4, 3, 2, 2, 1, 1 pages, then the dead rows; ties keep slot order
+    np.testing.assert_array_equal(order, [2, 6, 1, 5, 4, 7, 0, 3])
+
+
+def test_handed_plan_equals_own_plan():
+    """A caller that makes the plan once (models/paged._prefix_plan) gets
+    the numbers of a call that makes its own."""
+    lengths = RAGGED["one_page_beside_four"]
+    q, kp, vp, tables, lens = _setup(
+        B=len(lengths), Hq=4, Hkv=2, NB=40, lengths=lengths, seed=23,
+        q_dtype=jnp.bfloat16, engine_tables=True,
+    )
+    group = page_group(1, 4, kp.shape, kp.dtype, False, tables.shape[1])
+    assert group == PAGE_GROUP
+    plan = plan_pages(tables, lens, BS, group)
+    own = paged_flash_attention(q, kp, vp, tables, lens, interpret=True)
+    handed = paged_flash_attention(
+        q, kp, vp, tables, lens, interpret=True, plan=plan
+    )
+    for a, b in zip(own, handed):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_stream_page_ids_pad_a_ragged_table_width():
+    # MB not a multiple of the group: the padded steps repeat the last id
+    tables = jnp.asarray([[5, 6, 7], [8, 0, 0]], jnp.int32)
+    ids = np.asarray(
+        stream_page_ids(tables, jnp.asarray([3 * BS, 10]), BS, 2)
+    )
+    np.testing.assert_array_equal(ids, [[5, 6, 7, 6], [8, 6, 8, 6]])
+
+
+@pytest.mark.parametrize("seed", [0, 4])
+@pytest.mark.parametrize(
+    "lengths",
+    [[512, 512, 512, 512], [1, 130, 256, 511], [0, 512, 37, 300]],
+)
+def test_paged_attention_matches_reference(lengths, seed):
+    q, kp, vp, tables, lens = _setup(lengths=lengths, seed=seed)
+    got = paged_flash_attention(q, kp, vp, tables, lens, interpret=True)
+    want = reference_paged_partials(q, kp, vp, tables, lens)
+    _assert_matches_reference(got, want, lens)
 
 
 def test_paged_attention_multi_query_chunk():
@@ -93,12 +226,12 @@ def test_paged_matches_dense_flash_decode():
     )
 
 
-def test_layered_pool_matches_per_layer_slice():
+@pytest.mark.parametrize("seed,L", [(11, 3), (12, 2)])
+def test_layered_pool_matches_per_layer_slice(seed, L):
     # the 5-D stacked-pool entry with a layer scalar must equal slicing
     # the layer out and calling the 4-D form
     q, kp, vp, tables, lens = _setup(B=2, Hq=4, Hkv=2, MB=2, NB=8,
-                                     lengths=[200, 77], seed=11)
-    L = 3
+                                     lengths=[200, 77], seed=seed)
     kps = jnp.stack([kp + i for i in range(L)])
     vps = jnp.stack([vp - i for i in range(L)])
     for layer in range(L):
@@ -117,72 +250,20 @@ def test_layered_pool_matches_per_layer_slice():
         )
 
 
-@pytest.mark.parametrize(
-    "lengths", [[512, 512, 512, 512], [1, 130, 256, 511], [0, 512, 37, 300]]
-)
-def test_deep_pipelined_kernel_matches_reference(lengths):
-    """The experimental manual-DMA kernel (deep page-copy ring) must give
-    the same partials as the reference/default kernel."""
-    from areal_tpu.ops.paged_attention import paged_flash_attention_deep
-
-    q, kp, vp, tables, lens = _setup(lengths=lengths, seed=4)
-    acc, m, l = paged_flash_attention_deep(
-        q, kp, vp, tables, lens, interpret=True
-    )
-    acc_r, m_r, l_r = reference_paged_partials(q, kp, vp, tables, lens)
-    valid = np.asarray(lens) > 0
-    out = np.asarray(acc)[valid] / np.asarray(l)[valid][..., None, None]
-    out_r = np.asarray(acc_r)[valid] / np.asarray(l_r)[valid][..., None, None]
-    np.testing.assert_allclose(out, out_r, rtol=3e-3, atol=3e-3)
-    empty = ~valid
-    if empty.any():
-        assert (np.asarray(l)[empty] == 0).all()
-
-
-def test_deep_kernel_ring_wraparound():
-    """Rows spanning MORE pages than the DMA ring is deep: the
-    steady-state refill path (slot reuse, dma_pair(j + NBUF)) must
-    produce correct attention — the core mechanism of the deep kernel,
-    unreachable at <= ring-depth pages."""
-    from areal_tpu.ops.paged_attention import (
-        DEEP_BUFFERS,
-        paged_flash_attention_deep,
-    )
-
-    MB = 2 * DEEP_BUFFERS  # 16 pages per row at ring depth 8
+def test_rows_longer_than_one_grid_step():
+    """Rows of MORE pages than one grid step streams (16 against
+    PAGE_GROUP): the page axis of the grid is longer than 1, a stream
+    walks several pages of one row, and the forward fill runs across
+    rows of 16, 2 and 0 pages."""
+    MB = 4 * PAGE_GROUP
+    lengths = [MB * BS, 2 * BS - 37, 0, MB * BS - 37]
     q, kp, vp, tables, lens = _setup(
-        B=2, Hq=4, Hkv=2, MB=MB, NB=2 * MB + 4,
-        lengths=[MB * BS, MB * BS - 37], seed=13,
+        B=4, Hq=4, Hkv=2, MB=MB, NB=4 * MB + 4, lengths=lengths, seed=13,
+        q_dtype=jnp.bfloat16, engine_tables=True,
     )
-    acc, m, l = paged_flash_attention_deep(
-        q, kp, vp, tables, lens, interpret=True
-    )
-    acc_r, m_r, l_r = reference_paged_partials(q, kp, vp, tables, lens)
-    out = np.asarray(acc) / np.asarray(l)[..., None]
-    out_r = np.asarray(acc_r) / np.asarray(l_r)[..., None]
-    np.testing.assert_allclose(out, out_r, rtol=3e-3, atol=3e-3)
-
-
-def test_deep_kernel_layered_pool():
-    from areal_tpu.ops.paged_attention import paged_flash_attention_deep
-
-    q, kp, vp, tables, lens = _setup(
-        B=2, Hq=4, Hkv=2, MB=2, NB=8, lengths=[200, 77], seed=12
-    )
-    L = 2
-    kps = jnp.stack([kp + i for i in range(L)])
-    vps = jnp.stack([vp - i for i in range(L)])
-    for layer in range(L):
-        acc_d, m_d, l_d = paged_flash_attention_deep(
-            q, kps, vps, tables, lens,
-            layer=jnp.int32(layer), interpret=True,
-        )
-        acc_r, m_r, l_r = reference_paged_partials(
-            q, kps[layer], vps[layer], tables, lens
-        )
-        out = np.asarray(acc_d) / np.asarray(l_d)[..., None]
-        out_r = np.asarray(acc_r) / np.asarray(l_r)[..., None]
-        np.testing.assert_allclose(out, out_r, rtol=3e-3, atol=3e-3)
+    got = paged_flash_attention(q, kp, vp, tables, lens, interpret=True)
+    want = reference_paged_partials(q, kp, vp, tables, lens)
+    _assert_matches_reference(got, want, lens)
 
 
 def test_shared_blocks_between_rows():
