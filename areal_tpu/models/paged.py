@@ -19,8 +19,14 @@ allocator; this module owns the device-side compute:
   one decode-stalling wave (chunked prefill, the round-4 verdict's #1/#2);
 * :func:`paged_decode_chunk` mirrors ``transformer.decode_chunk``'s
   window design (in-chunk KV in a small contiguous window, ONE pool
-  scatter per chunk) with the paged kernel streaming each row's valid
-  blocks — cost scales with the row's true length, not a padded bucket.
+  write per chunk) with the paged kernel streaming each row's valid
+  blocks — cost scales with the row's true length, not a padded bucket;
+* ONE pool write per chunk holds for the fill too: inside both programs'
+  layer loops the pool is a read-only operand of the kernel, in the
+  layout it is stored in, and the chunk's KV (every layer's, stacked)
+  reaches it afterwards through :func:`write_kv_runs`, which keeps that
+  layout.  A scatter in the loop, or a ``(pid, off)`` scatter after it,
+  makes XLA convert the whole pool to the scatter's layout and back.
 
 Every function threads the pool through donated jit args; the layered
 kernel entry reads blocks straight from the stacked pool so no per-layer
@@ -32,8 +38,8 @@ one absmax scale per (block, head, page slot).  The slot axis is what
 makes append-only pages exact: a single per-(block, head) scale would
 need a read-modify-write requantization of the whole block every time
 decode appends one token to the tail page, while per-slot scales let
-every write path quantize just the values it scatters.  Writes quantize
-at insert (:func:`quantize_kv` before the pool scatter in
+every write path quantize just the values it writes.  Writes quantize
+at insert (:func:`quantize_kv` before the pool write in
 :func:`paged_window_forward` / :func:`paged_decode_chunk`'s chunk-end
 merge); reads dequantize inline right after the block gather (the jnp
 reference path and both Pallas kernels multiply by scales before the
@@ -277,6 +283,83 @@ def _prefix_partials(
     )
 
 
+def write_kv_runs(
+    pools: Sequence[jax.Array],  # each [L, NB, Hkv, BS, ...]
+    values: Sequence[jax.Array],  # one per pool, [L, R, T, Hkv, ...]
+    tables: jax.Array,  # [R, MB] pool block ids
+    starts: jax.Array,  # [R] cache position of each row's first value
+    counts: jax.Array,  # [R] values to write per row (the first ones)
+) -> Tuple[jax.Array, ...]:
+    """Write row r's ``values[:, r, :counts[r]]`` to cache positions
+    ``starts[r] ...`` of its table row, in the pool's OWN layout — the
+    one pool write of a prefill chunk (:func:`paged_window_forward`) and
+    of a decode chunk (:func:`paged_decode_chunk`).
+
+    A row's run is consecutive slots of at most ``ceil(T / BS) + 1``
+    pages, so it goes in as one piece per page touched: ``min(T, BS)``
+    slots read from the page, merged with the run's part of them, and
+    put back by ``dynamic_update_slice``, which keeps the operand's
+    layout.  Pieces that hold nothing (short runs, rows with ``counts``
+    0, pages past the table) are never visited: the loop's trip count is
+    the number of live pieces.  Slots outside a run keep their bits.
+
+    Why not a scatter: ``pool.at[:, pid, :, off].set(...)`` makes XLA
+    convert the whole pool to the scatter's preferred layout and back
+    (two pool-sized copies a pool, 70 ms for both pools at the
+    benchmark's shape), and a scatter of single ``hd`` rows, which keeps
+    the layout, spends as long on a fill's 459k row updates; the pieces
+    take 1.1-1.7 ms for a fill of 8 x 1,024 tokens and 1.2-3.0 ms for a
+    decode chunk of 64 rows (my chip runs, PR 28: PERF.md section 6)."""
+    L, _, Hkv, BS = pools[0].shape[:4]
+    T = values[0].shape[2]
+    MB = tables.shape[1]
+    S = min(T, BS)  # slots in a piece
+    P = -(-T // BS) + 1  # pages a run can touch
+    lp = (starts // BS)[:, None] + jnp.arange(P, dtype=jnp.int32)  # [R, P]
+    lo = jnp.maximum(starts[:, None], lp * BS)
+    hi = jnp.minimum((starts + counts)[:, None], (lp + 1) * BS)
+    live = ((hi > lo) & (lp < MB)).reshape(-1)
+    # the piece's first slot in its page: the run's, pulled back so that
+    # S slots fit; and the run index that slot holds (negative: before it)
+    s0 = jnp.clip(lo - lp * BS, 0, BS - S)
+    c0 = (lp * BS + s0 - starts[:, None]).reshape(-1)
+    s0 = s0.reshape(-1)
+    pid = jnp.take_along_axis(
+        tables, jnp.clip(lp, 0, MB - 1), axis=1
+    ).reshape(-1)
+    order = jnp.argsort(~live, stable=True)  # live pieces first
+    iot = jnp.arange(S, dtype=jnp.int32)
+
+    def put(i, pools):
+        j = order[i]
+        r = j // P
+        c = c0[j] + iot
+        keep = (c >= 0) & (c < counts[r])  # [S]
+        out = []
+        for pool, val in zip(pools, values):
+            tail = pool.shape[4:]
+            z = (0,) * len(tail)
+            at = (0, pid[j], 0, s0[j]) + z
+            old = jax.lax.dynamic_slice(pool, at, (L, 1, Hkv, S) + tail)
+            row = jax.lax.dynamic_index_in_dim(val, r, 1)  # [L,1,T,Hkv,..]
+            # the run shifted to the piece's frame: entry i is run index
+            # c0 + i wherever ``keep`` holds (a roll; the wrap is masked)
+            new = jax.lax.dynamic_slice_in_dim(
+                jnp.concatenate([row, row], axis=2), c0[j] % T, S, axis=2
+            ).swapaxes(2, 3)
+            m = keep.reshape((1, 1, 1, S) + (1,) * len(tail))
+            out.append(
+                jax.lax.dynamic_update_slice(
+                    pool, jnp.where(m, new, old), at
+                )
+            )
+        return tuple(out)
+
+    return jax.lax.fori_loop(
+        0, jnp.sum(live, dtype=jnp.int32), put, tuple(pools)
+    )
+
+
 def paged_window_forward(
     params: Params,
     k_pool: jax.Array,  # [L, NB, Hkv, BS, hd]
@@ -284,7 +367,7 @@ def paged_window_forward(
     cfg: TransformerConfig,
     tokens: jax.Array,  # [F, C] window tokens (right-padded)
     starts: jax.Array,  # [F] tokens already cached per row (window offset)
-    valid: jax.Array,  # [F, C] bool: positions to compute + scatter
+    valid: jax.Array,  # [F, C] bool: positions to compute + write
     tables: jax.Array,  # [F, MB] pool block ids
     use_kernel: bool,
     mesh=None,
@@ -295,23 +378,33 @@ def paged_window_forward(
            Optional[jax.Array]]:
     """Forward a short token WINDOW for F rows over their cached paged
     prefixes: in-window causal self-attention merged online with the
-    paged kernel's partials over ``[0, start)``, window KV scattered into
+    paged kernel's partials over ``[0, start)``, window KV written into
     the rows' pool blocks (invalid positions dropped).  Shared core of
     chunked prefill (:func:`paged_fill_chunk`) and the speculative-decode
     verify step (engine/spec_decode.py) — verify IS a batched paged
     prefill of the draft window, so both paths ride the same attention
-    math and the same pool scatter.  Returns ``(x [F, C, D], k_pool,
+    math and the same pool write.  Returns ``(x [F, C, D], k_pool,
     v_pool, k_scale, v_scale)`` with ``x`` the final hidden states
     (pre-head); the scales pass through as None on unquantized pools.
 
-    On an int8 pool the window KV is computed in model dtype, quantized
-    per (token, head) right before the scatter, and its scales land in
-    the scale pools through the same (pid, off) coordinates.
+    ``valid`` is a PREFIX mask: row f's first ``valid[f].sum()`` window
+    positions, which is what both callers build.
+
+    The pools are read-only operands of the layer scan, in the layout
+    the kernel reads them in: no layer reads what this window writes
+    (the kernel attends ``[0, start)``, the window attends itself from
+    registers), so every layer's window KV leaves the scan as its output
+    and reaches the pool in ONE :func:`write_kv_runs` after it (why:
+    the module docstring).
+
+    On an int8 pool the window KV is computed in model dtype and
+    quantized per (token, head) inside the scan; values and scales go
+    through the same write.
 
     Callers jit this (it is not jitted itself); the pools thread through
     donated args of the enclosing jit."""
     F, C = tokens.shape
-    L, NB, Hkv, BS, hd = k_pool.shape
+    L, _, Hkv, _, hd = k_pool.shape
     r = cfg.n_q_heads // Hkv
     positions = starts[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     # masked rows must stream zero prefix blocks (their ``starts`` may be
@@ -329,11 +422,6 @@ def paged_window_forward(
         & valid[:, :, None]
         & (iot[:, None] >= iot[None, :])
     )  # [F, Cq, Ckv] causal
-    # pool write coordinates for every chunk token
-    pid_log = jnp.clip(positions // BS, 0, tables.shape[1] - 1)
-    pid = jnp.take_along_axis(tables, pid_log, axis=1)
-    pid = jnp.where(valid, pid, NB)  # invalid -> OOB -> dropped
-    off = positions % BS
     seg_ids = valid.astype(jnp.int32)
     scale = 1.0 / np.sqrt(hd)
     plan = _prefix_plan(
@@ -341,8 +429,7 @@ def paged_window_forward(
         mesh=mesh, kv_axis=kv_axis, quantized=k_scale is not None,
     )
 
-    def body(carry, xs):
-        x, k_pool, v_pool, k_scale, v_scale = carry
+    def body(x, xs):
         lp, l = xs
         h = _norm(x, lp["attn_norm"], cfg)
         q, k, v = _attn_qkv(cfg, lp, h, positions, rope_cs)
@@ -382,32 +469,25 @@ def paged_window_forward(
         h2 = _norm(x, lp["mlp_norm"], cfg)
         mlp_out, _ = _mlp_block(cfg, lp, h2, seg_ids=seg_ids, mesh=mesh)
         x = x + mlp_out
-        # scatter chunk KV into the pool (in-place on the donated carry);
-        # advanced indices split by the Hkv slice -> result [F, C, Hkv, hd]
+        # this layer's window KV, as the pool stores it: [F, C, Hkv, hd]
+        # (and [F, C, Hkv] scales)
         if k_scale is not None:
             kq, ks = quantize_kv(k)
             vq, vs = quantize_kv(v)
-            k_pool = k_pool.at[l, pid, :, off].set(kq, mode="drop")
-            v_pool = v_pool.at[l, pid, :, off].set(vq, mode="drop")
-            # scale pools [L, NB, Hkv, BS]: same (pid, off) coordinates,
-            # advanced indices split by the Hkv slice -> [F, C, Hkv]
-            k_scale = k_scale.at[l, pid, :, off].set(ks, mode="drop")
-            v_scale = v_scale.at[l, pid, :, off].set(vs, mode="drop")
-        else:
-            k_pool = k_pool.at[l, pid, :, off].set(
-                k.astype(k_pool.dtype), mode="drop"
-            )
-            v_pool = v_pool.at[l, pid, :, off].set(
-                v.astype(v_pool.dtype), mode="drop"
-            )
-        return (x, k_pool, v_pool, k_scale, v_scale), None
+            return x, (kq, vq, ks, vs)
+        return x, (k.astype(k_pool.dtype), v.astype(v_pool.dtype))
 
-    (x, k_pool, v_pool, k_scale, v_scale), _ = jax.lax.scan(
-        body,
-        (x, k_pool, v_pool, k_scale, v_scale),
-        (params["layers"], jnp.arange(L)),
+    x, window_kv = jax.lax.scan(
+        body, x, (params["layers"], jnp.arange(L))
     )
-    return x, k_pool, v_pool, k_scale, v_scale
+    pools = (k_pool, v_pool)
+    if k_scale is not None:
+        pools += (k_scale, v_scale)
+    pools = write_kv_runs(
+        pools, window_kv, tables, starts,
+        jnp.sum(valid, axis=1, dtype=jnp.int32),
+    )
+    return (x, *pools) if k_scale is not None else (x, *pools, None, None)
 
 
 @partial(
@@ -436,9 +516,9 @@ def paged_fill_chunk(
     Each row's chunk tokens attend causally within the chunk AND over the
     row's already-cached prefix ``[0, start)`` via paged partials — an
     exact continuation of the row's prefill no matter how the prompt was
-    split into chunks.  Chunk KV is scattered into the rows' pool blocks
+    split into chunks.  Chunk KV is written into the rows' pool blocks
     (the engine pre-allocated blocks covering ``start + chunk_len``);
-    int8 pools quantize at the scatter and land scales alongside.
+    int8 pools quantize at the write and land scales alongside.
 
     Returns ``(last_logits [F, V], k_pool, v_pool)`` — plus ``(k_scale,
     v_scale)`` when the pool is quantized — logits at each row's LAST
@@ -515,7 +595,7 @@ def paged_decode_chunk(
     )
     B = cur_tokens.shape[0]
     W = chunk_size
-    L, NB, Hkv, BS, hd = k_pool.shape
+    L, _, Hkv, _, hd = k_pool.shape
     r = cfg.n_q_heads // Hkv
     base_lens = lengths  # frozen: pool-resident prefix per row
     # dead rows stream nothing (parked/freed rows keep their lengths)
@@ -534,8 +614,8 @@ def paged_decode_chunk(
     wvalid0 = jnp.zeros((W, B), bool)
 
     def step(i, st):
-        (lengths_, cur, active, budgets, k_pool, v_pool, wk, wv, wvalid,
-         out_t, out_l, emitted, rng) = st
+        (lengths_, cur, active, budgets, wk, wv, wvalid, out_t, out_l,
+         emitted, rng) = st
         positions = lengths_[:, None]
         x = _embed(params, cfg, cur[:, None], positions)
         rope_cs = (
@@ -612,43 +692,30 @@ def paged_decode_chunk(
         active = (
             active & ~stop_fn(tok) & (budgets > 0) & (new_lengths < max_len)
         )
-        return (new_lengths, tok, active, budgets, k_pool, v_pool, wk, wv,
-                wvalid, out_t, out_l, emitted, rng)
+        return (new_lengths, tok, active, budgets, wk, wv, wvalid, out_t,
+                out_l, emitted, rng)
 
     out_t = jnp.zeros((B, W), jnp.int32)
     out_l = jnp.zeros((B, W), jnp.float32)
     emitted = jnp.zeros((B, W), bool)
-    st = (base_lens, cur_tokens, active, budgets, k_pool, v_pool, wk, wv,
-          wvalid0, out_t, out_l, emitted, rng)
-    (lengths_, cur, active, budgets, k_pool, v_pool, wk, wv, wvalid,
-     out_t, out_l, emitted, rng) = jax.lax.fori_loop(0, W, step, st)
+    st = (base_lens, cur_tokens, active, budgets, wk, wv, wvalid0, out_t,
+          out_l, emitted, rng)
+    (lengths_, cur, active, budgets, wk, wv, _, out_t, out_l, emitted,
+     rng) = jax.lax.fori_loop(0, W, step, st)
 
-    # merge the window into pool blocks: ONE scatter per chunk
-    offs = base_lens[None, :] + jnp.cumsum(
-        wvalid.astype(jnp.int32), axis=0
-    ) - wvalid.astype(jnp.int32)  # [W, B] absolute slot per window entry
-    b_idx = jnp.broadcast_to(jnp.arange(B)[None, :], (W, B))
-    pid_log = jnp.clip(offs // BS, 0, tables.shape[1] - 1)
-    pid = tables[b_idx, pid_log]  # [W, B]
-    pid = jnp.where(wvalid, pid, NB)  # invalid -> OOB -> dropped
-    off = offs % BS
-    # advanced indices split by the Hkv slice -> result [W, B, L, Hkv, hd]
-    val_k = wk.transpose(1, 2, 0, 3, 4)
-    val_v = wv.transpose(1, 2, 0, 3, 4)
+    # merge the window into pool blocks: ONE write per chunk.  A row is
+    # live for its first ``lengths_ - base_lens`` steps (``active`` only
+    # ever falls), so its window entries are one run from ``base_lens``
+    pools = (k_pool, v_pool)
+    vals = (wk.swapaxes(1, 2), wv.swapaxes(1, 2))  # [L, B, W, Hkv, hd]
     if k_scale is not None:
-        kq, ks = quantize_kv(val_k)
-        vq, vs = quantize_kv(val_v)
-        k_pool = k_pool.at[:, pid, :, off].set(kq, mode="drop")
-        v_pool = v_pool.at[:, pid, :, off].set(vq, mode="drop")
-        # scale pools [L, NB, Hkv, BS]: same coordinates -> [W, B, L, Hkv]
-        k_scale = k_scale.at[:, pid, :, off].set(ks, mode="drop")
-        v_scale = v_scale.at[:, pid, :, off].set(vs, mode="drop")
-        return (k_pool, v_pool, lengths_, out_t, out_l, emitted, cur,
-                active, budgets, rng, k_scale, v_scale)
-    k_pool = k_pool.at[:, pid, :, off].set(val_k, mode="drop")
-    v_pool = v_pool.at[:, pid, :, off].set(val_v, mode="drop")
+        (kq, ks), (vq, vs) = quantize_kv(vals[0]), quantize_kv(vals[1])
+        pools, vals = pools + (k_scale, v_scale), (kq, vq, ks, vs)
+    k_pool, v_pool, *scales = write_kv_runs(
+        pools, vals, tables, base_lens, lengths_ - base_lens
+    )
     return (k_pool, v_pool, lengths_, out_t, out_l, emitted, cur, active,
-            budgets, rng)
+            budgets, rng, *scales)
 
 
 @jax.jit

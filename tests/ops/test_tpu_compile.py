@@ -13,6 +13,8 @@ worker that is handed this file may load the TPU library.  Keep every
 such test in THIS file for the same reason.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -259,3 +261,175 @@ def test_vmem_plan_raises_when_nothing_fits():
     """A shape the kernel cannot take raises where it is chosen."""
     with pytest.raises(ValueError, match="VMEM"):
         pa._plan_tiles(256, 8, 64, 4096, 256, 2, False, 4)
+
+
+# --- the serving programs whole: where the KV pool's layout goes ---------
+
+#: the rollout cell's engine (benchmark/traffic/rollout-longtail.json):
+#: 192 pages of 1,024 tokens, rows of 4 pages, prefill chunks of 8 x 1,024
+#: tokens, decode chunks of 64 steps for 64 rows
+CELL_NB, FILL_F, FILL_C, DECODE_B, DECODE_W = 192, 8, 1024, 64, 64
+#: hidden, intermediate, vocabulary, tied embedding, at the published widths
+WIDTHS = {
+    "qwen2.5-1.5b": (1536, 8960, 151936, True),
+    "qwen2.5-7b": (3584, 18944, 152064, False),
+}
+
+
+def _serving_program_args(model, n_layers, quantized, place):
+    """``(cfg, params, pool args, scale kwargs)`` of a serving program,
+    shapes only; ``place(shape, dtype, spec)`` attaches the sharding."""
+    Hq, Hkv = HEADS[model]
+    hidden, inter, vocab, tied = WIDTHS[model]
+    cfg = TransformerConfig(
+        n_layers=n_layers, hidden_dim=hidden, n_q_heads=Hq, n_kv_heads=Hkv,
+        head_dim=HD, intermediate_dim=inter, vocab_size=vocab,
+        use_attention_bias=True, tied_embedding=tied, rotary_base=1e6,
+    )
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    params = jax.tree.map(
+        lambda a, spec: place(a.shape, jnp.bfloat16, spec),
+        shapes, transformer.param_pspecs(cfg, shapes),
+    )
+    pool = place(
+        (n_layers, CELL_NB, Hkv, PAGE, HD),
+        jnp.int8 if quantized else jnp.bfloat16,
+        P(None, None, "model"),
+    )
+    scale = place(
+        (n_layers, CELL_NB, Hkv, PAGE), jnp.float32, P(None, None, "model")
+    )
+    scales = {"k_scale": scale, "v_scale": scale} if quantized else {}
+    return cfg, params, pool, scales
+
+
+def _pool_copies(compiled, pool_shape):
+    """``computation: instruction`` of every ``copy`` in the optimized
+    HLO whose result has the pool's dimensions: a conversion of the
+    whole pool between two layouts (``{minor_to_major:tiles}``), which
+    no trace is needed to find (docs/observability.md)."""
+    dims = ",".join(str(d) for d in pool_shape)
+    found, computation = [], None
+    for line in compiled.as_text().splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            computation = ("ENTRY " if head.group(1) else "") + head.group(2)
+        m = re.match(
+            r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* copy\(", line
+        )
+        if m and m.group(2) == dims:
+            found.append(f"{computation}: {m.group(1)}")
+    return found
+
+
+def _assert_pool_stays_put(compiled, pool, temp_share):
+    shape = pool.sharding.shard_shape(pool.shape)
+    assert _pool_copies(compiled, shape) == []
+    one_pool = int(np.prod(shape)) * jnp.dtype(pool.dtype).itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < temp_share * one_pool, (temp, one_pool)
+
+
+SERVING_PROGRAMS = [
+    # model, layers, int8 pool, chips
+    ("qwen2.5-1.5b", 28, False, 1),
+    ("qwen2.5-1.5b", 28, True, 1),
+    ("qwen2.5-7b", 12, False, 1),
+    ("qwen2.5-1.5b", 28, False, 2),
+]
+
+
+def _place_on(topo, chips):
+    if chips == 1:
+        one = SingleDeviceSharding(topo.devices[0])
+        return None, lambda shape, dtype, _spec: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=one
+        )
+    # the serving mesh of a TP engine: weights, pool and scale pools
+    # split over "model" (engine/inference_server.py)
+    mesh = Mesh(
+        np.array(topo.devices[:chips]).reshape(1, chips), ("fsdp", "model")
+    )
+    return mesh, lambda shape, dtype, spec: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, spec)
+    )
+
+
+@pytest.mark.parametrize("model,n_layers,quantized,chips", SERVING_PROGRAMS)
+def test_fill_program_keeps_the_pool_layout(
+    topo, monkeypatch, model, n_layers, quantized, chips
+):
+    """``paged_fill_chunk`` whole, at the rollout cell's shape: the
+    optimized HLO holds NO copy of a KV pool, in the layer loop or in
+    ``ENTRY``, and its temporaries cannot hold one.
+
+    On the parent of PR 28 the bf16 program at 28 layers held SIX
+    (``temp_size_in_bytes`` 6.03 GB): ``copy.151`` / ``copy.152`` in
+    ``ENTRY`` took each pool from its own layout
+    ``{4,3,2,1,0:T(8,128)(2,1)}`` to the one the in-scan scatter wanted,
+    ``{4,2,3,1,0:T(2,128)(2,1)}``; ``copy.224.remat2`` /
+    ``copy.225.remat2`` INSIDE the layer loop's body converted each back
+    for the kernel, so every layer rewrote all 28 layers of both pools
+    (31% of the rollout cell's device time, ledger, PR 25); ``copy.155``
+    / ``copy.156`` after the loop restored the donated outputs' layout.
+    The 7B heads did not compile at 12 layers at all (16.24 of 15.75 GB,
+    my chip run, PR 23).  What is left are the chunk's own
+    temporaries, most of them the in-chunk scores (two float32
+    ``[F, Hq, C, C]``, 0.8 GB at 8 x 1,024 tokens and 12 heads)."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    mesh, place = _place_on(topo, chips)
+    cfg, params, pool, scales = _serving_program_args(
+        model, n_layers, quantized, place
+    )
+    compiled = paged.paged_fill_chunk.lower(
+        params, pool, pool, cfg,
+        place((FILL_F, FILL_C), jnp.int32, P()),
+        place((FILL_F,), jnp.int32, P()),
+        place((FILL_F,), jnp.int32, P()),
+        place((FILL_F, MB), jnp.int32, P()),
+        use_kernel=True, mesh=mesh,
+        kv_axis=None if mesh is None else "model", **scales,
+    ).compile()
+    _assert_kernel(compiled)
+    _assert_pool_stays_put(compiled, pool, temp_share=1.0)
+
+
+def _greedy(logits, _rng):
+    return jnp.argmax(logits, -1).astype(jnp.int32), jnp.max(logits, -1)
+
+
+def _never_stop(tokens):
+    return jnp.zeros_like(tokens, bool)
+
+
+@pytest.mark.parametrize("model,n_layers,quantized,chips", SERVING_PROGRAMS)
+def test_decode_program_keeps_the_pool_layout(
+    topo, monkeypatch, model, n_layers, quantized, chips
+):
+    """``paged_decode_chunk`` whole (64 rows, 64 steps): no copy of a KV
+    pool anywhere.  The parent of PR 28 held four in ``ENTRY``, around
+    its chunk-end scatter (``copy.126`` / ``copy.137`` to
+    ``{4,3,1,2,0:T(8,128)(2,1)}``, ``copy.142`` / ``copy.143`` back;
+    ``temp_size_in_bytes`` 2.94 GB), and none in the loop."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    mesh, place = _place_on(topo, chips)
+    cfg, params, pool, scales = _serving_program_args(
+        model, n_layers, quantized, place
+    )
+
+    def rows(dtype):
+        return place((DECODE_B,), dtype, P())
+
+    compiled = paged.paged_decode_chunk.lower(
+        params, pool, pool, cfg,
+        place((DECODE_B, MB), jnp.int32, P()),
+        rows(jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.int32),
+        place((2,), jnp.uint32, P()),
+        chunk_size=DECODE_W, sample_fn=_greedy, stop_fn=_never_stop,
+        use_kernel=True, max_len=PAGE * MB, mesh=mesh,
+        kv_axis=None if mesh is None else "model", **scales,
+    ).compile()
+    _assert_kernel(compiled)
+    _assert_pool_stays_put(compiled, pool, temp_share=0.25)
