@@ -157,15 +157,14 @@ class SpecDecodeConfig:
     ngram_max: int = 3
     ngram_min: int = 1
     # acceptance-rate EMA below which a row falls back to plain decode;
-    # None = the measured default in engine/dispatch.py (bench.py's
-    # spec_decode_ab derives the break-even rate for the hardware)
+    # None = engine/spec_decode.py's DEFAULT_SPEC_MIN_ACCEPT_RATE
     min_accept_rate: Optional[float] = None
     ema_decay: float = 0.9
     # verifies before the fallback threshold may fire
     warmup_verifies: int = 4
-    # measured cost of one verify pass in plain-decode-step units (the
-    # per-step batch vote's c); None = engine/dispatch.py default.  Pin
-    # it from bench.py spec_decode_ab's verify_cost_over_decode_step
+    # cost of one verify pass in plain-decode-step units (the per-step
+    # batch vote's c); None = engine/spec_decode.py's
+    # DEFAULT_SPEC_VERIFY_COST (no chip run has measured it)
     verify_cost_over_decode_step: Optional[float] = None
 
 
@@ -202,9 +201,10 @@ class GenServerConfig:
     # slot) f32 scales alongside — ~half the HBM per cached token (~2x
     # live rows / prefix-cache capacity / half-cost host spills at the
     # same budget), reads dequantize inline so the error is
-    # storage-only.  Quality is MEASURED, not assumed: bench.py's
-    # kv_quant_ab section reports the greedy divergence rate per
-    # workload and the fleet exports areal_inference_kv_quant_* series.
+    # storage-only.  Quality is pinned, not assumed:
+    # tests/engine/test_kv_quant.py holds the greedy divergence rate on
+    # a multi-turn replay under a bar, and the fleet exports the
+    # areal_inference_kv_quant_* series.
     kv_cache_dtype: str = "auto"
     # serving WEIGHT storage dtype (the SGLang --quantization / vLLM
     # quantized-weight-loading knob): "auto" serves the model-dtype
@@ -219,9 +219,9 @@ class GenServerConfig:
     # full-precision tree (restored full, quantized on arrival), never
     # a crash.  Dequantization happens at use inside each projection,
     # so matmul math stays model dtype and the error is storage-only —
-    # measured, not assumed: bench.py weight_quant_ab reports the
-    # greedy divergence rate per workload and the fleet exports the
-    # areal_inference_weight_quant_* series.
+    # pinned, not assumed: tests/engine/test_weight_quant.py holds the
+    # greedy divergence rate on a replay under a bar, and the fleet
+    # exports the areal_inference_weight_quant_* series.
     serving_weight_dtype: str = "auto"
     prefill_chunk_tokens: int = 1024
     # cross-request radix prefix cache over the paged pool (default on
@@ -303,8 +303,8 @@ class GenServerConfig:
     # request-level SLO plane (observability/latency.py): per-request
     # latency decomposition (schedule/admission wait, TTFT, TPOT,
     # swap/preempt stall) streamed into mergeable percentile digests and
-    # exported as the areal_slo_* families.  Off = the bench A/B's
-    # baseline arm; overhead is a few clock stamps per request.
+    # exported as the areal_slo_* families.  Overhead is a few clock
+    # stamps per request.
     slo_tracking: bool = True
     # decode-pipeline depth: max chunks dispatched-but-unharvested (the
     # engine's in-flight ring).  2 overlaps each chunk's output fetch
@@ -312,10 +312,6 @@ class GenServerConfig:
     # exceeds a chunk's device time.  1 =
     # unpipelined baseline.
     pipeline_depth: int = 2
-    # measured dispatch-table override for cache_mode="auto" (None =
-    # builtin default / bench-derived value from engine/dispatch.py):
-    # paged_min_cache_len switches dense->paged by kv_cache_len
-    paged_min_cache_len: Optional[int] = None
     # recompile sentinel (observability/compile_watch.py): engine steps
     # after which the serving loop is declared steady-state — any
     # decode/fill-path XLA compile from then on fires

@@ -383,7 +383,10 @@ def _shutdown_workers(sched, cfg, specs, panel, master_name):
             remove_status=(JobState.COMPLETED, JobState.CANCELLED),
         )
     except TimeoutError:
-        logger.warning("workers still running after master exit; killing")
+        logger.warning(
+            "workers still running after master exit; killing %s",
+            [j.name for j in sched.find_all() if j.state == JobState.RUNNING],
+        )
     finally:
         sched.stop_all()
 

@@ -119,8 +119,9 @@ def clear_time_marks():
 
 #: dense bf16 peak TFLOP/s per chip, keyed by substrings of
 #: ``device.device_kind`` ("TPU v5 lite" is how a v5e reports itself).
-#: THE table for every MFU denominator in the repo (bench.py reads it
-#: through :func:`device_peak_flops`).  Source: Google Cloud TPU
+#: THE table for every MFU denominator in the program, read through
+#: :func:`device_peak_flops` (``benchmark/lib/peaks.py`` keeps the
+#: yardstick's own copy).  Source: Google Cloud TPU
 #: documentation, "System architecture" page of each generation
 #: (cloud.google.com/tpu/docs/v5e: 197 TFLOP/s bf16, 16 GB HBM at
 #: 819 GB/s; .../v4: 275; .../v5p: 459; .../v6e: 918; .../v3: 123).

@@ -1,8 +1,7 @@
 """Bounded execution of one named section/phase in a daemon thread.
 
-Shared core of ``bench.py``'s ``_section`` and ``__graft_entry__``'s
-dryrun ``_phase``: run ``fn`` in a daemon thread, join for ``timeout_s``,
-and report ``{status: ok|error|timeout, seconds[, result|error]}`` — so
+The core of ``__graft_entry__``'s dryrun ``_phase``: run ``fn`` in a
+daemon thread, join for ``timeout_s``, and report ``{status: ok|error|timeout, seconds[, result|error]}`` — so
 one hung or crashing section forfeits its own numbers instead of eating
 the whole run's budget (a dryrun once died at rc=124 with no way to tell
 which phase hung).

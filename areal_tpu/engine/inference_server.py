@@ -69,10 +69,6 @@ from areal_tpu.api import model_api
 from areal_tpu.base import jax_compat, logging_
 from areal_tpu.engine import spec_decode
 from areal_tpu.engine.batching import bucket_len, spec_window_bucket
-from areal_tpu.engine.dispatch import (
-    DEFAULT_PAGED_MIN_CACHE_LEN,
-    PagedDispatchTable,
-)
 from areal_tpu.engine.prefix_cache import PrefixMatch, RadixPrefixCache
 from areal_tpu.engine.sampling import SamplingParams, sample_logits_keyed
 from areal_tpu.models import paged, quantize
@@ -87,10 +83,9 @@ from areal_tpu.observability.latency import LatencyDigest, LatencyRecord
 from areal_tpu.observability.table import ENGINE_PHASES
 from areal_tpu.observability.tracing import PhaseClock, get_tracer
 
-#: back-compat alias: the auto dense/paged crossover now lives in the
-#: (config-overridable, bench-derivable) dispatch table — see
-#: areal_tpu/engine/dispatch.py
-PAGED_MIN_CACHE_LEN = DEFAULT_PAGED_MIN_CACHE_LEN
+#: ``cache_mode="auto"``: dense rows below this ``kv_cache_len`` (short
+#: prefixes amortize no paging), the paged block pool at and above it
+PAGED_MIN_CACHE_LEN = 2048
 
 
 @partial(jax.jit, static_argnames=("sampling", "mesh"))
@@ -422,7 +417,6 @@ class ContinuousBatchingEngine:
         serving_weight_dtype: str = "auto",
         prefill_chunk_tokens: int = 1024,
         pipeline_depth: int = 2,
-        dispatch_table: Optional[PagedDispatchTable] = None,
         prefix_cache: bool = True,
         prefix_cache_capacity_frac: float = 0.5,
         prefix_cache_min_tokens: int = 1,
@@ -443,8 +437,8 @@ class ContinuousBatchingEngine:
         ``cache_mode``: "dense" keeps per-row ``[max_batch, kv_cache_len]``
         KV; "paged" uses a shared block pool + block tables (capacity in
         ``page_size``-token pages, chunked prefill, block-shared group
-        prompts); "auto" consults ``dispatch_table`` (default: paged at
-        ``kv_cache_len >= 2048``) for global-attention models.
+        prompts); "auto" is paged at ``kv_cache_len >=
+        PAGED_MIN_CACHE_LEN`` for global-attention models.
 
         ``pipeline_depth``: max decode chunks dispatched-but-unharvested
         (the in-flight ring).  K=1 is the unpipelined baseline (dispatch
@@ -495,9 +489,9 @@ class ContinuousBatchingEngine:
         (reads dequantize inline; attention math stays in model dtype).
         Every pool path carries the scales: fill/decode/verify writes
         quantize at the scatter, COW tail copies, host-tier spills, and
-        swap-ins move int8 bytes + scales together.  The bench's
-        kv_quant_ab section measures the token-quality delta; dense
-        mode ignores the knob with a warning.
+        swap-ins move int8 bytes + scales together.
+        tests/engine/test_kv_quant.py pins the token-quality delta;
+        dense mode ignores the knob with a warning.
 
         ``serving_weight_dtype`` ("auto" | "int8"): "auto" serves the
         param tree exactly as passed (bit-for-bit today's behavior);
@@ -507,7 +501,7 @@ class ContinuousBatchingEngine:
         weight HBM (freed for paged blocks / prefix cache) and ~half
         the bytes a staged weight swap restores, at the cost of
         storage-rounding error (matmul math stays at activation dtype;
-        the bench's weight_quant_ab section measures the token-quality
+        tests/engine/test_weight_quant.py pins the token-quality
         delta).  Works on every path — dense, paged, TP/EP meshes —
         because the forward reads weights through one format-agnostic
         accessor.  Incoming swap trees must arrive in the engine's
@@ -545,7 +539,6 @@ class ContinuousBatchingEngine:
         assert cache_mode in ("auto", "dense", "paged"), cache_mode
         assert pipeline_depth >= 1, pipeline_depth
         self.pipeline_depth = pipeline_depth
-        self.dispatch_table = dispatch_table or PagedDispatchTable()
         self._prefix_cache: Optional[RadixPrefixCache] = None
         self._prefix_cache_enabled = bool(prefix_cache)
         self._prefix_cache_capacity_frac = prefix_cache_capacity_frac
@@ -553,7 +546,7 @@ class ContinuousBatchingEngine:
         self._prefix_cache_host_bytes = max(0, int(prefix_cache_host_bytes))
         self.paged = cache_mode == "paged" or (
             cache_mode == "auto"
-            and kv_cache_len >= self.dispatch_table.paged_min_cache_len
+            and kv_cache_len >= PAGED_MIN_CACHE_LEN
             and cfg.sliding_window is None
         )
         assert kv_cache_dtype in ("auto", "int8"), kv_cache_dtype
@@ -571,8 +564,8 @@ class ContinuousBatchingEngine:
         self._weight_quant = serving_weight_dtype == "int8"
         # quantized-serving-weight quality counters (the
         # areal_inference_weight_quant_* divergence series): external
-        # parity harnesses (bench weight_quant_ab, tests) fold their
-        # measured greedy-divergence checks in here
+        # parity harnesses fold their measured greedy-divergence checks
+        # in here
         self.weight_quant_divergence_checks_total = 0
         self.weight_quant_divergence_diverged_total = 0
         # abstract full-precision tree template (int8 engines only):
@@ -596,9 +589,8 @@ class ContinuousBatchingEngine:
         self.k_scale: Optional[jax.Array] = None
         self.v_scale: Optional[jax.Array] = None
         # quantized-serving quality counters: external parity harnesses
-        # (bench kv_quant_ab, tests) fold their greedy divergence checks
-        # in here so the fleet's metrics carry measured quality, not
-        # assumptions
+        # fold their greedy divergence checks in here so the fleet's
+        # metrics carry measured quality, not assumptions
         self.kv_quant_divergence_checks_total = 0
         self.kv_quant_divergence_diverged_total = 0
         if self.paged and cfg.sliding_window is not None:
@@ -911,7 +903,6 @@ class ContinuousBatchingEngine:
         # log buckets.  Host-side telemetry only — a few monotonic-clock
         # stamps per request lifecycle event, nothing on the per-token
         # path and nothing dispatch decisions read (SPMD-safe).
-        # ``slo_tracking=False`` is the bench A/B's off arm.
         self._slo_enabled = bool(slo_tracking)
         self.server_name = server_name
         self.slo_records_total = 0
@@ -1127,9 +1118,9 @@ class ContinuousBatchingEngine:
             self.k_pool, self.v_pool = out
 
     def note_kv_divergence_check(self, checked: int, diverged: int):
-        """Fold a measured greedy-divergence check (bench kv_quant_ab /
-        parity tests compare an int8 arm against an fp arm token by
-        token) into the engine's cumulative quality counters — the
+        """Fold a measured greedy-divergence check (a parity harness
+        compares an int8 arm against an fp arm token by token) into the
+        engine's cumulative quality counters — the
         ``areal_inference_kv_quant_*`` divergence series."""
         self.kv_quant_divergence_checks_total += int(checked)
         self.kv_quant_divergence_diverged_total += int(diverged)
@@ -1157,9 +1148,9 @@ class ContinuousBatchingEngine:
         }
 
     def note_weight_divergence_check(self, checked: int, diverged: int):
-        """Fold a measured greedy-divergence check (bench weight_quant_ab
-        / parity tests compare an int8-weight arm against a
-        full-precision arm token by token) into the engine's cumulative
+        """Fold a measured greedy-divergence check (a parity harness
+        compares an int8-weight arm against a full-precision arm token
+        by token) into the engine's cumulative
         quality counters — the ``areal_inference_weight_quant_*``
         divergence series."""
         self.weight_quant_divergence_checks_total += int(checked)
@@ -1167,7 +1158,7 @@ class ContinuousBatchingEngine:
 
     def weight_quant_stats(self) -> Dict[str, int]:
         """Quantized-serving-weight counters (worker scrape + metrics
-        RPC + bench): resident format, storage bits, quantized-leaf
+        RPC): resident format, storage bits, quantized-leaf
         count, the param tree's HBM byte footprint, and the measured
         divergence-check counters."""
         quantized = quantize.is_quantized_tree(self.params)
@@ -1761,7 +1752,7 @@ class ContinuousBatchingEngine:
 
     def drain_handoff_segments(self) -> List[Dict[str, Any]]:
         """Pop the outbound export segments (worker poll loop; in-process
-        drivers — bench, dryrun, tests — pump them straight into the
+        drivers — dryrun, tests — pump them straight into the
         decode engine).  Payloads are still device arrays; the pusher
         materializes them (``jax.device_get``) off the engine thread."""
         out = self._handoff_segments
@@ -1987,8 +1978,7 @@ class ContinuousBatchingEngine:
         return backlog
 
     def handoff_stats(self) -> Dict[str, Any]:
-        """Cumulative KV-handoff counters (worker scrape + metrics RPC +
-        bench)."""
+        """Cumulative KV-handoff counters (worker scrape + metrics RPC)."""
         return {
             "exports_total": self.handoff_exports_total,
             "imports_total": self.handoff_imports_total,
@@ -2292,7 +2282,7 @@ class ContinuousBatchingEngine:
 
     def prefix_peer_stats(self) -> Dict[str, Any]:
         """Cumulative fleet-fabric pull counters (worker scrape +
-        metrics RPC + bench)."""
+        metrics RPC)."""
         return {
             "pulls_total": self.prefix_peer_pulls_total,
             "pull_bytes_total": self.prefix_peer_pull_bytes_total,
@@ -2391,8 +2381,8 @@ class ContinuousBatchingEngine:
         return out
 
     def slo_stats(self) -> Dict[str, Any]:
-        """Percentile summary of the engine-local digests (metrics RPC +
-        bench); ``digests`` carries the mergeable raw state."""
+        """Percentile summary of the engine-local digests (metrics RPC);
+        ``digests`` carries the mergeable raw state."""
         return {
             "records_total": self.slo_records_total,
             **{k: d.percentiles() for k, d in self._slo_digests.items()},
@@ -2705,7 +2695,7 @@ class ContinuousBatchingEngine:
             self._ledger_sync_staged_locked()
 
     def swap_stats(self) -> Dict[str, float]:
-        """Cumulative weight-swap counters (worker scrape + bench)."""
+        """Cumulative weight-swap counters (worker scrape)."""
         return {
             "stage_s": self.swap_stage_s,
             "pause_s": self.swap_pause_s,

@@ -1,8 +1,9 @@
 """Self-speculative decoding for the paged serving engine.
 
-Decode is the rollout bottleneck: BENCH_r04 measured ~6.4k decode tok/s
-against ~38k prefill tok/s at b64 on one v5e — the engine's prefill
-machinery sits ~6x faster than the loop that actually produces tokens.
+Decode is the rollout bottleneck: a July run on a 0.5B model measured
+~6.4k decode tok/s against ~38k prefill tok/s at b64 on one v5e — the
+engine's prefill machinery sits ~6x faster than the loop that actually
+produces tokens.
 Speculative decoding converts that prefill-rate surplus into decode
 throughput, and RL math/code traces are repetitive enough that no draft
 model is needed: each row DRAFTS its own continuation by n-gram /
@@ -46,14 +47,24 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from areal_tpu.engine.dispatch import (
-    DEFAULT_SPEC_MIN_ACCEPT_RATE,
-    DEFAULT_SPEC_VERIFY_COST,
-)
 from areal_tpu.engine.sampling import SamplingParams, sample_logits
 from areal_tpu.models import paged
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import _head
+
+#: acceptance-rate EMA below which a row's drafts are judged not worth
+#: verifying and the row falls back to plain chunked decode.  Conservative
+#: on purpose: at 7 drafts it only ejects rows whose windows verify about
+#: 2 tokens or fewer a pass.
+DEFAULT_SPEC_MIN_ACCEPT_RATE = 0.2
+
+#: cost of one verify pass in plain-decode-step units.  The per-step batch
+#: vote dispatches a verify instead of a decode chunk only when the
+#: EMA-expected emitted tokens a pass exceed this times the live rows (the
+#: pass out-emits the decode steps it displaces).  A window runs at
+#: prefill arithmetic intensity, so on TPU it sits near 1-2; no chip run
+#: has measured it.
+DEFAULT_SPEC_VERIFY_COST = 2.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,16 +90,16 @@ class SpecDecodeParams:
     #: verifies before the fallback threshold may fire (one unlucky
     #: first window must not disable a row for its whole generation)
     warmup_verifies: int = 4
-    #: measured verify-pass cost in plain-decode-step units; the batch
-    #: vote dispatches a verify only when the EMA-expected emission
-    #: beats this per live row (engine/dispatch.py owns the default)
+    #: verify-pass cost in plain-decode-step units; the batch vote
+    #: dispatches a verify only when the EMA-expected emission beats
+    #: this per live row
     verify_cost_over_decode_step: float = DEFAULT_SPEC_VERIFY_COST
 
 
 def resolve_spec_params(cfg_block) -> Optional[SpecDecodeParams]:
     """Map a ``GenServerConfig.spec_decode`` block (or None) to engine
-    params; a ``min_accept_rate`` of None keeps the measured default from
-    ``engine/dispatch.py``."""
+    params; a ``min_accept_rate`` or ``verify_cost_over_decode_step`` of
+    None keeps this module's default."""
     if cfg_block is None or not getattr(cfg_block, "enabled", False):
         return None
     thr = getattr(cfg_block, "min_accept_rate", None)

@@ -63,11 +63,8 @@ class AsyncPPOMathExperiment(PPOMathExperiment):
     gen_page_size: int = 1024
     gen_kv_pool_tokens: Optional[int] = None
     gen_prefill_chunk_tokens: int = 1024
-    # decode-pipeline ring depth (chunks in flight; 1 = unpipelined) and
-    # the measured dispatch-table override (None = engine/dispatch.py
-    # default; pin the value a bench.py decode A/B derived for this chip)
+    # decode-pipeline ring depth (chunks in flight; 1 = unpipelined)
     gen_pipeline_depth: int = 2
-    gen_paged_min_cache_len: Optional[int] = None
     # device index hosting each gen server's engine (trainer/gen split)
     gen_device_start: Optional[int] = None
     success_rate_lb: float = 0.0
@@ -156,7 +153,6 @@ class AsyncPPOMathExperiment(PPOMathExperiment):
                 kv_pool_tokens=self.gen_kv_pool_tokens,
                 prefill_chunk_tokens=self.gen_prefill_chunk_tokens,
                 pipeline_depth=self.gen_pipeline_depth,
-                paged_min_cache_len=self.gen_paged_min_cache_len,
                 device_idx=(
                     self.gen_device_start + i * gen_tp
                     if self.gen_device_start is not None

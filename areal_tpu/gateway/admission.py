@@ -3,7 +3,7 @@ limits, and cumulative token budgets, with TYPED reject reasons.
 
 The plane is pure host-side Python (no jax, no zmq) so it can live
 inside the gserver manager's scheduling path, inside an in-process
-gateway backend (bench/dryrun), and inside unit tests unchanged.  Every
+gateway backend (dryrun), and inside unit tests unchanged.  Every
 time-dependent method takes an explicit ``now`` so the refill math is
 deterministic under test; production callers pass ``time.monotonic()``.
 

@@ -16,8 +16,8 @@ pressure).
 Two backends speak the same five-call protocol (admit / submit / poll
 / cancel / finish):
 
-* :class:`EngineBackend` — in-process engines, used by tests, bench's
-  ``gateway_ab`` load generator, and the dryrun's gateway phase.  The
+* :class:`EngineBackend` — in-process engines, used by tests and the
+  dryrun's gateway phase.  The
   caller (or :meth:`EngineBackend.start_pump`) steps the engines;
   cancels queue and apply on the stepping thread (the engine's cancel
   rewrites pool state and must never race a step).
@@ -28,7 +28,7 @@ Two backends speak the same five-call protocol (admit / submit / poll
   settles tenant budgets back through the manager.
 
 A client disconnect mid-stream cancels the engine row and releases its
-blocks (leak-audited in tests/bench).
+blocks (leak-audited in tests).
 """
 
 from __future__ import annotations
@@ -88,8 +88,8 @@ class EngineBackend:
 
     def admit(self, tenant: str, est_tokens: float) -> Dict[str, Any]:
         if self.plane is None:
-            # admission plane off (the bench A/B's baseline arm): every
-            # request admitted, no priority class stamped
+            # admission plane off: every request admitted, no priority
+            # class stamped
             return {"ok": True, "tenant": tenant, "priority": ""}
         return self.plane.admit(tenant, est_tokens, time.monotonic()).as_dict()
 
@@ -355,7 +355,7 @@ class FleetBackend:
         )
 
 
-# -- request lifecycle (transport-agnostic: HTTP handler + bench) -----------
+# -- request lifecycle (transport-agnostic) ---------------------------------
 
 
 def run_request(
@@ -373,7 +373,7 @@ def run_request(
 ) -> Dict[str, Any]:
     """Submit one admitted request and drive it to completion, invoking
     ``on_chunk`` with each incremental token batch (streaming mode).
-    ``pump`` lets a single-threaded caller (bench, dryrun) step the
+    ``pump`` lets a single-threaded caller (dryrun, tests) step the
     in-process engines between polls.  A pre-made ``handle`` (from
     ``admit_and_submit``'s combined round trip) skips the submit.  A
     ``ClientDisconnected`` raised by ``on_chunk`` cancels the engine

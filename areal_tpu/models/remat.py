@@ -33,8 +33,8 @@ exactly this memory/throughput trade; realhf/api/cli_args.py).
 ``compile_train_step`` AOT-compiles one full train step (grad + optimizer
 update) WITHOUT materializing params, so "fits v5e at the bench batch" is a
 checkable property of every (policy, moment-dtype) cell via XLA's
-``memory_analysis`` — asserted in tests at tiny shapes and reported per cell
-by the bench sweep (bench.py ``bench_train_sweep``).
+``memory_analysis`` — asserted at tiny shapes in
+``tests/model/test_remat_policies.py``.
 """
 
 from __future__ import annotations

@@ -218,8 +218,8 @@ METRIC_TABLE = [
         "areal_inference_kv_quant_divergence_checks_total",
         "counter",
         "Greedy-divergence checks folded into the engine by quality "
-        "harnesses (bench kv_quant_ab / parity tests comparing the int8 "
-        "arm against an fp arm token by token)",
+        "harnesses (parity tests comparing the int8 arm against an fp arm "
+        "token by token)",
     ),
     MetricSpec(
         "areal_inference_kv_quant_divergence_diverged_total",
@@ -246,8 +246,8 @@ METRIC_TABLE = [
         "areal_inference_weight_quant_divergence_checks_total",
         "counter",
         "Greedy-divergence checks folded into the engine by quality "
-        "harnesses (bench weight_quant_ab / parity tests comparing the "
-        "int8-weight arm against a full-precision arm token by token)",
+        "harnesses (parity tests comparing the int8-weight arm against a "
+        "full-precision arm token by token)",
     ),
     MetricSpec(
         "areal_inference_weight_quant_divergence_diverged_total",

@@ -208,7 +208,6 @@ class GenerationServerWorker(worker_base.Worker):
         self.logger = logging_.getLogger(self.worker_name)
 
         from areal_tpu.engine.backend import cast_floating, make_model
-        from areal_tpu.engine.dispatch import resolve_dispatch_table
         from areal_tpu.engine.inference_server import ContinuousBatchingEngine
         from areal_tpu.engine.sampling import SamplingParams
         from areal_tpu.engine.spec_decode import resolve_spec_params
@@ -327,9 +326,6 @@ class GenerationServerWorker(worker_base.Worker):
             ),
             prefill_chunk_tokens=config.prefill_chunk_tokens,
             pipeline_depth=config.pipeline_depth,
-            dispatch_table=resolve_dispatch_table(
-                config.paged_min_cache_len
-            ),
             prefix_cache=config.prefix_cache,
             prefix_cache_capacity_frac=config.prefix_cache_capacity_frac,
             prefix_cache_min_tokens=config.prefix_cache_min_match_tokens,

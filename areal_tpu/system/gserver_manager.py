@@ -1756,6 +1756,11 @@ class GserverManager(worker_base.Worker):
         return worker_base.PollResult(sample_count=1)
 
     def _exit_hook(self):
+        # a fan-out RPC in flight to a server that has already exited
+        # would hold its pool thread, and with it the interpreter's exit,
+        # for the RPC's whole timeout
+        for client in getattr(self, "_clients", {}).values():
+            client.close()
         pool = getattr(self, "_update_pool", None)
         if pool is not None:
             pool.shutdown(wait=False)

@@ -10,7 +10,7 @@ as the SECOND argument (the first is the trace id) of a
 ``.event(tid, "...")`` / ``.span_begin(...)`` / ``.span_end(...)`` /
 ``.span(...)`` call, or as the FIRST argument of a phase span
 (``phase("areal....")``, ``clock.phase("areal....")``), anywhere under
-``areal_tpu/`` or in ``bench.py`` / ``__graft_entry__.py`` — found by
+``areal_tpu/`` or in ``__graft_entry__.py`` — found by
 AST walk (so formatting/aliasing of
 the registry/tracer object doesn't matter, and dynamically computed
 names are rejected by construction: names must be literals or the
@@ -53,10 +53,7 @@ _SKIP_FILES: Tuple[str, ...] = ()
 
 
 def _iter_source_files() -> List[str]:
-    out = [
-        os.path.join(REPO_ROOT, "bench.py"),
-        os.path.join(REPO_ROOT, "__graft_entry__.py"),
-    ]
+    out = [os.path.join(REPO_ROOT, "__graft_entry__.py")]
     for dirpath, _, filenames in os.walk(
         os.path.join(REPO_ROOT, "areal_tpu")
     ):
@@ -166,7 +163,7 @@ def phase_vocabulary_problems(
     for name in sorted(declared - set(phases)):
         problems.append(
             f"trace table entry {name} (phase) is never recorded "
-            "anywhere under areal_tpu/, bench.py, or __graft_entry__.py "
+            "anywhere under areal_tpu/ or __graft_entry__.py "
             "(dead vocabulary — remove it or wire the span)"
         )
     return problems
@@ -372,7 +369,7 @@ def stall_vocabulary_problems(
     for kind in sorted(set(kinds) - emitted):
         problems.append(
             f"STALL_KIND_TABLE entry {kind!r} is never emitted anywhere "
-            "under areal_tpu/, bench.py, or __graft_entry__.py (dead "
+            "under areal_tpu/ or __graft_entry__.py (dead "
             "vocabulary — remove it or wire the emission)"
         )
     for kind in sorted(set(kinds) - documented):
@@ -427,7 +424,7 @@ def run_lint() -> List[str]:
     for name in sorted(set(counts) - emitted_names):
         problems.append(
             f"table entry {name} is never emitted anywhere under "
-            "areal_tpu/ or bench.py (dead vocabulary — remove it or wire "
+            "areal_tpu/ (dead vocabulary — remove it or wire "
             "the instrument)"
         )
 
@@ -500,7 +497,7 @@ def run_lint() -> List[str]:
     for name in sorted(recorder - traced_names):
         problems.append(
             f"trace table entry {name} is never recorded anywhere under "
-            "areal_tpu/, bench.py, or __graft_entry__.py (dead "
+            "areal_tpu/ or __graft_entry__.py (dead "
             "vocabulary — remove it or wire the instrument)"
         )
     tdocumented = collect_documented_trace_names()

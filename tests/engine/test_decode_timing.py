@@ -1,7 +1,8 @@
 """Decode-loop time attribution: the engine splits step() wall time into
 host-bookkeeping vs blocked-on-device vs output-fetch, per chunk — the
 numbers behind the 'is the decode gap the fetch or host bookkeeping?'
-question (surfaced at /metrics and in bench.py decode sub-rows)."""
+question (surfaced at /metrics, and read by the benchmark as
+``engine_host_share``)."""
 
 import jax
 import pytest

@@ -18,9 +18,9 @@ insert.  This file pins, on CPU:
 * ``kv_cache_dtype="auto"`` parity: the quantization plumbing must
   leave the unquantized path token-identical to the dense engine (the
   acceptance criterion's pre-PR-behavior pin);
-* the bench section (bench_kv_quant_ab) as a CPU smoke: >= 1.8x paged
-  blocks per HBM byte at equal pool budget, divergence under the
-  section's quality bar, no silently dropped sub-arms.
+* what the quantized pool buys at an EQUAL byte budget: >= 1.8x paged
+  blocks per HBM byte, more full-context rows, and a higher
+  ``cached_token_frac`` on a multi-turn replay under cache pressure.
 
 Heavy parity arms (TP mesh, spec decode, the host-tier sweep at
 pressure) are ``slow``-marked from day one — run ``pytest -m slow``.
@@ -31,12 +31,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# THE quality-gate statistic, imported from the bench so the asserted
-# bar can never drift from what bench_kv_quant_ab reports
-from bench import lcp_divergence as _lcp_divergence
-
 from areal_tpu.models import paged
 
+from tests.helpers.divergence import lcp_divergence as _lcp_divergence
 from tests.engine.test_prefix_cache import (
     _req,
     make_engine,
@@ -45,8 +42,7 @@ from tests.engine.test_prefix_cache import (
 
 #: measured on the tiny-config multi-turn replay (see
 #: test_int8_divergence_pin): one request in ~5 flips a tail token.  The
-#: bar is asserted, not eyeballed — bench_kv_quant_ab reports the same
-#: statistic per workload.
+#: bar is asserted, not eyeballed.
 DIVERGENCE_BAR = 0.35
 
 
@@ -373,35 +369,40 @@ def test_dense_mode_rejects_int8_with_warning():
     assert not eng._kv_quant and eng.kv_cache_dtype == "auto"
 
 
-def test_bench_kv_quant_cpu_smoke():
-    """Acceptance criterion, as a CPU smoke: >= 1.8x paged blocks per
-    HBM byte at equal pool budget, the int8 arm's greedy divergence
-    rate asserted under the section's quality bar, the 'auto' arm
-    token-identical, and no silently dropped sub-arms."""
-    import bench
-    from areal_tpu.models import transformer
-    from areal_tpu.models.config import tiny_config
-
-    cfg = tiny_config(vocab_size=64, max_position_embeddings=1024)
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    out = bench.bench_kv_quant_ab(
-        cfg, params, n_reqs=2, prompt_len=48, max_new=12, page=16,
-        chunk=8, turns=2, sessions=3, user_len=8,
+def test_int8_pool_buys_rows_and_cache_hits_at_equal_hbm():
+    """What int8 KV storage is for, counted at an EQUAL pool byte budget:
+    >= 1.8x blocks a byte (from the layout arithmetic the allocator and
+    the HBM ledger share), strictly more full-context rows, and, on the
+    multi-turn replay with the radix cache under pressure, a strictly
+    higher share of prompt tokens served from cache."""
+    # one full-context row of pool and a cache share (8 blocks) that
+    # the replay's second turn (3 sessions x 5 blocks) overflows
+    fp, cfg, _ = make_engine(
+        kv_pool_tokens=256, prefix_cache_capacity_frac=0.25
     )
-    assert out["dropped"] == [], out
-    assert out["blocks_per_hbm_byte_gain"] >= 1.8, out
-    assert out["decode"]["quality_ok"] is True, out["decode"]
-    assert out["decode"]["divergence_rate"] <= out["divergence_bar"]
-    assert out["auto_token_parity"] is True, out
-    assert (
-        out["max_concurrent_rows"]["int8"]
-        > out["max_concurrent_rows"]["auto"]
-    ), out["max_concurrent_rows"]
-    assert (
-        out["prefix_equal_hbm"]["int8"]["pool_bytes"]
-        <= out["prefix_equal_hbm"]["auto"]["pool_bytes"]
+    page = fp.page_size
+    fp_block = sum(paged.kv_pool_layout_bytes(cfg, 1, page))
+    q_block = sum(paged.kv_pool_layout_bytes(cfg, 1, page, "int8"))
+    assert fp_block == fp._pool_block_bytes()
+    assert fp_block / q_block >= 1.8
+    budget = fp_block * fp.n_blocks
+    q, *_ = make_engine(
+        kv_cache_dtype="int8",
+        kv_pool_tokens=(budget // q_block) * page,
+        prefix_cache_capacity_frac=0.25,
     )
-    assert out["prefix_equal_hbm"]["cached_token_frac_gain"] > 0, out
+    assert q_block == q._pool_block_bytes()
+    assert q._pool_block_bytes() * q.n_blocks <= budget
+    assert (
+        q.n_blocks // q.blocks_per_row > fp.n_blocks // fp.blocks_per_row
+    )
+    fp.park_ttl_steps = q.park_ttl_steps = 0
+    cached = {}
+    for name, eng in (("auto", fp), ("int8", q)):
+        _replay(eng)
+        cached[name] = eng.prefix_cache_stats()["cached_tokens_total"]
+    # both arms were sent the same prompts, so the totals compare as shares
+    assert cached["int8"] > cached["auto"], cached
 
 
 # -- heavy parity arms (slow-marked from day one) -----------------------------

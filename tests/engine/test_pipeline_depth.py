@@ -13,7 +13,7 @@ throughput with correctness:
   old weights and emit nothing stale after the swap;
 * rows admitted while the ring is full must still be dispatched (the
   generalized ``_worth_dispatching`` epoch-count logic);
-* the measured dispatch table must drive cache_mode="auto".
+* cache_mode="auto" goes paged at ``PAGED_MIN_CACHE_LEN``.
 """
 
 import jax
@@ -22,11 +22,6 @@ import pytest
 from areal_tpu.api.model_api import (
     APIGenerateInput,
     GenerationHyperparameters,
-)
-from areal_tpu.engine.dispatch import (
-    PagedDispatchTable,
-    derive_dispatch_table,
-    resolve_dispatch_table,
 )
 from areal_tpu.engine.generation import generate_tokens
 from areal_tpu.engine.inference_server import ContinuousBatchingEngine
@@ -278,67 +273,17 @@ def test_async_fetch_counters(mode):
     assert eng.inflight_chunks == 0
 
 
-# -- measured dispatch table -------------------------------------------------
+# -- cache_mode="auto" --------------------------------------------------------
 
 
-def test_dispatch_table_defaults_reproduce_old_behavior():
-    t = PagedDispatchTable()
-    assert t.paged_min_cache_len == 2048
-    assert resolve_dispatch_table(None) == t
-    over = resolve_dispatch_table(4096)
-    assert over.paged_min_cache_len == 4096
-    assert over.source == "config"
-
-
-def test_derive_dispatch_table_from_bench_rows():
-    rows = {
-        2048: {"dense": 4000.0, "paged": 3000.0},
-        8192: {"dense": 1400.0, "paged": 1380.0},
-        16384: {"dense": 700.0, "paged": 760.0},
-        32768: {"dense": None, "paged": 400.0},  # dense OOM
-    }
-    t = derive_dispatch_table(rows)
-    # paged reaches parity from 8k up (0.95 margin)
-    assert t.paged_min_cache_len == 8192
-    assert t.source.startswith("bench(")
-
-
-def test_derive_dispatch_table_no_paged_win_and_noisy_island():
-    # paged never reaches parity: threshold pushed past the measured
-    # range (capacity arguments take over beyond it)
-    rows = {
-        2048: {"dense": 4000.0, "paged": 2000.0},
-        8192: {"dense": 1400.0, "paged": 900.0},
-    }
-    t = derive_dispatch_table(rows)
-    assert t.paged_min_cache_len == 2 * 8192
-    # a noisy mid-table dense win must not carve a dense island: the
-    # threshold is the start of the WINNING SUFFIX only
-    rows = {
-        2048: {"dense": 4000.0, "paged": 3950.0},
-        8192: {"dense": 1400.0, "paged": 1000.0},
-        16384: {"dense": 700.0, "paged": 760.0},
-    }
-    t = derive_dispatch_table(rows)
-    assert t.paged_min_cache_len == 16384
-
-
-def test_auto_mode_consults_dispatch_table():
-    cfg = tiny_config(vocab_size=64, max_position_embeddings=256)
+@pytest.mark.parametrize(
+    "kv_cache_len, paged", [(2047, False), (2048, True)]
+)
+def test_auto_mode_goes_paged_at_the_constant(kv_cache_len, paged):
+    cfg = tiny_config(vocab_size=64, max_position_embeddings=4096)
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    common = dict(max_batch=2, kv_cache_len=128, chunk_size=4)
-    dense_eng = ContinuousBatchingEngine(
-        cfg, params, cache_mode="auto", **common
+    eng = ContinuousBatchingEngine(
+        cfg, params, cache_mode="auto", max_batch=1,
+        kv_cache_len=kv_cache_len, chunk_size=4,
     )
-    assert not dense_eng.paged  # 128 < default 2048 threshold
-    paged_eng = ContinuousBatchingEngine(
-        cfg, params, cache_mode="auto",
-        dispatch_table=PagedDispatchTable(
-            paged_min_cache_len=64, source="config"
-        ),
-        page_size=16,
-        **common,
-    )
-    assert paged_eng.paged  # measured table moved the crossover
-
-
+    assert eng.paged is paged

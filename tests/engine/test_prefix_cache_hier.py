@@ -16,10 +16,8 @@ This file pins, on CPU:
   leaks after flush;
 * weight swaps invalidate the host tier too (stale KV across a swap
   stays impossible, host copies included);
-* the bench section (bench_prefix_cache_hier) shows cached_token_frac
-  strictly higher with the tier ON than OFF once the conversation count
-  overflows the HBM cache — the PR's acceptance criterion, as a CPU
-  smoke.
+* on a replay that overflows the HBM cache, the tier ON serves strictly
+  more prompt tokens from cache and prefills strictly fewer than OFF.
 """
 
 import numpy as np
@@ -362,42 +360,19 @@ def test_spec_decode_arm_parity_with_host_tier():
     assert eng.spec_verify_chunks_total > 0  # drafting really engaged
 
 
-def test_bench_hier_cpu_smoke():
-    """Acceptance criterion: on a conversation-count sweep that
-    overflows the HBM cache, cached_token_frac is STRICTLY higher with
-    the host tier ON than OFF, with greedy token parity, no leaks, and
-    no silently dropped sub-arms."""
-    import jax
-
-    import bench
-    from areal_tpu.models import transformer
-    from areal_tpu.models.config import tiny_config
-
-    cfg = tiny_config(vocab_size=64, max_position_embeddings=1024)
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    out = bench.bench_prefix_cache_hier(
-        cfg,
-        params,
-        counts=(4,),
-        turns=2,
-        prompt_len=48,
-        user_len=8,
-        max_new=8,
-        page=8,
-        chunk=8,
-        capacity_frac=0.1,
-        pool_rows=3,
+def test_host_tier_serves_more_from_cache_and_prefills_less():
+    """What the host tier is for: on the same replay that overflows the
+    HBM cache, the tier ON serves strictly more prompt tokens from cache
+    and prefills strictly fewer than the tier OFF, token-identical."""
+    on, *_ = _pressure_engine()
+    off, *_ = _pressure_engine(prefix_cache_host_bytes=0)
+    assert _replay(on) == _replay(off)
+    assert off.prefix_cache_stats()["spilled_blocks_total"] == 0
+    assert (
+        on.prefix_cache_stats()["cached_tokens_total"]
+        > off.prefix_cache_stats()["cached_tokens_total"]
     )
-    assert out["dropped"] == [], out
-    cell = out["sweep"]["c4"]
-    assert cell["token_parity"] is True, cell
-    on, off = cell["host_on"], cell["host_off"]
-    # the sweep actually overflowed HBM: the ON arm spilled and restored
-    assert on["spilled_blocks"] > 0 and on["restored_blocks"] > 0, cell
-    assert on["cached_token_frac"] > off["cached_token_frac"], cell
-    assert on["leak_free"] and off["leak_free"], cell
-    # strictly less prefill work with the tier on
-    assert on["prefill_tokens"] < off["prefill_tokens"], cell
+    assert on.prefill_tokens_total < off.prefill_tokens_total
 
 
 # -- HBM ledger attribution of the host spill tier ----------------------------

@@ -3,6 +3,8 @@ the dense and paged paths, swap-stall attribution, the park/resume
 continuation shape, the off switch, and the swap.commit/swap.stage
 flight-recorder spans (ISSUE 9)."""
 
+import math
+
 import jax
 import pytest
 
@@ -15,6 +17,10 @@ from areal_tpu.engine.sampling import SamplingParams
 from areal_tpu.models import transformer
 from areal_tpu.models.config import tiny_config
 from areal_tpu.observability import tracing
+from areal_tpu.observability.latency import (
+    SLO_REL_ERROR_BOUND,
+    LatencyDigest,
+)
 
 EOS = 5
 
@@ -203,3 +209,36 @@ def test_preemption_window_counts_as_stall():
     assert eng.preempted_total > 0, "workload did not trigger preemption"
     recs = eng.drain_slo_records()
     assert any(r.stall_s > 0 for r in recs), [r.as_dict() for r in recs]
+
+
+def test_two_engines_digests_merge_within_bound_of_pooled_records(mode):
+    """Fleet percentiles: the digests two engines export, merged, sit
+    within the documented bound of the inverted-CDF quantiles of the
+    pooled raw records, and every record names its own server."""
+    engines = [
+        make_engine(mode=mode, server_name=f"srv{j}")[0] for j in range(2)
+    ]
+    for j, eng in enumerate(engines):
+        for i in range(3):
+            submit(
+                eng, f"s{j}-{i}", max_new=6 + 3 * i,
+                prompt=(7 + i, 8, 9, 10 + j),
+            )
+        drain(eng)
+    records = [r for eng in engines for r in eng.drain_slo_records()]
+    assert sorted(r.server for r in records) == ["srv0"] * 3 + ["srv1"] * 3
+    for field in ("ttft_s", "tpot_s"):
+        fleet = LatencyDigest()
+        for eng in engines:
+            fleet.merge(LatencyDigest.from_dict(eng.slo_digests()[field]))
+        raw = sorted(
+            getattr(r, field)
+            for r in records
+            if getattr(r, field) is not None
+        )
+        assert fleet.count == len(raw) > 0
+        for q in (0.5, 0.95, 0.99):
+            emp = raw[max(0, math.ceil(q * len(raw)) - 1)]
+            assert abs(fleet.quantile(q) - emp) <= (
+                SLO_REL_ERROR_BOUND * emp + 1e-12
+            ), (field, q)

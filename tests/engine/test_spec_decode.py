@@ -23,7 +23,6 @@ from areal_tpu.api.model_api import (
 )
 from areal_tpu.engine import spec_decode
 from areal_tpu.engine.batching import spec_window_bucket
-from areal_tpu.engine.dispatch import spec_break_even_accept_rate
 from areal_tpu.engine.generation import generate_tokens
 from areal_tpu.engine.inference_server import ContinuousBatchingEngine
 from areal_tpu.engine.sampling import SamplingParams
@@ -485,14 +484,11 @@ def test_spec_window_bucket_and_break_even():
     assert spec_window_bucket(3) == 4
     assert spec_window_bucket(8) == 8
     assert spec_window_bucket(9) == 16
-    assert spec_break_even_accept_rate(1.0, 8) == 0.0
-    assert spec_break_even_accept_rate(3.0, 8) == pytest.approx(0.25)
-    assert spec_break_even_accept_rate(100.0, 4) == 1.0
 
 
 def test_resolve_spec_params_defaults_and_disable():
     from areal_tpu.api.system_api import SpecDecodeConfig
-    from areal_tpu.engine.dispatch import (
+    from areal_tpu.engine.spec_decode import (
         DEFAULT_SPEC_MIN_ACCEPT_RATE,
         DEFAULT_SPEC_VERIFY_COST,
     )

@@ -23,12 +23,13 @@ pins, on CPU:
   quantized manifest + serving_weight_dtype="auto" -> full-precision
   tree preferred; arch mismatch on the quantized tree -> ONE readable
   error before the pause window (the validate_manifest extension);
-* the bench section (bench_weight_quant_ab) as a CPU smoke: >= 1.8x
-  staged-swap bytes reduction, 'auto' arm token-identical, divergence
-  under the section's quality bar, no silently dropped sub-arms.
+* what the format buys: an int8 server's staged swap restores <= 55%
+  of the full tree's bytes through the server's own restore path, and
+  at a fixed HBM budget the freed weight bytes hold more full-context
+  rows, more again composed with int8 KV.
 
-Heavy parity arms (TP mesh, kv-int8 + weight-int8 composed, the staged
-swap A/B at size) are ``slow``-marked from day one — ``pytest -m slow``.
+Heavy parity arms (TP mesh, kv-int8 + weight-int8 composed) are
+``slow``-marked from day one — ``pytest -m slow``.
 """
 
 import os
@@ -37,10 +38,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-# THE quality-gate statistic, imported from the bench so the asserted
-# bar can never drift from what bench_weight_quant_ab reports
-from bench import lcp_divergence as _lcp_divergence
 
 from areal_tpu.models import quantize, transformer
 
@@ -54,10 +51,10 @@ from tests.engine.test_prefix_cache import (
     make_engine,
     run_until_done,
 )
+from tests.helpers.divergence import lcp_divergence as _lcp_divergence
 
 #: measured on the tiny-config multi-turn replay (same statistic and
-#: shape as the kv-quant pin): bench_weight_quant_ab reports it per
-#: workload with the same bar.
+#: shape as the kv-quant pin)
 DIVERGENCE_BAR = 0.35
 
 
@@ -427,38 +424,29 @@ def test_arch_mismatch_on_quant_tree_fails_readably(tmp_path):
     )
 
 
-def test_bench_weight_quant_cpu_smoke():
-    """Acceptance criterion, as a CPU smoke: staged-swap bytes reduced
-    >= 1.8x vs full-precision staging, the 'auto' arm token-identical
-    to today's engine, int8 divergence under the quality bar on the
-    multi-turn replay, no silently dropped sub-arms, and the composed
-    weight-int8 + kv-int8 capacity strictly above the baseline."""
-    import bench
-    from areal_tpu.models.config import TransformerConfig
+def test_freed_weight_bytes_buy_pool_rows_at_fixed_hbm():
+    """The capacity the two quantizations buy together, counted from the
+    engines' own byte accounts at one fixed budget (full-precision
+    weights + the fp pool): int8 weights free bytes that hold strictly
+    more full-context rows, and int8 KV on top holds at least as many
+    again."""
+    # rows of 64 tokens: the tiny model's weights are worth a few of them
+    fp, *_ = make_engine(kv_cache_len=64)
+    wq, *_ = make_engine(kv_cache_len=64, serving_weight_dtype="int8")
+    kvq, *_ = make_engine(kv_cache_len=64, kv_cache_dtype="int8")
+    w_fp = fp.weight_quant_stats()["param_bytes"]
+    w_q = wq.weight_quant_stats()["param_bytes"]
+    budget = w_fp + fp._pool_block_bytes() * fp.n_blocks
 
-    # wider vocab than the engine-level pin's tiny_config: random-weight
-    # argmax margins grow with vocab here, and the MEASURED deterministic
-    # replay divergence on this seeded workload is 0.208 — the 0.35 bar
-    # keeps the same ~1.7x platform-drift margin as the kv-quant smoke
-    cfg = TransformerConfig(
-        vocab_size=128, hidden_dim=32, intermediate_dim=64, n_layers=2,
-        n_q_heads=4, n_kv_heads=2, head_dim=8, tied_embedding=False,
-        max_position_embeddings=1024,
+    def rows(weight_bytes, block_bytes):
+        return (budget - weight_bytes) // block_bytes // fp.blocks_per_row
+
+    base = rows(w_fp, fp._pool_block_bytes())
+    assert base == fp.n_blocks // fp.blocks_per_row
+    assert rows(w_q, wq._pool_block_bytes()) > base
+    assert rows(w_q, kvq._pool_block_bytes()) >= rows(
+        w_q, wq._pool_block_bytes()
     )
-    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
-    out = bench.bench_weight_quant_ab(
-        cfg, params, n_reqs=2, prompt_len=48, max_new=12, page=16,
-        chunk=8, turns=2, sessions=3, user_len=8,
-    )
-    assert out["dropped"] == [], out
-    assert out["param_hbm"]["reduction"] >= 1.8, out["param_hbm"]
-    assert out["staged_swap"]["bytes_ok"] is True, out["staged_swap"]
-    assert out["staged_swap"]["bytes_ratio"] >= 1.8
-    assert out["auto_token_parity"] is True, out
-    assert out["replay"]["quality_ok"] is True, out["replay"]
-    rows = out["max_concurrent_rows"]
-    assert rows["w_int8+kv_auto"] > rows["w_auto+kv_auto"], rows
-    assert rows["w_int8+kv_int8"] >= rows["w_int8+kv_auto"], rows
 
 
 # -- heavy parity arms (slow-marked from day one) -----------------------------
@@ -545,10 +533,10 @@ def test_int8_weight_moe_ep_parity():
 
 @pytest.mark.slow
 def test_int8_weights_and_int8_kv_composed_sweep():
-    """Both quantizations together (the capacity configuration the
-    bench's composed cells price): multi-turn replay divergence vs the
-    all-fp arm stays under the bar, and both storage families report
-    quantized."""
+    """Both quantizations together (the capacity configuration of
+    test_freed_weight_bytes_buy_pool_rows_at_fixed_hbm): multi-turn
+    replay divergence vs the all-fp arm stays under the bar, and both
+    storage families report quantized."""
     fp, *_ = make_engine()
     both, *_ = make_engine(
         serving_weight_dtype="int8", kv_cache_dtype="int8"
@@ -564,12 +552,11 @@ def test_int8_weights_and_int8_kv_composed_sweep():
     assert both.kv_quant_stats()["quantized"] == 1
 
 
-@pytest.mark.slow
-def test_staged_swap_ab_bytes_and_residency():
-    """The staged-swap A/B at size (more layers than the smoke): an int8
-    engine stages the advertised quantized tree — restored bytes <= ~55%
-    of the full arm's — and the committed tree serves (post-swap replay
-    equals a fresh engine on the published params)."""
+def test_staged_swap_bytes_and_residency():
+    """An int8 engine stages the advertised quantized tree through the
+    server's restore path — restored bytes <= ~55% of the full tree's —
+    and the committed tree serves (post-swap replay equals a fresh
+    engine on the published params)."""
     import tempfile
 
     from areal_tpu.engine import checkpoint

@@ -271,3 +271,128 @@ def test_mid_stream_weight_swap_never_drops_or_duplicates_a_token():
     assert streamed == out["result"]["output_ids"]
     assert out["result"]["version_end"] == eng.version
     assert_pool_pristine(eng)
+
+
+# -- admission under a bulk storm, and the three read paths -------------------
+
+
+def _ginp(qid, ids, max_new):
+    return APIGenerateInput(
+        qid=qid, prompt_ids=list(ids), input_ids=list(ids),
+        gconfig=GenerationHyperparameters(
+            max_new_tokens=max_new, greedy=True
+        ),
+    )
+
+
+PROMPT_LEN, BULK_NEW, INTER_NEW, N_BULK, N_INTER = 16, 64, 8, 4, 4
+
+
+def _storm_then_burst(plane):
+    """A bulk storm claims a 2-engine fleet's rows, then an interactive
+    burst streams in.  Returns the bulk admissions and rejects, each
+    interactive stream's first-token step (counted in ``pump_once``
+    rounds after the burst: steps, never seconds), the streams and the
+    engines."""
+    engines = {f"srv{j}": make_engine()[0] for j in range(2)}
+    backend = EngineBackend(engines, plane=plane)
+    rng = np.random.default_rng(0)
+
+    def prompt():
+        return rng.integers(6, 60, (PROMPT_LEN,)).tolist()
+
+    admitted, rejects = 0, {}
+    for i in range(N_BULK):
+        dec, _ = backend.admit_and_submit(
+            _ginp(f"bulk{i}", prompt(), BULK_NEW), "bulk_load",
+            float(PROMPT_LEN + BULK_NEW), False,
+        )
+        if dec["ok"]:
+            admitted += 1
+        else:
+            rejects[dec["reason"]] = rejects.get(dec["reason"], 0) + 1
+    for _ in range(3):  # the storm settles into its rows
+        backend.pump_once()
+    inter_est = float(PROMPT_LEN + INTER_NEW)
+    handles = {}
+    for i in range(N_INTER):
+        dec, h = backend.admit_and_submit(
+            _ginp(f"int{i}", prompt(), INTER_NEW), "interactive",
+            inter_est, True,
+        )
+        assert dec["ok"], dec
+        handles[f"int{i}"] = h
+    first_step, streams, done = {}, {q: [] for q in handles}, set()
+    for step in range(1, 2000):
+        backend.pump_once()
+        for qid, h in handles.items():
+            if qid in done:
+                continue
+            r = backend.poll(h)
+            if r["tokens"]:
+                first_step.setdefault(qid, step)
+                streams[qid].extend(r["tokens"])
+            if r["done"]:
+                done.add(qid)
+                backend.finish(
+                    h, PROMPT_LEN + len(streams[qid]), inter_est
+                )
+        if len(done) == N_INTER:
+            break
+    assert len(done) == N_INTER
+    while backend.has_work():
+        backend.pump_once()
+    for eng in engines.values():
+        eng.drain_results()
+    return {
+        "admitted": admitted, "rejects": rejects,
+        "first_step": first_step, "streams": streams, "engines": engines,
+    }
+
+
+def test_admission_caps_the_bulk_storm_and_interactive_ttft_in_steps():
+    """Admission ON, the bulk tenant's bucket admits half the storm and
+    rejects the rest as ``rate_limited``, so the interactive burst finds
+    free rows: its worst first-token step is strictly earlier than with
+    admission OFF, where every bulk request admits.  Every interactive
+    stream is whole in both arms and no engine leaks a block."""
+    plane = AdmissionPlane([
+        TenantPolicy("bulk_load", priority="bulk", rate_tokens_per_s=1e-6,
+                     burst_tokens=2.0 * (PROMPT_LEN + BULK_NEW)),
+        TenantPolicy("interactive", priority="interactive"),
+    ])
+    on = _storm_then_burst(plane)
+    off = _storm_then_burst(None)
+    assert on["admitted"] == 2 and on["rejects"] == {"rate_limited": 2}
+    assert off["admitted"] == N_BULK and off["rejects"] == {}
+    assert max(on["first_step"].values()) < max(
+        off["first_step"].values()
+    ), (on["first_step"], off["first_step"])
+    for arm in (on, off):
+        assert sum(len(s) for s in arm["streams"].values()) == (
+            N_INTER * INTER_NEW
+        )
+        for eng in arm["engines"].values():
+            assert_pool_pristine(eng)
+
+
+def test_stream_chunks_final_result_and_rollout_path_agree():
+    """Greedy token identity across the three read paths of one engine:
+    the streamed chunks' concatenation, the request's final result, and
+    a plain rollout-style submission of the same prompt."""
+    eng, *_ = make_engine()
+    backend = EngineBackend({"srv": eng})
+    ids = list(np.random.default_rng(1).integers(6, 60, (16,)))
+    chunks = []
+    out = run_request(
+        backend, _ginp("par-gw", ids, 8), "interactive", "interactive",
+        stream=True, on_chunk=chunks.append, pump=backend.pump_once,
+    )
+    concat = [t for c in chunks for t in c]
+    assert len(chunks) > 1 and concat == list(out["result"]["output_ids"])
+    eng.submit(_ginp("par-rollout", ids, 8))
+    while eng.has_work:
+        eng.step()
+    rollout = eng.drain_results()["par-rollout"]
+    assert list(rollout.output_ids) == concat
+    assert_pool_pristine(eng)

@@ -1,8 +1,8 @@
 """Graduated remat presets (areal_tpu/models/remat.py): every policy must
 preserve the training math exactly (rematerialisation changes WHAT is
 recomputed, never the result), and the AOT memory-analysis harness that
-bench.py's sweep and the v5e fits-HBM assertion ride on must cover every
-preset end-to-end on CPU."""
+the v5e fits-HBM assertion rides on must cover every preset end-to-end on
+CPU."""
 
 import dataclasses
 
@@ -91,8 +91,8 @@ def test_compile_train_step_memory_analysis_every_preset():
 
 
 def test_compiled_step_trains():
-    """The AOT executable is the bench sweep's timing object: it must be
-    directly callable and actually descend the loss."""
+    """The AOT executable must be directly callable and actually descend
+    the loss."""
     cfg = dataclasses.replace(
         tiny_config(vocab_size=64), remat=True, remat_policy="attn_out"
     )

@@ -166,3 +166,68 @@ def test_on_steady_compile_exception_does_not_break_poll():
         reg.counter("areal_trace_stall_total").value(kind="recompile")
         == 1.0
     )
+
+
+def test_sentinel_on_the_engine_silent_when_steady_fires_on_bucket_change():
+    """The sentinel on the serving engine's own jits (the entry points
+    the generation server watches on the dense path): armed after a warm
+    wave, it sees no compile over further waves of the same shapes, and a
+    second engine with another KV bucket makes it fire exactly once, with
+    the burst counted."""
+    from areal_tpu.api.model_api import (
+        APIGenerateInput,
+        GenerationHyperparameters,
+    )
+    from areal_tpu.engine import inference_server as eng_mod
+    from areal_tpu.models import transformer
+    from areal_tpu.models.config import tiny_config
+
+    # a vocabulary no other test uses: the watched jits are module-level,
+    # and a shape another test compiled first would be a cache hit here
+    cfg = tiny_config(vocab_size=72, max_position_embeddings=256)
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+
+    def engine(kv_cache_len):
+        return eng_mod.ContinuousBatchingEngine(
+            cfg, params, max_batch=2, kv_cache_len=kv_cache_len,
+            chunk_size=4, cache_mode="dense",
+        )
+
+    def wave(eng, tag):
+        for i in range(2):
+            ids = [7 + i, 8, 9, 10]
+            eng.submit(
+                APIGenerateInput(
+                    qid=f"{tag}{i}", prompt_ids=ids, input_ids=ids,
+                    gconfig=GenerationHyperparameters(
+                        max_new_tokens=8, greedy=True
+                    ),
+                )
+            )
+        for _ in range(200):
+            if not eng.has_work:
+                break
+            eng.step()
+        assert len(eng.drain_results()) == 2
+
+    w, reg, _ = _watch(quiet_after_steps=1)
+    for name in ("decode_chunk", "admit_rows", "sample_rows"):
+        assert w.watch(name, getattr(eng_mod, "_" + name))
+    stalls = reg.counter("areal_trace_stall_total")
+
+    eng = engine(64)
+    wave(eng, "warm")
+    assert sum(w.poll().values()) >= 1  # the warm wave's own compiles
+    w.note_step(1)
+    assert w.armed
+    wave(eng, "steady-a")
+    wave(eng, "steady-b")
+    assert w.poll() == {}
+    assert stalls.value(kind="recompile") == 0.0
+
+    wave(engine(128), "forced")
+    burst = w.poll()
+    assert sum(burst.values()) >= 1, burst
+    assert stalls.value(kind="recompile") == 1.0
+    assert w.stats()["xla_sentinel_fires_total"] == 1.0
+    assert w.stats()["xla_steady_compiles_total"] == sum(burst.values())

@@ -67,8 +67,8 @@ def test_param_count_matches_init_params():
         assert chip_smoke.param_count(cfg, cfg["n_layers"]) == n
 
 
-# The two rehearsals run once each in module-scoped fixtures (like
-# test_bench_sweep's ``spec_ab``): several tests read one report.
+# The two rehearsals run once each in module-scoped fixtures: several
+# tests read one report.
 
 
 @pytest.fixture(scope="module")
