@@ -35,7 +35,11 @@ from areal_tpu.base import datapack
 @dataclasses.dataclass
 class MicroBatchSpec:
     """``n_mbs`` is the (minimum) number of micro-batches;
-    ``max_tokens_per_mb`` bounds tokens per micro-batch."""
+    ``max_tokens_per_mb`` bounds a micro-batch: its real TOKENS where
+    :meth:`SequenceSample.split` cuts (``TrainEngine.forward_batch``, the
+    workers' data splits), its SLOTS, padding counted, where
+    ``TrainEngine.train_batch`` lays a minibatch out
+    (``engine/batching.plan_minibatch``)."""
 
     n_mbs: int = 1
     max_tokens_per_mb: int = int(1e12)

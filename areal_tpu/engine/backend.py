@@ -147,10 +147,8 @@ class TrainBackend(model_api.ModelBackend):
     )
     #: FFD segment packing of train/forward micro-batches (multi-segment
     #: rows; see docs/parallelism.md "Training batch layout").  On by
-    #: default; pack_capacity raises the per-row token budget above the
-    #: longest sequence's bucket (0 = that bucket).
+    #: default.
     pack_sequences: bool = True
-    pack_capacity: int = 0
 
     def _initialize(self, model, spec):
         model.engine = TrainEngine(
@@ -161,7 +159,6 @@ class TrainBackend(model_api.ModelBackend):
             total_train_steps=max(1, spec.total_train_steps),
             name=str(model.name) if model.name else "",
             pack_sequences=self.pack_sequences,
-            pack_capacity=self.pack_capacity,
         )
         model.init_params = None
         return model
@@ -182,7 +179,6 @@ class InferenceBackend(model_api.ModelBackend):
     """Engine without optimizer state (reference: inference.py:230)."""
 
     pack_sequences: bool = True
-    pack_capacity: int = 0
 
     def _initialize(self, model, spec):
         model.engine = TrainEngine(
@@ -192,7 +188,6 @@ class InferenceBackend(model_api.ModelBackend):
             optimizer_cfg=None,
             name=str(model.name) if model.name else "",
             pack_sequences=self.pack_sequences,
-            pack_capacity=self.pack_capacity,
         )
         model.init_params = None
         return model

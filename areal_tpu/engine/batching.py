@@ -7,11 +7,14 @@ static shapes.  This module is the boundary, with two layouts:
   bucketed T (limiting recompilation) and B padded to a multiple of the
   mesh's dp shard count.
 * :func:`pack_batch` — MULTIPLE sequences per row: FFD bin packing
-  (base/datapack.py, native fast path) lays segments side by side under a
-  token-budget capacity, so a long-tail length distribution no longer pads
-  every row to the global max.  Per-row ``seg_ids`` are numbered 1..k and
-  ``positions`` restart at 0 per segment, so the transformer's
-  same-segment-causal mask and RoPE are correct by construction.
+  (base/datapack.py, native fast path) lays segments side by side, so a
+  long-tail length distribution no longer pads every row to the global
+  max.  Per-row ``seg_ids`` are numbered 1..k and ``positions`` restart at
+  0 per segment, so the transformer's same-segment-causal mask and RoPE
+  are correct by construction.
+
+:func:`plan_minibatch` chooses the ONE ``[rows, T]`` of a train step's
+micro-batches: the minibatch packed once, micro-batches cut as whole rows.
 
 Both produce the same :class:`PaddedBatch` dataclass, and both carry a
 **segment table** (``seg_rows``/``seg_starts``/``seg_lens``, flat ``[S]``
@@ -30,7 +33,7 @@ masking all consume ``seg_ids`` natively.)
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -251,8 +254,6 @@ def pad_batch(
 def pack_batch(
     sample: SequenceSample,
     token_key: str = "packed_input_ids",
-    capacity: int = 0,
-    buckets: Sequence[int] = DEFAULT_BUCKETS,
     row_multiple: int = 1,
     min_rows: int = 1,
     fixed_rows: int = 0,
@@ -260,25 +261,24 @@ def pack_batch(
     fixed_segs: int = 0,
     bins: Optional[List[List[int]]] = None,
 ) -> PaddedBatch:
-    """FFD-bin sequences into multi-segment rows under a token budget.
+    """FFD-bin sequences into multi-segment rows.
 
-    Row width T is ``bucket_len(max(capacity, longest sequence))`` (or
-    ``fixed_len``); :func:`datapack.bin_pack_ffd` (native fast path) packs
-    sequences into rows of at most T tokens, so the padded-slot count
-    tracks the TOTAL token count instead of ``n_seqs x max_len``.  Within
-    a row, segments are laid out in ascending original-sequence order
-    with ``seg_ids`` 1..k and per-segment positions — attention masking
-    and RoPE need no layout-specific handling downstream.
+    Row width T is ``bucket_len(longest sequence)`` (or ``fixed_len``);
+    :func:`datapack.bin_pack_ffd` (native fast path) packs sequences into
+    rows of at most T tokens, so the padded-slot count tracks the TOTAL
+    token count instead of ``n_seqs x max_len``.  Within a row, segments
+    are laid out in ascending original-sequence order with ``seg_ids``
+    1..k and per-segment positions — attention masking and RoPE need no
+    layout-specific handling downstream.
 
     ``fixed_segs`` forces the segment-table capacity S (default: the
     next power of two of the sequence count, bounding compile variety).
-    ``bins`` passes precomputed ``bin_pack_ffd(seqlens, T)`` groups so a
-    caller that already binned (the engine sizes rows across micro-batches
-    first) does not pay the FFD pass twice.
+    ``bins`` passes the rows a caller has already chosen (the engine
+    plans a whole minibatch first, :func:`plan_minibatch`).
     """
     seqlens = [l for ls in sample.seqlens[token_key] for l in ls]
     max_len = max(seqlens)
-    T = fixed_len or bucket_len(max(capacity, max_len), buckets)
+    T = fixed_len or bucket_len(max_len)
     assert max_len <= T, (max_len, T)
     if bins is None:
         bins = datapack.bin_pack_ffd(seqlens, T)
@@ -304,6 +304,145 @@ def pack_batch(
         sample, token_key, seqlens, placement, B, T, S=S,
         scalar_per_segment=True,
     )
+
+
+#: row lengths above this are whole multiples of it (the flash kernel's
+#: block, ``ops/flash_attention._BLOCK``); below it the power-of-two
+#: buckets stand, which are the lengths a short row may have there
+ROW_LEN_STEP = 512
+
+
+def row_len(n: int) -> int:
+    """Shortest row the trainer lays out for ``n`` tokens."""
+    if n <= ROW_LEN_STEP:
+        return bucket_len(n)
+    return pad_rows(n, ROW_LEN_STEP)
+
+
+@dataclasses.dataclass
+class MinibatchPlan:
+    """How one minibatch becomes micro-batches of one ``[rows, row_len]``:
+    micro-batch ``m`` holds the ids ``groups[m]`` (ascending indices into
+    the sample) and, counting its sequences from 0 in that order, its row
+    ``r`` holds the sequences ``bins[m][r]``."""
+
+    row_len: int
+    rows: int
+    groups: List[List[int]]
+    bins: List[List[List[int]]]
+
+    @property
+    def n_stacked(self) -> int:
+        """Micro-batches on the device: the count bucketed to a power of
+        two, so that a data-dependent count meets a bounded set of
+        compiled steps; the extra ones are all zero."""
+        return next_pow2(len(self.groups))
+
+    @property
+    def slots(self) -> int:
+        return self.n_stacked * self.rows * self.row_len
+
+
+def _row_units(
+    id_lens: List[List[int]], T: int, pack: bool, min_units: int
+) -> List[Tuple[List[int], List[List[Tuple[int, int]]]]]:
+    """Rows of length ``T`` in units that a micro-batch takes whole:
+    ``(ids, rows)``, a row a list of ``(id, k)`` for the id's k-th
+    sequence.  Packed: FFD over the IDS, an id's sequences side by side
+    in one row (a preference pair never straddles micro-batches); an id
+    longer than a row has rows of its own.  Not packed: a row a sequence.
+    At least ``min_units`` units (the caller has as many ids)."""
+    if not pack:
+        return [
+            ([i], [[(i, k)] for k in range(len(ls))])
+            for i, ls in enumerate(id_lens)
+        ]
+    totals = [sum(ls) for ls in id_lens]
+    units = [
+        ([i], [[(i, k) for k in b] for b in datapack.bin_pack_ffd(ls, T)])
+        for i, ls in enumerate(id_lens)
+        if totals[i] > T
+    ]
+    small = [i for i, t in enumerate(totals) if t <= T]
+    shared = [
+        [small[j] for j in b]
+        for b in datapack.bin_pack_ffd([totals[i] for i in small], T)
+    ]
+    while len(units) + len(shared) < min_units:
+        fullest = max(shared, key=len)
+        shared.remove(fullest)
+        shared += [fullest[::2], fullest[1::2]]
+    for ids in map(sorted, shared):
+        units.append(
+            (ids, [[(i, k) for i in ids for k in range(len(id_lens[i]))]])
+        )
+    return sorted(units, key=lambda u: u[0][0])
+
+
+def plan_minibatch(
+    seqlens: Sequence[Sequence[int]],
+    row_cost: Callable[[int], float],
+    max_slots_per_mb: int,
+    min_mbs: int = 1,
+    row_quantum: int = 1,
+    pack: bool = True,
+    grow: Callable[[int], bool] = lambda T: True,
+) -> MinibatchPlan:
+    """Lay a minibatch (``seqlens[i]``: the sequence lengths of id i) out
+    by slots: pack it ONCE into rows of one length T, then cut
+    micro-batches as groups of whole rows, each group at most
+    ``max_slots_per_mb`` slots (or one unit of rows, or one
+    ``row_quantum`` of them, where that is more), at least ``min_mbs``
+    groups, all padded to the largest group's rows.
+
+    T runs from the longest sequence's :func:`row_len` up the same
+    ladder, no further than one ``row_quantum`` of rows fits the slot
+    budget, and the T whose stacked rows cost least by ``row_cost(T)`` a
+    row is taken, the shorter on a tie.  T stays at the first step where
+    ``grow(T)`` is false there (the caller's attention holds [T, T]
+    scores), as it does with ``pack=False``."""
+    id_lens = [list(ls) for ls in seqlens]
+    if min_mbs > len(id_lens):
+        raise ValueError(
+            f"cannot cut {len(id_lens)} ids into {min_mbs} micro-batches"
+        )
+    total = sum(map(sum, id_lens))
+    T = row_len(max(map(max, id_lens)))
+    T_end = T
+    if pack and grow(T):
+        fit = max_slots_per_mb // row_quantum // ROW_LEN_STEP * ROW_LEN_STEP
+        T_end = max(T, min(row_len(total), fit))
+    best = None
+    while T <= T_end:
+        if best is not None and total / T * row_cost(T) >= best[0]:
+            break  # not even without padding: a row's cost a slot only grows
+        units = _row_units(id_lens, T, pack, min_mbs)
+        n_rows = [len(rows) for _, rows in units]
+        cap = max(max_slots_per_mb // T // row_quantum, 1) * row_quantum
+        cuts = datapack.partition_by_budget(n_rows, cap, min_groups=min_mbs)
+        rows = pad_rows(
+            max(sum(n_rows[u] for u in cut) for cut in cuts), row_quantum
+        )
+        c = next_pow2(len(cuts)) * rows * row_cost(T)
+        if best is None or c < best[0]:
+            best = (c, T, rows, units, cuts)
+        T = row_len(T + 1)
+    _, T, rows, units, cuts = best
+    groups, bins = [], []
+    for cut in cuts:
+        ids = sorted(i for u in cut for i in units[u][0])
+        first = dict(
+            zip(ids, np.cumsum([0] + [len(id_lens[i]) for i in ids]))
+        )
+        groups.append(ids)
+        bins.append(
+            [
+                [int(first[i]) + k for i, k in row]
+                for u in cut
+                for row in units[u][1]
+            ]
+        )
+    return MinibatchPlan(row_len=T, rows=rows, groups=groups, bins=bins)
 
 
 def unpad_per_token(

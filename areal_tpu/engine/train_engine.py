@@ -9,11 +9,11 @@ with the JAX SPMD equivalent:
 * params/opt-state live as NamedSharding'd global arrays over the model mesh
   (fsdp axis = ZeRO sharding, model axis = tensor parallel) — XLA inserts all
   collectives that Megatron's DDP/DistributedOptimizer did by hand.
-* ``train_batch`` splits a SequenceSample into token-budget micro-batches
-  (same ``MicroBatchSpec`` semantics), pads each to a bucketed [B, T], and
-  accumulates grads across micro-batches on device; the final apply divides
-  by the global denominator, clips, and updates — numerically equal to one
-  big batch.
+* ``train_batch`` packs a SequenceSample once into rows of one length,
+  cuts micro-batches as whole rows under the ``MicroBatchSpec``'s budget
+  in SLOTS (:func:`plan_layout`), and accumulates grads across
+  micro-batches on device; the final apply divides by the global
+  denominator, clips, and updates — numerically equal to one big batch.
 * loss functions are pure ``(params, cfg, batch) -> (loss_sum, denom, stats)``
   pytrees, so one jitted grad step serves every algorithm interface.
 """
@@ -31,12 +31,16 @@ import optax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.api.data import MicroBatchSpec, SequenceSample
-from areal_tpu.base import datapack, logging_
+from areal_tpu.api.data import (
+    MicroBatchSpec,
+    SequenceSample,
+    SequenceSplitSpec,
+)
+from areal_tpu.base import logging_
 from areal_tpu.engine import batching
 from areal_tpu.engine.optimizer import OptimizerConfig, make_optimizer
 from areal_tpu.models.config import TransformerConfig
-from areal_tpu.models.transformer import param_pspecs
+from areal_tpu.models.transformer import param_pspecs, takes_flash
 from areal_tpu.observability.tracing import phase
 
 logger = logging_.getLogger("train_engine")
@@ -57,6 +61,33 @@ LossFn = Callable[
 FwdFn = Callable[[Any, TransformerConfig, Dict[str, jax.Array]], Any]
 
 
+def plan_layout(
+    model_cfg: TransformerConfig,
+    seqlens,
+    mb_spec: MicroBatchSpec,
+    mesh=None,
+    row_quantum: int = 1,
+    pack: bool = True,
+) -> batching.MinibatchPlan:
+    """The ``[n, rows, T]`` a minibatch trains at
+    (:func:`batching.plan_minibatch`): the rows that cost the model's
+    forward pass least, padding counted, by ``flops_counter``'s arithmetic
+    (a linear term a slot, an attention term that grows with T^2 a row).
+    Rows grow past the longest sequence only where the model's attention
+    takes the flash kernel (:func:`takes_flash`)."""
+    from areal_tpu.system import flops_counter
+
+    return batching.plan_minibatch(
+        seqlens,
+        lambda T: flops_counter.forward_flops(model_cfg, [T]),
+        mb_spec.max_tokens_per_mb,
+        min_mbs=mb_spec.n_mbs,
+        row_quantum=row_quantum,
+        pack=pack,
+        grow=lambda T: takes_flash(model_cfg, T, mesh),
+    )
+
+
 class TrainEngine:
     """One model on one mesh: sharded params + optional optimizer state."""
 
@@ -69,18 +100,14 @@ class TrainEngine:
         total_train_steps: int = 1,
         name: str = "",
         pack_sequences: bool = True,
-        pack_capacity: int = 0,
     ):
         self.model_cfg = model_cfg
         self.mesh = mesh
         self.optimizer_cfg = optimizer_cfg
         # sequence packing (FFD segment packing, batching.pack_batch): rows
         # hold multiple segments, so micro-batch [B, T] slots track the real
-        # token count instead of n_seqs x bucket(max_len).  pack_capacity
-        # raises the row token budget above the longest sequence's bucket
-        # (0 = bucket of the longest sequence in the batch).
+        # token count instead of n_seqs x bucket(max_len)
         self.pack_sequences = pack_sequences
-        self.pack_capacity = pack_capacity
         # metric label: co-hosted engines (actor + critic on one worker)
         # must not conflate their areal_train_* series
         self.name = name or "model"
@@ -198,7 +225,6 @@ class TrainEngine:
             return batching.pack_batch(
                 sample,
                 token_key=token_key,
-                capacity=self.pack_capacity,
                 row_multiple=self.row_quantum,
                 min_rows=self.row_quantum,
             )
@@ -278,74 +304,54 @@ class TrainEngine:
             )
         return self._train_step_cache[key][0]
 
-    def _stack_batches(self, mbs, token_key: str):
-        """Lay every micro-batch out at a common [B, T] and stack to
-        [n, B, T], in numpy: ``(stacked, row count, padded batches)``.
-
-        Padded mode: one sequence per row, T = the GLOBAL max bucket —
-        one 8k-token trace in a batch of short rows pads every stacked
-        slot to 8192.  Packing mode (``pack_sequences``): FFD segment
-        packing bounds each row by ``bucket_len(max(pack_capacity,
-        longest))``, so the stacked row count tracks total tokens and a
-        micro-batch token budget maps ~1:1 to real compute."""
-        seqlens = [
-            [l for ls in mb.seqlens[token_key] for l in ls] for mb in mbs
-        ]
+    def _stack_batches(
+        self,
+        sample: SequenceSample,
+        plan: batching.MinibatchPlan,
+        token_key: str,
+    ):
+        """Lay the plan's micro-batches out at its [rows, T] and stack to
+        [n, rows, T], in numpy: ``(stacked, padded batches)``."""
+        mbs = SequenceSample.reorder(
+            sample, [i for g in plan.groups for i in g]
+        ).split_with_spec(SequenceSplitSpec(sizes=list(map(len, plan.groups))))
         if self.pack_sequences:
-            T = batching.bucket_len(
-                max(self.pack_capacity, max(max(s) for s in seqlens))
+            seg_cap = batching.next_pow2(
+                max(sum(map(len, b)) for b in plan.bins)
             )
-            # pre-bin (deterministic, native fast path) to find the shared
-            # row count before the layout pass; the bins are handed to
-            # pack_batch so FFD runs once per micro-batch
-            all_bins = [datapack.bin_pack_ffd(s, T) for s in seqlens]
-            rows = max(
-                batching.pad_rows(
-                    max(len(b) for b in all_bins), self.row_quantum
-                ),
-                self.row_quantum,
-            )
-            seg_cap = batching.next_pow2(max(len(s) for s in seqlens))
             pbs = [
                 batching.pack_batch(
                     mb,
                     token_key=token_key,
-                    fixed_rows=rows,
-                    fixed_len=T,
+                    fixed_rows=plan.rows,
+                    fixed_len=plan.row_len,
                     fixed_segs=seg_cap,
                     bins=b,
                 )
-                for mb, b in zip(mbs, all_bins)
+                for mb, b in zip(mbs, plan.bins)
             ]
         else:
-            rows = max(
-                batching.pad_rows(
-                    max(len(s) for s in seqlens), self.row_quantum
-                ),
-                self.row_quantum,
-            )
-            T = batching.bucket_len(max(max(s) for s in seqlens))
             pbs = [
                 batching.pad_batch(
-                    mb, token_key=token_key, fixed_rows=rows, fixed_len=T
+                    mb,
+                    token_key=token_key,
+                    fixed_rows=plan.rows,
+                    fixed_len=plan.row_len,
                 )
                 for mb in mbs
             ]
         batches = [self._batch_dict(pb) for pb in pbs]
-        # bucket the micro-batch count to the next power of two so
-        # token-budget splitting (data-dependent n_mbs) hits a bounded set
-        # of compiled steps; padding batches are all-zero (seg_ids 0 ->
+        # the all-zero micro-batches of the bucketed count: seg_ids 0 ->
         # zero loss, zero denom, zero grads; seg_lens 0 -> every segment
-        # masked out of per-segment gathers)
-        n_bucket = 1 << (len(batches) - 1).bit_length()
-        for _ in range(n_bucket - len(batches)):
+        # masked out of per-segment gathers
+        for _ in range(plan.n_stacked - len(batches)):
             batches.append(
                 {k: np.zeros_like(v) for k, v in batches[0].items()}
             )
         stacked = {
             k: np.stack([b[k] for b in batches]) for k in batches[0]
         }
-        return stacked, rows, pbs
+        return stacked, pbs
 
     def _upload_stacked(self, stacked, rows: int):
         """The stacked micro-batches on the devices."""
@@ -375,8 +381,16 @@ class TrainEngine:
         tik = time.perf_counter()
         with phase("areal.train.batch") as span:
             with phase("areal.train.pack"):
-                mbs, *_ = sample.split(mb_spec)
-                stacked, rows, pbs = self._stack_batches(mbs, token_key)
+                plan = plan_layout(
+                    self.model_cfg,
+                    sample.seqlens[token_key],
+                    mb_spec,
+                    mesh=self.mesh,
+                    row_quantum=self.row_quantum,
+                    pack=self.pack_sequences,
+                )
+                stacked, pbs = self._stack_batches(sample, plan, token_key)
+            rows, row_len = pbs[0].shape
             with phase("areal.train.upload"):
                 batch = self._upload_stacked(stacked, rows)
             n_mbs = next(iter(batch.values())).shape[0]  # bucketed count
@@ -395,7 +409,8 @@ class TrainEngine:
             self.real_tokens_total += real_tokens
             self._m_pad_frac.set(self.last_padding_frac, model=self.name)
             span.set_metadata(
-                real_tokens=real_tokens, padded_slots=slots, n_mbs=n_mbs
+                real_tokens=real_tokens, padded_slots=slots, n_mbs=n_mbs,
+                rows=rows, row_len=row_len,
             )
             step = self._get_train_step(loss_fn, n_mbs)
             with phase("areal.train.dispatch"):
@@ -420,7 +435,7 @@ class TrainEngine:
             loss=float(out["loss_sum"]) / max(denom_f, 1e-8),
             grad_norm=float(out["grad_norm"]),
             n_tokens=denom_f,
-            n_mbs=len(mbs),
+            n_mbs=len(pbs),
             tokens_per_sec=self.last_tokens_per_sec,
         )
         if self.last_mfu > 0:

@@ -383,6 +383,22 @@ def _pipe_mesh():
     return None
 
 
+def takes_flash(cfg: TransformerConfig, T: int, mesh) -> bool:
+    """Whether self-attention over segment-id rows of ``T`` slots runs the
+    Pallas flash kernel under ``mesh`` (O(T) memory a row).  The one
+    predicate of ``_attention_dispatch`` and of the trainer's layout
+    (``engine/train_engine.plan_layout``), which lengthens rows only where
+    this holds: the jnp path keeps [T, T] scores, and a ``seq`` axis
+    splits T (ring / Ulysses)."""
+    from areal_tpu.ops import flash_attention as fa
+
+    return (
+        (mesh is None or mesh.shape.get("seq", 1) == 1)
+        and jax.default_backend() == "tpu"
+        and fa.supported(T, T, cfg.sliding_window)
+    )
+
+
 def _attention_dispatch(
     q, k, v, mask, cfg: TransformerConfig, seg_ids=None, positions=None
 ):
@@ -390,8 +406,6 @@ def _attention_dispatch(
     mesh shards the sequence axis (context parallelism — a capability the
     reference lacks, SURVEY §2.9); Pallas flash on TPU for the dense
     self-attention path; jnp reference elsewhere."""
-    from areal_tpu.ops import flash_attention as fa
-
     mesh = _seq_parallel_mesh()
     if mesh is not None and seg_ids is not None and positions is not None:
         head_axis = (
@@ -426,8 +440,8 @@ def _attention_dispatch(
         )
     if (
         seg_ids is not None
-        and jax.default_backend() == "tpu"
-        and fa.supported(q.shape[1], k.shape[1], cfg.sliding_window)
+        and q.shape[1] == k.shape[1]
+        and takes_flash(cfg, q.shape[1], _AMBIENT_MESH)
     ):
         return _flash_attention(q, k, v, seg_ids, cfg)
     _warn_dense_fallback(

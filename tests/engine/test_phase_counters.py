@@ -164,7 +164,30 @@ def test_pages_are_zero_on_the_dense_cache():
     assert (eng.pages_live, eng.pages_total) == (0, 0)
 
 
-def test_train_engine_cumulative_slots_are_the_sum_of_each_calls():
+class _RecordingSpan:
+    """Stands in for ``tracing.phase``: keeps what each span was given."""
+
+    seen = []
+
+    def __init__(self, name, **counts):
+        self.name, self.counts = name, dict(counts)
+
+    def __enter__(self):
+        _RecordingSpan.seen.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **counts):
+        self.counts.update(counts)
+
+
+def test_train_engine_cumulative_slots_are_the_sum_of_each_calls(monkeypatch):
+    from areal_tpu.engine import train_engine
+
+    monkeypatch.setattr(_RecordingSpan, "seen", [])
+    monkeypatch.setattr(train_engine, "phase", _RecordingSpan)
     cfg = tiny_config(vocab_size=64)
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     mesh = MeshSpec(data=1, fsdp=1, model=1).make_mesh(jax.devices()[:1])
@@ -189,3 +212,14 @@ def test_train_engine_cumulative_slots_are_the_sum_of_each_calls():
     overall = 1 - eng.real_tokens_total / eng.padded_slots_total
     assert min(fracs) <= overall <= max(fracs)
     assert np.isfinite(overall)
+    # the span of each call says what was laid out: the counts the
+    # benchmark's train_pad_share_all reads, and the [rows, row_len] chosen
+    spans = [s for s in _RecordingSpan.seen if s.name == "areal.train.batch"]
+    assert [s.counts["padded_slots"] for s in spans] == slots
+    assert [s.counts["real_tokens"] for s in spans] == tokens
+    # sequences of 4-39 tokens: rows of 32 or 64, the count bucketed (3 -> 4)
+    assert [s.counts["n_mbs"] for s in spans] == [1, 2, 4]
+    for s in spans:
+        c = s.counts
+        assert c["row_len"] in (32, 64)
+        assert c["n_mbs"] * c["rows"] * c["row_len"] == c["padded_slots"]

@@ -433,3 +433,109 @@ def test_decode_program_keeps_the_pool_layout(
     ).compile()
     _assert_kernel(compiled)
     _assert_pool_stays_put(compiled, pool, temp_share=0.25)
+
+
+# --- the trainer's step whole: what the layout rule may ask of one chip ---
+
+
+def test_train_step_at_the_train_cells_largest_shape_fits_one_chip(
+    topo, one_chip, monkeypatch
+):
+    """The fused grad + optimizer program of the benchmark's train cell
+    (``train-packed.qwen2.5-1.5b``: 10 layers at Qwen2.5-1.5B widths,
+    float32 masters and Adam moments, the PPO actor's loss) at the LARGEST
+    micro-batch ``[rows, T]`` the engine's layout rule gives the cell's
+    three batches: arguments and temporaries fit the 15.75 GB a v5e leaves
+    a program, by the compiler's count.  A change of the rule that lays out
+    more slots a micro-batch shows here, before any chip: ``[1, 8192]``
+    counts 14.3 GB, the parent's ``[3, 4096]`` 15.4, ``[4, 4096]`` would
+    not pass.
+
+    The micro-batch is compiled as a program of ONE.  A described chip has
+    no memory limit for the scheduler to work to, so a program that
+    accumulates over two micro-batches counts its second float32 gradient
+    tree (2.8 GB) on top: the parent's ``[2, 3, 4096]`` counts 20.1 GB here
+    and has run on the chip in every benchmark run since PR 23."""
+    import json
+    import os
+
+    import areal_tpu.interfaces.ppo_interface  # noqa: F401 - "ppo_actor"
+    from areal_tpu.api import model_api
+    from areal_tpu.api.config import ModelInterfaceAbstraction
+    from areal_tpu.api.data import MicroBatchSpec, SequenceSample
+    from areal_tpu.base.topology import MeshSpec
+    from areal_tpu.engine.optimizer import OptimizerConfig, make_optimizer
+    from areal_tpu.engine.train_engine import TrainEngine, plan_layout
+    from benchmark.drivers import train_steps
+    from benchmark.lib import lengths
+    from benchmark.lib.program import model_config
+
+    root = os.path.join(os.path.dirname(__file__), "..", "..", "benchmark")
+    with open(os.path.join(root, "traffic", "train-packed.json")) as f:
+        traffic = json.load(f)
+    with open(os.path.join(root, "configs", "qwen2.5-1.5b.json")) as f:
+        cfg = model_config(json.load(f), "train")
+    assert (cfg.n_layers, cfg.hidden_dim) == (10, WIDTHS["qwen2.5-1.5b"][0])
+    iface = model_api.make_interface(
+        ModelInterfaceAbstraction("ppo_actor", dict(traffic["interface"]))
+    )
+    mb_spec = MicroBatchSpec(max_tokens_per_mb=traffic["max_tokens_per_mb"])
+
+    # an engine's layout and step without its arrays: nothing is attached
+    eng = object.__new__(TrainEngine)
+    eng.model_cfg, eng.pack_sequences, eng.pipe_size = cfg, True, 1
+    eng.mesh = MeshSpec().make_mesh(topo.devices[:1])
+    eng.tx = make_optimizer(OptimizerConfig(**traffic["optimizer"]), 10**6)
+    eng._train_step_cache = {}
+
+    # the model takes the flash kernel, and the layout rule lengthens rows,
+    # on a TPU only, and this process sees a CPU beside the described chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    largest = None
+    keys = (
+        train_steps.PER_TOKEN + train_steps.PER_TRANSITION
+        + train_steps.PER_SEQUENCE
+    )
+    for k in range(traffic["distinct_batches"]):
+        b = lengths.train_batch(traffic, 1, cfg.vocab_size, k)
+        sample = SequenceSample.from_default(
+            b["seqlens"],
+            [f"s{i}" for i in range(len(b["seqlens"]))],
+            {key: b[key] for key in keys},
+        )
+        iface._prepare_batch(sample)
+        n = traffic["interface"]["n_minibatches"]
+        for mb in sample.split(MicroBatchSpec(n_mbs=n))[0]:
+            plan = plan_layout(
+                cfg, mb.seqlens["packed_input_ids"], mb_spec, mesh=eng.mesh
+            )
+            size = (plan.rows * plan.row_len, plan.row_len)
+            if largest is None or size > largest[0]:
+                largest = (size, mb, plan)
+    _, mb, plan = largest
+    stacked, _ = eng._stack_batches(mb, plan, "packed_input_ids")
+    stacked = {k: v[:1] for k, v in stacked.items()}
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params = jax.eval_shape(
+        lambda: transformer.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    opt_state = jax.eval_shape(eng.tx.init, params)
+    compiled = (
+        eng._get_train_step(iface._loss_fn, 1)
+        .lower(on_chip(params), on_chip(opt_state), on_chip(stacked))
+        .compile()
+    )
+    _assert_kernel(compiled)
+    m = compiled.memory_analysis()
+    # params and optimizer state are donated: the outputs alias them
+    need = (
+        m.argument_size_in_bytes + m.temp_size_in_bytes
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    )
+    assert need < 15.75e9, (need / 1e9, plan)
