@@ -312,6 +312,12 @@ class GenServerConfig:
     # exceeds a chunk's device time.  1 =
     # unpipelined baseline.
     pipeline_depth: int = 2
+    # keep every layer's routed experts of the last N finished requests
+    # on the engine (``ContinuousBatchingEngine.routed_experts(qid)``):
+    # what a routing-replay trainer or a parity check follows.  Only a
+    # stack whose programs hand their routing out takes it (the hybrid
+    # one); 0 keeps nothing and fetches nothing
+    keep_routed_experts: int = 0
     # recompile sentinel (observability/compile_watch.py): engine steps
     # after which the serving loop is declared steady-state — any
     # decode/fill-path XLA compile from then on fires

@@ -50,7 +50,7 @@ def cast_floating(params, dtype):
     with jax.default_device(host_device()):
         return jax.tree.map(
             lambda x: x.astype(dtype)
-            if jnp.issubdtype(x.dtype, jnp.floating)
+            if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype != dtype
             else x,
             params,
         )
@@ -118,11 +118,20 @@ def make_model(
             model_cfg = TransformerConfig(**conf)
         else:
             model_cfg = tiny_config(**args)
-        from areal_tpu.models.transformer import init_params
+        if model_cfg.is_hybrid:
+            # a stack stated by kind: made in the model's dtype where
+            # jax's default device is (a server's chip), kind by kind; a
+            # float32 host copy of a 5 B-parameter share is 20 GB
+            from areal_tpu.models import hybrid
 
-        with jax.default_device(host_device()):
-            params = init_params(model_cfg, jax.random.PRNGKey(seed))
-        backend_name = "llama"
+            params = hybrid.init_params(model_cfg, jax.random.PRNGKey(seed))
+            backend_name = "granitemoehybrid"
+        else:
+            from areal_tpu.models.transformer import init_params
+
+            with jax.default_device(host_device()):
+                params = init_params(model_cfg, jax.random.PRNGKey(seed))
+            backend_name = "llama"
     else:
         raise ValueError(f"unknown model abstraction {cfg.type_}")
 

@@ -71,7 +71,7 @@ from areal_tpu.engine import spec_decode
 from areal_tpu.engine.batching import bucket_len, spec_window_bucket
 from areal_tpu.engine.prefix_cache import PrefixMatch, RadixPrefixCache
 from areal_tpu.engine.sampling import SamplingParams, sample_logits_keyed
-from areal_tpu.models import paged, quantize
+from areal_tpu.models import hybrid, paged, quantize
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import KVCache, decode_step, prefill
 from areal_tpu.observability.hbm_ledger import (
@@ -173,6 +173,10 @@ class _Row:
     t_last: float = 0.0
     slo_stall_s: float = 0.0
     t_preempt: float = 0.0
+    #: ``keep_routed_experts``: pieces ``[n, L, K]`` int16 of every
+    #: layer's routed experts, one entry a position the model has READ:
+    #: the prompt's from its fill, then each decode chunk's emitted steps
+    routed: Optional[List[np.ndarray]] = None
 
 
 @dataclasses.dataclass
@@ -203,6 +207,14 @@ class _Fill:
     blocks: List[int]
     targets: List[_FillTarget]
     fill_pos: int = 0
+    #: stateful models: the row slot whose recurrent state this fill
+    #: advances chunk by chunk (its first target's); siblings get a copy
+    state_slot: int = -1
+    #: ``keep_routed_experts``: ``(routed [L, F, C, K] on the device, this
+    #: fill's row in it, its valid tokens)`` of each chunk so far
+    routed: List[Tuple[Any, int, int]] = dataclasses.field(
+        default_factory=list
+    )
 
 
 @dataclasses.dataclass
@@ -394,6 +406,23 @@ def _warn_paged_reference(head_dim: int):
     )
 
 
+class StatefulModelUnsupported(NotImplementedError):
+    """A feature that assumes a sequence's cache is per-token blocks was
+    asked of a model whose layers also keep a recurrent state per
+    sequence (``cfg.n_mamba_layers > 0``): that state exists at the end
+    of what was computed and nowhere else, so it cannot be cut at a page
+    boundary, rewound after a rejected draft, or rebuilt from KV pages
+    another server sends."""
+
+    def __init__(self, feature: str):
+        super().__init__(
+            f"{feature} is not supported for a model with recurrent state "
+            "slots: a state exists where its sequence ends, not at page "
+            "boundaries"
+        )
+        self.feature = feature
+
+
 class ContinuousBatchingEngine:
     """Thread-safe continuous-batching generation over one model mesh."""
 
@@ -427,6 +456,7 @@ class ContinuousBatchingEngine:
         handoff_streaming: bool = False,
         prefix_pull_min_tokens: int = 256,
         hbm_ledger: Optional[HbmLedger] = None,
+        keep_routed_experts: int = 0,
     ):
         """``mesh``: a (small) jax Mesh for tensor-parallel serving — params
         shard via ``transformer.param_pspecs`` (TP over ``model``), the KV
@@ -549,6 +579,49 @@ class ContinuousBatchingEngine:
             and kv_cache_len >= PAGED_MIN_CACHE_LEN
             and cfg.sliding_window is None
         )
+        # the second cache kind: one recurrent-state slot a batch row (SSM
+        # state + conv tail per Mamba layer) beside the attention layers'
+        # pages.  Zeroed by a fill's first chunk, carried by its later
+        # ones, copied to the siblings that share the fill, free when the
+        # row is.  What assumes per-token blocks refuses by name here,
+        # where its option is set, or where it is asked for.
+        self._stateful = cfg.is_hybrid
+        # the routing of the last ``keep_routed_experts`` finished
+        # requests (:meth:`routed_experts`): what a routing-replay trainer
+        # or a parity check follows.  The hybrid stack's programs hand it
+        # out; nothing else does yet
+        if keep_routed_experts and not self._stateful:
+            raise ValueError(
+                "keep_routed_experts: only the hybrid stack's programs "
+                "hand their routing out"
+            )
+        self._keep_routed = int(keep_routed_experts)
+        self._routed_done: Dict[str, np.ndarray] = {}  # oldest first
+        if self._stateful:
+            refused = {
+                "the dense (unpaged) KV cache": cache_mode == "dense",
+                "a tensor- or expert-parallel serving mesh": mesh is not None,
+                "speculative verify": spec_decode_params is not None
+                and spec_decode_params.enabled,
+                "prefix-cache host spill": prefix_cache_host_bytes > 0,
+                "int8 KV storage": kv_cache_dtype == "int8",
+                "int8 serving weights": serving_weight_dtype == "int8",
+            }
+            for feature, asked in refused.items():
+                if asked:
+                    raise StatefulModelUnsupported(feature)
+            self.paged = True
+        #: sibling copies of a fill's end state; fills built for a prompt
+        #: that a live row already carries (a late sibling: the state at
+        #: the prompt's end was never kept, so it prefills again); (token,
+        #: k) pairs decode chunks routed to held experts and to absent ones
+        self.state_copies_total = 0
+        self.state_reprefills_total = 0
+        self.moe_pairs_held_total = 0
+        self.moe_pairs_routed_total = 0
+        self.moe_expert_pairs = np.zeros(
+            (cfg.n_held_experts if self._stateful else 0,), np.int64
+        )
         assert kv_cache_dtype in ("auto", "int8"), kv_cache_dtype
         if kv_cache_dtype == "int8" and not self.paged:
             logger.warning(
@@ -588,6 +661,9 @@ class ContinuousBatchingEngine:
         # else so every pool call site can pass them unconditionally
         self.k_scale: Optional[jax.Array] = None
         self.v_scale: Optional[jax.Array] = None
+        # recurrent-state slots exist only for a stateful model
+        self.ssm_state: Optional[jax.Array] = None
+        self.conv_state: Optional[jax.Array] = None
         # quantized-serving quality counters: external parity harnesses
         # fold their greedy divergence checks in here so the fleet's
         # metrics carry measured quality, not assumptions
@@ -995,6 +1071,23 @@ class ContinuousBatchingEngine:
         pool_b, scale_b = paged.kv_pool_layout_bytes(
             cfg, self.n_blocks, BS, kv_cache_dtype=kv_dtype
         )
+        if self._stateful:
+            self.ssm_state, self.conv_state = hybrid.state_zeros(
+                cfg, max_batch
+            )
+            pool_b += hybrid.state_layout_bytes(cfg, max_batch)
+            if self.device is not None:
+                # COMMITTED to the device from the start: a program's
+                # cache key holds whether each argument is, so the first
+                # fill (fresh, uncommitted zeros) and a later one of the
+                # same shape (a program's outputs) were two programs, and
+                # the second was built inside a benchmark's window
+                (self.k_pool, self.v_pool, self.ssm_state,
+                 self.conv_state) = jax.device_put(
+                    (self.k_pool, self.v_pool, self.ssm_state,
+                     self.conv_state),
+                    self.device,
+                )
         self._led_kv_pool.set(pool_b)
         self._led_kv_scales.set(scale_b)
         self.kv_lengths = jnp.zeros((max_batch,), jnp.int32)
@@ -1014,7 +1107,10 @@ class ContinuousBatchingEngine:
         # pool blocks (the cache speaks to the allocator only through
         # incref/decref, so its evictions can never recycle a block a
         # live row still pins)
-        if self._prefix_cache_enabled:
+        # (none for a stateful model: cached pages hold a prefix's KV and
+        # not the recurrent state at its end, so a match could skip
+        # nothing until state snapshots at page boundaries exist)
+        if self._prefix_cache_enabled and not self._stateful:
             host_bytes = self._prefix_cache_host_bytes
             if host_bytes > 0 and jax.process_count() > 1:
                 logger.warning(
@@ -1165,8 +1261,8 @@ class ContinuousBatchingEngine:
         if quantized:
             bits = quantize.STORAGE_BITS
         else:
-            probe = self.params["layers"]["attn"]["q"]
-            w = probe["w"] if isinstance(probe, dict) else probe
+            # the embedding is in every family's tree, at the weights' dtype
+            w = self.params["embed"]["weight"]
             bits = int(jnp.dtype(w.dtype).itemsize) * 8
         return {
             "quantized": int(quantized),
@@ -1274,6 +1370,14 @@ class ContinuousBatchingEngine:
             if row is not None and not row.parked:
                 live.update(self._row_blocks[row_id])
         return len(live)
+
+    @property
+    def state_slots_live(self) -> int:
+        """Recurrent-state slots held by rows that decode or fill (0 for
+        a model without such state; a slot is its row's)."""
+        if not self._stateful:
+            return 0
+        return sum(r is not None and not r.parked for r in self.rows)
 
     def _alloc_blocks_reclaiming(
         self, n: int, keep_qids=(), protect_step: Optional[int] = None
@@ -1415,6 +1519,12 @@ class ContinuousBatchingEngine:
             return None
         if self._prefix_cache is not None and len(seq) >= 2:
             self._prefix_cache.record(m)
+        if self._stateful and any(
+            r is not None and r.prompt == seq for r in self.rows
+        ):
+            # a late sibling: its prompt's end state sits in a live row's
+            # slot, already moved on; this fill computes it again
+            self.state_reprefills_total += 1
         if m.tail_block is not None:
             # COW: the partial tail's first tail_tokens are valid; copy
             # the whole block (append-only writes beyond that point are
@@ -1451,6 +1561,8 @@ class ContinuousBatchingEngine:
         by a weight swap or TTL — the decode side re-prefills) or on a
         dense engine.  This is the prefill role's half of the
         P/D-disaggregated serving path."""
+        if self._stateful:
+            raise StatefulModelUnsupported("P/D handoff")
         if not self.paged:
             return None
         for row_id, row in enumerate(self.rows):
@@ -1515,6 +1627,8 @@ class ContinuousBatchingEngine:
         re-prefills under the current weights.  Layout mismatches
         (page size, kv dtype, context length) and pool/row exhaustion
         reject the same way.  Returns ``(ok, reason)``."""
+        if self._stateful:
+            raise StatefulModelUnsupported("P/D handoff")
         t0 = time.perf_counter()
         qid = unit.get("qid", "?")
         if not self.paged:
@@ -1799,6 +1913,8 @@ class ContinuousBatchingEngine:
         extend the monolithic set with ``stream`` | ``abort`` |
         ``expired`` (the TTL sweep for dead peers).  Stale or incomplete
         KV is never decoded."""
+        if self._stateful:
+            raise StatefulModelUnsupported("P/D handoff")
         t0 = time.perf_counter()
         qid = seg.get("qid", "?")
         if seg.get("abort"):
@@ -2018,6 +2134,8 @@ class ContinuousBatchingEngine:
         their spill payloads directly — the spill buffer already IS the
         wire format.  Returns ``[]`` when nothing exportable is cached
         (the puller re-prefills)."""
+        if self._stateful:
+            raise StatefulModelUnsupported("prefix pulls")
         if not self.paged or self._prefix_cache is None or len(tokens) < 2:
             return []
         entries = self._prefix_cache.export_walk(
@@ -2178,6 +2296,8 @@ class ContinuousBatchingEngine:
         final segment radix-inserts the pulled prefix — the cache takes
         its own references and the pull's are dropped, so ownership
         rules are identical to a locally-computed prefix."""
+        if self._stateful:
+            raise StatefulModelUnsupported("prefix pulls")
         t0 = time.perf_counter()
         qid = seg.get("qid", "?")
         if not self.paged:
@@ -2293,6 +2413,12 @@ class ContinuousBatchingEngine:
     # -- client API (any thread) -------------------------------------------
 
     def submit(self, req: model_api.APIGenerateInput) -> str:
+        if self._stateful:
+            meta = req.metadata or {}
+            if meta.get("handoff_to"):
+                raise StatefulModelUnsupported("P/D handoff")
+            if meta.get("kv_source"):
+                raise StatefulModelUnsupported("prefix pulls")
         with self._lock:
             self._pending.append(req)
             ev = threading.Event()
@@ -3110,34 +3236,62 @@ class ContinuousBatchingEngine:
             return [], [], None
         C = bucket_len(max(take for _, take in batch))
         F_pad = 1 << (len(batch) - 1).bit_length()
-        with self._phases.phase(
-            "areal.engine.fill.dispatch", prompts=len(batch), f_pad=F_pad,
-            c=C, tokens=sum(take for _, take in batch),
-        ):
+        counts = dict(
+            prompts=len(batch), f_pad=F_pad, c=C,
+            tokens=sum(take for _, take in batch),
+        )
+        if self._stateful:
+            # running totals: sibling copies of a fill's end state, and
+            # requests that matched cached pages and prefilled from 0
+            counts.update(
+                state_copies=self.state_copies_total,
+                state_reprefills=self.state_reprefills_total,
+            )
+        with self._phases.phase("areal.engine.fill.dispatch", **counts):
             toks = np.zeros((F_pad, C), np.int32)
             starts = np.zeros((F_pad,), np.int32)
             cls = np.zeros((F_pad,), np.int32)
             tables = np.zeros((F_pad, self.blocks_per_row), np.int32)
+            slots = np.zeros((F_pad,), np.int32)
             for i, (f, take) in enumerate(batch):
                 toks[i, :take] = f.tokens[f.fill_pos : f.fill_pos + take]
                 starts[i] = f.fill_pos
                 cls[i] = take
                 tables[i, : len(f.blocks)] = f.blocks
-            out = paged.paged_fill_chunk(
-                self.params,
-                self.k_pool,
-                self.v_pool,
-                self.cfg,
-                jnp.asarray(toks),
-                jnp.asarray(starts),
-                jnp.asarray(cls),
-                jnp.asarray(tables),
-                use_kernel=self._use_paged_kernel,
-                mesh=self.mesh,
-                kv_axis=getattr(self, "_kv_axis", None),
-                k_scale=self.k_scale,
-                v_scale=self.v_scale,
-            )
+                slots[i] = f.state_slot
+            if self._stateful:
+                (logits, self.k_pool, self.v_pool, self.ssm_state,
+                 self.conv_state, _, routed) = hybrid.hybrid_fill_chunk(
+                    self.params, self.k_pool, self.v_pool, self.ssm_state,
+                    self.conv_state, self.cfg, jnp.asarray(toks),
+                    jnp.asarray(starts), jnp.asarray(cls),
+                    jnp.asarray(tables), jnp.asarray(slots),
+                    use_kernel=self._use_paged_kernel,
+                )
+                out = (logits, self.k_pool, self.v_pool)
+                if self._keep_routed:
+                    # on its way to the host while the rows decode: the
+                    # row that finishes reads it without a round trip
+                    jax_compat.start_host_copies((routed,))
+                    for i, (f, take) in enumerate(batch):
+                        if f.targets:
+                            f.routed.append((routed, i, take))
+            else:
+                out = paged.paged_fill_chunk(
+                    self.params,
+                    self.k_pool,
+                    self.v_pool,
+                    self.cfg,
+                    jnp.asarray(toks),
+                    jnp.asarray(starts),
+                    jnp.asarray(cls),
+                    jnp.asarray(tables),
+                    use_kernel=self._use_paged_kernel,
+                    mesh=self.mesh,
+                    kv_axis=getattr(self, "_kv_axis", None),
+                    k_scale=self.k_scale,
+                    v_scale=self.v_scale,
+                )
         if self._kv_quant:
             (logits, self.k_pool, self.v_pool, self.k_scale,
              self.v_scale) = out
@@ -3173,7 +3327,8 @@ class ContinuousBatchingEngine:
         weights), which is scatter-deterministic."""
         fills = [
             _Fill(
-                key=(), tokens=seq, blocks=self._row_blocks[rid], targets=[]
+                key=(), tokens=seq, blocks=self._row_blocks[rid], targets=[],
+                state_slot=rid,
             )
             for rid, seq in entries
             if len(seq) > 0
@@ -3235,13 +3390,34 @@ class ContinuousBatchingEngine:
                 logps = np.asarray(sampled[1])[:n]
             self.tokens_emitted_total += n
         with self._phases.phase("areal.engine.fill.activate"):
+            for f in fills:
+                self._hand_out_routing(f)
             self._activate_filled_rows(sample_targets, toks, logps, activation)
+
+    def _hand_out_routing(self, f: _Fill):
+        """``keep_routed_experts``: a completed fill's routing to every
+        target (a resumed row's was computed again, with everything
+        else), as ``[tokens, L, K]`` pieces on the host.  Its program has
+        run by now (its first tokens were fetched) and the copy started
+        at dispatch, so nothing waits here and the device arrays go."""
+        if not f.routed:
+            return
+        pieces = [
+            np.asarray(r)[:, i, :take].swapaxes(0, 1).astype(np.int16)
+            for r, i, take in f.routed
+        ]
+        f.routed = []
+        for tgt in f.targets:
+            row = tgt.resume or self.rows[tgt.row_id]
+            if row is not None:
+                row.routed = list(pieces)
 
     def _share_fill_blocks(self, fills: List[_Fill], idxs, logits):
         """The part of ``_distribute_fills`` before the fetch.  Returns
         (fresh targets to sample for, rows to activate as they are, the
         sampled tokens and log-probabilities still on the device)."""
         copy_src, copy_dst = [], []
+        state_src, state_dst = [], []
         sample_targets: List[Tuple[_Fill, _FillTarget, int]] = []
         activation: List[Tuple[int, int, int, int]] = []  # rid,cur,budget,len
         for f, li in zip(fills, idxs):
@@ -3252,6 +3428,11 @@ class ContinuousBatchingEngine:
             # retried or sibling request arriving next step already hits)
             self._cache_insert(f.tokens, f.blocks)
             for t_i, tgt in enumerate(f.targets):
+                if self._stateful and tgt.row_id != f.state_slot:
+                    # the prompt's end state, which the fill left in its
+                    # own slot, for a sibling that shares the fill
+                    state_src.append(f.state_slot)
+                    state_dst.append(tgt.row_id)
                 if t_i == 0:
                     self._set_row_blocks(tgt.row_id, list(f.blocks))
                 else:
@@ -3306,6 +3487,17 @@ class ContinuousBatchingEngine:
             src[: len(copy_src)] = copy_src
             dst[: len(copy_dst)] = copy_dst
             self._copy_pool_blocks(src, dst)
+        if state_src:
+            n_pad = 1 << (len(state_src) - 1).bit_length()
+            src = np.zeros((n_pad,), np.int32)
+            dst = np.full((n_pad,), self.max_batch, np.int32)  # pad -> skip
+            src[: len(state_src)] = state_src
+            dst[: len(state_dst)] = state_dst
+            self.ssm_state, self.conv_state = hybrid.copy_state_slots(
+                self.ssm_state, self.conv_state, jnp.asarray(src),
+                jnp.asarray(dst),
+            )
+            self.state_copies_total += len(state_src)
         sampled = None
         if sample_targets:
             n = len(sample_targets)
@@ -3391,6 +3583,15 @@ class ContinuousBatchingEngine:
             a for a in activation if self.rows[a[0]] is a[4]
         ]
         if activation:
+            # a power-of-two count, the last row named again (the same
+            # values twice): one set of scatter programs a bucket, not one
+            # a count.  Siblings that arrive over two steps activate as 3
+            # and 5 where 8 was warmed, and a count first met inside a
+            # benchmark's window compiled there (my chip run, PR 31)
+            n_pad = 1 << (len(activation) - 1).bit_length()
+            activation = activation + [activation[-1]] * (
+                n_pad - len(activation)
+            )
             ids = np.array([a[0] for a in activation], np.int32)
             curs = np.array([a[1] for a in activation], np.int32)
             buds = np.array([a[2] for a in activation], np.int32)
@@ -3458,6 +3659,7 @@ class ContinuousBatchingEngine:
                 free.insert(0, rid)
                 break
             self._preempted.pop(0)
+            fill.state_slot = rid
             self._set_row_blocks(rid, fill.blocks)
             row.filling = True
             self.rows[rid] = row
@@ -3526,6 +3728,7 @@ class ContinuousBatchingEngine:
                         self._pending.insert(0, req)
                     break
                 self._filling.append(fill)
+                fill.state_slot = rid
                 self._set_row_blocks(rid, fill.blocks)
                 prefix_hits += fill.fill_pos > 0
                 # canonical blocks live in target 0's table; refcount
@@ -3706,7 +3909,7 @@ class ContinuousBatchingEngine:
             for i, _ in snapshot
         ]
         page = self.page_size if self.paged else self.kv_cache_len
-        span.set_metadata(
+        counts = dict(
             rows=len(snapshot),
             ctx_tokens_sum=sum(ctx),
             chunk_size=chunk_size,
@@ -3714,6 +3917,10 @@ class ContinuousBatchingEngine:
             page_slots=len(self.rows)
             * (self.blocks_per_row if self.paged else 1),
         )
+        if self._stateful:
+            # state slots the chunk advances, at most: rows x steps
+            counts["state_rows_sum"] = len(snapshot) * chunk_size
+        span.set_metadata(**counts)
 
     def _dispatch_chunk_paged(self):
         snapshot = [
@@ -3728,6 +3935,26 @@ class ContinuousBatchingEngine:
         if self._tables_dirty:
             self._tables = self._upload_tables()
             self._tables_dirty = False
+        if self._stateful:
+            (
+                self.k_pool, self.v_pool, self.ssm_state, self.conv_state,
+                self.kv_lengths, out_t, out_l, emitted, self.cur_tokens,
+                self.active, self.budgets, _, pairs, routed,
+            ) = hybrid.hybrid_decode_chunk(
+                self.params, self.k_pool, self.v_pool, self.ssm_state,
+                self.conv_state, self.cfg, self._tables, self.kv_lengths,
+                self.cur_tokens, self.active, self.budgets,
+                self._sample_base_rng, self.chunk_size,
+                self._paged_sample_fn, self._paged_stop_fn,
+                use_kernel=self._use_paged_kernel,
+                max_len=self.kv_cache_len, row_seeds=self.row_seeds,
+            )
+            self._enqueue_chunk(
+                out_t, out_l, emitted, self.active, self.cur_tokens,
+                snapshot,
+                extra=(pairs, routed) if self._keep_routed else (pairs,),
+            )
+            return
         out = paged.paged_decode_chunk(
             self.params,
             self.k_pool,
@@ -4066,6 +4293,10 @@ class ContinuousBatchingEngine:
         out.version_start = row.version_start
         out.version_end = self.version
         self.gen_tokens_total += len(row.generated)
+        if self._keep_routed and row.routed is not None:
+            while len(self._routed_done) >= self._keep_routed:
+                del self._routed_done[next(iter(self._routed_done))]
+            self._routed_done[row.req.qid] = np.concatenate(row.routed)
         if started and self.paged and row_id >= 0:
             # cached KV covers prompt + generated[:-1] (the final token is
             # the pending cur; its KV was never written).  Inserting on
@@ -4096,6 +4327,13 @@ class ContinuousBatchingEngine:
             ev = self._result_events.get(row.req.qid)
         if ev:
             ev.set()
+
+    def routed_experts(self, qid: str) -> Optional[np.ndarray]:
+        """``[prompt + generated - 1, L, K]`` int16: every layer's routed
+        experts (published numbers) at each position request ``qid`` READ,
+        in order: entry ``t`` stands behind the log-probability of token
+        ``t + 1``.  None unless ``keep_routed_experts`` still holds it."""
+        return self._routed_done.get(qid)
 
     def _attn_bucket(self, extra: int = 0) -> int:
         """Static attention prefix for the next chunk, as a power-of-two
@@ -4164,7 +4402,7 @@ class ContinuousBatchingEngine:
 
     def _enqueue_chunk(
         self, out_t, out_l, emitted, active_dev, cur_dev, snapshot,
-        spec_meta=None,
+        spec_meta=None, extra=(),
     ):
         """Append a dispatched chunk to the in-flight ring and START its
         device->host output copy.  The copy rides under the device time
@@ -4177,7 +4415,7 @@ class ContinuousBatchingEngine:
             x.addressable_data(0)
             if isinstance(x, jax.Array) and not x.is_fully_addressable
             else x
-            for x in (out_t, out_l, emitted, active_dev, cur_dev)
+            for x in (out_t, out_l, emitted, active_dev, cur_dev, *extra)
         )
         if jax_compat.start_host_copies(arrs):
             self.async_fetches_total += 1
@@ -4223,13 +4461,26 @@ class ContinuousBatchingEngine:
         self.chunks_total += 1
         with self._phases.phase("areal.engine.harvest.fold") as span:
             n_tokens = self._fold_chunk(chunk, fetched)
-            span.set_metadata(tokens=n_tokens)
+            counts = {"tokens": n_tokens}
+            if len(fetched) > 5:
+                # the chunk's (token, k) pairs by held expert, and last
+                # those routed to experts held elsewhere
+                pairs = np.asarray(fetched[5], np.int64)
+                self.moe_expert_pairs += pairs[:-1]
+                self.moe_pairs_held_total += int(pairs[:-1].sum())
+                self.moe_pairs_routed_total += int(pairs.sum())
+                counts.update(
+                    moe_pairs_held=int(pairs[:-1].sum()),
+                    moe_pairs_routed=int(pairs.sum()),
+                    moe_expert_pairs_max=int(pairs[:-1].max()),
+                )
+            span.set_metadata(**counts)
         return n_tokens
 
     def _fold_chunk(self, chunk: _InflightChunk, fetched) -> int:
         """Fold a fetched chunk's outputs into the host rows; returns the
         tokens it handed them."""
-        out_t, out_l, emitted, active, cur = fetched
+        out_t, out_l, emitted, active, cur = fetched[:5]
         snapshot = chunk.snapshot
         n_tokens = 0
         t_harvest = time.monotonic()  # chunk's tokens reach the host NOW
@@ -4248,6 +4499,11 @@ class ContinuousBatchingEngine:
             lps = out_l[row_id][cols].tolist()
             row.generated.extend(toks)
             row.logprobs.extend(lps)
+            if len(fetched) > 6 and row.routed is not None:
+                # [W, L, K, B] -> this row's emitted steps as [n, L, K]
+                row.routed.append(
+                    fetched[6][cols, :, :, row_id].astype(np.int16)
+                )
             row.budget_left -= len(toks)
             n_tokens += len(toks)
             if toks and self._slo_enabled:
@@ -4281,8 +4537,11 @@ class ContinuousBatchingEngine:
                 row.no_eos = last not in self.stop_tokens
                 # budget-exhausted rows with cache headroom stay resident so
                 # the chunked continuation resumes without re-prefill
+                # (a stateful model's rows never park: their slot is
+                # free at once and the continuation re-prefills)
                 park = (
                     row.no_eos
+                    and not self._stateful
                     and len(row.prompt) + len(row.generated) + 1
                     < self.kv_cache_len
                 )
@@ -4391,6 +4650,15 @@ class ContinuousBatchingEngine:
                                 rows_preempted=preempted,
                                 pages_live=self.pages_live,
                                 pages_total=self.pages_total,
+                                **(
+                                    {
+                                        "state_slots_live":
+                                            self.state_slots_live,
+                                        "state_slots_total": self.max_batch,
+                                    }
+                                    if self._stateful
+                                    else {}
+                                ),
                             )
                     dispatched = False
                     if (

@@ -10,7 +10,7 @@ specific conversion lives in ``areal_tpu/models/hf/``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,6 +48,39 @@ class TransformerConfig:
     # qwen3-moe: per-config ``norm_topk_prob``)
     moe_norm_topk_prob: bool = True
 
+    # a shared expert beside the routed ones: every token, weight 1
+    # (granitemoehybrid ``shared_intermediate_size``); 0 = none
+    shared_expert_dim: int = 0
+    # "softmax_topk": softmax over all experts, then the top k (mixtral,
+    # qwen3-moe); "topk_softmax": the top k LOGITS, softmax over those k
+    moe_router: str = "softmax_topk"
+    # the experts THIS program holds, [first, first + held) of n_experts
+    # (one chip's share of a deployment that divides each layer's experts
+    # over chips).  The router keeps n_experts outputs and its top k; a
+    # pair routed to an absent expert adds nothing.  None = all of them.
+    moe_first_expert: int = 0
+    moe_held_experts: Optional[int] = None
+
+    # a stack stated by kind (models/hybrid.py): one of "attention" |
+    # "mamba" per layer, in the published order; None = every layer is
+    # the attention layer of models/transformer.py
+    layer_types: Optional[Tuple[str, ...]] = None
+    # Mamba-2 mixer sizes (d_inner = mamba_n_heads * mamba_head_dim)
+    mamba_n_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    # granite's multipliers: softmax scale of attention (None =
+    # 1/sqrt(head_dim)), the factor on every residual branch, and the
+    # divisor of the logits; ``embed_scale`` above is the fourth
+    attention_scale: Optional[float] = None
+    residual_scale: Optional[float] = None
+    logits_divisor: Optional[float] = None
+    # False = no position term at all (NoPE)
+    use_rope: bool = True
+
     # head
     is_critic: bool = False  # value head (dim 1) instead of lm head
 
@@ -84,6 +117,23 @@ class TransformerConfig:
     pipe_schedule: str = "gpipe"
 
     def __post_init__(self):
+        if self.layer_types is not None:
+            # a JSON list hashes as a tuple (the config is a static jit arg)
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            assert len(self.layer_types) == self.n_layers, (
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"n_layers is {self.n_layers}"
+            )
+            assert set(self.layer_types) <= {"attention", "mamba"}, (
+                self.layer_types
+            )
+        assert self.moe_router in ("softmax_topk", "topk_softmax")
+        if self.moe_held_experts is not None:
+            assert (
+                0 <= self.moe_first_expert
+                and self.moe_first_expert + self.moe_held_experts
+                <= self.n_experts
+            ), (self.moe_first_expert, self.moe_held_experts, self.n_experts)
         assert self.n_q_heads % self.n_kv_heads == 0
         assert self.activation in ("silu", "gelu")
         assert self.norm_type in ("rms", "layer")
@@ -111,6 +161,37 @@ class TransformerConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_hybrid(self) -> bool:
+        """Layers of more than one kind: models/hybrid.py runs the stack."""
+        return self.layer_types is not None
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that keep per-token KV (every layer of a dense stack)."""
+        if self.layer_types is None:
+            return self.n_layers
+        return sum(t == "attention" for t in self.layer_types)
+
+    @property
+    def n_mamba_layers(self) -> int:
+        """Layers that keep a recurrent state per sequence."""
+        return self.n_layers - self.n_attn_layers
+
+    @property
+    def n_held_experts(self) -> int:
+        if self.moe_held_experts is None:
+            return self.n_experts
+        return self.moe_held_experts
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
 
 def tiny_config(

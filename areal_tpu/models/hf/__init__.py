@@ -1,6 +1,12 @@
 """HF family adapters.  Importing registers all families."""
 
-from areal_tpu.models.hf import gpt2, llama_like, mixtral, qwen3_moe  # noqa: F401
+from areal_tpu.models.hf import (  # noqa: F401
+    gpt2,
+    granitemoehybrid,
+    llama_like,
+    mixtral,
+    qwen3_moe,
+)
 from areal_tpu.models.hf.registry import (  # noqa: F401
     get_hf_family,
     load_hf_config,

@@ -68,6 +68,43 @@ def ep_axis_size(mesh) -> int:
     return int(mesh.shape.get("expert", 1))
 
 
+def local_expert_compute(
+    x: jax.Array,  # [N, D] (compute dtype)
+    topk_idx: jax.Array,  # [N, K] global expert ids
+    gate_w: jax.Array,  # [E_held, D, F]: experts first .. first + E_held
+    up_w: jax.Array,
+    down_w: jax.Array,  # [E_held, F, D]
+    first,  # global id of the first held expert (python int or traced)
+    act_kind: str,
+) -> jax.Array:
+    """What the experts ``[first, first + E_held)`` give for the (token,
+    k) pairs routed to them: ``[N*K, D]`` in canonical (token, k) order,
+    exact zeros for every pair routed elsewhere.  The local part of
+    expert parallelism: each shard of :func:`_ep_expert_compute` calls it
+    with its own ``first`` and sums the shards; one chip's share of a
+    stated deployment (:func:`held_moe_mlp`) calls it alone, with no
+    exchange and nothing standing in for the absent chips.
+
+    Pairs are sorted by local expert id for one ``ragged_dot`` a
+    projection; pairs of absent experts ride group 0 with their inputs
+    zeroed, so they flow exact zeros through silu, product and down."""
+    e_held = gate_w.shape[0]
+    K = topk_idx.shape[1]
+    flat = topk_idx.reshape(-1) - first  # [N*K] local expert ids
+    is_local = (flat >= 0) & (flat < e_held)
+    key = jnp.where(is_local, flat, 0)
+    order = jnp.argsort(key)
+    inv_order = jnp.argsort(order)
+    xs = jnp.repeat(x, K, axis=0)[order]
+    xs = jnp.where(is_local[order][:, None], xs, 0)
+    group_sizes = jnp.bincount(key, length=e_held).astype(jnp.int32)
+    gate = jax.lax.ragged_dot(xs, gate_w, group_sizes)
+    up = jax.lax.ragged_dot(xs, up_w, group_sizes)
+    act = jax.nn.silu(gate) if act_kind == "silu" else jax.nn.gelu(gate)
+    out = jax.lax.ragged_dot(act * up, down_w, group_sizes)
+    return out[inv_order]
+
+
 def _ep_expert_compute(
     cfg: TransformerConfig,
     mesh,
@@ -87,12 +124,10 @@ def _ep_expert_compute(
     replicated in, expert weights arrive pre-sharded over ``expert``
     (the engine's serving pspecs shard the E axis ONLY, so no weight
     gather happens here), and each shard sorts its LOCAL (token, k)
-    pairs by local expert id for one ragged_dot per projection.
-    Non-local pairs are clamped into group 0 with their inputs zeroed —
-    they flow exact zeros through silu/mul/down — and the final ``psum``
-    over ``expert`` reassembles every pair from the one shard that owns
-    its expert."""
-    E, K = cfg.n_experts, cfg.n_experts_per_tok
+    pairs through :func:`local_expert_compute`; the final ``psum`` over
+    ``expert`` reassembles every pair from the one shard that owns its
+    expert."""
+    E = cfg.n_experts
     ep = ep_axis_size(mesh)
     assert E % ep == 0, (
         f"n_experts {E} not divisible by expert-parallel degree {ep}"
@@ -101,25 +136,11 @@ def _ep_expert_compute(
     from jax.sharding import PartitionSpec as P
 
     def local_fn(x, topk_idx, gate_w, up_w, down_w):
-        e_local = gate_w.shape[0]  # E / ep
-        e0 = jax.lax.axis_index("expert") * e_local
-        flat = topk_idx.reshape(-1) - e0  # [N*K] local expert ids
-        is_local = (flat >= 0) & (flat < e_local)
-        key = jnp.where(is_local, flat, 0)
-        order = jnp.argsort(key)
-        inv_order = jnp.argsort(order)
-        xs = jnp.repeat(x, K, axis=0)[order]
-        # zeroed non-local rows ride group 0: their gate/up are exact
-        # zeros, so the whole pair contributes 0 to the psum below
-        xs = jnp.where(is_local[order][:, None], xs, 0)
-        group_sizes = jnp.bincount(key, length=e_local).astype(jnp.int32)
-        gate = jax.lax.ragged_dot(xs, gate_w, group_sizes)
-        up = jax.lax.ragged_dot(xs, up_w, group_sizes)
-        act = (
-            jax.nn.silu(gate) if act_kind == "silu" else jax.nn.gelu(gate)
+        e0 = jax.lax.axis_index("expert") * gate_w.shape[0]
+        out = local_expert_compute(
+            x, topk_idx, gate_w, up_w, down_w, e0, act_kind
         )
-        out = jax.lax.ragged_dot(act * up, down_w, group_sizes)
-        return jax.lax.psum(out[inv_order], "expert")
+        return jax.lax.psum(out, "expert")
 
     w_spec = P("expert", None, None)
     fn = jax.shard_map(
@@ -224,3 +245,114 @@ def moe_mlp(
     out = jnp.sum(expert_out * topk_probs[..., None].astype(h.dtype), axis=1)
     aux = {"moe_aux_loss": aux_loss, "moe_z_loss": z_loss}
     return out.reshape(B, T, D), aux
+
+
+#: tokens of one :func:`dense_expert_compute` call; a longer input goes
+#: through it piece by piece (the served calls are a decode step's 64 rows
+#: and a fill's 4 x 256 at most, so only whole-sequence forwards are cut)
+DENSE_EXPERTS_CALL_TOKENS = 1024
+
+
+def dense_expert_compute(x, w_tok, gate_w, up_w, down_w, act_kind: str):
+    """``sum_e w_tok[n, e] * expert_e(x[n])`` over the held experts with
+    every expert computed for every token: ``x`` [N, D], ``w_tok`` [N,
+    E_held] (0 where token n was not routed to e), all three weights
+    ``[E_held, F, D]`` (the hidden width minor in each: given gate and up
+    as ``[E, D, F]`` the TPU compiler transposes the whole layer stack
+    first, 2.1 GB a projection at 10 x 36 experts).  Returns [N, D].
+
+    Why not pairs sorted by expert for ``ragged_dot``
+    (:func:`local_expert_compute`, the expert-parallel path's): that is a
+    custom call on the TPU, for which a layer's weights, sliced from the
+    layer stack, are COPIED (1.36 GB written and read again a layer at 36
+    experts of 4096 x 768 x 3, by a described-v5e compile, PR 31), where
+    a plain dot reads the slice in place."""
+    g = jnp.einsum("nd,efd->enf", x, gate_w)
+    u = jnp.einsum("nd,efd->enf", x, up_w)
+    act = jax.nn.silu(g) if act_kind == "silu" else jax.nn.gelu(g)
+    hid = act * u * w_tok.T[:, :, None].astype(x.dtype)
+    return jnp.einsum("enf,efd->nd", hid, down_w)
+
+
+def route(cfg: TransformerConfig, x: jax.Array, router_w: jax.Array):
+    """``(weights [N, K] f32, expert ids [N, K], logits [N, E] f32)`` of
+    the tokens ``x`` [N, D], by ``cfg.moe_router``: the router always
+    has its published ``n_experts`` outputs and takes its published k,
+    however many experts this program holds."""
+    K = cfg.n_experts_per_tok
+    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)  # [N, E]
+    if cfg.moe_router == "topk_softmax":
+        top, idx = jax.lax.top_k(logits, K)
+        return jax.nn.softmax(top, axis=-1), idx, logits
+    probs = jax.nn.softmax(logits, axis=-1)
+    top, idx = jax.lax.top_k(probs, K)
+    if cfg.moe_norm_topk_prob:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return top, idx, logits
+
+
+def held_moe_mlp(
+    cfg: TransformerConfig,
+    h: jax.Array,  # [B, T, D]
+    p: Dict[str, Any],  # one layer's {"router", "experts"[, "shared"]}
+    valid: Optional[jax.Array] = None,  # [B, T] bool
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The expert layer of a program that is TOLD which experts it holds
+    (``cfg.moe_first_expert``, ``cfg.n_held_experts``; ``p["experts"]``
+    holds exactly those, gate, up and down each ``[E_held, F, D]``):
+    routes over all ``n_experts``, computes its own
+    experts' part of the result, adds the shared expert (every token,
+    weight 1).  Returns ``(out [B, T, D], pairs [E_held + 1] int32,
+    expert ids [B, T, K] int32)``: the valid (token, k) pairs each held
+    expert took and, last, those routed to experts held elsewhere; and
+    each token's routed experts, by their published numbers, for a
+    caller that hands the routing out (a routing-replay trainer, a
+    parity check that follows the server's choices)."""
+    B, T, D = h.shape
+    x = h.reshape(-1, D)
+    w, idx, _ = route(cfg, x, p["router"]["w"])
+    first, held = cfg.moe_first_expert, cfg.n_held_experts
+    ex = p["experts"]
+    gate_w = quantize.leaf_weight(ex["gate"], h.dtype)
+    up_w = quantize.leaf_weight(ex["up"], h.dtype)
+    down_w = quantize.leaf_weight(ex["down"], h.dtype)
+    local = idx - first
+    is_held = (local >= 0) & (local < held)
+    # each token's weight for each held expert, 0 where not routed
+    w_tok = jnp.sum(
+        jnp.where(
+            local[:, :, None] == jnp.arange(held)[None, None, :],
+            w[:, :, None], 0.0,
+        ),
+        axis=1,
+    )
+    N, C = x.shape[0], DENSE_EXPERTS_CALL_TOKENS
+    if N <= C:
+        out = dense_expert_compute(
+            x, w_tok, gate_w, up_w, down_w, cfg.activation
+        )
+    else:
+        pad = (-N) % C
+        out = jax.lax.map(
+            lambda xw: dense_expert_compute(
+                *xw, gate_w, up_w, down_w, cfg.activation
+            ),
+            (
+                jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, C, D),
+                jnp.pad(w_tok, ((0, pad), (0, 0))).reshape(-1, C, held),
+            ),
+        ).reshape(-1, D)[:N]
+    if "shared" in p:
+        sh = p["shared"]
+        g = x @ quantize.leaf_weight(sh["gate"], h.dtype)
+        u = x @ quantize.leaf_weight(sh["up"], h.dtype)
+        act = jax.nn.silu(g) if cfg.activation == "silu" else jax.nn.gelu(g)
+        out = out + (act * u) @ quantize.leaf_weight(sh["down"], h.dtype)
+    slot = jnp.where(is_held, local, held)  # [N, K]
+    if valid is not None:
+        slot = jnp.where(valid.reshape(-1)[:, None], slot, held + 1)
+    pairs = jnp.bincount(slot.reshape(-1), length=held + 2)[: held + 1]
+    return (
+        out.reshape(B, T, D), pairs.astype(jnp.int32),
+        idx.reshape(B, T, -1).astype(jnp.int32),
+    )

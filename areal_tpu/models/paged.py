@@ -88,7 +88,8 @@ def pool_zeros(
     a page's every head in a single DMA)."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     shape = (
-        cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim
+        cfg.n_attn_layers, n_blocks, cfg.n_kv_heads, block_size,
+        cfg.head_dim,
     )
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
@@ -121,7 +122,8 @@ def alloc_kv_pool(
             f"kv_cache_dtype must be 'auto' or 'int8', got {kv_cache_dtype!r}"
         )
     shape = (
-        cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim
+        cfg.n_attn_layers, n_blocks, cfg.n_kv_heads, block_size,
+        cfg.head_dim,
     )
     sshape = shape[:-1]
     return (
@@ -145,7 +147,8 @@ def kv_pool_layout_bytes(
     this (the allocation itself runs under jit, where a host-side ledger
     call cannot live); ``scale_bytes`` is 0 for fp pools."""
     shape = (
-        cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim
+        cfg.n_attn_layers, n_blocks, cfg.n_kv_heads, block_size,
+        cfg.head_dim,
     )
     n = 1
     for d in shape:
@@ -202,10 +205,12 @@ def _prefix_plan(
 def _prefix_partials(
     q, k_pool, v_pool, tables, lengths, layer, use_kernel,
     mesh=None, kv_axis=None, k_scale=None, v_scale=None, plan=None,
+    scale=None,
 ):
     """Paged-attention partials over each row's cached prefix.  ``q`` is
     [B, Q, Hq, hd]; returns (acc, m, l) with Q query tokens per row.
-    ``plan`` is :func:`_prefix_plan` of the same arguments.
+    ``plan`` is :func:`_prefix_plan` of the same arguments; ``scale`` the
+    model's softmax scale where it is not ``1/sqrt(hd)`` (None).
 
     ``k_scale``/``v_scale`` mark an int8-quantized pool: both the kernel
     and the jnp reference dequantize (multiply by the per-(block, head,
@@ -224,7 +229,7 @@ def _prefix_partials(
             return paged_flash_attention(
                 q, k_pool, v_pool, tables, lengths, layer=layer,
                 interpret=interp, k_scale=k_scale, v_scale=v_scale,
-                plan=plan,
+                plan=plan, scale=scale,
             )
         from jax.sharding import PartitionSpec as P
 
@@ -245,7 +250,7 @@ def _prefix_partials(
             ks, vs = sc if sc else (None, None)
             return paged_flash_attention(
                 qq, kk, vv, tb, ln, layer=ly, interpret=interp,
-                k_scale=ks, v_scale=vs, plan=pp,
+                k_scale=ks, v_scale=vs, plan=pp, scale=scale,
             )
 
         fn = jax.shard_map(
@@ -279,7 +284,7 @@ def _prefix_partials(
         ksl = jax.lax.dynamic_index_in_dim(k_scale, layer, 0, keepdims=False)
         vsl = jax.lax.dynamic_index_in_dim(v_scale, layer, 0, keepdims=False)
     return reference_paged_partials(
-        q, kl, vl, tables, lengths, k_scale=ksl, v_scale=vsl
+        q, kl, vl, tables, lengths, k_scale=ksl, v_scale=vsl, scale=scale
     )
 
 
@@ -360,6 +365,76 @@ def write_kv_runs(
     )
 
 
+def chunk_attention(q, k, v, prefix, mask_chunk, scale, dtype):
+    """Attention of a chunk's queries ``q`` [F, C, Hq, hd] over the chunk
+    itself (``k``, ``v`` [F, C, Hkv, hd], causal by ``mask_chunk``
+    [F, Cq, Ckv]) merged online with the paged partials ``prefix`` =
+    ``(acc, m, l)`` over each row's cached prefix.  Returns [F, C,
+    Hq * hd] in ``dtype``."""
+    F, C, Hq, hd = q.shape
+    Hkv = k.shape[2]
+    r = Hq // Hkv
+    acc_p, m_p, l_p = prefix
+    # in-chunk causal scores (C <= ~1k keeps [F,Hq,C,C] small)
+    qg = q.reshape(F, C, Hkv, r, hd)
+    s_c = (
+        jnp.einsum(
+            "fikrd,fjkd->fkrij",
+            qg.astype(jnp.float32),
+            k.astype(jnp.float32),
+        )
+        * scale
+    )  # [F, Hkv, r, Cq, Ckv]
+    s_c = jnp.where(mask_chunk[:, None, None, :, :], s_c, _NEG_INF)
+    accp = acc_p.reshape(F, C, Hkv, r, hd).transpose(0, 2, 3, 1, 4)
+    mp = m_p.reshape(F, C, Hkv, r).transpose(0, 2, 3, 1)
+    lpp = l_p.reshape(F, C, Hkv, r).transpose(0, 2, 3, 1)
+    # online merge of prefix partials with the in-chunk scores
+    m_tot = jnp.maximum(mp, jnp.max(s_c, axis=-1))
+    p_c = jnp.exp(s_c - m_tot[..., None])
+    alpha = jnp.exp(mp - m_tot)
+    num = accp * alpha[..., None] + jnp.einsum(
+        "fkrij,fjkd->fkrid", p_c, v.astype(jnp.float32)
+    )
+    den = lpp * alpha + jnp.sum(p_c, axis=-1)
+    attn = (num / jnp.maximum(den, 1e-30)[..., None]).astype(dtype)
+    return attn.transpose(0, 3, 1, 2, 4).reshape(F, C, Hq * hd)
+
+
+def window_attention(q, wk_l, wv_l, prefix, mask_win, scale, dtype):
+    """Attention of one decode step's queries ``q`` [B, 1, Hq, hd] over
+    the chunk's window so far (``wk_l``, ``wv_l`` [W, B, Hkv, hd], valid
+    by ``mask_win`` [B, 1, 1, 1, W]) merged online with the paged
+    partials ``prefix`` over each row's cached prefix.  Returns [B, 1,
+    Hq * hd] in ``dtype``."""
+    B, _, Hq, hd = q.shape
+    Hkv = wk_l.shape[2]
+    r = Hq // Hkv
+    qg = q.reshape(B, 1, Hkv, r, hd)
+    s_win = (
+        jnp.einsum(
+            "btkrd,wbkd->bkrtw", qg, wk_l.astype(qg.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        * scale
+    )
+    s_win = jnp.where(mask_win, s_win, _NEG_INF)  # [B,Hkv,r,1,W]
+    acc, m_main, l_main = prefix
+    acc = acc.reshape(B, Hkv, r, hd)
+    m_main = m_main.reshape(B, Hkv, r)
+    l_main = l_main.reshape(B, Hkv, r)
+    sw = s_win[:, :, :, 0, :]  # [B,Hkv,r,W]
+    m_tot = jnp.maximum(m_main, jnp.max(sw, axis=-1))
+    p_win = jnp.exp(sw - m_tot[..., None])
+    alpha = jnp.exp(m_main - m_tot)
+    num = acc * alpha[..., None] + jnp.einsum(
+        "bkrw,wbkd->bkrd", p_win, wv_l.astype(jnp.float32)
+    )
+    den = l_main * alpha + jnp.sum(p_win, axis=-1)
+    attn = (num / jnp.maximum(den, 1e-30)[..., None]).astype(dtype)
+    return attn.reshape(B, 1, Hq * hd)
+
+
 def paged_window_forward(
     params: Params,
     k_pool: jax.Array,  # [L, NB, Hkv, BS, hd]
@@ -403,9 +478,8 @@ def paged_window_forward(
 
     Callers jit this (it is not jitted itself); the pools thread through
     donated args of the enclosing jit."""
-    F, C = tokens.shape
-    L, _, Hkv, _, hd = k_pool.shape
-    r = cfg.n_q_heads // Hkv
+    C = tokens.shape[1]
+    L, _, _, _, hd = k_pool.shape
     positions = starts[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     # masked rows must stream zero prefix blocks (their ``starts`` may be
     # any live length — e.g. non-participant rows of a verify window)
@@ -438,32 +512,8 @@ def paged_window_forward(
             mesh=mesh, kv_axis=kv_axis, k_scale=k_scale, v_scale=v_scale,
             plan=plan,
         )
-        # in-chunk causal scores (C <= ~1k keeps [F,Hq,C,C] small)
-        qg = q.reshape(F, C, Hkv, r, hd)
-        s_c = (
-            jnp.einsum(
-                "fikrd,fjkd->fkrij",
-                qg.astype(jnp.float32),
-                k.astype(jnp.float32),
-            )
-            * scale
-        )  # [F, Hkv, r, Cq, Ckv]
-        s_c = jnp.where(mask_chunk[:, None, None, :, :], s_c, _NEG_INF)
-        accp = acc_p.reshape(F, C, Hkv, r, hd).transpose(0, 2, 3, 1, 4)
-        mp = m_p.reshape(F, C, Hkv, r).transpose(0, 2, 3, 1)
-        lpp = l_p.reshape(F, C, Hkv, r).transpose(0, 2, 3, 1)
-        # online merge of prefix partials with the in-chunk scores
-        m_tot = jnp.maximum(mp, jnp.max(s_c, axis=-1))
-        p_c = jnp.exp(s_c - m_tot[..., None])
-        alpha = jnp.exp(mp - m_tot)
-        num = accp * alpha[..., None] + jnp.einsum(
-            "fkrij,fjkd->fkrid", p_c, v.astype(jnp.float32)
-        )
-        den = lpp * alpha + jnp.sum(p_c, axis=-1)
-        attn = (num / jnp.maximum(den, 1e-30)[..., None]).astype(x.dtype)
-        attn = (
-            attn.transpose(0, 3, 1, 2, 4)
-            .reshape(F, C, cfg.n_q_heads * hd)
+        attn = chunk_attention(
+            q, k, v, (acc_p, m_p, l_p), mask_chunk, scale, x.dtype
         )
         x = x + _proj(lp["attn"]["o"], attn)
         h2 = _norm(x, lp["mlp_norm"], cfg)
@@ -596,7 +646,6 @@ def paged_decode_chunk(
     B = cur_tokens.shape[0]
     W = chunk_size
     L, _, Hkv, _, hd = k_pool.shape
-    r = cfg.n_q_heads // Hkv
     base_lens = lengths  # frozen: pool-resident prefix per row
     # dead rows stream nothing (parked/freed rows keep their lengths)
     read_lens = jnp.where(active, base_lens, 0)
@@ -639,35 +688,14 @@ def paged_decode_chunk(
             )
             wk_l = jax.lax.dynamic_index_in_dim(wk, l, 0, keepdims=False)
             wv_l = jax.lax.dynamic_index_in_dim(wv, l, 0, keepdims=False)
-            qg = q.reshape(B, 1, Hkv, r, hd)
-            s_win = (
-                jnp.einsum(
-                    "btkrd,wbkd->bkrtw", qg, wk_l.astype(qg.dtype),
-                    preferred_element_type=jnp.float32,
-                )
-                * scale
-            )
-            s_win = jnp.where(mask_win, s_win, _NEG_INF)  # [B,Hkv,r,1,W]
-            acc, m_main, l_main = _prefix_partials(
+            prefix = _prefix_partials(
                 q, k_pool, v_pool, tables, read_lens, l, use_kernel,
                 mesh=mesh, kv_axis=kv_axis,
                 k_scale=k_scale, v_scale=v_scale, plan=plan,
             )
-            acc = acc.reshape(B, Hkv, r, hd)
-            m_main = m_main.reshape(B, Hkv, r)
-            l_main = l_main.reshape(B, Hkv, r)
-            sw = s_win[:, :, :, 0, :]  # [B,Hkv,r,W]
-            m_tot = jnp.maximum(m_main, jnp.max(sw, axis=-1))
-            p_win = jnp.exp(sw - m_tot[..., None])
-            alpha = jnp.exp(m_main - m_tot)
-            num = acc * alpha[..., None] + jnp.einsum(
-                "bkrw,wbkd->bkrd", p_win, wv_l.astype(jnp.float32)
+            attn = window_attention(
+                q, wk_l, wv_l, prefix, mask_win, scale, x.dtype
             )
-            den = l_main * alpha + jnp.sum(p_win, axis=-1)
-            attn = (num / jnp.maximum(den, 1e-30)[..., None]).astype(
-                x.dtype
-            )
-            attn = attn.reshape(B, 1, cfg.n_q_heads * hd)
             x = x + _proj(lp["attn"]["o"], attn)
             h2 = _norm(x, lp["mlp_norm"], cfg)
             mlp_out, _ = _mlp_block(cfg, lp, h2, mesh=mesh)

@@ -582,7 +582,7 @@ def _attn_qkv(cfg: TransformerConfig, lp: Params, h, positions, rope_cs):
     if cfg.use_qk_norm:
         q = _head_norm(q, lp["attn"]["q_norm"]["scale"], cfg.norm_eps)
         k = _head_norm(k, lp["attn"]["k_norm"]["scale"], cfg.norm_eps)
-    if not cfg.abs_position_embedding:
+    if not cfg.abs_position_embedding and cfg.use_rope:
         if rope_cs is None:
             rope_cs = rope_tables(positions, cfg.rotary_base, cfg.head_dim)
         q = rope_apply(q, *rope_cs)

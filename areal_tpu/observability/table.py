@@ -88,6 +88,20 @@ METRIC_TABLE = [
         "Blocks of the paged KV pool (0 on a dense-cache engine)",
     ),
     MetricSpec(
+        "areal_inference_state_slots_live",
+        "gauge",
+        "Recurrent-state slots (SSM state + conv tail per Mamba layer, "
+        "one a batch row) held by rows that decode or fill; 0 for a "
+        "model without such state",
+    ),
+    MetricSpec(
+        "areal_inference_moe_expert_pairs",
+        "gauge",
+        "(token, k) pairs decode chunks have routed to each expert this "
+        "server holds, since it started (a stack stated by kind only)",
+        ("expert",),
+    ),
+    MetricSpec(
         "areal_inference_prefill_tokens_total",
         "counter",
         "Unique-prompt tokens actually prefilled (post group-dedup)",
@@ -1083,7 +1097,10 @@ TRACE_TABLE = [
         "areal.engine.fill.dispatch",
         "phase",
         "One batched prefill chunk built on the host and dispatched "
-        "(counts: prompts, f_pad, c, tokens)",
+        "(counts: prompts, f_pad, c, tokens; for a model with recurrent "
+        "state also the running totals state_copies = sibling copies of "
+        "a fill's end state, state_reprefills = requests that matched "
+        "cached pages and prefilled from 0 all the same)",
     ),
     TraceSpec(
         "areal.engine.fill.first_token_wait",
@@ -1104,7 +1121,8 @@ TRACE_TABLE = [
         "phase",
         "Every decoding row's table extended to cover the next chunk "
         "(counts: blocks_allocated, rows_preempted, and pages_live and "
-        "pages_total after it)",
+        "pages_total after it; for a model with recurrent state also "
+        "state_slots_live, state_slots_total)",
     ),
     TraceSpec(
         "areal.engine.decode.dispatch",
@@ -1112,7 +1130,8 @@ TRACE_TABLE = [
         "One decode chunk (or verify window) dispatched (counts: rows, "
         "ctx_tokens_sum = prompt + generated known to the host over the "
         "dispatched rows, chunk_size, pages_attended, page_slots = "
-        "batch slots x pages a slot's table holds)",
+        "batch slots x pages a slot's table holds; state_rows_sum = "
+        "rows x steps whose state slots the chunk advances)",
     ),
     TraceSpec(
         "areal.engine.harvest.wait",
@@ -1130,7 +1149,9 @@ TRACE_TABLE = [
         "areal.engine.harvest.fold",
         "phase",
         "The fetched chunk folded into the host rows, finished rows "
-        "parked or released (counts: tokens)",
+        "parked or released (counts: tokens; for a model that holds a "
+        "share of the experts also moe_pairs_held, moe_pairs_routed, "
+        "moe_expert_pairs_max of the chunk)",
     ),
     # -- phase spans: what the profiler would drop ----------------------------
     TraceSpec(

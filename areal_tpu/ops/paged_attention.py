@@ -360,7 +360,7 @@ def _layer_scalar(layer):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
 def paged_flash_attention(
     q: jax.Array,  # [B, Q, Hq, hd]
     k_pool: jax.Array,  # [NB, Hkv, BS, hd] or [L, NB, Hkv, BS, hd]
@@ -372,6 +372,7 @@ def paged_flash_attention(
     k_scale: jax.Array | None = None,  # [(L,) NB, Hkv, BS] int8-pool scales
     v_scale: jax.Array | None = None,
     plan: Optional[PagePlan] = None,  # plan_pages(tables, lengths, ...)
+    scale: Optional[float] = None,  # softmax scale; None = 1/sqrt(hd)
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Un-normalized online-softmax attention partials over paged KV.
 
@@ -447,7 +448,7 @@ def paged_flash_attention(
         functools.partial(
             _kernel,
             block_size=BS,
-            scale=1.0 / np.sqrt(hd),
+            scale=1.0 / np.sqrt(hd) if scale is None else scale,
             n_kv_heads=Hkv,
             page_group=G,
             quantized=quantized,
@@ -532,7 +533,8 @@ def gather_paged_kv(
 
 
 def reference_paged_partials(
-    q, k_pool, v_pool, tables, lengths, k_scale=None, v_scale=None
+    q, k_pool, v_pool, tables, lengths, k_scale=None, v_scale=None,
+    scale=None,
 ):
     """jnp reference for :func:`paged_flash_attention` (same contract).
 
@@ -551,9 +553,8 @@ def reference_paged_partials(
         v = v.astype(jnp.float32) * vs
     S = k.shape[2]
     qg = q.reshape(B, Q, Hkv, r, hd).astype(jnp.float32)
-    s = jnp.einsum(
-        "bqkrd,bksd->bqkrs", qg, k.astype(jnp.float32)
-    ) / np.sqrt(hd)
+    s = jnp.einsum("bqkrd,bksd->bqkrs", qg, k.astype(jnp.float32))
+    s = s / np.sqrt(hd) if scale is None else s * scale
     mask = (
         jnp.arange(S)[None, None, None, None, :]
         < lengths[:, None, None, None, None]

@@ -341,6 +341,7 @@ class GenerationServerWorker(worker_base.Worker):
             prefix_pull_min_tokens=getattr(
                 config, "prefix_pull_min_tokens", 256
             ),
+            keep_routed_experts=getattr(config, "keep_routed_experts", 0),
         )
 
         self._ctx = zmq.Context.instance()
@@ -625,6 +626,10 @@ class GenerationServerWorker(worker_base.Worker):
             "inflight": reg.gauge("areal_inference_inflight_rows"),
             "pages_live": reg.gauge("areal_inference_kv_pages_live"),
             "pages_total": reg.gauge("areal_inference_kv_pages_total"),
+            "state_slots_live": reg.gauge(
+                "areal_inference_state_slots_live"
+            ),
+            "moe_expert_pairs": reg.gauge("areal_inference_moe_expert_pairs"),
             "pending": reg.gauge("areal_inference_pending_requests"),
             "version": reg.gauge("areal_inference_weight_version"),
             "ring_depth": reg.gauge("areal_inference_ring_depth"),
@@ -799,6 +804,9 @@ class GenerationServerWorker(worker_base.Worker):
         self._obs["inflight"].set(eng.n_inflight)
         self._obs["pages_live"].set(eng.pages_live)
         self._obs["pages_total"].set(eng.pages_total)
+        self._obs["state_slots_live"].set(eng.state_slots_live)
+        for e, n in enumerate(eng.moe_expert_pairs.tolist()):
+            self._obs["moe_expert_pairs"].set(n, expert=str(e))
         self._obs["pending"].set(eng.n_pending)
         self._obs["version"].set(eng.version)
         self._obs["ring_depth"].set(eng.pipeline_depth)

@@ -1,0 +1,27 @@
+"""``decode_hbm_share`` for a stack stated by kind: least time by HBM
+bandwidth for the window's decode work over the time the device was busy,
+both scaled to the window.  Each decode step reads the weights this chip
+holds once; every live (row, step) reads and writes the row's recurrent
+state in every Mamba layer (``tokens_emitted``: one token a live row a
+step); the steps together read the attention layers' KV of every context
+position each emitted token attended to (``lib/flops_hybrid.py``).  Busy
+time is the trace's busy share times the window.  Prefill's bytes are not
+counted, so the share reads a little low.  The share of the whole step
+that bounds later claims in this cell."""
+
+from benchmark.lib import flops_hybrid
+
+
+def value(ctx):
+    c, tr = ctx.window["counters"], ctx.trace
+    if not tr or "layer_types" not in c or c["decode_chunks"] <= 0:
+        return None
+    least = flops_hybrid.decode_min_seconds(
+        ctx.config["hf_config"], c["layer_types"], c["held_experts"],
+        decode_steps=c["decode_chunks"] * c["chunk_size"],
+        row_steps=c["tokens_emitted"],
+        context_token_reads=c["context_token_reads"],
+        hbm_bytes_per_s=ctx.peaks["hbm_bytes_per_s"],
+    )
+    busy = tr["busy_s"] / tr["window_s"] * c["window_s"]
+    return 100.0 * least / busy if busy > 0 else None

@@ -276,3 +276,52 @@ def test_shared_blocks_between_rows():
     np.testing.assert_allclose(
         np.asarray(acc[0]), np.asarray(acc[1]), rtol=1e-6, atol=1e-6
     )
+
+
+# -- the model's own softmax scale, and 8 kv heads x 4 q (neither Qwen shape) --
+
+
+@pytest.mark.parametrize("Q", [1, 24])
+@pytest.mark.parametrize("scale", [None, 0.0078125, 0.2])
+def test_softmax_scale_is_an_argument_with_the_old_default(scale, Q):
+    """``scale=None`` is 1/sqrt(hd) as before; a model that states its
+    own (granite's ``attention_multiplier``) hands it in.  At 32 q / 8 kv
+    heads the kernel's tile plan groups 4 query heads a kv head."""
+    lengths = [300, 0, 512, 77]
+    q, kp, vp, tables, lens = _setup(
+        B=4, Q=Q, Hq=32, Hkv=8, NB=24, lengths=lengths, seed=7,
+        q_dtype=jnp.bfloat16, engine_tables=True,
+    )
+    got = paged_flash_attention(
+        q, kp, vp, tables, lens, interpret=True, scale=scale
+    )
+    want = reference_paged_partials(q, kp, vp, tables, lens, scale=scale)
+    _assert_matches_reference(got, want, lens)
+    # the scale is in the scores, not folded away: another scale is
+    # another softmax
+    hd = q.shape[-1]
+    if scale not in (None, 1.0 / np.sqrt(hd)):
+        default = reference_paged_partials(q, kp, vp, tables, lens)
+        assert not np.allclose(np.asarray(default[1]), np.asarray(want[1]))
+    else:
+        explicit = reference_paged_partials(
+            q, kp, vp, tables, lens, scale=1.0 / np.sqrt(hd)
+        )
+        np.testing.assert_allclose(
+            np.asarray(explicit[0]), np.asarray(want[0]), rtol=1e-4, atol=1e-4
+        )
+
+
+@pytest.mark.parametrize("Q", [1, 64, 256])
+def test_tile_plan_for_eight_kv_heads_of_four_queries(Q):
+    """The published granite head layout at the engine's page of 1,024:
+    the plan keeps within the VMEM budget with whole sublane tiles."""
+    from areal_tpu.ops import paged_attention as pa
+
+    G, QT = pa._plan_tiles(Q, 4, 8, 1024, 128, 2, False, 4)
+    assert 1 <= G <= pa.PAGE_GROUP and 1 <= QT <= Q
+    assert QT == Q or (QT * 4) % 8 == 0
+    assert (
+        pa.vmem_bytes_needed(8, 1024, 128, 2, False, G, QT * 4)
+        <= pa.VMEM_BUDGET_BYTES
+    )

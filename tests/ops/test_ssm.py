@@ -1,0 +1,89 @@
+"""``ops/ssm.py`` in interpret mode against plain ``jnp``: the decode
+step of the Mamba-2 recurrence over layer-stacked state slots, in place,
+live slots only; and the row reader a fill takes its states with."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from areal_tpu.ops import ssm
+
+LM, S, N, HP = 3, 6, 16, 32
+
+
+def _inputs(seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return dict(
+        state=jax.random.normal(k[0], (LM, S, N, HP)),
+        decay=jax.random.uniform(k[1], (S, HP)),
+        dtx=jax.random.normal(k[2], (S, HP)),
+        b=jax.random.normal(k[3], (S, N)),
+        c=jax.random.normal(k[4], (S, N)),
+    )
+
+
+def _by_hand(state, layer, decay, dtx, b, c):
+    """S <- S * decay + B (x) dtx; y = C^T S, slot by slot in numpy."""
+    s = np.asarray(state[layer], np.float64)
+    new = s * np.asarray(decay)[:, None, :] + (
+        np.asarray(b)[:, :, None] * np.asarray(dtx)[:, None, :]
+    )
+    return np.einsum("snr,sn->sr", new, np.asarray(c)), new
+
+
+@pytest.mark.parametrize(
+    "live",
+    [[1, 0, 1, 1, 0, 0], [1] * 6, [0] * 6, [0, 0, 0, 0, 0, 1], [1, 0, 0, 0, 0, 0]],
+    ids=["mixed", "all", "none", "last_only", "first_only"],
+)
+@pytest.mark.parametrize("layer", [0, 2])
+def test_state_update_kernel_is_the_recurrence_and_leaves_dead_slots_alone(
+    live, layer
+):
+    x = _inputs()
+    live = jnp.asarray(live, bool)
+    y_want, s_want = _by_hand(x["state"], layer, x["decay"], x["dtx"], x["b"], x["c"])
+    y, state = ssm.ssm_state_update(
+        x["state"].copy(), jnp.int32(layer), x["decay"], x["dtx"], x["b"],
+        x["c"], live, interpret=True,
+    )
+    y_ref, state_ref = ssm.ssm_state_update_reference(
+        x["state"], layer, x["decay"], x["dtx"], x["b"], x["c"], live
+    )
+    lv = np.asarray(live)
+    assert np.abs(np.asarray(state[layer])[lv] - s_want[lv]).max(initial=0) < 1e-5
+    assert np.abs(np.asarray(y)[lv] - y_want[lv]).max(initial=0) < 1e-4
+    # dead slots and every other layer: bit for bit what they were
+    assert np.array_equal(np.asarray(state[layer])[~lv], np.asarray(x["state"][layer])[~lv])
+    others = [l for l in range(LM) if l != layer]
+    assert np.array_equal(np.asarray(state)[others], np.asarray(x["state"])[others])
+    assert np.abs(np.asarray(state) - np.asarray(state_ref)).max() < 1e-5
+    assert np.abs((np.asarray(y) - np.asarray(y_ref))[lv]).max(initial=0) < 1e-4
+
+
+def test_state_update_tiles_a_wide_state_by_lane_blocks(monkeypatch):
+    """Two lane blocks a slot: the dead steps of the grid must name the
+    LAST block a live step touched, not the first."""
+    monkeypatch.setattr(ssm, "LANE_BLOCK", 16)
+    x = _inputs(1)
+    live = jnp.asarray([0, 1, 0, 1, 0, 0], bool)
+    y, state = ssm.ssm_state_update(
+        x["state"].copy(), jnp.int32(1), x["decay"], x["dtx"], x["b"], x["c"],
+        live, interpret=True,
+    )
+    y_ref, state_ref = ssm.ssm_state_update_reference(
+        x["state"], 1, x["decay"], x["dtx"], x["b"], x["c"], live
+    )
+    assert np.abs(np.asarray(state) - np.asarray(state_ref)).max() < 1e-5
+    lv = np.asarray(live)
+    assert np.abs((np.asarray(y) - np.asarray(y_ref))[lv]).max() < 1e-4
+
+
+@pytest.mark.parametrize("slots", [[4], [5, 0], [2, 2, 1, 3]])
+def test_state_rows_reads_the_slots_of_one_layer(slots):
+    state = _inputs(2)["state"]
+    got = ssm.ssm_state_rows(
+        state, jnp.int32(1), jnp.asarray(slots, jnp.int32), interpret=True
+    )
+    assert np.array_equal(np.asarray(got), np.asarray(state[1])[slots])

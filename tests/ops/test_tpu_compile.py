@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models import paged, transformer
+from areal_tpu.models import hybrid, paged, transformer
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.ops import flash_attention as fa
 from areal_tpu.ops import paged_attention as pa
@@ -433,6 +433,140 @@ def test_decode_program_keeps_the_pool_layout(
     ).compile()
     _assert_kernel(compiled)
     _assert_pool_stays_put(compiled, pool, temp_share=0.25)
+
+
+# --- a stack stated by kind: state slots beside the pool, at published widths ---
+
+#: granite-4.0-h-small as the cell runs it (benchmark/configs): one period
+#: of 10 layers, 36 of 72 experts held, every width as published; the
+#: cell's engine: 64 slots, 192 pages of 1,024 tokens, fills of 256 tokens
+HYBRID_SLOTS, HYBRID_FILL_C = 64, 256
+USABLE_HBM_BYTES = 15.75e9
+
+
+def _hybrid_cell_args(one_chip):
+    cfg = TransformerConfig(
+        n_layers=10, hidden_dim=4096, n_q_heads=32, n_kv_heads=8,
+        head_dim=HD, intermediate_dim=768, moe_intermediate_dim=768,
+        shared_expert_dim=1536, vocab_size=100352, norm_eps=1e-5,
+        tied_embedding=True, n_experts=72, n_experts_per_tok=10,
+        moe_router="topk_softmax", moe_held_experts=36,
+        layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
+        mamba_n_heads=128, mamba_head_dim=64, mamba_d_state=128,
+        embed_scale=12.0, attention_scale=0.0078125, residual_scale=0.22,
+        logits_divisor=16.0, use_rope=False,
+    )
+
+    def place(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    params = jax.tree.map(lambda a: place(a.shape, a.dtype), shapes)
+    pool = place((1, CELL_NB, 8, PAGE, HD), jnp.bfloat16)
+    ssm, conv = (
+        place(a.shape, a.dtype)
+        for a in jax.eval_shape(lambda: hybrid.state_zeros(cfg, HYBRID_SLOTS))
+    )
+    return cfg, params, pool, ssm, conv, place
+
+
+def _assert_state_and_pool_stay_put(compiled, pool, ssm):
+    """No copy of the SSM state (2.4 GB) or of a KV pool anywhere in the
+    optimized HLO, temporaries that cannot hold one, and the whole
+    program inside one chip's memory."""
+    assert _pool_copies(compiled, ssm.shape) == []
+    assert _pool_copies(compiled, pool.shape) == []
+    m = compiled.memory_analysis()
+    state_bytes = int(np.prod(ssm.shape)) * 4
+    assert m.temp_size_in_bytes < 0.25 * state_bytes, m.temp_size_in_bytes
+    total = (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+    assert total < USABLE_HBM_BYTES, total
+    return total
+
+
+# one prompt's chunk, two fills in a batch, three (padded to four)
+@pytest.mark.parametrize("F", [1, 2, 4])
+def test_hybrid_fill_program_fits_and_copies_no_state(
+    one_chip, monkeypatch, F
+):
+    """``hybrid_fill_chunk`` whole at the cell's shapes.  What this holds
+    down (described-v5e compiles, PR 31): rows of the stacked state read
+    by a gather were lowered through lane-block slices of the WHOLE state
+    (2.4 GB a Mamba layer); read by ``dynamic_slice``, the layout the SSD
+    products prefer ran back to the stacked operand at F 4 and the state
+    was converted whole inside the layer loop (hence the row-reader
+    kernel, ``ssm_state_rows``); at F 1 the one-layer attention run is
+    inlined and the kernel's read of the donated pool met the write loop
+    (two copies a pool, hence the barrier before ``write_kv_runs``); with
+    the conv tails carried through the layer loops the compiler held the
+    whole ``conv`` array in its fast memory (``S(1)``) across the loops
+    and their Mosaic calls, and on the chip three layers' tails of slots
+    25-63 came back overwritten (hence the read before and the write
+    after the stack: no loop of the program may carry ``conv``)."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    cfg, params, pool, ssm, conv, place = _hybrid_cell_args(one_chip)
+    compiled = hybrid.hybrid_fill_chunk.lower(
+        params, pool, pool, ssm, conv, cfg,
+        place((F, HYBRID_FILL_C), jnp.int32), place((F,), jnp.int32),
+        place((F,), jnp.int32), place((F, MB), jnp.int32),
+        place((F,), jnp.int32), use_kernel=True,
+    ).compile()
+    text = compiled.as_text()
+    assert "paged_attn_fill" in text and "ssm_state_rows" in text
+    conv_dims = "[" + ",".join(str(d) for d in conv.shape) + "]"
+    carried = [
+        line for line in text.splitlines()
+        if " while(" in line and conv_dims in line
+    ]
+    assert carried == [], carried[0][:200]
+    # 9.93 GB of weights + 2.45 GB of state + 0.81 GB of pool, and little else
+    total = _assert_state_and_pool_stay_put(compiled, pool, ssm)
+    assert total > 13.0e9
+
+
+def _keyed_greedy(logits, _rng, _positions, _seeds):
+    return _greedy(logits, _rng)
+
+
+def test_hybrid_decode_program_fits_and_updates_the_state_in_place(
+    one_chip, monkeypatch
+):
+    """``hybrid_decode_chunk`` whole (64 rows, 64 steps): the state goes
+    through the step loop and the layer scans as the kernel's aliased
+    operand, no copy of it or of the pool; the held experts' weights are
+    read where they lie (a ``ragged_dot`` custom call had each layer's
+    sliced from the stack into a copy, 1.36 GB a layer and step); the
+    conv tails, which this program does carry through its loops, stay in
+    HBM (in the fill program they did not: see above)."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    cfg, params, pool, ssm, conv, place = _hybrid_cell_args(one_chip)
+
+    def rows(dtype):
+        return place((HYBRID_SLOTS,), dtype)
+
+    compiled = hybrid.hybrid_decode_chunk.lower(
+        params, pool, pool, ssm, conv, cfg,
+        place((HYBRID_SLOTS, MB), jnp.int32), rows(jnp.int32),
+        rows(jnp.int32), rows(jnp.bool_), rows(jnp.int32),
+        place((2,), jnp.uint32), chunk_size=DECODE_W,
+        sample_fn=_keyed_greedy, stop_fn=_never_stop, use_kernel=True,
+        max_len=PAGE * MB, row_seeds=rows(jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "ssm_state_update" in text and "paged_attn_decode" in text
+    assert "ragged-dot" not in text
+    conv_dims = ",".join(str(d) for d in conv.shape)
+    assert not re.search(
+        r"\[" + conv_dims + r"\]\{[^}]*S\(1\)\}", text
+    ), "the conv state in the compiler's fast memory"
+    # no copy of one layer's expert weights either
+    assert _pool_copies(compiled, (36, 768, 4096)) == []
+    _assert_state_and_pool_stay_put(compiled, pool, ssm)
 
 
 # --- the trainer's step whole: what the layout rule may ask of one chip ---
