@@ -42,6 +42,7 @@ from areal_tpu.engine.optimizer import OptimizerConfig, make_optimizer
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import param_pspecs, takes_flash
 from areal_tpu.observability.tracing import phase
+from areal_tpu.ops import flash_attention
 
 logger = logging_.getLogger("train_engine")
 
@@ -408,9 +409,17 @@ class TrainEngine:
             self.padded_slots_total += slots
             self.real_tokens_total += real_tokens
             self._m_pad_frac.set(self.last_padding_frac, model=self.name)
+            # the block pairs the flash kernels run on this layout, of
+            # those under the diagonal (the kernels' own rule, on the host)
+            blocks_run, blocks_causal = flash_attention.blocks_run(
+                stacked["seg_ids"].reshape(-1, row_len)
+            )
+            self.attn_blocks_run_total += blocks_run
+            self.attn_blocks_causal_total += blocks_causal
             span.set_metadata(
                 real_tokens=real_tokens, padded_slots=slots, n_mbs=n_mbs,
-                rows=rows, row_len=row_len,
+                rows=rows, row_len=row_len, attn_blocks_run=blocks_run,
+                attn_blocks_causal=blocks_causal,
             )
             step = self._get_train_step(loss_fn, n_mbs)
             with phase("areal.train.dispatch"):
@@ -452,6 +461,10 @@ class TrainEngine:
     #: padded_slots_total
     padded_slots_total: int = 0
     real_tokens_total: int = 0
+    #: flash-attention block pairs run / under the diagonal, cumulative
+    #: (``ops/flash_attention.blocks_run`` of every layout built)
+    attn_blocks_run_total: int = 0
+    attn_blocks_causal_total: int = 0
 
     def _record_step_metrics(
         self,

@@ -501,7 +501,7 @@ def _warn_dense_fallback(
         causes.append(f"q_len {q_len} != kv_len {kv_len}")
     from areal_tpu.ops import flash_attention as fa
 
-    if q_len % min(fa._BLOCK, max(q_len, 1)) != 0:
+    if not fa.supported(q_len, q_len, None):
         causes.append(f"length {q_len} not block-aligned")
     cause = ", ".join(causes) or f"unsupported length {T}"
     key = (cause, T)

@@ -1188,7 +1188,8 @@ TRACE_TABLE = [
         "areal.train.batch",
         "phase",
         "One TrainEngine.train_batch call (counts: real_tokens, "
-        "padded_slots, n_mbs)",
+        "padded_slots, n_mbs, rows, row_len, attn_blocks_run, "
+        "attn_blocks_causal)",
     ),
     TraceSpec(
         "areal.train.pack",

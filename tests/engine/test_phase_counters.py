@@ -223,3 +223,56 @@ def test_train_engine_cumulative_slots_are_the_sum_of_each_calls(monkeypatch):
         c = s.counts
         assert c["row_len"] in (32, 64)
         assert c["n_mbs"] * c["rows"] * c["row_len"] == c["padded_slots"]
+
+
+@pytest.mark.parametrize("pack", [True, False])
+def test_train_batch_counts_the_attention_block_pairs_its_layout_runs(
+    monkeypatch, pack
+):
+    """``areal.train.batch`` carries ``attn_blocks_run`` and
+    ``attn_blocks_causal`` of the layout just built (the flash kernels'
+    own rule, ``ops/flash_attention.block_ranges``, on the host's ids):
+    the pairs under the diagonal in which a q and a kv slot share an id, by
+    brute force, of ``n (n + 1) / 2`` a row; the engine keeps their sums."""
+    from areal_tpu.engine import train_engine
+    from tests.ops.test_flash_attention import pairs_that_meet
+
+    monkeypatch.setattr(_RecordingSpan, "seen", [])
+    monkeypatch.setattr(train_engine, "phase", _RecordingSpan)
+    cfg = tiny_config(vocab_size=64, max_position_embeddings=2048)
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    mesh = MeshSpec(data=1, fsdp=1, model=1).make_mesh(jax.devices()[:1])
+    eng = TrainEngine(
+        cfg, mesh, params,
+        OptimizerConfig(lr=1e-3, warmup_steps_proportion=0.0), 100,
+        pack_sequences=pack,
+    )
+    laid_out = []
+    stack = eng._stack_batches
+
+    def recording_stack(*args):
+        stacked, pbs = stack(*args)
+        laid_out.append(stacked["seg_ids"])
+        return stacked, pbs
+
+    monkeypatch.setattr(eng, "_stack_batches", recording_stack)
+    assert (eng.attn_blocks_run_total, eng.attn_blocks_causal_total) == (0, 0)
+    # sequences of 300-1,100 tokens: rows of 2,048 slots, four blocks each
+    for seed, n_mbs in enumerate([1, 2]):
+        sample = make_sample(5, 64, seed=seed, min_len=300, max_len=1100)
+        eng.train_batch(sample, sft_loss_fn, MicroBatchSpec(n_mbs=n_mbs))
+    spans = [s for s in _RecordingSpan.seen if s.name == "areal.train.batch"]
+    assert len(spans) == len(laid_out) == 2
+    run_total = causal_total = 0
+    for span, seg in zip(spans, laid_out):
+        rows = seg.reshape(-1, seg.shape[-1])
+        n = rows.shape[1] // 512
+        assert n == span.counts["row_len"] // 512 >= 2
+        run = sum(len(pairs_that_meet(row, 512)) for row in rows)
+        assert span.counts["attn_blocks_run"] == run
+        assert span.counts["attn_blocks_causal"] == len(rows) * n * (n + 1) // 2
+        assert 0 < run < span.counts["attn_blocks_causal"]
+        run_total += run
+        causal_total += span.counts["attn_blocks_causal"]
+    assert eng.attn_blocks_run_total == run_total
+    assert eng.attn_blocks_causal_total == causal_total

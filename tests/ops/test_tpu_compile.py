@@ -175,12 +175,15 @@ def test_shard_mapped_paged_kernel_compiles_tp2(
     [
         ("qwen2.5-1.5b", 2, 2048),
         ("qwen2.5-1.5b", 1, 8192),
+        ("qwen2.5-1.5b", 1, 4608),
         ("qwen2.5-7b", 1, 4096),
     ],
 )
 def test_flash_attention_fwd_bwd_compiles(one_chip, model, B, T):
-    """The trainer's attention (JAX-shipped flash kernel at block 512) at
-    packed-row bucket lengths, forward and backward."""
+    """The trainer's attention (this repository's flash kernels at block
+    512, ``ops/flash_attention.py``) at the train cell's row lengths,
+    forward and backward: three Mosaic calls, each under its stable
+    name."""
     Hq, Hkv = HEADS[model]
 
     def loss(q, k, v, seg):
@@ -200,6 +203,9 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, model, B, T):
         .compile()
     )
     _assert_kernel(compiled)
+    text = compiled.as_text()
+    for name in ("flash_attn_fwd", "flash_attn_bwd_dq", "flash_attn_bwd_dkv"):
+        assert name in text, name
 
 
 @pytest.mark.parametrize("axes", [(1, 2, 1), (1, 1, 2)])
@@ -582,8 +588,8 @@ def test_train_step_at_the_train_cells_largest_shape_fits_one_chip(
     three batches: arguments and temporaries fit the 15.75 GB a v5e leaves
     a program, by the compiler's count.  A change of the rule that lays out
     more slots a micro-batch shows here, before any chip: ``[1, 8192]``
-    counts 14.3 GB, the parent's ``[3, 4096]`` 15.4, ``[4, 4096]`` would
-    not pass.
+    counts 14.15 GB (14.3 with the library's flash kernels, before PR 32),
+    PR 30's parent's ``[3, 4096]`` 15.4, ``[4, 4096]`` would not pass.
 
     The micro-batch is compiled as a program of ONE.  A described chip has
     no memory limit for the scheduler to work to, so a program that
