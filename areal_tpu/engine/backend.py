@@ -125,7 +125,9 @@ def make_model(
             from areal_tpu.models import hybrid
 
             params = hybrid.init_params(model_cfg, jax.random.PRNGKey(seed))
-            backend_name = "granitemoehybrid"
+            backend_name = (
+                "deepseek_v3" if model_cfg.is_latent else "granitemoehybrid"
+            )
         else:
             from areal_tpu.models.transformer import init_params
 

@@ -215,6 +215,10 @@ class _Fill:
     routed: List[Tuple[Any, int, int]] = dataclasses.field(
         default_factory=list
     )
+    #: ``keep_routed_experts``: the routing ``[n, L, K]`` behind the ``n``
+    #: tokens whose pages this fill took from the prefix cache (what the
+    #: fill that wrote them handed out), or None where nobody kept it
+    routed_reused: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass
@@ -585,31 +589,50 @@ class ContinuousBatchingEngine:
         # ones, copied to the siblings that share the fill, free when the
         # row is.  What assumes per-token blocks refuses by name here,
         # where its option is set, or where it is asked for.
-        self._stateful = cfg.is_hybrid
+        # (A stack stated by kind WITHOUT recurrent layers, such as one
+        # of latent-attention layers, keeps what the dense model has:
+        # siblings share a prompt's pages, tail pages are copied, prefixes
+        # are reused, rows park.)
+        self._by_kind = cfg.is_hybrid  # models/hybrid.py runs the stack
+        self._stateful = cfg.n_mamba_layers > 0
         # the routing of the last ``keep_routed_experts`` finished
         # requests (:meth:`routed_experts`): what a routing-replay trainer
         # or a parity check follows.  The hybrid stack's programs hand it
         # out; nothing else does yet
-        if keep_routed_experts and not self._stateful:
+        if keep_routed_experts and not self._by_kind:
             raise ValueError(
                 "keep_routed_experts: only the hybrid stack's programs "
                 "hand their routing out"
             )
         self._keep_routed = int(keep_routed_experts)
         self._routed_done: Dict[str, np.ndarray] = {}  # oldest first
-        if self._stateful:
+        #: the routing of the last prompts filled, by their tokens: a later
+        #: request that reuses such a prompt's cached pages prefills its
+        #: tail alone and takes the rest of its routing from here
+        self._routed_prompts: Dict[Tuple[int, ...], np.ndarray] = {}
+        if self._by_kind:
+            # what the stack's two programs do not write, whatever its
+            # kinds; and what a recurrent state rules out besides
+            spec = spec_decode_params is not None and spec_decode_params.enabled
             refused = {
                 "the dense (unpaged) KV cache": cache_mode == "dense",
                 "a tensor- or expert-parallel serving mesh": mesh is not None,
-                "speculative verify": spec_decode_params is not None
-                and spec_decode_params.enabled,
-                "prefix-cache host spill": prefix_cache_host_bytes > 0,
+                "speculative verify": spec,
                 "int8 KV storage": kv_cache_dtype == "int8",
                 "int8 serving weights": serving_weight_dtype == "int8",
             }
+            if self._stateful:
+                refused["prefix-cache host spill"] = prefix_cache_host_bytes > 0
             for feature, asked in refused.items():
-                if asked:
+                if not asked:
+                    continue
+                if self._stateful:
                     raise StatefulModelUnsupported(feature)
+                raise NotImplementedError(
+                    f"{feature} is not supported for a stack stated by "
+                    f"kind {sorted(set(cfg.layer_types))}: its fill and "
+                    "decode programs (models/hybrid.py) do not write it"
+                )
             self.paged = True
         #: sibling copies of a fill's end state; fills built for a prompt
         #: that a live row already carries (a late sibling: the state at
@@ -619,8 +642,9 @@ class ContinuousBatchingEngine:
         self.state_reprefills_total = 0
         self.moe_pairs_held_total = 0
         self.moe_pairs_routed_total = 0
+        self.moe_groups_hit_total = 0
         self.moe_expert_pairs = np.zeros(
-            (cfg.n_held_experts if self._stateful else 0,), np.int64
+            (cfg.n_held_experts if self._by_kind else 0,), np.int64
         )
         assert kv_cache_dtype in ("auto", "int8"), kv_cache_dtype
         if kv_cache_dtype == "int8" and not self.paged:
@@ -1040,7 +1064,11 @@ class ContinuousBatchingEngine:
         # tiling — a misaligned model on a TPU takes the reference path,
         # and says so once
         on_tpu = jax.default_backend() == "tpu"
-        self._use_paged_kernel = on_tpu and cfg.head_dim % 128 == 0
+        # (a latent page's row is a whole number of lane tiles by
+        # construction: paged.latent_page_width)
+        self._use_paged_kernel = on_tpu and (
+            cfg.is_latent or cfg.head_dim % 128 == 0
+        )
         if on_tpu and not self._use_paged_kernel:
             _warn_paged_reference(cfg.head_dim)
         kv_dtype = self.kv_cache_dtype
@@ -1071,7 +1099,8 @@ class ContinuousBatchingEngine:
         pool_b, scale_b = paged.kv_pool_layout_bytes(
             cfg, self.n_blocks, BS, kv_cache_dtype=kv_dtype
         )
-        if self._stateful:
+        if self._by_kind:
+            # (no byte where no layer is recurrent)
             self.ssm_state, self.conv_state = hybrid.state_zeros(
                 cfg, max_batch
             )
@@ -1533,12 +1562,20 @@ class ContinuousBatchingEngine:
             dst = np.array([blocks[0]], np.int32)
             self._copy_pool_blocks(src, dst)
             self._free_block_list([m.tail_block])  # copy taken: unpin
+        key, reused = tuple(seq), None
+        if self._keep_routed and m.n_tokens:
+            # pages reused from the cache hold KV that an earlier fill of
+            # this prompt computed, under the routing it handed out
+            reused = self._routed_prompts.get(key)
+            if reused is not None:
+                reused = reused[: m.n_tokens]
         return _Fill(
-            key=tuple(seq),
+            key=key,
             tokens=list(seq),
             blocks=list(m.blocks) + blocks,
             targets=[],
             fill_pos=m.n_tokens,
+            routed_reused=reused,
         )
 
     def prefix_cache_stats(self) -> Dict[str, int]:
@@ -3228,6 +3265,13 @@ class ContinuousBatchingEngine:
             take = min(rem, left)
             if take <= 0:
                 break
+            # the batch is a dense [F_pad, C] array and the model runs all
+            # of it: one more row may double it.  Four budgets of padded
+            # positions at most (what four rows of a whole chunk each
+            # come to); the fill goes into the next batch
+            width = bucket_len(max([take] + [t for _, t in batch]))
+            if batch and (1 << len(batch).bit_length()) * width > 4 * budget:
+                break
             batch.append((f, take))
             left -= take
             if left <= 0:
@@ -3259,7 +3303,7 @@ class ContinuousBatchingEngine:
                 cls[i] = take
                 tables[i, : len(f.blocks)] = f.blocks
                 slots[i] = f.state_slot
-            if self._stateful:
+            if self._by_kind:
                 (logits, self.k_pool, self.v_pool, self.ssm_state,
                  self.conv_state, _, routed) = hybrid.hybrid_fill_chunk(
                     self.params, self.k_pool, self.v_pool, self.ssm_state,
@@ -3407,10 +3451,21 @@ class ContinuousBatchingEngine:
             for r, i, take in f.routed
         ]
         f.routed = []
+        n_reused = len(f.tokens) - sum(len(p) for p in pieces)
+        if n_reused:
+            if f.routed_reused is None or len(f.routed_reused) != n_reused:
+                return  # pages of a prompt whose routing nobody kept
+            pieces.insert(0, f.routed_reused)
+        routing = np.concatenate(pieces)
+        if not self._stateful:  # (a recurrent state rules page reuse out)
+            self._routed_prompts.pop(f.key, None)
+            while len(self._routed_prompts) >= self._keep_routed:
+                del self._routed_prompts[next(iter(self._routed_prompts))]
+            self._routed_prompts[f.key] = routing
         for tgt in f.targets:
             row = tgt.resume or self.rows[tgt.row_id]
             if row is not None:
-                row.routed = list(pieces)
+                row.routed = [routing]
 
     def _share_fill_blocks(self, fills: List[_Fill], idxs, logits):
         """The part of ``_distribute_fills`` before the fetch.  Returns
@@ -3920,6 +3975,11 @@ class ContinuousBatchingEngine:
         if self._stateful:
             # state slots the chunk advances, at most: rows x steps
             counts["state_rows_sum"] = len(snapshot) * chunk_size
+        if self.cfg.is_latent:
+            # what the latent kernel reads: ONE entry a position and layer
+            # whatever the head count (the same floor as ctx_tokens_sum)
+            counts["latent_ctx_tokens_sum"] = counts["ctx_tokens_sum"]
+            counts["latent_pages_attended"] = counts["pages_attended"]
         span.set_metadata(**counts)
 
     def _dispatch_chunk_paged(self):
@@ -3935,7 +3995,7 @@ class ContinuousBatchingEngine:
         if self._tables_dirty:
             self._tables = self._upload_tables()
             self._tables_dirty = False
-        if self._stateful:
+        if self._by_kind:
             (
                 self.k_pool, self.v_pool, self.ssm_state, self.conv_state,
                 self.kv_lengths, out_t, out_l, emitted, self.cur_tokens,
@@ -4466,6 +4526,12 @@ class ContinuousBatchingEngine:
                 # the chunk's (token, k) pairs by held expert, and last
                 # those routed to experts held elsewhere
                 pairs = np.asarray(fetched[5], np.int64)
+                if self.cfg.moe_router == "sigmoid_group":
+                    # a group-limited router's last count: (token, chosen
+                    # group) pairs whose group has an expert held here
+                    pairs, hit = pairs[:-1], int(pairs[-1])
+                    self.moe_groups_hit_total += hit
+                    counts["moe_groups_hit"] = hit
                 self.moe_expert_pairs += pairs[:-1]
                 self.moe_pairs_held_total += int(pairs[:-1].sum())
                 self.moe_pairs_routed_total += int(pairs.sum())

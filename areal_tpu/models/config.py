@@ -52,8 +52,15 @@ class TransformerConfig:
     # (granitemoehybrid ``shared_intermediate_size``); 0 = none
     shared_expert_dim: int = 0
     # "softmax_topk": softmax over all experts, then the top k (mixtral,
-    # qwen3-moe); "topk_softmax": the top k LOGITS, softmax over those k
+    # qwen3-moe); "topk_softmax": the top k LOGITS, softmax over those k;
+    # "sigmoid_group": sigmoid scores, the choice by score + a learned
+    # bias among the best ``moe_topk_groups`` of ``moe_n_groups`` groups
+    # of consecutive experts, weights the unbiased scores renormalised
+    # times ``moe_routed_scale`` (deepseek_v3 ``noaux_tc``)
     moe_router: str = "softmax_topk"
+    moe_n_groups: int = 1
+    moe_topk_groups: int = 1
+    moe_routed_scale: float = 1.0
     # the experts THIS program holds, [first, first + held) of n_experts
     # (one chip's share of a deployment that divides each layer's experts
     # over chips).  The router keeps n_experts outputs and its top k; a
@@ -62,9 +69,34 @@ class TransformerConfig:
     moe_held_experts: Optional[int] = None
 
     # a stack stated by kind (models/hybrid.py): one of "attention" |
-    # "mamba" per layer, in the published order; None = every layer is
-    # the attention layer of models/transformer.py
+    # "mamba" | "latent" per layer, in the published order; None = every
+    # layer is the attention layer of models/transformer.py
     layer_types: Optional[Tuple[str, ...]] = None
+    # the first ``n_dense_layers`` layers of a stack stated by kind have
+    # a dense MLP of ``intermediate_dim`` where the others have experts
+    # (deepseek_v3 ``first_k_dense_replace``)
+    n_dense_layers: int = 0
+    # latent attention (MLA): queries through a rank-``q_lora_rank``
+    # bottleneck; keys and values expanded from ONE latent of
+    # ``kv_lora_rank`` a token, beside ONE roped key part of
+    # ``qk_rope_head_dim`` shared by all heads.  A query/key head is
+    # ``head_dim`` = qk_nope_head_dim + qk_rope_head_dim wide, a value
+    # head ``v_head_dim``.  The cache holds [latent | roped part] a token
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # multi-token-prediction modules the checkpoint carries after its
+    # ``n_layers`` layers (deepseek_v3 ``num_nextn_predict_layers``): a
+    # drafting head, not served; the adapter skips their weights by name
+    n_mtp_modules: int = 0
+    # YaRN (``rope_scaling`` of type "yarn"); factor None = plain RoPE
+    rope_yarn_factor: Optional[float] = None
+    rope_yarn_original_max: int = 4096
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
     # Mamba-2 mixer sizes (d_inner = mamba_n_heads * mamba_head_dim)
     mamba_n_heads: int = 0
     mamba_head_dim: int = 0
@@ -124,10 +156,19 @@ class TransformerConfig:
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"n_layers is {self.n_layers}"
             )
-            assert set(self.layer_types) <= {"attention", "mamba"}, (
-                self.layer_types
-            )
-        assert self.moe_router in ("softmax_topk", "topk_softmax")
+            kinds = set(self.layer_types)
+            assert kinds <= {"attention", "mamba", "latent"}, self.layer_types
+            # one page format a pool: per-head K and V, or the latent
+            assert not {"attention", "latent"} <= kinds, self.layer_types
+            if "latent" in kinds:
+                assert self.kv_lora_rank > 0 and self.qk_rope_head_dim > 0
+        assert 0 <= self.n_dense_layers <= self.n_layers
+        assert self.moe_router in (
+            "softmax_topk", "topk_softmax", "sigmoid_group"
+        )
+        if self.moe_router == "sigmoid_group":
+            assert self.n_experts % self.moe_n_groups == 0
+            assert self.moe_topk_groups <= self.moe_n_groups
         if self.moe_held_experts is not None:
             assert (
                 0 <= self.moe_first_expert
@@ -172,12 +213,31 @@ class TransformerConfig:
         """Layers that keep per-token KV (every layer of a dense stack)."""
         if self.layer_types is None:
             return self.n_layers
-        return sum(t == "attention" for t in self.layer_types)
+        return sum(t in ("attention", "latent") for t in self.layer_types)
 
     @property
     def n_mamba_layers(self) -> int:
         """Layers that keep a recurrent state per sequence."""
         return self.n_layers - self.n_attn_layers
+
+    @property
+    def is_latent(self) -> bool:
+        """The paged layers cache ONE latent entry a token
+        (``kv_latent_dim`` wide) where the others keep per-head K and V."""
+        return self.layer_types is not None and "latent" in self.layer_types
+
+    @property
+    def kv_latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_nope_head_dim(self) -> int:
+        return self.head_dim - self.qk_rope_head_dim
+
+    @property
+    def n_expert_layers(self) -> int:
+        """Layers with an expert block (all but the leading dense ones)."""
+        return self.n_layers - self.n_dense_layers if self.is_moe else 0
 
     @property
     def n_held_experts(self) -> int:

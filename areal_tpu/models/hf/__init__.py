@@ -1,6 +1,7 @@
 """HF family adapters.  Importing registers all families."""
 
 from areal_tpu.models.hf import (  # noqa: F401
+    deepseek_v3,
     gpt2,
     granitemoehybrid,
     llama_like,

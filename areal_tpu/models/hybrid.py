@@ -1,22 +1,44 @@
-"""A stack stated by kind: Mamba-2 layers with a recurrent state beside
-attention layers with paged KV, every layer followed by the expert block
-this program's share of the experts gives (granitemoehybrid).
+"""A stack stated by kind: each layer a MIXER (Mamba-2 with a recurrent
+state, attention with paged per-head KV, latent attention with paged
+latent entries) and an MLP (a dense one on the leading
+``cfg.n_dense_layers`` layers, else the expert block this program's share
+of the experts gives) (granitemoehybrid, deepseek_v3).
 
     h0 = embed_scale * embed[tokens]
     per layer:  h += r * mixer(rmsnorm(h));  m = rmsnorm(h)
-                h += r * (experts(m) + shared(m))
-    logits = rmsnorm(h) @ embed^T / logits_divisor
+                h += r * (experts(m) + shared(m))   or   r * dense(m)
+    logits = rmsnorm(h) @ head / logits_divisor     (head: embed^T if tied)
 
 **The layer plan** (:func:`layer_plan`): the published ``layer_types`` cut
-into runs of one kind, in order; a run is one ``lax.scan`` over its
-layers.  Parameters are stacked BY KIND: ``params["mamba"]`` over the
-Mamba mixers, ``params["attn"]`` over the attention mixers,
-``params["layers"]`` (the two norms and the expert block) over all
-layers; a run's body indexes them by the layer's number and by its number
-among its kind.  The dense stack of ``transformer.py`` / ``paged.py`` does
-not go through this module, and this module calls their functions where
-they fit (``_norm``, ``_embed``, ``_attn_qkv``, the paged kernels and
-``write_kv_runs``).
+into runs of one (mixer, MLP) pair, in order; a run is one ``lax.scan``
+over its layers.  Parameters are stacked BY KIND: ``params["mamba"]``
+over the Mamba mixers, ``params["attn"]`` over the attention mixers,
+``params["latent"]`` over the latent ones, ``params["dense"]`` over the
+dense MLPs, ``params["layers"]["mlp"]`` over the expert blocks and the two
+norms of ``params["layers"]`` over all layers; a run's body indexes them
+by the layer's number and by its numbers among its kinds.  The dense stack
+of ``transformer.py`` / ``paged.py`` does not go through this module, and
+this module calls their functions where they fit (``_norm``, ``_embed``,
+``_attn_qkv``, the paged kernels and ``write_kv_runs``).
+
+**The latent mixer** (MLA) has three forms over one set of equations
+(``c_q = rmsnorm(a W_qa)``, ``[q_nope | q_rope]_i = c_q W_qb``; ``[c_kv |
+k_r] = a W_kva``, ``c_kv = rmsnorm(c_kv)``, ``k_rope = rope(k_r)``, ONE
+for all heads; ``k_nope_i = c_kv W_UK,i``, ``v_i = c_kv W_UV,i``; ``s_i =
+scale (q_nope_i . k_nope_i + rope(q_rope_i) . k_rope)``; ``out =
+concat_i(softmax(s_i) v_i) W_o``).  What is cached is a token's ``[c_kv |
+k_rope]`` and nothing a head:
+
+* whole sequence (:func:`hidden_states`): keys and values expanded;
+* one decode step: ABSORBED, ``q~_i = q_nope_i W_UK,i^T``, ``s_i = scale
+  ([q~_i | q_rope_i] . [c_kv | k_rope])``, ``o_i = (sum p_i c_kv) W_UV,i``:
+  the paged kernel reads latent pages as keys and as values, all heads
+  sharing the one stream;
+* a fill chunk: its own tokens expanded, its paged prefix absorbed, merged
+  by the online-softmax partials (the prefix's accumulator goes through
+  ``W_UV`` first, which is linear).
+
+The three are the same mathematics (``tests/model/test_latent.py``).
 
 **The Mamba-2 mixer** has three forms over one set of equations
 (``[z | xBC | dt] = a W_in``; ``xBC = silu(causal depthwise conv)``;
@@ -53,14 +75,17 @@ import numpy as np
 from areal_tpu.engine.sampling import call_sample_fn
 from areal_tpu.models import paged
 from areal_tpu.models.config import TransformerConfig
-from areal_tpu.models.moe import held_moe_mlp
+from areal_tpu.models import quantize
+from areal_tpu.models.moe import held_moe_mlp, n_pair_counts
 from areal_tpu.models.transformer import (
     Params,
+    _activation,
     _attn_qkv,
     _embed,
     _norm,
     _proj,
     make_attention_mask,
+    rope_apply,
 )
 from areal_tpu.ops import ssm as ssm_ops
 
@@ -69,28 +94,38 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class Run(NamedTuple):
-    kind: str  # "attention" | "mamba"
+    kind: str  # the mixer: "attention" | "mamba" | "latent"
+    mlp: str  # "dense" | "experts"
     first_layer: int  # number of the run's first layer in the stack
-    first_of_kind: int  # its number among the layers of its kind
+    first_of_kind: int  # its number among the layers of its mixer kind
+    first_of_mlp: int  # its number among the layers of its MLP kind
     count: int
 
 
 def layer_plan(cfg: TransformerConfig) -> Tuple[Run, ...]:
-    """``cfg.layer_types`` as runs of one kind, in the published order."""
-    runs, seen = [], {"attention": 0, "mamba": 0}
+    """``cfg.layer_types`` as runs of one (mixer, MLP) pair, in the
+    published order; the first ``cfg.n_dense_layers`` layers have the
+    dense MLP."""
+    runs, seen = [], {}
     for l, kind in enumerate(cfg.layer_types):
-        if runs and runs[-1].kind == kind:
+        mlp = "dense" if l < cfg.n_dense_layers else "experts"
+        if runs and (runs[-1].kind, runs[-1].mlp) == (kind, mlp):
             runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
         else:
-            runs.append(Run(kind, l, seen[kind], 1))
-        seen[kind] += 1
+            runs.append(
+                Run(kind, mlp, l, seen.get(kind, 0), seen.get(mlp, 0), 1)
+            )
+        seen[kind] = seen.get(kind, 0) + 1
+        seen[mlp] = seen.get(mlp, 0) + 1
     return tuple(runs)
 
 
 def _run_indices(run: Run):
-    return (
-        jnp.arange(run.first_layer, run.first_layer + run.count),
-        jnp.arange(run.first_of_kind, run.first_of_kind + run.count),
+    """``(layer numbers, numbers among the mixer kind, among the MLP
+    kind)`` of a run's layers."""
+    return tuple(
+        jnp.arange(first, first + run.count)
+        for first in (run.first_layer, run.first_of_kind, run.first_of_mlp)
     )
 
 
@@ -124,7 +159,7 @@ def _uniform_stack(key, n: int, shape, bound: float, dtype):
     return make(jax.random.split(key, n))
 
 
-#: rms of the random embedding (see :func:`init_params`)
+#: rms of the random embedding of a TIED head (see :func:`init_params`)
 EMBED_RMS = 0.05
 
 
@@ -135,54 +170,70 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
 
     Matrices are uniform in ``+-1/sqrt(fan_in)``; ``A`` in (-16, -1),
     ``dt_bias`` so that ``dt`` falls in (0.001, 0.1) (the Mamba-2
-    initialisation); scales and skips around 1.  The embedding (and tied
-    head) has rms ``EMBED_RMS``: with a tied head the logit of the token
-    a position HOLDS is ``D x rms x (the embedding's share of the hidden
-    state) / logits_divisor``, about 8 at granite's sizes against 0.2
-    for every other token, so that token repeats with a probability of a
-    few percent and a log-probability says something about the hidden
-    state.  (At rms 0.29 the repeat took probability 1 - 1e-6 and every
-    log-probability read 0 to five places, my chip run, PR 31; at the
-    dense family's 1/sqrt(D) the logits are uniform to 0.01.)"""
-    assert cfg.is_hybrid and cfg.is_moe and cfg.tied_embedding
+    initialisation); scales and skips around 1; a group-limited router's
+    choice bias uniform in +-0.15, the spread of its sigmoid scores (at
+    zero the bias would take no part in any choice).  The embedding of
+    a TIED head has rms ``EMBED_RMS``: with a tied head the logit of the
+    token a position HOLDS is ``D x rms x (the embedding's share of the
+    hidden state) / logits_divisor``, about 8 at granite's sizes against
+    0.2 for every other token, so that token repeats with a probability
+    of a few percent and a log-probability says something about the
+    hidden state.  (At rms 0.29 the repeat took probability 1 - 1e-6 and
+    every log-probability read 0 to five places, my chip run, PR 31; at
+    the dense family's 1/sqrt(D) the logits are uniform to 0.01.)  An
+    untied head is one more matrix (logits of deviation 0.58 over a
+    final norm of rms 1), beside an embedding of rms 0.5."""
+    assert cfg.is_hybrid and cfg.is_moe
     dt = jnp.dtype(cfg.dtype)
-    L, La, Lm = cfg.n_layers, cfg.n_attn_layers, cfg.n_mamba_layers
+    L, Le, Ld = cfg.n_layers, cfg.n_expert_layers, cfg.n_dense_layers
+    Lm = cfg.n_mamba_layers
+    Ll = L - Lm if cfg.is_latent else 0
+    La = L - Lm - Ll
     D, E, Eh = cfg.hidden_dim, cfg.n_experts, cfg.n_held_experts
     Fe, Fs = cfg.moe_intermediate_dim, cfg.shared_expert_dim
     Hq, Hkv, hd = cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim
     H, di, cd = cfg.mamba_n_heads, cfg.mamba_d_inner, cfg.mamba_conv_dim
     K = cfg.mamba_d_conv
     keys = iter(jax.random.split(key, 40))
+    # the kinds that came after the first keep their own stream, so that
+    # a seed's weights of the older kinds are what they were
+    more = iter(jax.random.split(jax.random.fold_in(key, 1), 40))
 
-    def mat(n, shape, fan_in):
+    def mat(n, shape, fan_in, keys=keys):
         return _uniform_stack(next(keys), n, shape, 1.0 / np.sqrt(fan_in), dt)
 
-    def ones(*shape):
+    def ones(*shape, keys=keys):
         # scales and skips around 1, not AT 1: a scale read from the
         # wrong layer, or left out, has to show against the reference
         return jax.random.uniform(next(keys), shape, F32, 0.75, 1.25).astype(dt)
 
     mlp: Params = {
-        "router": {"w": mat(L, (D, E), D)},
+        "router": {"w": mat(Le, (D, E), D)},
         "experts": {
             # all three [E_held, F, D]: see moe.dense_expert_compute
-            "gate": mat(L, (Eh, Fe, D), D),
-            "up": mat(L, (Eh, Fe, D), D),
-            "down": mat(L, (Eh, Fe, D), Fe),
+            "gate": mat(Le, (Eh, Fe, D), D),
+            "up": mat(Le, (Eh, Fe, D), D),
+            "down": mat(Le, (Eh, Fe, D), Fe),
         },
     }
     if Fs:
         mlp["shared"] = {
-            "gate": {"w": mat(L, (D, Fs), D)},
-            "up": {"w": mat(L, (D, Fs), D)},
-            "down": {"w": mat(L, (Fs, D), Fs)},
+            "gate": {"w": mat(Le, (D, Fs), D)},
+            "up": {"w": mat(Le, (D, Fs), D)},
+            "down": {"w": mat(Le, (Fs, D), Fs)},
         }
-    u = jax.random.uniform(next(keys), (Lm, H), F32)
-    dt0 = jnp.exp(u * (np.log(0.1) - np.log(0.001)) + np.log(0.001))
+    if cfg.moe_router == "sigmoid_group":
+        mlp["router"]["bias"] = jax.random.uniform(
+            next(more), (Le, E), F32, -0.15, 0.15
+        )
+    if Lm:
+        u = jax.random.uniform(next(keys), (Lm, H), F32)
+        dt0 = jnp.exp(u * (np.log(0.1) - np.log(0.001)) + np.log(0.001))
+    embed_rms = EMBED_RMS if cfg.tied_embedding else 0.5
     params: Params = {
         "embed": {
             "weight": _uniform_stack(
-                next(keys), 1, (cfg.vocab_size, D), EMBED_RMS * np.sqrt(3.0), dt
+                next(keys), 1, (cfg.vocab_size, D), embed_rms * np.sqrt(3.0), dt
             )[0]
         },
         "layers": {
@@ -190,7 +241,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
             "mlp_norm": {"scale": ones(L, D)},
             "mlp": mlp,
         },
-        "mamba": {
+    }
+    if Lm:
+        params["mamba"] = {
             "in_proj": {"w": mat(Lm, (D, di + cd + H), D)},
             "conv": {"w": mat(Lm, (K, cd), K), "b": mat(Lm, (cd,), 16)},
             "dt_bias": (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dt),
@@ -200,15 +253,44 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
             "D": ones(Lm, H),
             "norm": {"scale": ones(Lm, di)},
             "out_proj": {"w": mat(Lm, (di, D), di)},
-        },
-        "attn": {
+        }
+    if La:
+        params["attn"] = {
             "q": {"w": mat(La, (D, Hq * hd), D)},
             "k": {"w": mat(La, (D, Hkv * hd), D)},
             "v": {"w": mat(La, (D, Hkv * hd), D)},
             "o": {"w": mat(La, (Hq * hd, D), Hq * hd)},
-        },
-        "final_norm": {"scale": ones(D)},
-    }
+        }
+    params["final_norm"] = {"scale": ones(D)}
+    if Ll:
+        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+        m = partial(mat, keys=more)
+        params["latent"] = {
+            "q_a": {"w": m(Ll, (D, rq), D)},
+            "q_a_norm": {"scale": ones(Ll, rq, keys=more)},
+            "q_b": {"w": m(Ll, (rq, Hq * hd), rq)},
+            "kv_a": {"w": m(Ll, (D, cfg.kv_latent_dim), D)},
+            "kv_a_norm": {"scale": ones(Ll, rkv, keys=more)},
+            # the published kv_b, its key columns and its value columns
+            # apart: the absorbed form reads each alone
+            "k_b": {"w": m(Ll, (rkv, Hq * nope), rkv)},
+            "v_b": {"w": m(Ll, (rkv, Hq * vd), rkv)},
+            "o": {"w": m(Ll, (Hq * vd, D), Hq * vd)},
+        }
+    if Ld:
+        Fd = cfg.intermediate_dim
+        params["dense"] = {
+            "gate": {"w": mat(Ld, (D, Fd), D, keys=more)},
+            "up": {"w": mat(Ld, (D, Fd), D, keys=more)},
+            "down": {"w": mat(Ld, (Fd, D), Fd, keys=more)},
+        }
+    if not cfg.tied_embedding:
+        params["lm_head"] = {
+            "w": _uniform_stack(
+                next(more), 1, (D, cfg.vocab_size), 1.0 / np.sqrt(D), dt
+            )[0]
+        }
     return params
 
 
@@ -426,7 +508,7 @@ def mamba_step(
 
 
 # ---------------------------------------------------------------------------
-# shared pieces of the three programs
+# residual, softmax scale, rotary tables
 # ---------------------------------------------------------------------------
 
 
@@ -436,31 +518,172 @@ def _res(cfg: TransformerConfig, x, branch):
     return x + jnp.asarray(cfg.residual_scale, x.dtype) * branch
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+
 def _attn_scale(cfg: TransformerConfig) -> float:
-    if cfg.attention_scale is None:
-        return 1.0 / np.sqrt(cfg.head_dim)
-    return cfg.attention_scale
+    """The softmax scale: the stated one, else ``1/sqrt(head_dim)``, times
+    the square of YaRN's ``mscale_all_dim`` factor where that is set (as
+    the published ``DeepseekV3Attention``: 192^-0.5 x 1.4159^2 = 0.1447
+    at factor 64)."""
+    if cfg.attention_scale is not None:
+        return cfg.attention_scale
+    scale = 1.0 / np.sqrt(cfg.head_dim)
+    if cfg.rope_yarn_factor and cfg.rope_yarn_mscale_all_dim:
+        m = yarn_mscale(cfg.rope_yarn_factor, cfg.rope_yarn_mscale_all_dim)
+        scale = scale * m * m
+    return float(scale)
 
 
-def _expert_block(cfg: TransformerConfig, lp: Params, x, valid):
-    """The second half of every layer; returns ``(x, pairs, routed [B,
-    T, K])``: see ``moe.held_moe_mlp``."""
+def rope_inv_freq(cfg: TransformerConfig, dim: int) -> np.ndarray:
+    """``[dim / 2]`` float32 rotary frequencies: ``base^(-2j/dim)``, and
+    under YaRN (as ``DeepseekV3YarnRotaryEmbedding``) a blend of those and
+    the same divided by ``factor``, by a linear ramp between the
+    correction dims of ``beta_fast`` and ``beta_slow`` at the original
+    context: dims that turn fast keep their frequency, slow ones are
+    stretched."""
+    j = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / cfg.rotary_base**j
+    if not cfg.rope_yarn_factor:
+        return extra.astype(np.float32)
+    inter = extra / cfg.rope_yarn_factor
+
+    def correction_dim(n_rot):
+        return (
+            dim * np.log(cfg.rope_yarn_original_max / (n_rot * 2 * np.pi))
+        ) / (2 * np.log(cfg.rotary_base))
+
+    low = max(int(np.floor(correction_dim(cfg.rope_yarn_beta_fast))), 0)
+    high = min(int(np.ceil(correction_dim(cfg.rope_yarn_beta_slow))), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def latent_rope_tables(cfg: TransformerConfig, positions):
+    """``(cos, sin)`` [B, T, 1, rope/2] float32 for the rope parts of a
+    latent layer's queries and key, times YaRN's ``mscale /
+    mscale_all_dim`` ratio (1 where both are set alike)."""
+    freqs = jnp.asarray(rope_inv_freq(cfg, cfg.qk_rope_head_dim))
+    angles = positions[..., None].astype(F32) * freqs
+    m = 1.0
+    if cfg.rope_yarn_factor:
+        m = yarn_mscale(cfg.rope_yarn_factor, cfg.rope_yarn_mscale) / yarn_mscale(
+            cfg.rope_yarn_factor, cfg.rope_yarn_mscale_all_dim
+        )
+    return (
+        (jnp.cos(angles) * m)[:, :, None, :],
+        (jnp.sin(angles) * m)[:, :, None, :],
+    )
+
+
+# ---------------------------------------------------------------------------
+# the latent mixer's pieces
+# ---------------------------------------------------------------------------
+
+
+def _w3(p, dtype, heads: int):
+    """A projection ``[rank, heads * width]`` as ``[rank, heads, width]``."""
+    w = quantize.leaf_weight(p, dtype)
+    return w.reshape(w.shape[0], heads, -1)
+
+
+def latent_q(cfg: TransformerConfig, ap: Params, h, rope_cs):
+    """``(q_nope [B, T, H, nope], q_rope [B, T, H, rope])``, the rope part
+    roped."""
+    B, T, _ = h.shape
+    c_q = _norm(_proj(ap["q_a"], h), ap["q_a_norm"], cfg)
+    q = _proj(ap["q_b"], c_q).reshape(B, T, cfg.n_q_heads, cfg.head_dim)
+    nope = cfg.qk_nope_head_dim
+    return q[..., :nope], rope_apply(q[..., nope:], *rope_cs)
+
+
+def latent_kv(cfg: TransformerConfig, ap: Params, h, rope_cs):
+    """What a token leaves in the cache: ``(c_kv [B, T, rank]`` after its
+    norm, ``k_rope [B, T, rope]`` roped, ONE for all heads)``."""
+    ckr = _proj(ap["kv_a"], h)
+    r = cfg.kv_lora_rank
+    c_kv = _norm(ckr[..., :r], ap["kv_a_norm"], cfg)
+    k_rope = rope_apply(ckr[..., None, r:], *rope_cs)[..., 0, :]
+    return c_kv, k_rope
+
+
+def latent_entry(cfg: TransformerConfig, c_kv, k_rope):
+    """``[c_kv | k_rope | 0]``, a page's row (``paged.latent_page_width``)."""
+    pad = paged.latent_page_width(cfg) - cfg.kv_latent_dim
+    zeros = jnp.zeros(c_kv.shape[:-1] + (pad,), c_kv.dtype)
+    return jnp.concatenate([c_kv, k_rope.astype(c_kv.dtype), zeros], axis=-1)
+
+
+def latent_expand(cfg: TransformerConfig, ap: Params, c_kv, k_rope):
+    """Per-head keys ``[k_nope_i | k_rope]`` [B, T, H, head_dim] and values
+    [B, T, H, v_head_dim] of tokens whose latent is at hand."""
+    H = cfg.n_q_heads
+    k_nope = jnp.einsum("btc,chn->bthn", c_kv, _w3(ap["k_b"], c_kv.dtype, H))
+    v = jnp.einsum("btc,chv->bthv", c_kv, _w3(ap["v_b"], c_kv.dtype, H))
+    k_r = jnp.broadcast_to(
+        k_rope[:, :, None, :].astype(k_nope.dtype),
+        k_nope.shape[:-1] + k_rope.shape[-1:],
+    )
+    return jnp.concatenate([k_nope, k_r], axis=-1), v
+
+
+def latent_absorbed_q(cfg: TransformerConfig, ap: Params, q_nope, q_rope):
+    """``[q~_i | q_rope_i | 0]`` [B, T, H, page width] with ``q~_i =
+    q_nope_i W_UK,i^T``: a query against latent entries themselves."""
+    q_lat = jnp.einsum(
+        "bthn,chn->bthc", q_nope, _w3(ap["k_b"], q_nope.dtype, cfg.n_q_heads)
+    )
+    return latent_entry(cfg, q_lat, q_rope)
+
+
+def latent_values_out(cfg: TransformerConfig, ap: Params, o_lat, dtype=None):
+    """``o~_i W_UV,i`` [.., H, v_head_dim] of ``o_lat`` [.., H, rank]
+    (attention's output, or an unnormalised accumulator, in latent
+    space: the map is linear).  Comes out in ``dtype`` (the input's)."""
+    w = _w3(ap["v_b"], o_lat.dtype, cfg.n_q_heads)
+    return jnp.einsum(
+        "...hc,chv->...hv", o_lat, w, preferred_element_type=dtype
+    )
+
+
+# ---------------------------------------------------------------------------
+# shared pieces of the three programs
+# ---------------------------------------------------------------------------
+
+
+def _mlp_half(cfg: TransformerConfig, params: Params, run: Run, l, e, x, valid):
+    """The second half of layer ``l`` (number ``e`` among its MLP kind);
+    returns ``(x, pairs, routed [B, T, K])``, the last two None after a
+    dense MLP: see ``moe.held_moe_mlp``."""
+    h = _norm(x, _at(params["layers"]["mlp_norm"], l), cfg)
+    if run.mlp == "dense":
+        dp = _at(params["dense"], e)
+        hid = _activation(_proj(dp["gate"], h), cfg.activation) * _proj(
+            dp["up"], h
+        )
+        return _res(cfg, x, _proj(dp["down"], hid)), None, None
     out, pairs, routed = held_moe_mlp(
-        cfg, _norm(x, lp["mlp_norm"], cfg), lp["mlp"], valid=valid
+        cfg, h, _at(params["layers"]["mlp"], e), valid=valid
     )
     return _res(cfg, x, out), pairs, routed
 
 
 def _head_logits(params: Params, cfg: TransformerConfig, x):
-    """Logits of final-norm hidden states ``x``: the tied head's products
+    """Logits of final-norm hidden states ``x``: the head's products
     come OUT in float32.  A ``bfloat16 @ bfloat16`` product comes out in
-    bfloat16 whatever it accumulates in, and this family's logits are
+    bfloat16 whatever it accumulates in, and granite's logits are
     not small (tens to a hundred before ``logits_divisor``):
     rounded to 8 bits they moved the server's log-probabilities by
     0.024-0.027 at most and 0.0065-0.0071 on average, as much as serving
     every matrix in float8 (my chip runs, PR 31: PERF.md section 6)."""
-    assert cfg.tied_embedding and not cfg.is_critic
-    w = params["embed"]["weight"].astype(x.dtype).T
+    assert not cfg.is_critic
+    if cfg.tied_embedding:
+        w = params["embed"]["weight"].astype(x.dtype).T
+    else:
+        w = quantize.leaf_weight(params["lm_head"], x.dtype)
     logits = jnp.matmul(x, w, preferred_element_type=F32)
     logits = logits.astype(jnp.dtype(cfg.logits_dtype))
     if cfg.logits_divisor is not None:
@@ -473,7 +696,11 @@ def _logits(params: Params, cfg: TransformerConfig, x):
 
 
 def _pairs_zero(cfg: TransformerConfig):
-    return jnp.zeros((cfg.n_held_experts + 1,), jnp.int32)
+    return jnp.zeros((n_pair_counts(cfg),), jnp.int32)
+
+
+def _add_pairs(pairs, p):
+    return pairs if p is None else pairs + p
 
 
 # ---------------------------------------------------------------------------
@@ -495,37 +722,48 @@ def hidden_states(
     s0 = jnp.zeros((B, cfg.mamba_d_state, cfg.mamba_d_inner), F32)
     tail0 = jnp.zeros((B, cfg.mamba_d_conv - 1, cfg.mamba_conv_dim), x.dtype)
     scale = _attn_scale(cfg)
+    rope_cs = latent_rope_tables(cfg, positions) if cfg.is_latent else None
 
-    def mamba_body(x, idx):
-        l, j = idx
-        lp, mp = _at(params["layers"], l), _at(params["mamba"], j)
-        out, _, _ = mamba_chunk(
-            cfg, mp, _norm(x, lp["attn_norm"], cfg), n_valid, s0, tail0
-        )
-        x, _, _ = _expert_block(cfg, lp, _res(cfg, x, out), valid)
-        return x, None
-
-    def attn_body(x, idx):
-        l, j = idx
-        lp, ap = _at(params["layers"], l), _at(params["attn"], j)
-        h = _norm(x, lp["attn_norm"], cfg)
-        q, k, v = _attn_qkv(cfg, {"attn": ap}, h, positions, None)
-        Hkv, r = cfg.n_kv_heads, cfg.n_q_heads // cfg.n_kv_heads
+    def attend(q, k, v):
+        """Causal attention of whole rows: q [B, T, Hq, hd], k [B, T,
+        Hkv, hd], v [B, T, Hkv, vd] -> [B, T, Hq * vd]."""
+        Hkv = k.shape[2]
         s = jnp.einsum(
             "bikrd,bjkd->bkrij",
-            q.reshape(B, T, Hkv, r, -1).astype(F32), k.astype(F32),
+            q.reshape(B, T, Hkv, q.shape[2] // Hkv, -1).astype(F32),
+            k.astype(F32),
         ) * scale
         s = jnp.where(mask[:, None, None], s, -1e30)
         o = jnp.einsum(
             "bkrij,bjkd->bikrd", jax.nn.softmax(s, axis=-1), v.astype(F32)
-        ).reshape(B, T, -1).astype(x.dtype)
-        x, _, _ = _expert_block(
-            cfg, lp, _res(cfg, x, _proj(ap["o"], o)), valid
         )
-        return x, None
+        return o.reshape(B, T, -1).astype(x.dtype)
+
+    def mixer(run: Run, x, l, j):
+        h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
+        if run.kind == "mamba":
+            out, _, _ = mamba_chunk(
+                cfg, _at(params["mamba"], j), h, n_valid, s0, tail0
+            )
+            return out
+        if run.kind == "attention":
+            ap = _at(params["attn"], j)
+            q, k, v = _attn_qkv(cfg, {"attn": ap}, h, positions, None)
+            return _proj(ap["o"], attend(q, k, v))
+        ap = _at(params["latent"], j)
+        q_nope, q_rope = latent_q(cfg, ap, h, rope_cs)
+        k, v = latent_expand(cfg, ap, *latent_kv(cfg, ap, h, rope_cs))
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        return _proj(ap["o"], attend(q, k, v))
 
     for run in layer_plan(cfg):
-        body = mamba_body if run.kind == "mamba" else attn_body
+
+        def body(x, idx, run=run):
+            l, j, e = idx
+            x = _res(cfg, x, mixer(run, x, l, j))
+            x, _, _ = _mlp_half(cfg, params, run, l, e, x, valid)
+            return x, None
+
         x, _ = jax.lax.scan(body, x, _run_indices(run))
     return _norm(x, params["final_norm"], cfg)
 
@@ -614,7 +852,7 @@ def _put_conv_tails(conv, slots, tails, keep):
 )
 def hybrid_fill_chunk(
     params: Params,
-    k_pool: jax.Array,  # [La, NB, Hkv, BS, hd]
+    k_pool: jax.Array,  # [La, NB, Hkv, BS, hd] (paged.pool_shapes)
     v_pool: jax.Array,
     ssm: jax.Array,  # [Lm, slots, N, H*P] float32
     conv: jax.Array,  # [Lm, K-1, slots, conv_dim]
@@ -637,9 +875,12 @@ def hybrid_fill_chunk(
     moved the whole ``conv`` array into its fast memory for the loops'
     duration, where part of it came back overwritten (three layers' tails
     of slots 25-63 in one fill in twenty, my chip runs, PR 31: PERF.md
-    section 6).  Returns ``(last_logits [F, V], k_pool, v_pool, ssm, conv,
-    pairs [E_held + 1], routed [L, F, C, K])``: the last is every layer's
-    routed experts of every position (``moe.held_moe_mlp``)."""
+    section 6).  A latent layer attends the chunk with keys and values
+    expanded and the paged prefix in the absorbed form, and leaves its
+    latent entries for the same one write.  Returns ``(last_logits [F,
+    V], k_pool, v_pool, ssm, conv, pairs [moe.n_pair_counts], routed [Le,
+    F, C, K])``: the last is every EXPERT layer's routed experts of
+    every position (``moe.held_moe_mlp``)."""
     C = tokens.shape[1]
     valid = jnp.arange(C)[None, :] < chunk_lens[:, None]  # [F, C]
     row_valid = chunk_lens > 0
@@ -656,14 +897,15 @@ def hybrid_fill_chunk(
         C, cfg.n_q_heads, k_pool, tables, read_lens, use_kernel
     )
 
-    tails0 = jnp.where(
-        fresh[None, :, None, None], 0, _get_conv_tails(conv, slots)
-    )  # [Lm, F, K-1, conv_dim]
+    latent = cfg.is_latent
+    rope_cs = latent_rope_tables(cfg, positions) if latent else None
+    if cfg.n_mamba_layers:
+        tails0 = jnp.where(
+            fresh[None, :, None, None], 0, _get_conv_tails(conv, slots)
+        )  # [Lm, F, K-1, conv_dim]
 
-    def mamba_body(carry, inp):
-        x, ssm, pairs = carry
-        l, j, tail0 = inp
-        lp, mp = _at(params["layers"], l), _at(params["mamba"], j)
+    def mamba_mixer(x, ssm, l, j, tail0):
+        mp = _at(params["mamba"], j)
         if use_kernel:
             s0 = ssm_ops.ssm_state_rows(
                 ssm, j, slots, interpret=paged.kernel_interpret()
@@ -672,17 +914,14 @@ def hybrid_fill_chunk(
             s0 = _get_state_rows(ssm, j, slots)
         s0 = jnp.where(fresh[:, None, None], 0.0, s0)
         out, s1, tail1 = mamba_chunk(
-            cfg, mp, _norm(x, lp["attn_norm"], cfg), chunk_lens, s0, tail0
+            cfg, mp, _norm(x, _at(params["layers"]["attn_norm"], l), cfg),
+            chunk_lens, s0, tail0,
         )
-        ssm = _put_state_rows(ssm, j, slots, s1, row_valid)
-        x, p, routed = _expert_block(cfg, lp, _res(cfg, x, out), valid)
-        return (x, ssm, pairs + p), (tail1, routed)
+        return out, _put_state_rows(ssm, j, slots, s1, row_valid), tail1
 
-    def attn_body(carry, idx):
-        x, ssm, pairs = carry
-        l, j = idx
-        lp, ap = _at(params["layers"], l), _at(params["attn"], j)
-        h = _norm(x, lp["attn_norm"], cfg)
+    def attn_mixer(x, l, j):
+        ap = _at(params["attn"], j)
+        h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
         q, k, v = _attn_qkv(cfg, {"attn": ap}, h, positions, None)
         prefix = paged._prefix_partials(
             q, k_pool, v_pool, tables, read_lens, j, use_kernel,
@@ -691,44 +930,74 @@ def hybrid_fill_chunk(
         attn = paged.chunk_attention(
             q, k, v, prefix, mask_chunk, scale, x.dtype
         )
-        x, p, routed = _expert_block(
-            cfg, lp, _res(cfg, x, _proj(ap["o"], attn)), valid
+        return _proj(ap["o"], attn), (
+            k.astype(k_pool.dtype), v.astype(v_pool.dtype)
         )
-        return (x, ssm, pairs + p), (
-            k.astype(k_pool.dtype), v.astype(v_pool.dtype), routed
+
+    def latent_mixer(x, l, j):
+        ap = _at(params["latent"], j)
+        h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
+        q_nope, q_rope = latent_q(cfg, ap, h, rope_cs)
+        c_kv, k_rope = latent_kv(cfg, ap, h, rope_cs)
+        k, v = latent_expand(cfg, ap, c_kv, k_rope)
+        acc, m, lsum = paged._prefix_partials(
+            latent_absorbed_q(cfg, ap, q_nope, q_rope), k_pool, None,
+            tables, read_lens, j, use_kernel, plan=plan, scale=scale,
+            value_dim=cfg.kv_lora_rank,
         )
+        attn = paged.chunk_attention(
+            jnp.concatenate([q_nope, q_rope], axis=-1), k, v,
+            (latent_values_out(cfg, ap, acc), m, lsum),
+            mask_chunk, scale, x.dtype,
+        )
+        entry = latent_entry(cfg, c_kv, k_rope)[:, :, None, :]
+        return _proj(ap["o"], attn), (entry.astype(k_pool.dtype),)
 
     carry = (x, ssm, _pairs_zero(cfg))
     window_kv, tails1, routed = [], [], []
     for run in layer_plan(cfg):
-        l_idx, j_idx = _run_indices(run)
+        l_idx, j_idx, e_idx = _run_indices(run)
+
+        def body(carry, inp, run=run):
+            x, ssm, pairs = carry
+            l, j, e = inp[:3]
+            if run.kind == "mamba":
+                out, ssm, kept = mamba_mixer(x, ssm, l, j, inp[3])
+            elif run.kind == "attention":
+                out, kept = attn_mixer(x, l, j)
+            else:
+                out, kept = latent_mixer(x, l, j)
+            x, p, r = _mlp_half(
+                cfg, params, run, l, e, _res(cfg, x, out), valid
+            )
+            return (x, ssm, _add_pairs(pairs, p)), (kept, r)
+
+        xs = (l_idx, j_idx, e_idx)
         if run.kind == "mamba":
             of_kind = slice(run.first_of_kind, run.first_of_kind + run.count)
-            carry, (tails, r) = jax.lax.scan(
-                mamba_body, carry, (l_idx, j_idx, tails0[of_kind])
-            )
-            tails1.append(tails)
+            xs += (tails0[of_kind],)
+        carry, (kept, r) = jax.lax.scan(body, carry, xs)
+        if run.kind == "mamba":
+            tails1.append(kept)
         else:
-            carry, (k, v, r) = jax.lax.scan(attn_body, carry, (l_idx, j_idx))
-            window_kv.append((k, v))
-        routed.append(r)
+            window_kv.append(kept)
+        if r is not None:
+            routed.append(r)
     x, ssm, pairs = carry
     if tails1:
         conv = _put_conv_tails(
             conv, slots, jnp.concatenate(tails1, axis=0), row_valid
         )
     if window_kv:
-        ks, vs = (jnp.concatenate(t, axis=0) for t in zip(*window_kv))
+        vals = tuple(jnp.concatenate(t, axis=0) for t in zip(*window_kv))
+        pools = (k_pool,) if latent else (k_pool, v_pool)
         # the pool is written only after every layer has read it: without
         # the barrier a run of ONE layer is inlined, the kernel reads the
         # donated pool while the write loop wants it in place, and XLA
         # settles that with two copies of each pool
-        x, k_pool, v_pool, ks, vs = jax.lax.optimization_barrier(
-            (x, k_pool, v_pool, ks, vs)
-        )
-        k_pool, v_pool = paged.write_kv_runs(
-            (k_pool, v_pool), (ks, vs), tables, starts, chunk_lens
-        )
+        x, pools, vals = jax.lax.optimization_barrier((x, pools, vals))
+        pools = paged.write_kv_runs(pools, vals, tables, starts, chunk_lens)
+        k_pool, v_pool = pools + ((v_pool,) if latent else ())
     last_idx = jnp.maximum(chunk_lens - 1, 0)
     x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
     logits = _logits(params, cfg, x_last)[:, 0]
@@ -770,22 +1039,25 @@ def hybrid_decode_chunk(
     for the attention layers' KV, same outputs), with every Mamba layer's
     state advanced in place for the rows live at each step.  Returns
     ``(k_pool, v_pool, ssm, conv, lengths, out_t, out_l, emitted, cur,
-    active, budgets, rng, pairs [E_held + 1], routed [W, L, K, B])``: the
-    last is every layer's routed experts at each step, for the position
+    active, budgets, rng, pairs [moe.n_pair_counts], routed [W, Le, K,
+    B])``: the last is every EXPERT layer's routed experts at each step, for the position
     the step READ (row b's entry of step i means something where
     ``emitted[b, i]``; the row axis last, so that the array pads little
     on the chip)."""
     B = cur_tokens.shape[0]
     W = chunk_size
     La, _, Hkv, _, hd = k_pool.shape
+    latent = cfg.is_latent
     base_lens = lengths
     read_lens = jnp.where(active, base_lens, 0)
     scale = _attn_scale(cfg)
     plan = paged._prefix_plan(
         1, cfg.n_q_heads, k_pool, tables, read_lens, use_kernel
     )
+    # the chunk's own KV (latent layers: its latent entries, which are
+    # keys and values both), one pool write after the chunk
     wk = jnp.zeros((La, W, B, Hkv, hd), k_pool.dtype)
-    wv = jnp.zeros((La, W, B, Hkv, hd), k_pool.dtype)
+    wv = jnp.zeros((0 if latent else La, W, B, Hkv, hd), k_pool.dtype)
 
     def step(i, st):
         (lengths_, cur, active, budgets, wk, wv, wvalid, ssm, conv, out_t,
@@ -795,30 +1067,18 @@ def hybrid_decode_chunk(
         wvalid = wvalid.at[i].set(active)
         mask_win = wvalid.T[:, None, None, None, :]  # [B,1,1,1,W]
         live = active[:, None]
+        rope_cs = latent_rope_tables(cfg, positions) if latent else None
 
-        def mamba_body(carry, idx):
-            x, wk, wv, ssm, conv, pairs = carry
-            l, j = idx
-            lp, mp = _at(params["layers"], l), _at(params["mamba"], j)
-            out, ssm, conv = mamba_step(
-                cfg, mp, _norm(x, lp["attn_norm"], cfg), ssm, conv, j,
-                active, use_kernel,
+        def put(w, new, j):
+            return jax.lax.dynamic_update_slice(
+                w, new.swapaxes(0, 1)[None].astype(w.dtype), (j, i, 0, 0, 0)
             )
-            x, p, r = _expert_block(cfg, lp, _res(cfg, x, out), live)
-            return (x, wk, wv, ssm, conv, pairs + p), r[:, 0].T
 
-        def attn_body(carry, idx):
-            x, wk, wv, ssm, conv, pairs = carry
-            l, j = idx
-            lp, ap = _at(params["layers"], l), _at(params["attn"], j)
-            h = _norm(x, lp["attn_norm"], cfg)
+        def attn_mixer(x, wk, wv, l, j):
+            ap = _at(params["attn"], j)
+            h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
             q, k, v = _attn_qkv(cfg, {"attn": ap}, h, positions, None)
-            wk = jax.lax.dynamic_update_slice(
-                wk, k.swapaxes(0, 1)[None].astype(wk.dtype), (j, i, 0, 0, 0)
-            )
-            wv = jax.lax.dynamic_update_slice(
-                wv, v.swapaxes(0, 1)[None].astype(wv.dtype), (j, i, 0, 0, 0)
-            )
+            wk, wv = put(wk, k, j), put(wv, v, j)
             prefix = paged._prefix_partials(
                 q, k_pool, v_pool, tables, read_lens, j, use_kernel,
                 plan=plan, scale=scale,
@@ -829,16 +1089,53 @@ def hybrid_decode_chunk(
                 jax.lax.dynamic_index_in_dim(wv, j, 0, keepdims=False),
                 prefix, mask_win, scale, x.dtype,
             )
-            x, p, r = _expert_block(
-                cfg, lp, _res(cfg, x, _proj(ap["o"], attn)), live
+            return _proj(ap["o"], attn), wk, wv
+
+        def latent_mixer(x, wk, l, j):
+            ap = _at(params["latent"], j)
+            h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
+            q_nope, q_rope = latent_q(cfg, ap, h, rope_cs)
+            c_kv, k_rope = latent_kv(cfg, ap, h, rope_cs)
+            wk = put(wk, latent_entry(cfg, c_kv, k_rope)[:, :, None, :], j)
+            q = latent_absorbed_q(cfg, ap, q_nope, q_rope)
+            prefix = paged._prefix_partials(
+                q, k_pool, None, tables, read_lens, j, use_kernel,
+                plan=plan, scale=scale, value_dim=cfg.kv_lora_rank,
             )
-            return (x, wk, wv, ssm, conv, pairs + p), r[:, 0].T
+            wk_j = jax.lax.dynamic_index_in_dim(wk, j, 0, keepdims=False)
+            o_lat = paged.window_attention(
+                q, wk_j, wk_j[..., : cfg.kv_lora_rank], prefix, mask_win,
+                scale, x.dtype,
+            ).reshape(B, 1, cfg.n_q_heads, cfg.kv_lora_rank)
+            attn = latent_values_out(cfg, ap, o_lat).reshape(B, 1, -1)
+            return _proj(ap["o"], attn), wk
 
         carry, step_routed = (x, wk, wv, ssm, conv, pairs), []
         for run in layer_plan(cfg):
-            body = mamba_body if run.kind == "mamba" else attn_body
+
+            def body(carry, idx, run=run):
+                x, wk, wv, ssm, conv, pairs = carry
+                l, j, e = idx
+                if run.kind == "mamba":
+                    out, ssm, conv = mamba_step(
+                        cfg, _at(params["mamba"], j),
+                        _norm(x, _at(params["layers"]["attn_norm"], l), cfg),
+                        ssm, conv, j, active, use_kernel,
+                    )
+                elif run.kind == "attention":
+                    out, wk, wv = attn_mixer(x, wk, wv, l, j)
+                else:
+                    out, wk = latent_mixer(x, wk, l, j)
+                x, p, r = _mlp_half(
+                    cfg, params, run, l, e, _res(cfg, x, out), live
+                )
+                return (x, wk, wv, ssm, conv, _add_pairs(pairs, p)), (
+                    None if r is None else r[:, 0].T
+                )
+
             carry, r = jax.lax.scan(body, carry, _run_indices(run))
-            step_routed.append(r)  # [run.count, K, B]
+            if r is not None:
+                step_routed.append(r)  # [run.count, K, B]
         x, wk, wv, ssm, conv, pairs = carry
         routed = jax.lax.dynamic_update_slice(
             routed, jnp.concatenate(step_routed, axis=0)[None], (i, 0, 0, 0)
@@ -865,13 +1162,18 @@ def hybrid_decode_chunk(
         jnp.zeros((W, B), bool), ssm, conv,
         jnp.zeros((B, W), jnp.int32), jnp.zeros((B, W), F32),
         jnp.zeros((B, W), bool), rng, _pairs_zero(cfg),
-        jnp.zeros((W, cfg.n_layers, cfg.n_experts_per_tok, B), jnp.int32),
+        jnp.zeros(
+            (W, cfg.n_expert_layers, cfg.n_experts_per_tok, B), jnp.int32
+        ),
     )
     (lengths_, cur, active, budgets, wk, wv, _, ssm, conv, out_t, out_l,
      emitted, rng, pairs, routed) = jax.lax.fori_loop(0, W, step, st)
-    k_pool, v_pool = paged.write_kv_runs(
-        (k_pool, v_pool), (wk.swapaxes(1, 2), wv.swapaxes(1, 2)),
-        tables, base_lens, lengths_ - base_lens,
+    pools, vals = (k_pool, v_pool), (wk.swapaxes(1, 2), wv.swapaxes(1, 2))
+    if latent:
+        pools, vals = pools[:1], vals[:1]
+    pools = paged.write_kv_runs(
+        pools, vals, tables, base_lens, lengths_ - base_lens
     )
+    k_pool, v_pool = pools + ((v_pool,) if latent else ())
     return (k_pool, v_pool, ssm, conv, lengths_, out_t, out_l, emitted, cur,
             active, budgets, rng, pairs, routed)

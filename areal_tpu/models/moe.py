@@ -274,21 +274,56 @@ def dense_expert_compute(x, w_tok, gate_w, up_w, down_w, act_kind: str):
     return jnp.einsum("enf,efd->nd", hid, down_w)
 
 
-def route(cfg: TransformerConfig, x: jax.Array, router_w: jax.Array):
-    """``(weights [N, K] f32, expert ids [N, K], logits [N, E] f32)`` of
-    the tokens ``x`` [N, D], by ``cfg.moe_router``: the router always
-    has its published ``n_experts`` outputs and takes its published k,
-    however many experts this program holds."""
+def group_limited_choice(cfg: TransformerConfig, choice: jax.Array):
+    """``(expert ids [N, K], chosen groups [N, G] bool)`` of the
+    ``sigmoid_group`` router from its choice scores ``choice`` [N, E]
+    (score + bias): a group (``E / G`` consecutive experts) scores the
+    sum of its two largest entries, the ``moe_topk_groups`` best groups
+    are kept, and the top k are taken of the scores with every other
+    group's set to 0 (as the published ``masked_fill(..., 0.0)``)."""
+    N, E = choice.shape
+    G = cfg.moe_n_groups
+    per_group = choice.reshape(N, G, E // G)
+    group_score = jnp.sum(jax.lax.top_k(per_group, 2)[0], axis=-1)  # [N, G]
+    _, best = jax.lax.top_k(group_score, cfg.moe_topk_groups)
+    chosen = jnp.any(best[:, :, None] == jnp.arange(G)[None, None, :], axis=1)
+    masked = jnp.where(chosen[:, :, None], per_group, 0.0).reshape(N, E)
+    _, idx = jax.lax.top_k(masked, cfg.n_experts_per_tok)
+    return idx, chosen
+
+
+def route(cfg: TransformerConfig, x: jax.Array, router: Dict[str, Any]):
+    """``(weights [N, K] f32, expert ids [N, K], logits [N, E] f32,
+    chosen groups [N, G] bool or None)`` of the tokens ``x`` [N, D], by
+    ``cfg.moe_router`` over the router's ``{"w"[, "bias"]}``: the router
+    always has its published ``n_experts`` outputs and takes its
+    published k, however many experts this program holds."""
     K = cfg.n_experts_per_tok
-    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)  # [N, E]
+    logits = x.astype(jnp.float32) @ router["w"].astype(jnp.float32)  # [N, E]
+    if cfg.moe_router == "sigmoid_group":
+        # the bias takes part in the CHOICE only; the weights are the
+        # unbiased scores of the chosen, renormalised and scaled
+        scores = jax.nn.sigmoid(logits)
+        idx, groups = group_limited_choice(
+            cfg, scores + router["bias"].astype(jnp.float32)
+        )
+        top = jnp.take_along_axis(scores, idx, axis=-1)
+        if cfg.moe_norm_topk_prob:
+            top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+        return top * cfg.moe_routed_scale, idx, logits, groups
     if cfg.moe_router == "topk_softmax":
         top, idx = jax.lax.top_k(logits, K)
-        return jax.nn.softmax(top, axis=-1), idx, logits
+        return jax.nn.softmax(top, axis=-1), idx, logits, None
     probs = jax.nn.softmax(logits, axis=-1)
     top, idx = jax.lax.top_k(probs, K)
     if cfg.moe_norm_topk_prob:
         top = top / jnp.sum(top, axis=-1, keepdims=True)
-    return top, idx, logits
+    return top, idx, logits, None
+
+
+def n_pair_counts(cfg: TransformerConfig) -> int:
+    """Length of :func:`held_moe_mlp`'s ``pairs``."""
+    return cfg.n_held_experts + 1 + (cfg.moe_router == "sigmoid_group")
 
 
 def held_moe_mlp(
@@ -302,15 +337,17 @@ def held_moe_mlp(
     holds exactly those, gate, up and down each ``[E_held, F, D]``):
     routes over all ``n_experts``, computes its own
     experts' part of the result, adds the shared expert (every token,
-    weight 1).  Returns ``(out [B, T, D], pairs [E_held + 1] int32,
+    weight 1).  Returns ``(out [B, T, D], pairs [n_pair_counts] int32,
     expert ids [B, T, K] int32)``: the valid (token, k) pairs each held
-    expert took and, last, those routed to experts held elsewhere; and
+    expert took and, after them, those routed to experts held elsewhere
+    (a group-limited router appends the (token, chosen group) pairs
+    whose group has an expert held HERE); and
     each token's routed experts, by their published numbers, for a
     caller that hands the routing out (a routing-replay trainer, a
     parity check that follows the server's choices)."""
     B, T, D = h.shape
     x = h.reshape(-1, D)
-    w, idx, _ = route(cfg, x, p["router"]["w"])
+    w, idx, _, groups = route(cfg, x, p["router"])
     first, held = cfg.moe_first_expert, cfg.n_held_experts
     ex = p["experts"]
     gate_w = quantize.leaf_weight(ex["gate"], h.dtype)
@@ -352,6 +389,14 @@ def held_moe_mlp(
     if valid is not None:
         slot = jnp.where(valid.reshape(-1)[:, None], slot, held + 1)
     pairs = jnp.bincount(slot.reshape(-1), length=held + 2)[: held + 1]
+    if groups is not None:
+        per = cfg.n_experts // cfg.moe_n_groups
+        g = jnp.arange(cfg.moe_n_groups)
+        here = (g * per < first + held) & ((g + 1) * per > first)
+        hit = groups & here[None, :]
+        if valid is not None:
+            hit = hit & valid.reshape(-1)[:, None]
+        pairs = jnp.concatenate([pairs, jnp.sum(hit, dtype=pairs.dtype)[None]])
     return (
         out.reshape(B, T, D), pairs.astype(jnp.int32),
         idx.reshape(B, T, -1).astype(jnp.int32),

@@ -80,18 +80,51 @@ from areal_tpu.ops.paged_attention import (
 _NEG_INF = -1e30
 
 
+#: the lane tile: a pool's minor axis is a whole number of these
+LANES = 128
+
+
+def latent_page_width(cfg: TransformerConfig) -> int:
+    """Columns of a latent page's row: ``[c_kv | k_rope]``
+    (``cfg.kv_latent_dim``, 576 at the published MLA sizes) and zeros up
+    to the next lane tile (640).  Stored at its own width the pool is an
+    argument whose layout the Mosaic call does not take: the TPU
+    compiler then copies the WHOLE pool to a 640-column layout before
+    every call (2.1 GB of temporaries for a 1.9 GB pool, by its own
+    count for a described v5e, PR 33); the padding is written out so
+    that the bytes the ledger counts are the bytes the device holds."""
+    return -(-cfg.kv_latent_dim // LANES) * LANES
+
+
+def pool_shapes(
+    cfg: TransformerConfig, n_blocks: int, block_size: int
+) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+    """Shapes of the (k, v) block pools.  Per-head pages: both ``[L, NB,
+    Hkv, BS, hd]``.  LATENT pages (``cfg.is_latent``): ONE pool ``[L,
+    NB, 1, BS, latent_page_width]`` whose row is a token's ``[c_kv |
+    k_rope | 0]``, read as keys and (its first ``kv_lora_rank`` columns)
+    as values; the V pool has width 0, so that everything that moves
+    pages moves a latent pool as it moves any other and the V side
+    holds no byte."""
+    head = (cfg.n_attn_layers, n_blocks)
+    if cfg.is_latent:
+        return (
+            head + (1, block_size, latent_page_width(cfg)),
+            head + (1, block_size, 0),
+        )
+    shape = head + (cfg.n_kv_heads, block_size, cfg.head_dim)
+    return shape, shape
+
+
 def pool_zeros(
     cfg: TransformerConfig, n_blocks: int, block_size: int, dtype=None
 ) -> Tuple[jax.Array, jax.Array]:
-    """Allocate the (k, v) block pools ``[L, NB, Hkv, BS, hd]`` —
+    """Allocate the (k, v) block pools (:func:`pool_shapes`) —
     PAGE-major so one page is one contiguous HBM extent (the kernel reads
     a page's every head in a single DMA)."""
     dtype = dtype or jnp.dtype(cfg.dtype)
-    shape = (
-        cfg.n_attn_layers, n_blocks, cfg.n_kv_heads, block_size,
-        cfg.head_dim,
-    )
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+    k_shape, v_shape = pool_shapes(cfg, n_blocks, block_size)
+    return jnp.zeros(k_shape, dtype), jnp.zeros(v_shape, dtype)
 
 
 #: int8 symmetric absmax range (one sign bit + 7 magnitude bits; -128 is
@@ -121,10 +154,13 @@ def alloc_kv_pool(
         raise ValueError(
             f"kv_cache_dtype must be 'auto' or 'int8', got {kv_cache_dtype!r}"
         )
-    shape = (
-        cfg.n_attn_layers, n_blocks, cfg.n_kv_heads, block_size,
-        cfg.head_dim,
-    )
+    if cfg.is_latent:
+        raise NotImplementedError(
+            "int8 KV storage is not supported for latent pages: one "
+            "scale a (block, head, slot) would cover a token's whole "
+            "[c_kv | k_rope] row, whose two parts differ in scale"
+        )
+    shape, _ = pool_shapes(cfg, n_blocks, block_size)
     sshape = shape[:-1]
     return (
         jnp.zeros(shape, jnp.int8),
@@ -146,17 +182,14 @@ def kv_pool_layout_bytes(
     The HBM ledger sizes its ``kv_pool``/``kv_scales`` attributions from
     this (the allocation itself runs under jit, where a host-side ledger
     call cannot live); ``scale_bytes`` is 0 for fp pools."""
-    shape = (
-        cfg.n_attn_layers, n_blocks, cfg.n_kv_heads, block_size,
-        cfg.head_dim,
-    )
-    n = 1
-    for d in shape:
-        n *= int(d)
+    k_shape, v_shape = pool_shapes(cfg, n_blocks, block_size)
+    itemsize = jnp.dtype(dtype or cfg.dtype).itemsize
+    if cfg.is_latent:
+        return int(np.prod(k_shape)) * itemsize, 0
+    n = int(np.prod(k_shape))
     if kv_cache_dtype == "int8":
         # k + v int8 data, k + v float32 scale pools [L, NB, Hkv, BS]
         return 2 * n, 2 * (n // cfg.head_dim) * 4
-    itemsize = jnp.dtype(dtype or cfg.dtype).itemsize
     return 2 * n * itemsize, 0
 
 
@@ -205,12 +238,14 @@ def _prefix_plan(
 def _prefix_partials(
     q, k_pool, v_pool, tables, lengths, layer, use_kernel,
     mesh=None, kv_axis=None, k_scale=None, v_scale=None, plan=None,
-    scale=None,
+    scale=None, value_dim=None,
 ):
     """Paged-attention partials over each row's cached prefix.  ``q`` is
     [B, Q, Hq, hd]; returns (acc, m, l) with Q query tokens per row.
     ``plan`` is :func:`_prefix_plan` of the same arguments; ``scale`` the
     model's softmax scale where it is not ``1/sqrt(hd)`` (None).
+    ``value_dim``: LATENT pages, whose first ``value_dim`` columns are
+    the values (``v_pool`` is then not read; ``acc`` is that wide).
 
     ``k_scale``/``v_scale`` mark an int8-quantized pool: both the kernel
     and the jnp reference dequantize (multiply by the per-(block, head,
@@ -227,10 +262,12 @@ def _prefix_partials(
         interp = kernel_interpret()
         if mesh is None:
             return paged_flash_attention(
-                q, k_pool, v_pool, tables, lengths, layer=layer,
-                interpret=interp, k_scale=k_scale, v_scale=v_scale,
-                plan=plan, scale=scale,
+                q, k_pool, None if value_dim else v_pool, tables, lengths,
+                layer=layer, interpret=interp, k_scale=k_scale,
+                v_scale=v_scale, plan=plan, scale=scale,
+                value_dim=value_dim,
             )
+        assert value_dim is None, "latent pages under a serving mesh"
         from jax.sharding import PartitionSpec as P
 
         layered = k_pool.ndim == 5
@@ -278,6 +315,10 @@ def _prefix_partials(
             jnp.asarray(layer, jnp.int32).reshape(1), plan, *scales,
         )
     kl = jax.lax.dynamic_index_in_dim(k_pool, layer, 0, keepdims=False)
+    if value_dim:
+        return reference_paged_partials(
+            q, kl, None, tables, lengths, scale=scale, value_dim=value_dim
+        )
     vl = jax.lax.dynamic_index_in_dim(v_pool, layer, 0, keepdims=False)
     ksl = vsl = None
     if k_scale is not None:
@@ -367,10 +408,10 @@ def write_kv_runs(
 
 def chunk_attention(q, k, v, prefix, mask_chunk, scale, dtype):
     """Attention of a chunk's queries ``q`` [F, C, Hq, hd] over the chunk
-    itself (``k``, ``v`` [F, C, Hkv, hd], causal by ``mask_chunk``
-    [F, Cq, Ckv]) merged online with the paged partials ``prefix`` =
-    ``(acc, m, l)`` over each row's cached prefix.  Returns [F, C,
-    Hq * hd] in ``dtype``."""
+    itself (``k`` [F, C, Hkv, hd], ``v`` [F, C, Hkv, vd], causal by
+    ``mask_chunk`` [F, Cq, Ckv]) merged online with the paged partials
+    ``prefix`` = ``(acc [F, C, Hq, vd], m, l)`` over each row's cached
+    prefix.  Returns [F, C, Hq * vd] in ``dtype``."""
     F, C, Hq, hd = q.shape
     Hkv = k.shape[2]
     r = Hq // Hkv
@@ -386,7 +427,7 @@ def chunk_attention(q, k, v, prefix, mask_chunk, scale, dtype):
         * scale
     )  # [F, Hkv, r, Cq, Ckv]
     s_c = jnp.where(mask_chunk[:, None, None, :, :], s_c, _NEG_INF)
-    accp = acc_p.reshape(F, C, Hkv, r, hd).transpose(0, 2, 3, 1, 4)
+    accp = acc_p.reshape(F, C, Hkv, r, -1).transpose(0, 2, 3, 1, 4)
     mp = m_p.reshape(F, C, Hkv, r).transpose(0, 2, 3, 1)
     lpp = l_p.reshape(F, C, Hkv, r).transpose(0, 2, 3, 1)
     # online merge of prefix partials with the in-chunk scores
@@ -398,15 +439,15 @@ def chunk_attention(q, k, v, prefix, mask_chunk, scale, dtype):
     )
     den = lpp * alpha + jnp.sum(p_c, axis=-1)
     attn = (num / jnp.maximum(den, 1e-30)[..., None]).astype(dtype)
-    return attn.transpose(0, 3, 1, 2, 4).reshape(F, C, Hq * hd)
+    return attn.transpose(0, 3, 1, 2, 4).reshape(F, C, -1)
 
 
 def window_attention(q, wk_l, wv_l, prefix, mask_win, scale, dtype):
     """Attention of one decode step's queries ``q`` [B, 1, Hq, hd] over
-    the chunk's window so far (``wk_l``, ``wv_l`` [W, B, Hkv, hd], valid
-    by ``mask_win`` [B, 1, 1, 1, W]) merged online with the paged
-    partials ``prefix`` over each row's cached prefix.  Returns [B, 1,
-    Hq * hd] in ``dtype``."""
+    the chunk's window so far (``wk_l`` [W, B, Hkv, hd], ``wv_l`` [W, B,
+    Hkv, vd], valid by ``mask_win`` [B, 1, 1, 1, W]) merged online with
+    the paged partials ``prefix`` over each row's cached prefix.  Returns
+    [B, 1, Hq * vd] in ``dtype``."""
     B, _, Hq, hd = q.shape
     Hkv = wk_l.shape[2]
     r = Hq // Hkv
@@ -420,7 +461,7 @@ def window_attention(q, wk_l, wv_l, prefix, mask_win, scale, dtype):
     )
     s_win = jnp.where(mask_win, s_win, _NEG_INF)  # [B,Hkv,r,1,W]
     acc, m_main, l_main = prefix
-    acc = acc.reshape(B, Hkv, r, hd)
+    acc = acc.reshape(B, Hkv, r, -1)
     m_main = m_main.reshape(B, Hkv, r)
     l_main = l_main.reshape(B, Hkv, r)
     sw = s_win[:, :, :, 0, :]  # [B,Hkv,r,W]
@@ -432,7 +473,7 @@ def window_attention(q, wk_l, wv_l, prefix, mask_win, scale, dtype):
     )
     den = l_main * alpha + jnp.sum(p_win, axis=-1)
     attn = (num / jnp.maximum(den, 1e-30)[..., None]).astype(dtype)
-    return attn.reshape(B, 1, Hq * hd)
+    return attn.reshape(B, 1, -1)
 
 
 def paged_window_forward(

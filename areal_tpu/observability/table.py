@@ -102,6 +102,13 @@ METRIC_TABLE = [
         ("expert",),
     ),
     MetricSpec(
+        "areal_inference_moe_groups_hit",
+        "gauge",
+        "(token, chosen group) pairs of a group-limited router whose "
+        "group has an expert this server holds, over decode chunks since "
+        "it started (0 for any other router)",
+    ),
+    MetricSpec(
         "areal_inference_prefill_tokens_total",
         "counter",
         "Unique-prompt tokens actually prefilled (post group-dedup)",
@@ -1131,7 +1138,9 @@ TRACE_TABLE = [
         "ctx_tokens_sum = prompt + generated known to the host over the "
         "dispatched rows, chunk_size, pages_attended, page_slots = "
         "batch slots x pages a slot's table holds; state_rows_sum = "
-        "rows x steps whose state slots the chunk advances)",
+        "rows x steps whose state slots the chunk advances; for latent "
+        "pages latent_ctx_tokens_sum and latent_pages_attended = the "
+        "same context and pages, ONE entry a position and layer)",
     ),
     TraceSpec(
         "areal.engine.harvest.wait",
@@ -1151,7 +1160,8 @@ TRACE_TABLE = [
         "The fetched chunk folded into the host rows, finished rows "
         "parked or released (counts: tokens; for a model that holds a "
         "share of the experts also moe_pairs_held, moe_pairs_routed, "
-        "moe_expert_pairs_max of the chunk)",
+        "moe_expert_pairs_max of the chunk, and under a group-limited "
+        "router moe_groups_hit)",
     ),
     # -- phase spans: what the profiler would drop ----------------------------
     TraceSpec(

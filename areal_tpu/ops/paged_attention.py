@@ -38,6 +38,21 @@ Kernel shape:
 Returns UN-normalized partials ``(acc, m, l)`` so the caller online-merges
 them with attention over KV not in the pool yet (the decode chunk's
 in-flight window, or a prefill chunk's causal self-attention).
+
+**Latent pages** (``v_pool=None``, ``value_dim``): a pool of ONE "head"
+whose page ``[BS, kv_latent_dim]`` holds each token's ``[c_kv | k_rope]``
+(latent attention in its absorbed form).  The page is fetched ONCE and
+serves as the keys (all ``kv_latent_dim`` columns) and as the values
+(its first ``value_dim`` columns), so the kernel has G operand streams
+where the K/V form has 2 G, its accumulator is ``value_dim`` wide, and
+every query head shares the one stream (``r = Hq``).  It is the same
+kernel, grid, page plan and softmax update; the K/V form's programs are
+what they were (the mode is a static branch), and the Mosaic call is
+named ``paged_mla_decode`` / ``paged_mla_fill``.  Why one kernel and not
+two: everything that was hard to get right here (which page a stream
+addresses when it has nothing to fetch, the visiting order, the bf16
+routes of the two dots) is the latent form's too, and a second kernel
+would have had to copy it.
 """
 
 from __future__ import annotations
@@ -161,11 +176,12 @@ def _kernel(
     n_kv_heads: int,
     page_group: int,
     quantized: bool = False,
+    value_dim: Optional[int] = None,  # latent pages: no v-page refs
 ):
     G = page_group
     k_refs = refs[:G]
-    v_refs = refs[G : 2 * G]
-    base_idx = 2 * G
+    v_refs = refs[G : 2 * G] if value_dim is None else None
+    base_idx = 2 * G if value_dim is None else G
     ks_refs = vs_refs = ()
     if quantized:
         ks_refs = refs[2 * G : 3 * G]
@@ -191,7 +207,10 @@ def _kernel(
             # each page tile is one CONTIGUOUS (Hkv, BS, hd) copy; all
             # KV heads ride it together
             k_all = k_refs[g][...].reshape(n_kv_heads, block_size, hd)
-            v_all = v_refs[g][...].reshape(n_kv_heads, block_size, hd)
+            if value_dim is None:
+                v_all = v_refs[g][...].reshape(n_kv_heads, block_size, hd)
+            else:  # the values are the page's first columns
+                v_all = k_all[:, :, :value_dim]
             if quantized:
                 # in-kernel dequant: multiply the int8 page by its
                 # per-(head, slot) scales right after the gather; the
@@ -360,11 +379,13 @@ def _layer_scalar(layer):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
+@functools.partial(
+    jax.jit, static_argnames=("interpret", "scale", "value_dim")
+)
 def paged_flash_attention(
     q: jax.Array,  # [B, Q, Hq, hd]
     k_pool: jax.Array,  # [NB, Hkv, BS, hd] or [L, NB, Hkv, BS, hd]
-    v_pool: jax.Array,
+    v_pool: Optional[jax.Array],  # None: latent pages (see ``value_dim``)
     tables: jax.Array,  # [B, MB] int32 — pool block id per logical block
     lengths: jax.Array,  # [B] int32 — valid cache prefix per row
     layer: jax.Array | None = None,  # [] or [1] int32, for stacked pools
@@ -373,6 +394,7 @@ def paged_flash_attention(
     v_scale: jax.Array | None = None,
     plan: Optional[PagePlan] = None,  # plan_pages(tables, lengths, ...)
     scale: Optional[float] = None,  # softmax scale; None = 1/sqrt(hd)
+    value_dim: Optional[int] = None,  # latent pages: values = k[..., :value_dim]
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Un-normalized online-softmax attention partials over paged KV.
 
@@ -400,8 +422,14 @@ def paged_flash_attention(
     ``plan``: what :func:`plan_pages` made of these ``tables`` and
     ``lengths`` (which are then not read), for a caller that makes many
     calls over the same rows.
+
+    ``v_pool=None`` with ``value_dim``: latent pages (module docstring);
+    ``acc`` is then ``[B, Q, Hq, value_dim]``.
     """
     B, Q, Hq, hd = q.shape
+    latent = v_pool is None
+    assert latent == (value_dim is not None), (latent, value_dim)
+    vd = value_dim if latent else hd
     layered = k_pool.ndim == 5
     NB, Hkv, BS, _ = k_pool.shape[-4:]
     MB = tables.shape[1]
@@ -410,6 +438,7 @@ def paged_flash_attention(
         assert layer is not None, "layer index required for a stacked pool"
     r = Hq // Hkv
     quantized = k_scale is not None
+    assert not (latent and quantized), "int8 latent pages are not written"
     # tile the query axis (QT tokens per grid cell, QT*r rows of scratch)
     # and pick the page group from the VMEM these shapes need
     G, QT = _plan_tiles(
@@ -452,6 +481,7 @@ def paged_flash_attention(
             n_kv_heads=Hkv,
             page_group=G,
             quantized=quantized,
+            value_dim=value_dim,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -464,13 +494,15 @@ def paged_flash_attention(
                     )
                 ]
                 + kv_specs  # G k-page streams
-                + kv_specs  # G v-page streams (same maps, v operands)
+                # G v-page streams (same maps, v operands); none for
+                # latent pages, whose values ride the k-page stream
+                + ([] if latent else kv_specs)
                 + scale_specs  # int8 pools: G k-scale streams,
                 + scale_specs  # G v-scale streams
             ),
             out_specs=[
                 pl.BlockSpec(
-                    (1, 1, Hkv, QT * r, hd),
+                    (1, 1, Hkv, QT * r, vd),
                     _row_map,
                 ),
                 pl.BlockSpec(
@@ -483,13 +515,13 @@ def paged_flash_attention(
                 ),
             ],
             scratch_shapes=[
-                pltpu.VMEM((Hkv, QT * r, hd), jnp.float32),
+                pltpu.VMEM((Hkv, QT * r, vd), jnp.float32),
                 pltpu.VMEM((Hkv, QT * r, 128), jnp.float32),
                 pltpu.VMEM((Hkv, QT * r, 128), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, vd), jnp.float32),
             jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, 128), jnp.float32),
             jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, 128), jnp.float32),
         ],
@@ -500,7 +532,8 @@ def paged_flash_attention(
         interpret=interpret,
         # the trace names the Mosaic call after this: one query row a
         # sequence is a decode step, a query tile a prefill chunk
-        name="paged_attn_decode" if Q == 1 else "paged_attn_fill",
+        name=("paged_mla_" if latent else "paged_attn_")
+        + ("decode" if Q == 1 else "fill"),
     )(
         plan.lengths,
         plan.page_ids,
@@ -508,11 +541,11 @@ def paged_flash_attention(
         plan.order,
         qg,
         *([k_pool] * G),
-        *([v_pool] * G),
+        *([] if latent else [v_pool] * G),
         *(([k_scale] * G + [v_scale] * G) if quantized else []),
     )
 
-    return _ungroup_outputs(acc, m, l, B, QB, QT, Hkv, r, Q, Hq, hd)
+    return _ungroup_outputs(acc, m, l, B, QB, QT, Hkv, r, Q, Hq, vd)
 
 
 def gather_paged_kv(
@@ -534,9 +567,10 @@ def gather_paged_kv(
 
 def reference_paged_partials(
     q, k_pool, v_pool, tables, lengths, k_scale=None, v_scale=None,
-    scale=None,
+    scale=None, value_dim=None,
 ):
-    """jnp reference for :func:`paged_flash_attention` (same contract).
+    """jnp reference for :func:`paged_flash_attention` (same contract;
+    ``v_pool=None`` with ``value_dim``: latent pages).
 
     ``k_scale``/``v_scale`` ([NB, Hkv, BS]) mark an int8 pool: the
     gathered pages are multiplied by their per-(head, slot) scales right
@@ -544,7 +578,11 @@ def reference_paged_partials(
     B, Q, Hq, hd = q.shape
     NB, Hkv, BS, _ = k_pool.shape
     r = Hq // Hkv
-    k, v = gather_paged_kv(k_pool, v_pool, tables)  # [B,Hkv,S,hd]
+    if v_pool is None:
+        k, _ = gather_paged_kv(k_pool, k_pool, tables)
+        v = k[..., :value_dim]
+    else:
+        k, v = gather_paged_kv(k_pool, v_pool, tables)  # [B,Hkv,S,hd]
     if k_scale is not None:
         ks, vs = gather_paged_kv(
             k_scale[..., None], v_scale[..., None], tables
@@ -565,7 +603,7 @@ def reference_paged_partials(
     l = jnp.sum(p, axis=-1)
     acc = jnp.einsum("bqkrs,bksd->bqkrd", p, v.astype(jnp.float32))
     return (
-        acc.reshape(B, Q, Hq, hd),
+        acc.reshape(B, Q, Hq, v.shape[-1]),
         m.reshape(B, Q, Hq),
         l.reshape(B, Q, Hq),
     )

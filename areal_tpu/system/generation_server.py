@@ -630,6 +630,7 @@ class GenerationServerWorker(worker_base.Worker):
                 "areal_inference_state_slots_live"
             ),
             "moe_expert_pairs": reg.gauge("areal_inference_moe_expert_pairs"),
+            "moe_groups_hit": reg.gauge("areal_inference_moe_groups_hit"),
             "pending": reg.gauge("areal_inference_pending_requests"),
             "version": reg.gauge("areal_inference_weight_version"),
             "ring_depth": reg.gauge("areal_inference_ring_depth"),
@@ -807,6 +808,7 @@ class GenerationServerWorker(worker_base.Worker):
         self._obs["state_slots_live"].set(eng.state_slots_live)
         for e, n in enumerate(eng.moe_expert_pairs.tolist()):
             self._obs["moe_expert_pairs"].set(n, expert=str(e))
+        self._obs["moe_groups_hit"].set(eng.moe_groups_hit_total)
         self._obs["pending"].set(eng.n_pending)
         self._obs["version"].set(eng.version)
         self._obs["ring_depth"].set(eng.pipeline_depth)

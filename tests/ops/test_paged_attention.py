@@ -325,3 +325,76 @@ def test_tile_plan_for_eight_kv_heads_of_four_queries(Q):
         pa.vmem_bytes_needed(8, 1024, 128, 2, False, G, QT * 4)
         <= pa.VMEM_BUDGET_BYTES
     )
+
+
+# -- latent pages: one "head", the page is keys AND (first columns) values --
+
+
+def _latent_setup(B, Q, lengths, MB=5, NB=32, H=16, width=256, L=None, seed=0):
+    """A latent pool ``[NB, 1, BS, width]`` (a token's ``[c_kv | k_rope |
+    0]``) and queries ``[B, Q, H, width]``, as the absorbed form makes
+    them; the table as the engine writes it."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    shape = (NB, 1, BS, width) if L is None else (L, NB, 1, BS, width)
+    pool = (jax.random.normal(ks[0], shape) * 0.5).astype(jnp.bfloat16)
+    q = (jax.random.normal(ks[1], (B, Q, H, width)) * 0.5).astype(jnp.bfloat16)
+    lens = jnp.asarray(lengths, jnp.int32)
+    tables = jax.random.permutation(ks[2], NB)[: B * MB].reshape(B, MB)
+    held = jnp.arange(MB)[None, :] * BS < lens[:, None]
+    return q, pool, jnp.where(held, tables, 0).astype(jnp.int32), lens
+
+
+# rows of 1, 2 and 5 pages, a dead row between them and one at the end
+LATENT_ROWS = [BS - 28, 0, 2 * BS, 5 * BS - 3, BS + 1, 0]
+
+
+@pytest.mark.parametrize("Q", [1, 24])
+def test_latent_pages_match_the_plain_partials(Q):
+    """``paged_mla_decode`` (Q 1) / ``paged_mla_fill``: one fetch of a page
+    serves the scores (every column) and the values (the first
+    ``value_dim``); the accumulator is ``value_dim`` wide."""
+    q, pool, tables, lens = _latent_setup(6, Q, LATENT_ROWS)
+    got = paged_flash_attention(
+        q, pool, None, tables, lens, interpret=True, scale=0.1447, value_dim=128
+    )
+    want = reference_paged_partials(
+        q, pool, None, tables, lens, scale=0.1447, value_dim=128
+    )
+    assert got[0].shape == (6, Q, 16, 128) and got[1].shape == (6, Q, 16)
+    _assert_matches_reference(got, want, lens)
+    # the values ARE the keys' first columns: the same partials as a K/V
+    # pool whose V holds them
+    kv = reference_paged_partials(
+        q, pool, pool[..., :128], tables, lens, scale=0.1447
+    )
+    np.testing.assert_allclose(np.asarray(want[2]), np.asarray(kv[2]), rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(want[0]), np.asarray(kv[0])[..., :128], rtol=1e-5, atol=1e-5
+    )
+
+
+def test_latent_pages_of_a_layer_stacked_pool_and_a_handed_plan():
+    from areal_tpu.ops.paged_attention import page_group, plan_pages
+
+    q, pool, tables, lens = _latent_setup(6, 1, LATENT_ROWS, L=3, seed=3)
+    G = page_group(1, 16, pool.shape, pool.dtype, False, tables.shape[1])
+    plan = plan_pages(tables, lens, BS, G)
+    for layer in (0, 2):
+        got = paged_flash_attention(
+            q, pool, None, tables, lens, layer=jnp.int32(layer), interpret=True,
+            scale=0.2, value_dim=128, plan=plan,
+        )
+        want = reference_paged_partials(
+            q, pool[layer], None, tables, lens, scale=0.2, value_dim=128
+        )
+        _assert_matches_reference(got, want, lens)
+
+
+def test_latent_mode_wants_its_value_width_and_no_v_pool():
+    q, pool, tables, lens = _latent_setup(2, 1, [BS, 3])
+    with pytest.raises(AssertionError):
+        paged_flash_attention(q, pool, None, tables, lens, interpret=True)
+    with pytest.raises(AssertionError):
+        paged_flash_attention(
+            q, pool, pool, tables, lens, interpret=True, value_dim=128
+        )

@@ -575,6 +575,120 @@ def test_hybrid_decode_program_fits_and_updates_the_state_in_place(
     _assert_state_and_pool_stay_put(compiled, pool, ssm)
 
 
+# --- a stack of latent layers: latent pages, at published widths ---
+
+#: gigachat3.1-702b-a36b as the cell runs it (benchmark/configs): one
+#: dense + four expert layers, 16 of 256 experts held, 16,032 vocabulary
+#: rows, every width as published; the cell's engine: 64 rows of 5,120,
+#: 327,680 tokens of latent pages, decode chunks of 16 steps
+LATENT_ROWS, LATENT_CTX, LATENT_POOL_TOKENS = 64, 5120, 327680
+LATENT_CHUNK = 16
+
+
+def _latent_cell_args(one_chip, page):
+    cfg = TransformerConfig(
+        n_layers=5, hidden_dim=7168, n_q_heads=64, n_kv_heads=64,
+        head_dim=192, intermediate_dim=18432, moe_intermediate_dim=2048,
+        shared_expert_dim=2048, vocab_size=16032, norm_eps=1e-6,
+        rotary_base=100000.0, tied_embedding=False, n_experts=256,
+        n_experts_per_tok=8, moe_router="sigmoid_group", moe_n_groups=8,
+        moe_topk_groups=4, moe_routed_scale=2.5, moe_held_experts=16,
+        layer_types=("latent",) * 5, n_dense_layers=1, q_lora_rank=1536,
+        kv_lora_rank=512, qk_rope_head_dim=64, v_head_dim=192,
+        rope_yarn_factor=64.0, rope_yarn_original_max=4096,
+        rope_yarn_mscale=1.0, rope_yarn_mscale_all_dim=1.0,
+    )
+
+    def place(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    params = jax.tree.map(lambda a: place(a.shape, a.dtype), shapes)
+    k_shape, v_shape = paged.pool_shapes(cfg, LATENT_POOL_TOKENS // page, page)
+    assert k_shape[2:] == (1, page, 640) and v_shape[-1] == 0
+    pools = place(k_shape, jnp.bfloat16), place(v_shape, jnp.bfloat16)
+    ssm, conv = (
+        place(a.shape, a.dtype)
+        for a in jax.eval_shape(lambda: hybrid.state_zeros(cfg, LATENT_ROWS))
+    )
+    return cfg, params, pools, ssm, conv, place
+
+
+def _assert_latent_program_fits(compiled, pool):
+    """No copy of the latent pool (2.1 GB) in the optimized HLO, and the
+    whole program inside one chip's memory; returns (total, temporaries)
+    by the compiler's count."""
+    assert _pool_copies(compiled, pool.shape) == []
+    m = compiled.memory_analysis()
+    total = (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+    assert total < USABLE_HBM_BYTES, (total, m.temp_size_in_bytes)
+    return total, m.temp_size_in_bytes
+
+
+# the cell's largest fill: one prompt's chunk of 1,024; and two prompts'
+# tails in one batch
+@pytest.mark.parametrize("F,C", [(1, 1024), (2, 512), (4, 256)])
+def test_latent_fill_program_fits_beside_weights_and_pool(
+    one_chip, monkeypatch, F, C
+):
+    """``hybrid_fill_chunk`` whole at the latent cell's shapes: the
+    prefix part is the Mosaic call ``paged_mla_fill`` (the name the
+    readers match), the pool is an operand in its own layout (640
+    columns: at 576 the compiler copied it whole before every call), and
+    8.58 GB of weights + 2.10 GB of pool + the chunk's temporaries (the
+    in-chunk scores of 64 heads are 0.27 GB in float32 at 1,024 tokens)
+    fit one chip."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    page = 512
+    cfg, params, (pool, vpool), ssm, conv, place = _latent_cell_args(one_chip, page)
+    compiled = hybrid.hybrid_fill_chunk.lower(
+        params, pool, vpool, ssm, conv, cfg,
+        place((F, C), jnp.int32), place((F,), jnp.int32),
+        place((F,), jnp.int32), place((F, LATENT_CTX // page), jnp.int32),
+        place((F,), jnp.int32), use_kernel=True,
+    ).compile()
+    text = compiled.as_text()
+    assert "paged_mla_fill" in text and "paged_attn" not in text
+    assert "ragged-dot" not in text
+    total, temp = _assert_latent_program_fits(compiled, pool)
+    assert 10.6e9 < total, total
+    print(f"latent fill F={F} C={C}: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
+
+
+def test_latent_decode_program_fits_and_reads_the_pool_in_place(
+    one_chip, monkeypatch
+):
+    """``hybrid_decode_chunk`` whole (64 rows, 16 steps) over latent
+    pages: ``paged_mla_decode`` by name, no copy of the pool, none of a
+    layer's held experts, the whole inside one chip."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    page = 512
+    cfg, params, (pool, vpool), ssm, conv, place = _latent_cell_args(one_chip, page)
+
+    def rows(dtype):
+        return place((LATENT_ROWS,), dtype)
+
+    compiled = hybrid.hybrid_decode_chunk.lower(
+        params, pool, vpool, ssm, conv, cfg,
+        place((LATENT_ROWS, LATENT_CTX // page), jnp.int32), rows(jnp.int32),
+        rows(jnp.int32), rows(jnp.bool_), rows(jnp.int32),
+        place((2,), jnp.uint32), chunk_size=LATENT_CHUNK,
+        sample_fn=_keyed_greedy, stop_fn=_never_stop, use_kernel=True,
+        max_len=LATENT_CTX, row_seeds=rows(jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "paged_mla_decode" in text and "paged_attn" not in text
+    assert "ragged-dot" not in text
+    assert _pool_copies(compiled, (16, 2048, 7168)) == []
+    total, temp = _assert_latent_program_fits(compiled, pool)
+    print(f"latent decode: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
+
+
 # --- the trainer's step whole: what the layout rule may ask of one chip ---
 
 
