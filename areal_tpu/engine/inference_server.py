@@ -3952,11 +3952,14 @@ class ContinuousBatchingEngine:
         """The counts of a decode dispatch's span: the context the host
         knows the dispatched rows to have (prompt + generated so far;
         chunks still in the ring are not in it, so it is a floor), the
-        pages that context spans, and the page slots of the whole batch
+        pages that context spans, the page slots of the whole batch
         (every slot's whole table: what a kernel that followed capacity
         would stream; 1 - pages_attended / page_slots is the share the
-        paged kernel skips).  Read only while a profiler session records
-        them."""
+        paged kernel skips), and the tiles the paged kernel copies of
+        those pages (a row's last page only as far as it is filled:
+        ctx_tokens_sum / (tiles_attended x tile_tokens) is the share of
+        what the kernel reads that is attended).  Read only
+        while a profiler session records them."""
         if not span.is_enabled():
             return
         ctx = [
@@ -3964,6 +3967,13 @@ class ContinuousBatchingEngine:
             for i, _ in snapshot
         ]
         page = self.page_size if self.paged else self.kv_cache_len
+        # the unit is the kernel's to name, from the shapes it sees
+        tile = (
+            paged.kernel_tile_tokens(
+                self.k_pool, self.mesh, getattr(self, "_kv_axis", None)
+            )
+            if self.paged else page
+        )
         counts = dict(
             rows=len(snapshot),
             ctx_tokens_sum=sum(ctx),
@@ -3971,6 +3981,8 @@ class ContinuousBatchingEngine:
             pages_attended=sum(-(-c // page) for c in ctx),
             page_slots=len(self.rows)
             * (self.blocks_per_row if self.paged else 1),
+            tiles_attended=sum(-(-c // tile) for c in ctx),
+            tile_tokens=tile,
         )
         if self._stateful:
             # state slots the chunk advances, at most: rows x steps

@@ -72,6 +72,7 @@ from areal_tpu.models.transformer import (
 )
 from areal_tpu.ops.paged_attention import (
     page_group,
+    page_tile,
     paged_flash_attention,
     plan_pages,
     reference_paged_partials,
@@ -213,6 +214,18 @@ def kernel_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _shard_pool_shape(k_pool, mesh=None, kv_axis=None):
+    """(shards, pool shape ``(Hkv, BS, hd)``) as ONE call of the paged
+    kernel sees them: one shard's, where the kv heads split over
+    ``kv_axis``."""
+    shards = (
+        mesh.shape[kv_axis] if mesh is not None and kv_axis is not None
+        else 1
+    )
+    Hkv, BS, hd = k_pool.shape[-3:]
+    return shards, (Hkv // shards, BS, hd)
+
+
 def _prefix_plan(
     n_queries, n_q_heads, k_pool, tables, lengths, use_kernel,
     mesh=None, kv_axis=None, quantized=False,
@@ -223,16 +236,19 @@ def _prefix_plan(
     inside them otherwise.  None without the kernel."""
     if not use_kernel:
         return None
-    shards = (
-        mesh.shape[kv_axis] if mesh is not None and kv_axis is not None
-        else 1
-    )
-    Hkv, BS, hd = k_pool.shape[-3:]
+    shards, shard_shape = _shard_pool_shape(k_pool, mesh, kv_axis)
     group = page_group(
-        n_queries, n_q_heads // shards, (Hkv // shards, BS, hd),
-        k_pool.dtype, quantized, tables.shape[1],
+        n_queries, n_q_heads // shards, shard_shape, k_pool.dtype,
+        quantized, tables.shape[1],
     )
-    return plan_pages(tables, lengths, BS, group)
+    return plan_pages(tables, lengths, shard_shape[1], group)
+
+
+def kernel_tile_tokens(k_pool, mesh=None, kv_axis=None) -> int:
+    """Tokens of the unit the paged kernel copies a page of ``k_pool``
+    in: a row of ``n`` cached positions costs it ``ceil(n / tile)`` tiles
+    (for the engine's counts)."""
+    return page_tile(_shard_pool_shape(k_pool, mesh, kv_axis)[1], k_pool.dtype)
 
 
 def _prefix_partials(

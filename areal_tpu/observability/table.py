@@ -1137,7 +1137,9 @@ TRACE_TABLE = [
         "One decode chunk (or verify window) dispatched (counts: rows, "
         "ctx_tokens_sum = prompt + generated known to the host over the "
         "dispatched rows, chunk_size, pages_attended, page_slots = "
-        "batch slots x pages a slot's table holds; state_rows_sum = "
+        "batch slots x pages a slot's table holds, tiles_attended = "
+        "sum of ceil(context / tile_tokens), tile_tokens = the unit the "
+        "paged kernel copies a page in; state_rows_sum = "
         "rows x steps whose state slots the chunk advances; for latent "
         "pages latent_ctx_tokens_sum and latent_pages_attended = the "
         "same context and pages, ONE entry a position and layer)",

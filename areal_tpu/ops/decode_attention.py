@@ -13,9 +13,8 @@ over that row's cache prefix ``[0, length)``:
   later grid steps of the SAME operand, so they re-address the tile of
   the step before and the pipeline skips their HBM->VMEM copies: short
   rows stream only the KV they own.  (That holds for one operand walked
-  along the grid's minor axis, as here; the paged kernel's G page
-  streams are operands of their own and need
-  ``paged_attention.stream_page_ids``);
+  along the grid's minor axis, as here; the paged kernel, whose pages
+  lie anywhere in a pool, starts its own copies: ``ops/paged_attention``);
 * GQA is grouped: the query head group ``r = Hq // Hkv`` shares one KV head
   per grid cell, so the cache is read once per KV head (never
   repeat-materialized).

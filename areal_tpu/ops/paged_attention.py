@@ -14,26 +14,43 @@ Kernel shape:
 
 * grid ``(B, QB, ceil(MB/G))`` — MB is the static per-row block
   capacity, G pages stream per step (PAGE_GROUP), QB tiles the query
-  axis so VMEM scratch stays bounded at prefill-chunk shapes; the minor
-  axis iterates sequentially on TPU so online-softmax state (m/l/acc)
-  lives in VMEM scratch across blocks;
-* each of the G page streams is an OPERAND of its own, and the pipeline
-  skips an operand's HBM->VMEM copy only when its block index is the one
-  of the grid step before.  So the K/V index maps read a scalar-prefetch
-  table of page ids made outside the kernel (:func:`stream_page_ids`):
-  a stream's own page where that page holds valid KV, and otherwise the
-  page the stream fetched last.  Each valid page is copied once; dead
-  rows and the pages past a short row's length start no copy.  (Clamping
-  a stream to its row's LAST valid page instead re-reads that page once
-  a stream and row: 256 MiB a call for 69 MiB of valid pages at 64 slots
-  x 4 pages, PERF.md section 6);
+  axis so VMEM scratch stays bounded at prefill-chunk shapes; the steps
+  run in order on one core, so online-softmax state (m/l/acc) lives in
+  VMEM scratch across a row's page steps and a copy started at one step
+  lands at the next;
+* the pools stay in HBM and the kernel copies pages ITSELF: each of the
+  G streams has two page buffers, and a grid step first queues the NEXT
+  step's copies (into the buffers this step does not read), then waits
+  for its own, then multiplies — the one-step look-ahead of the BlockSpec
+  pipeline it replaces, without that pipeline's bookkeeping for pages no
+  row holds (eight operands' index maps at every step, dead rows' too:
+  half of a call at 22 live rows of 64, PERF.md PR 37);
+* a page is copied only AS FAR AS IT IS FILLED, in tiles
+  (:func:`_tile_tokens`): one descriptor of ``s`` tiles for each ``s`` a
+  page can hold, chosen by a branch on the row's length.  Each valid
+  page is copied once; dead rows and the pages past a row's length start
+  no copy; a stream that already holds the page as far (a query tile
+  that visits the row again, a sibling that shares it) starts none
+  either (:func:`stream_page`, :func:`page_fetched`).  It is MULTIPLIED
+  whole, in one update a head, with what lies past the row's length
+  masked as ever: an update's cost on a v5e is a third of a microsecond
+  a head almost whatever its length, so tiles as units of their own
+  (operand streams of tiles, or a dot a tile) lost at every shape
+  (PERF.md PR 37).  The buffers' value side is zeroed at a call's first
+  step, so that what a bounded copy leaves behind it is finite;
+* a step's streams are a LOOP (``each_stream``), for their copies and
+  for their dots: the kernel is traced, lowered and compiled at a
+  quarter of its unrolled size, which is what a program's first call
+  pays, in every process (the unrolled form doubled the benchmark's
+  warm set-up: PERF.md PR 37);
 * the grid visits the rows in falling order of their page counts
   (:func:`visit_order`; q and the outputs are addressed through it), so
-  that the copies of the next row overlap the dots of this one;
+  that the copies of the next row overlap the dots of this one and dead
+  rows come last, where a step costs one branch;
 * queries are GQA-grouped AND chunk-grouped: ``q`` carries Q query
   tokens per row (Q=1 for decode; Q=chunk for chunked prefill's
   prefix attention) and every query row of a (b, qb) cell shares one
-  streamed KV page — all KV heads of a page ride one contiguous DMA.
+  copied KV page — all KV heads of a page ride one copy.
 
 Returns UN-normalized partials ``(acc, m, l)`` so the caller online-merges
 them with attention over KV not in the pool yet (the decode chunk's
@@ -43,7 +60,7 @@ in-flight window, or a prefill chunk's causal self-attention).
 whose page ``[BS, kv_latent_dim]`` holds each token's ``[c_kv | k_rope]``
 (latent attention in its absorbed form).  The page is fetched ONCE and
 serves as the keys (all ``kv_latent_dim`` columns) and as the values
-(its first ``value_dim`` columns), so the kernel has G operand streams
+(its first ``value_dim`` columns), so the kernel has G page streams
 where the K/V form has 2 G, its accumulator is ``value_dim`` wide, and
 every query head shares the one stream (``r = Hq``).  It is the same
 kernel, grid, page plan and softmax update; the K/V form's programs are
@@ -75,15 +92,35 @@ from areal_tpu.ops.decode_attention import (
 _NEG_INF = -1e30
 
 
-#: logical pages streamed per grid step, each as an operand stream of its
-#: own.  A row of up to G pages is one grid step: its 2 G tile copies are
-#: in flight together while the row before it computes.  G was chosen
-#: against the dense-einsum path on v5e at 8k context (1.5B heads, B=16):
-#: G=1 0.70x and G=4 0.78x with 256-token pages, G=4 0.93x and G=8 0.83x
-#: with 1024-token pages.  What G overlaps is whole rows' copies with
-#: whole rows' dots, not copy latencies: with 1024-token pages and every
-#: copy useful the pipeline reaches 733 GB/s of 819 (PERF.md, PR 25).
+#: logical pages streamed per grid step, each a stream of its own (two
+#: page buffers, K and V).  A row of up to G pages is one grid step: its
+#: copies are in flight together while the row before it computes.  G
+#: was chosen against the dense-einsum path on v5e at 8k context (1.5B
+#: heads, B=16) when the BlockSpec pipeline made the copies: G=1 0.70x
+#: and G=4 0.78x with 256-token pages, G=4 0.93x and G=8 0.83x with
+#: 1024-token pages; not timed again since the kernel copies for itself
+#: (PR 37).  What G overlaps is whole rows' copies with whole rows' dots,
+#: not copy latencies: with 1024-token pages and every page full the
+#: kernel reaches 734 GB/s of 819 (PERF.md, PR 37; 733 in PR 25).
 PAGE_GROUP = 4
+
+
+#: the lane tile: a page tile is a whole number of these
+LANES = 128
+
+#: the unit a page is copied in (:func:`_tile_tokens`): a row's LAST page
+#: is copied as far as the last tile that holds a cached position, not
+#: whole.  At least this many tokens and at least this many bytes of one
+#: pool's page: the kernel holds a copy descriptor a stream for every
+#: number of filled tiles, and the scalar core walks their branches at
+#: every grid step (kernel alone at tiles of 1024 / 512 / 256 tokens:
+#: PERF.md, PR 37).
+MIN_TILE_TOKENS = 256
+MIN_TILE_BYTES = 256 << 10
+
+#: tiles a page is cut into at most
+MAX_PAGE_TILES = 4
+
 
 
 #: cap on query rows (Q*r) per grid cell, so prefill-chunk shapes (Q up
@@ -108,12 +145,14 @@ def vmem_bytes_needed(
     page_group: int, q_rows: int,
 ) -> int:
     """VMEM one grid cell of :func:`paged_flash_attention` needs, from its
-    shapes: double-buffered page/scale/q/output tiles, the f32 scratch,
-    and the per-head temporaries of :func:`softmax_block_update` (scores
-    and probabilities [q_rows, BS], the page's f32 copies, and the bf16
-    splits HIGHEST precision makes of each dot operand)."""
+    shapes: the two page/scale buffers of each of the G streams,
+    double-buffered q/output tiles, the f32 scratch, and the per-head
+    temporaries of :func:`softmax_block_update` (scores and
+    probabilities [q_rows, BS], the page's f32 copies, and the bf16
+    splits HIGHEST precision makes of each dot operand).  A page copied
+    as far as it is filled needs the buffers of a whole one."""
     page = Hkv * BS * hd * kv_itemsize
-    pages = 2 * page_group * 2 * page  # k+v, G streams, double-buffered
+    pages = 2 * page_group * 2 * page  # k+v, G streams, two buffers each
     if quantized:
         pages += 2 * page_group * 2 * Hkv * BS * 4  # scale tiles
         pages += 2 * Hkv * BS * hd * 4  # the dequantized page, f32
@@ -124,15 +163,33 @@ def vmem_bytes_needed(
     return pages + q_tile + outs_and_scratch + temps
 
 
+def _tile_tokens(Hkv: int, BS: int, hd: int, kv_itemsize: int) -> int:
+    """Tokens of the unit a page is copied in: the smallest divisor of
+    the page that is a whole number of lane tiles, at least
+    MIN_TILE_TOKENS, MIN_TILE_BYTES of one pool (all kv heads) and a
+    MAX_PAGE_TILES-th of the page; the page itself where it has no such
+    divisor."""
+    for tile in range(LANES, BS, LANES):
+        if (
+            BS % tile == 0
+            and tile >= MIN_TILE_TOKENS
+            and Hkv * tile * hd * kv_itemsize >= MIN_TILE_BYTES
+            and tile * MAX_PAGE_TILES >= BS
+        ):
+            return tile
+    return BS
+
+
 def _plan_tiles(
     Q: int, r: int, Hkv: int, BS: int, hd: int, kv_itemsize: int,
     quantized: bool, MB: int,
-) -> Tuple[int, int]:
-    """(page_group G, query tokens per cell QT) for these shapes: start
-    from PAGE_GROUP pages and MAX_Q_ROWS rows and give up query rows,
-    then pages, until :func:`vmem_bytes_needed` fits the budget.  Raises
-    when even one page and one sublane tile of queries do not fit —
-    the caller must not quietly take another path."""
+) -> Tuple[int, int, int]:
+    """(page_group G, query tokens per cell QT, tokens a tile) for these
+    shapes: start from PAGE_GROUP pages and MAX_Q_ROWS rows and give up
+    query rows, then pages, until :func:`vmem_bytes_needed` fits the
+    budget; the tile is :func:`_tile_tokens`'s.  Raises when even one
+    page and one sublane tile of queries do not fit — the caller must
+    not quietly take another path."""
     # QT*r must be a multiple of the 8-row sublane tile unless one cell
     # holds the whole (short) query axis
     step = 8 // np.gcd(8, r)
@@ -148,7 +205,7 @@ def _plan_tiles(
                 )
                 <= VMEM_BUDGET_BYTES
             ):
-                return G, QT
+                return G, QT, _tile_tokens(Hkv, BS, hd, kv_itemsize)
             if QT <= step:
                 break
             QT = max(step, (QT // 2) // step * step)
@@ -164,111 +221,204 @@ def _plan_tiles(
         G //= 2
 
 
+def stream_page(lengths, page_ids, b, j, g, group: int, block_size: int, tile: int):
+    """``(pool page id, tiles that hold cached positions)`` of stream ``g``
+    at the grid step of row ``b`` (in visiting order) and page step ``j``:
+    what the kernel copies there, from a :class:`PagePlan`'s lengths and
+    page ids (an id past a row's length is never read: it holds no
+    tile)."""
+    col = j * group + g
+    held = (lengths[b] - col * block_size + tile - 1) // tile
+    return page_ids[b, col], jnp.clip(held, 0, block_size // tile)
+
+
+def page_fetched(this, before, no_step_before):
+    """Whether a stream starts a copy for a grid step where its
+    :func:`stream_page` is ``this``, after a step where it was
+    ``before``: it holds cached positions there, and not already the same
+    page as far (a query tile that visits the row again, a sibling that
+    shares the page)."""
+    (pid, n), (pid0, n0) = this, before
+    return (n > 0) & (no_step_before | (pid != pid0) | (n > n0))
+
+
 def _kernel(
     lengths_ref,  # scalar prefetch [B], in the order the grid visits
-    ids_ref,  # scalar prefetch [B, MB']: stream_page_ids, for the maps
+    ids_ref,  # scalar prefetch [B, MB']: the rows' tables
     layer_ref,  # scalar prefetch [1] (0 when the pool is per-layer)
     order_ref,  # scalar prefetch [B]: visit_order, for the maps
     q_ref,  # (1, 1, Hkv, QR, hd)
-    *refs,  # G k-page refs, G v-page refs, [2G scale refs], 3 outs, 3 scratch
+    *refs,  # pools in HBM, 3 outs, page buffers, 3 scratch, slots, semaphores
     block_size: int,
+    tile: int,
     scale: float,
     n_kv_heads: int,
     page_group: int,
+    layered: bool,
     quantized: bool = False,
-    value_dim: Optional[int] = None,  # latent pages: no v-page refs
+    value_dim: Optional[int] = None,  # latent pages: no v pool
 ):
-    G = page_group
-    k_refs = refs[:G]
-    v_refs = refs[G : 2 * G] if value_dim is None else None
-    base_idx = 2 * G if value_dim is None else G
-    ks_refs = vs_refs = ()
-    if quantized:
-        ks_refs = refs[2 * G : 3 * G]
-        vs_refs = refs[3 * G : 4 * G]
-        base_idx = 4 * G
-    acc_ref, m_ref, l_ref = refs[base_idx : base_idx + 3]
-    s_acc, s_m, s_l = refs[base_idx + 3 :]
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nb = pl.num_programs(2)
+    G, S = page_group, block_size // tile
+    # the arrays a page is made of: K, V (none for latent pages, whose
+    # values ride the K page) and an int8 pool's two scale arrays
+    n_arrays = (1 if value_dim is not None else 2) * (2 if quantized else 1)
+    pools = refs[:n_arrays]
+    acc_ref, m_ref, l_ref = refs[n_arrays : n_arrays + 3]
+    bufs = refs[n_arrays + 3 : 2 * n_arrays + 3]
+    s_acc, s_m, s_l, slot_ref, coming_ref, landing_ref = refs[
+        2 * n_arrays + 3 : 2 * n_arrays + 9
+    ]
+    sems = refs[2 * n_arrays + 9 :]
+    # what the values are made of: V (the K page's first columns for
+    # latent pages) and an int8 pool's V scales
+    value_bufs = [bufs[0 if value_dim is not None else 1]] + (
+        [bufs[3]] if quantized else []
+    )
+    b, qb, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nB, nQ, nJ = pl.num_programs(0), pl.num_programs(1), pl.num_programs(2)
+    first = (b == 0) & (qb == 0) & (j == 0)
+    last = (b == nB - 1) & (qb == nQ - 1) & (j == nJ - 1)
+    # the grid step after this one (the last step stays where it is)
+    row_ends = j == nJ - 1
+    b_next = jnp.where(row_ends & (qb == nQ - 1) & (b < nB - 1), b + 1, b)
+    j_next = jnp.where(row_ends, 0, j + 1)
+
+    def page(bb, jj, g):
+        return stream_page(
+            lengths_ref, ids_ref, bb, jj, g, G, block_size, tile
+        )
+
+    def copies(g, slot, pid, tiles):
+        """One descriptor an array for the first ``tiles`` tiles of pool
+        page ``pid`` into buffer ``slot`` of stream ``g``."""
+        n = tiles * tile
+        at = (layer_ref[0], pid) if layered else (pid,)
+        out = []
+        for pool, buf, sem in zip(pools, bufs, sems):
+            tail = (slice(None), pl.ds(0, n)) + (slice(None),) * (
+                len(pool.shape) - len(at) - 2
+            )
+            out.append(
+                pltpu.make_async_copy(
+                    pool.at[at + tail], buf.at[(slot, g) + tail],
+                    sem.at[slot, g],
+                )
+            )
+        return out
+
+    def for_tiles(n, fn, also=True):
+        """``fn(s)`` for the static ``s`` in 1..S that ``n`` equals."""
+        for s in range(1, S + 1):
+            pl.when(also & (n == s))(functools.partial(fn, s))
+
+    def each_stream(fn):
+        """``fn(g)`` for every stream, as a loop: a stream's copies and
+        dots are traced, lowered and held by the core ONCE (a step's
+        streams run one after the other either way, each behind its own
+        branch)."""
+        jax.lax.fori_loop(0, G, lambda g, _: fn(g) or 0, 0)
+
+    def start_copies_for(bb, jj, b_before, j_before, no_step_before):
+        """The copies grid step ``(bb, jj)`` reads, each into the buffer
+        its stream is NOT reading from, with word of it for that step."""
+
+        def _stream(g):
+            pid, n = page(bb, jj, g)
+            wanted = page_fetched(
+                (pid, n), page(b_before, j_before, g), no_step_before
+            )
+
+            def _start(s):
+                for c in copies(g, 1 - slot_ref[g], pid, s):
+                    c.start()
+                coming_ref[g] = s
+
+            for_tiles(n, _start, wanted)
+
+        each_stream(_stream)
+
+    @pl.when(first)
+    def _first_step_fetches_for_itself():
+        if S > 1:
+            # a page is multiplied whole and copied as far as it is
+            # filled: what lies behind holds probability 0, which only a
+            # FINITE value keeps out of the sum, and a buffer no copy has
+            # reached yet holds whatever the kernel before left there
+            for buf in value_bufs:
+                buf[...] = jnp.zeros(buf.shape, buf.dtype)
+
+        def _reset(g):
+            slot_ref[g] = 0
+            coming_ref[g] = 0
+
+        each_stream(_reset)
+        start_copies_for(b, j, b, j, True)
+
+    # a stream whose copy is on its way reads from the other buffer from
+    # now on, which leaves the one it read from free for the next step's
+    def _turn(g):
+        landing_ref[g] = coming_ref[g]
+        slot_ref[g] = jnp.where(coming_ref[g] > 0, 1 - slot_ref[g], slot_ref[g])
+        coming_ref[g] = 0
+
+    each_stream(_turn)
+
+    # the next step's copies are queued behind this step's before this
+    # step waits for its own: the copy engine never idles between them.
+    # Stream 0 holds a step's lowest positions: where it has none (a
+    # dead row: they come last), no stream has
+    @pl.when(jnp.logical_not(last) & (page(b_next, j_next, 0)[1] > 0))
+    def _fetch_for_the_next_step():
+        start_copies_for(b_next, j_next, b, j, False)
 
     @pl.when(j == 0)
     def _init():
         softmax_scratch_init(s_acc, s_m, s_l)
 
     length = lengths_ref[b]
-    hd = k_refs[0].shape[-1]
-    for g in range(G):
-        base = (j * G + g) * block_size
 
-        @pl.when(base < length)
-        def _block(g=g, base=base):
-            # each page tile is one CONTIGUOUS (Hkv, BS, hd) copy; all
-            # KV heads ride it together
-            k_all = k_refs[g][...].reshape(n_kv_heads, block_size, hd)
-            if value_dim is None:
-                v_all = v_refs[g][...].reshape(n_kv_heads, block_size, hd)
-            else:  # the values are the page's first columns
-                v_all = k_all[:, :, :value_dim]
-            if quantized:
-                # in-kernel dequant: multiply the int8 page by its
-                # per-(head, slot) scales right after the gather; the
-                # dots below then take float32 operands (HIGHEST)
-                ks = ks_refs[g][...].reshape(n_kv_heads, block_size)
-                vs = vs_refs[g][...].reshape(n_kv_heads, block_size)
-                k_all = k_all.astype(jnp.float32) * ks[:, :, None]
-                v_all = v_all.astype(jnp.float32) * vs[:, :, None]
-            for h in range(n_kv_heads):
-                softmax_block_update(
-                    q_ref[0, 0, h], k_all[h], v_all[h],
-                    s_acc.at[h], s_m.at[h], s_l.at[h],
-                    base=base, length=length, scale=scale,
-                )
+    @pl.when(page(b, j, 0)[1] > 0)
+    def _row_has_pages_here():
+        def _stream(g):
+            # what this step reads was started one step ago and has to be
+            # here now (the wait needs the copy's size, not its source)
+            def _wait(s):
+                for c in copies(g, slot_ref[g], 0, s):
+                    c.wait()
 
-    @pl.when(j == nb - 1)
+            for_tiles(landing_ref[g], _wait)
+
+            @pl.when(page(b, j, g)[1] > 0)
+            def _block():
+                # all KV heads of the page rode one copy
+                slot = slot_ref[g]
+                k_all = bufs[0][slot, g]
+                if value_dim is None:
+                    v_all = bufs[1][slot, g]
+                else:  # the values are the page's first columns
+                    v_all = k_all[:, :, :value_dim]
+                if quantized:
+                    # in-kernel dequant: multiply the int8 page by its
+                    # per-(head, slot) scales right after the gather;
+                    # the dots below then take float32 operands (HIGHEST)
+                    ks, vs = bufs[2][slot, g], bufs[3][slot, g]
+                    k_all = k_all.astype(jnp.float32) * ks[:, :, None]
+                    v_all = v_all.astype(jnp.float32) * vs[:, :, None]
+                for h in range(n_kv_heads):
+                    softmax_block_update(
+                        q_ref[0, 0, h], k_all[h], v_all[h],
+                        s_acc.at[h], s_m.at[h], s_l.at[h],
+                        base=(j * G + g) * block_size, length=length,
+                        scale=scale,
+                    )
+
+        each_stream(_stream)
+
+    @pl.when(j == nJ - 1)
     def _emit():
         acc_ref[0, 0] = s_acc[...]
         m_ref[0, 0] = s_m[...]
         l_ref[0, 0] = s_l[...]
-
-
-def stream_page_ids(tables, lengths, block_size: int, group: int):
-    """Pool page id that stream ``g`` of the kernel addresses at grid
-    step ``(b, ., j)``, as ``[B, ceil(MB/group) * group]`` int32 indexed
-    ``[b, j * group + g]``: the row's own page where that page holds
-    valid KV (``(j * group + g) * block_size < lengths[b]``), and
-    otherwise the page the stream fetched LAST, in the order the grid
-    visits its steps (a forward fill of the valid ids down each of the
-    ``group`` columns).
-
-    The BlockSpec pipeline skips an operand's HBM->VMEM copy when its
-    block index is the one of the step before, so a stream that has
-    nothing to fetch must repeat ITS OWN last index.  Every row visits
-    the same ``j`` sequence under each ``qb``, and a column with no
-    valid page in a row is constant over that row, so the fill over
-    ``(b, j)`` holds for any number of query tiles.  Rows that share a
-    page (siblings of one prompt) in consecutive slots skip its copy
-    too.
-    """
-    B, MB = tables.shape
-    n_j = -(-MB // group)
-    width = n_j * group
-    tables = tables.astype(jnp.int32)
-    if width != MB:
-        tables = jnp.pad(tables, ((0, 0), (0, width - MB)))
-    page = jnp.arange(width, dtype=jnp.int32)
-    valid = page[None, :] * block_size < lengths.astype(jnp.int32)[:, None]
-    # flatten (b, j) into the order the grid visits: one line a step,
-    # one column a stream
-    step = jnp.arange(B * n_j, dtype=jnp.int32)[:, None]
-    last_valid_step = jax.lax.cummax(
-        jnp.where(valid.reshape(B * n_j, group), step, 0), axis=0
-    )
-    ids = jnp.take_along_axis(
-        tables.reshape(B * n_j, group), last_valid_step, axis=0
-    )
-    return ids.reshape(B, width)
 
 
 def visit_order(lengths, block_size: int):
@@ -284,11 +434,11 @@ def visit_order(lengths, block_size: int):
 
 
 class PagePlan(NamedTuple):
-    """What the kernel's index maps read, made from one (tables,
-    lengths) pair for one page group."""
+    """What the kernel reads of one (tables, lengths) pair, in the order
+    its grid visits the rows."""
 
-    lengths: jax.Array  # [B] valid prefix per row, in visiting order
-    page_ids: jax.Array  # [B, MB'] stream_page_ids, in visiting order
+    lengths: jax.Array  # [B] valid prefix per row
+    page_ids: jax.Array  # [B, MB'] the rows' tables, a whole number of steps wide
     order: jax.Array  # [B] visit_order
 
 
@@ -297,12 +447,13 @@ def plan_pages(tables, lengths, block_size: int, group: int) -> PagePlan:
     is handed one.  A caller that runs the kernel many times over the
     same rows (every layer of every step of a decode chunk) makes it
     once, with the ``group`` :func:`page_group` names for its shapes:
-    XLA does not hoist the sort and the scan out of those loops (about
-    9 us a call on a v5e, beside a kernel of 80-120)."""
+    XLA does not hoist the sort out of those loops."""
     order = visit_order(lengths, block_size)
-    lengths = lengths.astype(jnp.int32)[order]
-    ids = stream_page_ids(tables[order], lengths, block_size, group)
-    return PagePlan(lengths, ids, order)
+    tables = tables.astype(jnp.int32)[order]
+    short = -tables.shape[1] % group
+    if short:  # the steps past a row's table hold no cached position
+        tables = jnp.pad(tables, ((0, 0), (0, short)))
+    return PagePlan(lengths.astype(jnp.int32)[order], tables, order)
 
 
 def page_group(
@@ -318,20 +469,19 @@ def page_group(
     )[0]
 
 
+def page_tile(pool_shape, kv_dtype) -> int:
+    """Tokens of the unit the kernel copies a page of this pool in
+    (``pool_shape`` as the kernel sees it: one shard's, under a TP mesh):
+    of a row of ``n`` cached positions it reads ``ceil(n / tile)``
+    tiles."""
+    Hkv, BS, hd = pool_shape[-3:]
+    return _tile_tokens(Hkv, BS, hd, jnp.dtype(kv_dtype).itemsize)
+
+
 def _row_map(b, qb, j, lengths_ref, ids_ref, layer_ref, order_ref):
     """Query and output tiles of step (b, qb, .): those of the row this
     step works on."""
     return (order_ref[b], qb, 0, 0, 0)
-
-
-def _paged_page_map(b, qb, j, lengths_ref, ids_ref, layer_ref, order_ref, *,
-                    layered, group, offset, rank):
-    """Block index (of ``rank`` axes: a KV page's or its int8 scales') of
-    stream ``offset`` at step (b, qb, j): the page :func:`stream_page_ids`
-    assigned it, whole."""
-    pid = ids_ref[b, j * group + offset]
-    head = (layer_ref[0], pid) if layered else (pid,)
-    return head + (0,) * (rank - len(head))
 
 
 def _group_queries(q, Hkv, r, QT):
@@ -404,9 +554,10 @@ def paged_flash_attention(
     self-attention term).  Returns ``(acc [B,Q,Hq,hd] f32, m [B,Q,Hq],
     l [B,Q,Hq])``; rows with ``length == 0`` return ``acc=0, l=0, m=-inf``.
 
-    Pool layout is PAGE-major ``[NB, Hkv, BS, hd]`` so one page's tile is
-    one contiguous (Hkv, BS, hd) HBM read, and the grid streams
-    ``PAGE_GROUP`` pages per step (their DMAs overlap — see PAGE_GROUP).
+    Pool layout is PAGE-major ``[NB, Hkv, BS, hd]`` so a whole page is one
+    contiguous (Hkv, BS, hd) HBM read (a partly filled one ``Hkv``
+    pieces), and the grid streams ``PAGE_GROUP`` pages per step (their
+    copies overlap — see PAGE_GROUP).
 
     A 5-D ``k_pool``/``v_pool`` is the FULL layer-stacked pool; ``layer``
     (traced scalar) selects the layer inside the kernel's index map, so a
@@ -415,9 +566,9 @@ def paged_flash_attention(
     forward).
 
     ``k_scale``/``v_scale`` mark an int8-quantized pool: each page's
-    scale tile streams beside its KV tile through the same index map and
-    the kernel dequantizes in VMEM right after the gather (the
-    storage-only quantization contract).
+    scales are copied beside it, as far, and the kernel dequantizes in
+    VMEM right after the gather (the storage-only quantization
+    contract).
 
     ``plan``: what :func:`plan_pages` made of these ``tables`` and
     ``lengths`` (which are then not read), for a caller that makes many
@@ -441,7 +592,7 @@ def paged_flash_attention(
     assert not (latent and quantized), "int8 latent pages are not written"
     # tile the query axis (QT tokens per grid cell, QT*r rows of scratch)
     # and pick the page group from the VMEM these shapes need
-    G, QT = _plan_tiles(
+    G, QT, tile = _plan_tiles(
         Q, r, Hkv, BS, hd, jnp.dtype(k_pool.dtype).itemsize, quantized, MB
     )
     qg, QB = _group_queries(q, Hkv, r, QT)
@@ -454,71 +605,51 @@ def paged_flash_attention(
     assert plan.page_ids.shape == (B, grid[2] * G), (
         plan.page_ids.shape, B, MB, G,
     )
-    kv_block = (1, 1, Hkv, BS, hd) if layered else (1, Hkv, BS, hd)
-    # int8 pools: each page's scale tile (one f32 per head x slot) rides
-    # the same page ids as its KV tile
-    scale_block = (1, 1, Hkv, BS) if layered else (1, Hkv, BS)
-
-    def page_specs(block):
-        return [
-            pl.BlockSpec(
-                block,
-                functools.partial(
-                    _paged_page_map, layered=layered, group=G, offset=g,
-                    rank=len(block),
-                ),
-            )
-            for g in range(G)
-        ]
-
-    kv_specs = page_specs(kv_block)
-    scale_specs = page_specs(scale_block) if quantized else []
+    # the pools stay in HBM: the kernel copies each stream's page into one
+    # of its two buffers itself, as far as the page is filled.  int8
+    # pools: a page's scales (one f32 per head x slot) ride beside it
+    pools = [k_pool] + ([] if latent else [v_pool])
+    if quantized:
+        pools += [k_scale, v_scale]
+    page_buffers = [
+        pltpu.VMEM((2, G) + p.shape[k_pool.ndim - 3:], p.dtype)  # a page
+        for p in pools
+    ]
     acc, m, l = pl.pallas_call(
         functools.partial(
             _kernel,
             block_size=BS,
+            tile=tile,
             scale=1.0 / np.sqrt(hd) if scale is None else scale,
             n_kv_heads=Hkv,
             page_group=G,
+            layered=layered,
             quantized=quantized,
             value_dim=value_dim,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=grid,
-            in_specs=(
-                [
-                    pl.BlockSpec(
-                        (1, 1, Hkv, QT * r, hd),
-                        _row_map,
-                    )
-                ]
-                + kv_specs  # G k-page streams
-                # G v-page streams (same maps, v operands); none for
-                # latent pages, whose values ride the k-page stream
-                + ([] if latent else kv_specs)
-                + scale_specs  # int8 pools: G k-scale streams,
-                + scale_specs  # G v-scale streams
-            ),
+            in_specs=[pl.BlockSpec((1, 1, Hkv, QT * r, hd), _row_map)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
             out_specs=[
-                pl.BlockSpec(
-                    (1, 1, Hkv, QT * r, vd),
-                    _row_map,
-                ),
-                pl.BlockSpec(
-                    (1, 1, Hkv, QT * r, 128),
-                    _row_map,
-                ),
-                pl.BlockSpec(
-                    (1, 1, Hkv, QT * r, 128),
-                    _row_map,
-                ),
+                pl.BlockSpec((1, 1, Hkv, QT * r, vd), _row_map),
+                pl.BlockSpec((1, 1, Hkv, QT * r, 128), _row_map),
+                pl.BlockSpec((1, 1, Hkv, QT * r, 128), _row_map),
             ],
-            scratch_shapes=[
+            scratch_shapes=page_buffers
+            + [
                 pltpu.VMEM((Hkv, QT * r, vd), jnp.float32),
                 pltpu.VMEM((Hkv, QT * r, 128), jnp.float32),
                 pltpu.VMEM((Hkv, QT * r, 128), jnp.float32),
-            ],
+                # which of its two buffers each stream reads from, the
+                # tiles on their way into the other one for the next
+                # step, and those this step waits for
+                pltpu.SMEM((G,), jnp.int32),
+                pltpu.SMEM((G,), jnp.int32),
+                pltpu.SMEM((G,), jnp.int32),
+            ]
+            + [pltpu.SemaphoreType.DMA((2, G))] * len(pools),
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, vd), jnp.float32),
@@ -526,7 +657,9 @@ def paged_flash_attention(
             jax.ShapeDtypeStruct((B, QB, Hkv, QT * r, 128), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # a copy started at one grid step lands at the next: the
+            # steps run in order, on one core
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
@@ -540,9 +673,7 @@ def paged_flash_attention(
         layer_arr,
         plan.order,
         qg,
-        *([k_pool] * G),
-        *([] if latent else [v_pool] * G),
-        *(([k_scale] * G + [v_scale] * G) if quantized else []),
+        *pools,
     )
 
     return _ungroup_outputs(acc, m, l, B, QB, QT, Hkv, r, Q, Hq, vd)

@@ -29,8 +29,10 @@ HOST_PHASES = [
 ]
 
 
-def _engine(mode="paged", **kw):
-    cfg = tiny_config(vocab_size=64, max_position_embeddings=512)
+def _engine(mode="paged", head_dim=8, **kw):
+    cfg = tiny_config(
+        vocab_size=64, max_position_embeddings=512, head_dim=head_dim
+    )
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     args = dict(
         max_batch=8, kv_cache_len=128, chunk_size=8,
@@ -276,3 +278,57 @@ def test_train_batch_counts_the_attention_block_pairs_its_layout_runs(
         causal_total += span.counts["attn_blocks_causal"]
     assert eng.attn_blocks_run_total == run_total
     assert eng.attn_blocks_causal_total == causal_total
+
+
+class _Span:
+    """A span that records: what ``_count_dispatch`` meets while a
+    profiler session is on."""
+
+    def __init__(self):
+        self.counts = {}
+
+    def is_enabled(self):
+        return True
+
+    def set_metadata(self, **kw):
+        self.counts.update(kw)
+
+
+@pytest.mark.parametrize(
+    "mode,kw,page,tile",
+    [
+        ("paged", {}, 16, 16),  # a page of 16 tokens is its own tile
+        # heads of 8: 64 B a token, too little to cut a page of 1,024
+        ("paged", dict(page_size=1024, kv_cache_len=2048,
+                       kv_pool_tokens=8192), 1024, 1024),
+        # 2 kv heads x 128 x 4 B: a tile of 256 tokens is 256 KiB
+        ("paged", dict(page_size=1024, kv_cache_len=2048, head_dim=128,
+                       kv_pool_tokens=8192), 1024, 256),
+        ("dense", {}, 128, 128),  # one row, one "page", nothing to cut
+    ],
+)
+def test_dispatch_span_counts_the_tiles_the_kernel_reads(mode, kw, page, tile):
+    """``tiles_attended`` / ``tile_tokens`` on ``areal.engine.decode.dispatch``
+    against a hand count: the tile is the kernel module's (from the
+    pool's shape), the tiles a row costs ``ceil(context / tile)``."""
+    from areal_tpu.ops.paged_attention import page_tile
+
+    eng = _engine(mode, **kw)
+    for i, plen in enumerate((20, 37, 5)):
+        eng.submit(_req(f"r{i}", [6 + (i + k) % 50 for k in range(plen)], 40))
+    while eng.n_decoding < 3:
+        eng.step()
+    snapshot = [(i, r.epoch) for i, r in enumerate(eng.rows) if r is not None]
+    ctx = [len(eng.rows[i].prompt) + len(eng.rows[i].generated) for i, _ in snapshot]
+    assert len(ctx) == 3 and min(ctx) > 5
+    span = _Span()
+    eng._count_dispatch(span, snapshot, eng.chunk_size)
+    c = span.counts
+    assert c["tile_tokens"] == tile and c["ctx_tokens_sum"] == sum(ctx)
+    if mode == "paged":
+        assert tile == page_tile(eng.k_pool.shape, eng.k_pool.dtype)
+    assert c["tiles_attended"] == sum(-(-n // tile) for n in ctx)
+    assert c["pages_attended"] == sum(-(-n // page) for n in ctx)
+    # what the kernel reads holds what is attended, and no whole page more
+    assert c["ctx_tokens_sum"] <= c["tiles_attended"] * tile
+    assert c["tiles_attended"] * tile <= c["pages_attended"] * page

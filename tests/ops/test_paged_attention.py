@@ -11,19 +11,24 @@ from areal_tpu.ops.paged_attention import (
     PAGE_GROUP,
     gather_paged_kv,
     page_group,
+    page_tile,
     paged_flash_attention,
+    page_fetched,
     plan_pages,
     reference_paged_partials,
-    stream_page_ids,
+    stream_page,
     visit_order,
 )
 
 BS = 128
+#: a page of TWO tiles (the kernel copies and multiplies a row's last page
+#: as far as the last tile that holds a cached position), and its tile
+BS2, TILE = 512, 256
 
 
 def _setup(B=4, Q=1, Hq=8, Hkv=4, MB=4, NB=32, hd=128, seed=0,
            lengths=None, dtype=jnp.bfloat16, q_dtype=jnp.float32,
-           engine_tables=False):
+           engine_tables=False, BS=BS):
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (B, Q, Hq, hd), jnp.float32).astype(q_dtype)
     k_pool = jax.random.normal(
@@ -44,6 +49,18 @@ def _setup(B=4, Q=1, Hq=8, Hkv=4, MB=4, NB=32, hd=128, seed=0,
         held = jnp.arange(MB)[None, :] * BS < lens[:, None]
         tables = jnp.where(held, tables, 0)
     return q, k_pool, v_pool, tables, lens
+
+
+def _kv_heads(page, dtype):
+    """KV heads that make a tile of TILE tokens 256 KiB of a pool of
+    ``dtype``, so that a page of BS2 IS read in two tiles (the rule wants
+    that much a copy); 2 heads on the one-tile page."""
+    return 2 if page == BS else 2048 // TILE // jnp.dtype(dtype).itemsize
+
+
+def _assert_tiled(pool, page):
+    """The kernel copies ``pool``'s pages of BS2 in tiles of TILE."""
+    assert page_tile(pool.shape, pool.dtype) == (TILE if page == BS2 else BS)
 
 
 def _assert_matches_reference(got, want, lens, tol=3e-3):
@@ -72,27 +89,41 @@ RAGGED = {
     "on_a_page_edge": [128, 256, 384, 512, 129, 257, 0, 127],
     "dead_first_and_last": [0, 0, 512, 1, 0, 200, 0, 0],
 }
+#: the same on pages of two tiles: lengths one under, on and one over a
+#: tile's edge, at a page's edge and one past it, dead rows between
+TILED = {
+    "on_a_tile_edge": [255, 256, 257, 0, 511, 512, 513, 1],
+    "pages_and_tiles": [767, 768, 769, 0, 1025, 2048, 1281, 1024],
+}
+#: case -> (tokens a page, lengths)
+CASES = {
+    **{name: (BS, lengths) for name, lengths in RAGGED.items()},
+    **{name: (BS2, lengths) for name, lengths in TILED.items()},
+}
 
 
 @pytest.mark.parametrize("pool", ["bf16", "float32", "int8"])
-@pytest.mark.parametrize("case", sorted(RAGGED))
+@pytest.mark.parametrize("case", sorted(CASES))
 def test_ragged_batch_matches_reference(case, pool):
-    """The copy rule (each valid page once, nothing for dead rows or past
-    a row's blocks) changes no number: engine-written tables (zeros past
-    a row's blocks) over every pool format, bf16 queries as the model
-    hands them (so the bf16 pool takes the bf16-operand dots and the
-    other two the float32 path)."""
-    lengths = RAGGED[case]
+    """The copy rule (each valid page once and as far as it is filled,
+    nothing for dead rows or past a row's blocks) changes no number:
+    engine-written tables (zeros past a row's blocks) over every pool
+    format, bf16 queries as the model hands them (so the bf16 pool takes
+    the bf16-operand dots and the other two the float32 path)."""
+    page, lengths = CASES[case]
+    dtype = {"float32": jnp.float32, "int8": jnp.int8}.get(pool, jnp.bfloat16)
+    Hkv = _kv_heads(page, dtype)
     q, kp, vp, tables, lens = _setup(
-        B=len(lengths), Hq=4, Hkv=2, NB=40, lengths=lengths, seed=21,
+        B=len(lengths), Hq=2 * Hkv, Hkv=Hkv, NB=40, lengths=lengths, seed=21,
         dtype=jnp.float32 if pool == "float32" else jnp.bfloat16,
-        q_dtype=jnp.bfloat16, engine_tables=True,
+        q_dtype=jnp.bfloat16, engine_tables=True, BS=page,
     )
     scales = {}
     if pool == "int8":
         kp, ks = quantize_kv(kp)
         vp, vs = quantize_kv(vp)
         scales = dict(k_scale=ks, v_scale=vs)
+    _assert_tiled(kp, page)
     got = paged_flash_attention(
         q, kp, vp, tables, lens, interpret=True, **scales
     )
@@ -121,28 +152,99 @@ def test_bf16_operand_dots_equal_float32_highest_at_4k():
     assert np.max(np.abs(out - out_f)) <= 1e-5 * np.max(np.abs(out_f))
 
 
-@pytest.mark.parametrize("group", [1, 2, PAGE_GROUP])
-@pytest.mark.parametrize("case", sorted(RAGGED))
-def test_stream_page_ids_change_once_a_valid_page(case, group):
-    """Down each stream's column the forward-filled ids change exactly
-    as many times as the rows hold valid pages (the pipeline copies a
-    tile when its index changes), and every valid page keeps its own
-    id."""
-    lengths = np.asarray(RAGGED[case])
-    B, MB = len(lengths), 4
+def _held_table(lengths, page, MB=4):
+    """Distinct page ids where a row holds a page, zeros past it (as the
+    engine writes tables), and the mask of held pages."""
+    B = len(lengths)
     tables = np.arange(1, B * MB + 1, dtype=np.int32).reshape(B, MB)
-    held = np.arange(MB)[None, :] * BS < lengths[:, None]
-    tables = np.where(held, tables, 0)  # distinct ids, zeros past a row
-    ids = np.asarray(
-        stream_page_ids(jnp.asarray(tables), jnp.asarray(lengths), BS, group)
+    held = np.arange(MB)[None, :] * page < np.asarray(lengths)[:, None]
+    return np.where(held, tables, 0), held
+
+
+def _walk_the_grid(lengths, page, tile, group, query_tiles=1, MB=4):
+    """What the kernel copies over a whole grid, by the functions the
+    kernel itself calls (``stream_page``, ``page_fetched``) on the plan
+    ``plan_pages`` makes: ``{(row in slot order, page id): [tiles copied,
+    one entry a copy]}``."""
+    lengths = np.asarray(lengths)
+    tables, _ = _held_table(lengths, page, MB)
+    plan = plan_pages(jnp.asarray(tables), jnp.asarray(lengths), page, group)
+    lens, ids, order = (np.asarray(x) for x in plan)
+    assert ids.shape == (len(lens), -(-MB // group) * group)
+    held = np.arange(ids.shape[1])[None, :] * page < lens[:, None]
+    np.testing.assert_array_equal(  # every held page under its own id
+        ids[held], tables[order][np.arange(MB)[None, :] * page < lens[:, None]]
     )
-    assert ids.shape == (B, MB)
-    np.testing.assert_array_equal(ids[held], tables[held])
-    cols = ids.reshape(B * MB // group, group)
-    # the copy before the first step fetches whatever the first line
-    # names; it is a useful copy only where that page is valid
-    changes = (cols[1:] != cols[:-1]).sum() + held.reshape(cols.shape)[0].sum()
-    assert changes == held.sum()
+    copied, before = {}, None
+    for b in range(len(lens)):
+        for _qb in range(query_tiles):
+            for j in range(ids.shape[1] // group):
+                here = [
+                    stream_page(lens, ids, b, j, g, group, page, tile)
+                    for g in range(group)
+                ]
+                for this, was in zip(here, before or here):
+                    if page_fetched(this, was, before is None):
+                        pid, n = this
+                        copied.setdefault((order[b], int(pid)), []).append(int(n))
+                before = here
+    return tables, copied
+
+
+@pytest.mark.parametrize("query_tiles", [1, 3])
+@pytest.mark.parametrize("group", [1, 2, PAGE_GROUP])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_every_filled_tile_is_copied_once_and_no_other(case, group, query_tiles):
+    """In visiting order every page a row holds is copied as far as its
+    last tile with a cached position, exactly once while the grid stays
+    on it (once a query tile where a row takes several page steps, as
+    the BlockSpec pipeline did); no tile past a row's length and nothing
+    of a dead row is ever copied: the bytes fetched are ``sum(ceil(len /
+    tile)) x tile`` entries."""
+    page, lengths = CASES[case]
+    tile = page_tile((2, page, 128), jnp.float32)
+    tables, copied = _walk_the_grid(lengths, page, tile, group, query_tiles)
+    visits = 1 if group == PAGE_GROUP else query_tiles  # page steps a row: 1
+    for b, n in enumerate(lengths):
+        for p in range(tables.shape[1]):
+            filled = min(max(n - p * page, 0), page)
+            got = copied.pop((b, int(tables[b, p])), []) if filled else []
+            assert got == [-(-filled // tile)] * visits if filled else not got
+    assert not copied  # no page of a dead row, none past a row's length
+    _, copied = _walk_the_grid(lengths, page, tile, group)
+    assert sum(sum(v) for v in copied.values()) * tile == sum(
+        -(-n // tile) * tile for n in lengths
+    )
+
+
+@pytest.mark.parametrize(
+    "shape,dtype,tile",
+    [
+        ((2, 16, 128), jnp.bfloat16, 16),  # a page of at most 256 tokens
+        ((2, 128, 128), jnp.bfloat16, 128),  # is its own tile
+        ((2, 256, 128), jnp.float32, 256),
+        ((2, 384, 128), jnp.float32, 384),  # no divisor of 256 or more
+        ((2, 512, 128), jnp.float32, 256),  # these tests' two-tile page
+        ((2, 1024, 128), jnp.bfloat16, 512),  # Qwen2.5-1.5B: 256 KiB a tile
+        ((2, 1024, 128), jnp.int8, 1024),
+        ((4, 1024, 128), jnp.bfloat16, 256),  # Qwen2.5-7B
+        ((8, 1024, 128), jnp.bfloat16, 256),  # granite-4.0-h-small
+        ((5, 64, 1, 512, 640), jnp.bfloat16, 256),  # latent pages
+        ((2, 4096, 128), jnp.bfloat16, 1024),  # four tiles at most
+    ],
+)
+def test_tile_is_a_function_of_the_pools_shape(shape, dtype, tile):
+    """A page of at most 256 tokens is its own tile (one copy descriptor
+    a page); a longer one is cut into at most four tiles of at least 256
+    tokens and 256 KiB of one pool, each a whole number of lane tiles,
+    whatever the queries."""
+    from areal_tpu.ops import paged_attention as pa
+
+    Hkv, page, hd = shape[-3:]
+    assert page_tile(shape, dtype) == tile
+    itemsize = jnp.dtype(dtype).itemsize
+    for Q, r in [(1, 4), (256, 4), (1, 64)]:
+        assert pa._plan_tiles(Q, r, Hkv, page, hd, itemsize, False, 4)[2] == tile
 
 
 def test_visit_order_puts_long_rows_first_and_dead_rows_last():
@@ -171,13 +273,17 @@ def test_handed_plan_equals_own_plan():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_stream_page_ids_pad_a_ragged_table_width():
-    # MB not a multiple of the group: the padded steps repeat the last id
-    tables = jnp.asarray([[5, 6, 7], [8, 0, 0]], jnp.int32)
-    ids = np.asarray(
-        stream_page_ids(tables, jnp.asarray([3 * BS, 10]), BS, 2)
-    )
-    np.testing.assert_array_equal(ids, [[5, 6, 7, 6], [8, 6, 8, 6]])
+def test_plan_pads_a_ragged_table_width():
+    # MB not a multiple of the group: the step past a row's table holds
+    # no cached position, whatever id stands there
+    tables = jnp.asarray([[8, 0, 0], [5, 6, 7]], jnp.int32)
+    plan = plan_pages(tables, jnp.asarray([10, 3 * BS]), BS, 2)
+    np.testing.assert_array_equal(plan.order, [1, 0])
+    np.testing.assert_array_equal(plan.lengths, [3 * BS, 10])
+    np.testing.assert_array_equal(plan.page_ids, [[5, 6, 7, 0], [8, 0, 0, 0]])
+    assert [
+        int(stream_page(*plan[:2], 0, 1, g, 2, BS, BS)[1]) for g in range(2)
+    ] == [1, 0]
 
 
 @pytest.mark.parametrize("seed", [0, 4])
@@ -192,12 +298,18 @@ def test_paged_attention_matches_reference(lengths, seed):
     _assert_matches_reference(got, want, lens)
 
 
-def test_paged_attention_multi_query_chunk():
+@pytest.mark.parametrize(
+    "page,lengths", [(BS, [300, 77]), (BS2, [TILE + 1, 3 * TILE])]
+)
+def test_paged_attention_multi_query_chunk(page, lengths):
     # Q=16 queries per row (the chunked-prefill prefix-attention shape):
     # every query sees the same full prefix
+    Hkv = _kv_heads(page, jnp.bfloat16)  # 16 x 2 = 32 query rows a cell
     q, kp, vp, tables, lens = _setup(
-        B=2, Q=16, Hq=4, Hkv=2, MB=3, NB=8, lengths=[300, 77], seed=2
+        B=2, Q=16, Hq=2 * Hkv, Hkv=Hkv, MB=3, NB=8, lengths=lengths, seed=2,
+        BS=page,
     )
+    _assert_tiled(kp, page)
     acc, m, l = paged_flash_attention(q, kp, vp, tables, lens, interpret=True)
     acc_r, m_r, l_r = reference_paged_partials(q, kp, vp, tables, lens)
     out = np.asarray(acc) / np.asarray(l)[..., None]
@@ -226,12 +338,18 @@ def test_paged_matches_dense_flash_decode():
     )
 
 
-@pytest.mark.parametrize("seed,L", [(11, 3), (12, 2)])
-def test_layered_pool_matches_per_layer_slice(seed, L):
+@pytest.mark.parametrize(
+    "seed,L,page,lengths",
+    [(11, 3, BS, [200, 77]), (12, 2, BS, [200, 77]),
+     (13, 2, BS2, [TILE - 1, BS2 + TILE])],
+)
+def test_layered_pool_matches_per_layer_slice(seed, L, page, lengths):
     # the 5-D stacked-pool entry with a layer scalar must equal slicing
     # the layer out and calling the 4-D form
-    q, kp, vp, tables, lens = _setup(B=2, Hq=4, Hkv=2, MB=2, NB=8,
-                                     lengths=[200, 77], seed=seed)
+    Hkv = _kv_heads(page, jnp.bfloat16)
+    q, kp, vp, tables, lens = _setup(B=2, Hq=2 * Hkv, Hkv=Hkv, MB=2, NB=8,
+                                     lengths=lengths, seed=seed, BS=page)
+    _assert_tiled(kp, page)
     kps = jnp.stack([kp + i for i in range(L)])
     vps = jnp.stack([vp - i for i in range(L)])
     for layer in range(L):
@@ -250,17 +368,21 @@ def test_layered_pool_matches_per_layer_slice(seed, L):
         )
 
 
-def test_rows_longer_than_one_grid_step():
-    """Rows of MORE pages than one grid step streams (16 against
-    PAGE_GROUP): the page axis of the grid is longer than 1, a stream
-    walks several pages of one row, and the forward fill runs across
-    rows of 16, 2 and 0 pages."""
-    MB = 4 * PAGE_GROUP
-    lengths = [MB * BS, 2 * BS - 37, 0, MB * BS - 37]
+@pytest.mark.parametrize("page,pages", [(BS, 16), (BS2, 6)])
+def test_rows_longer_than_one_grid_step(page, pages):
+    """Rows of MORE pages than one grid step streams (16, or 6 of two
+    tiles, against PAGE_GROUP): the page axis of the grid is longer than
+    1, a stream walks several pages of one row, its two buffers take
+    turns within a row, and the forward fill runs across rows of all, 2
+    and 0 pages and one that ends a tile into its last page."""
+    MB = pages
+    lengths = [MB * page, 2 * page - 37, 0, MB * page - page // 2 - 37]
+    Hkv = _kv_heads(page, jnp.bfloat16)
     q, kp, vp, tables, lens = _setup(
-        B=4, Hq=4, Hkv=2, MB=MB, NB=4 * MB + 4, lengths=lengths, seed=13,
-        q_dtype=jnp.bfloat16, engine_tables=True,
+        B=4, Hq=2 * Hkv, Hkv=Hkv, MB=MB, NB=4 * MB + 4, lengths=lengths,
+        seed=13, q_dtype=jnp.bfloat16, engine_tables=True, BS=page,
     )
+    _assert_tiled(kp, page)
     got = paged_flash_attention(q, kp, vp, tables, lens, interpret=True)
     want = reference_paged_partials(q, kp, vp, tables, lens)
     _assert_matches_reference(got, want, lens)
@@ -318,9 +440,10 @@ def test_tile_plan_for_eight_kv_heads_of_four_queries(Q):
     the plan keeps within the VMEM budget with whole sublane tiles."""
     from areal_tpu.ops import paged_attention as pa
 
-    G, QT = pa._plan_tiles(Q, 4, 8, 1024, 128, 2, False, 4)
+    G, QT, tile = pa._plan_tiles(Q, 4, 8, 1024, 128, 2, False, 4)
     assert 1 <= G <= pa.PAGE_GROUP and 1 <= QT <= Q
     assert QT == Q or (QT * 4) % 8 == 0
+    assert tile == 256
     assert (
         pa.vmem_bytes_needed(8, 1024, 128, 2, False, G, QT * 4)
         <= pa.VMEM_BUDGET_BYTES
@@ -330,7 +453,8 @@ def test_tile_plan_for_eight_kv_heads_of_four_queries(Q):
 # -- latent pages: one "head", the page is keys AND (first columns) values --
 
 
-def _latent_setup(B, Q, lengths, MB=5, NB=32, H=16, width=256, L=None, seed=0):
+def _latent_setup(B, Q, lengths, MB=5, NB=32, H=16, width=256, L=None, seed=0,
+                  BS=BS):
     """A latent pool ``[NB, 1, BS, width]`` (a token's ``[c_kv | k_rope |
     0]``) and queries ``[B, Q, H, width]``, as the absorbed form makes
     them; the table as the engine writes it."""
@@ -346,14 +470,21 @@ def _latent_setup(B, Q, lengths, MB=5, NB=32, H=16, width=256, L=None, seed=0):
 
 # rows of 1, 2 and 5 pages, a dead row between them and one at the end
 LATENT_ROWS = [BS - 28, 0, 2 * BS, 5 * BS - 3, BS + 1, 0]
+# the same on pages of two tiles: a tile and one more, a page and a tile
+LATENT_ROWS2 = [TILE + 1, 0, BS2 + TILE, 5 * BS2 - 3, TILE, 0]
 
 
+@pytest.mark.parametrize("page,rows", [(BS, LATENT_ROWS), (BS2, LATENT_ROWS2)])
 @pytest.mark.parametrize("Q", [1, 24])
-def test_latent_pages_match_the_plain_partials(Q):
+def test_latent_pages_match_the_plain_partials(Q, page, rows):
     """``paged_mla_decode`` (Q 1) / ``paged_mla_fill``: one fetch of a page
     serves the scores (every column) and the values (the first
     ``value_dim``); the accumulator is ``value_dim`` wide."""
-    q, pool, tables, lens = _latent_setup(6, Q, LATENT_ROWS)
+    # a row of 512 columns makes a tile of TILE tokens 256 KiB
+    q, pool, tables, lens = _latent_setup(
+        6, Q, rows, BS=page, width=256 if page == BS else 512
+    )
+    _assert_tiled(pool, page)
     got = paged_flash_attention(
         q, pool, None, tables, lens, interpret=True, scale=0.1447, value_dim=128
     )

@@ -28,7 +28,11 @@ from areal_tpu.ops import flash_attention as fa
 from areal_tpu.ops import paged_attention as pa
 
 #: (n_q_heads, n_kv_heads) at head_dim 128
-HEADS = {"qwen2.5-1.5b": (12, 2), "qwen2.5-7b": (28, 4)}
+HEADS = {
+    "qwen2.5-1.5b": (12, 2),
+    "qwen2.5-7b": (28, 4),
+    "granite-4.0-h-small": (32, 8),
+}
 HD = 128
 #: the engine's serving shapes: page_size 1024, a 4-block row (4k
 #: context), a layer-stacked pool
@@ -116,15 +120,56 @@ def _assert_kernel(compiled):
         ("qwen2.5-1.5b", 64, 1, False),
         ("qwen2.5-1.5b", 16, 256, True),
         ("qwen2.5-7b", 16, 256, False),
+        # the hybrid cell's one attention layer: 8 kv heads of 4 queries,
+        # a page of 2 MiB, a decode step of 64 rows and a fill of 4 x 256
+        ("granite-4.0-h-small", 64, 1, False),
+        ("granite-4.0-h-small", 4, 256, False),
     ],
 )
 def test_paged_kernel_compiles(one_chip, model, B, Q, quantized):
+    """With the tile the rule picks for a page of 1,024 (a copy
+    descriptor for each number of filled tiles of each stream), under the
+    stated VMEM limit."""
+
     def place(shape, dtype, _spec):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    Hq, Hkv = HEADS[model]
+    tile = pa.page_tile(
+        (Hkv, PAGE, HD), jnp.int8 if quantized else jnp.bfloat16
+    )
+    # the three cells' heads: 256 KiB of one pool a tile
+    assert tile == {(2, False): 512, (4, False): 256, (8, False): 256,
+                    (2, True): 1024, (4, True): 512}[Hkv, quantized]
     args = _paged_args(model, B, Q, quantized, place)
     compiled = jax.jit(_call_kernel).lower(*args).compile()
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("B,Q", [(64, 1), (1, 1024)])
+def test_latent_paged_kernel_compiles(one_chip, B, Q):
+    """The latent cell's kernel alone: one pool of 640-column pages of 512
+    tokens, 64 query heads on the one stream, rows of ten pages (three
+    grid steps, so a stream's two buffers take turns within a row)."""
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = s((5, 640, 1, 512, 640), jnp.bfloat16)
+    assert pa.page_tile(pool.shape, pool.dtype) == 256  # two a page
+
+    def call(q, pool, tables, lengths, layer):
+        return pa.paged_flash_attention(
+            q, pool, None, tables, lengths, layer=layer, scale=0.1447,
+            value_dim=512,
+        )
+
+    compiled = jax.jit(call).lower(
+        s((B, Q, 64, 640), jnp.bfloat16), pool, s((B, 10), jnp.int32),
+        s((B,), jnp.int32), s((1,), jnp.int32),
+    ).compile()
+    _assert_kernel(compiled)
+    assert ("paged_mla_decode" if Q == 1 else "paged_mla_fill") in compiled.as_text()
 
 
 @pytest.mark.parametrize(
@@ -256,9 +301,11 @@ def test_vmem_plan_fits_budget(model, quantized, Q):
     Hq, Hkv = HEADS[model]
     r = Hq // Hkv
     itemsize = 1 if quantized else 2
-    G, QT = pa._plan_tiles(Q, r, Hkv, PAGE, HD, itemsize, quantized, MB)
+    G, QT, tile = pa._plan_tiles(Q, r, Hkv, PAGE, HD, itemsize, quantized, MB)
     assert 1 <= G <= pa.PAGE_GROUP and 1 <= QT <= Q
     assert QT == Q or (QT * r) % 8 == 0
+    assert PAGE % tile == 0 and tile % pa.LANES == 0
+    assert PAGE // tile <= pa.MAX_PAGE_TILES
     need = pa.vmem_bytes_needed(Hkv, PAGE, HD, itemsize, quantized, G, QT * r)
     assert need <= pa.VMEM_BUDGET_BYTES < pa.VMEM_LIMIT_BYTES
 
