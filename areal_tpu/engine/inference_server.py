@@ -3984,9 +3984,6 @@ class ContinuousBatchingEngine:
             tiles_attended=sum(-(-c // tile) for c in ctx),
             tile_tokens=tile,
         )
-        if self._stateful:
-            # state slots the chunk advances, at most: rows x steps
-            counts["state_rows_sum"] = len(snapshot) * chunk_size
         if self.cfg.is_latent:
             # what the latent kernel reads: ONE entry a position and layer
             # whatever the head count (the same floor as ctx_tokens_sum)

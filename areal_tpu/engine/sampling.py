@@ -29,6 +29,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from areal_tpu.observability.tracing import region
+
 
 @dataclasses.dataclass(frozen=True)
 class SamplingParams:
@@ -69,6 +71,7 @@ def _filtered_logits(
     return filtered
 
 
+@region("areal.sample")
 def sample_logits(
     logits: jax.Array,  # [B, V] float32
     rng: jax.Array,
@@ -99,6 +102,7 @@ def sample_logits(
     return tokens.astype(jnp.int32), logp
 
 
+@region("areal.sample")
 def sample_logits_keyed(
     logits: jax.Array,  # [B, V] float32
     base_rng: jax.Array,  # ONE fixed key per engine/run, never split
@@ -194,3 +198,39 @@ def call_sample_fn(sample_fn, logits, rng, positions, row_seeds=None):
     if n == 3:
         return sample_fn(logits, rng, positions)
     return sample_fn(logits, rng)
+
+
+@region("areal.sample")
+def sample_and_advance(
+    sample_fn,
+    stop_fn,
+    logits: jax.Array,  # [B, V] of the step's pending tokens
+    rng: jax.Array,
+    i,  # the step's number in its chunk
+    lengths: jax.Array,  # [B] positions cached before this step
+    active: jax.Array,  # [B] bool
+    budgets: jax.Array,  # [B] tokens a row may still emit
+    out_t: jax.Array,  # [B, W] the chunk's tokens so far
+    out_l: jax.Array,  # [B, W] their log-probabilities
+    emitted: jax.Array,  # [B, W] bool
+    max_len: int,
+    row_seeds=None,
+):
+    """The end of one decode step, the same in every decode program:
+    sample each live row's next token (position-aware samplers get the
+    position it will occupy, ``lengths + 1``), record it in column ``i``
+    of the chunk's outputs, and retire the rows that stopped, ran out of
+    budget or reached ``max_len``.  Returns ``(lengths, tokens, active,
+    budgets, out_t, out_l, emitted, rng)``."""
+    rng, sub = jax.random.split(rng)
+    tok, logp = call_sample_fn(
+        sample_fn, logits.astype(jnp.float32), sub, lengths + 1, row_seeds
+    )
+    tok = jnp.where(active, tok, 0)
+    out_t = out_t.at[:, i].set(tok)
+    out_l = out_l.at[:, i].set(jnp.where(active, logp, 0.0))
+    emitted = emitted.at[:, i].set(active)
+    lengths = lengths + active.astype(jnp.int32)
+    budgets = budgets - active.astype(jnp.int32)
+    active = active & ~stop_fn(tok) & (budgets > 0) & (lengths < max_len)
+    return lengths, tok, active, budgets, out_t, out_l, emitted, rng

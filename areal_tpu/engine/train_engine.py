@@ -41,7 +41,7 @@ from areal_tpu.engine import batching
 from areal_tpu.engine.optimizer import OptimizerConfig, make_optimizer
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import param_pspecs, takes_flash
-from areal_tpu.observability.tracing import phase
+from areal_tpu.observability.tracing import phase, region
 from areal_tpu.ops import flash_attention
 
 logger = logging_.getLogger("train_engine")
@@ -273,8 +273,10 @@ class TrainEngine:
                     def body(carry, mb):
                         g_acc, loss_acc, denom_acc, stats_acc = carry
                         g, ls, dn, st = grad_of(params, mb)
+                        with region("areal.optimizer"):
+                            g_acc = jax.tree.map(jnp.add, g_acc, g)
                         return (
-                            jax.tree.map(jnp.add, g_acc, g),
+                            g_acc,
                             loss_acc + ls,
                             denom_acc + dn,
                             jax.tree.map(jnp.add, stats_acc, st),
@@ -284,13 +286,16 @@ class TrainEngine:
                     (grads, loss_sum, denom, stats), _ = jax.lax.scan(
                         body, carry, rest
                     )
-                grads = jax.tree.map(
-                    lambda g: g / jnp.maximum(denom, 1e-8).astype(g.dtype),
-                    grads,
-                )
-                gnorm = optax.global_norm(grads)
-                updates, opt_state = self.tx.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                with region("areal.optimizer"):
+                    grads = jax.tree.map(
+                        lambda g: g / jnp.maximum(denom, 1e-8).astype(g.dtype),
+                        grads,
+                    )
+                    gnorm = optax.global_norm(grads)
+                    updates, opt_state = self.tx.update(
+                        grads, opt_state, params
+                    )
+                    params = optax.apply_updates(params, updates)
                 out = {
                     "stats": stats,
                     "loss_sum": loss_sum,

@@ -30,7 +30,7 @@ from areal_tpu.base import logging_, stats_tracker
 from areal_tpu.engine import batching
 from areal_tpu.interfaces import ppo_functional
 from areal_tpu.models.transformer import head_weight, hidden_states
-from areal_tpu.observability.tracing import phase
+from areal_tpu.observability.tracing import phase, region
 from areal_tpu.ops.gae import gae_advantages_returns
 from areal_tpu.ops.loss import per_token_logprobs_entropy
 
@@ -367,6 +367,12 @@ def _actor_loss(params, cfg, batch, iface: PPOActorInterface):
         batch["seg_ids"],
         with_aux=True,
     )
+    return _actor_loss_of_hidden(params, cfg, batch, iface, hidden, moe_aux)
+
+
+@region("areal.loss")
+def _actor_loss_of_hidden(params, cfg, batch, iface, hidden, moe_aux):
+    """The head product and the PPO loss over final-norm hidden states."""
     B, T, D = hidden.shape
     w = head_weight(params, cfg).astype(hidden.dtype) / iface.temperature
     new_logp, entropy = per_token_logprobs_entropy(

@@ -72,7 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from areal_tpu.engine.sampling import call_sample_fn
+from areal_tpu.engine.sampling import sample_and_advance
 from areal_tpu.models import paged
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models import quantize
@@ -82,11 +82,15 @@ from areal_tpu.models.transformer import (
     _activation,
     _attn_qkv,
     _embed,
+    _final_norm,
     _norm,
     _proj,
     make_attention_mask,
     rope_apply,
+    scan_layers,
+    window_put,
 )
+from areal_tpu.observability.tracing import region
 from areal_tpu.ops import ssm as ssm_ops
 
 F32 = jnp.float32
@@ -127,6 +131,13 @@ def _run_indices(run: Run):
         jnp.arange(first, first + run.count)
         for first in (run.first_layer, run.first_of_kind, run.first_of_mlp)
     )
+
+
+def _mixer_region(run: Run):
+    """The region of a layer's first half (norm, mixer, residual add)."""
+    if run.kind == "mamba":
+        return region("areal.ssm")
+    return region("areal.attn")
 
 
 def _at(tree, i):
@@ -319,6 +330,7 @@ def state_layout_bytes(cfg: TransformerConfig, slots: int) -> int:
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
+@region("areal.ssm")
 def copy_state_slots(ssm, conv, src: jax.Array, dst: jax.Array):
     """Copy slot ``src[i]`` to slot ``dst[i]`` (every Mamba layer's state
     and conv tail) for each ``i`` with ``dst[i] < slots``: a fill's
@@ -562,6 +574,7 @@ def rope_inv_freq(cfg: TransformerConfig, dim: int) -> np.ndarray:
     return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
 
 
+@region("areal.attn")
 def latent_rope_tables(cfg: TransformerConfig, positions):
     """``(cos, sin)`` [B, T, 1, rope/2] float32 for the rope parts of a
     latent layer's queries and key, times YaRN's ``mscale /
@@ -654,6 +667,7 @@ def latent_values_out(cfg: TransformerConfig, ap: Params, o_lat, dtype=None):
 # ---------------------------------------------------------------------------
 
 
+@region("areal.mlp")
 def _mlp_half(cfg: TransformerConfig, params: Params, run: Run, l, e, x, valid):
     """The second half of layer ``l`` (number ``e`` among its MLP kind);
     returns ``(x, pairs, routed [B, T, K])``, the last two None after a
@@ -671,6 +685,7 @@ def _mlp_half(cfg: TransformerConfig, params: Params, run: Run, l, e, x, valid):
     return _res(cfg, x, out), pairs, routed
 
 
+@region("areal.head")
 def _head_logits(params: Params, cfg: TransformerConfig, x):
     """Logits of final-norm hidden states ``x``: the head's products
     come OUT in float32.  A ``bfloat16 @ bfloat16`` product comes out in
@@ -692,7 +707,7 @@ def _head_logits(params: Params, cfg: TransformerConfig, x):
 
 
 def _logits(params: Params, cfg: TransformerConfig, x):
-    return _head_logits(params, cfg, _norm(x, params["final_norm"], cfg))
+    return _head_logits(params, cfg, _final_norm(params, cfg, x))
 
 
 def _pairs_zero(cfg: TransformerConfig):
@@ -760,12 +775,13 @@ def hidden_states(
 
         def body(x, idx, run=run):
             l, j, e = idx
-            x = _res(cfg, x, mixer(run, x, l, j))
+            with _mixer_region(run):
+                x = _res(cfg, x, mixer(run, x, l, j))
             x, _, _ = _mlp_half(cfg, params, run, l, e, x, valid)
             return x, None
 
-        x, _ = jax.lax.scan(body, x, _run_indices(run))
-    return _norm(x, params["final_norm"], cfg)
+        x, _ = scan_layers(body, x, _run_indices(run))
+    return _final_norm(params, cfg, x)
 
 
 def forward(params: Params, cfg: TransformerConfig, tokens, positions, seg_ids):
@@ -819,6 +835,7 @@ def _put_state_rows(ssm, j, slots, rows, keep):
     return jax.lax.fori_loop(0, slots.shape[0], put, ssm)
 
 
+@region("areal.ssm")
 def _get_conv_tails(conv, slots):
     """``conv[:, :, slots]`` as ``[Lm, F, K-1, conv_dim]``: every Mamba
     layer's tail of each filling row, one ``dynamic_slice`` a row."""
@@ -830,6 +847,7 @@ def _get_conv_tails(conv, slots):
     return jnp.concatenate(rows, axis=2).swapaxes(1, 2)
 
 
+@region("areal.ssm")
 def _put_conv_tails(conv, slots, tails, keep):
     """``conv[:, :, slots[i]] = tails[:, i]`` for each ``i`` with
     ``keep[i]``, one row after the other (F is 1, 2 or 4: written out,
@@ -900,9 +918,10 @@ def hybrid_fill_chunk(
     latent = cfg.is_latent
     rope_cs = latent_rope_tables(cfg, positions) if latent else None
     if cfg.n_mamba_layers:
-        tails0 = jnp.where(
-            fresh[None, :, None, None], 0, _get_conv_tails(conv, slots)
-        )  # [Lm, F, K-1, conv_dim]
+        with region("areal.ssm"):
+            tails0 = jnp.where(
+                fresh[None, :, None, None], 0, _get_conv_tails(conv, slots)
+            )  # [Lm, F, K-1, conv_dim]
 
     def mamba_mixer(x, ssm, l, j, tail0):
         mp = _at(params["mamba"], j)
@@ -961,22 +980,22 @@ def hybrid_fill_chunk(
         def body(carry, inp, run=run):
             x, ssm, pairs = carry
             l, j, e = inp[:3]
-            if run.kind == "mamba":
-                out, ssm, kept = mamba_mixer(x, ssm, l, j, inp[3])
-            elif run.kind == "attention":
-                out, kept = attn_mixer(x, l, j)
-            else:
-                out, kept = latent_mixer(x, l, j)
-            x, p, r = _mlp_half(
-                cfg, params, run, l, e, _res(cfg, x, out), valid
-            )
+            with _mixer_region(run):
+                if run.kind == "mamba":
+                    out, ssm, kept = mamba_mixer(x, ssm, l, j, inp[3])
+                elif run.kind == "attention":
+                    out, kept = attn_mixer(x, l, j)
+                else:
+                    out, kept = latent_mixer(x, l, j)
+                x = _res(cfg, x, out)
+            x, p, r = _mlp_half(cfg, params, run, l, e, x, valid)
             return (x, ssm, _add_pairs(pairs, p)), (kept, r)
 
         xs = (l_idx, j_idx, e_idx)
         if run.kind == "mamba":
             of_kind = slice(run.first_of_kind, run.first_of_kind + run.count)
             xs += (tails0[of_kind],)
-        carry, (kept, r) = jax.lax.scan(body, carry, xs)
+        carry, (kept, r) = scan_layers(body, carry, xs)
         if run.kind == "mamba":
             tails1.append(kept)
         else:
@@ -998,9 +1017,7 @@ def hybrid_fill_chunk(
         x, pools, vals = jax.lax.optimization_barrier((x, pools, vals))
         pools = paged.write_kv_runs(pools, vals, tables, starts, chunk_lens)
         k_pool, v_pool = pools + ((v_pool,) if latent else ())
-    last_idx = jnp.maximum(chunk_lens - 1, 0)
-    x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
-    logits = _logits(params, cfg, x_last)[:, 0]
+    logits = _logits(params, cfg, paged.last_valid(x, chunk_lens))[:, 0]
     return (
         logits, k_pool, v_pool, ssm, conv, pairs,
         jnp.concatenate(routed, axis=0),
@@ -1069,16 +1086,11 @@ def hybrid_decode_chunk(
         live = active[:, None]
         rope_cs = latent_rope_tables(cfg, positions) if latent else None
 
-        def put(w, new, j):
-            return jax.lax.dynamic_update_slice(
-                w, new.swapaxes(0, 1)[None].astype(w.dtype), (j, i, 0, 0, 0)
-            )
-
         def attn_mixer(x, wk, wv, l, j):
             ap = _at(params["attn"], j)
             h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
             q, k, v = _attn_qkv(cfg, {"attn": ap}, h, positions, None)
-            wk, wv = put(wk, k, j), put(wv, v, j)
+            wk, wv = window_put(wk, k, j, i), window_put(wv, v, j, i)
             prefix = paged._prefix_partials(
                 q, k_pool, v_pool, tables, read_lens, j, use_kernel,
                 plan=plan, scale=scale,
@@ -1096,7 +1108,9 @@ def hybrid_decode_chunk(
             h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
             q_nope, q_rope = latent_q(cfg, ap, h, rope_cs)
             c_kv, k_rope = latent_kv(cfg, ap, h, rope_cs)
-            wk = put(wk, latent_entry(cfg, c_kv, k_rope)[:, :, None, :], j)
+            wk = window_put(
+                wk, latent_entry(cfg, c_kv, k_rope)[:, :, None, :], j, i
+            )
             q = latent_absorbed_q(cfg, ap, q_nope, q_rope)
             prefix = paged._prefix_partials(
                 q, k_pool, None, tables, read_lens, j, use_kernel,
@@ -1116,43 +1130,39 @@ def hybrid_decode_chunk(
             def body(carry, idx, run=run):
                 x, wk, wv, ssm, conv, pairs = carry
                 l, j, e = idx
-                if run.kind == "mamba":
-                    out, ssm, conv = mamba_step(
-                        cfg, _at(params["mamba"], j),
-                        _norm(x, _at(params["layers"]["attn_norm"], l), cfg),
-                        ssm, conv, j, active, use_kernel,
-                    )
-                elif run.kind == "attention":
-                    out, wk, wv = attn_mixer(x, wk, wv, l, j)
-                else:
-                    out, wk = latent_mixer(x, wk, l, j)
-                x, p, r = _mlp_half(
-                    cfg, params, run, l, e, _res(cfg, x, out), live
-                )
+                with _mixer_region(run):
+                    if run.kind == "mamba":
+                        out, ssm, conv = mamba_step(
+                            cfg, _at(params["mamba"], j),
+                            _norm(
+                                x, _at(params["layers"]["attn_norm"], l), cfg
+                            ),
+                            ssm, conv, j, active, use_kernel,
+                        )
+                    elif run.kind == "attention":
+                        out, wk, wv = attn_mixer(x, wk, wv, l, j)
+                    else:
+                        out, wk = latent_mixer(x, wk, l, j)
+                    x = _res(cfg, x, out)
+                x, p, r = _mlp_half(cfg, params, run, l, e, x, live)
                 return (x, wk, wv, ssm, conv, _add_pairs(pairs, p)), (
                     None if r is None else r[:, 0].T
                 )
 
-            carry, r = jax.lax.scan(body, carry, _run_indices(run))
+            carry, r = scan_layers(body, carry, _run_indices(run))
             if r is not None:
                 step_routed.append(r)  # [run.count, K, B]
         x, wk, wv, ssm, conv, pairs = carry
-        routed = jax.lax.dynamic_update_slice(
-            routed, jnp.concatenate(step_routed, axis=0)[None], (i, 0, 0, 0)
-        )
+        with region("areal.moe.route"):
+            routed = jax.lax.dynamic_update_slice(
+                routed, jnp.concatenate(step_routed, axis=0)[None],
+                (i, 0, 0, 0),
+            )
         logits = _logits(params, cfg, x)[:, 0]
-        rng, sub = jax.random.split(rng)
-        tok, logp = call_sample_fn(
-            sample_fn, logits.astype(F32), sub, lengths_ + 1, row_seeds
-        )
-        tok = jnp.where(active, tok, 0)
-        out_t = out_t.at[:, i].set(tok)
-        out_l = out_l.at[:, i].set(jnp.where(active, logp, 0.0))
-        emitted = emitted.at[:, i].set(active)
-        new_lengths = lengths_ + active.astype(jnp.int32)
-        budgets = budgets - active.astype(jnp.int32)
-        active = (
-            active & ~stop_fn(tok) & (budgets > 0) & (new_lengths < max_len)
+        (new_lengths, tok, active, budgets, out_t, out_l, emitted,
+         rng) = sample_and_advance(
+            sample_fn, stop_fn, logits, rng, i, lengths_, active, budgets,
+            out_t, out_l, emitted, max_len, row_seeds,
         )
         return (new_lengths, tok, active, budgets, wk, wv, wvalid, ssm, conv,
                 out_t, out_l, emitted, rng, pairs, routed)

@@ -38,6 +38,7 @@ import numpy as np
 
 from areal_tpu.models import quantize
 from areal_tpu.models.config import TransformerConfig
+from areal_tpu.observability.tracing import region
 
 
 def init_moe_params(cfg: TransformerConfig, key: jax.Array) -> Dict[str, Any]:
@@ -174,11 +175,19 @@ def moe_mlp(
     weight shards; None (training) leaves sharding to XLA's partitioner
     over the pspecs."""
     B, T, D = h.shape
-    E, K = cfg.n_experts, cfg.n_experts_per_tok
     x = h.reshape(-1, D)
-    N = x.shape[0]
+    topk_probs, topk_idx, aux = _route_with_losses(cfg, x, p["router"], valid)
+    out = _routed_experts(cfg, h.dtype, x, topk_probs, topk_idx, p, mesh)
+    return out.reshape(B, T, D), aux
 
-    router_logits = (x.astype(jnp.float32)) @ p["router"]["w"].astype(
+
+@region("areal.moe.route")
+def _route_with_losses(cfg: TransformerConfig, x, router, valid):
+    """``(weights [N, K], expert ids [N, K], the router's losses)`` of the
+    trainer's softmax top-k router over the tokens ``x`` [N, D]."""
+    E, K = cfg.n_experts, cfg.n_experts_per_tok
+    N = x.shape[0]
+    router_logits = (x.astype(jnp.float32)) @ router["w"].astype(
         jnp.float32
     )  # [N, E]
     probs = jax.nn.softmax(router_logits, axis=-1)
@@ -203,7 +212,18 @@ def moe_mlp(
     z_loss = cfg.moe_z_loss_coef * jnp.sum(
         jax.nn.logsumexp(router_logits, axis=-1) ** 2 * vmask
     ) / n_valid
+    return (
+        topk_probs, topk_idx,
+        {"moe_aux_loss": aux_loss, "moe_z_loss": z_loss},
+    )
 
+
+@region("areal.moe.experts")
+def _routed_experts(cfg, dtype, x, topk_probs, topk_idx, p, mesh):
+    """``sum_k weight * expert(x)`` [N, D] over each token's routed
+    experts."""
+    E, K = cfg.n_experts, cfg.n_experts_per_tok
+    N, D = x.shape
     # leaf_weight serves both formats: plain arrays and the int8 serving
     # format's {"qw", "scale"} leaves.  Dequant happens at use, OUTSIDE
     # the EP shard_map: the qw/scale leaves are sharded over the same
@@ -211,11 +231,11 @@ def moe_mlp(
     # partitioner dequantizes each shard's resident [E/ep, ...] slice
     # locally and the shard_map's in_specs see the layout they expect —
     # no gather, and per-chip residency stays E/ep at int8 bytes.
-    gate_w = quantize.leaf_weight(p["experts"]["gate"], h.dtype)
-    up_w = quantize.leaf_weight(p["experts"]["up"], h.dtype)
-    down_w = quantize.leaf_weight(p["experts"]["down"], h.dtype)
+    gate_w = quantize.leaf_weight(p["experts"]["gate"], dtype)
+    up_w = quantize.leaf_weight(p["experts"]["up"], dtype)
+    down_w = quantize.leaf_weight(p["experts"]["down"], dtype)
 
-    xd = x.astype(h.dtype)
+    xd = x.astype(dtype)
     if ep_axis_size(mesh) > 1:
         # serving EP: explicit shard_map over the expert axis (already in
         # canonical (token, k) order — no global unsort needed)
@@ -242,9 +262,7 @@ def moe_mlp(
         )  # [N*K, D]
         # combine: unsort, weight, sum over K
         expert_out = expert_out[inv_order].reshape(N, K, D)
-    out = jnp.sum(expert_out * topk_probs[..., None].astype(h.dtype), axis=1)
-    aux = {"moe_aux_loss": aux_loss, "moe_z_loss": z_loss}
-    return out.reshape(B, T, D), aux
+    return jnp.sum(expert_out * topk_probs[..., None].astype(dtype), axis=1)
 
 
 #: tokens of one :func:`dense_expert_compute` call; a longer input goes
@@ -253,6 +271,7 @@ def moe_mlp(
 DENSE_EXPERTS_CALL_TOKENS = 1024
 
 
+@region("areal.moe.experts")
 def dense_expert_compute(x, w_tok, gate_w, up_w, down_w, act_kind: str):
     """``sum_e w_tok[n, e] * expert_e(x[n])`` over the held experts with
     every expert computed for every token: ``x`` [N, D], ``w_tok`` [N,
@@ -292,6 +311,7 @@ def group_limited_choice(cfg: TransformerConfig, choice: jax.Array):
     return idx, chosen
 
 
+@region("areal.moe.route")
 def route(cfg: TransformerConfig, x: jax.Array, router: Dict[str, Any]):
     """``(weights [N, K] f32, expert ids [N, K], logits [N, E] f32,
     chosen groups [N, G] bool or None)`` of the tokens ``x`` [N, D], by
@@ -349,42 +369,60 @@ def held_moe_mlp(
     x = h.reshape(-1, D)
     w, idx, _, groups = route(cfg, x, p["router"])
     first, held = cfg.moe_first_expert, cfg.n_held_experts
-    ex = p["experts"]
-    gate_w = quantize.leaf_weight(ex["gate"], h.dtype)
-    up_w = quantize.leaf_weight(ex["up"], h.dtype)
-    down_w = quantize.leaf_weight(ex["down"], h.dtype)
     local = idx - first
-    is_held = (local >= 0) & (local < held)
-    # each token's weight for each held expert, 0 where not routed
-    w_tok = jnp.sum(
-        jnp.where(
-            local[:, :, None] == jnp.arange(held)[None, None, :],
-            w[:, :, None], 0.0,
-        ),
-        axis=1,
-    )
-    N, C = x.shape[0], DENSE_EXPERTS_CALL_TOKENS
-    if N <= C:
-        out = dense_expert_compute(
-            x, w_tok, gate_w, up_w, down_w, cfg.activation
+    with region("areal.moe.route"):
+        # each token's weight for each held expert, 0 where not routed
+        w_tok = jnp.sum(
+            jnp.where(
+                local[:, :, None] == jnp.arange(held)[None, None, :],
+                w[:, :, None], 0.0,
+            ),
+            axis=1,
         )
-    else:
-        pad = (-N) % C
-        out = jax.lax.map(
-            lambda xw: dense_expert_compute(
-                *xw, gate_w, up_w, down_w, cfg.activation
-            ),
-            (
-                jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, C, D),
-                jnp.pad(w_tok, ((0, pad), (0, 0))).reshape(-1, C, held),
-            ),
-        ).reshape(-1, D)[:N]
+    with region("areal.moe.experts"):
+        ex = p["experts"]
+        gate_w = quantize.leaf_weight(ex["gate"], h.dtype)
+        up_w = quantize.leaf_weight(ex["up"], h.dtype)
+        down_w = quantize.leaf_weight(ex["down"], h.dtype)
+        N, C = x.shape[0], DENSE_EXPERTS_CALL_TOKENS
+        if N <= C:
+            out = dense_expert_compute(
+                x, w_tok, gate_w, up_w, down_w, cfg.activation
+            )
+        else:
+            pad = (-N) % C
+            out = jax.lax.map(
+                lambda xw: dense_expert_compute(
+                    *xw, gate_w, up_w, down_w, cfg.activation
+                ),
+                (
+                    jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, C, D),
+                    jnp.pad(w_tok, ((0, pad), (0, 0))).reshape(-1, C, held),
+                ),
+            ).reshape(-1, D)[:N]
     if "shared" in p:
-        sh = p["shared"]
-        g = x @ quantize.leaf_weight(sh["gate"], h.dtype)
-        u = x @ quantize.leaf_weight(sh["up"], h.dtype)
-        act = jax.nn.silu(g) if cfg.activation == "silu" else jax.nn.gelu(g)
-        out = out + (act * u) @ quantize.leaf_weight(sh["down"], h.dtype)
+        with region("areal.moe.shared"):
+            sh = p["shared"]
+            g = x @ quantize.leaf_weight(sh["gate"], h.dtype)
+            u = x @ quantize.leaf_weight(sh["up"], h.dtype)
+            act = (
+                jax.nn.silu(g) if cfg.activation == "silu" else jax.nn.gelu(g)
+            )
+            out = out + (act * u) @ quantize.leaf_weight(sh["down"], h.dtype)
+    return (
+        out.reshape(B, T, D),
+        _held_pairs(cfg, local, groups, valid),
+        idx.reshape(B, T, -1).astype(jnp.int32),
+    )
+
+
+@region("areal.moe.route")
+def _held_pairs(cfg: TransformerConfig, local, groups, valid):
+    """:func:`held_moe_mlp`'s ``pairs`` from the routed experts' numbers
+    among the held ones (``local`` [N, K]: outside ``[0, held)`` where
+    held elsewhere) and the router's chosen groups."""
+    first, held = cfg.moe_first_expert, cfg.n_held_experts
+    is_held = (local >= 0) & (local < held)
     slot = jnp.where(is_held, local, held)  # [N, K]
     if valid is not None:
         slot = jnp.where(valid.reshape(-1)[:, None], slot, held + 1)
@@ -397,7 +435,4 @@ def held_moe_mlp(
         if valid is not None:
             hit = hit & valid.reshape(-1)[:, None]
         pairs = jnp.concatenate([pairs, jnp.sum(hit, dtype=pairs.dtype)[None]])
-    return (
-        out.reshape(B, T, D), pairs.astype(jnp.int32),
-        idx.reshape(B, T, -1).astype(jnp.int32),
-    )
+    return pairs.astype(jnp.int32)

@@ -58,18 +58,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from areal_tpu.engine.sampling import call_sample_fn
+from areal_tpu.engine.sampling import sample_and_advance
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import (
     Params,
-    _attn_qkv,
+    _attn_half,
     _embed,
     _head,
-    _mlp_block,
-    _norm,
-    _proj,
+    _mlp_half,
     rope_tables,
+    scan_layers,
+    window_put,
 )
+from areal_tpu.observability.tracing import region
 from areal_tpu.ops.paged_attention import (
     page_group,
     page_tile,
@@ -345,6 +346,7 @@ def _prefix_partials(
     )
 
 
+@region("areal.kv_write")
 def write_kv_runs(
     pools: Sequence[jax.Array],  # each [L, NB, Hkv, BS, ...]
     values: Sequence[jax.Array],  # one per pool, [L, R, T, Hkv, ...]
@@ -562,29 +564,30 @@ def paged_window_forward(
 
     def body(x, xs):
         lp, l = xs
-        h = _norm(x, lp["attn_norm"], cfg)
-        q, k, v = _attn_qkv(cfg, lp, h, positions, rope_cs)
-        acc_p, m_p, l_p = _prefix_partials(
-            q, k_pool, v_pool, tables, read_lens, l, use_kernel,
-            mesh=mesh, kv_axis=kv_axis, k_scale=k_scale, v_scale=v_scale,
-            plan=plan,
-        )
-        attn = chunk_attention(
-            q, k, v, (acc_p, m_p, l_p), mask_chunk, scale, x.dtype
-        )
-        x = x + _proj(lp["attn"]["o"], attn)
-        h2 = _norm(x, lp["mlp_norm"], cfg)
-        mlp_out, _ = _mlp_block(cfg, lp, h2, seg_ids=seg_ids, mesh=mesh)
-        x = x + mlp_out
-        # this layer's window KV, as the pool stores it: [F, C, Hkv, hd]
-        # (and [F, C, Hkv] scales)
-        if k_scale is not None:
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            return x, (kq, vq, ks, vs)
-        return x, (k.astype(k_pool.dtype), v.astype(v_pool.dtype))
 
-    x, window_kv = jax.lax.scan(
+        def attend(q, k, v):
+            prefix = _prefix_partials(
+                q, k_pool, v_pool, tables, read_lens, l, use_kernel,
+                mesh=mesh, kv_axis=kv_axis, k_scale=k_scale,
+                v_scale=v_scale, plan=plan,
+            )
+            attn = chunk_attention(
+                q, k, v, prefix, mask_chunk, scale, x.dtype
+            )
+            # this layer's window KV, as the pool stores it: [F, C, Hkv,
+            # hd] (and [F, C, Hkv] scales)
+            with region("areal.kv_write"):
+                if k_scale is not None:
+                    kq, ks = quantize_kv(k)
+                    vq, vs = quantize_kv(v)
+                    return attn, (kq, vq, ks, vs)
+                return attn, (k.astype(k_pool.dtype), v.astype(v_pool.dtype))
+
+        x, kept = _attn_half(cfg, lp, x, positions, rope_cs, attend)
+        x, _ = _mlp_half(cfg, lp, x, seg_ids=seg_ids, mesh=mesh)
+        return x, kept
+
+    x, window_kv = scan_layers(
         body, x, (params["layers"], jnp.arange(L))
     )
     pools = (k_pool, v_pool)
@@ -595,6 +598,13 @@ def paged_window_forward(
         jnp.sum(valid, axis=1, dtype=jnp.int32),
     )
     return (x, *pools) if k_scale is not None else (x, *pools, None, None)
+
+
+@region("areal.head")
+def last_valid(x, chunk_lens):
+    """``x`` [F, C, D] at each row's last valid position: [F, 1, D]."""
+    last_idx = jnp.maximum(chunk_lens - 1, 0)
+    return jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
 
 
 @partial(
@@ -639,9 +649,7 @@ def paged_fill_chunk(
         use_kernel=use_kernel, mesh=mesh, kv_axis=kv_axis,
         k_scale=k_scale, v_scale=v_scale,
     )
-    last_idx = jnp.maximum(chunk_lens - 1, 0)
-    x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
-    logits = _head(params, cfg, x_last)[:, 0]  # [F, V]
+    logits = _head(params, cfg, last_valid(x, chunk_lens))[:, 0]  # [F, V]
     if k_scale is None:
         return logits, k_pool, v_pool
     return logits, k_pool, v_pool, k_scale, v_scale
@@ -735,47 +743,35 @@ def paged_decode_chunk(
         def body(carry, xs):
             x, wk, wv = carry
             lp, l = xs
-            h = _norm(x, lp["attn_norm"], cfg)
-            q, k, v = _attn_qkv(cfg, lp, h, positions, rope_cs)
-            wk = jax.lax.dynamic_update_slice(
-                wk, k.swapaxes(0, 1)[None].astype(wk.dtype), (l, i, 0, 0, 0)
-            )
-            wv = jax.lax.dynamic_update_slice(
-                wv, v.swapaxes(0, 1)[None].astype(wv.dtype), (l, i, 0, 0, 0)
-            )
-            wk_l = jax.lax.dynamic_index_in_dim(wk, l, 0, keepdims=False)
-            wv_l = jax.lax.dynamic_index_in_dim(wv, l, 0, keepdims=False)
-            prefix = _prefix_partials(
-                q, k_pool, v_pool, tables, read_lens, l, use_kernel,
-                mesh=mesh, kv_axis=kv_axis,
-                k_scale=k_scale, v_scale=v_scale, plan=plan,
-            )
-            attn = window_attention(
-                q, wk_l, wv_l, prefix, mask_win, scale, x.dtype
-            )
-            x = x + _proj(lp["attn"]["o"], attn)
-            h2 = _norm(x, lp["mlp_norm"], cfg)
-            mlp_out, _ = _mlp_block(cfg, lp, h2, mesh=mesh)
-            x = x + mlp_out
+
+            def attend(q, k, v):
+                win = window_put(wk, k, l, i), window_put(wv, v, l, i)
+                wk_l, wv_l = (
+                    jax.lax.dynamic_index_in_dim(w, l, 0, keepdims=False)
+                    for w in win
+                )
+                prefix = _prefix_partials(
+                    q, k_pool, v_pool, tables, read_lens, l, use_kernel,
+                    mesh=mesh, kv_axis=kv_axis,
+                    k_scale=k_scale, v_scale=v_scale, plan=plan,
+                )
+                attn = window_attention(
+                    q, wk_l, wv_l, prefix, mask_win, scale, x.dtype
+                )
+                return attn, win
+
+            x, (wk, wv) = _attn_half(cfg, lp, x, positions, rope_cs, attend)
+            x, _ = _mlp_half(cfg, lp, x, mesh=mesh)
             return (x, wk, wv), None
 
-        (x, wk, wv), _ = jax.lax.scan(
+        (x, wk, wv), _ = scan_layers(
             body, (x, wk, wv), (params["layers"], jnp.arange(L))
         )
         logits = _head(params, cfg, x)[:, 0]
-        rng, sub = jax.random.split(rng)
-        tok, logp = call_sample_fn(
-            sample_fn, logits.astype(jnp.float32), sub, lengths_ + 1,
-            row_seeds,
-        )
-        tok = jnp.where(active, tok, 0)
-        out_t = out_t.at[:, i].set(tok)
-        out_l = out_l.at[:, i].set(jnp.where(active, logp, 0.0))
-        emitted = emitted.at[:, i].set(active)
-        new_lengths = lengths_ + active.astype(jnp.int32)
-        budgets = budgets - active.astype(jnp.int32)
-        active = (
-            active & ~stop_fn(tok) & (budgets > 0) & (new_lengths < max_len)
+        (new_lengths, tok, active, budgets, out_t, out_l, emitted,
+         rng) = sample_and_advance(
+            sample_fn, stop_fn, logits, rng, i, lengths_, active, budgets,
+            out_t, out_l, emitted, max_len, row_seeds,
         )
         return (new_lengths, tok, active, budgets, wk, wv, wvalid, out_t,
                 out_l, emitted, rng)
@@ -794,7 +790,8 @@ def paged_decode_chunk(
     pools = (k_pool, v_pool)
     vals = (wk.swapaxes(1, 2), wv.swapaxes(1, 2))  # [L, B, W, Hkv, hd]
     if k_scale is not None:
-        (kq, ks), (vq, vs) = quantize_kv(vals[0]), quantize_kv(vals[1])
+        with region("areal.kv_write"):
+            (kq, ks), (vq, vs) = quantize_kv(vals[0]), quantize_kv(vals[1])
         pools, vals = pools + (k_scale, v_scale), (kq, vq, ks, vs)
     k_pool, v_pool, *scales = write_kv_runs(
         pools, vals, tables, base_lens, lengths_ - base_lens
@@ -1010,6 +1007,7 @@ def _restore_padded(
 @partial(
     jax.jit, donate_argnums=(0, 1), donate_argnames=("k_scale", "v_scale")
 )
+@region("areal.kv_write")
 def copy_blocks(
     k_pool: jax.Array,
     v_pool: jax.Array,

@@ -39,9 +39,10 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from areal_tpu.base import logging_
-from areal_tpu.engine.sampling import call_sample_fn
+from areal_tpu.engine.sampling import sample_and_advance
 from areal_tpu.models import quantize
 from areal_tpu.models.config import TransformerConfig
+from areal_tpu.observability.tracing import region
 
 logger = logging_.getLogger("transformer")
 
@@ -265,6 +266,7 @@ def _head_norm(x, scale, eps):
     return (x * scale.astype(jnp.float32)).astype(dt)
 
 
+@region("areal.attn")
 def rope_tables(
     positions: jax.Array, base: float, head_dim: int
 ) -> Tuple[jax.Array, jax.Array]:
@@ -299,6 +301,7 @@ def _activation(x, kind: str):
     return jax.nn.gelu(x)
 
 
+@region("areal.attn")
 def make_attention_mask(
     seg_q: jax.Array,
     pos_q: jax.Array,
@@ -590,6 +593,50 @@ def _attn_qkv(cfg: TransformerConfig, lp: Params, h, positions, rope_cs):
     return q, k, v
 
 
+@region("areal.attn")
+def _attn_half(cfg: TransformerConfig, lp: Params, x, positions, rope_cs,
+               attend):
+    """A layer's first half, in ONE region for every program that runs
+    it: the norm, q/k/v, the program's own ``attend(q, k, v) -> (attn [B,
+    T, Hq * hd], kept)`` over whatever it caches (``kept``: what it
+    carries on), the output projection and the residual add.  Returns
+    ``(x, kept)``."""
+    h = _norm(x, lp["attn_norm"], cfg)
+    q, k, v = _attn_qkv(cfg, lp, h, positions, rope_cs)
+    attn, kept = attend(q, k, v)
+    return x + _proj(lp["attn"]["o"], attn), kept
+
+
+@region("areal.mlp")
+def _mlp_half(cfg: TransformerConfig, lp: Params, x, seg_ids=None, mesh=None):
+    """A layer's second half, in one region likewise: the norm,
+    :func:`_mlp_block`, the residual add.  Returns ``(x, aux)``."""
+    h = _norm(x, lp["mlp_norm"], cfg)
+    mlp_out, aux = _mlp_block(cfg, lp, h, seg_ids=seg_ids, mesh=mesh)
+    return x + checkpoint_name(mlp_out, "mlp_out"), aux
+
+
+@region("areal.kv_write")
+def window_put(w, new, l, i):
+    """A decode step's own keys (or values, or latent entries) ``new``
+    ``[B, 1, Hkv, hd]`` into the chunk's window ``w`` ``[L, W, B, Hkv,
+    hd]`` at layer ``l``, step ``i``: scalar offsets, so contiguous and in
+    place."""
+    return jax.lax.dynamic_update_slice(
+        w, new.swapaxes(0, 1)[None].astype(w.dtype), (l, i, 0, 0, 0)
+    )
+
+
+@region("areal.layers")
+def scan_layers(body, init, xs):
+    """``lax.scan`` over a stack's layers, with a region for the loop
+    itself: jax traces a scan's slicing of ``xs`` (a layer's weights out
+    of the stack) and its stacking of what the bodies leave (a fill's keys
+    and values, the weights' gradients) OUTSIDE the body's scopes, so they
+    would carry no region.  What the body names keeps its own."""
+    return jax.lax.scan(body, init, xs)
+
+
 def _mlp_block(cfg: TransformerConfig, lp: Params, h, seg_ids=None,
                mesh=None):
     """Shared MLP/MoE block (post-attention half of every layer).
@@ -627,40 +674,35 @@ def _layer(
     k/v_full include cached history when provided and aux carries MoE
     router losses (None for dense)."""
     B, T, D = x.shape
-    h = _norm(x, lp["attn_norm"], cfg)
-    proj = _proj
-    q, k, v = _attn_qkv(cfg, lp, h, positions, rope_cs)
 
-    if kv is not None:
-        # write new k/v into cache at per-row offsets, attend over full cache
-        k_cache, v_cache = kv  # [B, Hkv, S, hd]
-
-        def write_row(cache_row, new_row, off):
-            # cache_row [Hkv, S, hd]; new_row [T, Hkv, hd]
-            return jax.lax.dynamic_update_slice(
-                cache_row,
-                new_row.swapaxes(0, 1).astype(cache_row.dtype),
-                (0, off, 0),
-            )
-
-        k_full = jax.vmap(write_row)(k_cache, k, kv_write_pos)
-        v_full = jax.vmap(write_row)(v_cache, v, kv_write_pos)
-        attn_out = cache_attention(q, k_full, v_full, mask)
-    else:
-        k_full = v_full = None
-        attn_out = _attention_dispatch(
-            q, k, v, mask, cfg, seg_ids=seg_ids, positions=positions
+    def write_row(cache_row, new_row, off):
+        # cache_row [Hkv, S, hd]; new_row [T, Hkv, hd]
+        return jax.lax.dynamic_update_slice(
+            cache_row,
+            new_row.swapaxes(0, 1).astype(cache_row.dtype),
+            (0, off, 0),
         )
 
-    attn_out = attn_out.reshape(B, T, cfg.n_q_heads * cfg.head_dim)
-    attn_out = checkpoint_name(attn_out, "attn_out")
-    x = x + proj(lp["attn"]["o"], attn_out)
+    def attend(q, k, v):
+        if kv is not None:
+            # write new k/v into cache at per-row offsets, attend over
+            # the full cache
+            k_cache, v_cache = kv  # [B, Hkv, S, hd]
+            with region("areal.kv_write"):
+                k_full = jax.vmap(write_row)(k_cache, k, kv_write_pos)
+                v_full = jax.vmap(write_row)(v_cache, v, kv_write_pos)
+            attn_out = cache_attention(q, k_full, v_full, mask)
+        else:
+            k_full = v_full = None
+            attn_out = _attention_dispatch(
+                q, k, v, mask, cfg, seg_ids=seg_ids, positions=positions
+            )
+        attn_out = attn_out.reshape(B, T, cfg.n_q_heads * cfg.head_dim)
+        return checkpoint_name(attn_out, "attn_out"), (k_full, v_full)
 
-    h = _norm(x, lp["mlp_norm"], cfg)
-    mlp_out, aux = _mlp_block(cfg, lp, h, seg_ids=seg_ids, mesh=mesh)
-    mlp_out = checkpoint_name(mlp_out, "mlp_out")
-    x = x + mlp_out
-    return x, (k_full, v_full), aux
+    x, kv_full = _attn_half(cfg, lp, x, positions, rope_cs, attend)
+    x, aux = _mlp_half(cfg, lp, x, seg_ids=seg_ids, mesh=mesh)
+    return x, kv_full, aux
 
 
 def _scan_layers(cfg: TransformerConfig, stacked_lp, x, positions, mask,
@@ -686,7 +728,7 @@ def _scan_layers(cfg: TransformerConfig, stacked_lp, x, positions, mask,
             body = jax.checkpoint(body)
         else:
             body = jax.checkpoint(body, policy=policy)
-    return jax.lax.scan(body, x, stacked_lp)
+    return scan_layers(body, x, stacked_lp)
 
 
 def _run_layers_pipelined(
@@ -811,6 +853,7 @@ def _run_layers(
     return x, aux_total
 
 
+@region("areal.embed")
 def _embed(params, cfg: TransformerConfig, tokens, positions):
     x = params["embed"]["weight"].astype(jnp.dtype(cfg.dtype))[tokens]
     if cfg.embed_scale is not None:
@@ -820,6 +863,12 @@ def _embed(params, cfg: TransformerConfig, tokens, positions):
     return x
 
 
+@region("areal.head")
+def _final_norm(params, cfg: TransformerConfig, x):
+    return _norm(x, params["final_norm"], cfg)
+
+
+@region("areal.head")
 def _head(params, cfg: TransformerConfig, x):
     x = _norm(x, params["final_norm"], cfg)
     if cfg.is_critic:
@@ -902,7 +951,7 @@ def prefill(
         )
         return y, (k_full, v_full)
 
-    x, (new_k, new_v) = jax.lax.scan(
+    x, (new_k, new_v) = scan_layers(
         body, x, (params["layers"], cache.k, cache.v)
     )
     new_lengths = cache.lengths + jnp.sum(seg_ids != 0, axis=1).astype(jnp.int32)
@@ -952,25 +1001,24 @@ def decode_step(
     def body(carry, xs):
         x, k_all, v_all = carry
         lp, l = xs
-        h = _norm(x, lp["attn_norm"], cfg)
-        q, k, v = _attn_qkv(cfg, lp, h, positions, rope_cs)
-        kv_heads = jnp.arange(cfg.n_kv_heads)
-        k_all = k_all.at[
-            l, rows[:, None], kv_heads[None, :], cache.lengths[:, None]
-        ].set(k[:, 0].astype(k_all.dtype))
-        v_all = v_all.at[
-            l, rows[:, None], kv_heads[None, :], cache.lengths[:, None]
-        ].set(v[:, 0].astype(v_all.dtype))
-        attn_out = cache_attention(q, k_all[l], v_all[l], mask)
-        attn_out = attn_out.reshape(B, 1, cfg.n_q_heads * cfg.head_dim)
-        x = x + _proj(lp["attn"]["o"], attn_out)
 
-        h = _norm(x, lp["mlp_norm"], cfg)
-        mlp_out, _ = _mlp_block(cfg, lp, h, mesh=mesh)
-        x = x + mlp_out
+        def attend(q, k, v):
+            kv_heads = jnp.arange(cfg.n_kv_heads)
+            at = (l, rows[:, None], kv_heads[None, :], cache.lengths[:, None])
+            with region("areal.kv_write"):
+                k_new = k_all.at[at].set(k[:, 0].astype(k_all.dtype))
+                v_new = v_all.at[at].set(v[:, 0].astype(v_all.dtype))
+            attn_out = cache_attention(q, k_new[l], v_new[l], mask)
+            return (
+                attn_out.reshape(B, 1, cfg.n_q_heads * cfg.head_dim),
+                (k_new, v_new),
+            )
+
+        x, (k_all, v_all) = _attn_half(cfg, lp, x, positions, rope_cs, attend)
+        x, _ = _mlp_half(cfg, lp, x, mesh=mesh)
         return (x, k_all, v_all), None
 
-    (x, new_k, new_v), _ = jax.lax.scan(
+    (x, new_k, new_v), _ = scan_layers(
         body,
         (x, cache.k, cache.v),
         (params["layers"], jnp.arange(cfg.n_layers)),
@@ -1107,66 +1155,52 @@ def decode_chunk(
                 # slots rows can actually occupy this chunk
                 kc = jax.lax.slice_in_dim(kc, 0, Sa, axis=2)
                 vc = jax.lax.slice_in_dim(vc, 0, Sa, axis=2)
-            h = _norm(x, lp["attn_norm"], cfg)
-            q, k, v = _attn_qkv(cfg, lp, h, positions, rope_cs)
-            # contiguous window write at scalar offsets (l, i)
-            wk = jax.lax.dynamic_update_slice(
-                wk, k.swapaxes(0, 1)[None].astype(wk.dtype), (l, i, 0, 0, 0)
-            )
-            wv = jax.lax.dynamic_update_slice(
-                wv, v.swapaxes(0, 1)[None].astype(wv.dtype), (l, i, 0, 0, 0)
-            )
-            wk_l = jax.lax.dynamic_index_in_dim(wk, l, 0, keepdims=False)
-            wv_l = jax.lax.dynamic_index_in_dim(wv, l, 0, keepdims=False)
-            r = cfg.n_q_heads // Hkv
-            qg = q.reshape(B, 1, Hkv, r, hd)
-            s_win = jnp.einsum(
-                "btkrd,wbkd->bkrtw", qg, wk_l.astype(qg.dtype),
-                preferred_element_type=jnp.float32,
-            ) / np.sqrt(hd)
-            s_win = jnp.where(mask_win, s_win, -1e30)  # [B,Hkv,r,1,W]
-            s_main = jnp.einsum(
-                "btkrd,bksd->bkrts", qg, kc.astype(qg.dtype),
-                preferred_element_type=jnp.float32,
-            ) / np.sqrt(hd)
-            s_main = jnp.where(
-                mask_main[:, None, None, None, :], s_main, -1e30
-            )
-            s = jnp.concatenate([s_main, s_win], axis=-1)
-            p = jax.nn.softmax(s, axis=-1)
-            p_main, p_win = p[..., :Seff], p[..., Seff:]
-            attn = jnp.einsum(
-                "bkrts,bksd->btkrd", p_main.astype(vc.dtype), vc
-            ) + jnp.einsum(
-                "bkrtw,wbkd->btkrd", p_win.astype(wv_l.dtype), wv_l
-            )
-            attn = attn.reshape(B, 1, cfg.n_q_heads * hd)
-            x = x + _proj(lp["attn"]["o"], attn)
-            h = _norm(x, lp["mlp_norm"], cfg)
-            mlp_out, _ = _mlp_block(cfg, lp, h, mesh=mesh)
-            x = x + mlp_out
+
+            def attend(q, k, v):
+                win = window_put(wk, k, l, i), window_put(wv, v, l, i)
+                wk_l, wv_l = (
+                    jax.lax.dynamic_index_in_dim(w, l, 0, keepdims=False)
+                    for w in win
+                )
+                r = cfg.n_q_heads // Hkv
+                qg = q.reshape(B, 1, Hkv, r, hd)
+                s_win = jnp.einsum(
+                    "btkrd,wbkd->bkrtw", qg, wk_l.astype(qg.dtype),
+                    preferred_element_type=jnp.float32,
+                ) / np.sqrt(hd)
+                s_win = jnp.where(mask_win, s_win, -1e30)  # [B,Hkv,r,1,W]
+                s_main = jnp.einsum(
+                    "btkrd,bksd->bkrts", qg, kc.astype(qg.dtype),
+                    preferred_element_type=jnp.float32,
+                ) / np.sqrt(hd)
+                s_main = jnp.where(
+                    mask_main[:, None, None, None, :], s_main, -1e30
+                )
+                s = jnp.concatenate([s_main, s_win], axis=-1)
+                p = jax.nn.softmax(s, axis=-1)
+                p_main, p_win = p[..., :Seff], p[..., Seff:]
+                attn = jnp.einsum(
+                    "bkrts,bksd->btkrd", p_main.astype(vc.dtype), vc
+                ) + jnp.einsum(
+                    "bkrtw,wbkd->btkrd", p_win.astype(wv_l.dtype), wv_l
+                )
+                return attn.reshape(B, 1, cfg.n_q_heads * hd), win
+
+            x, (wk, wv) = _attn_half(cfg, lp, x, positions, rope_cs, attend)
+            x, _ = _mlp_half(cfg, lp, x, mesh=mesh)
             return (x, wk, wv), None
 
-        (x, wk, wv), _ = jax.lax.scan(
+        (x, wk, wv), _ = scan_layers(
             body,
             (x, wk, wv),
             (params["layers"], jnp.arange(L), attn_k, attn_v),
         )
         logits = _head(params, cfg, x)[:, 0]
-        rng, sub = jax.random.split(rng)
-        # position-aware samplers receive each sampled token's absolute
-        # position (cur sits at ``lengths``; its successor at lengths+1)
-        tok, logp = call_sample_fn(
-            sample_fn, logits.astype(jnp.float32), sub, lengths + 1,
-            row_seeds,
+        (new_lengths, tok, active, budgets, out_t, out_l, emitted,
+         rng) = sample_and_advance(
+            sample_fn, stop_fn, logits, rng, i, lengths, active, budgets,
+            out_t, out_l, emitted, S, row_seeds,
         )
-        tok = jnp.where(active, tok, 0)
-        out_t = out_t.at[:, i].set(tok)
-        out_l = out_l.at[:, i].set(jnp.where(active, logp, 0.0))
-        emitted = emitted.at[:, i].set(active)
-        new_lengths = lengths + active.astype(jnp.int32)
-        budgets = budgets - active.astype(jnp.int32)
-        active = active & ~stop_fn(tok) & (budgets > 0) & (new_lengths < S)
         return (new_lengths, tok, active, budgets, wk, wv, wvalid,
                 out_t, out_l, emitted, rng)
 
@@ -1218,9 +1252,9 @@ def hidden_states(
         x, aux = _run_layers(
             params, cfg, x, positions, mask, seg_ids, with_aux=True
         )
-        return _norm(x, params["final_norm"], cfg), aux
+        return _final_norm(params, cfg, x), aux
     x = _run_layers(params, cfg, x, positions, mask, seg_ids)
-    return _norm(x, params["final_norm"], cfg)
+    return _final_norm(params, cfg, x)
 
 
 def head_weight(params: Params, cfg: TransformerConfig) -> jax.Array:
@@ -1250,7 +1284,14 @@ def logprobs_of_labels(
         seg_ids, positions, seg_ids, positions, cfg.sliding_window
     )
     x = _run_layers(params, cfg, x, positions, mask, seg_ids)
-    x = _norm(x, params["final_norm"], cfg)
+    x = _final_norm(params, cfg, x)
+    return _chunked_label_logprobs(params, cfg, x, tokens)
+
+
+@region("areal.loss")
+def _chunked_label_logprobs(params, cfg: TransformerConfig, x, tokens):
+    """The head product and each label's log-probability, a chunk of
+    positions at a time, of final-norm hidden states ``x``."""
     if cfg.tied_embedding:
         w = params["embed"]["weight"].astype(x.dtype).T
     else:

@@ -825,13 +825,15 @@ METRIC_TABLE = [
 class TraceSpec:
     """One canonical trace name.  ``kind`` is "span" (the flight
     recorder's span_begin/span_end/span — a duration of one SAMPLE),
-    "event" (instant) or "phase" (``tracing.phase`` — what a THREAD is
+    "event" (instant), "phase" (``tracing.phase`` — what a THREAD is
     doing, a ``jax.profiler.TraceAnnotation`` in the profiler's own
     trace; the name starts with ``areal.`` and the help text names the
-    counts the span carries)."""
+    counts the span carries) or "region" (``tracing.region`` — which part
+    of a step program a DEVICE operation belongs to, a
+    ``jax.named_scope``; same prefix)."""
 
     name: str
-    kind: str  # "span" | "event" | "phase"
+    kind: str  # "span" | "event" | "phase" | "region"
     help: str
 
 
@@ -1139,8 +1141,7 @@ TRACE_TABLE = [
         "dispatched rows, chunk_size, pages_attended, page_slots = "
         "batch slots x pages a slot's table holds, tiles_attended = "
         "sum of ceil(context / tile_tokens), tile_tokens = the unit the "
-        "paged kernel copies a page in; state_rows_sum = "
-        "rows x steps whose state slots the chunk advances; for latent "
+        "paged kernel copies a page in; for latent "
         "pages latent_ctx_tokens_sum and latent_pages_attended = the "
         "same context and pages, ONE entry a position and layer)",
     ),
@@ -1224,6 +1225,88 @@ TRACE_TABLE = [
         "areal.train.sync",
         "phase",
         "The step's one device_get: blocks until the program has run",
+    ),
+    # -- regions: which part of a step program a device operation is in ------
+    TraceSpec(
+        "areal.embed",
+        "region",
+        "Token (and position) embedding",
+    ),
+    TraceSpec(
+        "areal.attn",
+        "region",
+        "A layer's attention half: the norm before it, q/k/v (latent: "
+        "the low-rank projections and the absorbed query), rope, the "
+        "attention call (paged and flash kernels, chunk and cache "
+        "attention), the output projection and its residual add",
+    ),
+    TraceSpec(
+        "areal.kv_write",
+        "region",
+        "Keys and values put where later steps read them: "
+        "write_kv_runs, a decode step's window write, copy_blocks",
+    ),
+    TraceSpec(
+        "areal.ssm",
+        "region",
+        "A Mamba layer's mixer half: norm, in-projection, conv, state "
+        "update (ssm_state_update / ssd_chunked), gate and norm, "
+        "out-projection, residual; the state rows' and conv tails' get "
+        "and put, copy_state_slots",
+    ),
+    TraceSpec(
+        "areal.layers",
+        "region",
+        "The layer loop itself: a layer's weights sliced out of the "
+        "stack, what the layers leave stacked (a fill's keys and values, "
+        "the weights' gradients); what a layer's halves name keeps "
+        "their region",
+    ),
+    TraceSpec(
+        "areal.mlp",
+        "region",
+        "A layer's MLP half: norm, dense MLP, residual (around an "
+        "expert block: the norm and the residual)",
+    ),
+    TraceSpec(
+        "areal.moe.route",
+        "region",
+        "The router: logits, choice, weights, the pair histogram and "
+        "the router losses",
+    ),
+    TraceSpec(
+        "areal.moe.experts",
+        "region",
+        "The routed experts' three projections and their combination",
+    ),
+    TraceSpec(
+        "areal.moe.shared",
+        "region",
+        "The shared expert",
+    ),
+    TraceSpec(
+        "areal.head",
+        "region",
+        "Final norm and the logits product on the serving path (the "
+        "trainer's final norm; its head product is in areal.loss)",
+    ),
+    TraceSpec(
+        "areal.sample",
+        "region",
+        "The sampler, the sampled token's log-probability, the stop "
+        "rule and a decode step's row bookkeeping",
+    ),
+    TraceSpec(
+        "areal.loss",
+        "region",
+        "The chunked head product with log-probability and entropy, "
+        "and the loss arithmetic over them",
+    ),
+    TraceSpec(
+        "areal.optimizer",
+        "region",
+        "Gradient accumulation and scaling, norm and clip, the "
+        "optimizer's update, the parameters' update",
     ),
 ]
 

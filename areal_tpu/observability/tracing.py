@@ -41,6 +41,15 @@ of the device operations, whenever a profiler session is live (``GET
 /profile``, a benchmark's traced slice) and cost a flag check when none
 is.  Their names carry the prefix ``areal.`` and are declared in the same
 table (kind ``"phase"``).
+
+**Regions** (:func:`region`) name the DEVICE's time: which part of a step
+program an operation belongs to.  A region is a ``jax.named_scope``, so
+it is metadata of the lowered program and nothing else: no operation, no
+host work a step, nothing to switch on.  The profiler writes each
+operation's scope path into the trace (``tf_op``), where the pass is
+read from the path jax builds (``transpose(`` = backward,
+``rematted_computation`` = recomputed forward).  Same prefix, same table
+(kind ``"region"``).
 """
 
 from __future__ import annotations
@@ -337,6 +346,32 @@ def phase(name: str, **counts):
     check otherwise.  ``__enter__`` returns the annotation:
     ``set_metadata(**counts)`` adds counts known only at the end."""
     return _annotation(name, counts)
+
+
+class region(contextlib.ContextDecorator):
+    """``with region("areal.mlp"):`` or ``@region("areal.mlp")`` over a
+    function — the operations traced inside carry the name in their scope
+    path (``jit(step)/.../areal.mlp/dot_general``).  A
+    ``jax.named_scope`` and nothing else, a fresh one every time it is
+    entered: jax's own keeps what it replaced on ITSELF, so one instance
+    around a function would be shared by every thread that traces it.
+    Every operation of a step program lies in one innermost region; the
+    names are in ``docs/observability.md``, "Device regions"."""
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def _recreate_cm(self):
+        import jax  # only programs being traced come here
+
+        return jax.named_scope(self.name)
+
+    def __enter__(self):
+        self._scope = self._recreate_cm()
+        return self._scope.__enter__()
+
+    def __exit__(self, *exc):
+        return self._scope.__exit__(*exc)
 
 
 class _TimedPhase:

@@ -16,6 +16,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from areal_tpu.observability.tracing import region
+
 
 def _chunk_logp_ent(h, w, labels):
     """h [C, D], labels [C] -> (logp [C], entropy [C])."""
@@ -38,6 +40,7 @@ def _chunk_logp(h, w, labels):
     return logp, jnp.zeros_like(logp)
 
 
+@region("areal.loss")
 def per_token_logprobs_entropy(
     hidden: jax.Array,  # [N, D] hidden states (pre final-head)
     head_w: jax.Array,  # [D, V]
@@ -66,6 +69,7 @@ def per_token_logprobs_entropy(
     return logps.reshape(-1)[:N], ents.reshape(-1)[:N]
 
 
+@region("areal.loss")
 def masked_cross_entropy(
     hidden: jax.Array,  # [N, D]
     head_w: jax.Array,  # [D, V]

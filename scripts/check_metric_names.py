@@ -9,7 +9,8 @@ table.py).
 as the SECOND argument (the first is the trace id) of a
 ``.event(tid, "...")`` / ``.span_begin(...)`` / ``.span_end(...)`` /
 ``.span(...)`` call, or as the FIRST argument of a phase span
-(``phase("areal....")``, ``clock.phase("areal....")``), anywhere under
+(``phase("areal....")``, ``clock.phase("areal....")``) or of a device
+region (``region("areal....")``), anywhere under
 ``areal_tpu/`` or in ``__graft_entry__.py`` — found by
 AST walk (so formatting/aliasing of
 the registry/tracer object doesn't matter, and dynamically computed
@@ -44,7 +45,10 @@ _TRACER_METHODS = ("event", "span_begin", "span_end", "span")
 #: phase spans (observability/tracing.phase, PhaseClock.phase): the name
 #: is the FIRST argument, and the call may be a bare ``phase(...)``
 _PHASE_FUNCTIONS = ("phase",)
-#: what keeps a phase span's name apart from the flight recorder's
+#: device regions (observability/tracing.region): same call shape
+_REGION_FUNCTIONS = ("region",)
+#: what keeps a phase span's or a region's name apart from the flight
+#: recorder's
 PHASE_PREFIX = "areal."
 
 #: files whose registry-shaped calls are not metric emissions; currently
@@ -130,20 +134,30 @@ def collect_phase_names(
     return _collect(_PHASE_FUNCTIONS, 0, bare=True, sources=sources)
 
 
+def collect_region_names(
+    sources: Dict[str, str] | None = None,
+) -> Dict[str, List[Tuple[str, int]]]:
+    """Device region name literals: the first argument of
+    ``region(...)`` / ``tracing.region(...)``."""
+    return _collect(_REGION_FUNCTIONS, 0, bare=True, sources=sources)
+
+
 def phase_vocabulary_problems(
-    phases: Dict[str, List[Tuple[str, int]]], table
+    phases: Dict[str, List[Tuple[str, int]]], table, kind: str = "phase"
 ) -> List[str]:
     """Phase spans against TRACE_TABLE's ``"phase"`` entries, both ways:
     every literal at a ``phase(...)`` site is declared with that kind and
     carries the prefix, and every declared phase is recorded somewhere.
-    A pure function of its inputs, so the tier-1 test can feed it a
-    fabricated site."""
+    With ``kind="region"``, the same for ``region(...)`` sites and the
+    ``"region"`` entries.  A pure function of its inputs, so the tier-1
+    test can feed it a fabricated site."""
     problems: List[str] = []
-    declared = {spec.name for spec in table if spec.kind == "phase"}
+    what = "phase span" if kind == "phase" else kind
+    declared = {spec.name for spec in table if spec.kind == kind}
     for name in sorted(declared):
         if not name.startswith(PHASE_PREFIX):
             problems.append(
-                f"phase span {name} in TRACE_TABLE lacks the prefix "
+                f"{what} {name} in TRACE_TABLE lacks the prefix "
                 f"{PHASE_PREFIX!r} that keeps it apart from the flight "
                 "recorder's names"
             )
@@ -151,18 +165,18 @@ def phase_vocabulary_problems(
         where = ", ".join(f"{p}:{ln}" for p, ln in sites)
         if name == "<non-literal>":
             problems.append(
-                f"non-literal phase span name at {where} — phase names "
+                f"non-literal {what} name at {where} — {kind} names "
                 "must be string literals so the table lint can see them"
             )
         elif name != "<syntax-error>" and name not in declared:
             problems.append(
-                f"phase span {name} ({where}) is missing from "
+                f"{what} {name} ({where}) is missing from "
                 "areal_tpu/observability/table.py TRACE_TABLE (kind "
-                '"phase")'
+                f'"{kind}")'
             )
     for name in sorted(declared - set(phases)):
         problems.append(
-            f"trace table entry {name} (phase) is never recorded "
+            f"trace table entry {name} ({kind}) is never recorded "
             "anywhere under areal_tpu/ or __graft_entry__.py "
             "(dead vocabulary — remove it or wire the span)"
         )
@@ -475,7 +489,15 @@ def run_lint() -> List[str]:
     problems.extend(
         phase_vocabulary_problems(collect_phase_names(), TRACE_TABLE)
     )
-    recorder = {s.name for s in TRACE_TABLE if s.kind != "phase"}
+    # device regions: the same table and discipline, kind "region"
+    problems.extend(
+        phase_vocabulary_problems(
+            collect_region_names(), TRACE_TABLE, kind="region"
+        )
+    )
+    recorder = {
+        s.name for s in TRACE_TABLE if s.kind not in ("phase", "region")
+    }
     traced = collect_trace_names()
     for name, sites in sorted(traced.items()):
         where = ", ".join(f"{p}:{ln}" for p, ln in sites)

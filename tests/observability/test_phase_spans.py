@@ -42,9 +42,11 @@ def test_every_phase_literal_is_declared_and_every_declared_phase_used():
     declared = {s.name for s in TRACE_TABLE if s.kind == "phase"}
     assert set(sites) == declared
     assert all(n.startswith("areal.") for n in declared)
-    # the flight recorder's names stay apart from the phases'
+    # the flight recorder's names stay apart from the phases' and the
+    # device regions'
     assert not any(
-        s.name.startswith("areal.") for s in TRACE_TABLE if s.kind != "phase"
+        s.name.startswith("areal.")
+        for s in TRACE_TABLE if s.kind not in ("phase", "region")
     )
 
 
