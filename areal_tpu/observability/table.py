@@ -1202,7 +1202,7 @@ TRACE_TABLE = [
         "phase",
         "One TrainEngine.train_batch call (counts: real_tokens, "
         "padded_slots, n_mbs, rows, row_len, attn_blocks_run, "
-        "attn_blocks_causal)",
+        "attn_blocks_causal, loss_head_products)",
     ),
     TraceSpec(
         "areal.train.pack",
@@ -1300,7 +1300,8 @@ TRACE_TABLE = [
         "areal.loss",
         "region",
         "The chunked head product with log-probability and entropy, "
-        "and the loss arithmetic over them",
+        "and the loss arithmetic over them (a token-sum loss takes each "
+        "chunk's gradient in the forward scan: no recomputed pass)",
     ),
     TraceSpec(
         "areal.optimizer",

@@ -225,6 +225,9 @@ def test_train_engine_cumulative_slots_are_the_sum_of_each_calls(monkeypatch):
         c = s.counts
         assert c["row_len"] in (32, 64)
         assert c["n_mbs"] * c["rows"] * c["row_len"] == c["padded_slots"]
+        # the SFT loss is a sum over tokens: its step programs take each
+        # chunk's gradient in place, three head products a token
+        assert c["loss_head_products"] == 3
 
 
 @pytest.mark.parametrize("pack", [True, False])

@@ -201,14 +201,14 @@ def test_hybrid_programs_name_their_regions(name, program, also):
 # -- the PPO train step ---------------------------------------------------------
 
 
-def _train_step():
+def _train_step(engines=None, vocab_size=64):
     """The PPO actor's fused train step at a toy size, rematerialised."""
     from areal_tpu.base.topology import MeshSpec
     from areal_tpu.engine.optimizer import OptimizerConfig
     from areal_tpu.engine.train_engine import TrainEngine
     from areal_tpu.interfaces.ppo_interface import PPOActorInterface
 
-    cfg = tiny_config(vocab_size=64, remat=True)
+    cfg = tiny_config(vocab_size=vocab_size, remat=True)
     mesh = MeshSpec(data=1, fsdp=1, model=1).make_mesh(jax.devices()[:1])
     engine = TrainEngine(
         cfg, mesh, tfm.init_params(cfg, jax.random.PRNGKey(0)),
@@ -226,11 +226,14 @@ def _train_step():
         "advantages": np.ones((1, 1, T), np.float32),
     }
     step = engine._get_train_step(PPOActorInterface()._loss_fn, 1)
+    if engines is not None:
+        engines.append(engine)
     return step, (engine.params, engine.opt_state, batch), {}
 
 
 def test_train_step_shows_the_three_passes_and_its_own_regions():
-    paths = _paths(_lower(_train_step()))
+    engines = []
+    paths = _paths(_lower(_train_step(engines)))
     _assert_products_in_regions(
         paths,
         {"areal.embed", "areal.attn", "areal.mlp", "areal.head",
@@ -250,10 +253,13 @@ def test_train_step_shows_the_three_passes_and_its_own_regions():
         p for p in paths if _PRODUCT.search(p) and _region_of(p) == "areal.head"
     ]
     assert not head_products, head_products
-    assert any(
-        _PRODUCT.search(p) and "transpose(" in p
-        for p in paths if _region_of(p) == "areal.loss"
-    )
+    # a token-sum loss takes each chunk's gradient inside its forward scan
+    # (the chunk's own transpose(jvp())): three head products a token, the
+    # count the span areal.train.batch carries, and no recomputed pass
+    of_loss = [p for p in paths if _region_of(p) == "areal.loss"]
+    assert any(_PRODUCT.search(p) and "transpose(" in p for p in of_loss)
+    assert not [p for p in of_loss if "rematted_computation" in p]
+    assert list(engines[0]._loss_head_products.values()) == [3]
     assert not any(
         "transpose(" in p or "jvp(" in p
         for p in paths if _region_of(p) == "areal.optimizer"

@@ -749,8 +749,12 @@ def test_train_step_at_the_train_cells_largest_shape_fits_one_chip(
     three batches: arguments and temporaries fit the 15.75 GB a v5e leaves
     a program, by the compiler's count.  A change of the rule that lays out
     more slots a micro-batch shows here, before any chip: ``[1, 8192]``
-    counts 14.15 GB (14.3 with the library's flash kernels, before PR 32),
-    PR 30's parent's ``[3, 4096]`` 15.4, ``[4, 4096]`` would not pass.
+    counts 14.146 GB = 8.417 arguments + 5.730 temporaries (0.6 MB over
+    PR 39's parent, whose loss recomputed each chunk's logits: the loss
+    that takes a chunk's gradient in place keeps ``d hidden`` and ``d
+    head`` where the transposed scan kept them; 14.3 with the library's
+    flash kernels, before PR 32), PR 30's parent's ``[3, 4096]`` 15.4,
+    ``[4, 4096]`` would not pass.
 
     The micro-batch is compiled as a program of ONE.  A described chip has
     no memory limit for the scheduler to work to, so a program that
@@ -787,7 +791,7 @@ def test_train_step_at_the_train_cells_largest_shape_fits_one_chip(
     eng.model_cfg, eng.pack_sequences, eng.pipe_size = cfg, True, 1
     eng.mesh = MeshSpec().make_mesh(topo.devices[:1])
     eng.tx = make_optimizer(OptimizerConfig(**traffic["optimizer"]), 10**6)
-    eng._train_step_cache = {}
+    eng._train_step_cache, eng._loss_head_products = {}, {}
 
     # the model takes the flash kernel, and the layout rule lengthens rows,
     # on a TPU only, and this process sees a CPU beside the described chip
