@@ -195,6 +195,11 @@ class GenServerConfig:
     cache_mode: str = "auto"
     page_size: int = 1024
     kv_pool_tokens: Optional[int] = None
+    # the pool of a stack's WINDOW layers, whose pages are released once
+    # every holder's window has passed them (engine/window_pages.py);
+    # None = as many tokens as kv_pool_tokens.  Read only where the model
+    # has such layers
+    kv_window_pool_tokens: Optional[int] = None
     # paged KV storage dtype (the SGLang/vLLM --kv-cache-dtype knob):
     # "auto" stores blocks at model dtype (bit-for-bit today's
     # behavior); "int8" stores quantized pools with per-(block, head,

@@ -126,7 +126,9 @@ def make_model(
 
             params = hybrid.init_params(model_cfg, jax.random.PRNGKey(seed))
             backend_name = (
-                "deepseek_v3" if model_cfg.is_latent else "granitemoehybrid"
+                "deepseek_v3" if model_cfg.is_latent
+                else "smallthinker" if model_cfg.n_window_layers
+                else "granitemoehybrid"
             )
         else:
             from areal_tpu.models.transformer import init_params

@@ -69,6 +69,7 @@ from areal_tpu.api import model_api
 from areal_tpu.base import jax_compat, logging_
 from areal_tpu.engine import spec_decode
 from areal_tpu.engine.batching import bucket_len, spec_window_bucket
+from areal_tpu.engine.window_pages import GONE, WindowPages
 from areal_tpu.engine.prefix_cache import PrefixMatch, RadixPrefixCache
 from areal_tpu.engine.sampling import SamplingParams, sample_logits_keyed
 from areal_tpu.models import hybrid, paged, quantize
@@ -210,6 +211,9 @@ class _Fill:
     #: stateful models: the row slot whose recurrent state this fill
     #: advances chunk by chunk (its first target's); siblings get a copy
     state_slot: int = -1
+    #: a stack with window layers: their pages by number (``GONE`` where
+    #: released behind the fill: engine/window_pages.py)
+    wblocks: Optional[List[int]] = None
     #: ``keep_routed_experts``: ``(routed [L, F, C, K] on the device, this
     #: fill's row in it, its valid tokens)`` of each chunk so far
     routed: List[Tuple[Any, int, int]] = dataclasses.field(
@@ -449,6 +453,7 @@ class ContinuousBatchingEngine:
         kv_cache_dtype: str = "auto",
         serving_weight_dtype: str = "auto",
         prefill_chunk_tokens: int = 1024,
+        kv_window_pool_tokens: Optional[int] = None,
         pipeline_depth: int = 2,
         prefix_cache: bool = True,
         prefix_cache_capacity_frac: float = 0.5,
@@ -497,7 +502,10 @@ class ContinuousBatchingEngine:
         plain chunked decode.
         ``kv_pool_tokens`` sizes the paged pool (default: dense-equivalent
         ``max_batch * kv_cache_len``; set smaller to serve long contexts a
-        dense cache could never reserve).  ``prefill_chunk_tokens`` bounds
+        dense cache could never reserve).  ``kv_window_pool_tokens`` sizes
+        the pool of a stack's WINDOW layers (default: as many tokens):
+        their pages follow a rule of their own (engine/window_pages.py).
+        ``prefill_chunk_tokens`` bounds
         the prompt tokens prefetched per engine step — the decode stall
         during a long-prompt admission is one chunk, not the whole wave.
 
@@ -604,6 +612,17 @@ class ContinuousBatchingEngine:
                 "keep_routed_experts: only the hybrid stack's programs "
                 "hand their routing out"
             )
+        #: window layers have pools, a table and a page rule of their own
+        #: (``_init_paged_state``); what moves whole rows' pages between
+        #: servers (handoff, prefix pull) is not written for two tables
+        self._windowed = bool(cfg.is_hybrid and cfg.n_window_layers)
+        self._win: Optional[WindowPages] = None
+        self._win_tables = None
+        self._kv_window_pool_tokens = kv_window_pool_tokens
+        self.win_k_pool = self.win_v_pool = None
+        #: cached prefixes a request could not reuse because the window
+        #: layers no longer held the pages before its first position
+        self.prefix_refused_window = 0
         self._keep_routed = int(keep_routed_experts)
         self._routed_done: Dict[str, np.ndarray] = {}  # oldest first
         #: the routing of the last prompts filled, by their tokens: a later
@@ -621,7 +640,7 @@ class ContinuousBatchingEngine:
                 "int8 KV storage": kv_cache_dtype == "int8",
                 "int8 serving weights": serving_weight_dtype == "int8",
             }
-            if self._stateful:
+            if self._stateful or cfg.n_window_layers:
                 refused["prefix-cache host spill"] = prefix_cache_host_bytes > 0
             for feature, asked in refused.items():
                 if not asked:
@@ -693,7 +712,7 @@ class ContinuousBatchingEngine:
         # metrics carry measured quality, not assumptions
         self.kv_quant_divergence_checks_total = 0
         self.kv_quant_divergence_diverged_total = 0
-        if self.paged and cfg.sliding_window is not None:
+        if self.paged and cfg.sliding_window is not None and not self._by_kind:
             raise ValueError(
                 "paged cache serves global-attention models; sliding-window "
                 "models use the dense window-gather path"
@@ -919,7 +938,7 @@ class ContinuousBatchingEngine:
         # counters plus exporter-side aborts (a stream cut short by EOS
         # at the first token, a weight swap restarting the fill, or an
         # explicit cancel — the decode peer releases its partial blocks)
-        self._handoff_streaming = bool(handoff_streaming)
+        self._handoff_streaming = bool(handoff_streaming) and not self._windowed
         self.handoff_segment_exports_total = 0
         self.handoff_segment_imports_total = 0
         self.handoff_segment_aborts_total = 0
@@ -1099,6 +1118,28 @@ class ContinuousBatchingEngine:
         pool_b, scale_b = paged.kv_pool_layout_bytes(
             cfg, self.n_blocks, BS, kv_cache_dtype=kv_dtype
         )
+        if self._windowed:
+            # the window layers' pools, table and allocator: a page there
+            # goes once every holder's window has passed it
+            win_tokens = self._kv_window_pool_tokens or pool_tokens
+            n_win = max(-(-win_tokens // BS), self.blocks_per_row)
+            self.win_k_pool, self.win_v_pool, _, _ = paged.alloc_kv_pool(
+                cfg, n_win, BS, layers=cfg.n_window_layers
+            )
+            if self.device is not None:
+                self.win_k_pool, self.win_v_pool = jax.device_put(
+                    (self.win_k_pool, self.win_v_pool), self.device
+                )
+            pool_b += paged.kv_pool_layout_bytes(
+                cfg, n_win, BS, layers=cfg.n_window_layers
+            )[0]
+            self._win = WindowPages(
+                n_win, BS, cfg.sliding_window, max_batch, self.blocks_per_row
+            )
+            self._win_tables = jnp.array(self._win.tables_np)
+            #: references the prefix cache holds, by global block: the
+            #: window-layer page cached with a block goes when they do
+            self._cache_refs: Dict[int, int] = {}
         if self._by_kind:
             # (no byte where no layer is recurrent)
             self.ssm_state, self.conv_state = hybrid.state_zeros(
@@ -1161,8 +1202,14 @@ class ContinuousBatchingEngine:
                 capacity_blocks=int(
                     self._prefix_cache_capacity_frac * self.n_blocks
                 ),
-                acquire=self._incref_blocks,
-                release=self._free_block_list,
+                acquire=(
+                    self._cache_acquire if self._windowed
+                    else self._incref_blocks
+                ),
+                release=(
+                    self._cache_release if self._windowed
+                    else self._free_block_list
+                ),
                 min_match_tokens=self._prefix_cache_min_tokens,
                 host_bytes_budget=host_bytes,
                 block_bytes=block_bytes,
@@ -1370,12 +1417,138 @@ class ContinuousBatchingEngine:
         t[: len(blocks)] = blocks
         self._tables_dirty = True
 
+    def _set_fill_row(self, row_id: int, fill: _Fill):
+        """A fill's canonical pages live in its first target's tables
+        (the same lists: what the fill releases behind itself, the row
+        has released)."""
+        self._set_row_blocks(row_id, fill.blocks)
+        if self._win is not None:
+            self._win.set_row(row_id, fill.wblocks)
+
     def _release_row(self, row_id: int):
         """Single exit point for a row slot: frees its pool blocks."""
         self.rows[row_id] = None
         if self.paged and self._row_blocks[row_id]:
             self._free_block_list(self._row_blocks[row_id])
             self._set_row_blocks(row_id, [])
+        if self._win is not None:
+            self._win.release_row(row_id)
+
+    def _refuse_page_transfer(self, feature: str):
+        """What moves a row's pages between servers assumes ONE table of
+        per-token blocks a row: refused by name for a recurrent state and
+        for window layers' second table."""
+        if self._stateful:
+            raise StatefulModelUnsupported(feature)
+        if self._windowed:
+            raise NotImplementedError(
+                f"{feature} is not supported for a stack with window "
+                "layers: their pages live in a pool and a table of their "
+                "own (engine/window_pages.py), which it does not move"
+            )
+
+    # -- window layers' pages (engine/window_pages.py) ----------------------
+
+    def _cache_acquire(self, blocks: List[int]):
+        self._incref_blocks(blocks)
+        for b in blocks:
+            self._cache_refs[b] = self._cache_refs.get(b, 0) + 1
+
+    def _cache_release(self, blocks: List[int]):
+        for b in blocks:
+            self._cache_refs[b] -= 1
+            if self._cache_refs[b] == 0:
+                del self._cache_refs[b]
+                self._win.cache_drop(b)
+        self._free_block_list(blocks)
+
+    def _reclaim_one(self, keep_qids=(), preempt_but: Optional[int] = None):
+        """One step of reclamation for an allocation that failed: a
+        prefix-cache entry (pure recompute insurance), else the longest
+        parked row, else (``preempt_but`` given: the row that must stay,
+        or -1) the youngest decoding row.  Returns what went: "cache",
+        "parked", "preempted" or None."""
+        if self._prefix_cache is not None and self._prefix_cache.evict_one():
+            return "cache"
+        if self._evict_parked(keep_qids=keep_qids) is not None:
+            return "parked"
+        if preempt_but is None:
+            return None
+        victim = self._pick_preemption_victim(exclude=preempt_but)
+        if victim is None:
+            return None
+        self._preempt_row(victim)
+        return "preempted"
+
+    def _alloc_window_blocks(
+        self, n: int, keep_qids=(), preempt_but: Optional[int] = None
+    ) -> Optional[List[int]]:
+        """``n`` window-layer pages, reclaiming as :meth:`_reclaim_one`."""
+        blocks = self._win.alloc(n)
+        while blocks is None:
+            if self._reclaim_one(keep_qids, preempt_but) is None:
+                return None
+            blocks = self._win.alloc(n)
+        return blocks
+
+    def _copy_window_blocks(self, src: List[int], dst: List[int]):
+        """Tail-page copies in the window layers' pool (power-of-two
+        counts, as ``_copy_pool_blocks``' callers pad theirs)."""
+        n_pad = 1 << (len(src) - 1).bit_length()
+        s = np.zeros((n_pad,), np.int32)
+        d = np.full((n_pad,), self._win.n_blocks, np.int32)  # pad -> drop
+        s[: len(src)], d[: len(dst)] = src, dst
+        self.win_k_pool, self.win_v_pool = paged.copy_blocks(
+            self.win_k_pool, self.win_v_pool, jnp.asarray(s), jnp.asarray(d)
+        )
+
+    def _window_pages_of_fill(
+        self, m: PrefixMatch, held: List[int], n_blocks: int, keep_qids
+    ) -> Optional[List[int]]:
+        """The window layers' pages of a fill that reuses the cached
+        prefix ``m``, whose window-layer pages are ``held``
+        (``WindowPages.cached_tail``): the window's part of the prefix by
+        reference, a cached tail page by copy, the rest its own; None when
+        the pool cannot give them."""
+        held = list(held)
+        self._win.incref(held)  # before allocating: that may evict them
+        own = self._alloc_window_blocks(
+            n_blocks - len(m.blocks), keep_qids=keep_qids
+        )
+        if own is None:
+            self._win.free(held)
+            return None
+        if m.tail_block is not None:
+            self._copy_window_blocks([held[-1]], [own[0]])
+            self._win.free([held.pop()])  # copy taken: unpin
+        return held + own
+
+    def _window_args(self, win_tables) -> Dict[str, Any]:
+        """What the stack's two programs take besides, where it has window
+        layers: their pools (donated) and ``win_tables``."""
+        if self._win is None:
+            return {}
+        return dict(
+            win_pools=(self.win_k_pool, self.win_v_pool),
+            win_tables=jnp.asarray(win_tables),
+        )
+
+    @property
+    def window_pages_live(self) -> int:
+        """Window-layer pages held by rows that decode or fill, each once
+        (0 for a stack without window layers)."""
+        if self._win is None:
+            return 0
+        live = set()
+        for row_id, row in enumerate(self.rows):
+            if row is not None and not row.parked:
+                live.update(b for b in self._win.rows[row_id] if b != GONE)
+        return len(live)
+
+    @property
+    def window_pages_released(self) -> int:
+        """Window-layer pages let go behind a holder's window so far."""
+        return self._win.released_total if self._win is not None else 0
 
     @property
     def free_pool_blocks(self) -> int:
@@ -1399,6 +1572,10 @@ class ContinuousBatchingEngine:
             if row is not None and not row.parked:
                 live.update(self._row_blocks[row_id])
         return len(live)
+
+    #: the pages of the layers that attend the whole context: the engine's
+    #: own pool and table (all of a stack's pages where no layer has a window)
+    global_pages_live = pages_live
 
     @property
     def state_slots_live(self) -> int:
@@ -1485,14 +1662,26 @@ class ContinuousBatchingEngine:
         self.host_restore_rounds_total += 1
         return True
 
-    def _cache_insert(self, seq: List[int], blocks: List[int]):
+    def _cache_insert(
+        self, seq: List[int], blocks: List[int],
+        wblocks: Optional[List[int]] = None,
+    ):
         """Register ``seq``'s KV-bearing blocks in the radix cache (full
-        blocks by reference, the partial tail by value)."""
+        blocks by reference, the partial tail by value).  ``wblocks``: the
+        window layers' pages of the same sequence; the cache holds those
+        of the sequence's last window with the blocks it took, so that a
+        request that reuses the whole prefix finds them
+        (``WindowPages.cached_tail``)."""
         if self._prefix_cache is None or not seq or not blocks:
             return
         self._prefix_cache.insert(
             seq, blocks, step=self._step_seq, version=self.version
         )
+        if self._win is not None:
+            n_pages = min(-(-len(seq) // self.page_size), len(blocks))
+            for i in range(self._win.first_kept(len(seq)), n_pages):
+                if blocks[i] in self._cache_refs:
+                    self._win.cache_pair(blocks[i], wblocks[i])
 
     def _match_prefix(self, seq: List[int]) -> PrefixMatch:
         # record=False: a requeued admission re-matches every engine step
@@ -1540,12 +1729,28 @@ class ContinuousBatchingEngine:
         pinned = list(m.blocks)
         if m.tail_block is not None:
             pinned.append(m.tail_block)
+        wheld: Optional[List[int]] = []
+        if self._win is not None and m.n_tokens:
+            wheld = self._win.cached_tail(pinned, m.n_tokens)
+            if wheld is None:
+                # the window layers no longer hold [n - W + 1, n) of this
+                # prefix: nothing of it can be skipped
+                self.prefix_refused_window += 1
+                m, pinned, wheld = PrefixMatch(), [], []
         self._incref_blocks(pinned)
         own_needed = n_blocks - len(m.blocks)
         blocks = self._alloc_blocks_reclaiming(own_needed, keep_qids=keep_qids)
         if blocks is None:
             self._free_block_list(pinned)
             return None
+        wblocks = None
+        if self._win is not None:
+            wblocks = self._window_pages_of_fill(
+                m, wheld, n_blocks, keep_qids
+            )
+            if wblocks is None:
+                self._free_block_list(pinned + blocks)
+                return None
         if self._prefix_cache is not None and len(seq) >= 2:
             self._prefix_cache.record(m)
         if self._stateful and any(
@@ -1576,6 +1781,7 @@ class ContinuousBatchingEngine:
             targets=[],
             fill_pos=m.n_tokens,
             routed_reused=reused,
+            wblocks=wblocks,
         )
 
     def prefix_cache_stats(self) -> Dict[str, int]:
@@ -1598,8 +1804,7 @@ class ContinuousBatchingEngine:
         by a weight swap or TTL — the decode side re-prefills) or on a
         dense engine.  This is the prefill role's half of the
         P/D-disaggregated serving path."""
-        if self._stateful:
-            raise StatefulModelUnsupported("P/D handoff")
+        self._refuse_page_transfer("P/D handoff")
         if not self.paged:
             return None
         for row_id, row in enumerate(self.rows):
@@ -1664,8 +1869,7 @@ class ContinuousBatchingEngine:
         re-prefills under the current weights.  Layout mismatches
         (page size, kv dtype, context length) and pool/row exhaustion
         reject the same way.  Returns ``(ok, reason)``."""
-        if self._stateful:
-            raise StatefulModelUnsupported("P/D handoff")
+        self._refuse_page_transfer("P/D handoff")
         t0 = time.perf_counter()
         qid = unit.get("qid", "?")
         if not self.paged:
@@ -1950,8 +2154,7 @@ class ContinuousBatchingEngine:
         extend the monolithic set with ``stream`` | ``abort`` |
         ``expired`` (the TTL sweep for dead peers).  Stale or incomplete
         KV is never decoded."""
-        if self._stateful:
-            raise StatefulModelUnsupported("P/D handoff")
+        self._refuse_page_transfer("P/D handoff")
         t0 = time.perf_counter()
         qid = seg.get("qid", "?")
         if seg.get("abort"):
@@ -2171,8 +2374,7 @@ class ContinuousBatchingEngine:
         their spill payloads directly — the spill buffer already IS the
         wire format.  Returns ``[]`` when nothing exportable is cached
         (the puller re-prefills)."""
-        if self._stateful:
-            raise StatefulModelUnsupported("prefix pulls")
+        self._refuse_page_transfer("prefix pulls")
         if not self.paged or self._prefix_cache is None or len(tokens) < 2:
             return []
         entries = self._prefix_cache.export_walk(
@@ -2333,8 +2535,7 @@ class ContinuousBatchingEngine:
         final segment radix-inserts the pulled prefix — the cache takes
         its own references and the pull's are dropped, so ownership
         rules are identical to a locally-computed prefix."""
-        if self._stateful:
-            raise StatefulModelUnsupported("prefix pulls")
+        self._refuse_page_transfer("prefix pulls")
         t0 = time.perf_counter()
         qid = seg.get("qid", "?")
         if not self.paged:
@@ -2450,12 +2651,12 @@ class ContinuousBatchingEngine:
     # -- client API (any thread) -------------------------------------------
 
     def submit(self, req: model_api.APIGenerateInput) -> str:
-        if self._stateful:
+        if self._stateful or self._windowed:
             meta = req.metadata or {}
             if meta.get("handoff_to"):
-                raise StatefulModelUnsupported("P/D handoff")
+                self._refuse_page_transfer("P/D handoff")
             if meta.get("kv_source"):
-                raise StatefulModelUnsupported("prefix pulls")
+                self._refuse_page_transfer("prefix pulls")
         with self._lock:
             self._pending.append(req)
             ev = threading.Event()
@@ -3067,7 +3268,30 @@ class ContinuousBatchingEngine:
                     self.rows[rid].req.qid, "engine.recompute",
                     version=self.version,
                 )
-            if entries:
+            if self._win is not None:
+                # a window layer no longer holds what lies behind a row's
+                # window, and computing its last window again needs all
+                # of it: the rows go back through the fill queue (oldest
+                # first), which holds a whole prompt's window pages only
+                # while it fills; a restarted fill gets back the pages it
+                # had released behind itself
+                for rid, _ in sorted(
+                    entries, key=lambda e: self.rows[e[0]].epoch
+                ):
+                    self._requeue_row(rid, self.rows[rid])
+                for f in self._filling:
+                    gone = [i for i, b in enumerate(f.wblocks) if b == GONE]
+                    again = self._alloc_window_blocks(len(gone))
+                    if again is None:
+                        raise RuntimeError(
+                            "window pool too small to restart a fill of "
+                            f"{len(f.tokens)} tokens after a weight swap"
+                        )
+                    for i, b in zip(gone, again):
+                        f.wblocks[i] = b
+                    if self._win.rows[f.state_slot] is f.wblocks:
+                        self._win.sync_row(f.state_slot)
+            elif entries:
                 # existing blocks are overwritten in place; the pending
                 # cur_tokens are untouched (no resampling to discard)
                 self._refill_rows_paged(entries)
@@ -3296,6 +3520,7 @@ class ContinuousBatchingEngine:
             starts = np.zeros((F_pad,), np.int32)
             cls = np.zeros((F_pad,), np.int32)
             tables = np.zeros((F_pad, self.blocks_per_row), np.int32)
+            wtables = np.zeros_like(tables)
             slots = np.zeros((F_pad,), np.int32)
             for i, (f, take) in enumerate(batch):
                 toks[i, :take] = f.tokens[f.fill_pos : f.fill_pos + take]
@@ -3303,15 +3528,20 @@ class ContinuousBatchingEngine:
                 cls[i] = take
                 tables[i, : len(f.blocks)] = f.blocks
                 slots[i] = f.state_slot
+                if self._win is not None:
+                    self._win.table_of(f.wblocks, wtables[i])
             if self._by_kind:
+                win = self._window_args(wtables)
                 (logits, self.k_pool, self.v_pool, self.ssm_state,
-                 self.conv_state, _, routed) = hybrid.hybrid_fill_chunk(
+                 self.conv_state, _, routed, *win_out) = hybrid.hybrid_fill_chunk(
                     self.params, self.k_pool, self.v_pool, self.ssm_state,
                     self.conv_state, self.cfg, jnp.asarray(toks),
                     jnp.asarray(starts), jnp.asarray(cls),
                     jnp.asarray(tables), jnp.asarray(slots),
-                    use_kernel=self._use_paged_kernel,
+                    use_kernel=self._use_paged_kernel, **win,
                 )
+                if win_out:
+                    self.win_k_pool, self.win_v_pool = win_out[0]
                 out = (logits, self.k_pool, self.v_pool)
                 if self._keep_routed:
                     # on its way to the host while the rows decode: the
@@ -3346,6 +3576,10 @@ class ContinuousBatchingEngine:
         completed, idxs = [], []
         for i, (f, take) in enumerate(batch):
             f.fill_pos += take
+            if self._win is not None:
+                # (the chunk just dispatched reads them first: the device
+                # runs the programs in the order they were dispatched)
+                self._win.release_behind(f.wblocks, f.fill_pos, f.state_slot)
             if f.targets:  # weight-swap refills (no targets) trace as
                 # engine.recompute, not per-chunk fill events
                 self.tracer.event(
@@ -3472,6 +3706,7 @@ class ContinuousBatchingEngine:
         (fresh targets to sample for, rows to activate as they are, the
         sampled tokens and log-probabilities still on the device)."""
         copy_src, copy_dst = [], []
+        wcopy_src, wcopy_dst = [], []
         state_src, state_dst = [], []
         sample_targets: List[Tuple[_Fill, _FillTarget, int]] = []
         activation: List[Tuple[int, int, int, int]] = []  # rid,cur,budget,len
@@ -3481,7 +3716,7 @@ class ContinuousBatchingEngine:
             has_tail = plen % self.page_size != 0
             # the completed prompt's KV enters the radix cache NOW (a
             # retried or sibling request arriving next step already hits)
-            self._cache_insert(f.tokens, f.blocks)
+            self._cache_insert(f.tokens, f.blocks, f.wblocks)
             for t_i, tgt in enumerate(f.targets):
                 if self._stateful and tgt.row_id != f.state_slot:
                     # the prompt's end state, which the fill left in its
@@ -3490,6 +3725,8 @@ class ContinuousBatchingEngine:
                     state_dst.append(tgt.row_id)
                 if t_i == 0:
                     self._set_row_blocks(tgt.row_id, list(f.blocks))
+                    if self._win is not None:
+                        self._win.set_row(tgt.row_id, list(f.wblocks))
                 else:
                     shared = f.blocks[:n_full]
                     self._incref_blocks(shared)
@@ -3497,26 +3734,34 @@ class ContinuousBatchingEngine:
                     if has_tail:
                         tail = self._alloc_blocks(1)
                         while tail is None:
-                            if (
-                                self._prefix_cache is not None
-                                and self._prefix_cache.evict_one()
-                            ):
-                                pass
-                            elif self._evict_parked() is None:
-                                victim = self._pick_preemption_victim(
-                                    exclude=-1
+                            if self._reclaim_one(preempt_but=-1) is None:
+                                raise RuntimeError(
+                                    "pool exhausted distributing a "
+                                    "group fill"
                                 )
-                                if victim is None:
-                                    raise RuntimeError(
-                                        "pool exhausted distributing a "
-                                        "group fill"
-                                    )
-                                self._preempt_row(victim)
                             tail = self._alloc_blocks(1)
                         copy_src.append(f.blocks[n_full])
                         copy_dst.append(tail[0])
                         own += tail
                     self._set_row_blocks(tgt.row_id, own)
+                    if self._win is not None:
+                        # the window's part of the prompt by reference
+                        # (what lies before it is GONE), the tail by copy
+                        wown = list(f.wblocks[:n_full])
+                        self._win.incref(wown)
+                        if has_tail:
+                            wtail = self._alloc_window_blocks(
+                                1, preempt_but=-1
+                            )
+                            if wtail is None:
+                                raise RuntimeError(
+                                    "window pool exhausted distributing "
+                                    "a group fill"
+                                )
+                            wcopy_src.append(f.wblocks[n_full])
+                            wcopy_dst.append(wtail[0])
+                            wown += wtail
+                        self._win.set_row(tgt.row_id, wown)
                 if tgt.resume is not None:
                     row = tgt.resume
                     if self._slo_enabled and row.t_preempt:
@@ -3542,6 +3787,8 @@ class ContinuousBatchingEngine:
             src[: len(copy_src)] = copy_src
             dst[: len(copy_dst)] = copy_dst
             self._copy_pool_blocks(src, dst)
+        if wcopy_src:
+            self._copy_window_blocks(wcopy_src, wcopy_dst)
         if state_src:
             n_pad = 1 << (len(state_src) - 1).bit_length()
             src = np.zeros((n_pad,), np.int32)
@@ -3715,7 +3962,7 @@ class ContinuousBatchingEngine:
                 break
             self._preempted.pop(0)
             fill.state_slot = rid
-            self._set_row_blocks(rid, fill.blocks)
+            self._set_fill_row(rid, fill)
             row.filling = True
             self.rows[rid] = row
             admitted += 1
@@ -3784,7 +4031,7 @@ class ContinuousBatchingEngine:
                     break
                 self._filling.append(fill)
                 fill.state_slot = rid
-                self._set_row_blocks(rid, fill.blocks)
+                self._set_fill_row(rid, fill)
                 prefix_hits += fill.fill_pos > 0
                 # canonical blocks live in target 0's table; refcount
                 # stays 1 until extra targets share them
@@ -3845,34 +4092,41 @@ class ContinuousBatchingEngine:
             host_len = len(row.prompt) + len(row.generated) + 1 + n_pend * W
             need = -(-(host_len + W) // self.page_size)
             need = min(need, self.blocks_per_row)
-            while need > len(self._row_blocks[row_id]):
-                deficit = need - len(self._row_blocks[row_id])
-                blocks = self._alloc_blocks(deficit)
-                if blocks is not None:
-                    self._set_row_blocks(
-                        row_id, self._row_blocks[row_id] + blocks
-                    )
-                    allocated += deficit
+            # the global layers' table, then the window layers'
+            tables = [self._row_blocks] + (
+                [self._win.rows] if self._win is not None else []
+            )
+            while True:
+                short = [need - len(t[row_id]) for t in tables]
+                if max(short) <= 0:
                     break
+                if short[0] > 0:
+                    blocks = self._alloc_blocks(short[0])
+                    if blocks is not None:
+                        self._set_row_blocks(
+                            row_id, self._row_blocks[row_id] + blocks
+                        )
+                        allocated += short[0]
+                        continue
+                else:
+                    blocks = self._win.alloc(short[1])
+                    if blocks is not None:
+                        self._win.rows[row_id].extend(blocks)
+                        self._win.sync_row(row_id)
+                        continue
                 # reclamation tiers: prefix-cache entries (recompute
                 # insurance only — always yield to a live row), then
                 # parked rows, then preemption
-                if (
-                    self._prefix_cache is not None
-                    and self._prefix_cache.evict_one()
-                ):
-                    continue
-                if self._evict_parked() is not None:
-                    continue
-                victim = self._pick_preemption_victim(exclude=row_id)
-                if victim is None:
+                went = self._reclaim_one(preempt_but=row_id)
+                if went is None:
                     # only this row left: it must fit by construction
                     raise RuntimeError(
                         "KV pool exhausted with no evictable rows; "
                         f"pool={self.n_blocks} blocks is too small for "
                         f"kv_cache_len={self.kv_cache_len}"
                     )
-                self._preempt_row(victim)
+                if went != "preempted":
+                    continue
                 # the preemption DRAINED the ring: pending chunks are now
                 # folded into every row's generated, so the counts taken
                 # above would double-charge them — recompute this row's
@@ -3888,6 +4142,17 @@ class ContinuousBatchingEngine:
                 need = min(
                     -(-(host_len + W) // self.page_size),
                     self.blocks_per_row,
+                )
+            if self._win is not None and self.rows[row_id] is row:
+                # what the row is KNOWN to have cached (chunks still in the
+                # ring have only added to it): its window has passed the
+                # pages before that, whoever else still holds them
+                wrow = self._win.rows[row_id]
+                self._win.release_behind(
+                    wrow, len(row.prompt) + len(row.generated) - 1, row_id
+                )
+                self._win.row_pages_max = max(
+                    self._win.row_pages_max, self._win.held(wrow)
                 )
         return allocated, self.preempted_total - preempted0
 
@@ -3918,6 +4183,15 @@ class ContinuousBatchingEngine:
                 best, best_key = row_id, key
         return best
 
+    def _requeue_row(self, row_id: int, row: _Row):
+        """Take a decoding row's pages and put it back in the fill queue
+        (it re-admits with its KV computed again)."""
+        self.active = self.active.at[row_id].set(False)
+        self._release_row(row_id)
+        if self._slo_enabled:
+            row.t_preempt = time.monotonic()  # stall until re-activation
+        self._preempted.append(row)
+
     def _preempt_row(self, row_id: int):
         """Stop decoding a row and reclaim its blocks; it re-admits
         through the fill queue (prefix recompute) when space frees up."""
@@ -3928,11 +4202,7 @@ class ContinuousBatchingEngine:
         row = self.rows[row_id]
         if row is None or row.parked or row.filling:
             return  # the drain finished or parked the victim: done
-        self.active = self.active.at[row_id].set(False)
-        self._release_row(row_id)
-        if self._slo_enabled:
-            row.t_preempt = time.monotonic()  # stall until re-activation
-        self._preempted.append(row)
+        self._requeue_row(row_id, row)
         self.preempted_total += 1
         cls = self._row_priority(row)
         self.preempted_by_class[cls] = (
@@ -3984,6 +4254,11 @@ class ContinuousBatchingEngine:
             tiles_attended=sum(-(-c // tile) for c in ctx),
             tile_tokens=tile,
         )
+        if self._win is not None:
+            # what a window layer's kernel reads of those contexts
+            counts["window_tokens_sum"] = sum(
+                min(c, self.cfg.sliding_window) for c in ctx
+            )
         if self.cfg.is_latent:
             # what the latent kernel reads: ONE entry a position and layer
             # whatever the head count (the same floor as ctx_tokens_sum)
@@ -4005,10 +4280,14 @@ class ContinuousBatchingEngine:
             self._tables = self._upload_tables()
             self._tables_dirty = False
         if self._by_kind:
+            if self._win is not None and self._win.dirty:
+                self._win_tables = jnp.array(self._win.tables_np)
+                self._win.dirty = False
+            win = self._window_args(self._win_tables)
             (
                 self.k_pool, self.v_pool, self.ssm_state, self.conv_state,
                 self.kv_lengths, out_t, out_l, emitted, self.cur_tokens,
-                self.active, self.budgets, _, pairs, routed,
+                self.active, self.budgets, _, pairs, routed, *win_out,
             ) = hybrid.hybrid_decode_chunk(
                 self.params, self.k_pool, self.v_pool, self.ssm_state,
                 self.conv_state, self.cfg, self._tables, self.kv_lengths,
@@ -4016,8 +4295,10 @@ class ContinuousBatchingEngine:
                 self._sample_base_rng, self.chunk_size,
                 self._paged_sample_fn, self._paged_stop_fn,
                 use_kernel=self._use_paged_kernel,
-                max_len=self.kv_cache_len, row_seeds=self.row_seeds,
+                max_len=self.kv_cache_len, row_seeds=self.row_seeds, **win,
             )
+            if win_out:
+                self.win_k_pool, self.win_v_pool = win_out[0]
             self._enqueue_chunk(
                 out_t, out_l, emitted, self.active, self.cur_tokens,
                 snapshot,
@@ -4372,10 +4653,14 @@ class ContinuousBatchingEngine:
             # BOTH park and release is what makes the next turn of a
             # multi-turn conversation — arriving under a fresh qid, on
             # any schedule — prefill only its new suffix.
-            self._cache_insert(
-                (row.prompt + row.generated)[:-1],
-                self._row_blocks[row_id],
-            )
+            cached = (row.prompt + row.generated)[:-1]
+            wrow = None
+            if self._win is not None:
+                # what a parked row keeps, and the cache with it, is its
+                # LAST window
+                wrow = self._win.rows[row_id]
+                self._win.release_behind(wrow, len(cached), row_id)
+            self._cache_insert(cached, self._row_blocks[row_id], wrow)
         if started and park:
             # keep KV resident; the last generated token is the pending
             # cur_token (its KV was never written — see decode_chunk)
@@ -4725,6 +5010,20 @@ class ContinuousBatchingEngine:
                                 rows_preempted=preempted,
                                 pages_live=self.pages_live,
                                 pages_total=self.pages_total,
+                                **(
+                                    {
+                                        "window_pages_live":
+                                            self.window_pages_live,
+                                        "window_pages_total":
+                                            self._win.n_blocks,
+                                        "window_pages_released":
+                                            self.window_pages_released,
+                                        "prefix_refused_window":
+                                            self.prefix_refused_window,
+                                    }
+                                    if self._win is not None
+                                    else {}
+                                ),
                                 **(
                                     {
                                         "state_slots_live":

@@ -25,7 +25,7 @@ class TransformerConfig:
     max_position_embeddings: int = 32768
 
     # architecture knobs
-    activation: str = "silu"  # silu | gelu
+    activation: str = "silu"  # silu | gelu | relu
     norm_type: str = "rms"  # rms | layer
     norm_eps: float = 1e-6
     rotary_base: float = 10000.0
@@ -69,9 +69,21 @@ class TransformerConfig:
     moe_held_experts: Optional[int] = None
 
     # a stack stated by kind (models/hybrid.py): one of "attention" |
-    # "mamba" | "latent" per layer, in the published order; None = every
-    # layer is the attention layer of models/transformer.py
+    # "window" | "mamba" | "latent" per layer, in the published order;
+    # None = every layer is the attention layer of models/transformer.py.
+    # A "window" layer is an attention layer that attends the last
+    # ``sliding_window`` positions (``i - j < sliding_window``) and whose
+    # pages the engine releases once every holder's window has passed them
     layer_types: Optional[Tuple[str, ...]] = None
+    # per layer of a stack stated by kind: whether its attention mixer
+    # ropes q and k (smallthinker ``rope_layout``: the global layers have
+    # no position term); None = every layer follows ``use_rope``
+    rope_layers: Optional[Tuple[bool, ...]] = None
+    # what an expert layer's ROUTER reads: "mlp" = the experts' own input
+    # (the norm of the layer's second half); "attn" = the mixer's input
+    # (the norm of its first half: smallthinker places the router before
+    # attention), while the experts still read the second norm
+    moe_router_input: str = "mlp"
     # the first ``n_dense_layers`` layers of a stack stated by kind have
     # a dense MLP of ``intermediate_dim`` where the others have experts
     # (deepseek_v3 ``first_k_dense_replace``)
@@ -157,11 +169,24 @@ class TransformerConfig:
                 f"n_layers is {self.n_layers}"
             )
             kinds = set(self.layer_types)
-            assert kinds <= {"attention", "mamba", "latent"}, self.layer_types
+            assert kinds <= {"attention", "window", "mamba", "latent"}, (
+                self.layer_types
+            )
             # one page format a pool: per-head K and V, or the latent
-            assert not {"attention", "latent"} <= kinds, self.layer_types
+            assert not (
+                "latent" in kinds and kinds & {"attention", "window"}
+            ), self.layer_types
             if "latent" in kinds:
                 assert self.kv_lora_rank > 0 and self.qk_rope_head_dim > 0
+            if "window" in kinds:
+                assert self.sliding_window and self.sliding_window > 1
+        if self.rope_layers is not None:
+            assert self.layer_types is not None, "rope_layers: a stack by kind"
+            object.__setattr__(
+                self, "rope_layers", tuple(bool(r) for r in self.rope_layers)
+            )
+            assert len(self.rope_layers) == self.n_layers, self.rope_layers
+        assert self.moe_router_input in ("mlp", "attn")
         assert 0 <= self.n_dense_layers <= self.n_layers
         assert self.moe_router in (
             "softmax_topk", "topk_softmax", "sigmoid_group"
@@ -176,7 +201,7 @@ class TransformerConfig:
                 <= self.n_experts
             ), (self.moe_first_expert, self.moe_held_experts, self.n_experts)
         assert self.n_q_heads % self.n_kv_heads == 0
-        assert self.activation in ("silu", "gelu")
+        assert self.activation in ("silu", "gelu", "relu")
         assert self.norm_type in ("rms", "layer")
         assert self.pipe_schedule in ("gpipe", "1f1b"), (
             f"unknown pipe_schedule {self.pipe_schedule!r}"
@@ -213,12 +238,26 @@ class TransformerConfig:
         """Layers that keep per-token KV (every layer of a dense stack)."""
         if self.layer_types is None:
             return self.n_layers
-        return sum(t in ("attention", "latent") for t in self.layer_types)
+        return sum(t != "mamba" for t in self.layer_types)
+
+    @property
+    def n_window_layers(self) -> int:
+        """Layers of a stack stated by kind whose pages follow the
+        window's page rule (a pool and a table of their own)."""
+        if self.layer_types is None:
+            return 0
+        return sum(t == "window" for t in self.layer_types)
 
     @property
     def n_mamba_layers(self) -> int:
         """Layers that keep a recurrent state per sequence."""
         return self.n_layers - self.n_attn_layers
+
+    def layer_ropes(self, layer: int) -> bool:
+        """Whether layer ``layer``'s attention mixer ropes q and k."""
+        if self.rope_layers is None:
+            return self.use_rope
+        return self.rope_layers[layer]
 
     @property
     def is_latent(self) -> bool:

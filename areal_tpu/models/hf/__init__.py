@@ -7,6 +7,7 @@ from areal_tpu.models.hf import (  # noqa: F401
     llama_like,
     mixtral,
     qwen3_moe,
+    smallthinker,
 )
 from areal_tpu.models.hf.registry import (  # noqa: F401
     get_hf_family,

@@ -1,8 +1,9 @@
 """A stack stated by kind: each layer a MIXER (Mamba-2 with a recurrent
-state, attention with paged per-head KV, latent attention with paged
-latent entries) and an MLP (a dense one on the leading
-``cfg.n_dense_layers`` layers, else the expert block this program's share
-of the experts gives) (granitemoehybrid, deepseek_v3).
+state, attention with paged per-head KV over the whole context or over a
+window of it, latent attention with paged latent entries) and an MLP (a
+dense one on the leading ``cfg.n_dense_layers`` layers, else the expert
+block this program's share of the experts gives) (granitemoehybrid,
+deepseek_v3, smallthinker).
 
     h0 = embed_scale * embed[tokens]
     per layer:  h += r * mixer(rmsnorm(h));  m = rmsnorm(h)
@@ -20,6 +21,19 @@ by the layer's number and by its numbers among its kinds.  The dense stack
 of ``transformer.py`` / ``paged.py`` does not go through this module, and
 this module calls their functions where they fit (``_norm``, ``_embed``,
 ``_attn_qkv``, the paged kernels and ``write_kv_runs``).
+
+**Window layers** (kind ``"window"``: attention with ``i - j <
+cfg.sliding_window``) share the attention mixers' parameter stack
+(``params["attn"]``, numbered with them in layer order) and have pools
+and a block table of THEIR OWN (``win_pools``, ``win_tables``): the
+engine releases a window layer's page once every holder's window has
+passed it, while a global layer's page lives as long as its row, so the
+two kinds cannot share one table.  The paged kernel visits a window
+layer's pages from the one that holds the window's first position
+(``ops/paged_attention``, ``window=``).  A layer ropes q and k or not by
+``cfg.layer_ropes`` (smallthinker's global layers have no position term),
+and an expert layer's router reads the mixer's input where
+``cfg.moe_router_input == "attn"``.
 
 **The latent mixer** (MLA) has three forms over one set of equations
 (``c_q = rmsnorm(a W_qa)``, ``[q_nope | q_rope]_i = c_q W_qb``; ``[c_kv |
@@ -65,6 +79,7 @@ its last axis to a lane tile, 43 times its size).
 
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import NamedTuple, Optional, Tuple
 
@@ -98,38 +113,69 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class Run(NamedTuple):
-    kind: str  # the mixer: "attention" | "mamba" | "latent"
+    kind: str  # the mixer: "attention" | "window" | "mamba" | "latent"
     mlp: str  # "dense" | "experts"
     first_layer: int  # number of the run's first layer in the stack
-    first_of_kind: int  # its number among the layers of its mixer kind
+    #: its number in its mixer's parameter stack ("attention" and
+    #: "window" layers share ``params["attn"]``, in layer order)
+    first_of_kind: int
     first_of_mlp: int  # its number among the layers of its MLP kind
     count: int
+    rope: bool = True  # whether its attention mixers rope q and k
+    #: its number among the layers of its POOL (a window layer's among the
+    #: window layers, an attention layer's among those; else first_of_kind)
+    first_in_pool: int = 0
+
+
+def _param_kind(kind: str) -> str:
+    return "attention" if kind == "window" else kind
 
 
 def layer_plan(cfg: TransformerConfig) -> Tuple[Run, ...]:
-    """``cfg.layer_types`` as runs of one (mixer, MLP) pair, in the
-    published order; the first ``cfg.n_dense_layers`` layers have the
+    """``cfg.layer_types`` as runs of one (mixer, MLP, rope) triple, in
+    the published order; the first ``cfg.n_dense_layers`` layers have the
     dense MLP."""
     runs, seen = [], {}
     for l, kind in enumerate(cfg.layer_types):
         mlp = "dense" if l < cfg.n_dense_layers else "experts"
-        if runs and (runs[-1].kind, runs[-1].mlp) == (kind, mlp):
-            runs[-1] = runs[-1]._replace(count=runs[-1].count + 1)
+        rope = cfg.layer_ropes(l)
+        last = runs[-1] if runs else None
+        if last and (last.kind, last.mlp, last.rope) == (kind, mlp, rope):
+            runs[-1] = last._replace(count=last.count + 1)
         else:
             runs.append(
-                Run(kind, mlp, l, seen.get(kind, 0), seen.get(mlp, 0), 1)
+                Run(
+                    kind, mlp, l, seen.get(_param_kind(kind), 0),
+                    seen.get(mlp, 0), 1, rope, seen.get("pool:" + kind, 0),
+                )
             )
-        seen[kind] = seen.get(kind, 0) + 1
+        seen[_param_kind(kind)] = seen.get(_param_kind(kind), 0) + 1
+        seen["pool:" + kind] = seen.get("pool:" + kind, 0) + 1
         seen[mlp] = seen.get(mlp, 0) + 1
     return tuple(runs)
 
 
+def pool_layer_numbers(cfg: TransformerConfig, kind: str) -> np.ndarray:
+    """The numbers, in ``params["attn"]``'s stack, of the layers whose
+    pages live in ``kind``'s pool, in the pool's order."""
+    return np.array(
+        [
+            j for run in layer_plan(cfg) if run.kind == kind
+            for j in range(run.first_of_kind, run.first_of_kind + run.count)
+        ],
+        np.int32,
+    )
+
+
 def _run_indices(run: Run):
-    """``(layer numbers, numbers among the mixer kind, among the MLP
-    kind)`` of a run's layers."""
+    """``(layer numbers, numbers in the mixer's parameter stack, among
+    the MLP kind, in the mixer's pool)`` of a run's layers."""
     return tuple(
         jnp.arange(first, first + run.count)
-        for first in (run.first_layer, run.first_of_kind, run.first_of_mlp)
+        for first in (
+            run.first_layer, run.first_of_kind, run.first_of_mlp,
+            run.first_in_pool,
+        )
     )
 
 
@@ -137,7 +183,16 @@ def _mixer_region(run: Run):
     """The region of a layer's first half (norm, mixer, residual add)."""
     if run.kind == "mamba":
         return region("areal.ssm")
+    if run.kind == "window":
+        return region("areal.attn.window")
     return region("areal.attn")
+
+
+def _rope_cfg(cfg: TransformerConfig, run: Run) -> TransformerConfig:
+    """``cfg`` as ``_attn_qkv`` is to read it for a run's layers."""
+    if cfg.use_rope == run.rope:
+        return cfg
+    return dataclasses.replace(cfg, use_rope=run.rope)
 
 
 def _at(tree, i):
@@ -199,7 +254,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     L, Le, Ld = cfg.n_layers, cfg.n_expert_layers, cfg.n_dense_layers
     Lm = cfg.n_mamba_layers
     Ll = L - Lm if cfg.is_latent else 0
-    La = L - Lm - Ll
+    La = L - Lm - Ll  # attention and window layers: one stack
     D, E, Eh = cfg.hidden_dim, cfg.n_experts, cfg.n_held_experts
     Fe, Fs = cfg.moe_intermediate_dim, cfg.shared_expert_dim
     Hq, Hkv, hd = cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim
@@ -668,10 +723,14 @@ def latent_values_out(cfg: TransformerConfig, ap: Params, o_lat, dtype=None):
 
 
 @region("areal.mlp")
-def _mlp_half(cfg: TransformerConfig, params: Params, run: Run, l, e, x, valid):
+def _mlp_half(
+    cfg: TransformerConfig, params: Params, run: Run, l, e, x, valid, a=None
+):
     """The second half of layer ``l`` (number ``e`` among its MLP kind);
-    returns ``(x, pairs, routed [B, T, K])``, the last two None after a
-    dense MLP: see ``moe.held_moe_mlp``."""
+    ``a``: the mixer's input, which the router reads where
+    ``cfg.moe_router_input == "attn"``.  Returns ``(x, pairs, routed [B,
+    T, K])``, the last two None after a dense MLP: see
+    ``moe.held_moe_mlp``."""
     h = _norm(x, _at(params["layers"]["mlp_norm"], l), cfg)
     if run.mlp == "dense":
         dp = _at(params["dense"], e)
@@ -680,7 +739,8 @@ def _mlp_half(cfg: TransformerConfig, params: Params, run: Run, l, e, x, valid):
         )
         return _res(cfg, x, _proj(dp["down"], hid)), None, None
     out, pairs, routed = held_moe_mlp(
-        cfg, h, _at(params["layers"]["mlp"], e), valid=valid
+        cfg, h, _at(params["layers"]["mlp"], e), valid=valid,
+        router_input=a if cfg.moe_router_input == "attn" else None,
     )
     return _res(cfg, x, out), pairs, routed
 
@@ -734,12 +794,17 @@ def hidden_states(
     valid = seg_ids != 0
     x = _embed(params, cfg, tokens, positions)
     mask = make_attention_mask(seg_ids, positions, seg_ids, positions)
+    mask_window = mask
+    if cfg.n_window_layers:
+        mask_window = make_attention_mask(
+            seg_ids, positions, seg_ids, positions, cfg.sliding_window
+        )
     s0 = jnp.zeros((B, cfg.mamba_d_state, cfg.mamba_d_inner), F32)
     tail0 = jnp.zeros((B, cfg.mamba_d_conv - 1, cfg.mamba_conv_dim), x.dtype)
     scale = _attn_scale(cfg)
     rope_cs = latent_rope_tables(cfg, positions) if cfg.is_latent else None
 
-    def attend(q, k, v):
+    def attend(q, k, v, mask):
         """Causal attention of whole rows: q [B, T, Hq, hd], k [B, T,
         Hkv, hd], v [B, T, Hkv, vd] -> [B, T, Hq * vd]."""
         Hkv = k.shape[2]
@@ -754,30 +819,33 @@ def hidden_states(
         )
         return o.reshape(B, T, -1).astype(x.dtype)
 
-    def mixer(run: Run, x, l, j):
-        h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
+    def mixer(run: Run, h, j):
         if run.kind == "mamba":
             out, _, _ = mamba_chunk(
                 cfg, _at(params["mamba"], j), h, n_valid, s0, tail0
             )
             return out
-        if run.kind == "attention":
+        if run.kind in ("attention", "window"):
             ap = _at(params["attn"], j)
-            q, k, v = _attn_qkv(cfg, {"attn": ap}, h, positions, None)
-            return _proj(ap["o"], attend(q, k, v))
+            q, k, v = _attn_qkv(
+                _rope_cfg(cfg, run), {"attn": ap}, h, positions, None
+            )
+            m = mask_window if run.kind == "window" else mask
+            return _proj(ap["o"], attend(q, k, v, m))
         ap = _at(params["latent"], j)
         q_nope, q_rope = latent_q(cfg, ap, h, rope_cs)
         k, v = latent_expand(cfg, ap, *latent_kv(cfg, ap, h, rope_cs))
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        return _proj(ap["o"], attend(q, k, v))
+        return _proj(ap["o"], attend(q, k, v, mask))
 
     for run in layer_plan(cfg):
 
         def body(x, idx, run=run):
-            l, j, e = idx
+            l, j, e, _ = idx
             with _mixer_region(run):
-                x = _res(cfg, x, mixer(run, x, l, j))
-            x, _, _ = _mlp_half(cfg, params, run, l, e, x, valid)
+                a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
+                x = _res(cfg, x, mixer(run, a, j))
+            x, _, _ = _mlp_half(cfg, params, run, l, e, x, valid, a)
             return x, None
 
         x, _ = scan_layers(body, x, _run_indices(run))
@@ -867,6 +935,7 @@ def _put_conv_tails(conv, slots, tails, keep):
     jax.jit,
     static_argnames=("cfg", "use_kernel"),
     donate_argnums=(1, 2, 3, 4),
+    donate_argnames=("win_pools",),
 )
 def hybrid_fill_chunk(
     params: Params,
@@ -881,11 +950,16 @@ def hybrid_fill_chunk(
     tables: jax.Array,  # [F, MB] pool block ids
     slots: jax.Array,  # [F] state slot of each row
     use_kernel: bool,
+    win_pools: Optional[Tuple[jax.Array, jax.Array]] = None,  # [Lw, NBw, ..]
+    win_tables: Optional[jax.Array] = None,  # [F, MB] window-pool block ids
 ):
     """One prefill chunk for F filling rows of a hybrid stack: the
     hybrid twin of ``paged.paged_fill_chunk``.  An attention layer attends
     the chunk and the row's paged prefix and leaves its KV for ONE pool
-    write after the stack; a Mamba layer starts from the row's slot
+    write after the stack; a window layer likewise under ``i - j <
+    cfg.sliding_window``, over its own pools and table (``win_pools``,
+    ``win_tables``: the pages before the window's first position are not
+    read, and need not be held); a Mamba layer starts from the row's slot
     (from zero where ``starts`` is 0: a slot is never cleared by a pass
     of its own) and leaves the state after the chunk's last valid token
     there.  The conv tails are read before the stack and written after
@@ -897,8 +971,9 @@ def hybrid_fill_chunk(
     expanded and the paged prefix in the absorbed form, and leaves its
     latent entries for the same one write.  Returns ``(last_logits [F,
     V], k_pool, v_pool, ssm, conv, pairs [moe.n_pair_counts], routed [Le,
-    F, C, K])``: the last is every EXPERT layer's routed experts of
-    every position (``moe.held_moe_mlp``)."""
+    F, C, K])`` and, given ``win_pools``, those last: ``routed`` is every
+    EXPERT layer's routed experts of every position
+    (``moe.held_moe_mlp``)."""
     C = tokens.shape[1]
     valid = jnp.arange(C)[None, :] < chunk_lens[:, None]  # [F, C]
     row_valid = chunk_lens > 0
@@ -914,6 +989,13 @@ def hybrid_fill_chunk(
     plan = paged._prefix_plan(
         C, cfg.n_q_heads, k_pool, tables, read_lens, use_kernel
     )
+    window = cfg.sliding_window if cfg.n_window_layers else None
+    if window:
+        mask_chunk_win = mask_chunk & (iot[:, None] - iot[None, :] < window)
+        plan_win = paged._prefix_plan(
+            C, cfg.n_q_heads, win_pools[0], win_tables, read_lens,
+            use_kernel, window=window,
+        )
 
     latent = cfg.is_latent
     rope_cs = latent_rope_tables(cfg, positions) if latent else None
@@ -923,7 +1005,7 @@ def hybrid_fill_chunk(
                 fresh[None, :, None, None], 0, _get_conv_tails(conv, slots)
             )  # [Lm, F, K-1, conv_dim]
 
-    def mamba_mixer(x, ssm, l, j, tail0):
+    def mamba_mixer(h, ssm, j, tail0):
         mp = _at(params["mamba"], j)
         if use_kernel:
             s0 = ssm_ops.ssm_state_rows(
@@ -932,30 +1014,33 @@ def hybrid_fill_chunk(
         else:
             s0 = _get_state_rows(ssm, j, slots)
         s0 = jnp.where(fresh[:, None, None], 0.0, s0)
-        out, s1, tail1 = mamba_chunk(
-            cfg, mp, _norm(x, _at(params["layers"]["attn_norm"], l), cfg),
-            chunk_lens, s0, tail0,
-        )
+        out, s1, tail1 = mamba_chunk(cfg, mp, h, chunk_lens, s0, tail0)
         return out, _put_state_rows(ssm, j, slots, s1, row_valid), tail1
 
-    def attn_mixer(x, l, j):
+    def attn_mixer(run, h, j, p):
         ap = _at(params["attn"], j)
-        h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
-        q, k, v = _attn_qkv(cfg, {"attn": ap}, h, positions, None)
-        prefix = paged._prefix_partials(
-            q, k_pool, v_pool, tables, read_lens, j, use_kernel,
-            plan=plan, scale=scale,
+        q, k, v = _attn_qkv(
+            _rope_cfg(cfg, run), {"attn": ap}, h, positions, None
         )
-        attn = paged.chunk_attention(
-            q, k, v, prefix, mask_chunk, scale, x.dtype
-        )
+        if run.kind == "window":
+            prefix = paged._prefix_partials(
+                q, *win_pools, win_tables, read_lens, p, use_kernel,
+                plan=plan_win, scale=scale, window=window,
+            )
+            mask = mask_chunk_win
+        else:
+            prefix = paged._prefix_partials(
+                q, k_pool, v_pool, tables, read_lens, p, use_kernel,
+                plan=plan, scale=scale,
+            )
+            mask = mask_chunk
+        attn = paged.chunk_attention(q, k, v, prefix, mask, scale, h.dtype)
         return _proj(ap["o"], attn), (
             k.astype(k_pool.dtype), v.astype(v_pool.dtype)
         )
 
-    def latent_mixer(x, l, j):
+    def latent_mixer(h, j):
         ap = _at(params["latent"], j)
-        h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
         q_nope, q_rope = latent_q(cfg, ap, h, rope_cs)
         c_kv, k_rope = latent_kv(cfg, ap, h, rope_cs)
         k, v = latent_expand(cfg, ap, c_kv, k_rope)
@@ -967,39 +1052,42 @@ def hybrid_fill_chunk(
         attn = paged.chunk_attention(
             jnp.concatenate([q_nope, q_rope], axis=-1), k, v,
             (latent_values_out(cfg, ap, acc), m, lsum),
-            mask_chunk, scale, x.dtype,
+            mask_chunk, scale, h.dtype,
         )
         entry = latent_entry(cfg, c_kv, k_rope)[:, :, None, :]
         return _proj(ap["o"], attn), (entry.astype(k_pool.dtype),)
 
     carry = (x, ssm, _pairs_zero(cfg))
-    window_kv, tails1, routed = [], [], []
+    chunk_kv, chunk_kv_win, tails1, routed = [], [], [], []
     for run in layer_plan(cfg):
-        l_idx, j_idx, e_idx = _run_indices(run)
+        l_idx, j_idx, e_idx, p_idx = _run_indices(run)
 
         def body(carry, inp, run=run):
             x, ssm, pairs = carry
-            l, j, e = inp[:3]
+            l, j, e, p = inp[:4]
             with _mixer_region(run):
+                a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
                 if run.kind == "mamba":
-                    out, ssm, kept = mamba_mixer(x, ssm, l, j, inp[3])
-                elif run.kind == "attention":
-                    out, kept = attn_mixer(x, l, j)
+                    out, ssm, kept = mamba_mixer(a, ssm, j, inp[4])
+                elif run.kind == "latent":
+                    out, kept = latent_mixer(a, j)
                 else:
-                    out, kept = latent_mixer(x, l, j)
+                    out, kept = attn_mixer(run, a, j, p)
                 x = _res(cfg, x, out)
-            x, p, r = _mlp_half(cfg, params, run, l, e, x, valid)
-            return (x, ssm, _add_pairs(pairs, p)), (kept, r)
+            x, n, r = _mlp_half(cfg, params, run, l, e, x, valid, a)
+            return (x, ssm, _add_pairs(pairs, n)), (kept, r)
 
-        xs = (l_idx, j_idx, e_idx)
+        xs = (l_idx, j_idx, e_idx, p_idx)
         if run.kind == "mamba":
             of_kind = slice(run.first_of_kind, run.first_of_kind + run.count)
             xs += (tails0[of_kind],)
         carry, (kept, r) = scan_layers(body, carry, xs)
         if run.kind == "mamba":
             tails1.append(kept)
+        elif run.kind == "window":
+            chunk_kv_win.append(kept)
         else:
-            window_kv.append(kept)
+            chunk_kv.append(kept)
         if r is not None:
             routed.append(r)
     x, ssm, pairs = carry
@@ -1007,21 +1095,29 @@ def hybrid_fill_chunk(
         conv = _put_conv_tails(
             conv, slots, jnp.concatenate(tails1, axis=0), row_valid
         )
-    if window_kv:
-        vals = tuple(jnp.concatenate(t, axis=0) for t in zip(*window_kv))
-        pools = (k_pool,) if latent else (k_pool, v_pool)
-        # the pool is written only after every layer has read it: without
-        # the barrier a run of ONE layer is inlined, the kernel reads the
-        # donated pool while the write loop wants it in place, and XLA
-        # settles that with two copies of each pool
-        x, pools, vals = jax.lax.optimization_barrier((x, pools, vals))
+    pools = (k_pool,) if latent else (k_pool, v_pool)
+    vals = tuple(jnp.concatenate(t, axis=0) for t in zip(*chunk_kv))
+    vals_win = tuple(jnp.concatenate(t, axis=0) for t in zip(*chunk_kv_win))
+    # the pools are written only after every layer has read them: without
+    # the barrier a run of ONE layer is inlined, the kernel reads the
+    # donated pool while the write loop wants it in place, and XLA
+    # settles that with two copies of each pool
+    x, pools, vals, win_pools, vals_win = jax.lax.optimization_barrier(
+        (x, pools, vals, win_pools, vals_win)
+    )
+    if vals:
         pools = paged.write_kv_runs(pools, vals, tables, starts, chunk_lens)
-        k_pool, v_pool = pools + ((v_pool,) if latent else ())
+    if vals_win:
+        win_pools = paged.write_kv_runs(
+            win_pools, vals_win, win_tables, starts, chunk_lens
+        )
+    k_pool, v_pool = pools + ((v_pool,) if latent else ())
     logits = _logits(params, cfg, paged.last_valid(x, chunk_lens))[:, 0]
-    return (
+    out = (
         logits, k_pool, v_pool, ssm, conv, pairs,
         jnp.concatenate(routed, axis=0),
     )
+    return out if win_pools is None else out + (win_pools,)
 
 
 @partial(
@@ -1030,6 +1126,7 @@ def hybrid_fill_chunk(
         "cfg", "chunk_size", "use_kernel", "max_len", "sample_fn", "stop_fn",
     ),
     donate_argnums=(1, 2, 3, 4),
+    donate_argnames=("win_pools",),
 )
 def hybrid_decode_chunk(
     params: Params,
@@ -1050,20 +1147,28 @@ def hybrid_decode_chunk(
     use_kernel: bool,
     max_len: int,
     row_seeds: Optional[jax.Array] = None,
+    win_pools: Optional[Tuple[jax.Array, jax.Array]] = None,  # [Lw, NBw, ..]
+    win_tables: Optional[jax.Array] = None,  # [B, MB] window-pool block ids
 ):
     """Up to ``chunk_size`` tokens for all active rows of a hybrid stack:
     the hybrid twin of ``paged.paged_decode_chunk`` (same window design
     for the attention layers' KV, same outputs), with every Mamba layer's
-    state advanced in place for the rows live at each step.  Returns
-    ``(k_pool, v_pool, ssm, conv, lengths, out_t, out_l, emitted, cur,
-    active, budgets, rng, pairs [moe.n_pair_counts], routed [W, Le, K,
-    B])``: the last is every EXPERT layer's routed experts at each step, for the position
+    state advanced in place for the rows live at each step.  A window
+    layer reads its own pools through its own table (``win_pools``,
+    ``win_tables``) from the page that holds ``length - sliding_window +
+    1`` on; the chunk's own tokens lie inside every window (``chunk_size
+    < sliding_window``).  Returns ``(k_pool, v_pool, ssm, conv, lengths,
+    out_t, out_l, emitted, cur, active, budgets, rng, pairs
+    [moe.n_pair_counts], routed [W, Le, K, B])`` and, given
+    ``win_pools``, those last: ``routed`` is every EXPERT layer's routed
+    experts at each step, for the position
     the step READ (row b's entry of step i means something where
     ``emitted[b, i]``; the row axis last, so that the array pads little
     on the chip)."""
     B = cur_tokens.shape[0]
     W = chunk_size
-    La, _, Hkv, _, hd = k_pool.shape
+    _, _, Hkv, _, hd = k_pool.shape
+    La = cfg.n_attn_layers  # attention, window or latent: the chunk's KV
     latent = cfg.is_latent
     base_lens = lengths
     read_lens = jnp.where(active, base_lens, 0)
@@ -1071,6 +1176,13 @@ def hybrid_decode_chunk(
     plan = paged._prefix_plan(
         1, cfg.n_q_heads, k_pool, tables, read_lens, use_kernel
     )
+    window = cfg.sliding_window if cfg.n_window_layers else None
+    if window:
+        assert W < window, (W, window)
+        plan_win = paged._prefix_plan(
+            1, cfg.n_q_heads, win_pools[0], win_tables, read_lens,
+            use_kernel, window=window,
+        )
     # the chunk's own KV (latent layers: its latent entries, which are
     # keys and values both), one pool write after the chunk
     wk = jnp.zeros((La, W, B, Hkv, hd), k_pool.dtype)
@@ -1086,26 +1198,35 @@ def hybrid_decode_chunk(
         live = active[:, None]
         rope_cs = latent_rope_tables(cfg, positions) if latent else None
 
-        def attn_mixer(x, wk, wv, l, j):
+        def attn_mixer(run, h, wk, wv, j, p):
             ap = _at(params["attn"], j)
-            h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
-            q, k, v = _attn_qkv(cfg, {"attn": ap}, h, positions, None)
-            wk, wv = window_put(wk, k, j, i), window_put(wv, v, j, i)
-            prefix = paged._prefix_partials(
-                q, k_pool, v_pool, tables, read_lens, j, use_kernel,
-                plan=plan, scale=scale,
+            q, k, v = _attn_qkv(
+                _rope_cfg(cfg, run), {"attn": ap}, h, positions, None
             )
+            wk, wv = window_put(wk, k, j, i), window_put(wv, v, j, i)
+            if run.kind == "window":
+                # the plan is of the chunk's start; this step's queries
+                # stand i positions past it
+                prefix = paged._prefix_partials(
+                    q, *win_pools, win_tables, read_lens, p, use_kernel,
+                    plan=plan_win, scale=scale, window=window,
+                    window_shift=i,
+                )
+            else:
+                prefix = paged._prefix_partials(
+                    q, k_pool, v_pool, tables, read_lens, p, use_kernel,
+                    plan=plan, scale=scale,
+                )
             attn = paged.window_attention(
                 q,
                 jax.lax.dynamic_index_in_dim(wk, j, 0, keepdims=False),
                 jax.lax.dynamic_index_in_dim(wv, j, 0, keepdims=False),
-                prefix, mask_win, scale, x.dtype,
+                prefix, mask_win, scale, h.dtype,
             )
             return _proj(ap["o"], attn), wk, wv
 
-        def latent_mixer(x, wk, l, j):
+        def latent_mixer(h, wk, j):
             ap = _at(params["latent"], j)
-            h = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
             q_nope, q_rope = latent_q(cfg, ap, h, rope_cs)
             c_kv, k_rope = latent_kv(cfg, ap, h, rope_cs)
             wk = window_put(
@@ -1119,7 +1240,7 @@ def hybrid_decode_chunk(
             wk_j = jax.lax.dynamic_index_in_dim(wk, j, 0, keepdims=False)
             o_lat = paged.window_attention(
                 q, wk_j, wk_j[..., : cfg.kv_lora_rank], prefix, mask_win,
-                scale, x.dtype,
+                scale, h.dtype,
             ).reshape(B, 1, cfg.n_q_heads, cfg.kv_lora_rank)
             attn = latent_values_out(cfg, ap, o_lat).reshape(B, 1, -1)
             return _proj(ap["o"], attn), wk
@@ -1129,23 +1250,21 @@ def hybrid_decode_chunk(
 
             def body(carry, idx, run=run):
                 x, wk, wv, ssm, conv, pairs = carry
-                l, j, e = idx
+                l, j, e, p = idx
                 with _mixer_region(run):
+                    a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
                     if run.kind == "mamba":
                         out, ssm, conv = mamba_step(
-                            cfg, _at(params["mamba"], j),
-                            _norm(
-                                x, _at(params["layers"]["attn_norm"], l), cfg
-                            ),
+                            cfg, _at(params["mamba"], j), a,
                             ssm, conv, j, active, use_kernel,
                         )
-                    elif run.kind == "attention":
-                        out, wk, wv = attn_mixer(x, wk, wv, l, j)
+                    elif run.kind == "latent":
+                        out, wk = latent_mixer(a, wk, j)
                     else:
-                        out, wk = latent_mixer(x, wk, l, j)
+                        out, wk, wv = attn_mixer(run, a, wk, wv, j, p)
                     x = _res(cfg, x, out)
-                x, p, r = _mlp_half(cfg, params, run, l, e, x, live)
-                return (x, wk, wv, ssm, conv, _add_pairs(pairs, p)), (
+                x, n, r = _mlp_half(cfg, params, run, l, e, x, live, a)
+                return (x, wk, wv, ssm, conv, _add_pairs(pairs, n)), (
                     None if r is None else r[:, 0].T
                 )
 
@@ -1181,9 +1300,19 @@ def hybrid_decode_chunk(
     pools, vals = (k_pool, v_pool), (wk.swapaxes(1, 2), wv.swapaxes(1, 2))
     if latent:
         pools, vals = pools[:1], vals[:1]
-    pools = paged.write_kv_runs(
-        pools, vals, tables, base_lens, lengths_ - base_lens
-    )
+    counts = lengths_ - base_lens
+    if window:
+        # the chunk's KV holds both kinds' layers, in the order of their
+        # parameter stack: each pool takes its own
+        of_win = pool_layer_numbers(cfg, "window")
+        win_pools = paged.write_kv_runs(
+            win_pools, tuple(v[of_win] for v in vals), win_tables,
+            base_lens, counts,
+        )
+        of_global = pool_layer_numbers(cfg, "attention")
+        vals = tuple(v[of_global] for v in vals)
+    pools = paged.write_kv_runs(pools, vals, tables, base_lens, counts)
     k_pool, v_pool = pools + ((v_pool,) if latent else ())
-    return (k_pool, v_pool, ssm, conv, lengths_, out_t, out_l, emitted, cur,
-            active, budgets, rng, pairs, routed)
+    out = (k_pool, v_pool, ssm, conv, lengths_, out_t, out_l, emitted, cur,
+           active, budgets, rng, pairs, routed)
+    return out if win_pools is None else out + (win_pools,)

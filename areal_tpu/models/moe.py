@@ -38,6 +38,7 @@ import numpy as np
 
 from areal_tpu.models import quantize
 from areal_tpu.models.config import TransformerConfig
+from areal_tpu.models.transformer import _activation
 from areal_tpu.observability.tracing import region
 
 
@@ -101,8 +102,7 @@ def local_expert_compute(
     group_sizes = jnp.bincount(key, length=e_held).astype(jnp.int32)
     gate = jax.lax.ragged_dot(xs, gate_w, group_sizes)
     up = jax.lax.ragged_dot(xs, up_w, group_sizes)
-    act = jax.nn.silu(gate) if act_kind == "silu" else jax.nn.gelu(gate)
-    out = jax.lax.ragged_dot(act * up, down_w, group_sizes)
+    out = jax.lax.ragged_dot(_activation(gate, act_kind) * up, down_w, group_sizes)
     return out[inv_order]
 
 
@@ -252,13 +252,8 @@ def _routed_experts(cfg, dtype, x, topk_probs, topk_idx, p, mesh):
 
         gate = jax.lax.ragged_dot(xs, gate_w, group_sizes)
         up = jax.lax.ragged_dot(xs, up_w, group_sizes)
-        act = (
-            jax.nn.silu(gate)
-            if cfg.activation == "silu"
-            else jax.nn.gelu(gate)
-        )
         expert_out = jax.lax.ragged_dot(
-            act * up, down_w, group_sizes
+            _activation(gate, cfg.activation) * up, down_w, group_sizes
         )  # [N*K, D]
         # combine: unsort, weight, sum over K
         expert_out = expert_out[inv_order].reshape(N, K, D)
@@ -288,8 +283,7 @@ def dense_expert_compute(x, w_tok, gate_w, up_w, down_w, act_kind: str):
     a plain dot reads the slice in place."""
     g = jnp.einsum("nd,efd->enf", x, gate_w)
     u = jnp.einsum("nd,efd->enf", x, up_w)
-    act = jax.nn.silu(g) if act_kind == "silu" else jax.nn.gelu(g)
-    hid = act * u * w_tok.T[:, :, None].astype(x.dtype)
+    hid = _activation(g, act_kind) * u * w_tok.T[:, :, None].astype(x.dtype)
     return jnp.einsum("enf,efd->nd", hid, down_w)
 
 
@@ -351,13 +345,16 @@ def held_moe_mlp(
     h: jax.Array,  # [B, T, D]
     p: Dict[str, Any],  # one layer's {"router", "experts"[, "shared"]}
     valid: Optional[jax.Array] = None,  # [B, T] bool
+    router_input: Optional[jax.Array] = None,  # [B, T, D]; None: ``h``
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The expert layer of a program that is TOLD which experts it holds
     (``cfg.moe_first_expert``, ``cfg.n_held_experts``; ``p["experts"]``
     holds exactly those, gate, up and down each ``[E_held, F, D]``):
     routes over all ``n_experts``, computes its own
     experts' part of the result, adds the shared expert (every token,
-    weight 1).  Returns ``(out [B, T, D], pairs [n_pair_counts] int32,
+    weight 1).  The ROUTER reads ``router_input`` where one is given (a
+    model whose router stands before attention routes on the mixer's
+    input) while the experts read ``h``.  Returns ``(out [B, T, D], pairs [n_pair_counts] int32,
     expert ids [B, T, K] int32)``: the valid (token, k) pairs each held
     expert took and, after them, those routed to experts held elsewhere
     (a group-limited router appends the (token, chosen group) pairs
@@ -367,7 +364,8 @@ def held_moe_mlp(
     parity check that follows the server's choices)."""
     B, T, D = h.shape
     x = h.reshape(-1, D)
-    w, idx, _, groups = route(cfg, x, p["router"])
+    routed_on = x if router_input is None else router_input.reshape(-1, D)
+    w, idx, _, groups = route(cfg, routed_on, p["router"])
     first, held = cfg.moe_first_expert, cfg.n_held_experts
     local = idx - first
     with region("areal.moe.route"):
@@ -405,10 +403,9 @@ def held_moe_mlp(
             sh = p["shared"]
             g = x @ quantize.leaf_weight(sh["gate"], h.dtype)
             u = x @ quantize.leaf_weight(sh["up"], h.dtype)
-            act = (
-                jax.nn.silu(g) if cfg.activation == "silu" else jax.nn.gelu(g)
+            out = out + (_activation(g, cfg.activation) * u) @ quantize.leaf_weight(
+                sh["down"], h.dtype
             )
-            out = out + (act * u) @ quantize.leaf_weight(sh["down"], h.dtype)
     return (
         out.reshape(B, T, D),
         _held_pairs(cfg, local, groups, valid),

@@ -99,16 +99,22 @@ def latent_page_width(cfg: TransformerConfig) -> int:
 
 
 def pool_shapes(
-    cfg: TransformerConfig, n_blocks: int, block_size: int
+    cfg: TransformerConfig, n_blocks: int, block_size: int,
+    layers: Optional[int] = None,
 ) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
-    """Shapes of the (k, v) block pools.  Per-head pages: both ``[L, NB,
+    """Shapes of the (k, v) block pools over ``layers`` layers (None: the
+    layers that keep per-token KV for as long as their row lives, i.e.
+    all but a stack's "window" layers, which have a pool of their own:
+    ``layers=cfg.n_window_layers``).  Per-head pages: both ``[L, NB,
     Hkv, BS, hd]``.  LATENT pages (``cfg.is_latent``): ONE pool ``[L,
     NB, 1, BS, latent_page_width]`` whose row is a token's ``[c_kv |
     k_rope | 0]``, read as keys and (its first ``kv_lora_rank`` columns)
     as values; the V pool has width 0, so that everything that moves
     pages moves a latent pool as it moves any other and the V side
     holds no byte."""
-    head = (cfg.n_attn_layers, n_blocks)
+    if layers is None:
+        layers = cfg.n_attn_layers - cfg.n_window_layers
+    head = (layers, n_blocks)
     if cfg.is_latent:
         return (
             head + (1, block_size, latent_page_width(cfg)),
@@ -119,13 +125,14 @@ def pool_shapes(
 
 
 def pool_zeros(
-    cfg: TransformerConfig, n_blocks: int, block_size: int, dtype=None
+    cfg: TransformerConfig, n_blocks: int, block_size: int, dtype=None,
+    layers: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Allocate the (k, v) block pools (:func:`pool_shapes`) —
     PAGE-major so one page is one contiguous HBM extent (the kernel reads
     a page's every head in a single DMA)."""
     dtype = dtype or jnp.dtype(cfg.dtype)
-    k_shape, v_shape = pool_shapes(cfg, n_blocks, block_size)
+    k_shape, v_shape = pool_shapes(cfg, n_blocks, block_size, layers)
     return jnp.zeros(k_shape, dtype), jnp.zeros(v_shape, dtype)
 
 
@@ -140,6 +147,7 @@ def alloc_kv_pool(
     block_size: int,
     kv_cache_dtype: str = "auto",
     dtype=None,
+    layers: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array], Optional[jax.Array]]:
     """Allocate the paged KV storage: ``(k_pool, v_pool, k_scale,
     v_scale)``.
@@ -150,7 +158,7 @@ def alloc_kv_pool(
     so the storage cost per cached token-head drops from ``2 * hd *
     itemsize(model dtype)`` to ``2 * (hd + 4)`` bytes."""
     if kv_cache_dtype == "auto":
-        k, v = pool_zeros(cfg, n_blocks, block_size, dtype=dtype)
+        k, v = pool_zeros(cfg, n_blocks, block_size, dtype=dtype, layers=layers)
         return k, v, None, None
     if kv_cache_dtype != "int8":
         raise ValueError(
@@ -162,7 +170,7 @@ def alloc_kv_pool(
             "scale a (block, head, slot) would cover a token's whole "
             "[c_kv | k_rope] row, whose two parts differ in scale"
         )
-    shape, _ = pool_shapes(cfg, n_blocks, block_size)
+    shape, _ = pool_shapes(cfg, n_blocks, block_size, layers)
     sshape = shape[:-1]
     return (
         jnp.zeros(shape, jnp.int8),
@@ -178,13 +186,14 @@ def kv_pool_layout_bytes(
     block_size: int,
     kv_cache_dtype: str = "auto",
     dtype=None,
+    layers: Optional[int] = None,
 ) -> Tuple[int, int]:
     """``(pool_bytes, scale_bytes)`` that :func:`alloc_kv_pool` with the
     same arguments will allocate — pure arithmetic, no device memory.
     The HBM ledger sizes its ``kv_pool``/``kv_scales`` attributions from
     this (the allocation itself runs under jit, where a host-side ledger
     call cannot live); ``scale_bytes`` is 0 for fp pools."""
-    k_shape, v_shape = pool_shapes(cfg, n_blocks, block_size)
+    k_shape, v_shape = pool_shapes(cfg, n_blocks, block_size, layers)
     itemsize = jnp.dtype(dtype or cfg.dtype).itemsize
     if cfg.is_latent:
         return int(np.prod(k_shape)) * itemsize, 0
@@ -229,12 +238,13 @@ def _shard_pool_shape(k_pool, mesh=None, kv_axis=None):
 
 def _prefix_plan(
     n_queries, n_q_heads, k_pool, tables, lengths, use_kernel,
-    mesh=None, kv_axis=None, quantized=False,
+    mesh=None, kv_axis=None, quantized=False, window=None,
 ):
     """The paged kernel's page plan for every :func:`_prefix_partials`
-    call over these ``tables`` and ``lengths``: made ONCE, before the
-    layer scan (and the decode chunk's step loop), because XLA leaves it
-    inside them otherwise.  None without the kernel."""
+    call over these ``tables`` and ``lengths`` (under this ``window``):
+    made ONCE, before the layer scan (and the decode chunk's step loop),
+    because XLA leaves it inside them otherwise.  None without the
+    kernel."""
     if not use_kernel:
         return None
     shards, shard_shape = _shard_pool_shape(k_pool, mesh, kv_axis)
@@ -242,7 +252,7 @@ def _prefix_plan(
         n_queries, n_q_heads // shards, shard_shape, k_pool.dtype,
         quantized, tables.shape[1],
     )
-    return plan_pages(tables, lengths, shard_shape[1], group)
+    return plan_pages(tables, lengths, shard_shape[1], group, window)
 
 
 def kernel_tile_tokens(k_pool, mesh=None, kv_axis=None) -> int:
@@ -255,7 +265,7 @@ def kernel_tile_tokens(k_pool, mesh=None, kv_axis=None) -> int:
 def _prefix_partials(
     q, k_pool, v_pool, tables, lengths, layer, use_kernel,
     mesh=None, kv_axis=None, k_scale=None, v_scale=None, plan=None,
-    scale=None, value_dim=None,
+    scale=None, value_dim=None, window=None, window_shift=None,
 ):
     """Paged-attention partials over each row's cached prefix.  ``q`` is
     [B, Q, Hq, hd]; returns (acc, m, l) with Q query tokens per row.
@@ -263,6 +273,9 @@ def _prefix_partials(
     model's softmax scale where it is not ``1/sqrt(hd)`` (None).
     ``value_dim``: LATENT pages, whose first ``value_dim`` columns are
     the values (``v_pool`` is then not read; ``acc`` is that wide).
+    ``window``: query ``t`` of a row attends the cached positions ``j``
+    with ``length + window_shift + t - j < window`` only (``window_shift``:
+    a decode chunk's step, over the plan made at its start).
 
     ``k_scale``/``v_scale`` mark an int8-quantized pool: both the kernel
     and the jnp reference dequantize (multiply by the per-(block, head,
@@ -282,9 +295,11 @@ def _prefix_partials(
                 q, k_pool, None if value_dim else v_pool, tables, lengths,
                 layer=layer, interpret=interp, k_scale=k_scale,
                 v_scale=v_scale, plan=plan, scale=scale,
-                value_dim=value_dim,
+                value_dim=value_dim, window=window,
+                window_shift=window_shift,
             )
         assert value_dim is None, "latent pages under a serving mesh"
+        assert window is None, "a windowed call under a serving mesh"
         from jax.sharding import PartitionSpec as P
 
         layered = k_pool.ndim == 5
@@ -342,7 +357,8 @@ def _prefix_partials(
         ksl = jax.lax.dynamic_index_in_dim(k_scale, layer, 0, keepdims=False)
         vsl = jax.lax.dynamic_index_in_dim(v_scale, layer, 0, keepdims=False)
     return reference_paged_partials(
-        q, kl, vl, tables, lengths, k_scale=ksl, v_scale=vsl, scale=scale
+        q, kl, vl, tables, lengths, k_scale=ksl, v_scale=vsl, scale=scale,
+        window=window, window_shift=0 if window_shift is None else window_shift,
     )
 
 

@@ -298,7 +298,7 @@ def rope(x: jax.Array, positions: jax.Array, base: float) -> jax.Array:
 def _activation(x, kind: str):
     if kind == "silu":
         return jax.nn.silu(x)
-    return jax.nn.gelu(x)
+    return jax.nn.relu(x) if kind == "relu" else jax.nn.gelu(x)
 
 
 @region("areal.attn")

@@ -1131,7 +1131,9 @@ TRACE_TABLE = [
         "Every decoding row's table extended to cover the next chunk "
         "(counts: blocks_allocated, rows_preempted, and pages_live and "
         "pages_total after it; for a model with recurrent state also "
-        "state_slots_live, state_slots_total)",
+        "state_slots_live, state_slots_total; with window layers also "
+        "window_pages_live, window_pages_total, window_pages_released "
+        "and prefix_refused_window)",
     ),
     TraceSpec(
         "areal.engine.decode.dispatch",
@@ -1143,7 +1145,8 @@ TRACE_TABLE = [
         "sum of ceil(context / tile_tokens), tile_tokens = the unit the "
         "paged kernel copies a page in; for latent "
         "pages latent_ctx_tokens_sum and latent_pages_attended = the "
-        "same context and pages, ONE entry a position and layer)",
+        "same context and pages, ONE entry a position and layer; with "
+        "window layers window_tokens_sum = sum of min(context, window))",
     ),
     TraceSpec(
         "areal.engine.harvest.wait",
@@ -1239,6 +1242,13 @@ TRACE_TABLE = [
         "the low-rank projections and the absorbed query), rope, the "
         "attention call (paged and flash kernels, chunk and cache "
         "attention), the output projection and its residual add",
+    ),
+    TraceSpec(
+        "areal.attn.window",
+        "region",
+        "The same half of a WINDOW layer of a stack stated by kind: its "
+        "paged kernel reads its own pools from the window's first page "
+        "(paged_window_decode / paged_window_fill)",
     ),
     TraceSpec(
         "areal.kv_write",

@@ -80,7 +80,7 @@ def _dot_bf16(a, b, dims):
 
 
 def softmax_block_update(
-    q, k, v, s_acc, s_m, s_l, *, base, length, scale
+    q, k, v, s_acc, s_m, s_l, *, base, length, scale, first=None
 ):
     """One KV block's online-softmax update over (rows, hd) queries —
     the SINGLE definition of the decode-attention numerics, used by both
@@ -98,6 +98,13 @@ def softmax_block_update(
     * anything else (an int8 pool dequantized to float32, a float32
       pool, float32 queries): float32 operands at HIGHEST precision.
 
+    ``first`` ((rows, 1) int32, or None): the first position each query
+    row attends (a sliding window); positions before it are masked like
+    those at and past ``length``, and their probabilities are set to 0
+    (a block may then hold no position at all for a row, whose running
+    maximum stays at its initial value: ``exp(s - m)`` of a masked entry
+    would read 1 there).
+
     Decode is NOT so HBM-bound that HIGHEST's passes are free: widening
     every bf16 K and V tile to float32 and splitting it back into bf16
     terms held the paged kernel to 458 GB/s on a v5e with every page
@@ -111,12 +118,17 @@ def softmax_block_update(
     bf16 = all(x.dtype == jnp.bfloat16 for x in (q, k, v))
     s = (_dot_bf16 if bf16 else _dot_f32)(q, k, nt) * scale  # (rows, BS)
     pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < length, s, _NEG_INF)
+    valid = pos < length
+    if first is not None:
+        valid &= pos >= first
+    s = jnp.where(valid, s, _NEG_INF)
 
     m_prev = s_m[:, 0]  # (rows,)
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
     alpha = jnp.exp(m_prev - m_cur)
     p = jnp.exp(s - m_cur[:, None])  # (rows, BS)
+    if first is not None:
+        p = jnp.where(valid, p, 0.0)
     if bf16:
         rows = p.shape[0]
         stacked = _dot_bf16(jnp.concatenate(_split_bf16(p), axis=0), v, nn)

@@ -52,6 +52,18 @@ Kernel shape:
   prefix attention) and every query row of a (b, qb) cell shares one
   copied KV page — all KV heads of a page ride one copy.
 
+**A window** (``window=W``): every query attends the last ``W - 1``
+cached positions before it and not the whole prefix (query ``t`` of a
+row of ``length`` cached positions stands at position ``length + t`` and
+attends ``j`` with ``length + t - j < W``; what lies inside its own
+chunk is the caller's).  The grid visits a row from the page that holds
+its first such position (``PagePlan.firsts``): pages wholly before it
+are neither copied nor multiplied, whoever still holds them, and the
+grid is as long as a window's pages, not a table's.  That first page is
+copied whole, like every page but a row's last, and masked by position
+(per query row: a fill chunk's later tokens start later).  The Mosaic
+call is named ``paged_window_decode`` / ``paged_window_fill``.
+
 Returns UN-normalized partials ``(acc, m, l)`` so the caller online-merges
 them with attention over KV not in the pool yet (the decode chunk's
 in-flight window, or a prefill chunk's causal self-attention).
@@ -221,15 +233,26 @@ def _plan_tiles(
         G //= 2
 
 
-def stream_page(lengths, page_ids, b, j, g, group: int, block_size: int, tile: int):
+def stream_page(
+    lengths, page_ids, b, j, g, group: int, block_size: int, tile: int,
+    firsts=None,
+):
     """``(pool page id, tiles that hold cached positions)`` of stream ``g``
     at the grid step of row ``b`` (in visiting order) and page step ``j``:
-    what the kernel copies there, from a :class:`PagePlan`'s lengths and
-    page ids (an id past a row's length is never read: it holds no
-    tile)."""
-    col = j * group + g
+    what the kernel copies there, from a plan's lengths, page ids and
+    (under a window) first pages (an id past a row's length is never
+    read: it holds no tile)."""
+    col = stream_col(b, j, g, group, firsts)
     held = (lengths[b] - col * block_size + tile - 1) // tile
+    if firsts is not None:  # a window's last steps may lie past the table
+        col = jnp.minimum(col, page_ids.shape[1] - 1)
     return page_ids[b, col], jnp.clip(held, 0, block_size // tile)
+
+
+def stream_col(b, j, g, group: int, firsts=None):
+    """The number, in its row, of the page :func:`stream_page` names."""
+    col = j * group + g
+    return col if firsts is None else col + firsts[b]
 
 
 def page_fetched(this, before, no_step_before):
@@ -245,10 +268,12 @@ def page_fetched(this, before, no_step_before):
 def _kernel(
     lengths_ref,  # scalar prefetch [B], in the order the grid visits
     ids_ref,  # scalar prefetch [B, MB']: the rows' tables
-    layer_ref,  # scalar prefetch [1] (0 when the pool is per-layer)
+    layer_ref,  # scalar prefetch [1] (0 when the pool is per-layer); under
+    # a window [2]: the layer, and how far the queries stand past ``length``
     order_ref,  # scalar prefetch [B]: visit_order, for the maps
-    q_ref,  # (1, 1, Hkv, QR, hd)
-    *refs,  # pools in HBM, 3 outs, page buffers, 3 scratch, slots, semaphores
+    *refs,  # [firsts (scalar prefetch [B]) under a window,] q (1, 1, Hkv,
+    # QR, hd), pools in HBM, 3 outs, page buffers, 3 scratch, slots,
+    # semaphores
     block_size: int,
     tile: int,
     scale: float,
@@ -257,8 +282,14 @@ def _kernel(
     layered: bool,
     quantized: bool = False,
     value_dim: Optional[int] = None,  # latent pages: no v pool
+    window: Optional[int] = None,
+    q_per_kv: int = 1,  # query heads a kv head: a q tile's row t*r + i
 ):
     G, S = page_group, block_size // tile
+    firsts_ref = None
+    if window is not None:
+        firsts_ref, refs = refs[0], refs[1:]
+    q_ref, refs = refs[0], refs[1:]
     # the arrays a page is made of: K, V (none for latent pages, whose
     # values ride the K page) and an int8 pool's two scale arrays
     n_arrays = (1 if value_dim is not None else 2) * (2 if quantized else 1)
@@ -285,7 +316,7 @@ def _kernel(
 
     def page(bb, jj, g):
         return stream_page(
-            lengths_ref, ids_ref, bb, jj, g, G, block_size, tile
+            lengths_ref, ids_ref, bb, jj, g, G, block_size, tile, firsts_ref
         )
 
     def copies(g, slot, pid, tiles):
@@ -376,6 +407,14 @@ def _kernel(
         softmax_scratch_init(s_acc, s_m, s_l)
 
     length = lengths_ref[b]
+    first = None
+    if window is not None:
+        # query token t of this tile stands at position length + t
+        rows = q_ref.shape[3]
+        t = qb * (rows // q_per_kv) + (
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // q_per_kv
+        )
+        first = length + layer_ref[1] + t - (window - 1)
 
     @pl.when(page(b, j, 0)[1] > 0)
     def _row_has_pages_here():
@@ -408,8 +447,9 @@ def _kernel(
                     softmax_block_update(
                         q_ref[0, 0, h], k_all[h], v_all[h],
                         s_acc.at[h], s_m.at[h], s_l.at[h],
-                        base=(j * G + g) * block_size, length=length,
-                        scale=scale,
+                        base=stream_col(b, j, g, G, firsts_ref) * block_size,
+                        length=length,
+                        scale=scale, first=first,
                     )
 
         each_stream(_stream)
@@ -421,15 +461,32 @@ def _kernel(
         l_ref[0, 0] = s_l[...]
 
 
-def visit_order(lengths, block_size: int):
+def window_first_pages(lengths, block_size: int, window: int):
+    """The number, in its row, of the page that holds the first position a
+    row's FIRST query attends under ``window`` (position ``length - window
+    + 1``; 0 while the row is shorter than the window)."""
+    start = jnp.maximum(lengths.astype(jnp.int32) - (window - 1), 0)
+    return start // block_size
+
+
+def window_span_pages(block_size: int, window: int) -> int:
+    """Pages the ``window - 1`` positions a query attends before itself
+    can touch, at the worst alignment."""
+    return max(window - 2, 0) // block_size + 2
+
+
+def visit_order(lengths, block_size: int, firsts=None):
     """The batch row each grid step ``b`` works on: rows in falling order
-    of the valid pages they hold, dead rows last.  The pipeline fetches
+    of the valid pages they hold (from ``firsts`` on, under a window),
+    dead rows last.  The pipeline fetches
     step ``b + 1``'s pages while step ``b`` computes, one step ahead and
     no further, so a step lasts as long as the LONGER of this row's dots
     and the next row's copies: rows of equal page counts side by side
     keep both busy, and dead rows in a block at the end start no copy
     between two live rows."""
     pages = -(-lengths.astype(jnp.int32) // block_size)
+    if firsts is not None:
+        pages = pages - firsts
     return jnp.argsort(-pages, stable=True).astype(jnp.int32)
 
 
@@ -442,18 +499,37 @@ class PagePlan(NamedTuple):
     order: jax.Array  # [B] visit_order
 
 
-def plan_pages(tables, lengths, block_size: int, group: int) -> PagePlan:
+class WindowPagePlan(NamedTuple):
+    """A :class:`PagePlan` of ``[length - window + 1, length)`` of each
+    row."""
+
+    lengths: jax.Array
+    page_ids: jax.Array
+    order: jax.Array
+    firsts: jax.Array  # [B] window_first_pages, in visiting order
+
+
+def plan_pages(
+    tables, lengths, block_size: int, group: int, window: Optional[int] = None
+):
     """The plan :func:`paged_flash_attention` makes for itself unless it
     is handed one.  A caller that runs the kernel many times over the
     same rows (every layer of every step of a decode chunk) makes it
     once, with the ``group`` :func:`page_group` names for its shapes:
-    XLA does not hoist the sort out of those loops."""
-    order = visit_order(lengths, block_size)
+    XLA does not hoist the sort out of those loops.  Under ``window`` the
+    plan is of ``[length - window + 1, length)`` of each row."""
+    firsts = None
+    if window is not None:
+        firsts = window_first_pages(lengths, block_size, window)
+    order = visit_order(lengths, block_size, firsts)
     tables = tables.astype(jnp.int32)[order]
     short = -tables.shape[1] % group
     if short:  # the steps past a row's table hold no cached position
         tables = jnp.pad(tables, ((0, 0), (0, short)))
-    return PagePlan(lengths.astype(jnp.int32)[order], tables, order)
+    lengths = lengths.astype(jnp.int32)[order]
+    if firsts is None:
+        return PagePlan(lengths, tables, order)
+    return WindowPagePlan(lengths, tables, order, firsts[order])
 
 
 def page_group(
@@ -478,7 +554,7 @@ def page_tile(pool_shape, kv_dtype) -> int:
     return _tile_tokens(Hkv, BS, hd, jnp.dtype(kv_dtype).itemsize)
 
 
-def _row_map(b, qb, j, lengths_ref, ids_ref, layer_ref, order_ref):
+def _row_map(b, qb, j, lengths_ref, ids_ref, layer_ref, order_ref, *firsts):
     """Query and output tiles of step (b, qb, .): those of the row this
     step works on."""
     return (order_ref[b], qb, 0, 0, 0)
@@ -521,16 +597,19 @@ def _ungroup_outputs(acc, m, l, B, QB, QT, Hkv, r, Q, Hq, hd):
     )
 
 
-def _layer_scalar(layer):
-    return (
+def _layer_scalar(layer, window_shift=None):
+    layer = (
         jnp.zeros((1,), jnp.int32)
         if layer is None
         else jnp.asarray(layer, jnp.int32).reshape(1)
     )
+    if window_shift is None:
+        return layer
+    return jnp.concatenate([layer, jnp.asarray(window_shift, jnp.int32).reshape(1)])
 
 
 @functools.partial(
-    jax.jit, static_argnames=("interpret", "scale", "value_dim")
+    jax.jit, static_argnames=("interpret", "scale", "value_dim", "window")
 )
 def paged_flash_attention(
     q: jax.Array,  # [B, Q, Hq, hd]
@@ -542,16 +621,23 @@ def paged_flash_attention(
     interpret: bool = False,
     k_scale: jax.Array | None = None,  # [(L,) NB, Hkv, BS] int8-pool scales
     v_scale: jax.Array | None = None,
-    plan: Optional[PagePlan] = None,  # plan_pages(tables, lengths, ...)
+    plan=None,  # plan_pages(tables, lengths, ..., window)
     scale: Optional[float] = None,  # softmax scale; None = 1/sqrt(hd)
     value_dim: Optional[int] = None,  # latent pages: values = k[..., :value_dim]
+    window: Optional[int] = None,  # attend i - j < window only
+    window_shift: jax.Array | None = None,  # [] int32: see below
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Un-normalized online-softmax attention partials over paged KV.
 
     Every query token attends the FULL prefix ``[0, length)`` of its row
     (decode queries by definition; prefill-chunk queries because the
     prefix precedes the whole chunk — in-chunk causality is the caller's
-    self-attention term).  Returns ``(acc [B,Q,Hq,hd] f32, m [B,Q,Hq],
+    self-attention term); under ``window``, query ``t`` attends ``[length
+    + t - window + 1, length)`` of it (module docstring).
+    ``window_shift`` (traced, 0 if None) moves every query that many
+    positions on: step ``i`` of a decode chunk, whose queries stand ``i``
+    past the cached prefix the chunk started from, is ``window_shift=i``
+    over the plan made once for the chunk.  Returns ``(acc [B,Q,Hq,hd] f32, m [B,Q,Hq],
     l [B,Q,Hq])``; rows with ``length == 0`` return ``acc=0, l=0, m=-inf``.
 
     Pool layout is PAGE-major ``[NB, Hkv, BS, hd]`` so a whole page is one
@@ -571,8 +657,8 @@ def paged_flash_attention(
     contract).
 
     ``plan``: what :func:`plan_pages` made of these ``tables`` and
-    ``lengths`` (which are then not read), for a caller that makes many
-    calls over the same rows.
+    ``lengths`` (which are then not read) under this ``window``, for a
+    caller that makes many calls over the same rows.
 
     ``v_pool=None`` with ``value_dim``: latent pages (module docstring);
     ``acc`` is then ``[B, Q, Hq, value_dim]``.
@@ -596,15 +682,23 @@ def paged_flash_attention(
         Q, r, Hkv, BS, hd, jnp.dtype(k_pool.dtype).itemsize, quantized, MB
     )
     qg, QB = _group_queries(q, Hkv, r, QT)
-    layer_arr = _layer_scalar(layer)
-    grid = (B, QB, -(-MB // G))
+    if window is not None and window_shift is None:
+        window_shift = 0
+    layer_arr = _layer_scalar(layer, window_shift if window is not None else None)
+    # a row's page steps: its table's, or no more than a window can touch
+    pages = MB if window is None else min(MB, window_span_pages(BS, window))
+    grid = (B, QB, -(-pages // G))
     # lengths and page ids in the order the grid visits the rows; q and
     # the outputs stay where they are and are addressed through the order
     if plan is None:
-        plan = plan_pages(tables, lengths, BS, G)
-    assert plan.page_ids.shape == (B, grid[2] * G), (
+        plan = plan_pages(tables, lengths, BS, G, window)
+    assert plan.page_ids.shape == (B, -(-MB // G) * G), (
         plan.page_ids.shape, B, MB, G,
     )
+    assert isinstance(plan, WindowPagePlan) == (window is not None), window
+    prefetch = [plan.lengths, plan.page_ids, layer_arr, plan.order]
+    if window is not None:
+        prefetch.append(plan.firsts)
     # the pools stay in HBM: the kernel copies each stream's page into one
     # of its two buffers itself, as far as the page is filled.  int8
     # pools: a page's scales (one f32 per head x slot) ride beside it
@@ -626,9 +720,11 @@ def paged_flash_attention(
             layered=layered,
             quantized=quantized,
             value_dim=value_dim,
+            window=window,
+            q_per_kv=r,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=[pl.BlockSpec((1, 1, Hkv, QT * r, hd), _row_map)]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
@@ -664,17 +760,14 @@ def paged_flash_attention(
         ),
         interpret=interpret,
         # the trace names the Mosaic call after this: one query row a
-        # sequence is a decode step, a query tile a prefill chunk
-        name=("paged_mla_" if latent else "paged_attn_")
+        # sequence is a decode step, a query tile a prefill chunk; a
+        # windowed call apart from one over the whole prefix
+        name=(
+            "paged_mla_" if latent
+            else "paged_attn_" if window is None else "paged_window_"
+        )
         + ("decode" if Q == 1 else "fill"),
-    )(
-        plan.lengths,
-        plan.page_ids,
-        layer_arr,
-        plan.order,
-        qg,
-        *pools,
-    )
+    )(*prefetch, qg, *pools)
 
     return _ungroup_outputs(acc, m, l, B, QB, QT, Hkv, r, Q, Hq, vd)
 
@@ -698,10 +791,11 @@ def gather_paged_kv(
 
 def reference_paged_partials(
     q, k_pool, v_pool, tables, lengths, k_scale=None, v_scale=None,
-    scale=None, value_dim=None,
+    scale=None, value_dim=None, window=None, window_shift=0,
 ):
     """jnp reference for :func:`paged_flash_attention` (same contract;
-    ``v_pool=None`` with ``value_dim``: latent pages).
+    ``v_pool=None`` with ``value_dim``: latent pages; ``window``: query
+    ``t`` attends ``[length + t - window + 1, length)``).
 
     ``k_scale``/``v_scale`` ([NB, Hkv, BS]) mark an int8 pool: the
     gathered pages are multiplied by their per-(head, slot) scales right
@@ -724,10 +818,13 @@ def reference_paged_partials(
     qg = q.reshape(B, Q, Hkv, r, hd).astype(jnp.float32)
     s = jnp.einsum("bqkrd,bksd->bqkrs", qg, k.astype(jnp.float32))
     s = s / np.sqrt(hd) if scale is None else s * scale
-    mask = (
-        jnp.arange(S)[None, None, None, None, :]
-        < lengths[:, None, None, None, None]
-    )
+    pos = jnp.arange(S)[None, None, None, None, :]
+    mask = pos < lengths[:, None, None, None, None]
+    if window is not None:
+        first = (
+            lengths[:, None] + window_shift + jnp.arange(Q)[None, :]
+        ) - (window - 1)
+        mask = mask & (pos >= first[:, :, None, None, None])
     s = jnp.where(mask, s, _NEG_INF)
     m = jnp.max(s, axis=-1)
     p = jnp.where(mask, jnp.exp(s - m[..., None]), 0.0)

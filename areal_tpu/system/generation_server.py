@@ -320,6 +320,9 @@ class GenerationServerWorker(worker_base.Worker):
             cache_mode=config.cache_mode,
             page_size=config.page_size,
             kv_pool_tokens=config.kv_pool_tokens,
+            kv_window_pool_tokens=getattr(
+                config, "kv_window_pool_tokens", None
+            ),
             kv_cache_dtype=getattr(config, "kv_cache_dtype", "auto"),
             serving_weight_dtype=getattr(
                 config, "serving_weight_dtype", "auto"

@@ -58,7 +58,9 @@ def model():
 def test_layer_plan_cuts_runs_by_mixer_and_mlp():
     plan = hybrid.layer_plan(make_cfg())
     assert [tuple(r) for r in plan] == [
-        ("latent", "dense", 0, 0, 0, 1), ("latent", "experts", 1, 1, 0, 3),
+        # (..., count, rope, number in its pool)
+        ("latent", "dense", 0, 0, 0, 1, True, 0),
+        ("latent", "experts", 1, 1, 0, 3, True, 1),
     ]
     cfg = make_cfg()
     assert cfg.is_latent and cfg.n_attn_layers == 4 and cfg.n_mamba_layers == 0

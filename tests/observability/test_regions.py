@@ -147,9 +147,20 @@ DEEPSEEK = dict(
     ),
     attention_bias=False, tie_word_embeddings=False, torch_dtype="bfloat16",
 )
+SMALLTHINKER = dict(
+    architectures=["SmallThinkerForCausalLM"], hidden_size=32,
+    num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=8, moe_ffn_hidden_size=16, moe_num_primary_experts=8,
+    moe_num_active_primary_experts=3, rms_norm_eps=1e-6, rope_theta=10000.0,
+    rope_layout=[0, 1, 1, 1], sliding_window_layout=[0, 1, 1, 1],
+    sliding_window_size=12, tie_word_embeddings=False, vocab_size=64,
+)
 STACKS = {
-    "hybrid": (GRANITE, {"areal.ssm", "areal.attn"}),
-    "latent": (DEEPSEEK, {"areal.attn", "areal.mlp"}),
+    "hybrid": (GRANITE, {"areal.ssm", "areal.attn", "areal.moe.shared"}),
+    "latent": (DEEPSEEK, {"areal.attn", "areal.mlp", "areal.moe.shared"}),
+    # the window layers' half has a region of its own, the global layer's
+    # keeps ``areal.attn``; no shared expert
+    "window": (SMALLTHINKER, {"areal.attn", "areal.attn.window"}),
 }
 SLOTS = 2
 
@@ -161,6 +172,16 @@ def _stack(name):
     return cfg, hybrid.init_params(cfg, jax.random.PRNGKey(0))
 
 
+def _window_pools(cfg, rows):
+    """The window layers' pools and table, where the stack has such."""
+    if not cfg.n_window_layers:
+        return {}
+    return dict(
+        win_pools=paged.pool_zeros(cfg, NB, BS, layers=cfg.n_window_layers),
+        win_tables=jnp.zeros((rows, MB), jnp.int32),
+    )
+
+
 def _hybrid_decode(cfg, params):
     k_pool, v_pool = paged.pool_zeros(cfg, NB, BS)
     ssm, conv = hybrid.state_zeros(cfg, SLOTS)
@@ -170,7 +191,7 @@ def _hybrid_decode(cfg, params):
         jnp.ones((SLOTS,), jnp.int32), jnp.ones((SLOTS,), bool),
         jnp.full((SLOTS,), 4, jnp.int32), jax.random.PRNGKey(0), 2, _greedy,
         _never_stop,
-    ), dict(use_kernel=False, max_len=32)
+    ), dict(use_kernel=False, max_len=32, **_window_pools(cfg, SLOTS))
 
 
 def _hybrid_fill(cfg, params):
@@ -180,7 +201,7 @@ def _hybrid_fill(cfg, params):
         params, k_pool, v_pool, ssm, conv, cfg, jnp.ones((2, 8), jnp.int32),
         jnp.zeros((2,), jnp.int32), jnp.full((2,), 5, jnp.int32),
         jnp.zeros((2, MB), jnp.int32), jnp.arange(2, dtype=jnp.int32),
-    ), dict(use_kernel=False)
+    ), dict(use_kernel=False, **_window_pools(cfg, 2))
 
 
 @pytest.mark.parametrize("name", sorted(STACKS))
@@ -193,7 +214,7 @@ def test_hybrid_programs_name_their_regions(name, program, also):
     cfg, params = _stack(name)
     want = STACKS[name][1] | also | {
         "areal.embed", "areal.kv_write", "areal.moe.route",
-        "areal.moe.experts", "areal.moe.shared", "areal.head",
+        "areal.moe.experts", "areal.head",
     }
     _assert_products_in_regions(_paths(_lower(program(cfg, params))), want)
 
@@ -360,7 +381,9 @@ def test_lint_refuses_an_undeclared_region_and_a_computed_name():
     assert any("areal.made_up" in p and "missing" in p for p in problems)
     assert any("non-literal region name" in p for p in problems)
     # declared and used here: no complaint; declared and unused: dead
-    assert not any("areal.attn" in p for p in problems)
+    # (by the whole name: ``areal.attn.window`` is another entry)
+    assert not any("areal.attn " in p for p in problems)
+    assert any("areal.attn.window" in p and "never recorded" in p for p in problems)
     assert any("areal.mlp" in p and "never recorded" in p for p in problems)
 
 

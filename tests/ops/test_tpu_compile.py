@@ -736,6 +736,149 @@ def test_latent_decode_program_fits_and_reads_the_pool_in_place(
     print(f"latent decode: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
 
 
+# --- window and global attention in one stack: two pools, published widths ---
+
+#: smallthinker-21b-a3b as the cell runs it (benchmark/configs): two
+#: periods [global, window x 3], all 64 experts held, the whole
+#: vocabulary, every width as published; the cell's engine: 64 rows of
+#: 10,240, 262,144 tokens of global pages and 196,608 of window pages,
+#: decode chunks of 8 steps, pages of 512
+WINDOW_ROWS, WINDOW_CTX, WINDOW_CHUNK = 64, 10240, 8
+WINDOW_POOL_TOKENS = {"global": 262144, "window": 196608}
+
+
+def _window_cell_args(one_chip, page):
+    cfg = TransformerConfig(
+        n_layers=8, hidden_dim=2560, n_q_heads=28, n_kv_heads=4,
+        head_dim=128, intermediate_dim=768, moe_intermediate_dim=768,
+        vocab_size=151936, max_position_embeddings=16384, norm_eps=1e-6,
+        rotary_base=1.5e6, tied_embedding=False, activation="relu",
+        n_experts=64, n_experts_per_tok=6, moe_router="topk_softmax",
+        moe_router_input="attn", sliding_window=4096,
+        layer_types=("attention", "window", "window", "window") * 2,
+        rope_layers=(False, True, True, True) * 2,
+    )
+
+    def place(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    params = jax.tree.map(lambda a: place(a.shape, a.dtype), shapes)
+    pools = {}
+    for kind, layers in (("global", None), ("window", cfg.n_window_layers)):
+        k_shape, v_shape = paged.pool_shapes(
+            cfg, WINDOW_POOL_TOKENS[kind] // page, page, layers
+        )
+        pools[kind] = place(k_shape, jnp.bfloat16), place(v_shape, jnp.bfloat16)
+    assert pools["global"][0].shape[0] == 2 and pools["window"][0].shape[0] == 6
+    ssm, conv = (
+        place(a.shape, a.dtype)
+        for a in jax.eval_shape(lambda: hybrid.state_zeros(cfg, WINDOW_ROWS))
+    )
+    return cfg, params, pools, ssm, conv, place
+
+
+def _assert_window_program_fits(compiled, pools):
+    """No copy of either pool in the optimized HLO, and the whole program
+    inside one chip's memory; returns (total, temporaries)."""
+    for kind in pools:
+        assert _pool_copies(compiled, pools[kind][0].shape) == [], kind
+    m = compiled.memory_analysis()
+    total = (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+    assert total < USABLE_HBM_BYTES, (total, m.temp_size_in_bytes)
+    return total, m.temp_size_in_bytes
+
+
+WINDOW_PAGE = 512
+
+
+# the cell's largest fill: four rows of a chunk's width (a prompt's last
+# piece with tails behind it); one prompt's chunk `[1, 1024]` counts
+# 11.72 GB and sixteen tails `[16, 256]` 13.34 (PERF.md section 4)
+@pytest.mark.parametrize("F,C", [(4, 1024)])
+def test_window_fill_program_fits_beside_weights_and_two_pools(
+    one_chip, monkeypatch, F, C
+):
+    """``hybrid_fill_chunk`` whole at the window cell's shapes: the global
+    layers' prefix part is ``paged_attn_fill``, the window layers'
+    ``paged_window_fill`` (the names the readers match), each pool an
+    operand in its own layout, and 7.94 GB of weights + 3.49 GB of pools +
+    the chunk's temporaries fit one chip."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    cfg, params, pools, ssm, conv, place = _window_cell_args(one_chip, WINDOW_PAGE)
+    table = place((F, WINDOW_CTX // WINDOW_PAGE), jnp.int32)
+    compiled = hybrid.hybrid_fill_chunk.lower(
+        params, *pools["global"], ssm, conv, cfg,
+        place((F, C), jnp.int32), place((F,), jnp.int32),
+        place((F,), jnp.int32), table, place((F,), jnp.int32),
+        use_kernel=True, win_pools=pools["window"], win_tables=table,
+    ).compile()
+    text = compiled.as_text()
+    assert "paged_attn_fill" in text and "paged_window_fill" in text
+    assert "ragged-dot" not in text
+    total, temp = _assert_window_program_fits(compiled, pools)
+    assert 11.4e9 < total, total
+    print(f"window fill F={F} C={C}: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
+
+
+def test_window_decode_program_fits_and_reads_both_pools_in_place(
+    one_chip, monkeypatch
+):
+    """``hybrid_decode_chunk`` whole (64 rows, 8 steps) over the two
+    pools: ``paged_attn_decode`` for the global layers and
+    ``paged_window_decode`` for the window layers by name, no copy of
+    either pool, none of a layer's 64 experts, the whole inside one
+    chip."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    cfg, params, pools, ssm, conv, place = _window_cell_args(one_chip, WINDOW_PAGE)
+
+    def rows(dtype):
+        return place((WINDOW_ROWS,), dtype)
+
+    table = place((WINDOW_ROWS, WINDOW_CTX // WINDOW_PAGE), jnp.int32)
+    compiled = hybrid.hybrid_decode_chunk.lower(
+        params, *pools["global"], ssm, conv, cfg, table, rows(jnp.int32),
+        rows(jnp.int32), rows(jnp.bool_), rows(jnp.int32),
+        place((2,), jnp.uint32), chunk_size=WINDOW_CHUNK,
+        sample_fn=_keyed_greedy, stop_fn=_never_stop, use_kernel=True,
+        max_len=WINDOW_CTX, row_seeds=rows(jnp.int32),
+        win_pools=pools["window"], win_tables=table,
+    ).compile()
+    text = compiled.as_text()
+    assert "paged_attn_decode" in text and "paged_window_decode" in text
+    assert "ragged-dot" not in text
+    assert _pool_copies(compiled, (64, 768, 2560)) == []
+    total, temp = _assert_window_program_fits(compiled, pools)
+    print(f"window decode: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
+
+
+# a decode row of 7 query heads a kv head, and a fill chunk's tile, at
+# the cell's page size (256 and 1,024 compiled too when the page was
+# timed: PERF.md section 4)
+@pytest.mark.parametrize("page", [512])
+@pytest.mark.parametrize("Q", [1, 1024])
+def test_windowed_kernel_compiles_at_the_cells_head_grouping(one_chip, Q, page):
+    """The windowed mode of the paged kernel by Mosaic, at 28 query heads
+    over 4 kv heads of 128 (7 rows a kv head and query token: the tile plan
+    no other cell runs), a layer-stacked pool, the window of 4,096."""
+    place = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    B, MB = (64, WINDOW_CTX // page) if Q == 1 else (1, WINDOW_CTX // page)
+    pool = place((6, 64, 4, page, HD), jnp.bfloat16)
+    compiled = pa.paged_flash_attention.lower(
+        place((B, Q, 28, HD), jnp.bfloat16), pool, pool,
+        place((B, MB), jnp.int32), place((B,), jnp.int32),
+        layer=place((), jnp.int32), window=4096,
+        window_shift=place((), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert ("paged_window_decode" if Q == 1 else "paged_window_fill") in text
+
+
 # --- the trainer's step whole: what the layout rule may ask of one chip ---
 
 
