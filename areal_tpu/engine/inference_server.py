@@ -72,7 +72,7 @@ from areal_tpu.engine.batching import bucket_len, spec_window_bucket
 from areal_tpu.engine.window_pages import GONE, WindowPages
 from areal_tpu.engine.prefix_cache import PrefixMatch, RadixPrefixCache
 from areal_tpu.engine.sampling import SamplingParams, sample_logits_keyed
-from areal_tpu.models import hybrid, paged, quantize
+from areal_tpu.models import hybrid, moe, paged, quantize
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import KVCache, decode_step, prefill
 from areal_tpu.observability.hbm_ledger import (
@@ -665,6 +665,16 @@ class ContinuousBatchingEngine:
         self.moe_expert_pairs = np.zeros(
             (cfg.n_held_experts if self._by_kind else 0,), np.int64
         )
+        #: prompt tokens that went through the expert layers of a fill
+        #: batch; those of them in a batch whose shape takes the grouped
+        #: product (``moe.group_rows``); the rounds past the first that
+        #: those products took, summed over a fill's expert layers on the
+        #: device (each fill's count rides to the host on its own and is
+        #: added here when it has arrived: no fetch waits for it)
+        self.moe_fill_tokens_total = 0
+        self.moe_fill_tokens_grouped_total = 0
+        self.moe_fill_extra_rounds_total = 0
+        self._fill_rounds_on_the_way: Deque[jax.Array] = deque()
         assert kv_cache_dtype in ("auto", "int8"), kv_cache_dtype
         if kv_cache_dtype == "int8" and not self.paged:
             logger.warning(
@@ -3515,6 +3525,20 @@ class ContinuousBatchingEngine:
                 state_copies=self.state_copies_total,
                 state_reprefills=self.state_reprefills_total,
             )
+        grouped = False
+        if self._by_kind and self.cfg.n_experts:
+            # running totals, this batch's tokens among them: the host
+            # knows from the batch's shape which product its experts take
+            grouped = bool(moe.group_rows(self.cfg, F_pad * C))
+            self.moe_fill_tokens_total += counts["tokens"]
+            if grouped:
+                self.moe_fill_tokens_grouped_total += counts["tokens"]
+            self._add_fill_rounds_that_arrived()
+            counts.update(
+                moe_fill_tokens=self.moe_fill_tokens_total,
+                moe_fill_tokens_grouped=self.moe_fill_tokens_grouped_total,
+                moe_fill_extra_rounds=self.moe_fill_extra_rounds_total,
+            )
         with self._phases.phase("areal.engine.fill.dispatch", **counts):
             toks = np.zeros((F_pad, C), np.int32)
             starts = np.zeros((F_pad,), np.int32)
@@ -3533,7 +3557,8 @@ class ContinuousBatchingEngine:
             if self._by_kind:
                 win = self._window_args(wtables)
                 (logits, self.k_pool, self.v_pool, self.ssm_state,
-                 self.conv_state, _, routed, *win_out) = hybrid.hybrid_fill_chunk(
+                 self.conv_state, _, routed, rounds,
+                 *win_out) = hybrid.hybrid_fill_chunk(
                     self.params, self.k_pool, self.v_pool, self.ssm_state,
                     self.conv_state, self.cfg, jnp.asarray(toks),
                     jnp.asarray(starts), jnp.asarray(cls),
@@ -3543,6 +3568,9 @@ class ContinuousBatchingEngine:
                 if win_out:
                     self.win_k_pool, self.win_v_pool = win_out[0]
                 out = (logits, self.k_pool, self.v_pool)
+                if grouped:
+                    jax_compat.start_host_copies((rounds,))
+                    self._fill_rounds_on_the_way.append(rounds)
                 if self._keep_routed:
                     # on its way to the host while the rows decode: the
                     # row that finishes reads it without a round trip
@@ -3596,6 +3624,14 @@ class ContinuousBatchingEngine:
                 completed.append(f)
                 idxs.append(i)
         return completed, idxs, logits
+
+    def _add_fill_rounds_that_arrived(self):
+        """Add to ``moe_fill_extra_rounds_total`` the counts of the fills
+        whose programs have run (their copies to the host were started at
+        dispatch): never a wait."""
+        on_the_way = self._fill_rounds_on_the_way
+        while on_the_way and on_the_way[0].is_ready():
+            self.moe_fill_extra_rounds_total += int(on_the_way.popleft())
 
     def _refill_rows_paged(self, entries: List[Tuple[int, List[int]]]):
         """Synchronously recompute rows' cached KV into their EXISTING
@@ -4815,6 +4851,7 @@ class ContinuousBatchingEngine:
         self.chunks_total += 1
         with self._phases.phase("areal.engine.harvest.fold") as span:
             n_tokens = self._fold_chunk(chunk, fetched)
+            self._add_fill_rounds_that_arrived()
             counts = {"tokens": n_tokens}
             if len(fetched) > 5:
                 # the chunk's (token, k) pairs by held expert, and last

@@ -91,7 +91,9 @@ from areal_tpu.engine.sampling import sample_and_advance
 from areal_tpu.models import paged
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models import quantize
+from areal_tpu.models import moe
 from areal_tpu.models.moe import held_moe_mlp, n_pair_counts
+from areal_tpu.models.moe import layer_of as _at
 from areal_tpu.models.transformer import (
     Params,
     _activation,
@@ -193,14 +195,6 @@ def _rope_cfg(cfg: TransformerConfig, run: Run) -> TransformerConfig:
     if cfg.use_rope == run.rope:
         return cfg
     return dataclasses.replace(cfg, use_rope=run.rope)
-
-
-def _at(tree, i):
-    """Layer ``i`` of a stacked tree (a dynamic slice inside a scan: what
-    ``lax.scan`` over the stack itself reads)."""
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -729,20 +723,21 @@ def _mlp_half(
     """The second half of layer ``l`` (number ``e`` among its MLP kind);
     ``a``: the mixer's input, which the router reads where
     ``cfg.moe_router_input == "attn"``.  Returns ``(x, pairs, routed [B,
-    T, K])``, the last two None after a dense MLP: see
-    ``moe.held_moe_mlp``."""
+    T, K], extra rounds)``, the last three None after a dense MLP and the
+    last one wherever the experts took the product over every held one:
+    see ``moe.held_moe_mlp``."""
     h = _norm(x, _at(params["layers"]["mlp_norm"], l), cfg)
     if run.mlp == "dense":
         dp = _at(params["dense"], e)
         hid = _activation(_proj(dp["gate"], h), cfg.activation) * _proj(
             dp["up"], h
         )
-        return _res(cfg, x, _proj(dp["down"], hid)), None, None
-    out, pairs, routed = held_moe_mlp(
-        cfg, h, _at(params["layers"]["mlp"], e), valid=valid,
-        router_input=a if cfg.moe_router_input == "attn" else None,
+        return _res(cfg, x, _proj(dp["down"], hid)), None, None, None
+    out, pairs, routed, rounds = held_moe_mlp(
+        cfg, h, params["layers"]["mlp"], valid=valid,
+        router_input=a if cfg.moe_router_input == "attn" else None, layer=e,
     )
-    return _res(cfg, x, out), pairs, routed
+    return _res(cfg, x, out), pairs, routed, rounds
 
 
 @region("areal.head")
@@ -845,7 +840,7 @@ def hidden_states(
             with _mixer_region(run):
                 a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
                 x = _res(cfg, x, mixer(run, a, j))
-            x, _, _ = _mlp_half(cfg, params, run, l, e, x, valid, a)
+            x, _, _, _ = _mlp_half(cfg, params, run, l, e, x, valid, a)
             return x, None
 
         x, _ = scan_layers(body, x, _run_indices(run))
@@ -971,9 +966,11 @@ def hybrid_fill_chunk(
     expanded and the paged prefix in the absorbed form, and leaves its
     latent entries for the same one write.  Returns ``(last_logits [F,
     V], k_pool, v_pool, ssm, conv, pairs [moe.n_pair_counts], routed [Le,
-    F, C, K])`` and, given ``win_pools``, those last: ``routed`` is every
-    EXPERT layer's routed experts of every position
-    (``moe.held_moe_mlp``)."""
+    F, C, K], extra rounds)`` and, given ``win_pools``, those last:
+    ``routed`` is every EXPERT layer's routed experts of every position
+    and ``extra rounds`` the rounds past the first that the expert layers'
+    grouped products took, summed over them (``moe.held_moe_mlp``; None
+    where the batch's shape takes the product over every held expert)."""
     C = tokens.shape[1]
     valid = jnp.arange(C)[None, :] < chunk_lens[:, None]  # [F, C]
     row_valid = chunk_lens > 0
@@ -1057,13 +1054,21 @@ def hybrid_fill_chunk(
         entry = latent_entry(cfg, c_kv, k_rope)[:, :, None, :]
         return _proj(ap["o"], attn), (entry.astype(k_pool.dtype),)
 
-    carry = (x, ssm, _pairs_zero(cfg))
+    # the rounds' count rides the layer loops only in a program whose
+    # experts take the grouped product: any other is the program it was,
+    # to the letter (one scalar more through the hybrid cell's loops, and
+    # 4 of 187 served sequences came back non-finite in one run of four:
+    # my chip runs, PR 41, ``moe.group_rows``)
+    grouped = moe.group_rows(cfg, tokens.size)
+    carry = (
+        x, ssm, _pairs_zero(cfg), jnp.zeros((), jnp.int32) if grouped else None,
+    )
     chunk_kv, chunk_kv_win, tails1, routed = [], [], [], []
     for run in layer_plan(cfg):
         l_idx, j_idx, e_idx, p_idx = _run_indices(run)
 
         def body(carry, inp, run=run):
-            x, ssm, pairs = carry
+            x, ssm, pairs, rounds = carry
             l, j, e, p = inp[:4]
             with _mixer_region(run):
                 a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
@@ -1074,8 +1079,8 @@ def hybrid_fill_chunk(
                 else:
                     out, kept = attn_mixer(run, a, j, p)
                 x = _res(cfg, x, out)
-            x, n, r = _mlp_half(cfg, params, run, l, e, x, valid, a)
-            return (x, ssm, _add_pairs(pairs, n)), (kept, r)
+            x, n, r, m = _mlp_half(cfg, params, run, l, e, x, valid, a)
+            return (x, ssm, _add_pairs(pairs, n), _add_pairs(rounds, m)), (kept, r)
 
         xs = (l_idx, j_idx, e_idx, p_idx)
         if run.kind == "mamba":
@@ -1090,7 +1095,7 @@ def hybrid_fill_chunk(
             chunk_kv.append(kept)
         if r is not None:
             routed.append(r)
-    x, ssm, pairs = carry
+    x, ssm, pairs, rounds = carry
     if tails1:
         conv = _put_conv_tails(
             conv, slots, jnp.concatenate(tails1, axis=0), row_valid
@@ -1115,7 +1120,7 @@ def hybrid_fill_chunk(
     logits = _logits(params, cfg, paged.last_valid(x, chunk_lens))[:, 0]
     out = (
         logits, k_pool, v_pool, ssm, conv, pairs,
-        jnp.concatenate(routed, axis=0),
+        jnp.concatenate(routed, axis=0), rounds,
     )
     return out if win_pools is None else out + (win_pools,)
 
@@ -1263,7 +1268,7 @@ def hybrid_decode_chunk(
                     else:
                         out, wk, wv = attn_mixer(run, a, wk, wv, j, p)
                     x = _res(cfg, x, out)
-                x, n, r = _mlp_half(cfg, params, run, l, e, x, live, a)
+                x, n, r, _ = _mlp_half(cfg, params, run, l, e, x, live, a)
                 return (x, wk, wv, ssm, conv, _add_pairs(pairs, n)), (
                     None if r is None else r[:, 0].T
                 )

@@ -260,10 +260,23 @@ def _routed_experts(cfg, dtype, x, topk_probs, topk_idx, p, mesh):
     return jnp.sum(expert_out * topk_probs[..., None].astype(dtype), axis=1)
 
 
-#: tokens of one :func:`dense_expert_compute` call; a longer input goes
-#: through it piece by piece (the served calls are a decode step's 64 rows
-#: and a fill's 4 x 256 at most, so only whole-sequence forwards are cut)
+#: the call length from which :func:`held_moe_mlp` multiplies the routed
+#: pairs and not every held expert for every token (:func:`group_rows`
+#: has the timing): the served calls are a decode step's 64 rows and
+#: fills of ``[1, 1024]``, ``[4, 256]``, ``[4, 1024]`` in the latent and
+#: window cells, ``[1..4, 256]`` in the hybrid cell; longer are whole-
+#: sequence forwards.  A longer call that may not take the grouped form
+#: is cut into pieces of this many tokens, so that the ``[E_held, N, F]``
+#: temporaries of the product over every held expert do not grow with it
 DENSE_EXPERTS_CALL_TOKENS = 1024
+
+#: rows of one group of the grouped product: the ridge of a v5e (197
+#: TFLOP/s over 819 GB/s = 240 FLOP a byte, about 240 rows a bf16 weight
+#: read).  Under it a held expert's product is bound by reading its
+#: weights, so a smaller group saves little (2.20 ms a layer at 128 rows,
+#: 2.76 at 256, latent widths) where a second round costs a second read of
+#: them (+2.0 ms, and the combine's gathers again); my chip runs, PR 41
+GROUP_ROWS = 256
 
 
 @region("areal.moe.experts")
@@ -275,16 +288,143 @@ def dense_expert_compute(x, w_tok, gate_w, up_w, down_w, act_kind: str):
     as ``[E, D, F]`` the TPU compiler transposes the whole layer stack
     first, 2.1 GB a projection at 10 x 36 experts).  Returns [N, D].
 
+    The form of a call with FEW tokens a held expert (a decode step's 64
+    rows): there each expert's product is bound by reading its weights,
+    and the products nobody routed cost no time.  A fill's 1,024 tokens
+    are past that knee and take :func:`grouped_expert_compute`.
+
     Why not pairs sorted by expert for ``ragged_dot``
-    (:func:`local_expert_compute`, the expert-parallel path's): that is a
-    custom call on the TPU, for which a layer's weights, sliced from the
-    layer stack, are COPIED (1.36 GB written and read again a layer at 36
-    experts of 4096 x 768 x 3, by a described-v5e compile, PR 31), where
-    a plain dot reads the slice in place."""
+    (:func:`local_expert_compute`, the expert-parallel path's), in either
+    form: that is a custom call on the TPU, for which a layer's weights,
+    sliced from the layer stack, are COPIED (1.36 GB written and read
+    again a layer at 36 experts of 4096 x 768 x 3, by a described-v5e
+    compile, PR 31), where a plain dot reads the slice in place."""
     g = jnp.einsum("nd,efd->enf", x, gate_w)
     u = jnp.einsum("nd,efd->enf", x, up_w)
     hid = _activation(g, act_kind) * u * w_tok.T[:, :, None].astype(x.dtype)
     return jnp.einsum("enf,efd->nd", hid, down_w)
+
+
+def group_rows(cfg: TransformerConfig, n_tokens: int) -> int:
+    """Rows of one held expert's group in a call of ``n_tokens``
+    (``GROUP_ROWS``), or 0 where the call takes the product over every
+    held expert (:func:`dense_expert_compute`).  From the call's shape and
+    the configuration's facts alone.
+
+    Grouped from ``DENSE_EXPERTS_CALL_TOKENS`` tokens on: four times past
+    the knee where a held expert's product stops being bound by its weight
+    read, and four times the rows of a group, so the grouped form's own
+    cost (ranks, a gather a pair to lay a round out, a float32 gather a
+    pair to combine it: 0.5-1.4 ms a layer at 1,024 tokens) is a quarter
+    of the products it saves.  The crossover as timed on a v5e, ms a layer
+    at the window / latent / hybrid cell's widths, every held expert
+    against groups of 256 rows (my chip runs, PR 41): 64 tokens 1.03 /
+    1.94 / 0.93 against 1.18 / 2.12 / 1.07 at 128 rows (the smallest
+    timed), 256 tokens 1.12 / 2.10 / 1.03 against 1.84 / 2.76 / 1.65, 512
+    tokens 2.06 / 3.99 / 1.86 against 1.90 / 2.88 / 1.76 (within 8% but
+    for the latent widths), 1,024 tokens 4.09 / 7.88 / 3.74 against 2.17
+    / 4.79 / 2.38, 4,096 tokens 19.2 / 38.1 / 17.9 against 7.1 / 20.9 /
+    16.8 (two to four rounds a layer).
+
+    NEVER in a stack with recurrent state (a Mamba layer), at any length,
+    and not for its timing: on the chip the hybrid cell's served rows came
+    back NON-FINITE when its fill programs took the grouped form (three
+    runs, 89-127 of ~188 sequences, a row's first bad token right after a
+    fill of OTHER rows; my chip runs, PR 41), though the same programs
+    alone on the chip left every other state slot bit-equal and agreed
+    with this form.  The cause is not known (``PERF.md`` section 7 has
+    what was ruled out), so such a stack keeps the parent's product at
+    every shape until a ``bring_up`` PR finds it;
+    ``tests/model/test_moe_grouped.py`` and ``tests/ops/
+    test_tpu_compile.py`` hold that."""
+    if not cfg.n_held_experts or cfg.n_mamba_layers:
+        return 0
+    return GROUP_ROWS if n_tokens >= DENSE_EXPERTS_CALL_TOKENS else 0
+
+
+@region("areal.moe.experts")
+def grouped_expert_compute(
+    x: jax.Array,  # [N, D]
+    local: jax.Array,  # [N, K] expert numbers among the held ones
+    w: jax.Array,  # [N, K] float32 router weights
+    valid: Optional[jax.Array],  # [N] bool
+    weights,  # round -> (gate, up, down), each [E_held, F, D]
+    held: int,
+    act_kind: str,
+    cap: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """``sum_k w[n, k] * expert_{local[n, k]}(x[n])`` over the pairs whose
+    expert is held (``local`` in ``[0, E_held)``) and whose token is
+    ``valid``, computed for THOSE pairs only: ``(out [N, D], extra rounds
+    int32)``.  What :func:`dense_expert_compute` gives, less the products
+    whose weight is 0.
+
+    Each held expert's pairs are ranked in token order; a ROUND lays the
+    pairs of ranks ``[r cap, (r + 1) cap)`` out as ``[E_held, cap, D]`` (a
+    gather of ``x``'s rows), multiplies them by plain batched dots, which
+    read a layer's ``[E_held, F, D]`` slices where they lie in the stack,
+    and every pair GATHERS its row of the result (a scatter-add of
+    ``[E_held x cap, D]`` rows is a serial loop on the TPU); the down
+    products come out in float32 and a token's pairs are weighted and
+    summed there, rounded once (the dense form weights the hidden rows
+    in the compute dtype and rounds its one contraction over ``e`` and
+    ``f`` once).  ``weights(r)`` gives round ``r`` its three ``[E_held,
+    F, D]`` arrays (:func:`held_moe_mlp` says why a function and not the
+    arrays).  ``cap`` is a tile size, not
+    a limit: the rounds go on until the busiest expert's last pair is
+    computed (usually one: ``extra rounds`` counts the others), so no pair
+    is dropped at any routing.  Pairs of experts held elsewhere and of
+    padding tokens take no room in any group.
+
+    The rounds are a loop and not a dense product under ``lax.cond``: one
+    body in the program, where the other keeps both forms in every fill
+    program, and a routing whose busiest expert is 2.4 times the mean (the
+    latent cell's choice bias) would fall back in most calls."""
+    N, D = x.shape
+    K = local.shape[1]
+    is_held = (local >= 0) & (local < held)
+    if valid is not None:
+        is_held = is_held & valid[:, None]
+    hit = is_held[:, :, None] & (
+        local[:, :, None] == jnp.arange(held)[None, None, :]
+    )  # [N, K, E_held]; top-k names an expert once a token
+    # tokens of expert e up to and with token n, and each pair's rank
+    cum = jnp.cumsum(jnp.any(hit, axis=1).astype(jnp.int32), axis=0)
+    rank = jnp.sum(jnp.where(hit, cum[:, None, :], 0), axis=2) - 1  # [N, K]
+    count = cum[-1]  # [E_held]
+    rounds = (jnp.max(count) + cap - 1) // cap
+    first_slot = jnp.where(is_held, local, 0) * cap  # [N, K]
+    wf = w.astype(jnp.float32)
+
+    def one_round(carry):
+        r, acc = carry
+        j = r * cap + jnp.arange(cap)  # the round's ranks
+        # the token that holds rank j of expert e: the first whose count
+        # passes j (N where the expert has no such pair: any row will do,
+        # nobody gathers its result)
+        tok = jnp.sum(cum[:, :, None] <= j[None, None, :], axis=0)  # [E, cap]
+        xg = x[jnp.minimum(tok, N - 1)]  # [E_held, cap, D]
+        gate_w, up_w, down_w = weights(r)
+        g = jnp.einsum("ecd,efd->ecf", xg, gate_w)
+        u = jnp.einsum("ecd,efd->ecf", xg, up_w)
+        y = jnp.einsum(
+            "ecf,efd->ecd", _activation(g, act_kind) * u, down_w,
+            preferred_element_type=jnp.float32,
+        ).reshape(held * cap, D)
+        here = (rank >= r * cap) & (rank < (r + 1) * cap)  # [N, K]
+        slot = jnp.where(here, first_slot + rank - r * cap, 0)
+        for k in range(K):
+            acc = acc + jnp.where(
+                here[:, k, None], y[slot[:, k]] * wf[:, k, None], 0.0
+            )
+        return r + 1, acc
+
+    _, acc = jax.lax.while_loop(
+        lambda carry: carry[0] < rounds,
+        one_round,
+        (jnp.int32(0), jnp.zeros((N, D), jnp.float32)),
+    )
+    return acc.astype(x.dtype), jnp.maximum(rounds - 1, 0).astype(jnp.int32)
 
 
 def group_limited_choice(cfg: TransformerConfig, choice: jax.Array):
@@ -340,13 +480,23 @@ def n_pair_counts(cfg: TransformerConfig) -> int:
     return cfg.n_held_experts + 1 + (cfg.moe_router == "sigmoid_group")
 
 
+def layer_of(tree, layer):
+    """Layer ``layer`` of a stacked tree (a dynamic slice inside a scan:
+    what ``lax.scan`` over the stack itself reads)."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        tree,
+    )
+
+
 def held_moe_mlp(
     cfg: TransformerConfig,
     h: jax.Array,  # [B, T, D]
     p: Dict[str, Any],  # one layer's {"router", "experts"[, "shared"]}
     valid: Optional[jax.Array] = None,  # [B, T] bool
     router_input: Optional[jax.Array] = None,  # [B, T, D]; None: ``h``
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    layer: Optional[jax.Array] = None,  # ``p`` is the layer STACK: which
+) -> Tuple[jax.Array, jax.Array, jax.Array, Optional[jax.Array]]:
     """The expert layer of a program that is TOLD which experts it holds
     (``cfg.moe_first_expert``, ``cfg.n_held_experts``; ``p["experts"]``
     holds exactly those, gate, up and down each ``[E_held, F, D]``):
@@ -355,49 +505,89 @@ def held_moe_mlp(
     weight 1).  The ROUTER reads ``router_input`` where one is given (a
     model whose router stands before attention routes on the mixer's
     input) while the experts read ``h``.  Returns ``(out [B, T, D], pairs [n_pair_counts] int32,
-    expert ids [B, T, K] int32)``: the valid (token, k) pairs each held
+    expert ids [B, T, K] int32, extra rounds)``: the valid (token, k) pairs each held
     expert took and, after them, those routed to experts held elsewhere
     (a group-limited router appends the (token, chosen group) pairs
-    whose group has an expert held HERE); and
+    whose group has an expert held HERE);
     each token's routed experts, by their published numbers, for a
     caller that hands the routing out (a routing-replay trainer, a
-    parity check that follows the server's choices)."""
+    parity check that follows the server's choices); and the rounds past
+    the first that the grouped product took (int32), None where the
+    call took the product over every held expert.
+
+    Which of the two products a call takes follows from its shape and
+    the stack's kinds alone (:func:`group_rows`): a decode step's 64 rows
+    multiply every held expert (:func:`dense_expert_compute`), a fill's
+    1,024 tokens the pairs their router chose
+    (:func:`grouped_expert_compute`); a stack with recurrent state takes
+    the first at every length, a long call in pieces.
+
+    A caller inside a scan over layers hands over the STACK of every
+    layer's parameters and ``layer``, and not the layer's slice: the
+    grouped product's rounds are a loop, a slice taken outside it is the
+    loop's operand, and the compiler then COPIES a layer's expert weights
+    out of the stack (0.755 GB a layer at 64 experts of 768 x 2560 x 3, by
+    a described-v5e compile, PR 41) where a dot that slices the stack
+    itself reads them in place."""
     B, T, D = h.shape
+    cap = group_rows(cfg, B * T)
+    experts = p["experts"]
+    if layer is not None and cap:
+        # the rounds slice the experts' stack themselves: weights() below
+        p = layer_of({k: v for k, v in p.items() if k != "experts"}, layer)
+    elif layer is not None:
+        p = layer_of(p, layer)
+        experts, layer = p["experts"], None
     x = h.reshape(-1, D)
     routed_on = x if router_input is None else router_input.reshape(-1, D)
     w, idx, _, groups = route(cfg, routed_on, p["router"])
     first, held = cfg.moe_first_expert, cfg.n_held_experts
     local = idx - first
-    with region("areal.moe.route"):
-        # each token's weight for each held expert, 0 where not routed
-        w_tok = jnp.sum(
-            jnp.where(
-                local[:, :, None] == jnp.arange(held)[None, None, :],
-                w[:, :, None], 0.0,
-            ),
-            axis=1,
+    rounds = None
+
+    def weights(r=None):
+        """gate, up, down ``[E_held, F, D]``; inside round ``r`` of the
+        grouped product the stack's slice, tied to ``r``: a slice that
+        depends on nothing the loop changes is lifted out of it (loop-
+        invariant code motion), which makes it the copy described
+        above."""
+        ex = experts
+        if layer is not None:
+            ex = layer_of(ex, jax.lax.optimization_barrier((layer, r))[0])
+        return tuple(
+            quantize.leaf_weight(ex[k], h.dtype) for k in ("gate", "up", "down")
         )
-    with region("areal.moe.experts"):
-        ex = p["experts"]
-        gate_w = quantize.leaf_weight(ex["gate"], h.dtype)
-        up_w = quantize.leaf_weight(ex["up"], h.dtype)
-        down_w = quantize.leaf_weight(ex["down"], h.dtype)
-        N, C = x.shape[0], DENSE_EXPERTS_CALL_TOKENS
-        if N <= C:
-            out = dense_expert_compute(
-                x, w_tok, gate_w, up_w, down_w, cfg.activation
+
+    if cap:
+        out, rounds = grouped_expert_compute(
+            x, local, w, None if valid is None else valid.reshape(-1),
+            weights, held, cfg.activation, cap,
+        )
+    else:
+        with region("areal.moe.route"):
+            # each token's weight for each held expert, 0 where not routed
+            w_tok = jnp.sum(
+                jnp.where(
+                    local[:, :, None] == jnp.arange(held)[None, None, :],
+                    w[:, :, None], 0.0,
+                ),
+                axis=1,
             )
-        else:
-            pad = (-N) % C
-            out = jax.lax.map(
-                lambda xw: dense_expert_compute(
-                    *xw, gate_w, up_w, down_w, cfg.activation
-                ),
-                (
-                    jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, C, D),
-                    jnp.pad(w_tok, ((0, pad), (0, 0))).reshape(-1, C, held),
-                ),
-            ).reshape(-1, D)[:N]
+        with region("areal.moe.experts"):
+            N, C = x.shape[0], DENSE_EXPERTS_CALL_TOKENS
+            ws = weights()
+            if N <= C:
+                out = dense_expert_compute(x, w_tok, *ws, cfg.activation)
+            else:
+                # a long call of a stack that may not group: pieces of C
+                pad = (-N) % C
+                out = jax.lax.map(
+                    lambda xw: dense_expert_compute(*xw, *ws, cfg.activation),
+                    (
+                        jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, C, D),
+                        jnp.pad(w_tok, ((0, pad), (0, 0))).reshape(-1, C, held),
+                    ),
+                ).reshape(-1, D)[:N]
     if "shared" in p:
         with region("areal.moe.shared"):
             sh = p["shared"]
@@ -410,6 +600,7 @@ def held_moe_mlp(
         out.reshape(B, T, D),
         _held_pairs(cfg, local, groups, valid),
         idx.reshape(B, T, -1).astype(jnp.int32),
+        rounds,
     )
 
 

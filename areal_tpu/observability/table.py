@@ -102,6 +102,28 @@ METRIC_TABLE = [
         ("expert",),
     ),
     MetricSpec(
+        "areal_inference_moe_fill_tokens",
+        "gauge",
+        "Prompt tokens that went through the expert layers of a fill "
+        "batch since the server started (a stack stated by kind only)",
+    ),
+    MetricSpec(
+        "areal_inference_moe_fill_tokens_grouped",
+        "gauge",
+        "Those of areal_inference_moe_fill_tokens in a batch whose shape "
+        "takes the grouped product over the held experts (the routed "
+        "pairs only: moe.group_rows), not the product over every held "
+        "expert for every token",
+    ),
+    MetricSpec(
+        "areal_inference_moe_fill_extra_rounds",
+        "gauge",
+        "Rounds past the first that the fills' grouped products took, "
+        "summed over their expert layers (a round holds moe.group_rows "
+        "pairs an expert; the busiest expert's pairs decide how many a "
+        "layer takes); the fills still on the device are not in it yet",
+    ),
+    MetricSpec(
         "areal_inference_moe_groups_hit",
         "gauge",
         "(token, chosen group) pairs of a group-limited router whose "
@@ -1109,7 +1131,11 @@ TRACE_TABLE = [
         "(counts: prompts, f_pad, c, tokens; for a model with recurrent "
         "state also the running totals state_copies = sibling copies of "
         "a fill's end state, state_reprefills = requests that matched "
-        "cached pages and prefilled from 0 all the same)",
+        "cached pages and prefilled from 0 all the same; for a model "
+        "that holds a share of the experts the running totals "
+        "moe_fill_tokens, moe_fill_tokens_grouped = those in a batch "
+        "whose shape takes the grouped product, moe_fill_extra_rounds = "
+        "rounds past the first of the fills whose programs have run)",
     ),
     TraceSpec(
         "areal.engine.fill.first_token_wait",

@@ -634,6 +634,13 @@ class GenerationServerWorker(worker_base.Worker):
             ),
             "moe_expert_pairs": reg.gauge("areal_inference_moe_expert_pairs"),
             "moe_groups_hit": reg.gauge("areal_inference_moe_groups_hit"),
+            "moe_fill_tokens": reg.gauge("areal_inference_moe_fill_tokens"),
+            "moe_fill_tokens_grouped": reg.gauge(
+                "areal_inference_moe_fill_tokens_grouped"
+            ),
+            "moe_fill_extra_rounds": reg.gauge(
+                "areal_inference_moe_fill_extra_rounds"
+            ),
             "pending": reg.gauge("areal_inference_pending_requests"),
             "version": reg.gauge("areal_inference_weight_version"),
             "ring_depth": reg.gauge("areal_inference_ring_depth"),
@@ -812,6 +819,13 @@ class GenerationServerWorker(worker_base.Worker):
         for e, n in enumerate(eng.moe_expert_pairs.tolist()):
             self._obs["moe_expert_pairs"].set(n, expert=str(e))
         self._obs["moe_groups_hit"].set(eng.moe_groups_hit_total)
+        self._obs["moe_fill_tokens"].set(eng.moe_fill_tokens_total)
+        self._obs["moe_fill_tokens_grouped"].set(
+            eng.moe_fill_tokens_grouped_total
+        )
+        self._obs["moe_fill_extra_rounds"].set(
+            eng.moe_fill_extra_rounds_total
+        )
         self._obs["pending"].set(eng.n_pending)
         self._obs["version"].set(eng.version)
         self._obs["ring_depth"].set(eng.pipeline_depth)
@@ -1711,6 +1725,14 @@ class GenerationServerWorker(worker_base.Worker):
                     for name, sec in eng.phase_seconds().items()
                 ),
             )
+            if eng.moe_fill_tokens_total:
+                self.logger.info(
+                    "expert layers at fill: moe_fill_tokens=%d, "
+                    "moe_fill_tokens_grouped=%d, moe_fill_extra_rounds=%d",
+                    eng.moe_fill_tokens_total,
+                    eng.moe_fill_tokens_grouped_total,
+                    eng.moe_fill_extra_rounds_total,
+                )
             # releases the ledger attributions (and logs the leak audit:
             # a quiesced server returns the process ledger to baseline)
             eng.close()

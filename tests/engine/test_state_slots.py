@@ -17,7 +17,7 @@ from areal_tpu.engine.inference_server import (
     StatefulModelUnsupported,
 )
 from areal_tpu.engine.sampling import SamplingParams
-from areal_tpu.models import hybrid
+from areal_tpu.models import hybrid, moe
 from benchmark.lib import reference_granitemoehybrid as ref
 from tests.model.test_hybrid import HF, make_cfg
 
@@ -139,6 +139,30 @@ def test_more_requests_than_slots_queue_and_every_one_is_the_reference(model):
     assert eng.moe_pairs_routed_total > eng.moe_pairs_held_total > 0
     assert int(eng.moe_expert_pairs.sum()) == eng.moe_pairs_held_total
     assert eng.moe_expert_pairs.shape == (HELD,)
+
+
+def test_no_fill_of_a_stack_with_state_takes_the_grouped_product(model, monkeypatch):
+    """With the rule's threshold down at a fill chunk's 8 slots (where a
+    stack without state groups: tests/engine/test_latent_pages.py) this
+    stack's fills still multiply every held expert, a fill batch of 16
+    slots in pieces of 8: on the chip its rows came back non-finite
+    beside grouped fills (``moe.group_rows``)."""
+    monkeypatch.setattr(moe, "DENSE_EXPERTS_CALL_TOKENS", 8)
+    assert moe.group_rows(model[0], 8) == moe.group_rows(model[0], 4096) == 0
+    jax.clear_caches()  # programs traced under the real threshold
+    try:
+        eng = make_engine(model, max_batch=2, prefill_chunk_tokens=16)
+        prompts = _prompts(3, 9, 17, 4)
+        for i, p in enumerate(prompts):
+            eng.submit(_req(f"q{i}", p, 5 + i))
+        run_until_done(eng)
+        out = eng.drain_results()
+        assert len(out) == 3
+        assert_reference(model[1], out)
+        assert eng.moe_fill_tokens_grouped_total == 0 < eng.moe_fill_tokens_total
+        assert eng.moe_fill_extra_rounds_total == 0
+    finally:
+        jax.clear_caches()
 
 
 def test_recompute_preemption_goes_through_the_fill_path(model):

@@ -15,7 +15,7 @@ from areal_tpu.api.model_api import APIGenerateInput, GenerationHyperparameters
 from areal_tpu.engine.inference_server import ContinuousBatchingEngine
 from areal_tpu.engine.sampling import SamplingParams
 from areal_tpu.engine.window_pages import GONE, WindowPages
-from areal_tpu.models import hybrid
+from areal_tpu.models import hybrid, moe
 from benchmark.lib import reference_smallthinker as ref
 from tests.model.test_window import HF, WINDOW, make_cfg
 
@@ -305,3 +305,30 @@ def test_what_moves_whole_rows_between_servers_is_refused_by_name(model):
             call()
     with pytest.raises(NotImplementedError, match="host spill"):
         make_engine(model, prefix_cache_host_bytes=1 << 20)
+
+
+def test_fills_that_take_the_grouped_product_are_the_reference(model, monkeypatch):
+    """The sizes here never reach ``moe.group_rows``' 1,024 tokens, so the
+    rule is set to groups of 2 rows from a fill chunk's 8 slots on: every
+    fill then multiplies its routed pairs in ROUNDS that slice the layer
+    stack themselves, as the cells' fill programs do, beside decode chunks
+    that multiply every held expert; siblings, a queue and reused rows."""
+    monkeypatch.setattr(
+        moe, "group_rows", lambda cfg, n: 2 if n >= 8 and cfg.n_held_experts else 0
+    )
+    jax.clear_caches()  # programs traced under the real rule
+    try:
+        eng = make_engine(model, max_batch=2)
+        prompts = _prompts(2, 9, 17, 4, 37, 6)
+        for i, p in enumerate(prompts):
+            eng.submit(_req(f"q{i}", p, 5 + i))
+        eng.submit(_req("q0b", prompts[0], 7))
+        run_until_done(eng)
+        out = eng.drain_results()
+        assert len(out) == 6
+        assert_reference(model[1], out, eng)
+        assert eng.moe_fill_tokens_grouped_total == eng.moe_fill_tokens_total > 0
+        eng._add_fill_rounds_that_arrived()
+        assert eng.moe_fill_extra_rounds_total > 0
+    finally:
+        jax.clear_caches()

@@ -111,7 +111,7 @@ def test_fill_in_chunks_then_decode_through_slots_and_pages_is_the_reference(
             toks = np.zeros((2, 16), np.int32)
             toks[0, :take] = prompt[pos : pos + take]
             (logits, k_pool, v_pool, ssm, conv, pairs,
-             r) = hybrid.hybrid_fill_chunk(
+             r, rounds) = hybrid.hybrid_fill_chunk(
                 params, k_pool, v_pool, ssm, conv, cfg, jnp.asarray(toks),
                 jnp.asarray([pos, 0], jnp.int32), jnp.asarray([take, 0], jnp.int32),
                 jnp.asarray(tables), jnp.asarray([slot, 0], jnp.int32),
@@ -119,6 +119,9 @@ def test_fill_in_chunks_then_decode_through_slots_and_pages_is_the_reference(
             )
             # every valid token routed top-3 in each of 8 layers
             assert int(pairs.sum()) == take * 3 * 8
+            # no count of rounds comes out of (or rides the loops of) a
+            # program whose experts multiply every held expert
+            assert rounds is None
             routed.append(np.asarray(r)[:, 0, :take].swapaxes(0, 1))
             pos += take
         lp0 = jax.nn.log_softmax(logits[0])
@@ -190,7 +193,7 @@ def test_shares_of_a_stated_split_add_up_to_the_uncut_layer(n_tokens):
             for limit in (1024, 4):  # one call; pieces of 4 tokens
                 old, moe.DENSE_EXPERTS_CALL_TOKENS = moe.DENSE_EXPERTS_CALL_TOKENS, limit
                 try:
-                    out, p, idx = moe.held_moe_mlp(cfg, m, share)
+                    out, p, idx, _ = moe.held_moe_mlp(cfg, m, share)
                 finally:
                     moe.DENSE_EXPERTS_CALL_TOKENS = old
                 if limit == 1024:
@@ -200,7 +203,7 @@ def test_shares_of_a_stated_split_add_up_to_the_uncut_layer(n_tokens):
                     assert np.abs(np.asarray(out[0] - whole_call)).max() < 1e-5
                 assert idx.shape == (1, n_tokens, whole.n_experts_per_tok)
         cfg = make_cfg(moe_first_expert=0, moe_held_experts=0)
-        shared_only, _, _ = moe.held_moe_mlp(
+        shared_only, _, _, _ = moe.held_moe_mlp(
             cfg, m, dict(lp, experts=jax.tree.map(lambda a: a[:0], lp["experts"]))
         )
     assert np.abs(np.asarray(total + shared_only[0] - want)).max() < 1e-5

@@ -210,7 +210,7 @@ def test_shares_of_a_stated_split_add_up_to_the_uncut_layer(model, n_tokens):
                 no_shared,
                 experts=jax.tree.map(lambda a: a[first : first + 2], lp["experts"]),
             )
-            out, p, idx = moe.held_moe_mlp(cfg, m, share)
+            out, p, idx, _ = moe.held_moe_mlp(cfg, m, share)
             assert p.shape == (moe.n_pair_counts(cfg),) == (4,)
             total = total + out[0]
             held_pairs += int(p[:2].sum())
@@ -218,7 +218,7 @@ def test_shares_of_a_stated_split_add_up_to_the_uncut_layer(model, n_tokens):
             hits += int(p[3])
             assert int(p[3]) <= n_tokens  # a chip lies in ONE group
         cfg = make_cfg(moe_first_expert=0, moe_held_experts=0)
-        shared_only, _, _ = moe.held_moe_mlp(
+        shared_only, _, _, _ = moe.held_moe_mlp(
             cfg, m, dict(lp, experts=jax.tree.map(lambda a: a[:0], lp["experts"]))
         )
     assert np.abs(np.asarray(total + shared_only[0] - want)).max() < 2e-5
@@ -407,7 +407,7 @@ def test_fill_in_chunks_then_decode_through_latent_pages_is_the_reference(
             toks = np.zeros((2, 16), np.int32)
             toks[0, :take] = prompt[pos : pos + take]
             (logits, k_pool, v_pool, ssm, conv, pairs,
-             r) = hybrid.hybrid_fill_chunk(
+             r, _) = hybrid.hybrid_fill_chunk(
                 params, k_pool, v_pool, ssm, conv, cfg, jnp.asarray(toks),
                 jnp.asarray([pos, 0], jnp.int32), jnp.asarray([take, 0], jnp.int32),
                 jnp.asarray(tables), jnp.asarray([slot, 0], jnp.int32),

@@ -147,7 +147,7 @@ def test_the_router_reads_the_mixers_input_and_the_experts_their_own():
         lambda t: t[0], hybrid.init_params(cfg, jax.random.PRNGKey(1))["layers"]["mlp"]
     )
     a, m = jax.random.normal(jax.random.PRNGKey(2), (2, 1, 24, 32))
-    out, _, idx = moe.held_moe_mlp(cfg, m, p, router_input=a)
+    out, _, idx, _ = moe.held_moe_mlp(cfg, m, p, router_input=a)
     w, want_idx, _, _ = moe.route(cfg, a[0], p["router"])
     assert np.array_equal(np.asarray(idx[0]), np.asarray(want_idx))
     gate, up, down = (np.asarray(p["experts"][k]) for k in ("gate", "up", "down"))
@@ -159,7 +159,7 @@ def test_the_router_reads_the_mixers_input_and_the_experts_their_own():
             hid = np.maximum(gate[e] @ x[t], 0.0) * (up[e] @ x[t])  # ReLU gate
             want[t] += float(w[t, k]) * (hid @ down[e])
     assert np.abs(np.asarray(out[0]) - want).max() < 1e-5
-    swapped, _, idx2 = moe.held_moe_mlp(cfg, a, p, router_input=m)
+    swapped, _, idx2, _ = moe.held_moe_mlp(cfg, a, p, router_input=m)
     assert not np.array_equal(np.asarray(idx2), np.asarray(idx))
     assert np.abs(np.asarray(swapped) - np.asarray(out)).max() > 1e-2
 
@@ -225,7 +225,7 @@ def test_fill_in_chunks_then_decode_through_two_pools_is_the_reference(
             # which holds another row's values)
             wt = wtables.copy()
             wt[0, : max(pos - WINDOW + 1, 0) // BS] = 0
-            (logits, k_pool, v_pool, ssm, conv, pairs, r,
+            (logits, k_pool, v_pool, ssm, conv, pairs, r, _,
              win) = hybrid.hybrid_fill_chunk(
                 params, k_pool, v_pool, ssm, conv, cfg, jnp.asarray(toks),
                 jnp.asarray([pos, 0], jnp.int32), jnp.asarray([take, 0], jnp.int32),

@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from areal_tpu.models import hybrid, paged, transformer
+from areal_tpu.models import hybrid, moe, paged, transformer
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.ops import flash_attention as fa
 from areal_tpu.ops import paged_attention as pa
@@ -358,22 +358,52 @@ def _serving_program_args(model, n_layers, quantized, place):
     return cfg, params, pool, scales
 
 
-def _pool_copies(compiled, pool_shape):
-    """``computation: instruction`` of every ``copy`` in the optimized
-    HLO whose result has the pool's dimensions: a conversion of the
-    whole pool between two layouts (``{minor_to_major:tiles}``), which
-    no trace is needed to find (docs/observability.md)."""
-    dims = ",".join(str(d) for d in pool_shape)
-    found, computation = [], None
+def _instructions(compiled):
+    """``(computation, name, result type, opcode)`` of every instruction of
+    the optimized HLO."""
+    computation = ""
     for line in compiled.as_text().splitlines():
         head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
         if head:
             computation = ("ENTRY " if head.group(1) else "") + head.group(2)
         m = re.match(
-            r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* copy\(", line
+            r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(", line
         )
-        if m and m.group(2) == dims:
-            found.append(f"{computation}: {m.group(1)}")
+        if m:
+            yield computation, m.group(1), m.group(2), m.group(3)
+
+
+def _pool_copies(compiled, pool_shape):
+    """``computation: instruction`` of every ``copy`` in the optimized
+    HLO whose result has the pool's dimensions: a conversion of the
+    whole pool between two layouts (``{minor_to_major:tiles}``), which
+    no trace is needed to find (docs/observability.md)."""
+    dims = "[" + ",".join(str(d) for d in pool_shape) + "]"
+    return [
+        f"{computation}: {name}"
+        for computation, name, result, op in _instructions(compiled)
+        if op == "copy" and re.match(r"\w+" + re.escape(dims), result)
+    ]
+
+
+def _weights_moved(compiled, weight_shape):
+    """Where the optimized HLO makes a second array of a layer's expert
+    weights (``[E_held, F, D]``): a ``copy`` or ``transpose`` with that
+    result anywhere, or ANY instruction outside the fused computations
+    whose result holds an array of that shape (a slice of the layer stack
+    written out to feed a loop or a custom call, the loop that carries
+    it: inside a product's fusion the slice is read where it lies)."""
+    dims = ",".join(str(d) for d in weight_shape) + "]"
+    dims = ("[" + dims, "[1," + dims)  # a layer's slice keeps the stack's axis
+    found = []
+    for computation, name, result, op in _instructions(compiled):
+        if not any(d in result for d in dims):
+            continue
+        fused = "fused_computation" in computation or "fusion" in computation
+        if op in ("copy", "transpose") or not (
+            fused or op in ("parameter", "bitcast", "get-tuple-element")
+        ):
+            found.append(f"{computation}: {name} {op}")
     return found
 
 
@@ -571,6 +601,12 @@ def test_hybrid_fill_program_fits_and_copies_no_state(
     ).compile()
     text = compiled.as_text()
     assert "paged_attn_fill" in text and "ssm_state_rows" in text
+    # a stack with recurrent state multiplies every held expert at every
+    # shape, `[4, 256]`'s 1,024 slots too (served rows came back non-finite
+    # beside grouped fills, cause not known: ``moe.group_rows``): no round
+    # of 256 rows an expert is laid out anywhere in the program
+    assert moe.group_rows(cfg, F * HYBRID_FILL_C) == 0
+    assert f"[36,{moe.GROUP_ROWS},4096]" not in text
     conv_dims = "[" + ",".join(str(d) for d in conv.shape) + "]"
     carried = [
         line for line in text.splitlines()
@@ -702,8 +738,14 @@ def test_latent_fill_program_fits_beside_weights_and_pool(
     text = compiled.as_text()
     assert "paged_mla_fill" in text and "paged_attn" not in text
     assert "ragged-dot" not in text
+    # the grouped product's rounds (``[1, 1024]``, ``[4, 256]``; ``[2,
+    # 512]`` too) read a layer's held experts where they lie in the stack
+    assert _weights_moved(compiled, (16, 2048, 7168)) == []
     total, temp = _assert_latent_program_fits(compiled, pool)
-    assert 10.6e9 < total, total
+    # the cell's largest program no larger than with every held expert
+    # computed for every token (PR 33: 11.17 / 11.10 / 11.03 GB; grouped
+    # 11.17 / 11.13 / 11.03: a round's float32 rows at `[2, 512]`)
+    assert 10.6e9 < total < {1: 11.18e9, 2: 11.14e9, 4: 11.04e9}[F], total
     print(f"latent fill F={F} C={C}: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
 
 
@@ -798,9 +840,9 @@ WINDOW_PAGE = 512
 
 
 # the cell's largest fill: four rows of a chunk's width (a prompt's last
-# piece with tails behind it); one prompt's chunk `[1, 1024]` counts
-# 11.72 GB and sixteen tails `[16, 256]` 13.34 (PERF.md section 4)
-@pytest.mark.parametrize("F,C", [(4, 1024)])
+# piece with tails behind it), and one prompt's chunk; sixteen tails
+# `[16, 256]` counted 13.34 GB at PR 40 (PERF.md section 4)
+@pytest.mark.parametrize("F,C", [(4, 1024), (1, 1024)])
 def test_window_fill_program_fits_beside_weights_and_two_pools(
     one_chip, monkeypatch, F, C
 ):
@@ -821,8 +863,17 @@ def test_window_fill_program_fits_beside_weights_and_two_pools(
     text = compiled.as_text()
     assert "paged_attn_fill" in text and "paged_window_fill" in text
     assert "ragged-dot" not in text
+    # the grouped product's rounds read a layer's 64 experts where they lie
+    # in the stack: sliced before the rounds' loop they were written out,
+    # 0.755 GB a layer (12.53-13.28 GB at `[1, 1024]`, PR 41)
+    assert _weights_moved(compiled, (64, 768, 2560)) == []
     total, temp = _assert_window_program_fits(compiled, pools)
-    assert 11.4e9 < total, total
+    # with every held expert computed for every token (PR 40): 13.77 GB at
+    # `[4, 1024]` (four pieces of 1,024 tokens x 64 experts), 11.72 at
+    # `[1, 1024]`; grouped 12.32 and 11.82 (one round's float32 rows,
+    # `[64 x 256, 2560]`, are 0.10 GB more than the dense form's hidden;
+    # the cell's largest program is 1.45 GB smaller)
+    assert 11.4e9 < total < {4: 12.4e9, 1: 11.85e9}[F], total
     print(f"window fill F={F} C={C}: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
 
 
