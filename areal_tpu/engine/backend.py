@@ -127,6 +127,7 @@ def make_model(
             params = hybrid.init_params(model_cfg, jax.random.PRNGKey(seed))
             backend_name = (
                 "deepseek_v3" if model_cfg.is_latent
+                else "phi4flash" if model_cfg.is_mamba1
                 else "smallthinker" if model_cfg.n_window_layers
                 else "granitemoehybrid"
             )

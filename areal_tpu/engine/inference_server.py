@@ -420,13 +420,14 @@ class StatefulModelUnsupported(NotImplementedError):
     sequence (``cfg.n_mamba_layers > 0``): that state exists at the end
     of what was computed and nowhere else, so it cannot be cut at a page
     boundary, rewound after a rejected draft, or rebuilt from KV pages
-    another server sends."""
+    another server sends.  ``kinds``: the cache kinds the model holds
+    (the state slots first: they are what refuses)."""
 
-    def __init__(self, feature: str):
+    def __init__(self, feature: str, kinds: str = "recurrent state slots"):
         super().__init__(
-            f"{feature} is not supported for a model with recurrent state "
-            "slots: a state exists where its sequence ends, not at page "
-            "boundaries"
+            f"{feature} is not supported for a model with {kinds}: the "
+            "state slots refuse it (a state exists where its sequence "
+            "ends, not at page boundaries)"
         )
         self.feature = feature
 
@@ -607,10 +608,11 @@ class ContinuousBatchingEngine:
         # requests (:meth:`routed_experts`): what a routing-replay trainer
         # or a parity check follows.  The hybrid stack's programs hand it
         # out; nothing else does yet
-        if keep_routed_experts and not self._by_kind:
+        if keep_routed_experts and not (self._by_kind and cfg.n_experts):
             raise ValueError(
                 "keep_routed_experts: only the hybrid stack's programs "
-                "hand their routing out"
+                "hand their routing out, and only a stack with expert "
+                "layers has any"
             )
         #: window layers have pools, a table and a page rule of their own
         #: (``_init_paged_state``); what moves whole rows' pages between
@@ -646,7 +648,7 @@ class ContinuousBatchingEngine:
                 if not asked:
                     continue
                 if self._stateful:
-                    raise StatefulModelUnsupported(feature)
+                    raise StatefulModelUnsupported(feature, self._cache_kinds())
                 raise NotImplementedError(
                     f"{feature} is not supported for a stack stated by "
                     f"kind {sorted(set(cfg.layer_types))}: its fill and "
@@ -1095,11 +1097,13 @@ class ContinuousBatchingEngine:
         on_tpu = jax.default_backend() == "tpu"
         # (a latent page's row is a whole number of lane tiles by
         # construction: paged.latent_page_width)
+        # (a page's head is ``pool_head_dim`` wide: a differential pair's
+        # two heads of 64 are one of 128)
         self._use_paged_kernel = on_tpu and (
-            cfg.is_latent or cfg.head_dim % 128 == 0
+            cfg.is_latent or cfg.pool_head_dim % 128 == 0
         )
         if on_tpu and not self._use_paged_kernel:
-            _warn_paged_reference(cfg.head_dim)
+            _warn_paged_reference(cfg.pool_head_dim)
         kv_dtype = self.kv_cache_dtype
         if self._pool_sharding is not None:
             shardings = (self._pool_sharding, self._pool_sharding)
@@ -1449,13 +1453,22 @@ class ContinuousBatchingEngine:
         per-token blocks a row: refused by name for a recurrent state and
         for window layers' second table."""
         if self._stateful:
-            raise StatefulModelUnsupported(feature)
+            raise StatefulModelUnsupported(feature, self._cache_kinds())
         if self._windowed:
             raise NotImplementedError(
                 f"{feature} is not supported for a stack with window "
-                "layers: their pages live in a pool and a table of their "
-                "own (engine/window_pages.py), which it does not move"
+                "layers: the window pool refuses it (its pages live in a "
+                "pool and a table of their own, engine/window_pages.py, "
+                "which it does not move)"
             )
+
+    def _cache_kinds(self) -> str:
+        """The cache kinds this engine holds, for a refusal's message."""
+        kinds = ["recurrent state slots"] if self._stateful else []
+        if self._windowed:
+            kinds.append("a window pool")
+        kinds.append("a pool of whole-context pages")
+        return ", ".join(kinds)
 
     # -- window layers' pages (engine/window_pages.py) ----------------------
 
@@ -4295,6 +4308,10 @@ class ContinuousBatchingEngine:
             counts["window_tokens_sum"] = sum(
                 min(c, self.cfg.sliding_window) for c in ctx
             )
+        if self._by_kind and self.cfg.n_cross_layers:
+            # layers that read the pool of whole-context pages each step
+            # (ctx_tokens_sum times this is what a step reads of it)
+            counts["global_readers"] = self.cfg.n_global_readers
         if self.cfg.is_latent:
             # what the latent kernel reads: ONE entry a position and layer
             # whatever the head count (the same floor as ctx_tokens_sum)
@@ -4335,10 +4352,13 @@ class ContinuousBatchingEngine:
             )
             if win_out:
                 self.win_k_pool, self.win_v_pool = win_out[0]
+            # (a stack without expert layers has no pairs to count)
+            extra = () if not self.cfg.n_experts else (
+                (pairs, routed) if self._keep_routed else (pairs,)
+            )
             self._enqueue_chunk(
                 out_t, out_l, emitted, self.active, self.cur_tokens,
-                snapshot,
-                extra=(pairs, routed) if self._keep_routed else (pairs,),
+                snapshot, extra=extra,
             )
             return
         out = paged.paged_decode_chunk(

@@ -13,6 +13,12 @@ import dataclasses
 from typing import Optional, Tuple
 
 
+#: the kinds a layer of a stack stated by kind can be (``layer_types``)
+LAYER_KINDS = (
+    "attention", "window", "mamba", "latent", "mamba1", "gmu", "cross",
+)
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     n_layers: int
@@ -68,13 +74,26 @@ class TransformerConfig:
     moe_first_expert: int = 0
     moe_held_experts: Optional[int] = None
 
-    # a stack stated by kind (models/hybrid.py): one of "attention" |
-    # "window" | "mamba" | "latent" per layer, in the published order;
-    # None = every layer is the attention layer of models/transformer.py.
+    # a stack stated by kind (models/hybrid.py): one of LAYER_KINDS per
+    # layer, in the published order; None = every layer is the attention
+    # layer of models/transformer.py.
     # A "window" layer is an attention layer that attends the last
     # ``sliding_window`` positions (``i - j < sliding_window``) and whose
-    # pages the engine releases once every holder's window has passed them
+    # pages the engine releases once every holder's window has passed them.
+    # A "mamba1" layer is a selective-scan mixer whose decay differs by
+    # channel AND by state index (``mamba_d_state`` x ``mamba_d_inner``
+    # with ``mamba_head_dim`` 1: a channel is a head of one).  A "cross"
+    # layer is an attention layer with queries only: it reads the K and V
+    # of ``kv_shared_layer``, the one "attention" layer before it, from
+    # that layer's pages.  A "gmu" layer gates ``memory_layer``'s scan
+    # output (the last "mamba1" layer's, before ITS gate) by a projection
+    # of its own input, and caches nothing
     layer_types: Optional[Tuple[str, ...]] = None
+    # differential heads: adjacent heads pair up, a pair's two softmax
+    # maps are subtracted under a learned weight, and the pair's output
+    # goes through an RMS norm of twice ``head_dim`` (phi4flash).  A
+    # pair's ``[k1 | k2]`` and ``[v1 | v2]`` are ONE cached head
+    diff_attention: bool = False
     # per layer of a stack stated by kind: whether its attention mixer
     # ropes q and k (smallthinker ``rope_layout``: the global layers have
     # no position term); None = every layer follows ``use_rope``
@@ -109,13 +128,16 @@ class TransformerConfig:
     rope_yarn_beta_slow: float = 1.0
     rope_yarn_mscale: float = 1.0
     rope_yarn_mscale_all_dim: float = 0.0
-    # Mamba-2 mixer sizes (d_inner = mamba_n_heads * mamba_head_dim)
+    # Mamba mixer sizes (d_inner = mamba_n_heads * mamba_head_dim).  A
+    # "mamba1" layer's ``dt`` comes through a rank-``mamba_dt_rank``
+    # bottleneck; its scan goes token by token (no ``mamba_chunk_size``)
     mamba_n_heads: int = 0
     mamba_head_dim: int = 0
     mamba_d_state: int = 0
     mamba_n_groups: int = 1
     mamba_d_conv: int = 4
     mamba_chunk_size: int = 256
+    mamba_dt_rank: int = 0
     # granite's multipliers: softmax scale of attention (None =
     # 1/sqrt(head_dim)), the factor on every residual branch, and the
     # divisor of the logits; ``embed_scale`` above is the fourth
@@ -169,17 +191,33 @@ class TransformerConfig:
                 f"n_layers is {self.n_layers}"
             )
             kinds = set(self.layer_types)
-            assert kinds <= {"attention", "window", "mamba", "latent"}, (
-                self.layer_types
+            assert kinds <= set(LAYER_KINDS), (
+                f"layer_types {sorted(kinds - set(LAYER_KINDS))}: a layer "
+                f"is one of {LAYER_KINDS}"
             )
             # one page format a pool: per-head K and V, or the latent
             assert not (
-                "latent" in kinds and kinds & {"attention", "window"}
+                "latent" in kinds and kinds & {"attention", "window", "cross"}
             ), self.layer_types
+            # one state format the slots: Mamba-2's or Mamba-1's
+            assert not {"mamba", "mamba1"} <= kinds, self.layer_types
             if "latent" in kinds:
                 assert self.kv_lora_rank > 0 and self.qk_rope_head_dim > 0
             if "window" in kinds:
                 assert self.sliding_window and self.sliding_window > 1
+            if "mamba1" in kinds:
+                assert self.mamba_head_dim == 1 and self.mamba_dt_rank > 0
+            if "cross" in kinds:
+                first = self.layer_types.index("cross")
+                assert self.layer_types[:first].count("attention") == 1 and (
+                    "attention" not in self.layer_types[first:]
+                ), "cross layers read the ONE attention layer before them"
+            if "gmu" in kinds:
+                assert "mamba1" in self.layer_types[
+                    : self.layer_types.index("gmu")
+                ], "a gmu layer gates the scan output of a mamba1 layer"
+        if self.diff_attention:
+            assert self.n_kv_heads % 2 == 0 and not self.use_qk_norm
         if self.rope_layers is not None:
             assert self.layer_types is not None, "rope_layers: a stack by kind"
             object.__setattr__(
@@ -235,10 +273,14 @@ class TransformerConfig:
 
     @property
     def n_attn_layers(self) -> int:
-        """Layers that keep per-token KV (every layer of a dense stack)."""
+        """Layers that WRITE per-token KV (every layer of a dense stack;
+        "attention", "window" and "latent" layers of a stack by kind: a
+        "cross" layer reads another's, a "gmu" layer has none)."""
         if self.layer_types is None:
             return self.n_layers
-        return sum(t != "mamba" for t in self.layer_types)
+        return sum(
+            t in ("attention", "window", "latent") for t in self.layer_types
+        )
 
     @property
     def n_window_layers(self) -> int:
@@ -251,7 +293,58 @@ class TransformerConfig:
     @property
     def n_mamba_layers(self) -> int:
         """Layers that keep a recurrent state per sequence."""
-        return self.n_layers - self.n_attn_layers
+        if self.layer_types is None:
+            return 0
+        return sum(t in ("mamba", "mamba1") for t in self.layer_types)
+
+    @property
+    def n_cross_layers(self) -> int:
+        if self.layer_types is None:
+            return 0
+        return sum(t == "cross" for t in self.layer_types)
+
+    @property
+    def n_gmu_layers(self) -> int:
+        if self.layer_types is None:
+            return 0
+        return sum(t == "gmu" for t in self.layer_types)
+
+    @property
+    def is_mamba1(self) -> bool:
+        return self.layer_types is not None and "mamba1" in self.layer_types
+
+    @property
+    def kv_shared_layer(self) -> Optional[int]:
+        """The layer whose K and V the "cross" layers read."""
+        if not self.n_cross_layers:
+            return None
+        return self.layer_types.index("attention")
+
+    @property
+    def memory_layer(self) -> Optional[int]:
+        """The layer whose scan output the "gmu" layers gate: the last
+        "mamba1" layer before the first of them."""
+        if not self.n_gmu_layers:
+            return None
+        first = self.layer_types.index("gmu")
+        return max(
+            l for l in range(first) if self.layer_types[l] == "mamba1"
+        )
+
+    @property
+    def n_global_readers(self) -> int:
+        """Layers that read the pool of whole-context pages each decode
+        step (those that write it and the "cross" layers)."""
+        return self.n_attn_layers - self.n_window_layers + self.n_cross_layers
+
+    @property
+    def pool_kv_heads(self) -> int:
+        """Heads of a per-head page (a differential pair is one)."""
+        return self.n_kv_heads // 2 if self.diff_attention else self.n_kv_heads
+
+    @property
+    def pool_head_dim(self) -> int:
+        return 2 * self.head_dim if self.diff_attention else self.head_dim
 
     def layer_ropes(self, layer: int) -> bool:
         """Whether layer ``layer``'s attention mixer ropes q and k."""
@@ -290,6 +383,10 @@ class TransformerConfig:
 
     @property
     def mamba_conv_dim(self) -> int:
+        """Channels of the causal conv: ``[x | B | C]`` (Mamba-2), ``x``
+        alone (Mamba-1 makes B and C of the conv's output)."""
+        if self.is_mamba1:
+            return self.mamba_d_inner
         return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
 

@@ -6,6 +6,7 @@ from areal_tpu.models.hf import (  # noqa: F401
     granitemoehybrid,
     llama_like,
     mixtral,
+    phi4flash,
     qwen3_moe,
     smallthinker,
 )

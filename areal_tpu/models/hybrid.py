@@ -1,22 +1,29 @@
-"""A stack stated by kind: each layer a MIXER (Mamba-2 with a recurrent
-state, attention with paged per-head KV over the whole context or over a
-window of it, latent attention with paged latent entries) and an MLP (a
-dense one on the leading ``cfg.n_dense_layers`` layers, else the expert
-block this program's share of the experts gives) (granitemoehybrid,
-deepseek_v3, smallthinker).
+"""A stack stated by kind: each layer a MIXER (Mamba-2 or Mamba-1 with a
+recurrent state, attention with paged per-head KV over the whole context
+or over a window of it, latent attention with paged latent entries,
+CROSS attention over another layer's pages, a gated memory unit over
+another layer's scan output) and an MLP (a dense one on the leading
+``cfg.n_dense_layers`` layers, else the expert block this program's share
+of the experts gives) (granitemoehybrid, deepseek_v3, smallthinker,
+phi4flash).
 
     h0 = embed_scale * embed[tokens]
-    per layer:  h += r * mixer(rmsnorm(h));  m = rmsnorm(h)
+    per layer:  h += r * mixer(norm(h));  m = norm(h)
                 h += r * (experts(m) + shared(m))   or   r * dense(m)
-    logits = rmsnorm(h) @ head / logits_divisor     (head: embed^T if tied)
+    logits = norm(h) @ head / logits_divisor     (head: embed^T if tied)
+
+(``norm``: ``cfg.norm_type``, an RMS norm or a LayerNorm with bias.)
 
 **The layer plan** (:func:`layer_plan`): the published ``layer_types`` cut
 into runs of one (mixer, MLP) pair, in order; a run is one ``lax.scan``
-over its layers.  Parameters are stacked BY KIND: ``params["mamba"]``
+over its layers, and a stretch of single layers that repeats a pattern
+(``[mamba1, window] x 8``) is one scan over the pattern's repetitions
+(:func:`plan_periods`).  Parameters are stacked BY KIND: ``params["mamba"]``
 over the Mamba mixers, ``params["attn"]`` over the attention mixers,
 ``params["latent"]`` over the latent ones, ``params["dense"]`` over the
 dense MLPs, ``params["layers"]["mlp"]`` over the expert blocks and the two
-norms of ``params["layers"]`` over all layers; a run's body indexes them
+norms of ``params["layers"]`` over all layers (``params["mamba1"]``,
+``params["cross"]`` and ``params["gmu"]`` likewise); a run's body indexes them
 by the layer's number and by its numbers among its kinds.  The dense stack
 of ``transformer.py`` / ``paged.py`` does not go through this module, and
 this module calls their functions where they fit (``_norm``, ``_embed``,
@@ -34,6 +41,41 @@ layer's pages from the one that holds the window's first position
 ``cfg.layer_ropes`` (smallthinker's global layers have no position term),
 and an expert layer's router reads the mixer's input where
 ``cfg.moe_router_input == "attn"``.
+
+**Differential heads** (``cfg.diff_attention``): adjacent heads pair up;
+a pair ``j`` makes two softmax maps ``P1 = softmax(q1 k1^T s)``, ``P2 =
+softmax(q2 k2^T s)`` over ONE value ``[v1 | v2]`` and gives
+``rmsnorm((P1 - lam P2) V) (1 - lam0)``, ``lam = exp(lq1 . lk1) - exp(lq2
+. lk2) + lam0``, ``lam0 = 0.8 - 0.6 exp(-0.3 l)`` at layer ``l``.  A pair
+is ONE cached head of ``2 head_dim`` (``[k1 | k2]``, ``[v1 | v2]``: a
+whole lane tile where a head of 64 is half of one), and its two maps are
+two QUERY heads of that width, ``[q1 | 0]`` and ``[0 | q2]``: the paged
+kernel, ``chunk_attention`` and ``window_attention`` then return ``P1 V``
+and ``P2 V`` as they return any two heads' outputs (:func:`_diff_qkv`),
+and the weight, the norm and ``W_o`` follow outside (:func:`_diff_out`).
+The zeros double the score products and no byte of the cache.
+
+**One written cache layer, many readers** (kinds ``"attention"`` +
+``"cross"``): the ONE attention layer of such a stack writes the pool of
+whole-context pages (one layer); each cross layer makes queries only and
+attends that layer's pages, and in a fill or a decode chunk that layer's
+K and V of the chunk's own tokens, which are in hand once: no copy of
+either per reading layer.  **The gated memory unit** (kind ``"gmu"``)
+caches nothing: ``out = (silu(a W_1) * m) W_2`` with ``m`` the scan output
+``y`` of ``cfg.memory_layer`` (the last Mamba-1 layer, before ITS gate),
+which every program hands from that layer to the last of them.
+
+**The Mamba-1 mixer** (kind ``"mamba1"``) has three forms over one set of
+equations (``[x | z] = a W_in``; ``x = silu(causal depthwise conv)``;
+``[d | B | C] = x W_x``; ``dt = softplus(d W_dt + b_dt)``; ``S_t =
+exp(dt_t (x) A) S_{t-1} + (dt_t x_t) (x) B_t``, the decay differing by
+channel AND by state index; ``y_t = S_t C_t + D x_t``; ``out = (y *
+silu(z)) W_out``): whole sequence / fill chunk (:func:`mamba1_chunk`: the
+recurrence as a scan over the chunk's positions from a given state and
+conv tail) and one decode step over the engine's slots
+(:func:`mamba1_step`, by ``ops/ssm.ssm_state_update`` with ``a=``).  Its
+state is ``[N, d_inner]`` a sequence and layer like Mamba-2's, its conv
+tail holds ``x`` alone.
 
 **The latent mixer** (MLA) has three forms over one set of equations
 (``c_q = rmsnorm(a W_qa)``, ``[q_nope | q_rope]_i = c_q W_qb``; ``[c_kv |
@@ -115,7 +157,7 @@ HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class Run(NamedTuple):
-    kind: str  # the mixer: "attention" | "window" | "mamba" | "latent"
+    kind: str  # the mixer: one of ``config.LAYER_KINDS``
     mlp: str  # "dense" | "experts"
     first_layer: int  # number of the run's first layer in the stack
     #: its number in its mixer's parameter stack ("attention" and
@@ -127,6 +169,11 @@ class Run(NamedTuple):
     #: its number among the layers of its POOL (a window layer's among the
     #: window layers, an attention layer's among those; else first_of_kind)
     first_in_pool: int = 0
+    #: inside a PERIOD (:func:`plan_periods`) a run holds every
+    #: ``every``-th layer from its first, and its numbers among its kind,
+    #: its MLP kind and its pool advance by ``strides`` a layer
+    every: int = 1
+    strides: Tuple[int, int, int] = (1, 1, 1)
 
 
 def _param_kind(kind: str) -> str:
@@ -157,6 +204,55 @@ def layer_plan(cfg: TransformerConfig) -> Tuple[Run, ...]:
     return tuple(runs)
 
 
+#: the longest pattern of single layers :func:`plan_periods` looks for
+MAX_PERIOD = 4
+
+
+def plan_periods(cfg: TransformerConfig) -> Tuple[Tuple[Run, ...], ...]:
+    """:func:`layer_plan` with each stretch of SINGLE layers that repeats
+    a pattern (``[mamba1, window] x 8``: sixteen runs of one layer) folded
+    into one PERIOD: a tuple of runs, one a position of the pattern, each
+    holding every ``every``-th layer.  A period is one ``lax.scan`` whose
+    trip runs one layer of each of its runs in turn, so the program holds
+    the pattern once and not once a repetition (a stack that alternates
+    its kinds would otherwise be unrolled whole: 32 layer bodies to
+    compile, in every fill shape).  Every other run is a period of its
+    own, and its scan is what it was."""
+    runs = layer_plan(cfg)
+
+    def same(a: Run, b: Run):
+        return (a.kind, a.mlp, a.rope, a.count) == (b.kind, b.mlp, b.rope, 1)
+
+    out, i = [], 0
+    while i < len(runs):
+        for p in range(2, MAX_PERIOD + 1):
+            n = 1
+            while i + (n + 1) * p <= len(runs) and all(
+                same(runs[i + k], runs[i + n * p + k]) for k in range(p)
+            ):
+                n += 1
+            if n > 1 and all(r.count == 1 for r in runs[i : i + p]):
+                out.append(
+                    tuple(
+                        a._replace(
+                            count=n, every=p,
+                            strides=(
+                                b.first_of_kind - a.first_of_kind,
+                                b.first_of_mlp - a.first_of_mlp,
+                                b.first_in_pool - a.first_in_pool,
+                            ),
+                        )
+                        for a, b in zip(runs[i : i + p], runs[i + p : i + 2 * p])
+                    )
+                )
+                i += n * p
+                break
+        else:
+            out.append((runs[i],))
+            i += 1
+    return tuple(out)
+
+
 def pool_layer_numbers(cfg: TransformerConfig, kind: str) -> np.ndarray:
     """The numbers, in ``params["attn"]``'s stack, of the layers whose
     pages live in ``kind``'s pool, in the pool's order."""
@@ -172,22 +268,75 @@ def pool_layer_numbers(cfg: TransformerConfig, kind: str) -> np.ndarray:
 def _run_indices(run: Run):
     """``(layer numbers, numbers in the mixer's parameter stack, among
     the MLP kind, in the mixer's pool)`` of a run's layers."""
-    return tuple(
-        jnp.arange(first, first + run.count)
-        for first in (
-            run.first_layer, run.first_of_kind, run.first_of_mlp,
-            run.first_in_pool,
-        )
+    firsts = (
+        run.first_layer, run.first_of_kind, run.first_of_mlp,
+        run.first_in_pool,
     )
+    if run.every == 1:
+        return tuple(jnp.arange(first, first + run.count) for first in firsts)
+    return tuple(
+        first + stride * jnp.arange(run.count)
+        for first, stride in zip(firsts, (run.every,) + run.strides)
+    )
+
+
+def _of_kind(run: Run) -> slice:
+    """A run's layers in its mixer's parameter stack."""
+    return slice(
+        run.first_of_kind, run.first_of_kind + run.count * run.strides[0],
+        run.strides[0],
+    )
+
+
+def _scan_period(step, carry, period: Tuple[Run, ...], xs_of):
+    """``lax.scan`` over a period's repetitions: a trip runs
+    ``step(carry, xs, run) -> (carry, ys)`` for one layer of each of the
+    period's runs in turn (``xs_of(run)``: that run's scanned inputs).
+    Returns ``(carry, [a run's stacked ys, ...])``."""
+    if len(period) == 1:
+        (run,) = period
+        carry, ys = scan_layers(
+            lambda c, xs: step(c, xs, run), carry, xs_of(run)
+        )
+        return carry, [ys]
+
+    def trip(c, xs):
+        ys = []
+        for run, x in zip(period, xs):
+            c, y = step(c, x, run)
+            ys.append(y)
+        return c, tuple(ys)
+
+    carry, ys = scan_layers(trip, carry, tuple(xs_of(run) for run in period))
+    return carry, list(ys)
 
 
 def _mixer_region(run: Run):
     """The region of a layer's first half (norm, mixer, residual add)."""
-    if run.kind == "mamba":
+    if run.kind in ("mamba", "mamba1"):
         return region("areal.ssm")
     if run.kind == "window":
         return region("areal.attn.window")
+    if run.kind == "cross":
+        return region("areal.attn.cross")
+    if run.kind == "gmu":
+        return region("areal.gmu")
     return region("areal.attn")
+
+
+def _place_in(run: Run, layer: Optional[int]) -> Optional[int]:
+    """Where among a run's layers layer ``layer`` stands, or None."""
+    if layer is None:
+        return None
+    at, left = divmod(layer - run.first_layer, run.every)
+    return at if not left and 0 <= at < run.count else None
+
+
+def _held(run: Run, layer: Optional[int], ys):
+    """Layer ``layer``'s entry of what a run's scan stacked (``ys``, a
+    tree), or None where the run does not hold that layer."""
+    at = _place_in(run, layer)
+    return None if at is None else jax.tree.map(lambda a: a[at], ys)
 
 
 def _rope_cfg(cfg: TransformerConfig, run: Run) -> TransformerConfig:
@@ -242,13 +391,15 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     every log-probability read 0 to five places, my chip run, PR 31; at
     the dense family's 1/sqrt(D) the logits are uniform to 0.01.)  An
     untied head is one more matrix (logits of deviation 0.58 over a
-    final norm of rms 1), beside an embedding of rms 0.5."""
-    assert cfg.is_hybrid and cfg.is_moe
+    final norm of rms 1), beside an embedding of rms 0.5.  A LayerNorm's
+    bias is uniform in +-0.1; the newer kinds' pieces are in
+    :func:`_init_newer_kinds`."""
+    assert cfg.is_hybrid and (cfg.is_moe or cfg.n_dense_layers == cfg.n_layers)
     dt = jnp.dtype(cfg.dtype)
     L, Le, Ld = cfg.n_layers, cfg.n_expert_layers, cfg.n_dense_layers
-    Lm = cfg.n_mamba_layers
-    Ll = L - Lm if cfg.is_latent else 0
-    La = L - Lm - Ll  # attention and window layers: one stack
+    Lm = 0 if cfg.is_mamba1 else cfg.n_mamba_layers
+    Ll = cfg.n_attn_layers if cfg.is_latent else 0
+    La = cfg.n_attn_layers - Ll  # attention and window layers: one stack
     D, E, Eh = cfg.hidden_dim, cfg.n_experts, cfg.n_held_experts
     Fe, Fs = cfg.moe_intermediate_dim, cfg.shared_expert_dim
     Hq, Hkv, hd = cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim
@@ -275,7 +426,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
             "up": mat(Le, (Eh, Fe, D), D),
             "down": mat(Le, (Eh, Fe, D), Fe),
         },
-    }
+    } if Le else {}
     if Fs:
         mlp["shared"] = {
             "gate": {"w": mat(Le, (D, Fs), D)},
@@ -299,7 +450,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         "layers": {
             "attn_norm": {"scale": ones(L, D)},
             "mlp_norm": {"scale": ones(L, D)},
-            "mlp": mlp,
+            **({"mlp": mlp} if Le else {}),
         },
     }
     if Lm:
@@ -351,7 +502,91 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
                 next(more), 1, (D, cfg.vocab_size), 1.0 / np.sqrt(D), dt
             )[0]
         }
+    _init_newer_kinds(cfg, params, jax.random.fold_in(key, 2))
     return params
+
+
+def _init_newer_kinds(cfg: TransformerConfig, params: Params, key):
+    """What ``"mamba1"``, ``"cross"`` and ``"gmu"`` layers, differential
+    heads, attention biases and a LayerNorm add to :func:`init_params`'
+    tree, from a stream of their own (a seed's weights of the older kinds
+    stay what they were).  ``A`` uniform in (-16, -1) per (state index,
+    channel) and ``dt`` in (0.001, 0.1), as the Mamba-2 mixer's; the
+    pairs' four lambda vectors uniform in +-0.3, so that ``lam - lam0``
+    spreads by a few tenths."""
+    dt = jnp.dtype(cfg.dtype)
+    D, di, N = cfg.hidden_dim, cfg.mamba_d_inner, cfg.mamba_d_state
+    K, R = cfg.mamba_d_conv, cfg.mamba_dt_rank
+    Hq, hd = cfg.n_q_heads, cfg.head_dim
+    keys = iter(jax.random.split(key, 64))
+
+    def mat(n, shape, fan_in):
+        return _uniform_stack(next(keys), n, shape, 1.0 / np.sqrt(fan_in), dt)
+
+    def around(n, shape, lo, hi):
+        return jax.random.uniform(next(keys), (n,) + shape, F32, lo, hi).astype(dt)
+
+    def diff_parts(n):
+        return {
+            **{
+                name: around(n, (hd,), -0.3, 0.3)
+                for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+            },
+            "subln": {"scale": around(n, (2 * hd,), 0.75, 1.25)},
+        }
+
+    if cfg.norm_type == "layer":
+        for norm, n in (
+            (params["layers"]["attn_norm"], (cfg.n_layers, D)),
+            (params["layers"]["mlp_norm"], (cfg.n_layers, D)),
+            (params["final_norm"], (D,)),
+        ):
+            norm["bias"] = around(n[0], n[1:], -0.1, 0.1)
+    attn = params.get("attn")
+    if attn is not None:
+        La = attn["q"]["w"].shape[0]
+        if cfg.use_attention_bias:
+            for name in ("q", "k", "v", "o"):
+                attn[name]["b"] = mat(La, attn[name]["w"].shape[-1:], 64)
+        if cfg.diff_attention:
+            attn.update(diff_parts(La))
+    Lc, Lg = cfg.n_cross_layers, cfg.n_gmu_layers
+    if Lc:
+        params["cross"] = {
+            "q": {"w": mat(Lc, (D, Hq * hd), D)},
+            "o": {"w": mat(Lc, (Hq * hd, D), Hq * hd)},
+        }
+        if cfg.use_attention_bias:
+            params["cross"]["q"]["b"] = mat(Lc, (Hq * hd,), 64)
+            params["cross"]["o"]["b"] = mat(Lc, (D,), 64)
+        if cfg.diff_attention:
+            params["cross"].update(diff_parts(Lc))
+    if Lg:
+        params["gmu"] = {
+            "in_proj": {"w": mat(Lg, (D, di), D)},
+            "out_proj": {"w": mat(Lg, (di, D), di)},
+        }
+    if cfg.is_mamba1:
+        Lm = cfg.n_mamba_layers
+        u = jax.random.uniform(next(keys), (Lm, di), F32)
+        dt0 = jnp.exp(u * (np.log(0.1) - np.log(0.001)) + np.log(0.001))
+        params["mamba1"] = {
+            "in_proj": {"w": mat(Lm, (D, 2 * di), D)},
+            "conv": {"w": mat(Lm, (K, di), K), "b": mat(Lm, (di,), 16)},
+            "x_proj": {"w": mat(Lm, (di, R + 2 * N), di)},
+            # the bias is added in float32, before the softplus
+            "dt_proj": {
+                "w": mat(Lm, (R, di), R),
+                "b": dt0 + jnp.log(-jnp.expm1(-dt0)),
+            },
+            # [N, d_inner], the state's own layout (the published
+            # ``A_log`` is its transpose)
+            "A_log": jnp.log(
+                jax.random.uniform(next(keys), (Lm, N, di), F32, 1.0, 16.0)
+            ),
+            "D": around(Lm, (di,), 0.75, 1.25),
+            "out_proj": {"w": mat(Lm, (di, D), di)},
+        }
 
 
 def state_zeros(cfg: TransformerConfig, slots: int):
@@ -534,6 +769,22 @@ def mamba_chunk(cfg: TransformerConfig, mp: Params, h, n_valid, s0, tail0):
     return out, s.reshape(B, N, H * P), tail
 
 
+def _conv_step(mp: Params, new, conv, j, live):
+    """One position of the causal conv over every slot: ``new`` [S, cd]
+    behind layer ``j``'s tails of ``conv``.  Returns ``(the conv's output
+    before its silu, float32; conv with the live slots' tails moved on)``."""
+    tail = jax.lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
+    xp = jnp.concatenate([tail, new[None].astype(tail.dtype)], axis=0)
+    w = mp["conv"]["w"].astype(F32)  # [K, cd]
+    acc = mp["conv"]["b"].astype(F32) + jnp.sum(
+        w[:, None, :] * xp.astype(F32), axis=0
+    )
+    conv = jax.lax.dynamic_update_index_in_dim(
+        conv, jnp.where(live[None, :, None], xp[1:], tail), j, 0
+    )
+    return acc, conv
+
+
 def mamba_step(
     cfg: TransformerConfig, mp: Params, h, ssm, conv, j, live, use_kernel
 ):
@@ -544,15 +795,7 @@ def mamba_step(
     ssm, conv)``."""
     P = cfg.mamba_head_dim
     z, xbc, dt_raw = _split_in_proj(cfg, mp, h[:, 0])
-    tail = jax.lax.dynamic_index_in_dim(conv, j, 0, keepdims=False)
-    xp = jnp.concatenate([tail, xbc[None].astype(tail.dtype)], axis=0)
-    w = mp["conv"]["w"].astype(F32)  # [K, cd]
-    acc = mp["conv"]["b"].astype(F32) + jnp.sum(
-        w[:, None, :] * xp.astype(F32), axis=0
-    )
-    conv = jax.lax.dynamic_update_index_in_dim(
-        conv, jnp.where(live[None, :, None], xp[1:], tail), j, 0
-    )
+    acc, conv = _conv_step(mp, xbc, conv, j, live)
     x, bm, cm = _split_conv_out(cfg, jax.nn.silu(acc).astype(h.dtype))
     dt, a_neg = _dt_and_a(mp, dt_raw)  # [S, H]
     decay = jnp.repeat(jnp.exp(dt * a_neg), P, axis=-1)
@@ -566,6 +809,184 @@ def mamba_step(
     else:
         y, ssm = ssm_ops.ssm_state_update_reference(*args)
     return _mamba_out(cfg, mp, y, x, z)[:, None], ssm, conv
+
+
+# ---------------------------------------------------------------------------
+# the Mamba-1 mixer
+# ---------------------------------------------------------------------------
+
+
+def _m1_in(cfg: TransformerConfig, mp: Params, h):
+    """``(x [.., d_inner], z [.., d_inner])``."""
+    xz = _proj(mp["in_proj"], h)
+    return xz[..., : cfg.mamba_d_inner], xz[..., cfg.mamba_d_inner :]
+
+
+def _m1_dt_b_c(cfg: TransformerConfig, mp: Params, x):
+    """``(dt [.., d_inner], B [.., N], C [.., N])`` of the conv's output
+    ``x``, float32; ``dt`` after its bias and the softplus."""
+    R, N = cfg.mamba_dt_rank, cfg.mamba_d_state
+    dbc = _proj(mp["x_proj"], x)
+    dt_raw = dbc[..., :R] @ quantize.leaf_weight(mp["dt_proj"], x.dtype)
+    dt = jax.nn.softplus(dt_raw.astype(F32) + mp["dt_proj"]["b"].astype(F32))
+    return dt, dbc[..., R : R + N].astype(F32), dbc[..., R + N :].astype(F32)
+
+
+def _m1_out(mp: Params, y, x, z):
+    """``y`` [.., d_inner] float32 (without the skip) -> ``(the mixer's
+    output, y + D x in the model's dtype)``: the second is what a gated
+    memory unit reads, the scan's output before this layer's gate."""
+    y = (y + mp["D"].astype(F32) * x.astype(F32)).astype(z.dtype)
+    gated = y.astype(F32) * jax.nn.silu(z.astype(F32))
+    return _proj(mp["out_proj"], gated.astype(z.dtype)), y
+
+
+#: positions a trip of the fill scan's loop takes (``lax.scan``'s unroll)
+M1_SCAN_UNROLL = 4
+
+
+def selective_scan(x, dt, a_neg, bm, cm, s0):
+    """The Mamba-1 recurrence over a window, position by position.
+
+    ``x`` / ``dt`` [B, T, C] (``dt`` 0 where a position is not valid: no
+    decay, no input), ``a_neg`` [N, C] (< 0), ``bm`` / ``cm`` [B, T, N],
+    ``s0`` [B, N, C]; all float32.  Returns ``(y [B, T, C], state after
+    the last position)``.  The decay ``exp(dt_t[c] a[n, c])`` is a tile a
+    position, so there is no product form over a chunk as Mamba-2's SSD
+    has; the loop carries one row's ``[N, C]`` state and nothing a
+    position wide."""
+
+    def step(s, inp):
+        dt_t, dtx_t, b_t, c_t = inp
+        s = s * jnp.exp(dt_t[:, None, :] * a_neg) + (
+            b_t[:, :, None] * dtx_t[:, None, :]
+        )
+        return s, jnp.sum(s * c_t[:, :, None], axis=1)
+
+    s, y = jax.lax.scan(
+        step, s0,
+        tuple(t.swapaxes(0, 1) for t in (dt, dt * x, bm, cm)),
+        unroll=M1_SCAN_UNROLL,
+    )
+    return y.swapaxes(0, 1), s
+
+
+def mamba1_chunk(cfg: TransformerConfig, mp: Params, h, n_valid, s0, tail0):
+    """The Mamba-1 mixer over a window ``h`` [B, T, D] whose first
+    ``n_valid[b]`` positions are real, from state ``s0`` [B, N, d_inner]
+    float32 and conv tail ``tail0`` [B, K-1, d_inner].  Returns ``(out [B,
+    T, D], state, tail, y [B, T, d_inner])`` after each row's last real
+    position (``y``: see :func:`_m1_out`)."""
+    T = h.shape[1]
+    x, z = _m1_in(cfg, mp, h)
+    x, tail = causal_conv(x, tail0, mp["conv"]["w"], mp["conv"]["b"], n_valid)
+    dt, bm, cm = _m1_dt_b_c(cfg, mp, x)
+    valid = jnp.arange(T)[None, :] < n_valid[:, None]
+    dt = jnp.where(valid[..., None], dt, 0.0)
+    y, s = selective_scan(
+        x.astype(F32), dt, -jnp.exp(mp["A_log"].astype(F32)), bm, cm, s0
+    )
+    out, y = _m1_out(mp, y, x, z)
+    return out, s, tail, y
+
+
+def mamba1_step(
+    cfg: TransformerConfig, mp: Params, h, ssm, conv, j, live, use_kernel
+):
+    """The Mamba-1 mixer for ONE new position of every slot
+    (:func:`mamba_step`'s contract).  Returns ``(out [S, 1, D], ssm, conv,
+    y [S, 1, d_inner])``."""
+    x, z = _m1_in(cfg, mp, h[:, 0])
+    acc, conv = _conv_step(mp, x, conv, j, live)
+    x = jax.nn.silu(acc).astype(h.dtype)
+    dt, bm, cm = _m1_dt_b_c(cfg, mp, x)
+    args = (ssm, j, dt, dt * x.astype(F32), bm, cm, live)
+    a_neg = -jnp.exp(mp["A_log"].astype(F32))
+    if use_kernel:
+        y, ssm = ssm_ops.ssm_state_update(
+            *args, a=a_neg, interpret=paged.kernel_interpret()
+        )
+        y = jnp.where(live[:, None], y, 0.0)  # a dead slot's is not written
+    else:
+        y, ssm = ssm_ops.ssm_state_update_reference(*args, a=a_neg)
+    out, y = _m1_out(mp, y, x, z)
+    return out[:, None], ssm, conv, y[:, None]
+
+
+def gmu(gp: Params, a, mem):
+    """The gated memory unit: ``(silu(a W_1) * mem) W_2``."""
+    gate = jax.nn.silu(_proj(gp["in_proj"], a).astype(F32))
+    return _proj(gp["out_proj"], (gate * mem.astype(F32)).astype(a.dtype))
+
+
+# ---------------------------------------------------------------------------
+# attention heads as the caches hold them: plain, or differential pairs
+# ---------------------------------------------------------------------------
+
+
+def _heads_q(cfg: TransformerConfig, ap: Params, h, positions, run: Run):
+    """A layer's queries ``[B, T, Hq, pool_head_dim]``: a differential
+    pair's two as ``[q1 | 0]`` and ``[0 | q2]`` (module docstring)."""
+    B, T, _ = h.shape
+    if not cfg.diff_attention:
+        return _attn_qkv(
+            _rope_cfg(cfg, run), {"attn": ap}, h, positions, None
+        )[0]
+    assert not cfg.layer_ropes(run.first_layer), "roped differential heads"
+    q = _proj(ap["q"], h).reshape(B, T, cfg.n_q_heads // 2, 2, cfg.head_dim)
+    zeros = jnp.zeros_like(q[..., 0, :])
+    return jnp.stack(
+        [
+            jnp.concatenate([q[..., 0, :], zeros], axis=-1),
+            jnp.concatenate([zeros, q[..., 1, :]], axis=-1),
+        ],
+        axis=3,
+    ).reshape(B, T, cfg.n_q_heads, 2 * cfg.head_dim)
+
+
+def _heads_qkv(cfg: TransformerConfig, ap: Params, h, positions, run: Run):
+    """``(q, k, v)`` of an attention or window layer, k and v ``[B, T,
+    pool_kv_heads, pool_head_dim]`` as a page holds them (a differential
+    pair's ``[k1 | k2]`` and ``[v1 | v2]`` are adjacent heads' columns: a
+    reshape)."""
+    if not cfg.diff_attention:
+        return _attn_qkv(_rope_cfg(cfg, run), {"attn": ap}, h, positions, None)
+    B, T, _ = h.shape
+    shape = (B, T, cfg.pool_kv_heads, cfg.pool_head_dim)
+    return (
+        _heads_q(cfg, ap, h, positions, run),
+        _proj(ap["k"], h).reshape(shape),
+        _proj(ap["v"], h).reshape(shape),
+    )
+
+
+def _attn_dtype(cfg: TransformerConfig, dtype):
+    """What the attention functions hand to :func:`_heads_out`: a pair's
+    two outputs are subtracted, so they stay float32 until they are."""
+    return F32 if cfg.diff_attention else dtype
+
+
+def _heads_out(cfg: TransformerConfig, ap: Params, l, attn, dtype):
+    """Attention's output ``[B, T, Hq * pool_head_dim]`` through the
+    pairs' difference, weight and norm (differential heads) and ``W_o``."""
+    if not cfg.diff_attention:
+        return _proj(ap["o"], attn)
+    B, T, _ = attn.shape
+    o = attn.reshape(B, T, cfg.n_q_heads // 2, 2, 2 * cfg.head_dim).astype(F32)
+    lam0 = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(l, F32))
+
+    def dot(a, b):
+        return jnp.sum(ap[a].astype(F32) * ap[b].astype(F32))
+
+    lam = (
+        jnp.exp(dot("lambda_q1", "lambda_k1"))
+        - jnp.exp(dot("lambda_q2", "lambda_k2"))
+        + lam0
+    )
+    d = o[..., 0, :] - lam * o[..., 1, :]
+    d = d * jax.lax.rsqrt(jnp.mean(d * d, axis=-1, keepdims=True) + cfg.norm_eps)
+    d = d * ap["subln"]["scale"].astype(F32) * (1.0 - lam0)
+    return _proj(ap["o"], d.astype(dtype).reshape(B, T, -1))
 
 
 # ---------------------------------------------------------------------------
@@ -812,39 +1233,70 @@ def hidden_states(
         o = jnp.einsum(
             "bkrij,bjkd->bikrd", jax.nn.softmax(s, axis=-1), v.astype(F32)
         )
-        return o.reshape(B, T, -1).astype(x.dtype)
+        return o.reshape(B, T, -1).astype(_attn_dtype(cfg, x.dtype))
 
-    def mixer(run: Run, h, j):
+    def keeps_memory(run: Run):
+        return _place_in(run, cfg.memory_layer) is not None
+
+    def mixer(run: Run, h, l, j):
+        """``(the mixer's output, what later layers read of it)``: the K
+        and V the cross layers attend, the scan output the gated memory
+        units gate, else None."""
         if run.kind == "mamba":
             out, _, _ = mamba_chunk(
                 cfg, _at(params["mamba"], j), h, n_valid, s0, tail0
             )
-            return out
+            return out, None
+        if run.kind == "mamba1":
+            out, _, _, y = mamba1_chunk(
+                cfg, _at(params["mamba1"], j), h, n_valid, s0, tail0
+            )
+            return out, y if keeps_memory(run) else None
+        if run.kind == "gmu":
+            return gmu(_at(params["gmu"], j), h, shared["memory"]), None
+        if run.kind == "cross":
+            ap = _at(params["cross"], j)
+            q = _heads_q(cfg, ap, h, positions, run)
+            attn = attend(q, *shared["kv"], mask)
+            return _heads_out(cfg, ap, l, attn, h.dtype), None
         if run.kind in ("attention", "window"):
             ap = _at(params["attn"], j)
-            q, k, v = _attn_qkv(
-                _rope_cfg(cfg, run), {"attn": ap}, h, positions, None
-            )
+            q, k, v = _heads_qkv(cfg, ap, h, positions, run)
             m = mask_window if run.kind == "window" else mask
-            return _proj(ap["o"], attend(q, k, v, m))
+            out = _heads_out(cfg, ap, l, attend(q, k, v, m), h.dtype)
+            return out, (k, v) if cfg.n_cross_layers else None
         ap = _at(params["latent"], j)
         q_nope, q_rope = latent_q(cfg, ap, h, rope_cs)
         k, v = latent_expand(cfg, ap, *latent_kv(cfg, ap, h, rope_cs))
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        return _proj(ap["o"], attend(q, k, v, mask))
+        return _proj(ap["o"], attend(q, k, v, mask)), None
 
-    for run in layer_plan(cfg):
+    shared = {}  # what one layer leaves for later ones to read
 
-        def body(x, idx, run=run):
-            l, j, e, _ = idx
-            with _mixer_region(run):
-                a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
-                x = _res(cfg, x, mixer(run, a, j))
-            x, _, _, _ = _mlp_half(cfg, params, run, l, e, x, valid, a)
-            return x, None
+    def body(x, idx, run):
+        l, j, e, _ = idx
+        with _mixer_region(run):
+            a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
+            out, left = mixer(run, a, l, j)
+            x = _res(cfg, x, out)
+        x, _, _, _ = _mlp_half(cfg, params, run, l, e, x, valid, a)
+        return x, left
 
-        x, _ = scan_layers(body, x, _run_indices(run))
+    for period in plan_periods(cfg):
+        x, lefts = _scan_period(body, x, period, _run_indices)
+        for run, left in zip(period, lefts):
+            _keep_shared(cfg, run, shared, left)
     return _final_norm(params, cfg, x)
+
+
+def _keep_shared(cfg: TransformerConfig, run: Run, shared: dict, left):
+    """What a run's layers left (stacked over them) into ``shared``: the
+    K and V of ``cfg.kv_shared_layer`` under ``"kv"``, the scan output of
+    ``cfg.memory_layer`` under ``"memory"``."""
+    if run.kind == "attention" and cfg.n_cross_layers:
+        shared["kv"] = _held(run, cfg.kv_shared_layer, left)
+    if _place_in(run, cfg.memory_layer) is not None:
+        shared["memory"] = _held(run, cfg.memory_layer, left)
 
 
 def forward(params: Params, cfg: TransformerConfig, tokens, positions, seg_ids):
@@ -1002,8 +1454,8 @@ def hybrid_fill_chunk(
                 fresh[None, :, None, None], 0, _get_conv_tails(conv, slots)
             )  # [Lm, F, K-1, conv_dim]
 
-    def mamba_mixer(h, ssm, j, tail0):
-        mp = _at(params["mamba"], j)
+    def mamba_mixer(run, h, ssm, j, tail0):
+        mp = _at(params[run.kind], j)
         if use_kernel:
             s0 = ssm_ops.ssm_state_rows(
                 ssm, j, slots, interpret=paged.kernel_interpret()
@@ -1011,14 +1463,17 @@ def hybrid_fill_chunk(
         else:
             s0 = _get_state_rows(ssm, j, slots)
         s0 = jnp.where(fresh[:, None, None], 0.0, s0)
-        out, s1, tail1 = mamba_chunk(cfg, mp, h, chunk_lens, s0, tail0)
+        if run.kind == "mamba1":
+            out, s1, tail1, y = mamba1_chunk(cfg, mp, h, chunk_lens, s0, tail0)
+            if _place_in(run, cfg.memory_layer) is not None:
+                tail1 = (tail1, y)
+        else:
+            out, s1, tail1 = mamba_chunk(cfg, mp, h, chunk_lens, s0, tail0)
         return out, _put_state_rows(ssm, j, slots, s1, row_valid), tail1
 
-    def attn_mixer(run, h, j, p):
+    def attn_mixer(run, h, l, j, p):
         ap = _at(params["attn"], j)
-        q, k, v = _attn_qkv(
-            _rope_cfg(cfg, run), {"attn": ap}, h, positions, None
-        )
+        q, k, v = _heads_qkv(cfg, ap, h, positions, run)
         if run.kind == "window":
             prefix = paged._prefix_partials(
                 q, *win_pools, win_tables, read_lens, p, use_kernel,
@@ -1031,10 +1486,27 @@ def hybrid_fill_chunk(
                 plan=plan, scale=scale,
             )
             mask = mask_chunk
-        attn = paged.chunk_attention(q, k, v, prefix, mask, scale, h.dtype)
-        return _proj(ap["o"], attn), (
+        attn = paged.chunk_attention(
+            q, k, v, prefix, mask, scale, _attn_dtype(cfg, h.dtype)
+        )
+        return _heads_out(cfg, ap, l, attn, h.dtype), (
             k.astype(k_pool.dtype), v.astype(v_pool.dtype)
         )
+
+    def cross_mixer(run, h, l, j):
+        """Queries only, over the shared layer's pages (the pool's one
+        layer) and its K and V of this chunk's tokens."""
+        ap = _at(params["cross"], j)
+        q = _heads_q(cfg, ap, h, positions, run)
+        prefix = paged._prefix_partials(
+            q, k_pool, v_pool, tables, read_lens, 0, use_kernel,
+            plan=plan, scale=scale,
+        )
+        attn = paged.chunk_attention(
+            q, *shared["kv"], prefix, mask_chunk, scale,
+            _attn_dtype(cfg, h.dtype),
+        )
+        return _heads_out(cfg, ap, l, attn, h.dtype)
 
     def latent_mixer(h, j):
         ap = _at(params["latent"], j)
@@ -1064,37 +1536,49 @@ def hybrid_fill_chunk(
         x, ssm, _pairs_zero(cfg), jnp.zeros((), jnp.int32) if grouped else None,
     )
     chunk_kv, chunk_kv_win, tails1, routed = [], [], [], []
-    for run in layer_plan(cfg):
-        l_idx, j_idx, e_idx, p_idx = _run_indices(run)
+    shared = {}  # what one layer leaves for later ones to read
 
-        def body(carry, inp, run=run):
-            x, ssm, pairs, rounds = carry
-            l, j, e, p = inp[:4]
-            with _mixer_region(run):
-                a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
-                if run.kind == "mamba":
-                    out, ssm, kept = mamba_mixer(a, ssm, j, inp[4])
-                elif run.kind == "latent":
-                    out, kept = latent_mixer(a, j)
-                else:
-                    out, kept = attn_mixer(run, a, j, p)
-                x = _res(cfg, x, out)
-            x, n, r, m = _mlp_half(cfg, params, run, l, e, x, valid, a)
-            return (x, ssm, _add_pairs(pairs, n), _add_pairs(rounds, m)), (kept, r)
+    def xs_of(run):
+        xs = _run_indices(run)
+        if run.kind in ("mamba", "mamba1"):
+            xs += (tails0[_of_kind(run)],)
+        return xs
 
-        xs = (l_idx, j_idx, e_idx, p_idx)
-        if run.kind == "mamba":
-            of_kind = slice(run.first_of_kind, run.first_of_kind + run.count)
-            xs += (tails0[of_kind],)
-        carry, (kept, r) = scan_layers(body, carry, xs)
-        if run.kind == "mamba":
-            tails1.append(kept)
-        elif run.kind == "window":
-            chunk_kv_win.append(kept)
-        else:
-            chunk_kv.append(kept)
-        if r is not None:
-            routed.append(r)
+    def body(carry, inp, run):
+        x, ssm, pairs, rounds = carry
+        l, j, e, p = inp[:4]
+        with _mixer_region(run):
+            a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
+            if run.kind in ("mamba", "mamba1"):
+                out, ssm, kept = mamba_mixer(run, a, ssm, j, inp[4])
+            elif run.kind == "latent":
+                out, kept = latent_mixer(a, j)
+            elif run.kind == "gmu":
+                out, kept = gmu(_at(params["gmu"], j), a, shared["memory"]), None
+            elif run.kind == "cross":
+                out, kept = cross_mixer(run, a, l, j), None
+            else:
+                out, kept = attn_mixer(run, a, l, j, p)
+            x = _res(cfg, x, out)
+        x, n, r, m = _mlp_half(cfg, params, run, l, e, x, valid, a)
+        return (x, ssm, _add_pairs(pairs, n), _add_pairs(rounds, m)), (kept, r)
+
+    for period in plan_periods(cfg):
+        carry, left = _scan_period(body, carry, period, xs_of)
+        for run, (kept, r) in zip(period, left):
+            if run.kind in ("mamba", "mamba1"):
+                if _place_in(run, cfg.memory_layer) is not None:
+                    kept, shared["memory"] = kept[0], _held(
+                        run, cfg.memory_layer, kept[1]
+                    )
+                tails1.append(kept)
+            elif run.kind == "window":
+                chunk_kv_win.append(kept)
+            elif kept is not None:
+                chunk_kv.append(kept)
+                _keep_shared(cfg, run, shared, kept)
+            if r is not None:
+                routed.append(r)
     x, ssm, pairs, rounds = carry
     if tails1:
         conv = _put_conv_tails(
@@ -1120,7 +1604,7 @@ def hybrid_fill_chunk(
     logits = _logits(params, cfg, paged.last_valid(x, chunk_lens))[:, 0]
     out = (
         logits, k_pool, v_pool, ssm, conv, pairs,
-        jnp.concatenate(routed, axis=0), rounds,
+        jnp.concatenate(routed, axis=0) if routed else None, rounds,
     )
     return out if win_pools is None else out + (win_pools,)
 
@@ -1192,6 +1676,10 @@ def hybrid_decode_chunk(
     # keys and values both), one pool write after the chunk
     wk = jnp.zeros((La, W, B, Hkv, hd), k_pool.dtype)
     wv = jnp.zeros((0 if latent else La, W, B, Hkv, hd), k_pool.dtype)
+    if cfg.n_cross_layers:
+        # the shared layer's number in the chunk's own KV (the attention
+        # and window layers' parameter stack)
+        shared_j = int(pool_layer_numbers(cfg, "attention")[0])
 
     def step(i, st):
         (lengths_, cur, active, budgets, wk, wv, wvalid, ssm, conv, out_t,
@@ -1203,11 +1691,9 @@ def hybrid_decode_chunk(
         live = active[:, None]
         rope_cs = latent_rope_tables(cfg, positions) if latent else None
 
-        def attn_mixer(run, h, wk, wv, j, p):
+        def attn_mixer(run, h, wk, wv, l, j, p):
             ap = _at(params["attn"], j)
-            q, k, v = _attn_qkv(
-                _rope_cfg(cfg, run), {"attn": ap}, h, positions, None
-            )
+            q, k, v = _heads_qkv(cfg, ap, h, positions, run)
             wk, wv = window_put(wk, k, j, i), window_put(wv, v, j, i)
             if run.kind == "window":
                 # the plan is of the chunk's start; this step's queries
@@ -1226,9 +1712,24 @@ def hybrid_decode_chunk(
                 q,
                 jax.lax.dynamic_index_in_dim(wk, j, 0, keepdims=False),
                 jax.lax.dynamic_index_in_dim(wv, j, 0, keepdims=False),
-                prefix, mask_win, scale, h.dtype,
+                prefix, mask_win, scale, _attn_dtype(cfg, h.dtype),
             )
-            return _proj(ap["o"], attn), wk, wv
+            return _heads_out(cfg, ap, l, attn, h.dtype), wk, wv
+
+        def cross_mixer(run, h, wk, wv, l, j):
+            """Queries only, over the shared layer's pages (the pool's one
+            layer) and its K and V of this chunk's steps so far."""
+            ap = _at(params["cross"], j)
+            q = _heads_q(cfg, ap, h, positions, run)
+            prefix = paged._prefix_partials(
+                q, k_pool, v_pool, tables, read_lens, 0, use_kernel,
+                plan=plan, scale=scale,
+            )
+            attn = paged.window_attention(
+                q, wk[shared_j], wv[shared_j], prefix, mask_win, scale,
+                _attn_dtype(cfg, h.dtype),
+            )
+            return _heads_out(cfg, ap, l, attn, h.dtype)
 
         def latent_mixer(h, wk, j):
             ap = _at(params["latent"], j)
@@ -1251,37 +1752,53 @@ def hybrid_decode_chunk(
             return _proj(ap["o"], attn), wk
 
         carry, step_routed = (x, wk, wv, ssm, conv, pairs), []
-        for run in layer_plan(cfg):
+        shared = {}  # what one layer leaves for later ones to read
 
-            def body(carry, idx, run=run):
-                x, wk, wv, ssm, conv, pairs = carry
-                l, j, e, p = idx
-                with _mixer_region(run):
-                    a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
-                    if run.kind == "mamba":
-                        out, ssm, conv = mamba_step(
-                            cfg, _at(params["mamba"], j), a,
-                            ssm, conv, j, active, use_kernel,
-                        )
-                    elif run.kind == "latent":
-                        out, wk = latent_mixer(a, wk, j)
-                    else:
-                        out, wk, wv = attn_mixer(run, a, wk, wv, j, p)
-                    x = _res(cfg, x, out)
-                x, n, r, _ = _mlp_half(cfg, params, run, l, e, x, live, a)
-                return (x, wk, wv, ssm, conv, _add_pairs(pairs, n)), (
-                    None if r is None else r[:, 0].T
-                )
-
-            carry, r = scan_layers(body, carry, _run_indices(run))
-            if r is not None:
-                step_routed.append(r)  # [run.count, K, B]
-        x, wk, wv, ssm, conv, pairs = carry
-        with region("areal.moe.route"):
-            routed = jax.lax.dynamic_update_slice(
-                routed, jnp.concatenate(step_routed, axis=0)[None],
-                (i, 0, 0, 0),
+        def body(carry, idx, run):
+            x, wk, wv, ssm, conv, pairs = carry
+            l, j, e, p = idx
+            left = None
+            with _mixer_region(run):
+                a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
+                if run.kind == "mamba":
+                    out, ssm, conv = mamba_step(
+                        cfg, _at(params["mamba"], j), a,
+                        ssm, conv, j, active, use_kernel,
+                    )
+                elif run.kind == "mamba1":
+                    out, ssm, conv, y = mamba1_step(
+                        cfg, _at(params["mamba1"], j), a,
+                        ssm, conv, j, active, use_kernel,
+                    )
+                    if _place_in(run, cfg.memory_layer) is not None:
+                        left = y
+                elif run.kind == "gmu":
+                    out = gmu(_at(params["gmu"], j), a, shared["memory"])
+                elif run.kind == "cross":
+                    out = cross_mixer(run, a, wk, wv, l, j)
+                elif run.kind == "latent":
+                    out, wk = latent_mixer(a, wk, j)
+                else:
+                    out, wk, wv = attn_mixer(run, a, wk, wv, l, j, p)
+                x = _res(cfg, x, out)
+            x, n, r, _ = _mlp_half(cfg, params, run, l, e, x, live, a)
+            return (x, wk, wv, ssm, conv, _add_pairs(pairs, n)), (
+                None if r is None else r[:, 0].T, left,
             )
+
+        for period in plan_periods(cfg):
+            carry, lefts = _scan_period(body, carry, period, _run_indices)
+            for run, (r, left) in zip(period, lefts):
+                _keep_shared(cfg, run, shared, left)
+                if r is not None:
+                    step_routed.append(r)  # [run.count, K, B]
+        x, wk, wv, ssm, conv, pairs = carry
+        if step_routed:
+            with region("areal.moe.route"):
+                routed = jax.lax.dynamic_update_slice(
+                    routed, jnp.concatenate(step_routed, axis=0)[None],
+                    (i, 0, 0, 0),
+                )
         logits = _logits(params, cfg, x)[:, 0]
         (new_lengths, tok, active, budgets, out_t, out_l, emitted,
          rng) = sample_and_advance(

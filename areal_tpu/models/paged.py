@@ -120,7 +120,7 @@ def pool_shapes(
             head + (1, block_size, latent_page_width(cfg)),
             head + (1, block_size, 0),
         )
-    shape = head + (cfg.n_kv_heads, block_size, cfg.head_dim)
+    shape = head + (cfg.pool_kv_heads, block_size, cfg.pool_head_dim)
     return shape, shape
 
 
@@ -200,7 +200,7 @@ def kv_pool_layout_bytes(
     n = int(np.prod(k_shape))
     if kv_cache_dtype == "int8":
         # k + v int8 data, k + v float32 scale pools [L, NB, Hkv, BS]
-        return 2 * n, 2 * (n // cfg.head_dim) * 4
+        return 2 * n, 2 * (n // cfg.pool_head_dim) * 4
     return 2 * n * itemsize, 0
 
 

@@ -1277,6 +1277,21 @@ TRACE_TABLE = [
         "(paged_window_decode / paged_window_fill)",
     ),
     TraceSpec(
+        "areal.attn.cross",
+        "region",
+        "The same half of a CROSS layer of a stack stated by kind: queries "
+        "only, over the pages and the chunk's own K and V of the stack's "
+        "one full-attention layer (paged_attn_decode / paged_attn_fill "
+        "over the pool's ONE layer)",
+    ),
+    TraceSpec(
+        "areal.gmu",
+        "region",
+        "A gated memory unit's half: norm, the gate's projection, the "
+        "product with the last Mamba-1 layer's scan output, output "
+        "projection, residual add",
+    ),
+    TraceSpec(
         "areal.kv_write",
         "region",
         "Keys and values put where later steps read them: "

@@ -15,12 +15,23 @@ and layer.  :func:`ssm_state_update` is the Pallas kernel for it, over the
 engine's LAYER-STACKED state ``[Lm, slots, N, H*P]`` (the layer is picked
 in the index map, like the paged pool's), in place
 (``input_output_aliases``), visiting live slots only.
+
+**Mamba-1** (``a=``): the decay differs by channel AND by state index,
+``decay[n, c] = exp(dt[c] A[n, c])``, a TILE where Mamba-2's is a lane
+vector (a head's scalar over its channels).  The same kernel under a
+static branch: it is handed ``dt`` in the decay's place and the layer's
+``A`` ``[N, H*P]`` (one block for every slot: fetched once a lane block)
+and forms the tile inside, so no ``[slots, N, H*P]`` decay ever exists in
+HBM (it would be a third pass over state-sized bytes).  One kernel and not
+two because everything else is shared: the slot order, the skipped dead
+slots, the aliasing, the tiles.  Its calls are named
+``ssm_state_update_m1`` in the device trace.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,15 +56,21 @@ def _kernel(
     dtx_ref,  # (1, 1, RB)
     b_ref,  # (1, N, 1)
     c_ref,  # (1, N, 1)
-    y_ref,  # out (1, 1, RB)
-    so_ref,  # out (1, 1, N, RB), the same buffer as the state
+    *rest,  # [a_ref (N, RB) under ``per_state``,] then the two outputs:
+    # y_ref (1, 1, RB); so_ref (1, 1, N, RB), the same buffer as the state
+    per_state: bool = False,
 ):
+    y_ref, so_ref = rest[-2:]
     i, j = pl.program_id(0), pl.program_id(1)
     n_live = n_live_ref[0]
 
     @pl.when(i < n_live)
     def _live():
-        s = s_ref[0, 0] * decay_ref[0] + b_ref[0] * dtx_ref[0]  # [N, RB]
+        if per_state:  # decay_ref holds dt: the tile exp(dt[c] A[n, c])
+            decay = jnp.exp(decay_ref[0] * rest[0][...])
+            s = s_ref[0, 0] * decay + b_ref[0] * dtx_ref[0]
+        else:
+            s = s_ref[0, 0] * decay_ref[0] + b_ref[0] * dtx_ref[0]  # [N, RB]
         so_ref[0, 0] = s
         y_ref[0] = jnp.sum(s * c_ref[0], axis=0, keepdims=True)
 
@@ -84,10 +101,13 @@ def ssm_state_update(
     c: jax.Array,  # [S, N] float32
     live: jax.Array,  # [S] bool: slots that take this step
     interpret: bool = False,
+    a: Optional[jax.Array] = None,  # [N, HP] float32 (< 0): Mamba-1
 ) -> Tuple[jax.Array, jax.Array]:
     """One recurrence step of layer ``layer`` for every live slot, in
     place.  Returns ``(y [S, HP] float32, state)``; ``y`` of a dead slot
-    is NOT written (mask it), its state is not touched."""
+    is NOT written (mask it), its state is not touched.  With ``a``,
+    ``decay`` holds ``dt`` and the decay is ``exp(dt[c] a[n, c])``
+    (module docstring)."""
     _, S, N, HP = state.shape
     RB = _lane_block(HP)
     n_j = HP // RB
@@ -107,15 +127,25 @@ def ssm_state_update(
         slot, _ = _visited(i, j, order_ref, n_live_ref, n_j)
         return slot, 0, 0
 
+    def a_map(i, j, order_ref, n_live_ref, layer_ref):
+        _, jj = _visited(i, j, order_ref, n_live_ref, n_j)
+        return 0, jj
+
     lane_spec = pl.BlockSpec((1, 1, RB), lane_map)
     col_spec = pl.BlockSpec((1, N, 1), col_map)
     state_spec = pl.BlockSpec((1, 1, N, RB), state_map)
+    in_specs = [state_spec, lane_spec, lane_spec, col_spec, col_spec]
+    kernel, name, more = _kernel, "ssm_state_update", ()
+    if a is not None:
+        kernel = functools.partial(_kernel, per_state=True)
+        name, more = "ssm_state_update_m1", (a.astype(jnp.float32),)
+        in_specs = in_specs + [pl.BlockSpec((N, RB), a_map)]
     y, state = pl.pallas_call(
-        _kernel,
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(S, n_j),
-            in_specs=[state_spec, lane_spec, lane_spec, col_spec, col_spec],
+            in_specs=in_specs,
             out_specs=[lane_spec, state_spec],
         ),
         out_shape=[
@@ -128,11 +158,11 @@ def ssm_state_update(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-        name="ssm_state_update",
+        name=name,
     )(
         order, n_live, layer, state,
         decay.reshape(S, 1, HP), dtx.reshape(S, 1, HP),
-        b.reshape(S, N, 1), c.reshape(S, N, 1),
+        b.reshape(S, N, 1), c.reshape(S, N, 1), *more,
     )
     return y.reshape(S, HP), state
 
@@ -181,12 +211,15 @@ def ssm_state_rows(
     )(slots.astype(jnp.int32), layer, state)
 
 
-def ssm_state_update_reference(state, layer, decay, dtx, b, c, live):
+def ssm_state_update_reference(state, layer, decay, dtx, b, c, live, a=None):
     """The same step in plain ``jnp`` (same contract, but ``y`` of a dead
     slot is what its untouched state gives)."""
     layer = jnp.asarray(layer, jnp.int32).reshape(())
     s = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
-    new = s * decay[:, None, :] + b[:, :, None] * dtx[:, None, :]
+    decay = decay[:, None, :]
+    if a is not None:  # Mamba-1: ``decay`` holds dt
+        decay = jnp.exp(decay * a)
+    new = s * decay + b[:, :, None] * dtx[:, None, :]
     new = jnp.where(live[:, None, None], new, s)
     y = jnp.sum(new * c[:, :, None], axis=1)
     return y, jax.lax.dynamic_update_index_in_dim(state, new, layer, 0)

@@ -219,6 +219,39 @@ def test_hybrid_programs_name_their_regions(name, program, also):
     _assert_products_in_regions(_paths(_lower(program(cfg, params))), want)
 
 
+PHI4FLASH = dict(
+    architectures=["Phi4FlashForCausalLM"], hidden_size=32,
+    num_hidden_layers=8, num_attention_heads=8, num_key_value_heads=4,
+    intermediate_size=48, vocab_size=64, sliding_window=12, mb_per_layer=2,
+    layer_norm_eps=1e-5, tie_word_embeddings=True,
+)
+
+
+@pytest.mark.parametrize(
+    "program, also",
+    [(_hybrid_decode, {"areal.sample"}), (_hybrid_fill, set())],
+    ids=["decode", "fill"],
+)
+def test_a_decoder_hybrid_decoder_stack_splits_its_five_mixer_kinds(program, also):
+    """``[mamba1, window] x 2, mamba1, attention, gmu, cross``: the Mamba-1
+    mixers in ``areal.ssm``, the window layers, the one full-attention
+    layer and the cross layer each in a region of their own, the gated
+    memory unit in ``areal.gmu``; no expert layer, so no ``areal.moe.*``."""
+    cfg = family_from_architecture("Phi4FlashForCausalLM").config_from_hf(PHI4FLASH)
+    cfg = dataclasses.replace(cfg, dtype="float32")
+    params = hybrid.init_params(cfg, jax.random.PRNGKey(0))
+    paths = _paths(_lower(program(cfg, params)))
+    _assert_products_in_regions(
+        paths,
+        also | {
+            "areal.embed", "areal.ssm", "areal.attn", "areal.attn.window",
+            "areal.attn.cross", "areal.gmu", "areal.mlp", "areal.kv_write",
+            "areal.head",
+        },
+    )
+    assert not any("areal.moe" in p for p in paths)
+
+
 # -- the PPO train step ---------------------------------------------------------
 
 

@@ -162,3 +162,34 @@ def test_pages_wholly_before_the_window_are_never_copied():
         if pages:
             want[r] = pages
     assert copied == want
+
+
+@pytest.mark.parametrize("page, window", [(512, 512), (256, 200), (256, 256)])
+def test_a_window_no_longer_than_a_page_reads_two_pages_at_most(page, window):
+    """A window of 512 over pages of 512 (and shorter ones over pages of
+    256): the grid is two page steps whatever the row's length
+    (``window_span_pages``), a query whose window lies inside ONE page
+    reads that page alone, and one whose window straddles an edge reads
+    both; 4 query heads a KV head of 128 (a differential pair's plan)."""
+    assert window_span_pages(page, window) == 2
+    lengths = [3 * page + 7, 2 * page, 2 * page + window - 1, page // 2, 0]
+    q, k, v, tables, lens = _setup(
+        lengths, Hq=8, Hkv=2, MB=5, NB=32, page=page, seed=4
+    )
+    got = paged_flash_attention(q, k, v, tables, lens, interpret=True, window=window)
+    _assert_same(got, reference_paged_partials(q, k, v, tables, lens, window=window))
+    # later steps of a decode chunk over the plan made at its start
+    G = page_group(1, 8, k.shape, k.dtype, False, tables.shape[1])
+    plan = plan_pages(tables, lens, page, G, window=window)
+    assert list(np.asarray(plan.firsts)[np.argsort(np.asarray(plan.order))]) == [
+        max(n - (window - 1), 0) // page for n in lengths
+    ]
+    for shift in (1, 3):
+        got = paged_flash_attention(
+            q, k, v, tables, lens, interpret=True, plan=plan, window=window,
+            window_shift=jnp.int32(shift),
+        )
+        want = reference_paged_partials(
+            q, k, v, tables, lens, window=window, window_shift=shift
+        )
+        _assert_same(got, want)

@@ -87,3 +87,56 @@ def test_state_rows_reads_the_slots_of_one_layer(slots):
         state, jnp.int32(1), jnp.asarray(slots, jnp.int32), interpret=True
     )
     assert np.array_equal(np.asarray(got), np.asarray(state[1])[slots])
+
+
+# --- Mamba-1: the decay a tile exp(dt[c] A[n, c]), formed inside the kernel ---
+
+
+def _by_hand_m1(state, layer, dt, a, dtx, b, c):
+    s = np.asarray(state[layer], np.float64)
+    decay = np.exp(np.asarray(dt, np.float64)[:, None, :] * np.asarray(a, np.float64))
+    new = s * decay + np.asarray(b)[:, :, None] * np.asarray(dtx)[:, None, :]
+    return np.einsum("snr,sn->sr", new, np.asarray(c)), new
+
+
+@pytest.mark.parametrize(
+    "live", [[1, 0, 1, 1, 0, 0], [1] * 6, [0] * 6], ids=["mixed", "all", "none"]
+)
+@pytest.mark.parametrize("lane_block", [2048, 16], ids=["one_block", "two_blocks"])
+def test_mamba1_state_update_forms_the_decay_tile_inside_the_kernel(
+    monkeypatch, live, lane_block
+):
+    """``a=``: the kernel is handed dt and the layer's A ``[N, HP]`` and
+    the decay differs by channel AND by state index; against numpy by hand
+    and against ``ssm_state_update_reference``'s Mamba-1 twin; dead slots
+    and other layers bit for bit what they were; one lane block and two."""
+    monkeypatch.setattr(ssm, "LANE_BLOCK", lane_block)
+    x = _inputs(2)
+    dt = 0.5 * x["decay"]  # (0, 0.5)
+    a = -jax.random.uniform(jax.random.PRNGKey(9), (N, HP), minval=1.0, maxval=16.0)
+    live = jnp.asarray(live, bool)
+    layer = 1
+    y_want, s_want = _by_hand_m1(x["state"], layer, dt, a, x["dtx"], x["b"], x["c"])
+    y, state = ssm.ssm_state_update(
+        x["state"].copy(), jnp.int32(layer), dt, x["dtx"], x["b"], x["c"],
+        live, interpret=True, a=a,
+    )
+    y_ref, state_ref = ssm.ssm_state_update_reference(
+        x["state"], layer, dt, x["dtx"], x["b"], x["c"], live, a=a
+    )
+    lv = np.asarray(live)
+    assert np.abs(np.asarray(state[layer])[lv] - s_want[lv]).max(initial=0) < 1e-5
+    assert np.abs(np.asarray(y)[lv] - y_want[lv]).max(initial=0) < 1e-4
+    assert np.array_equal(np.asarray(state[layer])[~lv], np.asarray(x["state"][layer])[~lv])
+    others = [l for l in range(LM) if l != layer]
+    assert np.array_equal(np.asarray(state)[others], np.asarray(x["state"])[others])
+    assert np.abs(np.asarray(state) - np.asarray(state_ref)).max() < 1e-5
+    assert np.abs((np.asarray(y) - np.asarray(y_ref))[lv]).max(initial=0) < 1e-4
+    # and it is NOT a lane vector's decay: a head's scalar over its
+    # channels (Mamba-2's form with the tile's first row) gives another state
+    if lv.any():
+        flat, _ = _by_hand(
+            x["state"], layer, np.exp(np.asarray(dt) * np.asarray(a)[0]),
+            x["dtx"], x["b"], x["c"],
+        )
+        assert np.abs(flat[lv] - y_want[lv]).max() > 1e-2

@@ -908,6 +908,187 @@ def test_window_decode_program_fits_and_reads_both_pools_in_place(
     print(f"window decode: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
 
 
+# --- a decoder-hybrid-decoder stack WHOLE: three cache kinds, one pool layer read by eight ---
+
+#: phi-4-mini-flash-reasoning as the cell runs it (benchmark/configs): all
+#: 32 layers at the published widths; the cell's engine: 64 rows of 10,240,
+#: 294,912 tokens of whole-context pages (ONE layer), 114,688 of window
+#: pages (8 layers), pages of 512, decode chunks of 3 steps
+SHARED_ROWS, SHARED_CTX, SHARED_CHUNK, SHARED_PAGE = 64, 10240, 3, 512
+SHARED_POOL_TOKENS = {"global": 294912, "window": 114688}
+
+
+def _shared_cell_args(one_chip):
+    cfg = TransformerConfig(
+        n_layers=32, hidden_dim=2560, n_q_heads=40, n_kv_heads=20,
+        head_dim=64, intermediate_dim=10240, vocab_size=200064,
+        max_position_embeddings=262144, norm_type="layer", norm_eps=1e-5,
+        use_attention_bias=True, tied_embedding=True, use_rope=False,
+        sliding_window=512, diff_attention=True, n_dense_layers=32,
+        layer_types=("mamba1", "window") * 8 + ("mamba1", "attention")
+        + ("gmu", "cross") * 7,
+        mamba_n_heads=5120, mamba_head_dim=1, mamba_d_state=16,
+        mamba_d_conv=4, mamba_dt_rank=160,
+    )
+
+    def place(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    params = jax.tree.map(lambda a: place(a.shape, a.dtype), shapes)
+    pools = {}
+    for kind, layers in (("global", None), ("window", cfg.n_window_layers)):
+        k_shape, v_shape = paged.pool_shapes(
+            cfg, SHARED_POOL_TOKENS[kind] // SHARED_PAGE, SHARED_PAGE, layers
+        )
+        pools[kind] = place(k_shape, jnp.bfloat16), place(v_shape, jnp.bfloat16)
+    # ONE layer of whole-context pages, a pair's two heads of 64 as one of
+    # 128 (no byte of padding), for the eight layers that read it
+    assert pools["global"][0].shape == (1, 576, 10, SHARED_PAGE, 128)
+    assert pools["window"][0].shape[0] == 8
+    ssm, conv = (
+        place(a.shape, a.dtype)
+        for a in jax.eval_shape(lambda: hybrid.state_zeros(cfg, SHARED_ROWS))
+    )
+    assert ssm.shape == (9, 64, 16, 5120) and conv.shape == (9, 3, 64, 5120)
+    return cfg, params, pools, ssm, conv, place
+
+
+def _assert_shared_program_fits(compiled, pools, ssm):
+    """No copy of either pool or of the state in the optimized HLO, and
+    the whole program inside one chip's memory; returns (total,
+    temporaries)."""
+    for kind in pools:
+        assert _pool_copies(compiled, pools[kind][0].shape) == [], kind
+    assert _pool_copies(compiled, ssm.shape) == []
+    m = compiled.memory_analysis()
+    total = (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+    assert total < USABLE_HBM_BYTES, (total, m.temp_size_in_bytes)
+    return total, m.temp_size_in_bytes
+
+
+def _arrays_of(compiled, shape):
+    """Instructions outside the fused computations whose result holds an
+    array of ``shape`` (any dtype), by opcode: what the program WRITES of
+    that shape."""
+    dims = "[" + ",".join(str(d) for d in shape) + "]"
+    found = {}
+    for computation, name, result, op in _instructions(compiled):
+        fused = "fused_computation" in computation
+        if dims in result and not fused and op not in (
+            "parameter", "bitcast", "get-tuple-element", "tuple",
+        ):
+            found.setdefault(op, []).append(f"{computation}: {name}")
+    return found
+
+
+# the cell's largest fill (a prompt's last piece with the next prompt's
+# first piece behind it) and one prompt's chunk
+@pytest.mark.parametrize("F,C", [(2, 1024), (1, 1024)])
+def test_shared_fill_program_fits_and_copies_no_pool_for_a_reading_layer(
+    one_chip, monkeypatch, F, C
+):
+    """``hybrid_fill_chunk`` whole at the shared cell's shapes: 7.71 GB of
+    weights + 6.21 GB of pools + 0.21 GB of state + the chunk's
+    temporaries fit one chip; the eight global readers' prefix part is
+    ``paged_attn_fill`` over the ONE pool layer and the window layers'
+    ``paged_window_fill``; the shared layer's K and V of the chunk's own
+    tokens exist ONCE (``[1, F, C, 10, 128]``, what the pool write takes):
+    no copy of them a reading layer; the conv tails ride no loop."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    cfg, params, pools, ssm, conv, place = _shared_cell_args(one_chip)
+    table = place((F, SHARED_CTX // SHARED_PAGE), jnp.int32)
+    compiled = hybrid.hybrid_fill_chunk.lower(
+        params, *pools["global"], ssm, conv, cfg,
+        place((F, C), jnp.int32), place((F,), jnp.int32),
+        place((F,), jnp.int32), table, place((F,), jnp.int32),
+        use_kernel=True, win_pools=pools["window"], win_tables=table,
+    ).compile()
+    text = compiled.as_text()
+    for name in ("paged_attn_fill", "paged_window_fill", "ssm_state_rows"):
+        assert name in text, name
+    total, temp = _assert_shared_program_fits(compiled, pools, ssm)
+    # the chunk's own K and V of the shared layer (2.6 MB a row each): at
+    # most ONE relayout of each for the whole program, not one a reader
+    once = _pool_copies(compiled, (1, F, C, 10, 128)) + _pool_copies(
+        compiled, (F, C, 10, 128)
+    )
+    assert len(once) <= 2 and all(c.startswith("ENTRY") for c in once), once
+    conv_dims = "[" + ",".join(str(d) for d in conv.shape) + "]"
+    carried = [
+        line for line in text.splitlines()
+        if " while(" in line and conv_dims in line
+    ]
+    assert carried == [], carried[0][:200]
+    assert 14.1e9 < total < {2: 14.9e9, 1: 14.6e9}[F], total
+    print(f"shared fill F={F} C={C}: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
+
+
+def test_shared_decode_program_reads_one_pool_layer_from_eight_layers_in_place(
+    one_chip, monkeypatch
+):
+    """``hybrid_decode_chunk`` whole (64 rows, 3 steps) over the three
+    cache kinds: ``paged_attn_decode`` for the eight global readers,
+    ``paged_window_decode`` for the window layers and
+    ``ssm_state_update_m1`` for the Mamba-1 layers by name; no copy of a
+    pool or of the state, no second array of the global pool, and the
+    chunk's own K and V (``[9, W, 64, 10, 128]``: the window layers' and
+    layer 17's, which the cross layers read where they lie) copied
+    nowhere."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    cfg, params, pools, ssm, conv, place = _shared_cell_args(one_chip)
+
+    def rows(dtype):
+        return place((SHARED_ROWS,), dtype)
+
+    table = place((SHARED_ROWS, SHARED_CTX // SHARED_PAGE), jnp.int32)
+    compiled = hybrid.hybrid_decode_chunk.lower(
+        params, *pools["global"], ssm, conv, cfg, table, rows(jnp.int32),
+        rows(jnp.int32), rows(jnp.bool_), rows(jnp.int32),
+        place((2,), jnp.uint32), chunk_size=SHARED_CHUNK,
+        sample_fn=_keyed_greedy, stop_fn=_never_stop, use_kernel=True,
+        max_len=SHARED_CTX, row_seeds=rows(jnp.int32),
+        win_pools=pools["window"], win_tables=table,
+    ).compile()
+    text = compiled.as_text()
+    for name in ("paged_attn_decode", "paged_window_decode", "ssm_state_update_m1"):
+        assert name in text, name
+    total, temp = _assert_shared_program_fits(compiled, pools, ssm)
+    # the chunk's own K and V (5.9 MB each) change layout where they enter
+    # the step loop and a period's scan (6 copies at PR 42): once a step,
+    # never once a reading layer (that would be 7 x 2 more)
+    own = _pool_copies(compiled, (9, SHARED_CHUNK, SHARED_ROWS, 10, 128))
+    assert len(own) <= 6, own
+    # nothing but the pool write's loop and the program's own result holds
+    # an array of the global pool's shape
+    written = _arrays_of(compiled, pools["global"][0].shape)
+    assert set(written) <= {"while", "dynamic-update-slice", "fusion", "custom-call"}, written
+    assert 14.1e9 < total < 14.6e9, total
+    print(f"shared decode: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
+
+
+def test_mamba1_state_kernel_compiles_at_the_cells_state(one_chip):
+    """``ssm_state_update`` with ``a=`` over ``[9, 64, 16, 5120]``: a tile
+    of 16 sublanes where Mamba-2 has 128, one lane block of 5,120."""
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    from areal_tpu.ops import ssm as ssm_ops
+
+    S, N, C = 64, 16, 5120
+    compiled = ssm_ops.ssm_state_update.lower(
+        place((9, S, N, C), jnp.float32), place((), jnp.int32),
+        place((S, C), jnp.float32), place((S, C), jnp.float32),
+        place((S, N), jnp.float32), place((S, N), jnp.float32),
+        place((S,), jnp.bool_), a=place((N, C), jnp.float32),
+    ).compile()
+    assert "ssm_state_update_m1" in compiled.as_text()
+    assert _pool_copies(compiled, (9, S, N, C)) == []
+
+
 # a decode row of 7 query heads a kv head, and a fill chunk's tile, at
 # the cell's page size (256 and 1,024 compiled too when the page was
 # timed: PERF.md section 4)
