@@ -51,6 +51,11 @@ def register_impls():
     # concurrently at configure time
     from transformers import AutoConfig, AutoTokenizer  # noqa: F401
 
+    # the checkpointing library here too: a model worker's first save
+    # imports it, and as a thread among the other workers' threads, all
+    # taking turns at the interpreter, that import takes 14 s against 2
+    import orbax.checkpoint  # noqa: F401
+
 
 def run_experiment_local(
     cfg: system_api.ExperimentConfig,

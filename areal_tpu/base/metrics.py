@@ -5,8 +5,11 @@ realhf/system/master_worker.py:291-350 initializes wandb / swanlab /
 tensorboard and realhf/base/logging.py ``log_swanlab_wandb_tensorboard``
 writes every scalar to all three).  Differences by design: a JSONL sink is
 always on (it is the machine-readable artifact tests and the offline
-evaluator consume), tensorboard uses torch's bundled ``SummaryWriter``, and
-wandb/swanlab are optional imports that degrade to no-ops when the package
+evaluator consume), tensorboard event files are written with the
+``tensorboard`` package's own record writer and protos (torch's
+``SummaryWriter`` imports torch AND, where it is installed, tensorflow:
+15 s a process to log a few scalars), and wandb/swanlab are optional
+imports that degrade to no-ops when the package
 or the opt-in env (``AREAL_WANDB=1`` / ``AREAL_SWANLAB=1``) is absent.
 """
 
@@ -20,6 +23,48 @@ from typing import Any, Dict, Optional
 from areal_tpu.base import logging_
 
 logger = logging_.getLogger("metrics")
+
+
+class _ScalarEvents:
+    """A tensorboard event file of scalars: length-prefixed, checksummed
+    ``Event`` records, the first one the file version."""
+
+    def __init__(self, log_dir: str):
+        import socket
+
+        from tensorboard.compat.proto import event_pb2, summary_pb2
+        from tensorboard.summary.writer.record_writer import RecordWriter
+
+        self._event, self._summary = event_pb2.Event, summary_pb2.Summary
+        os.makedirs(log_dir, exist_ok=True)
+        self._file = open(
+            os.path.join(
+                log_dir,
+                f"events.out.tfevents.{int(time.time()):010d}."
+                f"{socket.gethostname()}.{os.getpid()}",
+            ),
+            "wb",
+        )
+        self._records = RecordWriter(self._file)
+        self._write(self._event(file_version="brain.Event:2"))
+
+    def _write(self, event):
+        event.wall_time = time.time()
+        self._records.write(event.SerializeToString())
+        self._file.flush()
+
+    def add_scalar(self, tag: str, value: float, global_step: int):
+        self._write(
+            self._event(
+                step=global_step,
+                summary=self._summary(
+                    value=[self._summary.Value(tag=tag, simple_value=value)]
+                ),
+            )
+        )
+
+    def close(self):
+        self._file.close()
 
 
 class MetricsLogger:
@@ -39,11 +84,7 @@ class MetricsLogger:
         self._tb = None
         if enable_tensorboard:
             try:
-                from torch.utils.tensorboard import SummaryWriter
-
-                self._tb = SummaryWriter(
-                    log_dir=os.path.join(log_dir, "tensorboard")
-                )
+                self._tb = _ScalarEvents(os.path.join(log_dir, "tensorboard"))
             except Exception:  # noqa: BLE001 - tb is best-effort
                 logger.warning("tensorboard unavailable; skipping")
         self._wandb = None

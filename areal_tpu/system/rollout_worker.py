@@ -203,11 +203,10 @@ class RolloutWorker(worker_base.AsyncWorker):
             self.prm.close()
 
     def _exit_hook(self):
+        # the loop ends only after an exit(): the manager client is
+        # closed for good, and only a server client that a rollout made
+        # since then is still open
         if hasattr(self, "prm"):
             self.prm.close()
-        if hasattr(self, "manager_client"):
-            # unblocks executor threads parked in manager calls; without
-            # this asyncio.run's shutdown joins them for up to 300s
-            self.manager_client.close()
         if hasattr(self, "pusher"):
             self.pusher.close()

@@ -407,11 +407,26 @@ class Worker:
 class AsyncWorker(Worker):
     """Worker whose poll is a coroutine (reference: worker_base.py:710)."""
 
+    #: seconds between two looks at the control socket
+    CONTROL_INTERVAL = 0.01
+
     async def _poll_async(self) -> PollResult:
         raise NotImplementedError()
 
     def _poll(self) -> PollResult:  # pragma: no cover - sync fallback
         raise RuntimeError("AsyncWorker must be run with run_async()")
+
+    async def _serve_control(self):
+        """Control requests are served BESIDE the poll, on the same loop:
+        a poll parked in a long await (a call to a manager that has gone)
+        must not keep "exit" unheard, since ``exit()`` is what unparks
+        it.  Handlers run between two awaits of the poll, never inside
+        one."""
+        import asyncio
+
+        while True:
+            self._server.handle_requests()
+            await asyncio.sleep(self.CONTROL_INTERVAL)
 
     def run_async(self, config=None) -> WorkerServerStatus:
         import asyncio
@@ -420,9 +435,14 @@ class AsyncWorker(Worker):
             if config is not None:
                 self.configure(config)
                 self._Worker__running = True  # noqa: SLF001
+            control = (
+                asyncio.create_task(self._serve_control())
+                if self._server
+                else None
+            )
             while not self._Worker__exiting:  # noqa: SLF001
-                if self._server:
-                    self._server.handle_requests()
+                if control is not None and control.done():
+                    control.result()  # a dead control channel is a failure
                 if not self._configured:
                     try:
                         cfg = self._config_queue.get_nowait()
@@ -438,6 +458,8 @@ class AsyncWorker(Worker):
                     await asyncio.sleep(0.002)
                 elif self._server:
                     self._server.note_activity()
+            if control is not None:
+                control.cancel()
             status = self._exit_status or WorkerServerStatus.COMPLETED
             if self._server:
                 self._server.set_status(status)

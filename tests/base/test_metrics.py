@@ -27,6 +27,23 @@ def test_metrics_logger_jsonl_and_tensorboard(tmp_path):
         os.path.join(tmp_path, "tensorboard", "events.out.tfevents.*")
     )
     assert events, "tensorboard event file missing"
+    # the file is tensorboard's own format: records of 8 bytes of length,
+    # 4 of checksum, the Event, 4 of checksum; the first names the version
+    import struct
+
+    from tensorboard.compat.proto.event_pb2 import Event
+
+    raw, read = open(events[0], "rb").read(), []
+    while raw:
+        (n,) = struct.unpack("<Q", raw[:8])
+        read.append(Event.FromString(raw[12 : 12 + n]))
+        raw = raw[12 + n + 4 :]
+    assert read[0].file_version == "brain.Event:2"
+    scalars = [
+        (e.step, v.tag, v.simple_value) for e in read for v in e.summary.value
+    ]
+    assert (0, "loss", 1.5) in scalars and (1, "n_mbs", 4.0) in scalars
+    assert len(scalars) == 5
 
 
 def test_flops_counter_relations():

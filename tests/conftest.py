@@ -4,6 +4,7 @@ reference's gloo/CPU multi-process harness (reference: realhf/base/testing.py).
 """
 
 import os
+import sys
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -28,6 +29,29 @@ def pytest_configure(config):
     )
 
 
+#: what a whole run starts with, in this order
+FIRST = ("tests/ops/test_tpu_compile.py", "tests/yardstick/")
+
+
+def pytest_collection_modifyitems(items):
+    """The longest file and the most threaded ones first.  Under ``--dist
+    loadfile`` one worker holds a file whole: the described-TPU compiles
+    take several times as long as any other file, and started in their
+    alphabetical turn they are what the run waits for at its end, five
+    workers idle.  The benchmark drivers' end-to-end tests (a minute of
+    many threads each) came last by the alphabet, beside the experiments
+    that run as five processes or threads (``tests/system``), which then
+    took 2.6 times what they take alone and tripped the 60 s guard."""
+
+    def turn(item):
+        for n, prefix in enumerate(FIRST):
+            if item.nodeid.startswith(prefix):
+                return n
+        return len(FIRST)
+
+    items.sort(key=turn)
+
+
 @pytest.hookimpl(hookwrapper=True)
 def pytest_runtest_makereport(item, call):
     """Tier-1 per-test runtime guard: a PASSING non-``slow`` test whose
@@ -48,6 +72,80 @@ def pytest_runtest_makereport(item, call):
         rep.longrepr = msg
 
 
+#: seconds a child or a thread that is already on its way out gets
+LEFTOVER_GRACE_S = 3.0
+#: the controller's list of what its workers' sessions left behind
+_LEFTOVERS = pytest.StashKey[list]()
+
+
+def _session_leftovers(where):
+    """What this process would take into its exit: (message or None)
+    after killing the children it names."""
+    import threading
+    import time
+
+    import psutil
+
+    from tests.helpers.runtime_guard import leftovers_message
+
+    deadline = time.monotonic() + LEFTOVER_GRACE_S
+    _, alive = psutil.wait_procs(
+        psutil.Process().children(recursive=True), timeout=LEFTOVER_GRACE_S
+    )
+    children = []
+    for p in alive:
+        try:
+            if p.status() != psutil.STATUS_ZOMBIE:
+                children.append((p.pid, p.name(), " ".join(p.cmdline())))
+                p.kill()
+        except psutil.NoSuchProcess:
+            pass
+    others = [
+        t for t in threading.enumerate() if t is not threading.main_thread()
+    ]
+    for t in others:
+        if not t.daemon:
+            t.join(timeout=max(0.0, deadline - time.monotonic()))
+    threads = [(t.name, t.daemon) for t in others if t.is_alive()]
+    return leftovers_message(where, children, threads)
+
+
+def pytest_testnodedown(node, error):
+    """The controller's half: keep what a worker's session-end guard
+    sent with its last message."""
+    msg = getattr(node, "workeroutput", {}).get("leftovers")
+    if msg:
+        node.config.stash.setdefault(_LEFTOVERS, []).append(msg)
+
+
+@pytest.hookimpl(trylast=True)
+def pytest_sessionfinish(session, exitstatus):
+    """Tier-1 session-end guard, the other half of the per-test one: a
+    child process that outlives the session keeps the pipe the driver
+    reads open (two idle workers of the verifier's forked pool did, for
+    as long as the driver waited), and a non-daemon thread is joined at
+    interpreter exit; either way the command returns long after its
+    summary line, and the failure mode is an opaque rc=124.  Here each is
+    named, the children are killed, and the run fails at once
+    (tests/helpers/runtime_guard.py).  A worker sends its message to the
+    controller; the controller looks last, when its workers are down."""
+    config = session.config
+    worker_id = getattr(config, "workerinput", {}).get("workerid")
+    msg = _session_leftovers(worker_id or "the controller")
+    if worker_id is not None:
+        if msg:
+            config.workeroutput["leftovers"] = msg
+        return
+    found = config.stash.get(_LEFTOVERS, []) + ([msg] if msg else [])
+    if not found:
+        return
+    reporter = config.pluginmanager.get_plugin("terminalreporter")
+    for m in found:
+        for line in m.splitlines():
+            reporter.write_line(line, red=True)
+    session.exitstatus = pytest.ExitCode.TESTS_FAILED
+
+
 @pytest.fixture(autouse=True)
 def _fresh_globals():
     """Reset process-global state between tests."""
@@ -62,3 +160,15 @@ def _fresh_globals():
     from areal_tpu.observability import set_registry
 
     set_registry(None)  # fresh metric series per test
+    # what lives as long as its process does, which here is the whole
+    # session, ends with the test: the verifier's forked pool, and the
+    # checkpointers with their non-daemon threads
+    math_verify = sys.modules.get("areal_tpu.verifiers.math_verify")
+    if math_verify is not None:
+        math_verify._shutdown_pool()
+    checkpoint = sys.modules.get("areal_tpu.engine.checkpoint")
+    if checkpoint is not None:
+        for name in ("_checkpointer", "_quant_checkpointer"):
+            if getattr(checkpoint, name) is not None:
+                getattr(checkpoint, name).close()
+                setattr(checkpoint, name, None)

@@ -10,16 +10,17 @@ import sys
 
 import pytest
 
-from areal_tpu.base import network
-from tests.helpers.capabilities import requires_multiprocess_cpu_mesh
+from tests.helpers.capabilities import (
+    free_port_outside_the_scan,
+    requires_multiprocess_cpu_mesh,
+)
 
 _WORKER = os.path.join(os.path.dirname(__file__), "_jax_dist_worker.py")
 
 
 @requires_multiprocess_cpu_mesh
 def test_two_process_global_mesh_train_step():
-    port = network.find_free_port()
-    coordinator = f"localhost:{port}"
+    coordinator = f"localhost:{free_port_outside_the_scan()}"
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["JAX_PLATFORMS"] = "cpu"

@@ -44,3 +44,18 @@ requires_multiprocess_cpu_mesh = pytest.mark.skipif(
     "backend (gloo collectives); the multi-process launch path is "
     "exercised on images with newer jax",
 )
+
+
+def free_port_outside_the_scan() -> int:
+    """A port the kernel hands out, for a coordinator that a child process
+    binds only seconds later (after it has imported jax).
+    ``network.find_free_port`` scans upward from 20000 and returns the
+    first port nobody has BOUND, so two tests that ask within those
+    seconds of each other are both given 20000, and the second one's
+    processes wait out jax's 300 s for a coordinator that is not theirs
+    (a whole run of PR 43 lost 5 minutes to it)."""
+    import socket
+
+    with socket.socket() as s:
+        s.bind(("localhost", 0))
+        return s.getsockname()[1]

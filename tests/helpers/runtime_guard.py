@@ -1,24 +1,29 @@
-"""Tier-1 per-test runtime guard.
+"""Tier-1 runtime guards: one per test, one per session.
 
-The tier-1 suite runs under a hard 870 s ``timeout`` (ROADMAP.md) and is
-already at ~690 s: one new slow test can push the whole suite into the
-kill window, where the failure mode is an opaque rc=124 instead of a
-named offender.  This guard makes creep fail LOUDLY: ``conftest.py``
-turns any PASSING non-``slow`` test whose call phase exceeded
-:data:`TIER1_TEST_BUDGET_S` into a failure naming the test and its
-duration (the verify command also passes ``--durations=15`` so the
-near-offenders are visible every run).
+The tier-1 suite runs under a hard limit (ROADMAP.md, "Tier-1 verify",
+says what the driver runs, its limit and the suite's time): past it the
+failure mode is an opaque rc=124 instead of a named offender.  Two
+guards, wired by ``tests/conftest.py``, make that fail LOUDLY:
 
-Tests that legitimately need longer belong behind the ``slow`` marker —
-they run outside the tier-1 budget (``pytest -m slow``).
+* per test: a PASSING non-``slow`` test whose call phase exceeded
+  :data:`TIER1_TEST_BUDGET_S` becomes a failure naming the test and its
+  duration.  Tests that legitimately need longer belong behind the
+  ``slow`` marker — they run outside the tier-1 budget
+  (``pytest -m slow``).
+* per session: a child process or a non-daemon thread still alive when
+  a session ends (an xdist worker's or the controller's) is named, the
+  children are killed, and the run's exit status becomes a failure.  A
+  process left behind keeps the pipe the driver reads open, and a thread
+  is joined at interpreter exit: either holds the run long after its
+  summary line.
 
-The decision is a pure function so it is itself unit-tested
+Both decisions are pure functions so they are themselves unit-tested
 (tests/base/test_runtime_guard.py).
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence, Tuple
 
 #: per-test wall budget (seconds) for the call phase of non-slow tests.
 #: Headroom check (2026-08): the slowest tier-1 test is ~35 s
@@ -39,7 +44,39 @@ def over_budget_message(
         return None
     return (
         f"tier-1 runtime guard: {nodeid} took {duration_s:.1f}s, over "
-        f"the {budget_s:.0f}s per-test budget (suite hard-timeout is "
-        "870s total — see ROADMAP.md).  Make the test faster, or mark "
-        "it @pytest.mark.slow to move it out of tier-1."
+        f"the {budget_s:.0f}s per-test budget (the whole suite has a "
+        "hard limit: ROADMAP.md, \"Tier-1 verify\").  Make the test "
+        "faster, or mark it @pytest.mark.slow to move it out of tier-1."
+    )
+
+
+def leftovers_message(
+    where: str,
+    children: Sequence[Tuple[int, str, str]],
+    threads: Sequence[Tuple[str, bool]],
+) -> Optional[str]:
+    """The session-end decision: a failure message naming what a test
+    session left behind, else None.  ``children`` are the (pid, name,
+    command) of child processes still alive; ``threads`` the (name,
+    daemon) of live threads besides the main one.  A daemon thread is
+    not held against the run: it cannot keep an interpreter from
+    exiting, and a non-daemon one is joined at exit for as long as it
+    likes."""
+    lines = [
+        f"  child process {pid} {name}: {command}"
+        for pid, name, command in children
+    ] + [
+        f"  non-daemon thread {name}"
+        for name, daemon in threads
+        if not daemon
+    ]
+    if not lines:
+        return None
+    return "\n".join(
+        [
+            f"tier-1 session-end guard: {where} finished with these still "
+            "alive (children are killed now; the test or the code that "
+            "made each must end it):"
+        ]
+        + lines
     )

@@ -215,19 +215,27 @@ def test_whole_sequence_forward_is_the_reference(model, T):
     assert np.abs(_forward(params, cfg, toks) - want).max() < TOL
 
 
-@pytest.mark.parametrize("wrong", ref.WRONG[1:])
-def test_each_assumed_mistake_fails_the_tolerance_at_peaked_weights(model, wrong):
-    """The window left off, a gated memory unit fed its own input, a cross
-    layer attending K and V of its own input, the second map's weight 0:
-    with softmax maps that pick few positions each moves the logits by far
-    more than the tolerance, while the program stays the reference."""
+@pytest.fixture(scope="module")
+def peaked(model):
+    """Peaked weights, 40 tokens, and what the reference and the program
+    say of them: the same for every mistake below."""
     cfg, params = model
     params = _peaked(params)
     toks = np.asarray(jax.random.randint(jax.random.PRNGKey(3), (40,), 3, 64))
     right = np.asarray(ref.forward_logits(HF, params, toks))
+    return params, toks, right, _forward(params, cfg, toks)
+
+
+@pytest.mark.parametrize("wrong", ref.WRONG[1:])
+def test_each_assumed_mistake_fails_the_tolerance_at_peaked_weights(peaked, wrong):
+    """The window left off, a gated memory unit fed its own input, a cross
+    layer attending K and V of its own input, the second map's weight 0:
+    with softmax maps that pick few positions each moves the logits by far
+    more than the tolerance, while the program stays the reference."""
+    params, toks, right, got = peaked
     mistaken = np.asarray(ref.forward_logits(HF, params, toks, wrong=wrong))
     assert np.abs(mistaken - right).max() > 1000 * TOL
-    assert np.abs(_forward(params, cfg, toks) - right).max() < TOL
+    assert np.abs(got - right).max() < TOL
 
 
 def test_bfloat16_where_float32_is_stated_fails_the_tolerance(model):

@@ -30,6 +30,10 @@ def test_async_ppo_e2e(dataset_path, tokenizer_path, tmp_path, monkeypatch):
     assert "actor_train" in names_ and "actor_inf" in names_
     assert cfg.gserver_manager is not None
     assert len(cfg.gen_servers) == 1 and len(cfg.rollout_workers) == 1
+    # this fleet serves the model's own dtype: no int8 tree beside each
+    # publish (tests/system/test_weight_publish.py holds that path)
+    for w in cfg.model_workers:
+        w.publish_quantized_int8 = False
 
     master = run_experiment_local(cfg, timeout=600)
 

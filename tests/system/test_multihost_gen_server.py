@@ -7,11 +7,13 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 import pytest
 
-from tests.helpers.capabilities import requires_multiprocess_cpu_mesh
+from tests.helpers.capabilities import (
+    free_port_outside_the_scan,
+    requires_multiprocess_cpu_mesh,
+)
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(__file__)))
 RUNNER = os.path.join(REPO_ROOT, "tests", "helpers", "run_gen_server.py")
@@ -21,14 +23,14 @@ MODEL_KWARGS = {"vocab_size": 64, "max_position_embeddings": 128}
 
 @pytest.fixture
 def cluster(tmp_path, monkeypatch):
-    from areal_tpu.base import constants, name_resolve, network
+    from areal_tpu.base import constants, name_resolve
 
     nr_root = str(tmp_path / "name_resolve")
     monkeypatch.setenv("AREAL_NAME_RESOLVE_ROOT", nr_root)
     name_resolve.reconfigure("nfs", record_root=nr_root)
     constants.set_experiment_trial_names("mhgen", "t0")
 
-    coord_port = network.find_free_port()
+    coord_port = free_port_outside_the_scan()
     procs = []
     for pid in range(2):
         spec = {
@@ -64,26 +66,19 @@ def cluster(tmp_path, monkeypatch):
             )
         )
     yield procs
+    # jax.distributed takes SIGTERM for a preemption notice and the server
+    # loop goes on: a terminate() here only waits out its timeout
     for p in procs:
-        p.terminate()
+        p.kill()
     for p in procs:
-        try:
-            p.communicate(timeout=15)
-        except subprocess.TimeoutExpired:
-            p.kill()
-            p.communicate()
+        p.wait()
+        p.stdout.close()  # (a failed test has read it to its end already)
 
 
 def _dump_on_failure(procs):
     for p in procs:
-        p.terminate()
-    outs = []
-    for p in procs:
-        try:
-            outs.append(p.communicate(timeout=15)[0])
-        except subprocess.TimeoutExpired:
-            p.kill()
-            outs.append(p.communicate()[0])
+        p.kill()
+    outs = [p.communicate()[0] for p in procs]
     return "\n=====\n".join(o or "" for o in outs)
 
 

@@ -5,6 +5,7 @@ reference analogue is the classic launcher realhf/apps/main.py:78 driving
 realhf/apps/remote.py worker processes discovered via name_resolve)."""
 
 import json
+import logging
 import os
 
 import pytest
@@ -83,9 +84,13 @@ def test_multiprocess_sync_ppo(dataset_path, tokenizer_path, tmp_path, launch_en
 
 
 @requires_multiprocess_cpu_mesh
-def test_multiprocess_async_ppo(dataset_path, tokenizer_path, tmp_path, launch_env):
+def test_multiprocess_async_ppo(
+    dataset_path, tokenizer_path, tmp_path, launch_env, caplog
+):
     """Full decoupled fleet as 6 processes: master, model worker, gen
-    server, gserver manager, rollout worker (+ launcher monitoring)."""
+    server, gserver manager, rollout worker (+ launcher monitoring).
+    Every worker hears "exit" and leaves by itself: the rollout worker
+    too, whose poll is parked in a call to a manager that has gone."""
     from areal_tpu.apps.main import launch_experiment
     from tests.system.exp_factories import make_async_ppo_exp
 
@@ -96,7 +101,17 @@ def test_multiprocess_async_ppo(dataset_path, tokenizer_path, tmp_path, launch_e
     )
     cfg = exp.initial_setup()
     assert cfg.gserver_manager is not None and len(cfg.rollout_workers) == 1
-    launch_experiment(cfg, mode="local", timeout=900, env=launch_env)
+    # the "areal" loggers do not propagate to the root, where caplog listens
+    areal_log = logging.getLogger("areal")
+    areal_log.addHandler(caplog.handler)
+    try:
+        launch_experiment(cfg, mode="local", timeout=900, env=launch_env)
+    finally:
+        areal_log.removeHandler(caplog.handler)
+    said = caplog.text
+    assert "submitted rollout_worker/0" in said  # the launcher's log is here
+    assert "did not ack exit" not in said
+    assert "workers still running after master exit" not in said
 
     steps = _read_master_stats(tmp_path, cfg.experiment_name, "mp-async")
     assert len(steps) >= 2
