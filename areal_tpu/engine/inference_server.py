@@ -677,6 +677,14 @@ class ContinuousBatchingEngine:
         self.moe_fill_tokens_grouped_total = 0
         self.moe_fill_extra_rounds_total = 0
         self._fill_rounds_on_the_way: Deque[jax.Array] = deque()
+        #: layers of the stack's keep-nothing tail, which a fill runs on
+        #: each row's last position alone (``hybrid.keep_nothing_tail``:
+        #: 0 for most stacks), and the (layer, position) pairs the fills
+        #: left out for it: ``tail_layers x (F_pad x C - F_pad)`` a batch
+        self.fill_tail_layers = (
+            hybrid.keep_nothing_tail_layers(cfg) if self._by_kind else 0
+        )
+        self.fill_tail_positions_saved_total = 0
         assert kv_cache_dtype in ("auto", "int8"), kv_cache_dtype
         if kv_cache_dtype == "int8" and not self.paged:
             logger.warning(
@@ -3551,6 +3559,14 @@ class ContinuousBatchingEngine:
                 moe_fill_tokens=self.moe_fill_tokens_total,
                 moe_fill_tokens_grouped=self.moe_fill_tokens_grouped_total,
                 moe_fill_extra_rounds=self.moe_fill_extra_rounds_total,
+            )
+        if self._by_kind:
+            self.fill_tail_positions_saved_total += (
+                self.fill_tail_layers * (F_pad * C - F_pad)
+            )
+            counts.update(
+                tail_layers=self.fill_tail_layers,
+                fill_tail_positions_saved=self.fill_tail_positions_saved_total,
             )
         with self._phases.phase("areal.engine.fill.dispatch", **counts):
             toks = np.zeros((F_pad, C), np.int32)

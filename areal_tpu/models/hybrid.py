@@ -253,6 +253,38 @@ def plan_periods(cfg: TransformerConfig) -> Tuple[Tuple[Run, ...], ...]:
     return tuple(out)
 
 
+#: the mixers that leave nothing for a later POSITION to read: no K or V
+#: for a pool, no state slot, no conv tail
+KEEP_NOTHING_KINDS = ("cross", "gmu")
+
+
+def keep_nothing_tail(cfg: TransformerConfig) -> Tuple[Tuple[Run, ...], ...]:
+    """The stack's KEEP-NOTHING TAIL: the longest run of trailing periods
+    of :func:`plan_periods` every one of whose runs has a mixer of
+    ``KEEP_NOTHING_KINDS`` and the dense MLP (an expert layer reports
+    every position's routed experts and pair counts).  No later position
+    reads what these layers make of a position and the engine keeps none
+    of it, so a fill runs them on each row's LAST position alone, the one
+    the head reads (``hybrid_fill_chunk``; YOCO's prefill saving, arXiv
+    2405.05254).  phi4flash: the cross-decoder, ``[gmu, cross] x 7``;
+    empty for a stack that ends in any other kind."""
+    periods = plan_periods(cfg)
+    n = 0
+    for period in reversed(periods):
+        if not all(
+            run.kind in KEEP_NOTHING_KINDS and run.mlp == "dense"
+            for run in period
+        ):
+            break
+        n += 1
+    return periods[len(periods) - n :]
+
+
+def keep_nothing_tail_layers(cfg: TransformerConfig) -> int:
+    """Layers in :func:`keep_nothing_tail`."""
+    return sum(run.count for period in keep_nothing_tail(cfg) for run in period)
+
+
 def pool_layer_numbers(cfg: TransformerConfig, kind: str) -> np.ndarray:
     """The numbers, in ``params["attn"]``'s stack, of the layers whose
     pages live in ``kind``'s pool, in the pool's order."""
@@ -1493,18 +1525,37 @@ def hybrid_fill_chunk(
             k.astype(k_pool.dtype), v.astype(v_pool.dtype)
         )
 
-    def cross_mixer(run, h, l, j):
+    def cross_mixer(run, h, l, j, last=False):
         """Queries only, over the shared layer's pages (the pool's one
-        layer) and its K and V of this chunk's tokens."""
+        layer) and its K and V of this chunk's tokens.  ``last``: ``h``
+        holds each row's last valid position alone (at ``positions_last``,
+        its calls' page plan ``plan_last``), which attends every valid
+        position of its chunk."""
         ap = _at(params["cross"], j)
-        q = _heads_q(cfg, ap, h, positions, run)
-        prefix = paged._prefix_partials(
-            q, k_pool, v_pool, tables, read_lens, 0, use_kernel,
-            plan=plan, scale=scale,
-        )
+        if last:
+            q = _heads_q(cfg, ap, h, positions_last, run)
+            # a query tile of TWO rows, the second zero: the kernel names
+            # a call of one query row a sequence a decode step
+            # (``paged_attn_decode``), and the decode kernel's share of
+            # its roofline counts every execution of that name
+            prefix = tuple(
+                t[:, :1]
+                for t in paged._prefix_partials(
+                    jnp.pad(q, ((0, 0), (0, 1), (0, 0), (0, 0))), k_pool,
+                    v_pool, tables, read_lens, 0, use_kernel,
+                    plan=plan_last, scale=scale,
+                )
+            )
+            mask = valid[:, None, :]
+        else:
+            q = _heads_q(cfg, ap, h, positions, run)
+            prefix = paged._prefix_partials(
+                q, k_pool, v_pool, tables, read_lens, 0, use_kernel,
+                plan=plan, scale=scale,
+            )
+            mask = mask_chunk
         attn = paged.chunk_attention(
-            q, *shared["kv"], prefix, mask_chunk, scale,
-            _attn_dtype(cfg, h.dtype),
+            q, *shared["kv"], prefix, mask, scale, _attn_dtype(cfg, h.dtype)
         )
         return _heads_out(cfg, ap, l, attn, h.dtype)
 
@@ -1563,7 +1614,8 @@ def hybrid_fill_chunk(
         x, n, r, m = _mlp_half(cfg, params, run, l, e, x, valid, a)
         return (x, ssm, _add_pairs(pairs, n), _add_pairs(rounds, m)), (kept, r)
 
-    for period in plan_periods(cfg):
+    periods, tail = plan_periods(cfg), keep_nothing_tail(cfg)
+    for period in periods[: len(periods) - len(tail)]:
         carry, left = _scan_period(body, carry, period, xs_of)
         for run, (kept, r) in zip(period, left):
             if run.kind in ("mamba", "mamba1"):
@@ -1580,6 +1632,30 @@ def hybrid_fill_chunk(
             if r is not None:
                 routed.append(r)
     x, ssm, pairs, rounds = carry
+
+    def tail_body(x, idx, run):
+        l, j, e, _ = idx
+        with _mixer_region(run):
+            a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
+            if run.kind == "gmu":
+                out = gmu(_at(params["gmu"], j), a, memory_last)
+            else:
+                out = cross_mixer(run, a, l, j, last=True)
+            x = _res(cfg, x, out)
+        return _mlp_half(cfg, params, run, l, e, x, row_valid[:, None])[0], None
+
+    if tail:
+        # the keep-nothing tail, on [F, 1, D]: each row's last valid
+        # position (a padding row's position 0, masked as it was)
+        x = paged.last_valid(x, chunk_lens)
+        if "memory" in shared:
+            memory_last = paged.last_valid(shared["memory"], chunk_lens)
+        positions_last = (starts + jnp.maximum(chunk_lens - 1, 0))[:, None]
+        plan_last = paged._prefix_plan(
+            2, cfg.n_q_heads, k_pool, tables, read_lens, use_kernel
+        )
+        for period in tail:
+            x, _ = _scan_period(tail_body, x, period, _run_indices)
     if tails1:
         conv = _put_conv_tails(
             conv, slots, jnp.concatenate(tails1, axis=0), row_valid
@@ -1601,7 +1677,9 @@ def hybrid_fill_chunk(
             win_pools, vals_win, win_tables, starts, chunk_lens
         )
     k_pool, v_pool = pools + ((v_pool,) if latent else ())
-    logits = _logits(params, cfg, paged.last_valid(x, chunk_lens))[:, 0]
+    if not tail:
+        x = paged.last_valid(x, chunk_lens)
+    logits = _logits(params, cfg, x)[:, 0]
     out = (
         logits, k_pool, v_pool, ssm, conv, pairs,
         jnp.concatenate(routed, axis=0) if routed else None, rounds,

@@ -1135,7 +1135,12 @@ TRACE_TABLE = [
         "that holds a share of the experts the running totals "
         "moe_fill_tokens, moe_fill_tokens_grouped = those in a batch "
         "whose shape takes the grouped product, moe_fill_extra_rounds = "
-        "rounds past the first of the fills whose programs have run)",
+        "rounds past the first of the fills whose programs have run; "
+        "for a stack stated by kind tail_layers = layers of its "
+        "keep-nothing tail, which a fill runs on each row's last "
+        "position alone, and the running total "
+        "fill_tail_positions_saved = tail_layers x (f_pad x c - f_pad) "
+        "summed over the fills)",
     ),
     TraceSpec(
         "areal.engine.fill.first_token_wait",

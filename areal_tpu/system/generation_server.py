@@ -1733,6 +1733,13 @@ class GenerationServerWorker(worker_base.Worker):
                     eng.moe_fill_tokens_grouped_total,
                     eng.moe_fill_extra_rounds_total,
                 )
+            if eng._by_kind:
+                self.logger.info(
+                    "keep-nothing tail at fill: tail_layers=%d, "
+                    "fill_tail_positions_saved=%d",
+                    eng.fill_tail_layers,
+                    eng.fill_tail_positions_saved_total,
+                )
             # releases the ledger attributions (and logs the leak audit:
             # a quiesced server returns the process ledger to baseline)
             eng.close()

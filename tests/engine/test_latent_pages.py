@@ -17,6 +17,9 @@ from areal_tpu.engine.sampling import SamplingParams
 from areal_tpu.models import hybrid, moe
 from benchmark.lib import reference_deepseek_v3 as ref
 from tests.model.test_latent import HF, make_cfg
+from tests.engine.test_window_pages import (
+    assert_no_fill_leaves_a_tail_position_out,
+)
 
 # one chip of four that share each layer's 16 experts: experts 4-7 here,
 # a whole routing group
@@ -187,6 +190,14 @@ def test_dispatch_span_counts_the_latent_context(model):
     assert counts["latent_ctx_tokens_sum"] == counts["ctx_tokens_sum"] >= 13
     assert counts["latent_pages_attended"] == counts["pages_attended"] == 2
     assert "state_rows_sum" not in counts
+
+
+def test_the_fill_span_of_a_stack_without_a_tail_says_zero(model):
+    """Latent layers keep their entries and the expert layers report
+    their routing: every layer of a fill runs on every position."""
+    assert_no_fill_leaves_a_tail_position_out(
+        make_engine(model), _req("t0", _prompts(5, 11)[0], 3), run_until_done
+    )
 
 
 def test_fills_that_take_the_grouped_product_are_the_reference(model, monkeypatch):

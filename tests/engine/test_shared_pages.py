@@ -20,7 +20,7 @@ from areal_tpu.engine.sampling import SamplingParams
 from areal_tpu.models import hybrid
 from benchmark.lib import reference_phi4flash as ref
 from tests.engine.test_window_pages import (
-    BS, CHUNK, _prompts, _req, check_page_rule, run_until_done,
+    BS, CHUNK, _prompts, _req, check_page_rule, fill_spans, run_until_done,
 )
 from tests.model.test_sambay import HF, make_cfg
 
@@ -125,6 +125,25 @@ def test_the_dispatch_span_counts_the_layers_that_read_the_global_pool(model):
         c["window_tokens_sum"] <= c["ctx_tokens_sum"] and c["rows"] == 1
         for c in seen
     )
+
+
+def test_the_fill_span_counts_the_tail_layers_and_the_positions_they_skip(model):
+    """Layers 8-11 here (``[gmu, cross] x 2``) keep nothing: a fill runs
+    them on each row's last position, and its span says how many (layer,
+    position) pairs that left out, as a running total."""
+    eng = make_engine(model, prefill_chunk_tokens=16)
+    assert eng.fill_tail_layers == 4
+    fills = fill_spans(eng)
+    for i, p in enumerate(_prompts(4, 19, 7, 30)):
+        eng.submit(_req(f"d{i}", p, 4))
+    run_until_done(eng)
+    assert len(eng.drain_results()) == 3
+    assert len(fills) >= 3 and all(c["tail_layers"] == 4 for c in fills)
+    total = 0
+    for c in fills:
+        total += 4 * (c["f_pad"] * c["c"] - c["f_pad"])
+        assert c["fill_tail_positions_saved"] == total
+    assert eng.fill_tail_positions_saved_total == total > 0
 
 
 def test_a_preempted_row_is_computed_again_through_the_fill_queue(model):

@@ -20,6 +20,9 @@ from areal_tpu.engine.sampling import SamplingParams
 from areal_tpu.models import hybrid, moe
 from benchmark.lib import reference_granitemoehybrid as ref
 from tests.model.test_hybrid import HF, make_cfg
+from tests.engine.test_window_pages import (
+    assert_no_fill_leaves_a_tail_position_out,
+)
 
 # one chip of two that share each layer's 8 experts: experts 2-5 here
 FIRST, HELD = 2, 4
@@ -163,6 +166,15 @@ def test_no_fill_of_a_stack_with_state_takes_the_grouped_product(model, monkeypa
         assert eng.moe_fill_extra_rounds_total == 0
     finally:
         jax.clear_caches()
+
+
+def test_the_fill_span_of_a_stack_without_a_tail_says_zero(model):
+    """Mamba layers keep a state and attention layers pages, and the
+    expert layers report their routing: every layer of a fill runs on
+    every position."""
+    assert_no_fill_leaves_a_tail_position_out(
+        make_engine(model), _req("t0", _prompts(5, 11)[0], 3), run_until_done
+    )
 
 
 def test_recompute_preemption_goes_through_the_fill_path(model):

@@ -95,6 +95,36 @@ def run_until_done(eng, max_steps=600, each=check_page_rule):
     raise AssertionError("engine did not drain")
 
 
+def fill_spans(eng):
+    """The counts of every ``areal.engine.fill.dispatch`` span the engine
+    opens from now on, in the list returned."""
+    seen, phase = [], eng._phases.phase
+
+    def spy(name, **counts):
+        if name == "areal.engine.fill.dispatch":
+            seen.append(counts)
+        return phase(name, **counts)
+
+    eng._phases.phase = spy
+    return seen
+
+
+def assert_no_fill_leaves_a_tail_position_out(eng, req, run):
+    """A stack without a keep-nothing tail, serving ``req`` to its
+    end (``run(eng)``): every layer of a fill runs on every position, and
+    the span says so."""
+    fills = fill_spans(eng)
+    eng.submit(req)
+    run(eng)
+    assert len(eng.drain_results()) == 1
+    assert fills and eng.fill_tail_layers == 0
+    assert all(
+        c["tail_layers"] == 0 and c["fill_tail_positions_saved"] == 0
+        for c in fills
+    )
+    assert eng.fill_tail_positions_saved_total == 0
+
+
 def assert_reference(params, results, eng, tol=2e-5):
     fn = ref.make_token_logps(HF)
     for qid, out in sorted(results.items()):
@@ -174,6 +204,14 @@ def test_a_long_prompt_fills_under_the_rule_and_siblings_share_its_window(model)
     assert eng.window_pages_released >= 3 + 1 + 3
     assert eng._win.row_pages_max <= -(-(WINDOW + 2 * CHUNK) // BS) + 1
     assert_nothing_leaked(eng)
+
+
+def test_the_fill_span_of_a_stack_without_a_tail_says_zero(model):
+    """Window and global layers with experts keep pages and report their
+    routing: no layer of this stack runs on the last position alone."""
+    assert_no_fill_leaves_a_tail_position_out(
+        make_engine(model), _req("t0", _prompts(5, 11)[0], 3), run_until_done
+    )
 
 
 def test_a_late_sibling_reuses_the_prefix_while_its_window_tail_is_held(model):

@@ -12,6 +12,8 @@ Every tolerance here is 2e-5 on log-probabilities or logits of deviation
 ~0.5, float32 against float32 at "highest" precision (readings: 1e-6)."""
 
 import dataclasses
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +23,7 @@ import pytest
 from areal_tpu.models import hybrid, paged
 from areal_tpu.models.hf.registry import family_from_architecture, get_hf_family
 from areal_tpu.ops import ssm as ssm_ops
+from benchmark.lib import program as bench_program
 from benchmark.lib import reference_phi4flash as ref
 
 WINDOW = 12
@@ -332,6 +335,137 @@ def test_fill_in_chunks_then_decode_through_pools_and_slots_is_the_reference(
     longer = ref.make_token_logps(dict(HF, sliding_window=WINDOW + BS))
     moved = ref.sequence_logps(longer, params, seq, pad_to=32)
     assert np.abs(moved[P - 1 :] - want[P - 1 :]).max() > 100 * TOL
+
+
+def _cell_cfg(name):
+    """A benchmark cell's stack as its server runs it (no weights)."""
+    path = os.path.join(
+        os.path.dirname(bench_program.__file__), "..", "configs", name + ".json"
+    )
+    with open(path) as f:
+        return bench_program.model_config(json.load(f), "serve")
+
+
+# (the stack, its keep-nothing tail as [(kind, first layer, every, count)
+# a run] a period).  A layer that writes pages or a state, or an expert
+# layer (it reports every position's routed experts), ends the tail
+@pytest.mark.parametrize(
+    "cfg_of, want",
+    [
+        (
+            lambda: _cell_cfg("phi-4-mini-flash-reasoning"),
+            [[("gmu", 18, 2, 7), ("cross", 19, 2, 7)]],  # layers 18-31
+        ),
+        (lambda: make_cfg(), [[("gmu", 8, 2, 2), ("cross", 9, 2, 2)]]),
+        (lambda: _cell_cfg("granite-4.0-h-small"), []),
+        (lambda: _cell_cfg("gigachat3.1-702b-a36b"), []),
+        (lambda: _cell_cfg("smallthinker-21b-a3b"), []),
+        # the LAST layer a cross layer with experts: not keep-nothing, and
+        # the tail is a run of TRAILING periods
+        (lambda: make_cfg(n_dense_layers=11), []),
+        # only the trailing cross layer is behind the last layer that keeps
+        (
+            lambda: make_cfg(
+                layer_types=KINDS[:10] + ("window", "cross"),
+            ),
+            [[("cross", 11, 1, 1)]],
+        ),
+    ],
+    ids=[
+        "phi4flash", "tiny", "granite", "gigachat", "smallthinker",
+        "cross-with-experts-last", "window-behind-a-cross",
+    ],
+)
+def test_the_keep_nothing_tail_follows_the_layer_kinds(cfg_of, want):
+    cfg = cfg_of()
+    tail = hybrid.keep_nothing_tail(cfg)
+    got = [
+        [(r.kind, r.first_layer, r.every, r.count) for r in period]
+        for period in tail
+    ]
+    assert got == want
+    periods = hybrid.plan_periods(cfg)
+    assert tail == periods[len(periods) - len(tail):]
+    assert hybrid.keep_nothing_tail_layers(cfg) == sum(
+        n for period in want for _, _, _, n in period
+    )
+
+
+def _whole_chunk_fill(monkeypatch):
+    """``hybrid_fill_chunk`` with the tail on EVERY position of the chunk,
+    as every other layer runs: the program under an empty
+    ``keep_nothing_tail`` (a jit of its own: the rule is read when a
+    program is traced).  A test's yardstick, not a path of the program."""
+    monkeypatch.setattr(hybrid, "keep_nothing_tail", lambda cfg: ())
+    return jax.jit(
+        hybrid.hybrid_fill_chunk.__wrapped__,
+        static_argnames=("cfg", "use_kernel"),
+    )
+
+
+#: fill batches of [2, 16] as calls of ``(tokens already filled, tokens of
+#: this chunk)`` a row, (0, 0) a padding row; prompts of 32 tokens
+FILL_BATCHES = {
+    # a row that ends in the middle of its chunk, a padding row beside it
+    "mid-chunk+padding": [[(0, 11), (0, 0)]],
+    # rows whose prefix lies in pages (two and three pages of 8: past the
+    # window of 12), one ending mid-chunk and one at its chunk's end
+    "prefix-in-pages": [[(0, 16), (0, 16)], [(16, 9), (16, 16)]],
+    # a third chunk beside a fresh row: starts differ within the batch
+    "third-chunk+fresh": [[(0, 16), (0, 0)], [(16, 8), (0, 0)], [(24, 5), (0, 13)]],
+}
+
+
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["jnp", "kernel"])
+@pytest.mark.parametrize("batch", list(FILL_BATCHES))
+def test_a_fill_runs_the_tail_on_the_last_position_and_keeps_what_the_whole_chunk_form_kept(
+    model, monkeypatch, batch, use_kernel
+):
+    """Last logits against ``hybrid.forward`` of the row's prompt so far;
+    pools, window pools, states and conv tails EQUAL to what the program
+    leaves with the tail on every position."""
+    cfg, params = model
+    BS, MB, C = 8, 10, 16
+    prompts = np.asarray(jax.random.randint(jax.random.PRNGKey(7), (2, 32), 3, 64))
+    tables = np.zeros((2, MB), np.int32)
+    tables[:, :4] = [[3, 5, 7, 9], [11, 13, 1, 2]]
+    wtables = np.zeros((2, MB), np.int32)
+    wtables[:, :4] = [[4, 6, 8, 10], [12, 14, 15, 1]]
+    slots = jnp.asarray([2, 1], jnp.int32)
+
+    def run(fill):
+        pools = paged.pool_zeros(cfg, 16, BS)
+        win = paged.pool_zeros(cfg, 16, BS, layers=cfg.n_window_layers)
+        ssm, conv = hybrid.state_zeros(cfg, 4)
+        ssm = ssm + 3.0  # a slot is never cleared by a pass of its own
+        for call in FILL_BATCHES[batch]:
+            toks = np.zeros((2, C), np.int32)
+            for i, (start, take) in enumerate(call):
+                toks[i, :take] = prompts[i, start : start + take]
+            starts, takes = (jnp.asarray(a, jnp.int32) for a in zip(*call))
+            live = np.asarray(takes) > 0
+            logits, *pools, ssm, conv, _, routed, _, win = fill(
+                params, *pools, ssm, conv, cfg, jnp.asarray(toks), starts, takes,
+                jnp.asarray(tables * live[:, None]), slots * live,
+                use_kernel=use_kernel, win_pools=win,
+                win_tables=jnp.asarray(wtables * live[:, None]),
+            )
+            assert routed is None
+        return np.asarray(logits), jax.tree.map(np.asarray, (pools, win, ssm, conv))
+
+    with jax.default_matmul_precision("highest"):
+        logits, kept = run(hybrid.hybrid_fill_chunk)
+        logits_whole, kept_whole = run(_whole_chunk_fill(monkeypatch))
+    for i, (start, take) in enumerate(FILL_BATCHES[batch][-1]):
+        if take:
+            want = _forward(params, cfg, prompts[i, : start + take])[-1]
+            assert np.abs(logits[i] - want).max() < TOL, i
+            assert np.abs(logits[i] - logits_whole[i]).max() < TOL, i
+    assert np.isfinite(logits).all()  # a padding row's too, which nobody reads
+    for got, want in zip(jax.tree.leaves(kept), jax.tree.leaves(kept_whole)):
+        assert np.array_equal(got, want)
+    # slot 0 is a padding row's, slot 3 nobody's
+    assert np.array_equal(kept[2][:, [0, 3]], np.full_like(kept[2][:, [0, 3]], 3.0))
 
 
 def test_the_float8_control_rounds_matrices_and_leaves_the_recurrence_alone(model):

@@ -1012,7 +1012,15 @@ def test_shared_fill_program_fits_and_copies_no_pool_for_a_reading_layer(
     text = compiled.as_text()
     for name in ("paged_attn_fill", "paged_window_fill", "ssm_state_rows"):
         assert name in text, name
+    # the keep-nothing tail's one query a row reads its prefix under the
+    # fill's name: the decode kernel's share of its roofline counts every
+    # execution named ``paged_attn_decode`` in a traced slice
+    assert "paged_attn_decode" not in text
     total, temp = _assert_shared_program_fits(compiled, pools, ssm)
+    # not above what the program took with the tail on every position
+    # (this compile at PR 43; the peak is a self-decoder layer's, so the
+    # fall is small: 0.026 and 0.001 GB)
+    assert temp <= {2: 660_966_400, 1: 301_635_584}[F], temp
     # the chunk's own K and V of the shared layer (2.6 MB a row each): at
     # most ONE relayout of each for the whole program, not one a reader
     once = _pool_copies(compiled, (1, F, C, 10, 128)) + _pool_copies(
