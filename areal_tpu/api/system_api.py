@@ -196,7 +196,7 @@ class GenServerConfig:
     page_size: int = 1024
     kv_pool_tokens: Optional[int] = None
     # the pool of a stack's WINDOW layers, whose pages are released once
-    # every holder's window has passed them (engine/window_pages.py);
+    # every holder's window has passed them (engine/kv_pages.py);
     # None = as many tokens as kv_pool_tokens.  Read only where the model
     # has such layers
     kv_window_pool_tokens: Optional[int] = None

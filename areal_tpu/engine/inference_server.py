@@ -7,9 +7,9 @@ weight-update mechanism, and realhf/impl/model/nn/real_llm_generate.py:670
 ``InflightBatchingGenerator``).
 
 Design:
-* One shared KV cache of ``max_batch`` independent rows (the model's
-  ``KVCache`` rows advance independently, so admission is a per-row prefill
-  scatter and decoding is one jitted multi-token chunk over all rows).
+* ``max_batch`` independent rows whose cache is held BY KIND: whole-context
+  pages, a window pool, recurrent state slots, latent pages (last item); the
+  dense ``KVCache`` rows (auto under 2k context) are the parity reference.
 * The host loop alternates: admit pending requests into free rows ->
   dispatch a ``decode_chunk`` (``chunk_size`` tokens fully device-side)
   into a ``pipeline_depth``-deep in-flight ring -> harvest the OLDEST
@@ -38,16 +38,16 @@ Design:
   (request seed, absolute position) from a fixed base key, so
   chunking / row placement /
   pipelining / acceptance length can never perturb sampled streams.
-* ``cache_mode="paged"`` (auto at >= 2k context) replaces the dense rows
-  with a shared BLOCK POOL + per-row block tables
-  (areal_tpu/models/paged.py — the paged/radix-cache role of the
-  reference's SGLang server): capacity is allocated in pages as rows
-  actually grow, a sampling group's prompt is shared by block REFERENCE
-  (one fill, refcounted full pages, per-member tail-page copy), pool
-  pressure evicts parked rows then preempts the youngest active rows
-  (recompute-on-readmit), and long prompts prefill in
-  ``prefill_chunk_tokens`` chunks interleaved with decode so admission
-  never stalls decoding for a whole wave (chunked prefill).
+* ``cache_mode="paged"`` (auto at >= 2k context, always for a stack stated
+  by kind): a shared BLOCK POOL + per-row block tables (models/paged.py —
+  the paged/radix-cache role of the reference's SGLang server).  Pages are
+  allocated as rows grow, a group's prompt is shared by block REFERENCE
+  (one fill, refcounted full pages, per-member tail-page copy), pressure
+  evicts cache entries and parked rows, then preempts the youngest rows,
+  long prompts prefill in ``prefill_chunk_tokens`` chunks between decodes.
+  The pools' host side (allocator, tables, the window layers' page rule,
+  what a cache kind refuses) is engine/kv_pages.py; a stack stated by kind
+  takes models/hybrid.py's fill and decode programs, any other paged.py's.
 """
 
 from __future__ import annotations
@@ -69,7 +69,13 @@ from areal_tpu.api import model_api
 from areal_tpu.base import jax_compat, logging_
 from areal_tpu.engine import spec_decode
 from areal_tpu.engine.batching import bucket_len, spec_window_bucket
-from areal_tpu.engine.window_pages import GONE, WindowPages
+from areal_tpu.engine.kv_pages import (  # noqa: F401 - callers name it here
+    GONE,
+    PagePool,
+    StatefulModelUnsupported,
+    kinds_held,
+    refuse,
+)
 from areal_tpu.engine.prefix_cache import PrefixMatch, RadixPrefixCache
 from areal_tpu.engine.sampling import SamplingParams, sample_logits_keyed
 from areal_tpu.models import hybrid, moe, paged, quantize
@@ -212,7 +218,7 @@ class _Fill:
     #: advances chunk by chunk (its first target's); siblings get a copy
     state_slot: int = -1
     #: a stack with window layers: their pages by number (``GONE`` where
-    #: released behind the fill: engine/window_pages.py)
+    #: released behind the fill: engine/kv_pages.py)
     wblocks: Optional[List[int]] = None
     #: ``keep_routed_experts``: ``(routed [L, F, C, K] on the device, this
     #: fill's row in it, its valid tokens)`` of each chunk so far
@@ -414,24 +420,6 @@ def _warn_paged_reference(head_dim: int):
     )
 
 
-class StatefulModelUnsupported(NotImplementedError):
-    """A feature that assumes a sequence's cache is per-token blocks was
-    asked of a model whose layers also keep a recurrent state per
-    sequence (``cfg.n_mamba_layers > 0``): that state exists at the end
-    of what was computed and nowhere else, so it cannot be cut at a page
-    boundary, rewound after a rejected draft, or rebuilt from KV pages
-    another server sends.  ``kinds``: the cache kinds the model holds
-    (the state slots first: they are what refuses)."""
-
-    def __init__(self, feature: str, kinds: str = "recurrent state slots"):
-        super().__init__(
-            f"{feature} is not supported for a model with {kinds}: the "
-            "state slots refuse it (a state exists where its sequence "
-            "ends, not at page boundaries)"
-        )
-        self.feature = feature
-
-
 class ContinuousBatchingEngine:
     """Thread-safe continuous-batching generation over one model mesh."""
 
@@ -505,7 +493,7 @@ class ContinuousBatchingEngine:
         ``max_batch * kv_cache_len``; set smaller to serve long contexts a
         dense cache could never reserve).  ``kv_window_pool_tokens`` sizes
         the pool of a stack's WINDOW layers (default: as many tokens):
-        their pages follow a rule of their own (engine/window_pages.py).
+        their pages follow a rule of their own (engine/kv_pages.py).
         ``prefill_chunk_tokens`` bounds
         the prompt tokens prefetched per engine step — the decode stall
         during a long-prompt admission is one chunk, not the whole wave.
@@ -614,12 +602,14 @@ class ContinuousBatchingEngine:
                 "hand their routing out, and only a stack with expert "
                 "layers has any"
             )
-        #: window layers have pools, a table and a page rule of their own
-        #: (``_init_paged_state``); what moves whole rows' pages between
-        #: servers (handoff, prefix pull) is not written for two tables
-        self._windowed = bool(cfg.is_hybrid and cfg.n_window_layers)
-        self._win: Optional[WindowPages] = None
-        self._win_tables = None
+        #: the host side of the device pools (engine/kv_pages.py): the
+        #: whole-context pages' and, where the stack has window layers,
+        #: theirs (pools, a table and a page rule of their own); both in
+        #: ``_pools``.  ``_kinds``: the cache kinds that rule something out
+        self._pages: Optional[PagePool] = None
+        self._win: Optional[PagePool] = None
+        self._pools: List[PagePool] = []
+        self._kinds = kinds_held(cfg)
         self._kv_window_pool_tokens = kv_window_pool_tokens
         self.win_k_pool = self.win_v_pool = None
         #: cached prefixes a request could not reuse because the window
@@ -631,29 +621,20 @@ class ContinuousBatchingEngine:
         #: request that reuses such a prompt's cached pages prefills its
         #: tail alone and takes the rest of its routing from here
         self._routed_prompts: Dict[Tuple[int, ...], np.ndarray] = {}
+        # what an option asks for that one of the model's cache kinds rules
+        # out refuses by name here, where the option is set
+        spec = spec_decode_params is not None and spec_decode_params.enabled
+        asked = {
+            "the dense (unpaged) KV cache": cache_mode == "dense",
+            "a tensor- or expert-parallel serving mesh": mesh is not None,
+            "speculative verify": spec,
+            "int8 KV storage": kv_cache_dtype == "int8",
+            "int8 serving weights": serving_weight_dtype == "int8",
+            "prefix-cache host spill": prefix_cache_host_bytes > 0,
+        }
+        for feature in (f for f, yes in asked.items() if yes):
+            refuse(feature, self._kinds)
         if self._by_kind:
-            # what the stack's two programs do not write, whatever its
-            # kinds; and what a recurrent state rules out besides
-            spec = spec_decode_params is not None and spec_decode_params.enabled
-            refused = {
-                "the dense (unpaged) KV cache": cache_mode == "dense",
-                "a tensor- or expert-parallel serving mesh": mesh is not None,
-                "speculative verify": spec,
-                "int8 KV storage": kv_cache_dtype == "int8",
-                "int8 serving weights": serving_weight_dtype == "int8",
-            }
-            if self._stateful or cfg.n_window_layers:
-                refused["prefix-cache host spill"] = prefix_cache_host_bytes > 0
-            for feature, asked in refused.items():
-                if not asked:
-                    continue
-                if self._stateful:
-                    raise StatefulModelUnsupported(feature, self._cache_kinds())
-                raise NotImplementedError(
-                    f"{feature} is not supported for a stack stated by "
-                    f"kind {sorted(set(cfg.layer_types))}: its fill and "
-                    "decode programs (models/hybrid.py) do not write it"
-                )
             self.paged = True
         #: sibling copies of a fill's end state; fills built for a prompt
         #: that a live row already carries (a late sibling: the state at
@@ -958,7 +939,9 @@ class ContinuousBatchingEngine:
         # counters plus exporter-side aborts (a stream cut short by EOS
         # at the first token, a weight swap restarting the fill, or an
         # explicit cancel — the decode peer releases its partial blocks)
-        self._handoff_streaming = bool(handoff_streaming) and not self._windowed
+        self._handoff_streaming = (
+            bool(handoff_streaming) and not cfg.n_window_layers
+        )
         self.handoff_segment_exports_total = 0
         self.handoff_segment_imports_total = 0
         self.handoff_segment_aborts_total = 0
@@ -1091,9 +1074,6 @@ class ContinuousBatchingEngine:
         self.page_size = BS
         self.blocks_per_row = -(-self.kv_cache_len // BS)  # MB
         pool_tokens = kv_pool_tokens or max_batch * self.kv_cache_len
-        self.n_blocks = max(
-            -(-pool_tokens // BS), self.blocks_per_row
-        )  # NB; one full-length row always fits
         self.prefill_chunk_tokens = prefill_chunk_tokens
         # TPU: the Pallas kernel (shard_mapped over the kv-head axis under
         # a TP mesh); elsewhere: the vectorized jnp reference (the kernel
@@ -1113,7 +1093,24 @@ class ContinuousBatchingEngine:
         if on_tpu and not self._use_paged_kernel:
             _warn_paged_reference(cfg.pool_head_dim)
         kv_dtype = self.kv_cache_dtype
-        if self._pool_sharding is not None:
+        # COMMITTED to the device from the start: a program's cache key
+        # holds whether each argument is, so the first fill (fresh,
+        # uncommitted zeros) and a later one of the same shape (a
+        # program's outputs) were two programs, and the second was built
+        # inside a benchmark's window
+        commit = self._by_kind and self.device is not None
+
+        def alloc(n: int, layers: Optional[int]):
+            def make():
+                return paged.alloc_kv_pool(
+                    cfg, n, BS, kv_cache_dtype=kv_dtype, layers=layers
+                )
+
+            if self._pool_sharding is None:
+                arrays = make()
+                if commit:
+                    arrays = jax.device_put(arrays, self.device)
+                return arrays
             shardings = (self._pool_sharding, self._pool_sharding)
             if self._kv_quant:
                 shardings += (
@@ -1121,78 +1118,55 @@ class ContinuousBatchingEngine:
                 )
             else:
                 shardings += (None, None)  # None leaves: no sharding slot
-            alloc = jax.jit(
-                lambda: paged.alloc_kv_pool(
-                    cfg, self.n_blocks, BS, kv_cache_dtype=kv_dtype
-                ),
-                out_shardings=shardings,
+            return jax.jit(make, out_shardings=shardings)()
+
+        # the stack's kinds of pages, each a device pool with its host side
+        # (engine/kv_pages.py): the layers that attend the whole context,
+        # then the window layers, whose pages go once every holder's
+        # window has passed them.  (layers of its own, tokens, window)
+        kinds = [(None, pool_tokens, None)]
+        if cfg.n_window_layers:
+            kinds.append((
+                cfg.n_window_layers,
+                self._kv_window_pool_tokens or pool_tokens,
+                cfg.sliding_window,
+            ))
+        pool_b = scale_b = 0
+        for layers, tokens, window in kinds:
+            # (one full-length row always fits)
+            n = max(-(-tokens // BS), self.blocks_per_row)
+            arrays = alloc(n, layers)
+            # ledger attribution: the alloc itself may run under jit
+            # (sharded path), so sizes come from the pure layout math,
+            # which matches the allocated arrays' nbytes exactly
+            b = paged.kv_pool_layout_bytes(
+                cfg, n, BS, kv_cache_dtype=kv_dtype, layers=layers
             )
-            (self.k_pool, self.v_pool, self.k_scale, self.v_scale) = alloc()
-        else:
-            (self.k_pool, self.v_pool, self.k_scale, self.v_scale) = (
-                paged.alloc_kv_pool(
-                    cfg, self.n_blocks, BS, kv_cache_dtype=kv_dtype
-                )
-            )
-        # ledger attribution: the alloc itself may run under jit (sharded
-        # path), so sizes come from the pure layout math, which matches
-        # the allocated arrays' nbytes exactly
-        pool_b, scale_b = paged.kv_pool_layout_bytes(
-            cfg, self.n_blocks, BS, kv_cache_dtype=kv_dtype
-        )
-        if self._windowed:
-            # the window layers' pools, table and allocator: a page there
-            # goes once every holder's window has passed it
-            win_tokens = self._kv_window_pool_tokens or pool_tokens
-            n_win = max(-(-win_tokens // BS), self.blocks_per_row)
-            self.win_k_pool, self.win_v_pool, _, _ = paged.alloc_kv_pool(
-                cfg, n_win, BS, layers=cfg.n_window_layers
-            )
-            if self.device is not None:
-                self.win_k_pool, self.win_v_pool = jax.device_put(
-                    (self.win_k_pool, self.win_v_pool), self.device
-                )
-            pool_b += paged.kv_pool_layout_bytes(
-                cfg, n_win, BS, layers=cfg.n_window_layers
-            )[0]
-            self._win = WindowPages(
-                n_win, BS, cfg.sliding_window, max_batch, self.blocks_per_row
-            )
-            self._win_tables = jnp.array(self._win.tables_np)
-            #: references the prefix cache holds, by global block: the
-            #: window-layer page cached with a block goes when they do
-            self._cache_refs: Dict[int, int] = {}
+            pool_b, scale_b = pool_b + b[0], scale_b + b[1]
+            # host allocator: LIFO free stack + refcounts (shared prompt
+            # blocks); all decisions host-deterministic for SPMD lockstep
+            pool = PagePool(n, BS, max_batch, self.blocks_per_row, window)
+            self._pools.append(pool)
+            if window is None:
+                self._pages, self.n_blocks = pool, n  # NB
+                self.k_pool, self.v_pool, self.k_scale, self.v_scale = arrays
+            else:
+                self._win = pool
+                self.win_k_pool, self.win_v_pool = arrays[:2]
         if self._by_kind:
-            # (no byte where no layer is recurrent)
+            # the recurrent state slots, a slot a batch row (no byte where
+            # no layer is recurrent)
             self.ssm_state, self.conv_state = hybrid.state_zeros(
                 cfg, max_batch
             )
             pool_b += hybrid.state_layout_bytes(cfg, max_batch)
-            if self.device is not None:
-                # COMMITTED to the device from the start: a program's
-                # cache key holds whether each argument is, so the first
-                # fill (fresh, uncommitted zeros) and a later one of the
-                # same shape (a program's outputs) were two programs, and
-                # the second was built inside a benchmark's window
-                (self.k_pool, self.v_pool, self.ssm_state,
-                 self.conv_state) = jax.device_put(
-                    (self.k_pool, self.v_pool, self.ssm_state,
-                     self.conv_state),
-                    self.device,
+            if commit:
+                self.ssm_state, self.conv_state = jax.device_put(
+                    (self.ssm_state, self.conv_state), self.device
                 )
         self._led_kv_pool.set(pool_b)
         self._led_kv_scales.set(scale_b)
         self.kv_lengths = jnp.zeros((max_batch,), jnp.int32)
-        self._tables_np = np.zeros(
-            (max_batch, self.blocks_per_row), np.int32
-        )
-        self._tables = self._upload_tables()
-        self._tables_dirty = False
-        # host allocator: LIFO free stack + refcounts (shared prompt
-        # blocks); all decisions host-deterministic for SPMD lockstep
-        self._free_blocks = list(range(self.n_blocks - 1, -1, -1))
-        self._block_ref = np.zeros((self.n_blocks,), np.int32)
-        self._row_blocks: List[List[int]] = [[] for _ in range(max_batch)]
         self._filling: List[_Fill] = []
         self._preempted: List[_Row] = []
         # cross-request radix prefix cache: trie nodes hold refcounted
@@ -1224,14 +1198,8 @@ class ContinuousBatchingEngine:
                 capacity_blocks=int(
                     self._prefix_cache_capacity_frac * self.n_blocks
                 ),
-                acquire=(
-                    self._cache_acquire if self._windowed
-                    else self._incref_blocks
-                ),
-                release=(
-                    self._cache_release if self._windowed
-                    else self._free_block_list
-                ),
+                acquire=self._cache_acquire,
+                release=self._cache_release,
                 min_match_tokens=self._prefix_cache_min_tokens,
                 host_bytes_budget=host_bytes,
                 block_bytes=block_bytes,
@@ -1299,11 +1267,22 @@ class ContinuousBatchingEngine:
             self.n_blocks, 1
         )
 
-    def _copy_pool_blocks(self, src: np.ndarray, dst: np.ndarray):
-        """COW block copies (group tails, prefix-cache tail matches);
-        int8 pools carry the scale slices with the bytes."""
+    def _copy_pages(self, pool: PagePool, src: List[int], dst: List[int]):
+        """COW page copies inside ``pool``'s device arrays (group tails,
+        prefix-cache tail matches), in power-of-two counts; int8 pools
+        carry the scale slices with the bytes."""
+        n_pad = 1 << (len(src) - 1).bit_length()
+        s = np.zeros((n_pad,), np.int32)
+        d = np.full((n_pad,), pool.n_blocks, np.int32)  # pad -> drop
+        s[: len(src)], d[: len(dst)] = src, dst
+        s, d = jnp.asarray(s), jnp.asarray(d)
+        if pool is self._win:
+            self.win_k_pool, self.win_v_pool = paged.copy_blocks(
+                self.win_k_pool, self.win_v_pool, s, d
+            )
+            return
         out = paged.copy_blocks(
-            self.k_pool, self.v_pool, jnp.asarray(src), jnp.asarray(dst),
+            self.k_pool, self.v_pool, s, d,
             k_scale=self.k_scale, v_scale=self.v_scale,
         )
         if self._kv_quant:
@@ -1324,7 +1303,7 @@ class ContinuousBatchingEngine:
         if self.paged:
             bits = int(jnp.dtype(self.k_pool.dtype).itemsize) * 8
             held = (
-                self.n_blocks - len(self._free_blocks)
+                self.n_blocks - self._pages.free_blocks
                 if self._kv_quant
                 else 0
             )
@@ -1405,101 +1384,51 @@ class ContinuousBatchingEngine:
             return quantize.quantize_param_tree(params)
         return params
 
-    def _upload_tables(self) -> jax.Array:
-        """The host block table as a device array — through a COPY.  The
-        host table is mutated in place by the allocator, and a transfer
-        may alias (CPU backend) or still be reading (async H2D) the numpy
-        buffer it was given: dispatched-but-not-yet-run chunks then saw a
-        LATER table, and streams differed run to run under host load."""
-        return jnp.array(self._tables_np)
-
-    def _alloc_blocks(self, n: int) -> Optional[List[int]]:
-        if len(self._free_blocks) < n:
-            return None
-        out = [self._free_blocks.pop() for _ in range(n)]
-        for b in out:
-            self._block_ref[b] = 1
-        return out
-
-    def _incref_blocks(self, blocks: List[int]):
-        for b in blocks:
-            self._block_ref[b] += 1
-
-    def _free_block_list(self, blocks: List[int]):
-        for b in blocks:
-            self._block_ref[b] -= 1
-            assert self._block_ref[b] >= 0, f"double free of block {b}"
-            if self._block_ref[b] == 0:
-                self._free_blocks.append(b)
-
-    def _set_row_blocks(self, row_id: int, blocks: List[int]):
-        self._row_blocks[row_id] = blocks
-        t = self._tables_np[row_id]
-        t[:] = 0
-        t[: len(blocks)] = blocks
-        self._tables_dirty = True
-
     def _set_fill_row(self, row_id: int, fill: _Fill):
         """A fill's canonical pages live in its first target's tables
         (the same lists: what the fill releases behind itself, the row
         has released)."""
-        self._set_row_blocks(row_id, fill.blocks)
-        if self._win is not None:
-            self._win.set_row(row_id, fill.wblocks)
+        for pool, held in self._pages_of(fill):
+            pool.set_row(row_id, held)
+
+    def _pages_of(self, fill: _Fill):
+        """``fill``'s pages, pool by pool: (pool, its list)."""
+        return zip(self._pools, (fill.blocks, fill.wblocks))
 
     def _release_row(self, row_id: int):
-        """Single exit point for a row slot: frees its pool blocks."""
+        """Single exit point for a row slot: frees its pages."""
         self.rows[row_id] = None
-        if self.paged and self._row_blocks[row_id]:
-            self._free_block_list(self._row_blocks[row_id])
-            self._set_row_blocks(row_id, [])
-        if self._win is not None:
-            self._win.release_row(row_id)
+        for pool in self._pools:
+            pool.release_row(row_id)
 
-    def _refuse_page_transfer(self, feature: str):
-        """What moves a row's pages between servers assumes ONE table of
-        per-token blocks a row: refused by name for a recurrent state and
-        for window layers' second table."""
-        if self._stateful:
-            raise StatefulModelUnsupported(feature, self._cache_kinds())
-        if self._windowed:
-            raise NotImplementedError(
-                f"{feature} is not supported for a stack with window "
-                "layers: the window pool refuses it (its pages live in a "
-                "pool and a table of their own, engine/window_pages.py, "
-                "which it does not move)"
-            )
-
-    def _cache_kinds(self) -> str:
-        """The cache kinds this engine holds, for a refusal's message."""
-        kinds = ["recurrent state slots"] if self._stateful else []
-        if self._windowed:
-            kinds.append("a window pool")
-        kinds.append("a pool of whole-context pages")
-        return ", ".join(kinds)
-
-    # -- window layers' pages (engine/window_pages.py) ----------------------
+    # -- what the prefix cache holds (engine/kv_pages.py) -------------------
 
     def _cache_acquire(self, blocks: List[int]):
-        self._incref_blocks(blocks)
-        for b in blocks:
-            self._cache_refs[b] = self._cache_refs.get(b, 0) + 1
+        self._pages.incref(blocks)
+        if self._win is not None:
+            self._win.cache_hold(blocks)
 
     def _cache_release(self, blocks: List[int]):
-        for b in blocks:
-            self._cache_refs[b] -= 1
-            if self._cache_refs[b] == 0:
-                del self._cache_refs[b]
-                self._win.cache_drop(b)
-        self._free_block_list(blocks)
+        if self._win is not None:
+            self._win.cache_drop(blocks)
+        self._pages.free(blocks)
 
-    def _reclaim_one(self, keep_qids=(), preempt_but: Optional[int] = None):
-        """One step of reclamation for an allocation that failed: a
-        prefix-cache entry (pure recompute insurance), else the longest
-        parked row, else (``preempt_but`` given: the row that must stay,
-        or -1) the youngest decoding row.  Returns what went: "cache",
-        "parked", "preempted" or None."""
-        if self._prefix_cache is not None and self._prefix_cache.evict_one():
+    # -- allocation under pressure ------------------------------------------
+
+    def _reclaim_one(
+        self, keep_qids=(), preempt_but: Optional[int] = None,
+        cache_blocks: int = 1, protect_step: Optional[int] = None,
+    ):
+        """One step of reclamation for an allocation that failed:
+        ``cache_blocks`` prefix-cache entries (pure recompute insurance —
+        the cache always yields to live rows; with the host tier on,
+        "yield" means spill, not die), else the longest parked row, else
+        (``preempt_but`` given: the row that must stay, or -1) the
+        youngest decoding row.  Returns what went: "cache", "parked",
+        "preempted" or None."""
+        if self._prefix_cache is not None and self._prefix_cache.evict(
+            cache_blocks, protect_step=protect_step
+        ):
             return "cache"
         if self._evict_parked(keep_qids=keep_qids) is not None:
             return "parked"
@@ -1511,70 +1440,60 @@ class ContinuousBatchingEngine:
         self._preempt_row(victim)
         return "preempted"
 
-    def _alloc_window_blocks(
-        self, n: int, keep_qids=(), preempt_but: Optional[int] = None
+    def _alloc_reclaiming(
+        self, pool: PagePool, n: int, keep_qids=(),
+        preempt_but: Optional[int] = None,
+        protect_step: Optional[int] = None,
     ) -> Optional[List[int]]:
-        """``n`` window-layer pages, reclaiming as :meth:`_reclaim_one`."""
-        blocks = self._win.alloc(n)
+        """``n`` pages of ``pool``, reclaiming as :meth:`_reclaim_one`
+        until it has them; None when every tier the caller allows is
+        exhausted (it may then preempt or requeue).  ``protect_step``
+        spares cache nodes touched at that step — the swap-in path
+        allocates while the nodes it is restoring sit freshly matched."""
+        blocks = pool.alloc(n)
         while blocks is None:
-            if self._reclaim_one(keep_qids, preempt_but) is None:
+            # the whole-context pool asks the cache for its whole deficit,
+            # so that a host-tier round stays ONE batched gather; a cached
+            # block's pair in the window pool may already be gone, so that
+            # pool asks entry by entry
+            ask = n - pool.free_blocks if pool is self._pages else 1
+            went = self._reclaim_one(keep_qids, preempt_but, ask, protect_step)
+            if went is None:
                 return None
-            blocks = self._win.alloc(n)
+            blocks = pool.alloc(n)
         return blocks
 
-    def _copy_window_blocks(self, src: List[int], dst: List[int]):
-        """Tail-page copies in the window layers' pool (power-of-two
-        counts, as ``_copy_pool_blocks``' callers pad theirs)."""
-        n_pad = 1 << (len(src) - 1).bit_length()
-        s = np.zeros((n_pad,), np.int32)
-        d = np.full((n_pad,), self._win.n_blocks, np.int32)  # pad -> drop
-        s[: len(src)], d[: len(dst)] = src, dst
-        self.win_k_pool, self.win_v_pool = paged.copy_blocks(
-            self.win_k_pool, self.win_v_pool, jnp.asarray(s), jnp.asarray(d)
-        )
-
-    def _window_pages_of_fill(
-        self, m: PrefixMatch, held: List[int], n_blocks: int, keep_qids
-    ) -> Optional[List[int]]:
-        """The window layers' pages of a fill that reuses the cached
-        prefix ``m``, whose window-layer pages are ``held``
-        (``WindowPages.cached_tail``): the window's part of the prefix by
-        reference, a cached tail page by copy, the rest its own; None when
-        the pool cannot give them."""
-        held = list(held)
-        self._win.incref(held)  # before allocating: that may evict them
-        own = self._alloc_window_blocks(
-            n_blocks - len(m.blocks), keep_qids=keep_qids
-        )
-        if own is None:
-            self._win.free(held)
-            return None
-        if m.tail_block is not None:
-            self._copy_window_blocks([held[-1]], [own[0]])
-            self._win.free([held.pop()])  # copy taken: unpin
-        return held + own
-
-    def _window_args(self, win_tables) -> Dict[str, Any]:
+    def _window_args(self, win_tables=None) -> Dict[str, Any]:
         """What the stack's two programs take besides, where it has window
-        layers: their pools (donated) and ``win_tables``."""
+        layers: their pools (donated) and ``win_tables`` (the rows' own
+        where none is given)."""
         if self._win is None:
             return {}
+        if win_tables is None:
+            win_tables = self._win.upload()
         return dict(
             win_pools=(self.win_k_pool, self.win_v_pool),
             win_tables=jnp.asarray(win_tables),
         )
 
+    # -- counts -------------------------------------------------------------
+
+    def _live_rows(self):
+        """The rows that decode or fill.  Parked rows and what the prefix
+        cache holds are not live: ``free_pool_blocks`` counts both as
+        held."""
+        return (
+            row_id for row_id, row in enumerate(self.rows)
+            if row is not None and not row.parked
+        )
+
     @property
     def window_pages_live(self) -> int:
-        """Window-layer pages held by rows that decode or fill, each once
-        (0 for a stack without window layers)."""
+        """Window-layer pages held by live rows, each once (0 for a stack
+        without window layers)."""
         if self._win is None:
             return 0
-        live = set()
-        for row_id, row in enumerate(self.rows):
-            if row is not None and not row.parked:
-                live.update(b for b in self._win.rows[row_id] if b != GONE)
-        return len(live)
+        return self._win.live(self._live_rows())
 
     @property
     def window_pages_released(self) -> int:
@@ -1583,7 +1502,7 @@ class ContinuousBatchingEngine:
 
     @property
     def free_pool_blocks(self) -> int:
-        return len(self._free_blocks)
+        return self._pages.free_blocks
 
     @property
     def pages_total(self) -> int:
@@ -1592,20 +1511,12 @@ class ContinuousBatchingEngine:
 
     @property
     def pages_live(self) -> int:
-        """Pool blocks referenced by rows that are decoding or filling,
-        each block once however many siblings share it.  Parked rows and
-        what the prefix cache holds are not live: ``free_pool_blocks``
-        counts both as held."""
-        if not self.paged:
-            return 0
-        live = set()
-        for row_id, row in enumerate(self.rows):
-            if row is not None and not row.parked:
-                live.update(self._row_blocks[row_id])
-        return len(live)
+        """Pool blocks referenced by live rows, each block once however
+        many siblings share it (0 with the dense cache)."""
+        return self._pages.live(self._live_rows()) if self.paged else 0
 
-    #: the pages of the layers that attend the whole context: the engine's
-    #: own pool and table (all of a stack's pages where no layer has a window)
+    #: the pages of the layers that attend the whole context (all of a
+    #: stack's pages where no layer has a window)
     global_pages_live = pages_live
 
     @property
@@ -1614,31 +1525,25 @@ class ContinuousBatchingEngine:
         a model without such state; a slot is its row's)."""
         if not self._stateful:
             return 0
-        return sum(r is not None and not r.parked for r in self.rows)
+        return sum(1 for _ in self._live_rows())
 
-    def _alloc_blocks_reclaiming(
-        self, n: int, keep_qids=(), protect_step: Optional[int] = None
-    ) -> Optional[List[int]]:
-        """``_alloc_blocks`` with tiered reclamation: prefix-cache entries
-        first (pure recompute insurance — the cache always yields to live
-        rows; with the host tier on, "yield" means spill, not die), then
-        parked rows.  Returns None only when both tiers are exhausted
-        (the caller may then preempt or requeue).  ``protect_step``
-        spares cache nodes touched at that step — the swap-in path
-        allocates while the nodes it is restoring sit freshly matched."""
-        blocks = self._alloc_blocks(n)
-        while blocks is None:
-            deficit = n - len(self._free_blocks)
-            if self._prefix_cache is not None and self._prefix_cache.evict(
-                deficit, protect_step=protect_step
-            ):
-                pass
-            elif self._evict_parked(keep_qids=keep_qids) is not None:
-                pass
-            else:
-                return None
-            blocks = self._alloc_blocks(n)
-        return blocks
+    def _cache_counts(self) -> Dict[str, int]:
+        """What each cache kind the engine holds has live, of how much:
+        the counts of the ``areal.engine.ensure_blocks`` span."""
+        counts = dict(pages_live=self.pages_live, pages_total=self.pages_total)
+        if self._win is not None:
+            counts.update(
+                window_pages_live=self.window_pages_live,
+                window_pages_total=self._win.n_blocks,
+                window_pages_released=self.window_pages_released,
+                prefix_refused_window=self.prefix_refused_window,
+            )
+        if self._stateful:
+            counts.update(
+                state_slots_live=self.state_slots_live,
+                state_slots_total=self.max_batch,
+            )
+        return counts
 
     # -- cross-request prefix cache ----------------------------------------
 
@@ -1680,8 +1585,8 @@ class ContinuousBatchingEngine:
         False when the pool cannot provide the blocks — the caller falls
         back to the resident-only prefix."""
         n = len(nodes)
-        blocks = self._alloc_blocks_reclaiming(
-            n, keep_qids=keep_qids, protect_step=self._step_seq
+        blocks = self._alloc_reclaiming(
+            self._pages, n, keep_qids=keep_qids, protect_step=self._step_seq
         )
         if blocks is None:
             return False
@@ -1702,7 +1607,7 @@ class ContinuousBatchingEngine:
         window layers' pages of the same sequence; the cache holds those
         of the sequence's last window with the blocks it took, so that a
         request that reuses the whole prefix finds them
-        (``WindowPages.cached_tail``)."""
+        (``PagePool.cached_tail``)."""
         if self._prefix_cache is None or not seq or not blocks:
             return
         self._prefix_cache.insert(
@@ -1711,8 +1616,7 @@ class ContinuousBatchingEngine:
         if self._win is not None:
             n_pages = min(-(-len(seq) // self.page_size), len(blocks))
             for i in range(self._win.first_kept(len(seq)), n_pages):
-                if blocks[i] in self._cache_refs:
-                    self._win.cache_pair(blocks[i], wblocks[i])
+                self._win.cache_pair(blocks[i], wblocks[i])
 
     def _match_prefix(self, seq: List[int]) -> PrefixMatch:
         # record=False: a requeued admission re-matches every engine step
@@ -1754,9 +1658,6 @@ class ContinuousBatchingEngine:
             # reuse below the configured floor and count it as a hit
             if m.n_tokens < self._prefix_cache.min_match_tokens:
                 m = PrefixMatch()
-        # pin everything the match returned BEFORE allocating: the
-        # allocation may evict cache entries, and an unpinned matched
-        # block could be recycled into our own allocation
         pinned = list(m.blocks)
         if m.tail_block is not None:
             pinned.append(m.tail_block)
@@ -1768,20 +1669,23 @@ class ContinuousBatchingEngine:
                 # prefix: nothing of it can be skipped
                 self.prefix_refused_window += 1
                 m, pinned, wheld = PrefixMatch(), [], []
-        self._incref_blocks(pinned)
-        own_needed = n_blocks - len(m.blocks)
-        blocks = self._alloc_blocks_reclaiming(own_needed, keep_qids=keep_qids)
-        if blocks is None:
-            self._free_block_list(pinned)
-            return None
-        wblocks = None
-        if self._win is not None:
-            wblocks = self._window_pages_of_fill(
-                m, wheld, n_blocks, keep_qids
+        # pool by pool: what it holds of the prefix (by reference; a cached
+        # tail page last) and the rest of the fill's pages, its own.  All
+        # of the first is pinned BEFORE anything is allocated: an
+        # allocation in either pool may evict cache entries, and an
+        # unpinned matched page could be recycled into our own allocation
+        held, own = [pinned, wheld][: len(self._pools)], []
+        for pool, h in zip(self._pools, held):
+            pool.incref(h)
+        for pool in self._pools:
+            got = self._alloc_reclaiming(
+                pool, n_blocks - len(m.blocks), keep_qids=keep_qids
             )
-            if wblocks is None:
-                self._free_block_list(pinned + blocks)
+            if got is None:
+                for had, h, o in zip(self._pools, held, own + [[], []]):
+                    had.free(h + o)
                 return None
+            own.append(got)
         if self._prefix_cache is not None and len(seq) >= 2:
             self._prefix_cache.record(m)
         if self._stateful and any(
@@ -1794,10 +1698,10 @@ class ContinuousBatchingEngine:
             # COW: the partial tail's first tail_tokens are valid; copy
             # the whole block (append-only writes beyond that point are
             # the donor's garbage and our suffix fill overwrites them)
-            src = np.array([m.tail_block], np.int32)
-            dst = np.array([blocks[0]], np.int32)
-            self._copy_pool_blocks(src, dst)
-            self._free_block_list([m.tail_block])  # copy taken: unpin
+            for pool, h, o in zip(self._pools, held, own):
+                self._copy_pages(pool, [h[-1]], [o[0]])
+                pool.free([h.pop()])  # copy taken: unpin
+        pages = [h + o for h, o in zip(held, own)] + [None]
         key, reused = tuple(seq), None
         if self._keep_routed and m.n_tokens:
             # pages reused from the cache hold KV that an earlier fill of
@@ -1808,11 +1712,11 @@ class ContinuousBatchingEngine:
         return _Fill(
             key=key,
             tokens=list(seq),
-            blocks=list(m.blocks) + blocks,
+            blocks=pages[0],
             targets=[],
             fill_pos=m.n_tokens,
             routed_reused=reused,
-            wblocks=wblocks,
+            wblocks=pages[1],
         )
 
     def prefix_cache_stats(self) -> Dict[str, int]:
@@ -1835,13 +1739,13 @@ class ContinuousBatchingEngine:
         by a weight swap or TTL — the decode side re-prefills) or on a
         dense engine.  This is the prefill role's half of the
         P/D-disaggregated serving path."""
-        self._refuse_page_transfer("P/D handoff")
+        refuse("P/D handoff", self._kinds)
         if not self.paged:
             return None
         for row_id, row in enumerate(self.rows):
             if row is None or not row.parked or row.req.qid != qid:
                 continue
-            blocks = list(self._row_blocks[row_id])
+            blocks = list(self._pages.rows[row_id])
             if not blocks:
                 return None
             tik = time.perf_counter()
@@ -1900,7 +1804,7 @@ class ContinuousBatchingEngine:
         re-prefills under the current weights.  Layout mismatches
         (page size, kv dtype, context length) and pool/row exhaustion
         reject the same way.  Returns ``(ok, reason)``."""
-        self._refuse_page_transfer("P/D handoff")
+        refuse("P/D handoff", self._kinds)
         t0 = time.perf_counter()
         qid = unit.get("qid", "?")
         if not self.paged:
@@ -1946,14 +1850,14 @@ class ContinuousBatchingEngine:
             rid = self._evict_parked()  # unprotected last resort
         if rid is None:
             return self._reject_handoff(qid, "capacity")
-        blocks = self._alloc_blocks_reclaiming(n, keep_qids=queued)
+        blocks = self._alloc_reclaiming(self._pages, n, keep_qids=queued)
         if blocks is None:
             return self._reject_handoff(qid, "pool")
         payloads = [tuple(a[i] for a in payload) for i in range(n)]
         try:
             self._scatter_host_payloads(payloads, blocks)
         except Exception:  # noqa: BLE001 - free the blocks, fail closed
-            self._free_block_list(blocks)
+            self._pages.free(blocks)
             logger.exception("handoff import scatter failed for %s", qid)
             return self._reject_handoff(qid, "scatter")
         row = _Row(
@@ -1970,7 +1874,7 @@ class ContinuousBatchingEngine:
         self._epoch_counter += 1
         row.epoch = self._epoch_counter
         self.rows[rid] = row
-        self._set_row_blocks(rid, blocks)
+        self._pages.set_row(rid, blocks)
         # cached KV covers everything but the pending cur token
         n_kv = len(prompt) + len(generated) - 1
         self.kv_lengths = self.kv_lengths.at[
@@ -2108,7 +2012,7 @@ class ContinuousBatchingEngine:
             # no chunk boundary ever emitted (short prompt): the whole
             # handoff is this one final segment
             st = {"dest": dest, "seq": 0, "exported": 0}
-        row_blocks = self._row_blocks[rid]
+        row_blocks = self._pages.rows[rid]
         self._queue_handoff_segment(
             qid, st, row_blocks[st["exported"] :],
             total=len(row_blocks), final=True, row=row,
@@ -2164,7 +2068,7 @@ class ContinuousBatchingEngine:
         pend = self._handoff_pending.pop(qid, None)
         if pend is None:
             return
-        self._free_block_list(pend["blocks"])
+        self._pages.free(pend["blocks"])
         if reason:
             self._reject_handoff(qid, reason)
 
@@ -2185,7 +2089,7 @@ class ContinuousBatchingEngine:
         extend the monolithic set with ``stream`` | ``abort`` |
         ``expired`` (the TTL sweep for dead peers).  Stale or incomplete
         KV is never decoded."""
-        self._refuse_page_transfer("P/D handoff")
+        refuse("P/D handoff", self._kinds)
         t0 = time.perf_counter()
         qid = seg.get("qid", "?")
         if seg.get("abort"):
@@ -2220,7 +2124,9 @@ class ContinuousBatchingEngine:
                 return self._reject_handoff(qid, "layout")
             with self._lock:
                 queued = {r.qid for r in self._pending}
-            blocks = self._alloc_blocks_reclaiming(total, keep_qids=queued)
+            blocks = self._alloc_reclaiming(
+                self._pages, total, keep_qids=queued
+            )
             if blocks is None:
                 return self._reject_handoff(qid, "pool")
             pend = {
@@ -2333,7 +2239,7 @@ class ContinuousBatchingEngine:
         self._epoch_counter += 1
         row.epoch = self._epoch_counter
         self.rows[rid] = row
-        self._set_row_blocks(rid, blocks)
+        self._pages.set_row(rid, blocks)
         self.kv_lengths = self.kv_lengths.at[
             np.array([rid], np.int32)
         ].set(n_kv)
@@ -2405,7 +2311,7 @@ class ContinuousBatchingEngine:
         their spill payloads directly — the spill buffer already IS the
         wire format.  Returns ``[]`` when nothing exportable is cached
         (the puller re-prefills)."""
-        self._refuse_page_transfer("prefix pulls")
+        refuse("prefix pulls", self._kinds)
         if not self.paged or self._prefix_cache is None or len(tokens) < 2:
             return []
         entries = self._prefix_cache.export_walk(
@@ -2475,7 +2381,7 @@ class ContinuousBatchingEngine:
         if rec is not None:
             blocks = rec.get("blocks")
             if blocks:
-                self._free_block_list(blocks)
+                self._pages.free(blocks)
                 rec["blocks"] = []
             rec["state"] = "failed"
             rec["step"] = self._step_seq
@@ -2566,7 +2472,7 @@ class ContinuousBatchingEngine:
         final segment radix-inserts the pulled prefix — the cache takes
         its own references and the pull's are dropped, so ownership
         rules are identical to a locally-computed prefix."""
-        self._refuse_page_transfer("prefix pulls")
+        refuse("prefix pulls", self._kinds)
         t0 = time.perf_counter()
         qid = seg.get("qid", "?")
         if not self.paged:
@@ -2597,8 +2503,8 @@ class ContinuousBatchingEngine:
                 return self._reject_prefix_pull(qid, "layout")
             with self._lock:
                 queued = {r.qid for r in self._pending}
-            blocks = self._alloc_blocks_reclaiming(
-                total, keep_qids=queued
+            blocks = self._alloc_reclaiming(
+                self._pages, total, keep_qids=queued
             )
             if blocks is None:
                 return self._reject_prefix_pull(qid, "pool")
@@ -2656,7 +2562,7 @@ class ContinuousBatchingEngine:
         # zero-leak invariant holds even if a raced flush drops the
         # insert (refs then hit zero and the blocks recycle)
         self._cache_insert(key, blocks)
-        self._free_block_list(blocks)
+        self._pages.free(blocks)
         rec["state"] = "done"
         rec["step"] = self._step_seq
         self.prefix_peer_pulls_total += 1
@@ -2682,12 +2588,12 @@ class ContinuousBatchingEngine:
     # -- client API (any thread) -------------------------------------------
 
     def submit(self, req: model_api.APIGenerateInput) -> str:
-        if self._stateful or self._windowed:
+        if self._kinds:
             meta = req.metadata or {}
             if meta.get("handoff_to"):
-                self._refuse_page_transfer("P/D handoff")
+                refuse("P/D handoff", self._kinds)
             if meta.get("kv_source"):
-                self._refuse_page_transfer("prefix pulls")
+                refuse("prefix pulls", self._kinds)
         with self._lock:
             self._pending.append(req)
             ev = threading.Event()
@@ -3312,7 +3218,7 @@ class ContinuousBatchingEngine:
                     self._requeue_row(rid, self.rows[rid])
                 for f in self._filling:
                     gone = [i for i, b in enumerate(f.wblocks) if b == GONE]
-                    again = self._alloc_window_blocks(len(gone))
+                    again = self._alloc_reclaiming(self._win, len(gone))
                     if again is None:
                         raise RuntimeError(
                             "window pool too small to restart a fill of "
@@ -3579,10 +3485,11 @@ class ContinuousBatchingEngine:
                 toks[i, :take] = f.tokens[f.fill_pos : f.fill_pos + take]
                 starts[i] = f.fill_pos
                 cls[i] = take
-                tables[i, : len(f.blocks)] = f.blocks
                 slots[i] = f.state_slot
-                if self._win is not None:
-                    self._win.table_of(f.wblocks, wtables[i])
+                for (pool, held), t in zip(
+                    self._pages_of(f), (tables, wtables)
+                ):
+                    pool.table_of(held, t[i])
             if self._by_kind:
                 win = self._window_args(wtables)
                 (logits, self.k_pool, self.v_pool, self.ssm_state,
@@ -3670,7 +3577,7 @@ class ContinuousBatchingEngine:
         weights), which is scatter-deterministic."""
         fills = [
             _Fill(
-                key=(), tokens=seq, blocks=self._row_blocks[rid], targets=[],
+                key=(), tokens=seq, blocks=self._pages.rows[rid], targets=[],
                 state_slot=rid,
             )
             for rid, seq in entries
@@ -3770,8 +3677,7 @@ class ContinuousBatchingEngine:
         """The part of ``_distribute_fills`` before the fetch.  Returns
         (fresh targets to sample for, rows to activate as they are, the
         sampled tokens and log-probabilities still on the device)."""
-        copy_src, copy_dst = [], []
-        wcopy_src, wcopy_dst = [], []
+        copies = [([], []) for _ in self._pools]  # (from, to) a pool
         state_src, state_dst = [], []
         sample_targets: List[Tuple[_Fill, _FillTarget, int]] = []
         activation: List[Tuple[int, int, int, int]] = []  # rid,cur,budget,len
@@ -3788,45 +3694,26 @@ class ContinuousBatchingEngine:
                     # own slot, for a sibling that shares the fill
                     state_src.append(f.state_slot)
                     state_dst.append(tgt.row_id)
-                if t_i == 0:
-                    self._set_row_blocks(tgt.row_id, list(f.blocks))
-                    if self._win is not None:
-                        self._win.set_row(tgt.row_id, list(f.wblocks))
-                else:
-                    shared = f.blocks[:n_full]
-                    self._incref_blocks(shared)
-                    own = list(shared)
+                for (pool, held), (src, dst) in zip(self._pages_of(f), copies):
+                    if t_i == 0:
+                        pool.set_row(tgt.row_id, list(held))
+                        continue
+                    # the prompt's full pages by reference (in a window
+                    # pool those of its last window: what lies before is
+                    # GONE), the tail by copy
+                    own = list(held[:n_full])
+                    pool.incref(own)
                     if has_tail:
-                        tail = self._alloc_blocks(1)
-                        while tail is None:
-                            if self._reclaim_one(preempt_but=-1) is None:
-                                raise RuntimeError(
-                                    "pool exhausted distributing a "
-                                    "group fill"
-                                )
-                            tail = self._alloc_blocks(1)
-                        copy_src.append(f.blocks[n_full])
-                        copy_dst.append(tail[0])
-                        own += tail
-                    self._set_row_blocks(tgt.row_id, own)
-                    if self._win is not None:
-                        # the window's part of the prompt by reference
-                        # (what lies before it is GONE), the tail by copy
-                        wown = list(f.wblocks[:n_full])
-                        self._win.incref(wown)
-                        if has_tail:
-                            wtail = self._alloc_window_blocks(
-                                1, preempt_but=-1
+                        tail = self._alloc_reclaiming(pool, 1, preempt_but=-1)
+                        if tail is None:
+                            raise RuntimeError(
+                                ("window pool" if pool.window else "pool")
+                                + " exhausted distributing a group fill"
                             )
-                            if wtail is None:
-                                raise RuntimeError(
-                                    "window pool exhausted distributing "
-                                    "a group fill"
-                                )
-                            wcopy_src.append(f.wblocks[n_full])
-                            wcopy_dst.append(wtail[0])
-                            wown += wtail
-                        self._win.set_row(tgt.row_id, wown)
+                        src.append(held[n_full])
+                        dst.append(tail[0])
+                        own += tail
+                    pool.set_row(tgt.row_id, own)
                 if tgt.resume is not None:
                     row = tgt.resume
                     if self._slo_enabled and row.t_preempt:
@@ -3845,15 +3732,9 @@ class ContinuousBatchingEngine:
                     )
                 else:
                     sample_targets.append((f, tgt, li))
-        if copy_src:
-            n_pad = 1 << (len(copy_src) - 1).bit_length()
-            src = np.zeros((n_pad,), np.int32)
-            dst = np.full((n_pad,), self.n_blocks, np.int32)  # pad -> drop
-            src[: len(copy_src)] = copy_src
-            dst[: len(copy_dst)] = copy_dst
-            self._copy_pool_blocks(src, dst)
-        if wcopy_src:
-            self._copy_window_blocks(wcopy_src, wcopy_dst)
+        for pool, (src, dst) in zip(self._pools, copies):
+            if src:
+                self._copy_pages(pool, src, dst)
         if state_src:
             n_pad = 1 << (len(state_src) - 1).bit_length()
             src = np.zeros((n_pad,), np.int32)
@@ -4148,7 +4029,8 @@ class ContinuousBatchingEngine:
         for ch in self._ring:
             for rid, _ in ch.snapshot:
                 pend_counts[rid] = pend_counts.get(rid, 0) + 1
-        allocated, preempted0 = 0, self.preempted_total
+        allocated0 = self._pages.allocated_total
+        preempted0 = self.preempted_total
         for row_id in range(self.max_batch):
             row = self.rows[row_id]
             if row is None or row.parked or row.filling:
@@ -4157,28 +4039,19 @@ class ContinuousBatchingEngine:
             host_len = len(row.prompt) + len(row.generated) + 1 + n_pend * W
             need = -(-(host_len + W) // self.page_size)
             need = min(need, self.blocks_per_row)
-            # the global layers' table, then the window layers'
-            tables = [self._row_blocks] + (
-                [self._win.rows] if self._win is not None else []
-            )
             while True:
-                short = [need - len(t[row_id]) for t in tables]
-                if max(short) <= 0:
+                # the first pool whose table is short of it (the
+                # whole-context pages', then the window layers')
+                pool = next(
+                    (p for p in self._pools if len(p.rows[row_id]) < need),
+                    None,
+                )
+                if pool is None:
                     break
-                if short[0] > 0:
-                    blocks = self._alloc_blocks(short[0])
-                    if blocks is not None:
-                        self._set_row_blocks(
-                            row_id, self._row_blocks[row_id] + blocks
-                        )
-                        allocated += short[0]
-                        continue
-                else:
-                    blocks = self._win.alloc(short[1])
-                    if blocks is not None:
-                        self._win.rows[row_id].extend(blocks)
-                        self._win.sync_row(row_id)
-                        continue
+                blocks = pool.alloc(need - len(pool.rows[row_id]))
+                if blocks is not None:
+                    pool.extend_row(row_id, blocks)
+                    continue
                 # reclamation tiers: prefix-cache entries (recompute
                 # insurance only — always yield to a live row), then
                 # parked rows, then preemption
@@ -4219,7 +4092,10 @@ class ContinuousBatchingEngine:
                 self._win.row_pages_max = max(
                     self._win.row_pages_max, self._win.held(wrow)
                 )
-        return allocated, self.preempted_total - preempted0
+        return (
+            self._pages.allocated_total - allocated0,
+            self.preempted_total - preempted0,
+        )
 
     def _row_priority(self, row: _Row) -> str:
         """The admission plane's priority class, stamped into request
@@ -4345,21 +4221,16 @@ class ContinuousBatchingEngine:
             self._dispatch_paged(snapshot)
 
     def _dispatch_paged(self, snapshot):
-        if self._tables_dirty:
-            self._tables = self._upload_tables()
-            self._tables_dirty = False
+        tables = self._pages.upload()
         if self._by_kind:
-            if self._win is not None and self._win.dirty:
-                self._win_tables = jnp.array(self._win.tables_np)
-                self._win.dirty = False
-            win = self._window_args(self._win_tables)
+            win = self._window_args()
             (
                 self.k_pool, self.v_pool, self.ssm_state, self.conv_state,
                 self.kv_lengths, out_t, out_l, emitted, self.cur_tokens,
                 self.active, self.budgets, _, pairs, routed, *win_out,
             ) = hybrid.hybrid_decode_chunk(
                 self.params, self.k_pool, self.v_pool, self.ssm_state,
-                self.conv_state, self.cfg, self._tables, self.kv_lengths,
+                self.conv_state, self.cfg, tables, self.kv_lengths,
                 self.cur_tokens, self.active, self.budgets,
                 self._sample_base_rng, self.chunk_size,
                 self._paged_sample_fn, self._paged_stop_fn,
@@ -4382,7 +4253,7 @@ class ContinuousBatchingEngine:
             self.k_pool,
             self.v_pool,
             self.cfg,
-            self._tables,
+            tables,
             self.kv_lengths,
             self.cur_tokens,
             self.active,
@@ -4531,15 +4402,13 @@ class ContinuousBatchingEngine:
             self.tracer.span_begin(
                 qid, "decode.verify", row=rid, drafted=len(d)
             )
-        if self._tables_dirty:
-            self._tables = self._upload_tables()
-            self._tables_dirty = False
+        tables = self._pages.upload()
         out = spec_decode.paged_verify_chunk(
             self.params,
             self.k_pool,
             self.v_pool,
             self.cfg,
-            self._tables,
+            tables,
             self.kv_lengths,
             self.cur_tokens,
             jnp.asarray(draft_arr),
@@ -4732,7 +4601,7 @@ class ContinuousBatchingEngine:
                 # LAST window
                 wrow = self._win.rows[row_id]
                 self._win.release_behind(wrow, len(cached), row_id)
-            self._cache_insert(cached, self._row_blocks[row_id], wrow)
+            self._cache_insert(cached, self._pages.rows[row_id], wrow)
         if started and park:
             # keep KV resident; the last generated token is the pending
             # cur_token (its KV was never written — see decode_chunk)
@@ -5081,31 +4950,7 @@ class ContinuousBatchingEngine:
                             sp.set_metadata(
                                 blocks_allocated=allocated,
                                 rows_preempted=preempted,
-                                pages_live=self.pages_live,
-                                pages_total=self.pages_total,
-                                **(
-                                    {
-                                        "window_pages_live":
-                                            self.window_pages_live,
-                                        "window_pages_total":
-                                            self._win.n_blocks,
-                                        "window_pages_released":
-                                            self.window_pages_released,
-                                        "prefix_refused_window":
-                                            self.prefix_refused_window,
-                                    }
-                                    if self._win is not None
-                                    else {}
-                                ),
-                                **(
-                                    {
-                                        "state_slots_live":
-                                            self.state_slots_live,
-                                        "state_slots_total": self.max_batch,
-                                    }
-                                    if self._stateful
-                                    else {}
-                                ),
+                                **self._cache_counts(),
                             )
                     dispatched = False
                     if (

@@ -27,7 +27,7 @@ Design constraints, in order:
   rows along one trie path is exact, not approximate.
 * **The cache owns references, never blocks.**  It speaks to the
   engine's allocator through two callbacks (``acquire``/``release`` =
-  the engine's ``_incref_blocks``/``_free_block_list``); eviction only
+  ``incref``/``free`` of the engine's ``kv_pages.PagePool``); eviction only
   drops the cache's OWN reference, so a prefix pinned by a live row can
   never be yanked from under it — the pool recycles a block only when
   every holder is gone.
@@ -779,11 +779,6 @@ class RadixPrefixCache:
                 self.blocks_held - self.capacity_blocks,
                 protect_step=int(ready_step) - 1,
             )
-
-    def evict_one(self, protect_step: Optional[int] = None) -> bool:
-        """Drop the single LRU cached unit; False when nothing is
-        evictable."""
-        return self.evict(1, protect_step=protect_step) == 1
 
     def flush(self, new_version: Optional[int] = None):
         """Drop every entry IN BOTH TIERS (weight swap: all cached KV —

@@ -62,7 +62,7 @@ def assert_pool_pristine(eng):
     if getattr(eng, "_prefix_cache", None) is not None:
         eng._prefix_cache.flush()
     assert eng.free_pool_blocks == eng.n_blocks
-    assert (np.asarray(eng._block_ref) == 0).all()
+    assert (np.asarray(eng._pages._ref) == 0).all()
 
 
 def test_stream_delivers_every_token_exactly_once():

@@ -98,12 +98,10 @@ def _fill_some_blocks(eng, seed=0, max_new=8):
 def test_cow_copy_preserves_scales():
     eng, *_ = make_engine(kv_cache_dtype="int8")
     _fill_some_blocks(eng)
-    used = [b for b in range(eng.n_blocks) if eng._block_ref[b] > 0]
-    free = [b for b in range(eng.n_blocks) if eng._block_ref[b] == 0]
+    used = [b for b in range(eng.n_blocks) if eng._pages._ref[b] > 0]
+    free = [b for b in range(eng.n_blocks) if eng._pages._ref[b] == 0]
     src, dst = used[0], free[0]
-    eng._copy_pool_blocks(
-        np.array([src], np.int32), np.array([dst], np.int32)
-    )
+    eng._copy_pages(eng._pages, [src], [dst])
     for pool in (eng.k_pool, eng.v_pool, eng.k_scale, eng.v_scale):
         np.testing.assert_array_equal(
             np.asarray(pool[:, dst]), np.asarray(pool[:, src])
@@ -133,7 +131,7 @@ def test_spill_restore_bit_identity_of_int8_blocks():
     eng.step()
     eng.step()  # TTL-release the parked row; cache refs remain
     cache = eng._prefix_cache
-    held = [b for b in range(eng.n_blocks) if eng._block_ref[b] > 0]
+    held = [b for b in range(eng.n_blocks) if eng._pages._ref[b] > 0]
     assert held, "prompt KV should be cache-resident"
     # snapshot the cached blocks' device contents, then force a spill
     before = {
@@ -347,7 +345,7 @@ def test_int8_spilled_prefix_swap_in_smoke():
     eng._prefix_cache.flush()
     st = eng.prefix_cache_stats()
     assert eng.free_pool_blocks == eng.n_blocks
-    assert (np.asarray(eng._block_ref) == 0).all()
+    assert (np.asarray(eng._pages._ref) == 0).all()
     assert st["host_bytes_held"] == 0 and st["host_blocks_held"] == 0
 
 

@@ -94,7 +94,7 @@ def test_siblings_of_one_fill_share_its_pages_and_copy_its_tail(model):
     # ONE prefill of the prompt; its full page is the SAME block in the
     # three rows, its tail page a copy of their own each
     assert eng.prefill_tokens_total == 13
-    rows = [eng._row_blocks[i] for i in range(4) if eng._row_blocks[i]]
+    rows = [eng._pages.rows[i] for i in range(4) if eng._pages.rows[i]]
     assert len(rows) == 3 and len({r[0] for r in rows}) == 1
     assert len({r[1] for r in rows}) == 3
     run_until_done(eng)

@@ -71,7 +71,7 @@ def test_all_blocks_freed_after_drain():
     # leaks across a full admit/park/evict cycle
     eng._prefix_cache.flush()
     assert eng.free_pool_blocks == eng.n_blocks
-    assert (np.asarray(eng._block_ref) == 0).all()
+    assert (np.asarray(eng._pages._ref) == 0).all()
 
 
 def test_all_blocks_freed_after_drain_cache_off():
@@ -86,7 +86,7 @@ def test_all_blocks_freed_after_drain_cache_off():
     eng.step()
     assert eng.n_parked == 0
     assert eng.free_pool_blocks == eng.n_blocks
-    assert (np.asarray(eng._block_ref) == 0).all()
+    assert (np.asarray(eng._pages._ref) == 0).all()
 
 
 def test_group_sharing_uses_fewer_blocks():

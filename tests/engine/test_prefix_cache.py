@@ -116,13 +116,13 @@ def test_lru_eviction_is_deterministic_and_leaf_first():
     c.insert([5, 6], [12], step=2, version=0)
     # touch the deep chain so the lone (5,6) leaf is oldest
     c.match([1, 2, 3, 4, 9], step=3)
-    assert c.evict_one() is True
+    assert c.evict(1) == 1
     assert a.refs[12] == 0  # LRU leaf went first
     # the chain evicts leaf-first (11 before 10): interior nodes must
     # not orphan their children
-    assert c.evict_one() is True and a.refs[11] == 0 and a.refs[10] == 1
-    assert c.evict_one() is True and a.refs[10] == 0
-    assert c.evict_one() is False  # empty
+    assert c.evict(1) == 1 and a.refs[11] == 0 and a.refs[10] == 1
+    assert c.evict(1) == 1 and a.refs[10] == 0
+    assert c.evict(1) == 0  # empty
 
 
 def test_concurrent_subpage_sessions_keep_distinct_tails():
@@ -295,15 +295,15 @@ def test_evicting_pinned_prefix_is_impossible():
     eng.step()
     assert eng.prefix_cache_stats()["hits_total"] >= 1
     pinned = [
-        b for r in range(eng.max_batch) for b in eng._row_blocks[r]
+        b for r in range(eng.max_batch) for b in eng._pages.rows[r]
     ]
-    while eng._prefix_cache.evict_one():
+    while eng._prefix_cache.evict(1):
         pass
     assert eng.prefix_cache_stats()["blocks_held"] == 0
     # the live row's blocks survived every eviction
     for b in pinned:
-        assert eng._block_ref[b] >= 1
-        assert b not in eng._free_blocks
+        assert eng._pages._ref[b] >= 1
+        assert b not in eng._pages._free
     run_until_done(eng)
     got = eng.wait_result("b", timeout=10)
 
@@ -342,7 +342,7 @@ def test_pool_pressure_evicts_cache_before_live_rows_and_never_leaks():
     eng.step()  # TTL-evict parked rows
     eng._prefix_cache.flush()
     assert eng.free_pool_blocks == eng.n_blocks
-    assert (np.asarray(eng._block_ref) == 0).all()
+    assert (np.asarray(eng._pages._ref) == 0).all()
 
 
 def test_weight_swap_invalidates_cache():

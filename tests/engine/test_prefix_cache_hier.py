@@ -238,7 +238,7 @@ def test_spill_restore_replay_parity_and_no_leak():
     eng._prefix_cache.flush()
     st = eng.prefix_cache_stats()
     assert eng.free_pool_blocks == eng.n_blocks
-    assert (np.asarray(eng._block_ref) == 0).all()
+    assert (np.asarray(eng._pages._ref) == 0).all()
     assert st["host_bytes_held"] == 0 and st["host_blocks_held"] == 0
 
 

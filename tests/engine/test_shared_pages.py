@@ -66,7 +66,7 @@ def assert_nothing_leaked(eng):
 def test_the_engine_holds_three_cache_kinds_and_one_written_pool_layer(model):
     cfg, _ = model
     eng = make_engine(model)
-    assert eng._stateful and eng._windowed and eng._by_kind
+    assert eng._stateful and eng._win is not None and eng._by_kind
     # ONE layer of whole-context pages for the four layers that read it
     # (layer 7 and the cross layers 9, 11 here), a pair's heads as one
     assert eng.k_pool.shape == (1, eng.n_blocks, 2, BS, 8)

@@ -239,7 +239,7 @@ def test_no_block_leak_after_rejected_drafts(monkeypatch):
     if eng._prefix_cache is not None:
         eng._prefix_cache.flush(new_version=99)
     assert eng.free_pool_blocks == eng.n_blocks
-    assert (np.asarray(eng._block_ref) == 0).all()
+    assert (np.asarray(eng._pages._ref) == 0).all()
 
 
 def test_rejected_drafts_never_poison_the_prefix_cache(monkeypatch):

@@ -1,5 +1,5 @@
 """The engine over a stack of window and global attention layers: two pools
-with a table each and a page rule per layer kind (engine/window_pages.py).
+with a table each and a page rule per layer kind (engine/kv_pages.py).
 A window layer's page goes once every holder's window has passed it; a
 global layer's page lives as long as its row.  Every sequence the engine
 completes has the log-probabilities of the benchmark's plain reference
@@ -14,7 +14,7 @@ import pytest
 from areal_tpu.api.model_api import APIGenerateInput, GenerationHyperparameters
 from areal_tpu.engine.inference_server import ContinuousBatchingEngine
 from areal_tpu.engine.sampling import SamplingParams
-from areal_tpu.engine.window_pages import GONE, WindowPages
+from areal_tpu.engine.kv_pages import GONE
 from areal_tpu.models import hybrid, moe
 from benchmark.lib import reference_smallthinker as ref
 from tests.model.test_window import HF, WINDOW, make_cfg
@@ -145,32 +145,9 @@ def assert_nothing_leaked(eng):
             eng._release_row(row_id)
     if eng._prefix_cache is not None:
         eng._prefix_cache.flush()
-    assert eng._win.cached == {} and eng._cache_refs == {}
+    assert eng._win.cached == {} and eng._win.cache_refs == {}
     assert eng._win.free_blocks == eng._win.n_blocks
     assert eng.free_pool_blocks == eng.n_blocks
-
-
-def test_the_allocator_lets_a_shared_page_go_with_its_last_holder():
-    win = WindowPages(n_blocks=6, page_size=8, window=12, max_batch=2, blocks_per_row=6)
-    a = win.alloc(4)
-    b = list(a[:3]) + win.alloc(1)  # a sibling: three pages shared, a tail of its own
-    win.incref(b[:3])
-    win.set_row(0, a)
-    win.set_row(1, b)
-    assert win.free_blocks == 1
-    # at 29 cached tokens a holder keeps [17, 29): pages 2 on (17 // 8)
-    assert (win.first_read(29), win.first_kept(29)) == (2, 2)
-    assert (win.first_read(28), win.first_kept(28)) == (2, 2)
-    assert (win.first_read(27), win.first_kept(27)) == (2, 1)
-    assert win.release_behind(a, 29) == 2 and a[:2] == [GONE, GONE]
-    assert win.free_blocks == 1  # the sibling still holds them
-    assert win.release_behind(b, 21) == 1 and win.free_blocks == 2  # its last holder
-    assert win.release_behind(b, 29) == 1 and win.free_blocks == 3
-    win.sync_row(0)
-    assert list(win.tables_np[0][:4]) == [0, 0, a[2], a[3]]
-    win.release_row(0)
-    win.release_row(1)
-    assert win.free_blocks == 6 and win.released_total == 4
 
 
 def test_a_long_prompt_fills_under_the_rule_and_siblings_share_its_window(model):
@@ -192,7 +169,7 @@ def test_a_long_prompt_fills_under_the_rule_and_siblings_share_its_window(model)
     assert len(rows) == 3
     assert all(r[:3] == [GONE] * 3 for r in rows)
     assert len({r[3] for r in rows}) == 1 and len({r[4] for r in rows}) == 3
-    assert all(len(b) >= 5 and GONE not in b for b in eng._row_blocks if b)
+    assert all(len(b) >= 5 and GONE not in b for b in eng._pages.rows if b)
     assert eng.window_pages_released == 3
     # (the shared page, three tails, and a page each for the next chunk)
     assert eng.window_pages_live == 1 + 3 + 3
@@ -244,6 +221,55 @@ def test_a_late_sibling_reuses_the_prefix_while_its_window_tail_is_held(model):
     out = eng.drain_results()
     assert sorted(out) == ["a0", "a1", "b0"]
     assert_reference(model[1], out, eng)
+    assert_nothing_leaked(eng)
+
+
+def test_a_cached_window_page_stays_pinned_while_its_fill_allocates(model):
+    """A fill that reuses a cached prefix pins its pages in BOTH pools
+    before it allocates in either: the whole-context pool's allocation
+    evicts cache entries, this very prefix's tail among them, and the
+    window-layer page cached with it must not go back to the free stack
+    (and out again as one of the fill's own) under the fill."""
+    eng = make_engine(model, max_batch=2)
+    p, c = _prompts(3, 21, 17)
+    for qid, prompt, n in (("c", c, 3), ("a", p, 6)):
+        eng.submit(_req(qid, prompt, n))
+        run_until_done(eng)
+    out = eng.drain_results()
+    while eng._evict_parked() is not None:
+        pass  # the cache alone holds the two sequences' pages now
+    taken = eng._pages.alloc(eng.free_pool_blocks)  # other holders, say
+    seq = list(out["a"].prompt_ids) + list(out["a"].output_ids)
+    new_fill, starts = eng._new_fill, []
+
+    def checked(*a, **kw):
+        fill = new_fill(*a, **kw)
+        starts.append(fill.fill_pos)
+        for pool, held in eng._pages_of(fill):
+            held = [b for b in held if b != GONE]
+            assert len(set(held)) == len(held) and not set(held) & set(pool._free)
+            assert all(pool._ref[b] >= 1 for b in held)
+        return fill
+
+    eng._new_fill = checked
+    eng.submit(_req("b", seq + _prompts(4, 9)[0], 5))
+    with jax.default_matmul_precision("highest"):
+        eng.step()
+    # 26 tokens reused; to make room the cache let go of the other
+    # sequence's two pages and of this one's own tail entry
+    assert starts == [26]
+    assert eng.prefix_cache_stats()["evictions_total"] == 3
+    eng._pages.free(taken)
+    run_until_done(eng)
+    assert_reference(model[1], out, eng)
+    # (nobody kept the routing behind the 26 reused tokens, which were
+    # another request's prompt AND output: the reference routes for itself)
+    b = eng.drain_results()["b"]
+    want, _, _ = ref.sequence_logps(
+        ref.make_token_logps(HF), model[1],
+        list(b.prompt_ids) + list(b.output_ids), pad_to=32,
+    )
+    assert np.abs(np.asarray(b.output_logprobs) - want[-5:]).max() < 2e-5
     assert_nothing_leaked(eng)
 
 

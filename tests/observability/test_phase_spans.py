@@ -206,11 +206,20 @@ def test_engine_spans_in_a_cpu_profile(tmp_path):
             assert m[2] == "areal.phase.end" and m[3]["of"] == begin[3]["of"]
             closed.append((begin, m))
     assert not open_
+    longer = []
     for (s, e, name, _), (begin, end) in zip(
         sorted(events), sorted(closed)
     ):
         assert begin[3]["of"] == name and begin[1] <= s and e <= end[0]
-        assert end[3]["seconds"] == pytest.approx((e - s) * 1e-9, abs=2e-4)
+        longer.append((e - s) * 1e-9 - end[3]["seconds"])
+    # ... and how long it took.  The clock's two readings lie INSIDE the
+    # span's, a few instructions from them (1-15 us on a quiet machine), so
+    # the span is never the shorter; it is the longer by what the machine
+    # took the thread away for in between, which is nothing in most spans
+    # and was 0.5 ms in one span of the driver's six-worker run and 0.4 and
+    # 2.8 ms in one of fifty with the machine three times oversubscribed
+    assert min(longer) > -2e-4
+    assert sorted(longer)[len(longer) * 9 // 10] < 2e-4
 
 
 @pytest.mark.filterwarnings("ignore::DeprecationWarning")

@@ -82,7 +82,7 @@ def _assert_pristine(eng):
     if eng._prefix_cache is not None:
         eng._prefix_cache.flush()
     assert eng.free_pool_blocks == eng.n_blocks
-    assert (np.asarray(eng._block_ref) == 0).all()
+    assert (np.asarray(eng._pages._ref) == 0).all()
 
 
 def _fabric_pair(params, **target_kw):

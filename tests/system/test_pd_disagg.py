@@ -440,7 +440,7 @@ def test_handoff_payload_bit_identical_through_import():
         if r is not None and r.req.qid == "pd4"
     )
     back = paged.gather_blocks_host(
-        D.k_pool, D.v_pool, D._row_blocks[rid],
+        D.k_pool, D.v_pool, D._pages.rows[rid],
         k_scale=D.k_scale, v_scale=D.v_scale,
     )
     for a, b in zip(unit["payload"], back):
@@ -663,7 +663,7 @@ def test_streamed_handoff_int8_segmented_bit_identity():
         if r is not None and r.req.qid == "si0"
     )
     back = paged.gather_blocks_host(
-        D.k_pool, D.v_pool, D._row_blocks[rid],
+        D.k_pool, D.v_pool, D._pages.rows[rid],
         k_scale=D.k_scale, v_scale=D.v_scale,
     )
     data_segs = [
