@@ -128,6 +128,7 @@ def make_model(
             backend_name = (
                 "deepseek_v3" if model_cfg.is_latent
                 else "phi4flash" if model_cfg.is_mamba1
+                else "falcon_h1" if model_cfg.n_parallel_layers
                 else "smallthinker" if model_cfg.n_window_layers
                 else "granitemoehybrid"
             )

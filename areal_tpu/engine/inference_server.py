@@ -1527,6 +1527,11 @@ class ContinuousBatchingEngine:
             return 0
         return sum(1 for _ in self._live_rows())
 
+    @property
+    def state_slots_total(self) -> int:
+        """Recurrent-state slots the engine holds: one a batch row."""
+        return self.max_batch if self._stateful else 0
+
     def _cache_counts(self) -> Dict[str, int]:
         """What each cache kind the engine holds has live, of how much:
         the counts of the ``areal.engine.ensure_blocks`` span."""
@@ -1541,7 +1546,7 @@ class ContinuousBatchingEngine:
         if self._stateful:
             counts.update(
                 state_slots_live=self.state_slots_live,
-                state_slots_total=self.max_batch,
+                state_slots_total=self.state_slots_total,
             )
         return counts
 
@@ -4200,6 +4205,14 @@ class ContinuousBatchingEngine:
             counts["window_tokens_sum"] = sum(
                 min(c, self.cfg.sliding_window) for c in ctx
             )
+        if self._stateful:
+            # (row, state layer) pairs each of the chunk's steps updates:
+            # a state read and written for each
+            counts["state_rows"] = len(snapshot) * self.cfg.n_mamba_layers
+        if self._by_kind and self.cfg.n_parallel_layers:
+            # layers that hold pages AND a state slot (both caches are
+            # read in one layer: ctx_tokens_sum and state_rows count them)
+            counts["parallel_layers"] = self.cfg.n_parallel_layers
         if self._by_kind and self.cfg.n_cross_layers:
             # layers that read the pool of whole-context pages each step
             # (ctx_tokens_sum times this is what a step reads of it)

@@ -16,6 +16,7 @@ from typing import Optional, Tuple
 #: the kinds a layer of a stack stated by kind can be (``layer_types``)
 LAYER_KINDS = (
     "attention", "window", "mamba", "latent", "mamba1", "gmu", "cross",
+    "parallel",
 )
 
 
@@ -87,7 +88,11 @@ class TransformerConfig:
     # of ``kv_shared_layer``, the one "attention" layer before it, from
     # that layer's pages.  A "gmu" layer gates ``memory_layer``'s scan
     # output (the last "mamba1" layer's, before ITS gate) by a projection
-    # of its own input, and caches nothing
+    # of its own input, and caches nothing.  A "parallel" layer runs an
+    # attention mixer AND a Mamba-2 mixer side by side on ONE normed input
+    # and adds both to the residual stream (falcon_h1): it has a number
+    # among the attention mixers and one among the Mamba mixers, pages in
+    # the pool of whole-context pages and a state slot
     layer_types: Optional[Tuple[str, ...]] = None
     # differential heads: adjacent heads pair up, a pair's two softmax
     # maps are subtracted under a learned weight, and the pair's output
@@ -146,6 +151,21 @@ class TransformerConfig:
     logits_divisor: Optional[float] = None
     # False = no position term at all (NoPE)
     use_rope: bool = True
+    # falcon_h1's muP multipliers, each a published number (None = 1; the
+    # embedding's is ``embed_scale`` and the head's the reciprocal of
+    # ``logits_divisor``).  A mixer reads ``in`` times the layer's normed
+    # input and adds ``out`` times its output projection; keys are scaled
+    # by ``key_scale`` before their rope; ``ssm_scales`` are the factors
+    # of the Mamba-2 in-projection's five segments ``(z, x, B, C, dt)``;
+    # ``mlp_scales`` scale the gate's pre-activation and the down
+    # projection's output
+    attn_in_scale: Optional[float] = None
+    attn_out_scale: Optional[float] = None
+    key_scale: Optional[float] = None
+    ssm_in_scale: Optional[float] = None
+    ssm_out_scale: Optional[float] = None
+    ssm_scales: Optional[Tuple[float, ...]] = None
+    mlp_scales: Optional[Tuple[float, float]] = None
 
     # head
     is_critic: bool = False  # value head (dim 1) instead of lm head
@@ -201,6 +221,10 @@ class TransformerConfig:
             ), self.layer_types
             # one state format the slots: Mamba-2's or Mamba-1's
             assert not {"mamba", "mamba1"} <= kinds, self.layer_types
+            # a parallel layer's halves are per-head pages and Mamba-2
+            assert not (
+                "parallel" in kinds and kinds & {"latent", "mamba1"}
+            ), self.layer_types
             if "latent" in kinds:
                 assert self.kv_lora_rank > 0 and self.qk_rope_head_dim > 0
             if "window" in kinds:
@@ -216,6 +240,25 @@ class TransformerConfig:
                 assert "mamba1" in self.layer_types[
                     : self.layer_types.index("gmu")
                 ], "a gmu layer gates the scan output of a mamba1 layer"
+        if self.n_mamba_layers and not self.is_mamba1:
+            # a B/C group serves a whole number of heads
+            assert self.mamba_n_heads % self.mamba_n_groups == 0, (
+                self.mamba_n_heads, self.mamba_n_groups,
+            )
+        for name, n in (("ssm_scales", 5), ("mlp_scales", 2)):
+            if getattr(self, name) is not None:
+                object.__setattr__(
+                    self, name, tuple(float(m) for m in getattr(self, name))
+                )
+                assert len(getattr(self, name)) == n, (name, getattr(self, name))
+        # the multipliers are read by the Mamba-2 mixer and the dense MLP
+        assert not (
+            self.is_mamba1
+            and (self.ssm_in_scale or self.ssm_out_scale or self.ssm_scales)
+        ), "ssm multipliers: the Mamba-2 mixer's"
+        assert self.mlp_scales is None or not self.n_experts, (
+            "mlp_scales: the dense MLP's"
+        )
         if self.diff_attention:
             assert self.n_kv_heads % 2 == 0 and not self.use_qk_norm
         if self.rope_layers is not None:
@@ -274,12 +317,13 @@ class TransformerConfig:
     @property
     def n_attn_layers(self) -> int:
         """Layers that WRITE per-token KV (every layer of a dense stack;
-        "attention", "window" and "latent" layers of a stack by kind: a
-        "cross" layer reads another's, a "gmu" layer has none)."""
+        "attention", "window", "latent" and "parallel" layers of a stack
+        by kind: a "cross" layer reads another's, a "gmu" layer has none)."""
         if self.layer_types is None:
             return self.n_layers
         return sum(
-            t in ("attention", "window", "latent") for t in self.layer_types
+            t in ("attention", "window", "latent", "parallel")
+            for t in self.layer_types
         )
 
     @property
@@ -292,10 +336,19 @@ class TransformerConfig:
 
     @property
     def n_mamba_layers(self) -> int:
-        """Layers that keep a recurrent state per sequence."""
+        """Layers that keep a recurrent state per sequence (a "parallel"
+        layer counts here AND in ``n_attn_layers``)."""
         if self.layer_types is None:
             return 0
-        return sum(t in ("mamba", "mamba1") for t in self.layer_types)
+        return sum(
+            t in ("mamba", "mamba1", "parallel") for t in self.layer_types
+        )
+
+    @property
+    def n_parallel_layers(self) -> int:
+        if self.layer_types is None:
+            return 0
+        return sum(t == "parallel" for t in self.layer_types)
 
     @property
     def n_cross_layers(self) -> int:

@@ -2,6 +2,7 @@
 
 from areal_tpu.models.hf import (  # noqa: F401
     deepseek_v3,
+    falcon_h1,
     gpt2,
     granitemoehybrid,
     llama_like,

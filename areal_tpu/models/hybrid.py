@@ -2,10 +2,10 @@
 recurrent state, attention with paged per-head KV over the whole context
 or over a window of it, latent attention with paged latent entries,
 CROSS attention over another layer's pages, a gated memory unit over
-another layer's scan output) and an MLP (a dense one on the leading
-``cfg.n_dense_layers`` layers, else the expert block this program's share
-of the experts gives) (granitemoehybrid, deepseek_v3, smallthinker,
-phi4flash).
+another layer's scan output, or attention AND Mamba-2 side by side on one
+input) and an MLP (a dense one on the leading ``cfg.n_dense_layers``
+layers, else the expert block this program's share of the experts gives)
+(granitemoehybrid, deepseek_v3, smallthinker, phi4flash, falcon_h1).
 
     h0 = embed_scale * embed[tokens]
     per layer:  h += r * mixer(norm(h));  m = norm(h)
@@ -41,6 +41,19 @@ layer's pages from the one that holds the window's first position
 ``cfg.layer_ropes`` (smallthinker's global layers have no position term),
 and an expert layer's router reads the mixer's input where
 ``cfg.moe_router_input == "attn"``.
+
+**Parallel layers** (kind ``"parallel"``: falcon_h1): ``h += attention(a) +
+mamba2(a)`` with ONE ``a = norm(h)``.  Such a layer is an attention mixer
+and a Mamba-2 mixer as the other kinds run them, each with its number in
+its own parameter stack (``Run.first_of_kind`` among ``params["attn"]``,
+``Run.first_of_state`` among ``params["mamba"]``), a place in the pool of
+whole-context pages AND a state slot; the three programs run both on the
+same ``a`` and add the two outputs (region ``areal.parallel`` around the
+norm and the sum, ``areal.attn`` and ``areal.ssm`` inside it).  The
+published multipliers are facts of the config that every mixer of their
+kind obeys (``_scaled``): a branch's input and output factors, the keys'
+(``transformer._attn_qkv``), the five of the Mamba-2 in-projection's
+segments, the dense MLP's two.
 
 **Differential heads** (``cfg.diff_attention``): adjacent heads pair up;
 a pair ``j`` makes two softmax maps ``P1 = softmax(q1 k1^T s)``, ``P2 =
@@ -100,7 +113,9 @@ The three are the same mathematics (``tests/model/test_latent.py``).
 (``[z | xBC | dt] = a W_in``; ``xBC = silu(causal depthwise conv)``;
 ``[x | B | C] = xBC``; ``dt = softplus(dt + dt_bias)``; per head ``S_t =
 exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``; ``out
-= (rmsnorm(y * silu(z)) * w) W_out``):
+= (rmsnorm(y * silu(z)) * w) W_out``; with ``cfg.mamba_n_groups`` = G > 1,
+B and C are ``[G, N]``, heads ``[g H/G, (g + 1) H/G)`` read group ``g``'s,
+and the norm is over each group's ``d_inner / G`` channels):
 
 * whole sequence / fill chunk (:func:`mamba_chunk`): the chunked SSD
   algorithm in plain ``jnp`` (products inside a chunk of
@@ -173,11 +188,21 @@ class Run(NamedTuple):
     #: ``every``-th layer from its first, and its numbers among its kind,
     #: its MLP kind and its pool advance by ``strides`` a layer
     every: int = 1
-    strides: Tuple[int, int, int] = (1, 1, 1)
+    strides: Tuple[int, int, int, int] = (1, 1, 1, 1)
+    #: a "parallel" layer's number among the MAMBA mixers (its
+    #: ``first_of_kind`` is its number among the attention mixers); the
+    #: last of ``strides`` is this number's
+    first_of_state: int = 0
 
 
 def _param_kind(kind: str) -> str:
-    return "attention" if kind == "window" else kind
+    return "attention" if kind in ("window", "parallel") else kind
+
+
+def _pool_kind(kind: str) -> str:
+    """Whose pool a layer's pages live in (a parallel layer's among the
+    attention layers')."""
+    return "attention" if kind == "parallel" else kind
 
 
 def layer_plan(cfg: TransformerConfig) -> Tuple[Run, ...]:
@@ -195,12 +220,16 @@ def layer_plan(cfg: TransformerConfig) -> Tuple[Run, ...]:
             runs.append(
                 Run(
                     kind, mlp, l, seen.get(_param_kind(kind), 0),
-                    seen.get(mlp, 0), 1, rope, seen.get("pool:" + kind, 0),
+                    seen.get(mlp, 0), 1, rope,
+                    seen.get("pool:" + _pool_kind(kind), 0),
+                    first_of_state=seen.get("mamba", 0)
+                    if kind == "parallel" else 0,
                 )
             )
-        seen[_param_kind(kind)] = seen.get(_param_kind(kind), 0) + 1
-        seen["pool:" + kind] = seen.get("pool:" + kind, 0) + 1
-        seen[mlp] = seen.get(mlp, 0) + 1
+        for name in (_param_kind(kind), "pool:" + _pool_kind(kind), mlp) + (
+            ("mamba",) if kind == "parallel" else ()
+        ):
+            seen[name] = seen.get(name, 0) + 1
     return tuple(runs)
 
 
@@ -240,6 +269,7 @@ def plan_periods(cfg: TransformerConfig) -> Tuple[Tuple[Run, ...], ...]:
                                 b.first_of_kind - a.first_of_kind,
                                 b.first_of_mlp - a.first_of_mlp,
                                 b.first_in_pool - a.first_in_pool,
+                                b.first_of_state - a.first_of_state,
                             ),
                         )
                         for a, b in zip(runs[i : i + p], runs[i + p : i + 2 * p])
@@ -290,7 +320,7 @@ def pool_layer_numbers(cfg: TransformerConfig, kind: str) -> np.ndarray:
     pages live in ``kind``'s pool, in the pool's order."""
     return np.array(
         [
-            j for run in layer_plan(cfg) if run.kind == kind
+            j for run in layer_plan(cfg) if _pool_kind(run.kind) == kind
             for j in range(run.first_of_kind, run.first_of_kind + run.count)
         ],
         np.int32,
@@ -299,11 +329,14 @@ def pool_layer_numbers(cfg: TransformerConfig, kind: str) -> np.ndarray:
 
 def _run_indices(run: Run):
     """``(layer numbers, numbers in the mixer's parameter stack, among
-    the MLP kind, in the mixer's pool)`` of a run's layers."""
+    the MLP kind, in the mixer's pool)`` of a run's layers; of a parallel
+    run's also their numbers among the Mamba mixers."""
     firsts = (
         run.first_layer, run.first_of_kind, run.first_of_mlp,
         run.first_in_pool,
     )
+    if run.kind == "parallel":
+        firsts += (run.first_of_state,)
     if run.every == 1:
         return tuple(jnp.arange(first, first + run.count) for first in firsts)
     return tuple(
@@ -317,6 +350,17 @@ def _of_kind(run: Run) -> slice:
     return slice(
         run.first_of_kind, run.first_of_kind + run.count * run.strides[0],
         run.strides[0],
+    )
+
+
+def _of_state(run: Run) -> slice:
+    """A run's layers in the Mamba mixers' stack (and among the conv
+    tails and state slots' layers)."""
+    if run.kind != "parallel":
+        return _of_kind(run)
+    return slice(
+        run.first_of_state, run.first_of_state + run.count * run.strides[3],
+        run.strides[3],
     )
 
 
@@ -353,6 +397,10 @@ def _mixer_region(run: Run):
         return region("areal.attn.cross")
     if run.kind == "gmu":
         return region("areal.gmu")
+    if run.kind == "parallel":
+        # the shared norm, the two outputs' sum and the residual add; the
+        # branches lie in ``areal.attn`` and ``areal.ssm`` inside it
+        return region("areal.parallel")
     return region("areal.attn")
 
 
@@ -369,6 +417,11 @@ def _held(run: Run, layer: Optional[int], ys):
     tree), or None where the run does not hold that layer."""
     at = _place_in(run, layer)
     return None if at is None else jax.tree.map(lambda a: a[at], ys)
+
+
+def _scaled(x, m: Optional[float]):
+    """``m x`` for a published multiplier ``m`` (None: there is none)."""
+    return x if m is None else x * jnp.asarray(m, x.dtype)
 
 
 def _rope_cfg(cfg: TransformerConfig, run: Run) -> TransformerConfig:
@@ -423,9 +476,18 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     every log-probability read 0 to five places, my chip run, PR 31; at
     the dense family's 1/sqrt(D) the logits are uniform to 0.01.)  An
     untied head is one more matrix (logits of deviation 0.58 over a
-    final norm of rms 1), beside an embedding of rms 0.5.  A LayerNorm's
-    bias is uniform in +-0.1; the newer kinds' pieces are in
-    :func:`_init_newer_kinds`."""
+    final norm of rms 1), beside an embedding of rms 0.5.  A matrix whose
+    product a PUBLISHED MULTIPLIER ``m`` scales (falcon_h1's muP factors:
+    ``_wider``) is drawn ``1/m`` wider, so that ``m`` times the product
+    has the deviation the product has in a stack without multipliers: a
+    trained muP model's matrices are larger by as much, and at the usual
+    width its factors (0.0078 on the logits, 0.011 on the MLP's output,
+    0.0375 and 0.088 on the branches') leave logits uniform to 0.005 and
+    eight layers that move the residual stream by under a hundredth, so
+    that no log-probability says anything about a page or a state.  The
+    program and the reference apply every multiplier as published either
+    way.  A LayerNorm's bias is uniform in +-0.1; the newer kinds' pieces
+    are in :func:`_init_newer_kinds`."""
     assert cfg.is_hybrid and (cfg.is_moe or cfg.n_dense_layers == cfg.n_layers)
     dt = jnp.dtype(cfg.dtype)
     L, Le, Ld = cfg.n_layers, cfg.n_expert_layers, cfg.n_dense_layers
@@ -472,7 +534,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     if Lm:
         u = jax.random.uniform(next(keys), (Lm, H), F32)
         dt0 = jnp.exp(u * (np.log(0.1) - np.log(0.001)) + np.log(0.001))
-    embed_rms = EMBED_RMS if cfg.tied_embedding else 0.5
+    embed_rms = (
+        EMBED_RMS if cfg.tied_embedding else 0.5 / (cfg.embed_scale or 1.0)
+    )
     params: Params = {
         "embed": {
             "weight": _uniform_stack(
@@ -487,7 +551,11 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     }
     if Lm:
         params["mamba"] = {
-            "in_proj": {"w": mat(Lm, (D, di + cd + H), D)},
+            "in_proj": {
+                "w": _segments_wider(cfg, mat(
+                    Lm, (D, di + cd + H), D * _wider(cfg.ssm_in_scale)
+                ))
+            },
             "conv": {"w": mat(Lm, (K, cd), K), "b": mat(Lm, (cd,), 16)},
             "dt_bias": (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dt),
             "A_log": jnp.log(
@@ -495,14 +563,17 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
             ).astype(dt),
             "D": ones(Lm, H),
             "norm": {"scale": ones(Lm, di)},
-            "out_proj": {"w": mat(Lm, (di, D), di)},
+            "out_proj": {"w": mat(Lm, (di, D), di * _wider(cfg.ssm_out_scale))},
         }
     if La:
+        d_in = D * _wider(cfg.attn_in_scale)
         params["attn"] = {
-            "q": {"w": mat(La, (D, Hq * hd), D)},
-            "k": {"w": mat(La, (D, Hkv * hd), D)},
-            "v": {"w": mat(La, (D, Hkv * hd), D)},
-            "o": {"w": mat(La, (Hq * hd, D), Hq * hd)},
+            "q": {"w": mat(La, (D, Hq * hd), d_in)},
+            "k": {"w": mat(La, (D, Hkv * hd), d_in * _wider(cfg.key_scale))},
+            "v": {"w": mat(La, (D, Hkv * hd), d_in)},
+            "o": {
+                "w": mat(La, (Hq * hd, D), Hq * hd * _wider(cfg.attn_out_scale))
+            },
         }
     params["final_norm"] = {"scale": ones(D)}
     if Ll:
@@ -523,19 +594,35 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         }
     if Ld:
         Fd = cfg.intermediate_dim
+        m_gate, m_down = cfg.mlp_scales or (None, None)
         params["dense"] = {
-            "gate": {"w": mat(Ld, (D, Fd), D, keys=more)},
+            "gate": {"w": mat(Ld, (D, Fd), D * _wider(m_gate), keys=more)},
             "up": {"w": mat(Ld, (D, Fd), D, keys=more)},
-            "down": {"w": mat(Ld, (Fd, D), Fd, keys=more)},
+            "down": {"w": mat(Ld, (Fd, D), Fd * _wider(m_down), keys=more)},
         }
     if not cfg.tied_embedding:
         params["lm_head"] = {
             "w": _uniform_stack(
-                next(more), 1, (D, cfg.vocab_size), 1.0 / np.sqrt(D), dt
+                next(more), 1, (D, cfg.vocab_size),
+                (cfg.logits_divisor or 1.0) / np.sqrt(D), dt,
             )[0]
         }
     _init_newer_kinds(cfg, params, jax.random.fold_in(key, 2))
     return params
+
+
+def _wider(m: Optional[float]) -> float:
+    """The factor of a matrix's fan-in under which it is drawn ``1/m``
+    wider (:func:`init_params`; 1.0, the same draw, without a multiplier)."""
+    return 1.0 if m is None else float(m) ** 2
+
+
+def _segments_wider(cfg: TransformerConfig, w):
+    """A Mamba-2 in-projection ``[.., D, z | x | B | C | dt]`` with each
+    segment's columns ``1/m`` wider for the segment's own multiplier."""
+    if cfg.ssm_scales is None:
+        return w
+    return w * jnp.asarray(1.0 / _segment_factors(cfg), w.dtype)
 
 
 def _init_newer_kinds(cfg: TransformerConfig, params: Params, key):
@@ -680,16 +767,28 @@ def copy_state_slots(ssm, conv, src: jax.Array, dst: jax.Array):
 def _split_in_proj(cfg: TransformerConfig, mp: Params, h):
     """``(z [.., d_inner], xBC [.., conv_dim], dt_raw [.., H])``."""
     di, cd = cfg.mamba_d_inner, cfg.mamba_conv_dim
-    zxd = _proj(mp["in_proj"], h)
+    zxd = _proj(mp["in_proj"], _scaled(h, cfg.ssm_in_scale))
+    if cfg.ssm_scales is not None:
+        zxd = zxd * jnp.asarray(_segment_factors(cfg), zxd.dtype)
     return zxd[..., :di], zxd[..., di : di + cd], zxd[..., di + cd :]
 
 
+def _segment_factors(cfg: TransformerConfig) -> np.ndarray:
+    """``cfg.ssm_scales`` a column of the Mamba-2 in-projection's output
+    ``[z | x | B | C | dt]``: one factor a segment."""
+    gn = cfg.mamba_n_groups * cfg.mamba_d_state
+    widths = (cfg.mamba_d_inner, cfg.mamba_d_inner, gn, gn, cfg.mamba_n_heads)
+    return np.repeat(np.asarray(cfg.ssm_scales, np.float32), widths)
+
+
 def _split_conv_out(cfg: TransformerConfig, xbc):
-    """``(x [.., d_inner], B [.., N], C [.., N])`` (one group: B and C are
-    shared by all heads)."""
-    assert cfg.mamba_n_groups == 1, "one B/C group is what is written here"
-    di, N = cfg.mamba_d_inner, cfg.mamba_d_state
-    return xbc[..., :di], xbc[..., di : di + N], xbc[..., di + N :]
+    """``(x [.., d_inner], B [.., N], C [.., N])`` with one group (B and
+    C are shared by all heads), else B and C ``[.., G, N]``."""
+    di, N, G = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_n_groups
+    if G == 1:
+        return xbc[..., :di], xbc[..., di : di + N], xbc[..., di + N :]
+    bc = xbc[..., di:].reshape(xbc.shape[:-1] + (2, G, N))
+    return xbc[..., :di], bc[..., 0, :, :], bc[..., 1, :, :]
 
 
 def _dt_and_a(mp: Params, dt_raw):
@@ -700,12 +799,19 @@ def _dt_and_a(mp: Params, dt_raw):
 def _mamba_out(cfg: TransformerConfig, mp: Params, y, x, z):
     """``y`` [.., d_inner] float32 (without the skip) -> the mixer's
     output: skip ``D x``, gate BEFORE the norm, norm over all of
-    ``d_inner``, output projection."""
-    P = cfg.mamba_head_dim
+    ``d_inner`` (over each group's channels where there are groups),
+    output projection."""
+    P, G = cfg.mamba_head_dim, cfg.mamba_n_groups
     y = y + jnp.repeat(mp["D"].astype(F32), P) * x.astype(F32)
     y = y * jax.nn.silu(z.astype(F32))
-    y = _norm(y, mp["norm"], cfg).astype(z.dtype)
-    return _proj(mp["out_proj"], y)
+    if G == 1:
+        y = _norm(y, mp["norm"], cfg).astype(z.dtype)
+    else:
+        grouped = {"scale": mp["norm"]["scale"].reshape(G, -1)}
+        y = _norm(y.reshape(y.shape[:-1] + (G, -1)), grouped, cfg).reshape(
+            y.shape
+        ).astype(z.dtype)
+    return _scaled(_proj(mp["out_proj"], y), cfg.ssm_out_scale)
 
 
 def causal_conv(xbc, tail, w, b, n_valid):
@@ -730,7 +836,8 @@ def ssd_chunked(x, dt, a_neg, bm, cm, s0, chunk: int):
 
     ``x`` [B, T, H, P], ``dt`` [B, T, H] (0 where a position is not
     valid: no decay, no input), ``a_neg`` [H] (< 0), ``bm`` / ``cm``
-    [B, T, N], ``s0`` [B, N, H, P]; all float32.  Returns ``(y [B, T, H,
+    [B, T, N] (or [B, T, G, N]: heads ``[g H/G, (g + 1) H/G)`` read group
+    ``g``'s), ``s0`` [B, N, H, P]; all float32.  Returns ``(y [B, T, H,
     P], state after the last position)``.  Inside a chunk of ``chunk``
     positions: ``y_i = sum_{j<=i} (C_i . B_j) exp(cum_i - cum_j) dt_j
     x_j`` with ``cum`` the running sum of ``dt A``; between chunks the
@@ -746,6 +853,11 @@ def ssd_chunked(x, dt, a_neg, bm, cm, s0, chunk: int):
     nc = (T + pad) // Q
     x = x.reshape(B, nc, Q, H, P)
     dt = dt.reshape(B, nc, Q, H)
+    if bm.ndim == 4:
+        return _ssd_grouped(
+            x, dt, a_neg, bm.reshape((B, nc, Q) + bm.shape[2:]),
+            cm.reshape((B, nc, Q) + cm.shape[2:]), s0, T,
+        )
     bm = bm.reshape(B, nc, Q, -1)
     cm = cm.reshape(B, nc, Q, -1)
     cum = jnp.cumsum(dt * a_neg, axis=2)  # [B, nc, Q, H], inclusive
@@ -774,6 +886,46 @@ def ssd_chunked(x, dt, a_neg, bm, cm, s0, chunk: int):
     y = y + ein(
         "bcin,cbnhp->bcihp", cm, s_before
     ) * jnp.exp(cum)[..., None]
+    return y.reshape(B, nc * Q, H, P)[:, :T], s_end
+
+
+def _ssd_grouped(x, dt, a_neg, bm, cm, s0, T: int):
+    """:func:`ssd_chunked`'s products with B and C by GROUP, on its
+    chunked operands: ``x`` [B, nc, Q, H, P], ``dt`` [B, nc, Q, H], ``bm``
+    / ``cm`` [B, nc, Q, G, N], ``s0`` [B, N, H, P].  A head axis is split
+    ``[G, H/G]`` where it meets B or C, and nothing else differs."""
+    B, nc, Q, H, P = x.shape
+    G, N = bm.shape[3:]
+    cum = jnp.cumsum(dt * a_neg, axis=2)  # [B, nc, Q, H], inclusive
+    dtx = dt[..., None] * x  # [B, nc, Q, H, P]
+    ein = partial(jnp.einsum, precision=HIGHEST)
+    grouped = (B, nc, Q, G, H // G, P)
+    # inside a chunk
+    g = ein("bcign,bcjgn->bcgij", cm, bm)  # [B, nc, G, Qi, Qj]
+    cum_h = cum.swapaxes(2, 3)  # [B, nc, H, Q]
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+    decay = jnp.exp(
+        jnp.where(causal, cum_h[..., :, None] - cum_h[..., None, :], -jnp.inf)
+    )  # [B, nc, H, Qi, Qj]
+    y = ein(
+        "bchij,bcjhp->bcihp", jnp.repeat(g, H // G, axis=2) * decay, dtx
+    )
+    to_end = jnp.exp(cum[:, :, -1:, :] - cum)  # [B, nc, Q, H]
+    s_in = ein(
+        "bcjgn,bcjghp->bcnghp", bm, (to_end[..., None] * dtx).reshape(grouped)
+    ).reshape(B, nc, N, H, P)
+    total = jnp.exp(cum[:, :, -1, :])  # [B, nc, H]
+
+    def step(s, inp):
+        s_c, dec = inp
+        return s * dec[:, None, :, None] + s_c, s
+
+    s_end, s_before = jax.lax.scan(
+        step, s0, (s_in.swapaxes(0, 1), total.swapaxes(0, 1))
+    )  # s_before [nc, B, N, H, P]: the state each chunk starts from
+    y = y + ein(
+        "bcign,cbnghp->bcighp", cm, s_before.reshape(nc, B, N, G, H // G, P)
+    ).reshape(B, nc, Q, H, P) * jnp.exp(cum)[..., None]
     return y.reshape(B, nc * Q, H, P)[:, :T], s_end
 
 
@@ -960,6 +1112,7 @@ def _heads_q(cfg: TransformerConfig, ap: Params, h, positions, run: Run):
     """A layer's queries ``[B, T, Hq, pool_head_dim]``: a differential
     pair's two as ``[q1 | 0]`` and ``[0 | q2]`` (module docstring)."""
     B, T, _ = h.shape
+    h = _scaled(h, cfg.attn_in_scale)
     if not cfg.diff_attention:
         return _attn_qkv(
             _rope_cfg(cfg, run), {"attn": ap}, h, positions, None
@@ -982,13 +1135,17 @@ def _heads_qkv(cfg: TransformerConfig, ap: Params, h, positions, run: Run):
     pair's ``[k1 | k2]`` and ``[v1 | v2]`` are adjacent heads' columns: a
     reshape)."""
     if not cfg.diff_attention:
-        return _attn_qkv(_rope_cfg(cfg, run), {"attn": ap}, h, positions, None)
+        return _attn_qkv(
+            _rope_cfg(cfg, run), {"attn": ap}, _scaled(h, cfg.attn_in_scale),
+            positions, None,
+        )
     B, T, _ = h.shape
     shape = (B, T, cfg.pool_kv_heads, cfg.pool_head_dim)
+    hs = _scaled(h, cfg.attn_in_scale)
     return (
         _heads_q(cfg, ap, h, positions, run),
-        _proj(ap["k"], h).reshape(shape),
-        _proj(ap["v"], h).reshape(shape),
+        _proj(ap["k"], hs).reshape(shape),
+        _proj(ap["v"], hs).reshape(shape),
     )
 
 
@@ -1002,7 +1159,7 @@ def _heads_out(cfg: TransformerConfig, ap: Params, l, attn, dtype):
     """Attention's output ``[B, T, Hq * pool_head_dim]`` through the
     pairs' difference, weight and norm (differential heads) and ``W_o``."""
     if not cfg.diff_attention:
-        return _proj(ap["o"], attn)
+        return _scaled(_proj(ap["o"], attn), cfg.attn_out_scale)
     B, T, _ = attn.shape
     o = attn.reshape(B, T, cfg.n_q_heads // 2, 2, 2 * cfg.head_dim).astype(F32)
     lam0 = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(l, F32))
@@ -1018,7 +1175,9 @@ def _heads_out(cfg: TransformerConfig, ap: Params, l, attn, dtype):
     d = o[..., 0, :] - lam * o[..., 1, :]
     d = d * jax.lax.rsqrt(jnp.mean(d * d, axis=-1, keepdims=True) + cfg.norm_eps)
     d = d * ap["subln"]["scale"].astype(F32) * (1.0 - lam0)
-    return _proj(ap["o"], d.astype(dtype).reshape(B, T, -1))
+    return _scaled(
+        _proj(ap["o"], d.astype(dtype).reshape(B, T, -1)), cfg.attn_out_scale
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -1182,10 +1341,12 @@ def _mlp_half(
     h = _norm(x, _at(params["layers"]["mlp_norm"], l), cfg)
     if run.mlp == "dense":
         dp = _at(params["dense"], e)
-        hid = _activation(_proj(dp["gate"], h), cfg.activation) * _proj(
-            dp["up"], h
-        )
-        return _res(cfg, x, _proj(dp["down"], hid)), None, None, None
+        m_gate, m_down = cfg.mlp_scales or (None, None)
+        hid = _activation(
+            _scaled(_proj(dp["gate"], h), m_gate), cfg.activation
+        ) * _proj(dp["up"], h)
+        out = _scaled(_proj(dp["down"], hid), m_down)
+        return _res(cfg, x, out), None, None, None
     out, pairs, routed, rounds = held_moe_mlp(
         cfg, h, params["layers"]["mlp"], valid=valid,
         router_input=a if cfg.moe_router_input == "attn" else None, layer=e,
@@ -1305,11 +1466,26 @@ def hidden_states(
 
     shared = {}  # what one layer leaves for later ones to read
 
+    def parallel_mixer(run: Run, h, l, j, js):
+        """Both mixers on the one normed input, summed."""
+        with region("areal.attn"):
+            ap = _at(params["attn"], j)
+            q, k, v = _heads_qkv(cfg, ap, h, positions, run)
+            out = _heads_out(cfg, ap, l, attend(q, k, v, mask), h.dtype)
+        with region("areal.ssm"):
+            out_m, _, _ = mamba_chunk(
+                cfg, _at(params["mamba"], js), h, n_valid, s0, tail0
+            )
+        return out + out_m
+
     def body(x, idx, run):
-        l, j, e, _ = idx
+        l, j, e = idx[:3]
         with _mixer_region(run):
             a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
-            out, left = mixer(run, a, l, j)
+            if run.kind == "parallel":
+                out, left = parallel_mixer(run, a, l, j, idx[4]), None
+            else:
+                out, left = mixer(run, a, l, j)
             x = _res(cfg, x, out)
         x, _, _, _ = _mlp_half(cfg, params, run, l, e, x, valid, a)
         return x, left
@@ -1487,7 +1663,7 @@ def hybrid_fill_chunk(
             )  # [Lm, F, K-1, conv_dim]
 
     def mamba_mixer(run, h, ssm, j, tail0):
-        mp = _at(params[run.kind], j)
+        mp = _at(params["mamba1" if run.kind == "mamba1" else "mamba"], j)
         if use_kernel:
             s0 = ssm_ops.ssm_state_rows(
                 ssm, j, slots, interpret=paged.kernel_interpret()
@@ -1591,8 +1767,8 @@ def hybrid_fill_chunk(
 
     def xs_of(run):
         xs = _run_indices(run)
-        if run.kind in ("mamba", "mamba1"):
-            xs += (tails0[_of_kind(run)],)
+        if run.kind in ("mamba", "mamba1", "parallel"):
+            xs += (tails0[_of_state(run)],)
         return xs
 
     def body(carry, inp, run):
@@ -1600,7 +1776,15 @@ def hybrid_fill_chunk(
         l, j, e, p = inp[:4]
         with _mixer_region(run):
             a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
-            if run.kind in ("mamba", "mamba1"):
+            if run.kind == "parallel":
+                # both mixers on the one normed input, summed; kept: the
+                # chunk's (K, V) and the conv tail after it
+                with region("areal.attn"):
+                    out, kv = attn_mixer(run, a, l, j, p)
+                with region("areal.ssm"):
+                    out_m, ssm, tail1 = mamba_mixer(run, a, ssm, inp[4], inp[5])
+                out, kept = out + out_m, (kv, tail1)
+            elif run.kind in ("mamba", "mamba1"):
                 out, ssm, kept = mamba_mixer(run, a, ssm, j, inp[4])
             elif run.kind == "latent":
                 out, kept = latent_mixer(a, j)
@@ -1618,7 +1802,10 @@ def hybrid_fill_chunk(
     for period in periods[: len(periods) - len(tail)]:
         carry, left = _scan_period(body, carry, period, xs_of)
         for run, (kept, r) in zip(period, left):
-            if run.kind in ("mamba", "mamba1"):
+            if run.kind == "parallel":
+                chunk_kv.append(kept[0])
+                tails1.append(kept[1])
+            elif run.kind in ("mamba", "mamba1"):
                 if _place_in(run, cfg.memory_layer) is not None:
                     kept, shared["memory"] = kept[0], _held(
                         run, cfg.memory_layer, kept[1]
@@ -1834,11 +2021,21 @@ def hybrid_decode_chunk(
 
         def body(carry, idx, run):
             x, wk, wv, ssm, conv, pairs = carry
-            l, j, e, p = idx
+            l, j, e, p = idx[:4]
             left = None
             with _mixer_region(run):
                 a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
-                if run.kind == "mamba":
+                if run.kind == "parallel":
+                    # both mixers on the one normed input, summed
+                    with region("areal.attn"):
+                        out, wk, wv = attn_mixer(run, a, wk, wv, l, j, p)
+                    with region("areal.ssm"):
+                        out_m, ssm, conv = mamba_step(
+                            cfg, _at(params["mamba"], idx[4]), a,
+                            ssm, conv, idx[4], active, use_kernel,
+                        )
+                    out = out + out_m
+                elif run.kind == "mamba":
                     out, ssm, conv = mamba_step(
                         cfg, _at(params["mamba"], j), a,
                         ssm, conv, j, active, use_kernel,

@@ -582,6 +582,8 @@ def _attn_qkv(cfg: TransformerConfig, lp: Params, h, positions, rope_cs):
     q = checkpoint_name(q, "q_proj")
     k = checkpoint_name(k, "k_proj")
     v = checkpoint_name(v, "v_proj")
+    if cfg.key_scale is not None:  # falcon_h1: keys scaled before their rope
+        k = k * jnp.asarray(cfg.key_scale, k.dtype)
     if cfg.use_qk_norm:
         q = _head_norm(q, lp["attn"]["q_norm"]["scale"], cfg.norm_eps)
         k = _head_norm(k, lp["attn"]["k_norm"]["scale"], cfg.norm_eps)

@@ -95,6 +95,12 @@ METRIC_TABLE = [
         "model without such state",
     ),
     MetricSpec(
+        "areal_inference_state_slots_total",
+        "gauge",
+        "Recurrent-state slots the engine holds (one a batch row; 0 for "
+        "a model without such state)",
+    ),
+    MetricSpec(
         "areal_inference_moe_expert_pairs",
         "gauge",
         "(token, k) pairs decode chunks have routed to each expert this "
@@ -1177,7 +1183,9 @@ TRACE_TABLE = [
         "paged kernel copies a page in; for latent "
         "pages latent_ctx_tokens_sum and latent_pages_attended = the "
         "same context and pages, ONE entry a position and layer; with "
-        "window layers window_tokens_sum = sum of min(context, window))",
+        "window layers window_tokens_sum = sum of min(context, window); "
+        "with recurrent state state_rows = rows x state layers, the "
+        "states a step updates; with parallel layers parallel_layers)",
     ),
     TraceSpec(
         "areal.engine.harvest.wait",
@@ -1288,6 +1296,14 @@ TRACE_TABLE = [
         "only, over the pages and the chunk's own K and V of the stack's "
         "one full-attention layer (paged_attn_decode / paged_attn_fill "
         "over the pool's ONE layer)",
+    ),
+    TraceSpec(
+        "areal.parallel",
+        "region",
+        "What the two mixers of a PARALLEL layer share (attention and "
+        "Mamba-2 side by side on one input): the norm before them, the "
+        "sum of their outputs and the residual add; the branches "
+        "themselves lie in areal.attn and areal.ssm inside it",
     ),
     TraceSpec(
         "areal.gmu",

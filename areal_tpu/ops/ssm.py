@@ -26,6 +26,13 @@ HBM (it would be a third pass over state-sized bytes).  One kernel and not
 two because everything else is shared: the slot order, the skipped dead
 slots, the aliasing, the tiles.  Its calls are named
 ``ssm_state_update_m1`` in the device trace.
+
+**B/C groups** (``b`` / ``c`` of shape ``[S, G, N]``): heads ``[g H/G, (g
++ 1) H/G)`` read group ``g``'s B and C.  Heads lie along the lanes, so a
+group is a run of ``H*P / G`` lanes; the lane block divides it, and the
+column a grid step is handed is the group of its lane block (picked in
+the index map like the slot and the layer: nothing else in the kernel
+knows of groups).  With ONE group (``[S, N]``) the call is what it was.
 """
 
 from __future__ import annotations
@@ -97,8 +104,8 @@ def ssm_state_update(
     layer: jax.Array,  # [] or [1] int32: which of the Lm layers
     decay: jax.Array,  # [S, HP] float32: exp(dt * A), repeated over P
     dtx: jax.Array,  # [S, HP] float32: dt * x
-    b: jax.Array,  # [S, N] float32
-    c: jax.Array,  # [S, N] float32
+    b: jax.Array,  # [S, N] float32, or [S, G, N]: one a group of heads
+    c: jax.Array,  # as b
     live: jax.Array,  # [S] bool: slots that take this step
     interpret: bool = False,
     a: Optional[jax.Array] = None,  # [N, HP] float32 (< 0): Mamba-1
@@ -109,7 +116,9 @@ def ssm_state_update(
     ``decay`` holds ``dt`` and the decay is ``exp(dt[c] a[n, c])``
     (module docstring)."""
     _, S, N, HP = state.shape
-    RB = _lane_block(HP)
+    G = b.shape[1] if b.ndim == 3 else 1
+    assert HP % G == 0 and (a is None or G == 1), (HP, G)
+    RB = _lane_block(HP // G)
     n_j = HP // RB
     order = jnp.argsort(~live, stable=True).astype(jnp.int32)
     n_live = jnp.sum(live, dtype=jnp.int32).reshape(1)
@@ -124,8 +133,11 @@ def ssm_state_update(
         return slot, 0, jj
 
     def col_map(i, j, order_ref, n_live_ref, layer_ref):
-        slot, _ = _visited(i, j, order_ref, n_live_ref, n_j)
-        return slot, 0, 0
+        slot, jj = _visited(i, j, order_ref, n_live_ref, n_j)
+        if G == 1:
+            return slot, 0, 0
+        # [S, G*N, 1] in blocks of N: the block's number is the group
+        return slot, jj // (n_j // G), 0
 
     def a_map(i, j, order_ref, n_live_ref, layer_ref):
         _, jj = _visited(i, j, order_ref, n_live_ref, n_j)
@@ -162,7 +174,7 @@ def ssm_state_update(
     )(
         order, n_live, layer, state,
         decay.reshape(S, 1, HP), dtx.reshape(S, 1, HP),
-        b.reshape(S, N, 1), c.reshape(S, N, 1), *more,
+        b.reshape(S, G * N, 1), c.reshape(S, G * N, 1), *more,
     )
     return y.reshape(S, HP), state
 
@@ -219,7 +231,14 @@ def ssm_state_update_reference(state, layer, decay, dtx, b, c, live, a=None):
     decay = decay[:, None, :]
     if a is not None:  # Mamba-1: ``decay`` holds dt
         decay = jnp.exp(decay * a)
-    new = s * decay + b[:, :, None] * dtx[:, None, :]
+    if b.ndim == 3:  # [S, G, N]: each lane reads its group's column
+        lanes = state.shape[-1] // b.shape[1]
+        b, c = (
+            jnp.repeat(t.swapaxes(1, 2), lanes, axis=2) for t in (b, c)
+        )  # [S, N, HP]
+    else:
+        b, c = b[:, :, None], c[:, :, None]
+    new = s * decay + b * dtx[:, None, :]
     new = jnp.where(live[:, None, None], new, s)
-    y = jnp.sum(new * c[:, :, None], axis=1)
+    y = jnp.sum(new * c, axis=1)
     return y, jax.lax.dynamic_update_index_in_dim(state, new, layer, 0)

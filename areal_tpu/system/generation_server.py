@@ -632,6 +632,9 @@ class GenerationServerWorker(worker_base.Worker):
             "state_slots_live": reg.gauge(
                 "areal_inference_state_slots_live"
             ),
+            "state_slots_total": reg.gauge(
+                "areal_inference_state_slots_total"
+            ),
             "moe_expert_pairs": reg.gauge("areal_inference_moe_expert_pairs"),
             "moe_groups_hit": reg.gauge("areal_inference_moe_groups_hit"),
             "moe_fill_tokens": reg.gauge("areal_inference_moe_fill_tokens"),
@@ -816,6 +819,7 @@ class GenerationServerWorker(worker_base.Worker):
         self._obs["pages_live"].set(eng.pages_live)
         self._obs["pages_total"].set(eng.pages_total)
         self._obs["state_slots_live"].set(eng.state_slots_live)
+        self._obs["state_slots_total"].set(eng.state_slots_total)
         for e, n in enumerate(eng.moe_expert_pairs.tolist()):
             self._obs["moe_expert_pairs"].set(n, expert=str(e))
         self._obs["moe_groups_hit"].set(eng.moe_groups_hit_total)

@@ -7,10 +7,11 @@ change leaves a stack's programs alone:
     (cd <a copy of the parent> && JAX_PLATFORMS=cpu PYTHONPATH=. python \\
         <this file> > parent.txt);  diff parent.txt change.txt
 
-The hybrid, latent and window cells' ``hybrid_fill_chunk`` /
-``hybrid_decode_chunk`` at their cells' shapes (the shapes of
+The hybrid, latent, window, shared and parallel cells' ``hybrid_fill_chunk`` /
+``hybrid_decode_chunk`` and the dense cell's ``paged_fill_chunk`` /
+``paged_decode_chunk`` at their cells' shapes (the shapes of
 ``tests/ops/test_tpu_compile.py``) and ``ops/ssm.ssm_state_update``'s
-Mamba-2 call.  A Mosaic kernel's body is embedded in the lowered text as
+Mamba-2 and Mamba-1 calls.  A Mosaic kernel's body is embedded in the lowered text as
 serialized MLIR WITH its source paths and line numbers, so two trees at
 two paths never agree byte for byte: each body is parsed back and hashed
 without its locations.  One process only (it loads the TPU compiler's
@@ -117,6 +118,52 @@ def main():
         *args, t.LATENT_ROWS, place((t.LATENT_ROWS, t.LATENT_CTX // page), i32),
         t.LATENT_CHUNK, t.LATENT_CTX,
     )
+    cfg, params, pools, ssm, conv, place = t._shared_cell_args(one)
+    args = (cfg, params, pools["global"], ssm, conv, place)
+    for F, C in ((2, 1024), (1, 1024)):
+        table = place((F, t.SHARED_CTX // t.SHARED_PAGE), i32)
+        out[f"shared_fill_F{F}"] = fill(
+            *args, F, C, table, win_pools=pools["window"], win_tables=table
+        )
+    table = place((t.SHARED_ROWS, t.SHARED_CTX // t.SHARED_PAGE), i32)
+    out["shared_decode"] = decode(
+        *args, t.SHARED_ROWS, table, t.SHARED_CHUNK, t.SHARED_CTX,
+        win_pools=pools["window"], win_tables=table,
+    )
+    S, N, HP = ssm.shape[1:]
+    out["ssm_state_update_mamba1"] = ssm_ops.ssm_state_update.lower(
+        ssm, place((), i32), place((S, HP), f32), place((S, HP), f32),
+        place((S, N), f32), place((S, N), f32), place((S,), jnp.bool_),
+        a=place((N, HP), f32),
+    )
+    _, place3 = t._place_on(desc, 1)
+    cfg, params, pool, scales = t._serving_program_args(
+        "qwen2.5-1.5b", 28, False, place3
+    )
+    out["dense_fill"] = paged.paged_fill_chunk.lower(
+        params, pool, pool, cfg, place((t.FILL_F, t.FILL_C), i32),
+        place((t.FILL_F,), i32), place((t.FILL_F,), i32),
+        place((t.FILL_F, t.MB), i32), use_kernel=True, mesh=None,
+        kv_axis=None, **scales,
+    )
+    r = lambda d: place((t.DECODE_B,), d)
+    out["dense_decode"] = paged.paged_decode_chunk.lower(
+        params, pool, pool, cfg, place((t.DECODE_B, t.MB), i32), r(i32),
+        r(i32), r(jnp.bool_), r(i32), place((2,), jnp.uint32),
+        chunk_size=t.DECODE_W, sample_fn=t._greedy, stop_fn=t._never_stop,
+        use_kernel=True, max_len=t.PAGE * t.MB, mesh=None, kv_axis=None,
+        **scales,
+    )
+    if hasattr(t, "_parallel_cell_args"):  # a tree from before the kind has none
+        cfg, params, pools, ssm, conv, place = t._parallel_cell_args(one)
+        args = (cfg, params, pools, ssm, conv, place)
+        pages = t.PARALLEL_CTX // t.PARALLEL_PAGE
+        for F, C in ((2, 1024), (1, 1024)):
+            out[f"parallel_fill_F{F}"] = fill(*args, F, C, place((F, pages), i32))
+        out["parallel_decode"] = decode(
+            *args, t.PARALLEL_ROWS, place((t.PARALLEL_ROWS, pages), i32),
+            t.PARALLEL_CHUNK, t.PARALLEL_CTX,
+        )
     for name, lowered in out.items():
         print(name, digest(lowered), flush=True)
 

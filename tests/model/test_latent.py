@@ -58,7 +58,7 @@ def model():
 def test_layer_plan_cuts_runs_by_mixer_and_mlp():
     plan = hybrid.layer_plan(make_cfg())
     # (each run a period of its own: nothing repeats a pattern of singles)
-    assert all((r.every, r.strides) == (1, (1, 1, 1)) for r in plan)
+    assert all((r.every, r.strides) == (1, (1, 1, 1, 1)) for r in plan)
     assert hybrid.plan_periods(make_cfg()) == tuple((r,) for r in plan)
     assert [tuple(r)[:8] for r in plan] == [
         # (..., count, rope, number in its pool)

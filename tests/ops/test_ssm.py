@@ -140,3 +140,57 @@ def test_mamba1_state_update_forms_the_decay_tile_inside_the_kernel(
             x["dtx"], x["b"], x["c"],
         )
         assert np.abs(flat[lv] - y_want[lv]).max() > 1e-2
+
+
+@pytest.mark.parametrize(
+    "live",
+    [[1, 0, 1, 1, 0, 0], [1] * 6, [0] * 6, [0, 0, 0, 0, 0, 1]],
+    ids=["mixed", "all", "none", "last_only"],
+)
+@pytest.mark.parametrize(
+    "groups, lane_block", [(2, 2048), (2, 8), (4, 2048)],
+    ids=["a_block_a_group", "two_blocks_a_group", "four_groups"],
+)
+def test_state_update_hands_each_lane_block_its_groups_b_and_c(
+    monkeypatch, live, groups, lane_block
+):
+    """B and C by GROUP (``[S, G, N]``): lanes ``[g HP/G, (g + 1) HP/G)``
+    read group ``g``'s column, picked in the index map; against numpy slot
+    by slot, against the jnp twin, dead slots and other layers bit for
+    bit.  A lane block is a whole group (falcon_h1's 2,048 lanes) or part
+    of one."""
+    monkeypatch.setattr(ssm, "LANE_BLOCK", lane_block)
+    x = _inputs(3)
+    k = jax.random.split(jax.random.PRNGKey(4), 2)
+    b = jax.random.normal(k[0], (S, groups, N))
+    c = jax.random.normal(k[1], (S, groups, N))
+    live = jnp.asarray(live, bool)
+    layer = 1
+    lanes = HP // groups
+    s = np.asarray(x["state"][layer], np.float64)
+    b_l = np.repeat(np.asarray(b).transpose(0, 2, 1), lanes, axis=2)  # [S, N, HP]
+    c_l = np.repeat(np.asarray(c).transpose(0, 2, 1), lanes, axis=2)
+    s_want = s * np.asarray(x["decay"])[:, None, :] + b_l * np.asarray(x["dtx"])[:, None, :]
+    y_want = (s_want * c_l).sum(1)
+    y, state = ssm.ssm_state_update(
+        x["state"].copy(), jnp.int32(layer), x["decay"], x["dtx"], b, c, live,
+        interpret=True,
+    )
+    y_ref, state_ref = ssm.ssm_state_update_reference(
+        x["state"], layer, x["decay"], x["dtx"], b, c, live
+    )
+    lv = np.asarray(live)
+    assert np.abs(np.asarray(state[layer])[lv] - s_want[lv]).max(initial=0) < 1e-5
+    assert np.abs(np.asarray(y)[lv] - y_want[lv]).max(initial=0) < 1e-4
+    assert np.array_equal(np.asarray(state[layer])[~lv], np.asarray(x["state"][layer])[~lv])
+    others = [l for l in range(LM) if l != layer]
+    assert np.array_equal(np.asarray(state)[others], np.asarray(x["state"])[others])
+    assert np.abs(np.asarray(state) - np.asarray(state_ref)).max() < 1e-5
+    assert np.abs((np.asarray(y) - np.asarray(y_ref))[lv]).max(initial=0) < 1e-4
+    # one group's column in every group's place is another result
+    if lv.any():
+        same = jnp.broadcast_to(b[:, :1], b.shape)
+        y_one, _ = ssm.ssm_state_update_reference(
+            x["state"], layer, x["decay"], x["dtx"], same, c, live
+        )
+        assert np.abs((np.asarray(y_one) - np.asarray(y_ref))[lv]).max() > 1e-2

@@ -1119,6 +1119,151 @@ def test_windowed_kernel_compiles_at_the_cells_head_grouping(one_chip, Q, page):
     assert ("paged_window_decode" if Q == 1 else "paged_window_fill") in text
 
 
+# --- a stack of parallel layers: pages AND a state slot in every layer ---
+
+#: falcon-h1-34b-instruct as the cell runs it (benchmark/configs): 8 of 72
+#: layers, an eighth of the vocabulary, every width and multiplier as
+#: published; the cell's engine: 64 rows of 5,120, 196,608 tokens of pages
+PARALLEL_ROWS, PARALLEL_CTX, PARALLEL_PAGE = 64, 5120, 512
+PARALLEL_POOL_TOKENS, PARALLEL_CHUNK = 196608, 8
+
+
+def _parallel_cell_args(one_chip):
+    cfg = TransformerConfig(
+        n_layers=8, hidden_dim=5120, n_q_heads=20, n_kv_heads=4, head_dim=HD,
+        intermediate_dim=21504, vocab_size=32640,
+        max_position_embeddings=262144, norm_eps=1e-5, rotary_base=1e11,
+        layer_types=("parallel",) * 8, n_dense_layers=8,
+        mamba_n_heads=32, mamba_head_dim=128, mamba_d_state=256,
+        mamba_n_groups=2, mamba_d_conv=4, mamba_chunk_size=128,
+        embed_scale=5.656854249492381, logits_divisor=128.0,
+        attn_out_scale=0.0375, key_scale=0.011048543456039804,
+        ssm_in_scale=0.25, ssm_out_scale=0.08838834764831845,
+        ssm_scales=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                    0.3535533905932738),
+        mlp_scales=(0.1767766952966369, 0.011160714285714284),
+    )
+
+    def place(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))
+    )
+    params = jax.tree.map(lambda a: place(a.shape, a.dtype), shapes)
+    k_shape, v_shape = paged.pool_shapes(
+        cfg, PARALLEL_POOL_TOKENS // PARALLEL_PAGE, PARALLEL_PAGE
+    )
+    pools = place(k_shape, jnp.bfloat16), place(v_shape, jnp.bfloat16)
+    assert k_shape == (8, 384, 4, PARALLEL_PAGE, HD)
+    ssm, conv = (
+        place(a.shape, a.dtype)
+        for a in jax.eval_shape(lambda: hybrid.state_zeros(cfg, PARALLEL_ROWS))
+    )
+    assert ssm.shape == (8, 64, 256, 4096) and conv.shape == (8, 3, 64, 5120)
+    return cfg, params, pools, ssm, conv, place
+
+
+# the cell's largest fill (a prompt's last piece with the next prompt's
+# first piece behind it) and one prompt's chunk
+@pytest.mark.parametrize("F,C", [(2, 1024), (1, 1024)])
+def test_parallel_fill_program_fits_beside_weights_pages_and_state(
+    one_chip, monkeypatch, F, C
+):
+    """``hybrid_fill_chunk`` whole at the parallel cell's shapes: 7.55 GB
+    of weights + 3.22 GB of pages + 2.16 GB of state + the chunk's
+    temporaries (the grouped SSD products, gate and up of 21,504 columns)
+    fit one chip; every layer's prefix part is ``paged_attn_fill`` and its
+    state rows come through ``ssm_state_rows``; no copy of the pools or of
+    the state, and the conv tails ride no loop."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    cfg, params, pools, ssm, conv, place = _parallel_cell_args(one_chip)
+    compiled = hybrid.hybrid_fill_chunk.lower(
+        params, *pools, ssm, conv, cfg, place((F, C), jnp.int32),
+        place((F,), jnp.int32), place((F,), jnp.int32),
+        place((F, PARALLEL_CTX // PARALLEL_PAGE), jnp.int32),
+        place((F,), jnp.int32), use_kernel=True,
+    ).compile()
+    text = compiled.as_text()
+    assert "paged_attn_fill" in text and "ssm_state_rows" in text
+    conv_dims = "[" + ",".join(str(d) for d in conv.shape) + "]"
+    carried = [
+        line for line in text.splitlines()
+        if " while(" in line and conv_dims in line
+    ]
+    assert carried == [], carried[0][:200]
+    total = _assert_state_and_pool_stay_put(compiled, pools[0], ssm)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 12.9e9 < total, total
+    print(f"parallel fill F={F} C={C}: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
+
+
+def test_parallel_decode_program_updates_pages_and_state_in_place(
+    one_chip, monkeypatch
+):
+    """``hybrid_decode_chunk`` whole (64 rows): ``paged_attn_decode`` at 5
+    query heads a KV head AND ``ssm_state_update`` with two groups in every
+    layer by name; no copy of the pools or of the state; the conv tails
+    stay in HBM.  What the compiler DOES copy, once a chunk and outside
+    the step loop, is four weight stacks into the layout its loop wants:
+    the Mamba-2 in-projection ``[8, 5120, 9248]`` (757 MB: 9,248 columns
+    are 72.25 lane tiles, and the stack arrives with 5,120 minor), the
+    queries' ``[8, 5120, 2560]`` (210 MB) and the keys' and values' ``[8,
+    5120, 512]`` (42 MB each): 1.05 GB of temporaries and 3.1 ms of a
+    chunk of 8 steps on the chip (``copy.125`` - ``copy.128`` of the traced
+    run: 1.5% of busy time, my chip run, PR 46; PERF.md section 7)."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    cfg, params, pools, ssm, conv, place = _parallel_cell_args(one_chip)
+
+    def rows(dtype):
+        return place((PARALLEL_ROWS,), dtype)
+
+    compiled = hybrid.hybrid_decode_chunk.lower(
+        params, *pools, ssm, conv, cfg,
+        place((PARALLEL_ROWS, PARALLEL_CTX // PARALLEL_PAGE), jnp.int32),
+        rows(jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.int32),
+        place((2,), jnp.uint32), chunk_size=PARALLEL_CHUNK,
+        sample_fn=_keyed_greedy, stop_fn=_never_stop, use_kernel=True,
+        max_len=PARALLEL_CTX, row_seeds=rows(jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "ssm_state_update" in text and "paged_attn_decode" in text
+    conv_dims = ",".join(str(d) for d in conv.shape)
+    assert not re.search(
+        r"\[" + conv_dims + r"\]\{[^}]*S\(1\)\}", text
+    ), "the conv state in the compiler's fast memory"
+    assert _pool_copies(compiled, ssm.shape) == []
+    assert _pool_copies(compiled, pools[0].shape) == []
+    m = compiled.memory_analysis()
+    temp = m.temp_size_in_bytes
+    # the four weight stacks above and nothing of their size beside them
+    assert temp < 1.10e9, temp
+    total = (
+        m.argument_size_in_bytes + m.output_size_in_bytes + temp
+        - m.alias_size_in_bytes
+    )
+    assert 12.9e9 < total < 14.2e9, total
+    print(f"parallel decode: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
+
+
+def test_grouped_state_kernel_compiles_at_the_cells_state(one_chip):
+    """``ssm_state_update`` with two B/C groups over ``[8, 64, 256,
+    4096]``: a tile of ``[256, 2048]`` float32 (2 MiB: 8 MiB of VMEM in and
+    out, double-buffered), one lane block a group."""
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    from areal_tpu.ops import ssm as ssm_ops
+
+    S, N, HP, G = 64, 256, 4096, 2
+    compiled = ssm_ops.ssm_state_update.lower(
+        place((8, S, N, HP), jnp.float32), place((), jnp.int32),
+        place((S, HP), jnp.float32), place((S, HP), jnp.float32),
+        place((S, G, N), jnp.float32), place((S, G, N), jnp.float32),
+        place((S,), jnp.bool_),
+    ).compile()
+    assert "ssm_state_update" in compiled.as_text()
+    assert _pool_copies(compiled, (8, S, N, HP)) == []
+
+
 # --- the trainer's step whole: what the layout rule may ask of one chip ---
 
 
