@@ -71,6 +71,7 @@ from areal_tpu.engine import spec_decode
 from areal_tpu.engine.batching import bucket_len, spec_window_bucket
 from areal_tpu.engine.kv_pages import (  # noqa: F401 - callers name it here
     GONE,
+    KeptFills,
     PagePool,
     StatefulModelUnsupported,
     kinds_held,
@@ -115,6 +116,23 @@ def _sample_rows(
         mesh=mesh,
     )
     return tok, logp
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _keep_logits_row(kept: jax.Array, logits: jax.Array, row, slot):
+    """Row ``row`` of a fill's last logits ``[F, V]`` into row ``slot`` of
+    the kept fills' ``[slots, V]`` float32 (exact from any logits dtype:
+    ``_sample_rows`` samples in float32), in place.  One program a fill
+    batch's ``F``: ``row`` and ``slot`` are numbers on the device."""
+    return kept.at[slot].set(logits[row].astype(kept.dtype))
+
+
+#: late siblings that join kept fills in ONE engine step (the next waits a
+#: step): what a distribution copies, samples and activates then stays
+#: among the padded counts (powers of two up to 8) that one fill's eight
+#: samples meet
+LATE_JOINS_A_STEP = 8
+
 
 logger = logging_.getLogger("inference_server")
 
@@ -229,6 +247,14 @@ class _Fill:
     #: tokens whose pages this fill took from the prefix cache (what the
     #: fill that wrote them handed out), or None where nobody kept it
     routed_reused: Optional[np.ndarray] = None
+    #: a stateful stack KEEPS an ended fill for its prompt's late siblings
+    #: (``kv_pages.KeptFills``): the snapshot slot that holds the prompt's
+    #: end state (the row of the kept logits too), -1 while it fills and
+    #: once it is let go; ``blocks`` / ``wblocks`` are then the kept
+    #: references, ``targets`` the late siblings joining in this step
+    snap: int = -1
+    #: ``keep_routed_experts``: a kept fill's routing ``[tokens, L, K]``
+    routing: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass
@@ -637,11 +663,18 @@ class ContinuousBatchingEngine:
         if self._by_kind:
             self.paged = True
         #: sibling copies of a fill's end state; fills built for a prompt
-        #: that a live row already carries (a late sibling: the state at
-        #: the prompt's end was never kept, so it prefills again); (token,
-        #: k) pairs decode chunks routed to held experts and to absent ones
+        #: that a live row already carries (a late sibling whose prompt's
+        #: kept fill was let go before it came, so it prefills again);
+        #: (token, k) pairs decode chunks routed to held experts and to
+        #: absent ones
         self.state_copies_total = 0
         self.state_reprefills_total = 0
+        #: the ended fills kept for their prompts' late siblings (none but
+        #: for a stateful stack: a stateless one reuses prefixes of any
+        #: length through the radix cache), and those that requests
+        #: admitted in this step are joining
+        self._kept = KeptFills(0, [])
+        self._joining: List[_Fill] = []
         self.moe_pairs_held_total = 0
         self.moe_pairs_routed_total = 0
         self.moe_groups_hit_total = 0
@@ -899,6 +932,10 @@ class ContinuousBatchingEngine:
             # draw is position-keyed off _sample_base_rng), kept only so
             # external probes of engine state keep working
             self.rng = jax.random.PRNGKey(seed)
+        if self._stateful:
+            # (outside ``default_device``: a program's cache key holds the
+            # context it was first called in, and the steps run outside)
+            self._warm_kept_fill_programs()
 
         # flight recorder: per-request lifecycle events (admit/resume/
         # fill/chunk/park/preempt/recompute) under the request's trace
@@ -1164,6 +1201,26 @@ class ContinuousBatchingEngine:
                 self.ssm_state, self.conv_state = jax.device_put(
                     (self.ssm_state, self.conv_state), self.device
                 )
+        if self._stateful:
+            # the snapshot slots of the fills kept for late siblings, in
+            # arrays of their own (the step programs see the rows' arrays
+            # at the shape they had, and no decode step reads a slot that
+            # no row owns), and the kept fills' last logits rows
+            n_snap = max(1, max_batch // 8)
+            self._kept = KeptFills(n_snap, self._pools)
+            self.snap_ssm, self.snap_conv = hybrid.state_zeros(cfg, n_snap)
+            self._kept_logits = jnp.zeros(
+                (n_snap, cfg.vocab_size), jnp.float32
+            )
+            pool_b += hybrid.state_layout_bytes(cfg, n_snap)
+            pool_b += int(self._kept_logits.nbytes)
+            if commit:
+                self.snap_ssm, self.snap_conv, self._kept_logits = (
+                    jax.device_put(
+                        (self.snap_ssm, self.snap_conv, self._kept_logits),
+                        self.device,
+                    )
+                )
         self._led_kv_pool.set(pool_b)
         self._led_kv_scales.set(scale_b)
         self.kv_lengths = jnp.zeros((max_batch,), jnp.int32)
@@ -1174,8 +1231,9 @@ class ContinuousBatchingEngine:
         # incref/decref, so its evictions can never recycle a block a
         # live row still pins)
         # (none for a stateful model: cached pages hold a prefix's KV and
-        # not the recurrent state at its end, so a match could skip
-        # nothing until state snapshots at page boundaries exist)
+        # not the recurrent state at its end, so a match inside a prompt
+        # could skip nothing; the one point where every fill's state is
+        # saved is its END, and ``KeptFills`` reuses that)
         if self._prefix_cache_enabled and not self._stateful:
             host_bytes = self._prefix_cache_host_bytes
             if host_bytes > 0 and jax.process_count() > 1:
@@ -1384,6 +1442,86 @@ class ContinuousBatchingEngine:
             return quantize.quantize_param_tree(params)
         return params
 
+    # -- the fills kept for late siblings (engine/kv_pages.KeptFills) --------
+
+    def _slot_pairs(self, src: List[int], dst: List[int]):
+        """``(src, dst, n)`` of ``hybrid.copy_state_slots_between``: one
+        length whatever the count, so one program a direction."""
+        pairs = np.zeros((2, self.max_batch), np.int32)
+        pairs[0, : len(src)], pairs[1, : len(dst)] = src, dst
+        return jnp.asarray(pairs[0]), jnp.asarray(pairs[1]), jnp.int32(len(src))
+
+    def _warm_kept_fill_programs(self):
+        """Build the small programs that keep a fill and hand it to late
+        siblings, when the engine starts: no round of a warm-up meets
+        them in every shape (WHICH fill batch ends a prompt, and whether a
+        sibling comes late, is the schedule's), and a program first met
+        under load is compiled there.  Nothing is copied (no pairs) but a
+        row of zeros onto zeros."""
+        none = self._slot_pairs([], [])
+        self.snap_ssm, self.snap_conv = hybrid.copy_state_slots_between(
+            self.ssm_state, self.conv_state, self.snap_ssm, self.snap_conv,
+            *none,
+        )
+        self.ssm_state, self.conv_state = hybrid.copy_state_slots_between(
+            self.snap_ssm, self.snap_conv, self.ssm_state, self.conv_state,
+            *none,
+        )
+        zero = jnp.int32(0)
+        f_pad = 1
+        while f_pad < 2 * self.max_batch:  # every F_pad a fill batch can have
+            logits = np.zeros(
+                (f_pad, self.cfg.vocab_size), jnp.dtype(self.cfg.logits_dtype)
+            )
+            # (committed where a fill program's output is)
+            logits = jax.device_put(logits, self.device)
+            self._kept_logits = _keep_logits_row(
+                self._kept_logits, logits, zero, zero
+            )
+            f_pad *= 2
+        ids = jnp.asarray(np.zeros((LATE_JOINS_A_STEP,), np.int32))
+        _sample_rows(
+            self._kept_logits, ids, ids, ids, self._sample_base_rng,
+            self.sampling, mesh=self.mesh,
+        )
+
+    def _keep_fills(self, fills: List[_Fill], idxs, logits):
+        """Keep the fills that just ended (``_share_fill_blocks`` has
+        handed their pages out): the end state from the fill's slot to a
+        snapshot slot, the last logits row, a reference on every page."""
+        src, dst = [], []
+        for f, li in zip(fills, idxs):
+            if f.targets[0].resume is not None:
+                continue  # a preempted row's sequence: no request brings it
+            if not self._kept.keep(f):
+                continue
+            src.append(f.state_slot)
+            dst.append(f.snap)
+            self._kept_logits = _keep_logits_row(
+                self._kept_logits, logits, jnp.int32(li), jnp.int32(f.snap)
+            )
+        if src:
+            self.snap_ssm, self.snap_conv = hybrid.copy_state_slots_between(
+                self.ssm_state, self.conv_state, self.snap_ssm,
+                self.snap_conv, *self._slot_pairs(src, dst),
+            )
+
+    @property
+    def state_late_joins_total(self) -> int:
+        """Requests served from a kept fill: no fill program ran for them."""
+        return self._kept.late_joins_total
+
+    @property
+    def state_fills_kept_total(self) -> int:
+        return self._kept.kept_total
+
+    @property
+    def state_fills_evicted(self) -> Dict[str, int]:
+        """Kept fills let go, by cause: ``slots`` (a newer fill took the
+        snapshot slot), ``pages`` (a live row needed a page), ``swap``
+        (computed under the weights before a swap)."""
+        return dict(self._kept.evicted)
+
     def _set_fill_row(self, row_id: int, fill: _Fill):
         """A fill's canonical pages live in its first target's tables
         (the same lists: what the fill releases behind itself, the row
@@ -1424,8 +1562,12 @@ class ContinuousBatchingEngine:
         the cache always yields to live rows; with the host tier on,
         "yield" means spill, not die), else the longest parked row, else
         (``preempt_but`` given: the row that must stay, or -1) the
-        youngest decoding row.  Returns what went: "cache", "parked",
-        "preempted" or None."""
+        youngest decoding row.  A stateful engine has its kept fills where
+        the cache is (``KeptFills``: the same insurance, one a step).
+        Returns what went: "kept", "cache", "parked", "preempted" or
+        None."""
+        if self._kept.evict("pages"):
+            return "kept"
         if self._prefix_cache is not None and self._prefix_cache.evict(
             cache_blocks, protect_step=protect_step
         ):
@@ -3177,6 +3319,10 @@ class ContinuousBatchingEngine:
             # KV is rejected.
             if self._prefix_cache is not None:
                 self._prefix_cache.flush(new_version=self.version)
+            # (the kept fills likewise: state, pages and logits computed
+            # under the old weights must not meet a row of the new version)
+            while self._kept.evict("swap"):
+                pass
             # streamed-handoff state is version-bound on BOTH sides:
             # export streams restart with their fills below (segments
             # re-emit from block 0 under the new version; the abort
@@ -3451,11 +3597,13 @@ class ContinuousBatchingEngine:
             tokens=sum(take for _, take in batch),
         )
         if self._stateful:
-            # running totals: sibling copies of a fill's end state, and
-            # requests that matched cached pages and prefilled from 0
+            # running totals: sibling copies of a fill's end state, late
+            # siblings that prefilled their prompt again, and those that
+            # joined its kept fill (KeptFills.counts)
             counts.update(
                 state_copies=self.state_copies_total,
                 state_reprefills=self.state_reprefills_total,
+                **self._kept.counts(),
             )
         grouped = False
         if self._by_kind and self.cfg.n_experts:
@@ -3604,7 +3752,17 @@ class ContinuousBatchingEngine:
         async jit dispatch chaining on the donated pool, so a 16k prompt
         issues its 16 chunks with no host round-trip between them
         instead of paying one engine-step (admit/harvest bookkeeping +
-        fetch) per chunk."""
+        fetch) per chunk.
+
+        First the late siblings that this step's admission found a kept
+        fill for: one distribution for all of them, by the code that
+        serves siblings queued on a fill in time, from the snapshot slots
+        and the kept logits rows where those read the fill's own."""
+        if self._joining:
+            joining, self._joining = self._joining, []
+            self._distribute_fills(
+                joining, [f.snap for f in joining], self._kept_logits
+            )
         while self._filling:
             completed, idxs, logits = self._run_fill_batch(
                 self._filling, self.prefill_chunk_tokens
@@ -3648,6 +3806,8 @@ class ContinuousBatchingEngine:
             for f in fills:
                 self._hand_out_routing(f)
             self._activate_filled_rows(sample_targets, toks, logps, activation)
+            for f in fills:
+                f.targets = []  # (a kept fill's next are its late siblings)
 
     def _hand_out_routing(self, f: _Fill):
         """``keep_routed_experts``: a completed fill's routing to every
@@ -3655,24 +3815,28 @@ class ContinuousBatchingEngine:
         else), as ``[tokens, L, K]`` pieces on the host.  Its program has
         run by now (its first tokens were fetched) and the copy started
         at dispatch, so nothing waits here and the device arrays go."""
-        if not f.routed:
+        if f.routed:
+            pieces = [
+                np.asarray(r)[:, i, :take].swapaxes(0, 1).astype(np.int16)
+                for r, i, take in f.routed
+            ]
+            f.routed = []
+            n_reused = len(f.tokens) - sum(len(p) for p in pieces)
+            if n_reused:
+                if f.routed_reused is None or len(f.routed_reused) != n_reused:
+                    return  # pages of a prompt whose routing nobody kept
+                pieces.insert(0, f.routed_reused)
+            f.routing = np.concatenate(pieces)
+            # (a recurrent state rules page reuse out: the routing stays
+            # with the fill, for as long as that is kept)
+            if not self._stateful:
+                self._routed_prompts.pop(f.key, None)
+                while len(self._routed_prompts) >= self._keep_routed:
+                    del self._routed_prompts[next(iter(self._routed_prompts))]
+                self._routed_prompts[f.key] = f.routing
+        routing = f.routing
+        if routing is None:
             return
-        pieces = [
-            np.asarray(r)[:, i, :take].swapaxes(0, 1).astype(np.int16)
-            for r, i, take in f.routed
-        ]
-        f.routed = []
-        n_reused = len(f.tokens) - sum(len(p) for p in pieces)
-        if n_reused:
-            if f.routed_reused is None or len(f.routed_reused) != n_reused:
-                return  # pages of a prompt whose routing nobody kept
-            pieces.insert(0, f.routed_reused)
-        routing = np.concatenate(pieces)
-        if not self._stateful:  # (a recurrent state rules page reuse out)
-            self._routed_prompts.pop(f.key, None)
-            while len(self._routed_prompts) >= self._keep_routed:
-                del self._routed_prompts[next(iter(self._routed_prompts))]
-            self._routed_prompts[f.key] = routing
         for tgt in f.targets:
             row = tgt.resume or self.rows[tgt.row_id]
             if row is not None:
@@ -3681,7 +3845,16 @@ class ContinuousBatchingEngine:
     def _share_fill_blocks(self, fills: List[_Fill], idxs, logits):
         """The part of ``_distribute_fills`` before the fetch.  Returns
         (fresh targets to sample for, rows to activate as they are, the
-        sampled tokens and log-probabilities still on the device)."""
+        sampled tokens and log-probabilities still on the device).
+
+        ``late``: the fills are KEPT ones and their targets the late
+        siblings that join them.  Every target is then a sibling (none
+        owns the kept pages: full pages by reference, the tail by copy,
+        which holds what the fill's first target has appended since, past
+        the prompt, where the joiner writes before anything reads), the
+        end states come from the snapshot slots and ``logits`` is the
+        kept rows'."""
+        late = fills[0].snap >= 0  # (a fill is kept after it is shared)
         copies = [([], []) for _ in self._pools]  # (from, to) a pool
         state_src, state_dst = [], []
         sample_targets: List[Tuple[_Fill, _FillTarget, int]] = []
@@ -3694,13 +3867,16 @@ class ContinuousBatchingEngine:
             # retried or sibling request arriving next step already hits)
             self._cache_insert(f.tokens, f.blocks, f.wblocks)
             for t_i, tgt in enumerate(f.targets):
-                if self._stateful and tgt.row_id != f.state_slot:
+                if late:
+                    state_src.append(f.snap)
+                    state_dst.append(tgt.row_id)
+                elif self._stateful and tgt.row_id != f.state_slot:
                     # the prompt's end state, which the fill left in its
                     # own slot, for a sibling that shares the fill
                     state_src.append(f.state_slot)
                     state_dst.append(tgt.row_id)
                 for (pool, held), (src, dst) in zip(self._pages_of(f), copies):
-                    if t_i == 0:
+                    if t_i == 0 and not late:
                         pool.set_row(tgt.row_id, list(held))
                         continue
                     # the prompt's full pages by reference (in a window
@@ -3740,7 +3916,12 @@ class ContinuousBatchingEngine:
         for pool, (src, dst) in zip(self._pools, copies):
             if src:
                 self._copy_pages(pool, src, dst)
-        if state_src:
+        if late:
+            self.ssm_state, self.conv_state = hybrid.copy_state_slots_between(
+                self.snap_ssm, self.snap_conv, self.ssm_state,
+                self.conv_state, *self._slot_pairs(state_src, state_dst),
+            )
+        elif state_src:
             n_pad = 1 << (len(state_src) - 1).bit_length()
             src = np.zeros((n_pad,), np.int32)
             dst = np.full((n_pad,), self.max_batch, np.int32)  # pad -> skip
@@ -3751,10 +3932,13 @@ class ContinuousBatchingEngine:
                 jnp.asarray(dst),
             )
             self.state_copies_total += len(state_src)
+        if self._stateful and not late:
+            self._keep_fills(fills, idxs, logits)
         sampled = None
         if sample_targets:
             n = len(sample_targets)
-            n_pad = 1 << (n - 1).bit_length()
+            # (late siblings: the one count that was built at the start)
+            n_pad = LATE_JOINS_A_STEP if late else 1 << (n - 1).bit_length()
             src_idx = np.zeros((n_pad,), np.int32)
             tgt_seeds = np.zeros((n_pad,), np.int32)
             tgt_pos = np.zeros((n_pad,), np.int32)
@@ -3952,6 +4136,17 @@ class ContinuousBatchingEngine:
             fill = next(
                 (f for f in self._filling if f.key == key), None
             )
+            if (
+                fill is None
+                and self._kept.peek(key) is not None
+                and sum(len(f.targets) for f in self._joining)
+                >= LATE_JOINS_A_STEP
+            ):
+                # (a late sibling more than one distribution serves: it
+                # joins its prompt's kept fill at the next step)
+                with self._lock:
+                    self._pending.insert(0, req)
+                break
             if fill is None and self._maybe_pull_prefix(req, prompt):
                 # fleet pull in flight: requeue step-keyed until the
                 # imported prefix lands in the radix cache (or the pull
@@ -3964,6 +4159,13 @@ class ContinuousBatchingEngine:
                 with self._lock:
                     self._pending.insert(0, req)
                 break
+            if fill is None:
+                # a late sibling of a stateful stack's prompt: the fill
+                # has ended and was kept, and is joined like one in flight
+                # (handed out in this step's ``_advance_fill``)
+                fill = self._kept.join(key)
+                if fill is not None and fill not in self._joining:
+                    self._joining.append(fill)
             if fill is None:
                 # radix walk first: a cached prefix (an earlier turn of
                 # this conversation, a retried request, a sibling's
@@ -3992,8 +4194,8 @@ class ContinuousBatchingEngine:
                     shared=False,
                 )
             else:
-                # group member joins the in-flight fill: ZERO extra
-                # prefill work (block-reference prompt sharing)
+                # group member joins the in-flight (or kept) fill: ZERO
+                # extra prefill work (block-reference prompt sharing)
                 self.tracer.event(
                     req.qid, "engine.admit", row=rid,
                     prompt_len=len(prompt), cached_tokens=fill.fill_pos,

@@ -41,7 +41,7 @@ slot is its row's) and have no allocator; they enter here as a row of
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Optional
+from typing import Any, Dict, Iterable, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -232,6 +232,97 @@ class PagePool:
                 return None
             out[i] = w
         return out
+
+
+# -- the fills a stateful engine keeps ----------------------------------------
+
+
+class KeptFills:
+    """The finished fills a stateful engine keeps for their prompts' late
+    siblings, by the prompt's tokens: the host side (which snapshot slot,
+    which pages, who goes first, the counts).  A stack with a recurrent
+    state can reuse a prompt only where its state was saved, and the one
+    point every fill has is its end: the engine copies the end state to a
+    SNAPSHOT slot (arrays of their own on the device, ``n_slots`` slots,
+    the fill's last logits row beside them) and this table holds a
+    reference on the fill's pages in every pool (the tail page too, and
+    in a window pool the prompt's last window: a holder that is no row,
+    so the window rule does not let them go behind the fill's first
+    target).  A request whose prompt equals a kept fill's joins it where
+    it would have prefilled again.
+
+    Least recently joined goes first: when the slots run out, when a live
+    row needs a page (recompute insurance always yields to a live row),
+    and all of them at a weight swap.  A fill with targets (requests that
+    are joining it in this engine step) is never let go.  Deterministic
+    (insertion order, no clock), as the pools are."""
+
+    CAUSES = ("slots", "pages", "swap")
+
+    def __init__(self, n_slots: int, pools: List[PagePool]):
+        self.n_slots = n_slots
+        self._pools = pools
+        self._free = list(range(n_slots - 1, -1, -1))
+        self._fills: Dict[tuple, Any] = {}  # least recently joined first
+        self.kept_total = 0
+        self.late_joins_total = 0
+        self.evicted = dict.fromkeys(self.CAUSES, 0)
+
+    def __len__(self) -> int:
+        return len(self._fills)
+
+    @staticmethod
+    def _pages_of(fill):
+        return (fill.blocks, fill.wblocks)
+
+    def peek(self, key: tuple):
+        return self._fills.get(key)
+
+    def join(self, key: tuple):
+        """The kept fill of prompt ``key`` for one more late sibling (it
+        becomes the most recently joined), or None."""
+        fill = self._fills.pop(key, None)
+        if fill is not None:
+            self._fills[key] = fill
+            self.late_joins_total += 1
+        return fill
+
+    def keep(self, fill) -> bool:
+        """Keep ``fill`` (ended, its pages listed by number in every
+        pool): take a slot, letting the least recently joined go for it,
+        and a reference on every page.  False where no slot can be had."""
+        assert fill.key not in self._fills, "a prompt is kept once"
+        if not self._free and not self.evict("slots"):
+            return False
+        fill.snap = self._free.pop()
+        for pool, held in zip(self._pools, self._pages_of(fill)):
+            pool.incref(held)
+        self._fills[fill.key] = fill
+        self.kept_total += 1
+        return True
+
+    def evict(self, cause: str) -> bool:
+        """Let the least recently joined fill go (slot and pages); False
+        where none can."""
+        fill = next((f for f in self._fills.values() if not f.targets), None)
+        if fill is None:
+            return False
+        del self._fills[fill.key]
+        for pool, held in zip(self._pools, self._pages_of(fill)):
+            pool.free(held)
+        self._free.append(fill.snap)
+        fill.snap = -1
+        self.evicted[cause] += 1
+        return True
+
+    def counts(self) -> Dict[str, int]:
+        """The running totals, as the span ``areal.engine.fill.dispatch``
+        carries them."""
+        return dict(
+            state_late_joins=self.late_joins_total,
+            state_fills_kept=self.kept_total,
+            **{f"state_fills_evicted_{c}": n for c, n in self.evicted.items()},
+        )
 
 
 # -- what a cache kind rules out ---------------------------------------------

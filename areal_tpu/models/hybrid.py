@@ -759,6 +759,36 @@ def copy_state_slots(ssm, conv, src: jax.Array, dst: jax.Array):
     return jax.lax.fori_loop(0, src.shape[0], put, (ssm, conv))
 
 
+@partial(jax.jit, donate_argnums=(2, 3))
+@region("areal.ssm")
+def copy_state_slots_between(
+    ssm_from, conv_from, ssm, conv, src: jax.Array, dst: jax.Array, n
+):
+    """Copy slot ``src[i]`` of ``(ssm_from, conv_from)`` to slot ``dst[i]``
+    of ``(ssm, conv)`` for ``i < n``: between the rows' slots and the
+    snapshot slots that keep a finished fill's end state, which are arrays
+    of their own (the same layout, fewer slots), so that the step programs
+    see the rows' arrays at the shape they had.  As
+    :func:`copy_state_slots`: one slot's pieces at a time, in place, no
+    state-sized temporary; ``n`` is a number on the device, so one program
+    serves every count up to ``src``'s length and copies nothing for the
+    rest."""
+    Lm, _, N, HP = ssm.shape
+    Km1, cd = conv.shape[1], conv.shape[3]
+
+    def put(i, st):
+        ssm, conv = st
+        s = src[i]
+        a = jax.lax.dynamic_slice(ssm_from, (0, s, 0, 0), (Lm, 1, N, HP))
+        b = jax.lax.dynamic_slice(conv_from, (0, 0, s, 0), (Lm, Km1, 1, cd))
+        return (
+            jax.lax.dynamic_update_slice(ssm, a, (0, dst[i], 0, 0)),
+            jax.lax.dynamic_update_slice(conv, b, (0, 0, dst[i], 0)),
+        )
+
+    return jax.lax.fori_loop(0, n, put, (ssm, conv))
+
+
 # ---------------------------------------------------------------------------
 # the Mamba-2 mixer
 # ---------------------------------------------------------------------------

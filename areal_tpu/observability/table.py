@@ -1135,9 +1135,18 @@ TRACE_TABLE = [
         "phase",
         "One batched prefill chunk built on the host and dispatched "
         "(counts: prompts, f_pad, c, tokens; for a model with recurrent "
-        "state also the running totals state_copies = sibling copies of "
-        "a fill's end state, state_reprefills = requests that matched "
-        "cached pages and prefilled from 0 all the same; for a model "
+        "state also the running totals state_copies = copies of a "
+        "fill's end state to the siblings queued on it, "
+        "state_late_joins = requests admitted after their prompt's fill "
+        "had ended that joined the KEPT fill (end state in a snapshot "
+        "slot, pages by reference, last logits row: no fill program ran "
+        "for them), state_reprefills = those whose prompt a live row "
+        "carries and whose kept fill was gone, so that they prefilled "
+        "it again (state_late_joins / (state_late_joins + "
+        "state_reprefills) is the kept fills' hit share), "
+        "state_fills_kept, and state_fills_evicted_slots / _pages / "
+        "_swap = kept fills let go for a newer fill's snapshot slot, "
+        "for a live row's page, at a weight swap; for a model "
         "that holds a share of the experts the running totals "
         "moe_fill_tokens, moe_fill_tokens_grouped = those in a batch "
         "whose shape takes the grouped product, moe_fill_extra_rounds = "

@@ -1744,6 +1744,15 @@ class GenerationServerWorker(worker_base.Worker):
                     eng.fill_tail_layers,
                     eng.fill_tail_positions_saved_total,
                 )
+            if eng._stateful:
+                self.logger.info(
+                    "kept fills: state_late_joins=%d, state_reprefills=%d, "
+                    "state_fills_kept=%d, state_fills_evicted=%s",
+                    eng.state_late_joins_total,
+                    eng.state_reprefills_total,
+                    eng.state_fills_kept_total,
+                    eng.state_fills_evicted,
+                )
             # releases the ledger attributions (and logs the leak audit:
             # a quiesced server returns the process ledger to baseline)
             eng.close()

@@ -60,6 +60,8 @@ def assert_nothing_leaked(eng):
         if eng.rows[row_id] is not None:
             eng._release_row(row_id)
     assert eng._prefix_cache is None  # a recurrent state rules it out
+    while eng._kept.evict("pages"):  # (and what was kept for late siblings)
+        pass
     assert eng.free_pool_blocks == eng.n_blocks
     assert eng.state_slots_live == 0
 
@@ -82,7 +84,9 @@ def test_siblings_get_the_tail_page_and_the_state_and_a_late_one_prefills_again(
     tail of 5): two are admitted together (one fill; the second takes the
     full pages by reference, a copy of the tail page and a copy of every
     layer's state and conv tail), the third arrives when they decode and
-    prefills the prompt again.  A second prompt runs beside."""
+    prefills the prompt again (the one snapshot slot of an engine of four
+    rows went to the second prompt's fill, which ended later).  A second
+    prompt runs beside."""
     eng = make_engine(model)
     p1, p2 = _prompts(1, 37, 21)
     eng.submit(_req("a0", p1, 22))

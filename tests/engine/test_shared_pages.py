@@ -58,6 +58,8 @@ def assert_nothing_leaked(eng):
         if eng.rows[row_id] is not None:
             eng._release_row(row_id)
     assert eng._prefix_cache is None  # a recurrent state rules it out
+    while eng._kept.evict("pages"):  # (and what was kept for late siblings)
+        pass
     assert eng._win.free_blocks == eng._win.n_blocks
     assert eng.free_pool_blocks == eng.n_blocks
     assert eng.state_slots_live == 0
@@ -81,7 +83,10 @@ def test_siblings_share_pages_and_copy_states_and_a_late_one_prefills_again(mode
     (one fill; the second takes the full pages by reference, a copy of the
     tail page of each pool and a copy of every Mamba layer's state and conv
     tail), the third arrives when they decode and prefills the prompt
-    again (no state was kept at its end).  A second prompt runs beside."""
+    again: an engine of four rows has ONE snapshot slot, and the second
+    prompt's fill, which ended later, took it (tests/engine/
+    test_kept_fills.py has the late sibling that joins).  A second prompt
+    runs beside."""
     eng = make_engine(model)
     p1, p2 = _prompts(1, 37, 21)
     eng.submit(_req("a0", p1, 22))

@@ -115,7 +115,8 @@ def test_a_reused_slot_starts_from_zero_and_a_late_sibling_reprefills(model):
         while eng.try_get_result("b0") is None:
             eng.step()
     # b0's slot is free and dirty; a0 decodes on.  Its sibling comes late:
-    # the prompt's end state sat in a0's slot and has moved on
+    # the prompt's end state sat in a0's slot and has moved on, and the one
+    # snapshot slot of an engine of two rows went to b0's fill
     assert eng.state_slots_live == 1
     assert all(float(abs(np.asarray(eng.ssm_state[:, i])).max()) > 0 for i in (0, 1))
     eng.submit(_req("a1", p1, 5))

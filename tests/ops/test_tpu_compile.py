@@ -1372,3 +1372,55 @@ def test_train_step_at_the_train_cells_largest_shape_fits_one_chip(
         + m.output_size_in_bytes - m.alias_size_in_bytes
     )
     assert need < 15.75e9, (need / 1e9, plan)
+
+
+# --- the snapshot slots of the fills kept for late siblings ---
+
+#: cell -> (its arguments, the resident bytes the ledger's line of PR 46
+#: gives it as ``hbm_peak_gb.rollout``): what eight snapshot slots and
+#: eight kept logits rows are added to
+KEPT_FILL_CELLS = {
+    "hybrid": (_hybrid_cell_args, 13.33e9),
+    "shared": (_shared_cell_args, 14.30e9),
+    "parallel": (_parallel_cell_args, 13.04e9),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(KEPT_FILL_CELLS))
+def test_snapshot_slots_fit_and_are_copied_a_slot_at_a_time(one_chip, cell):
+    """``copy_state_slots_between`` at a stateful cell's state shapes, both
+    ways (a fill's end state to a snapshot slot; a snapshot to the late
+    siblings' slots): the array written is updated in place, the array
+    read is not copied, the temporaries hold a slot or two and never a
+    state; and ``max_batch // 8`` snapshot slots with as many float32
+    logits rows fit beside what the cell holds."""
+    args, resident = KEPT_FILL_CELLS[cell]
+    cfg, *_, ssm, conv, place = args(one_chip)
+    rows = ssm.shape[1]
+    snap_ssm, snap_conv = (
+        place(a.shape, a.dtype)
+        for a in jax.eval_shape(lambda: hybrid.state_zeros(cfg, rows // 8))
+    )
+    pairs = place((rows,), jnp.int32), place((rows,), jnp.int32)
+    n = place((), jnp.int32)
+    slot_bytes = hybrid.state_layout_bytes(cfg, 1)
+    for frm, to in (
+        ((ssm, conv), (snap_ssm, snap_conv)),
+        ((snap_ssm, snap_conv), (ssm, conv)),
+    ):
+        compiled = hybrid.copy_state_slots_between.lower(
+            *frm, *to, *pairs, n
+        ).compile()
+        for a in (ssm, conv, snap_ssm, snap_conv):
+            assert _pool_copies(compiled, a.shape) == [], a.shape
+        m = compiled.memory_analysis()
+        assert m.temp_size_in_bytes < 2.5 * slot_bytes, (
+            m.temp_size_in_bytes, slot_bytes,
+        )
+        # the written arrays are donated: the outputs are theirs (but
+        # for the tuple that names them)
+        assert m.output_size_in_bytes - m.alias_size_in_bytes < 4096
+    kept = hybrid.state_layout_bytes(cfg, rows // 8)
+    kept += (rows // 8) * cfg.vocab_size * 4
+    print(f"{cell}: a slot {slot_bytes / 1e6:.1f} MB, kept {kept / 1e9:.3f} GB")
+    assert resident + kept < USABLE_HBM_BYTES
