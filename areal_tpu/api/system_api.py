@@ -317,9 +317,11 @@ class GenServerConfig:
     # exceeds a chunk's device time.  1 =
     # unpipelined baseline.
     pipeline_depth: int = 2
-    # keep every layer's routed experts of the last N finished requests
-    # on the engine (``ContinuousBatchingEngine.routed_experts(qid)``):
-    # what a routing-replay trainer or a parity check follows.  Only a
+    # keep every layer's routed experts of the last finished requests
+    # on the engine (``ContinuousBatchingEngine.routed_experts(qid)``),
+    # in the room that N sequences of ``kv_cache_len`` positions take: at
+    # least the last N, and more where they are shorter.  What a
+    # routing-replay trainer or a parity check follows.  Only a
     # stack whose programs hand their routing out takes it (the hybrid
     # one); 0 keeps nothing and fetches nothing
     keep_routed_experts: int = 0

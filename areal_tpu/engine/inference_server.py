@@ -58,7 +58,7 @@ import time
 import uuid
 import zlib
 from collections import deque
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -125,6 +125,70 @@ def _keep_logits_row(kept: jax.Array, logits: jax.Array, row, slot):
     ``_sample_rows`` samples in float32), in place.  One program a fill
     batch's ``F``: ``row`` and ``slot`` are numbers on the device."""
     return kept.at[slot].set(logits[row].astype(kept.dtype))
+
+
+def _sample_base_rng(seed: int) -> jax.Array:
+    return jax.random.fold_in(jax.random.PRNGKey(seed), 1)
+
+
+@lru_cache(maxsize=None)
+def _sample_and_stop_fns(sampling, stop_tokens, seed: int, mesh):
+    """The sampler and the stop rule that a paged decode program is given.
+    The program's jit is cached on THEIR identity, so engines of one
+    sampling, stop tokens, seed and mesh get the same two functions and
+    share their decode programs (a process of many engines, as a test's,
+    compiled one each before: every compiled program costs it memory
+    mappings that nothing gives back)."""
+    base_rng = _sample_base_rng(seed)
+
+    def _sample(logits, _sub, positions, seeds):
+        # position-keyed: the draw for (request seed, position) is a
+        # pure function of the engine seed (see sample_logits_keyed)
+        return sample_logits_keyed(
+            logits, base_rng, seeds, positions, sampling, mesh=mesh
+        )
+
+    def _stop(tok):
+        stop = jnp.zeros_like(tok, dtype=bool)
+        for s in stop_tokens:
+            stop |= tok == s
+        return stop
+
+    return _sample, _stop
+
+
+@partial(jax.jit, static_argnames=("stop_tokens",))
+def _activate_rows(
+    cur_tokens: jax.Array,  # [B] the engine's five row arrays
+    active: jax.Array,
+    budgets: jax.Array,
+    kv_lengths: jax.Array,
+    row_seeds: jax.Array,
+    sampled: jax.Array,  # [n] first tokens, as ``_sample_rows`` left them
+    entries: jax.Array,  # [6, n] int32: the rows, and what the host knows
+    stop_tokens: Tuple[int, ...],
+):
+    """Rows that a distribution hands over start decoding, in ONE program
+    and without the host seeing a token: entry ``i`` is ``(row id, fresh,
+    cur, budget, length, seed)``.  A FRESH target takes ``sampled[i]`` as
+    its pending token and is active unless that token is a stop token or
+    its budget is 0 (the host finds the same when the token reaches it);
+    a resumed row brings the pending token it was preempted with and is
+    active.  A row id past the batch is padding and writes nothing."""
+    ids, fresh, curs, buds, lens, seeds = entries
+    fresh = fresh > 0
+    tok = jnp.where(fresh, sampled, curs)
+    stop = jnp.zeros_like(fresh)
+    for s in stop_tokens:
+        stop |= tok == s
+    alive = ~fresh | (~stop & (buds > 0))
+    return tuple(
+        arr.at[ids].set(val, mode="drop")
+        for arr, val in (
+            (cur_tokens, tok), (active, alive), (budgets, buds),
+            (kv_lengths, lens), (row_seeds, seeds),
+        )
+    )
 
 
 #: late siblings that join kept fills in ONE engine step (the next waits a
@@ -202,6 +266,16 @@ class _Row:
     #: layer's routed experts, one entry a position the model has READ:
     #: the prompt's from its fill, then each decode chunk's emitted steps
     routed: Optional[List[np.ndarray]] = None
+    #: the row decodes and its FIRST token has not reached the host yet
+    #: (``_FirstTokens``): ``generated`` lacks it and ``cur_token`` is not
+    #: known, until the harvest that brings it
+    first_on_its_way: bool = False
+
+    @property
+    def n_tokens(self) -> int:
+        """Prompt and generated tokens, a first token on its way among
+        them: one more than the positions the row has cached."""
+        return len(self.prompt) + len(self.generated) + self.first_on_its_way
 
 
 @dataclasses.dataclass
@@ -243,10 +317,6 @@ class _Fill:
     routed: List[Tuple[Any, int, int]] = dataclasses.field(
         default_factory=list
     )
-    #: ``keep_routed_experts``: the routing ``[n, L, K]`` behind the ``n``
-    #: tokens whose pages this fill took from the prefix cache (what the
-    #: fill that wrote them handed out), or None where nobody kept it
-    routed_reused: Optional[np.ndarray] = None
     #: a stateful stack KEEPS an ended fill for its prompt's late siblings
     #: (``kv_pages.KeptFills``): the snapshot slot that holds the prompt's
     #: end state (the row of the kept logits too), -1 while it fills and
@@ -255,6 +325,21 @@ class _Fill:
     snap: int = -1
     #: ``keep_routed_experts``: a kept fill's routing ``[tokens, L, K]``
     routing: Optional[np.ndarray] = None
+
+
+@dataclasses.dataclass
+class _FirstTokens:
+    """The first tokens of ONE distribution (fills that ended, or late
+    siblings joining kept ones), sampled on the device and handed to
+    their rows there (``_activate_rows``): what the host still owes those
+    rows once the tokens reach it.  ``arrays`` are ``_sample_rows``'
+    ``(tokens, logps)`` with their copy to the host started, entry ``i``
+    for ``targets[i]``; ``fills`` names each fill with the targets it had
+    then (its routing goes to them)."""
+
+    targets: List[Tuple[_FillTarget, _Row]]
+    arrays: Optional[Tuple[Any, Any]]
+    fills: List[Tuple[_Fill, List[_FillTarget]]]
 
 
 @dataclasses.dataclass
@@ -279,6 +364,9 @@ class _InflightChunk:
     arrs: Tuple[Any, ...]
     snapshot: List[Tuple[int, int]]
     spec_meta: Optional[Dict[int, Tuple[str, int]]] = None
+    #: first tokens of the rows that this chunk is the first to decode:
+    #: folded at its harvest, before its own outputs are waited for
+    first_tokens: List[_FirstTokens] = dataclasses.field(default_factory=list)
 
 
 @partial(
@@ -618,10 +706,11 @@ class ContinuousBatchingEngine:
         # are reused, rows park.)
         self._by_kind = cfg.is_hybrid  # models/hybrid.py runs the stack
         self._stateful = cfg.n_mamba_layers > 0
-        # the routing of the last ``keep_routed_experts`` finished
-        # requests (:meth:`routed_experts`): what a routing-replay trainer
-        # or a parity check follows.  The hybrid stack's programs hand it
-        # out; nothing else does yet
+        # the routing of the last finished requests, at least
+        # ``keep_routed_experts`` of them (:meth:`routed_experts`,
+        # ``_keep_routing``): what a routing-replay trainer or a parity
+        # check follows.  The hybrid stack's programs hand it out; nothing
+        # else does yet
         if keep_routed_experts and not (self._by_kind and cfg.n_experts):
             raise ValueError(
                 "keep_routed_experts: only the hybrid stack's programs "
@@ -643,6 +732,7 @@ class ContinuousBatchingEngine:
         self.prefix_refused_window = 0
         self._keep_routed = int(keep_routed_experts)
         self._routed_done: Dict[str, np.ndarray] = {}  # oldest first
+        self._routed_done_positions = 0  # (what they hold: ``_keep_routing``)
         #: the routing of the last prompts filled, by their tokens: a later
         #: request that reuses such a prompt's cached pages prefills its
         #: tail alone and takes the rest of its routing from here
@@ -901,9 +991,8 @@ class ContinuousBatchingEngine:
             # on (request seed, position) from it, so streams are
             # invariant to
             # chunking / pipeline depth / speculative acceptance length
-            self._sample_base_rng = jax.random.fold_in(
-                jax.random.PRNGKey(seed), 1
-            )
+            self._seed = seed
+            self._sample_base_rng = _sample_base_rng(seed)
             if self.paged:
                 self._init_paged_state(
                     page_size, kv_pool_tokens, prefill_chunk_tokens
@@ -932,9 +1021,17 @@ class ContinuousBatchingEngine:
             # draw is position-keyed off _sample_base_rng), kept only so
             # external probes of engine state keep working
             self.rng = jax.random.PRNGKey(seed)
-        if self._stateful:
+        #: first tokens that reached their rows on the device before the
+        #: host (folded at a later harvest), and those it fetched at once
+        self.first_tokens_deferred_total = 0
+        self.first_tokens_blocking_total = 0
+        # distributions whose first tokens no dispatched chunk has taken up
+        self._first_tokens: List[_FirstTokens] = []
+        if self.paged:
             # (outside ``default_device``: a program's cache key holds the
             # context it was first called in, and the steps run outside)
+            self._warm_activation()
+        if self._stateful:
             self._warm_kept_fill_programs()
 
         # flight recorder: per-request lifecycle events (admit/resume/
@@ -1283,28 +1380,9 @@ class ContinuousBatchingEngine:
                     else "off"
                 ),
             )
-        # stable closures: paged_decode_chunk caches its jit on their ids
-        sampling_ref = self.sampling
-        stop_ref = self.stop_tokens
-        base_rng_ref = self._sample_base_rng
-        mesh_ref = self.mesh
-
-        def _sample(logits, _sub, positions, seeds):
-            # position-keyed: the draw for (request seed, position) is a
-            # pure function of the engine seed (see sample_logits_keyed)
-            return sample_logits_keyed(
-                logits, base_rng_ref, seeds, positions, sampling_ref,
-                mesh=mesh_ref,
-            )
-
-        def _stop(tok):
-            stop = jnp.zeros_like(tok, dtype=bool)
-            for s in stop_ref:
-                stop |= tok == s
-            return stop
-
-        self._paged_sample_fn = _sample
-        self._paged_stop_fn = _stop
+        self._paged_sample_fn, self._paged_stop_fn = _sample_and_stop_fns(
+            self.sampling, self.stop_tokens, self._seed, self.mesh
+        )
 
     # -- quantized KV storage helpers ---------------------------------------
 
@@ -1450,6 +1528,39 @@ class ContinuousBatchingEngine:
         pairs = np.zeros((2, self.max_batch), np.int32)
         pairs[0, : len(src)], pairs[1, : len(dst)] = src, dst
         return jnp.asarray(pairs[0]), jnp.asarray(pairs[1]), jnp.int32(len(src))
+
+    def _row_arrays(self):
+        return (
+            self.cur_tokens, self.active, self.budgets, self.kv_lengths,
+            self.row_seeds,
+        )
+
+    def _on_device(self, x):
+        """COMMITTED where a step program's small outputs are (the
+        engine's device; every device of its mesh, whole): a program's
+        cache key holds each argument's placement."""
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as P
+
+            return jax.device_put(x, NamedSharding(self.mesh, P()))
+        return jnp.asarray(x) if self.device is None else jax.device_put(
+            x, self.device
+        )
+
+    def _warm_activation(self):
+        """Build ``_activate_rows`` at every padded count a distribution
+        can have, when the engine starts: which counts a warm-up's rounds
+        meet is the schedule's, and a program first met under load is
+        compiled there.  Every entry is padding: nothing is written.  The
+        rows' arrays are committed from here on, like the outputs that
+        take their place."""
+        (self.cur_tokens, self.active, self.budgets, self.kv_lengths,
+         self.row_seeds) = map(self._on_device, self._row_arrays())
+        top = (self.max_batch - 1).bit_length()  # (2**top >= max_batch)
+        counts = {LATE_JOINS_A_STEP} | {1 << k for k in range(top + 1)}
+        for n in sorted(counts):
+            self._start_rows(self._on_device(np.zeros((n,), np.int32)), [])
 
     def _warm_kept_fill_programs(self):
         """Build the small programs that keep a fill and hand it to late
@@ -1849,20 +1960,12 @@ class ContinuousBatchingEngine:
                 self._copy_pages(pool, [h[-1]], [o[0]])
                 pool.free([h.pop()])  # copy taken: unpin
         pages = [h + o for h, o in zip(held, own)] + [None]
-        key, reused = tuple(seq), None
-        if self._keep_routed and m.n_tokens:
-            # pages reused from the cache hold KV that an earlier fill of
-            # this prompt computed, under the routing it handed out
-            reused = self._routed_prompts.get(key)
-            if reused is not None:
-                reused = reused[: m.n_tokens]
         return _Fill(
-            key=key,
+            key=tuple(seq),
             tokens=list(seq),
             blocks=pages[0],
             targets=[],
             fill_pos=m.n_tokens,
-            routed_reused=reused,
             wblocks=pages[1],
         )
 
@@ -3595,6 +3698,10 @@ class ContinuousBatchingEngine:
         counts = dict(
             prompts=len(batch), f_pad=F_pad, c=C,
             tokens=sum(take for _, take in batch),
+            # running totals: first tokens that reached their rows on the
+            # device first, and those the host fetched at once
+            first_tokens_deferred=self.first_tokens_deferred_total,
+            first_tokens_blocking=self.first_tokens_blocking_total,
         )
         if self._stateful:
             # running totals: sibling copies of a fill's end state, late
@@ -3784,37 +3891,116 @@ class ContinuousBatchingEngine:
         first token from the shared final logits; preempted targets
         restore their saved decode state with zero sampling.
 
-        Three spans one after the other, not nested: a profiler session
-        that starts inside the fetch loses every span open around it, and
-        what follows the fetch is where the device waits for the host."""
+        The first tokens stay on the device: ``_activate_rows`` hands them
+        to their rows there, and the host learns them at the harvest of
+        the next chunk dispatched (``_fold_first_tokens``), so nothing
+        here waits for the fill program.  Fetched at once only where the
+        token is needed at once (``_first_tokens_at_once``)."""
         with self._phases.phase("areal.engine.fill.activate"):
-            sample_targets, activation, sampled = self._share_fill_blocks(
+            targets, resumed, sampled = self._share_fill_blocks(
                 fills, idxs, logits
             )
+            activated = self._activate_filled_rows(targets, resumed, sampled)
+            self._first_tokens.append(
+                _FirstTokens(
+                    targets=activated, arrays=sampled,
+                    fills=[(f, f.targets) for f in fills],
+                )
+            )
+            for f in fills:
+                f.targets = []  # (a kept fill's next are its late siblings)
+        if self._first_tokens_at_once(targets):
+            self.first_tokens_blocking_total += len(targets)
+            self._settle_first_tokens()
+        else:
+            self.first_tokens_deferred_total += len(targets)
+
+    def _first_tokens_at_once(self, targets) -> bool:
+        """Whether a distribution's first tokens are fetched before
+        anything else is dispatched: speculation drafts from a row's
+        tokens at its first dispatch, and a request that is handed off
+        parks (and is exported) on its first token."""
+        return self._spec is not None or any(
+            (tgt.req.metadata or {}).get("handoff_to") for _, tgt, _ in targets
+        )
+
+    def _settle_first_tokens(self):
+        """Fold every distribution's first tokens that the host has not
+        seen, oldest first (the ring's chunks', then those no chunk has
+        taken up), with a BLOCKING fetch: before anything that reads or
+        rewrites a decoding row's tokens (wherever the ring is drained),
+        and at the end of a step that dispatched nothing to carry them."""
+        records = [r for ch in self._ring for r in ch.first_tokens]
+        records += self._first_tokens
+        for ch in self._ring:
+            ch.first_tokens = []
+        self._first_tokens = []
+        for record in records:
+            self._fold_first_tokens(record)
+
+    def _fold_first_tokens(self, record: _FirstTokens):
+        """A distribution's first tokens have reached the host (or are
+        waited for here, in ``areal.engine.fill.first_token_wait``): each
+        goes to its row's list, its stream and the stop rule.  A row that
+        its first token ended (a stop token, a budget of one) stopped on
+        the device when it was activated; the host ends it here."""
+        n = len(record.targets)
         toks = logps = np.zeros((0,))
-        if sample_targets:
-            n = len(sample_targets)
-            # a BLOCKING fetch: the sampled tokens exist once the fill
-            # program has run, behind every decode chunk queued before it
+        if n:
             with self._phases.phase(
                 "areal.engine.fill.first_token_wait", rows=n
             ):
-                toks = np.asarray(sampled[0])[:n]
-                logps = np.asarray(sampled[1])[:n]
+                toks = np.asarray(record.arrays[0])[:n]
+                logps = np.asarray(record.arrays[1])[:n]
             self.tokens_emitted_total += n
         with self._phases.phase("areal.engine.fill.activate"):
-            for f in fills:
-                self._hand_out_routing(f)
-            self._activate_filled_rows(sample_targets, toks, logps, activation)
-            for f in fills:
-                f.targets = []  # (a kept fill's next are its late siblings)
+            for f, targets in record.fills:
+                self._hand_out_routing(f, targets)
+            t_first = time.monotonic()  # fill's first tokens on host
+            for (tgt, row), tok_i, logp in zip(
+                record.targets, toks.tolist(), logps.tolist()
+            ):
+                assert self.rows[tgt.row_id] is row and row.first_on_its_way
+                row.first_on_its_way = False
+                row.generated = [int(tok_i)]
+                row.logprobs = [float(logp)]
+                self._slo_first_token(row, now=t_first)
+                self._stream_push(row, [int(tok_i)])
+                if tok_i in self.stop_tokens or tgt.max_new <= 1:
+                    row.no_eos = tok_i not in self.stop_tokens
+                    self._finish(tgt.row_id, row, started=False)
+                    self._release_row(tgt.row_id)
+                    if self._handoff_streaming:
+                        # the request ends HERE (EOS / 1-token budget):
+                        # any segments already streamed have no final —
+                        # tell the decode peer to release them
+                        self._abort_handoff_stream(
+                            tgt.req.qid, reason="eos"
+                        )
+                    continue
+                row.cur_token = int(tok_i)
+                if (row.req.metadata or {}).get("handoff_to"):
+                    # prefill-role handoff: park RIGHT AFTER the fill +
+                    # first token instead of decoding — the worker
+                    # exports the parked row's blocks to the decode
+                    # server and the continuation resumes THERE (the
+                    # activation stamped the device-side row length; a
+                    # normal park inherits it from its decode chunks)
+                    row.no_eos = True
+                    self._finish(tgt.row_id, row, park=True)
+                    if self._handoff_streaming:
+                        # streamed mode: the final segment (tail block +
+                        # first token + host state) replaces the
+                        # monolithic export — emitted now, row released
+                        self._emit_final_handoff_segment(tgt.row_id, row)
 
-    def _hand_out_routing(self, f: _Fill):
-        """``keep_routed_experts``: a completed fill's routing to every
-        target (a resumed row's was computed again, with everything
-        else), as ``[tokens, L, K]`` pieces on the host.  Its program has
-        run by now (its first tokens were fetched) and the copy started
-        at dispatch, so nothing waits here and the device arrays go."""
+    def _hand_out_routing(self, f: _Fill, targets: List[_FillTarget]):
+        """``keep_routed_experts``: a completed fill's routing to the
+        ``targets`` it had (a resumed row's was computed again, with
+        everything else), as ``[tokens, L, K]`` pieces on the host.  Its
+        program has run by now (its first tokens have arrived) and the
+        copy started at dispatch, so nothing waits here and the device
+        arrays go."""
         if f.routed:
             pieces = [
                 np.asarray(r)[:, i, :take].swapaxes(0, 1).astype(np.int16)
@@ -3823,9 +4009,15 @@ class ContinuousBatchingEngine:
             f.routed = []
             n_reused = len(f.tokens) - sum(len(p) for p in pieces)
             if n_reused:
-                if f.routed_reused is None or len(f.routed_reused) != n_reused:
+                # pages reused from the cache hold KV that an earlier fill
+                # of this prompt computed, under the routing it handed out
+                # (by now: the distributions' records are folded oldest
+                # first, and that fill's may have been on its way when
+                # this one was admitted)
+                reused = self._routed_prompts.get(f.key, ())[:n_reused]
+                if len(reused) != n_reused:
                     return  # pages of a prompt whose routing nobody kept
-                pieces.insert(0, f.routed_reused)
+                pieces.insert(0, reused)
             f.routing = np.concatenate(pieces)
             # (a recurrent state rules page reuse out: the routing stays
             # with the fill, for as long as that is kept)
@@ -3837,15 +4029,16 @@ class ContinuousBatchingEngine:
         routing = f.routing
         if routing is None:
             return
-        for tgt in f.targets:
+        for tgt in targets:
             row = tgt.resume or self.rows[tgt.row_id]
             if row is not None:
                 row.routed = [routing]
 
     def _share_fill_blocks(self, fills: List[_Fill], idxs, logits):
-        """The part of ``_distribute_fills`` before the fetch.  Returns
-        (fresh targets to sample for, rows to activate as they are, the
-        sampled tokens and log-probabilities still on the device).
+        """Pages, states and the sampler's draws of ``_distribute_fills``.
+        Returns (fresh targets sampled for, resumed rows to activate as
+        they are, the sampled tokens and log-probabilities on the device,
+        their copy to the host started).
 
         ``late``: the fills are KEPT ones and their targets the late
         siblings that join them.  Every target is then a sibling (none
@@ -3936,8 +4129,9 @@ class ContinuousBatchingEngine:
             self._keep_fills(fills, idxs, logits)
         sampled = None
         if sample_targets:
+            # padded to a power of two (late siblings: to the one count
+            # that was built at the start)
             n = len(sample_targets)
-            # (late siblings: the one count that was built at the start)
             n_pad = LATE_JOINS_A_STEP if late else 1 << (n - 1).bit_length()
             src_idx = np.zeros((n_pad,), np.int32)
             tgt_seeds = np.zeros((n_pad,), np.int32)
@@ -3955,92 +4149,66 @@ class ContinuousBatchingEngine:
                 self.sampling,
                 mesh=self.mesh,
             )
+            # on their way to the host from now: whoever folds them finds
+            # them there once the program has run
+            jax_compat.start_host_copies(sampled)
         return sample_targets, activation, sampled
 
-    def _activate_filled_rows(self, sample_targets, toks, logps, activation):
-        """The part of ``_distribute_fills`` after the fetch: first tokens
-        handed to their rows, rows activated on the device."""
-        if sample_targets:
-            t_first = time.monotonic()  # fill's first tokens on host
-            for (f, tgt, _), tok_i, logp in zip(
-                sample_targets, toks.tolist(), logps.tolist()
-            ):
-                row = self.rows[tgt.row_id]
-                assert row is not None and row.filling
-                row.generated = [int(tok_i)]
-                row.logprobs = [float(logp)]
-                row.filling = False
-                self._slo_first_token(row, now=t_first)
-                self._stream_push(row, [int(tok_i)])
-                plen = len(f.tokens)
-                if tok_i in self.stop_tokens or tgt.max_new <= 1:
-                    row.no_eos = tok_i not in self.stop_tokens
-                    self._finish(tgt.row_id, row, started=False)
-                    self._release_row(tgt.row_id)
-                    if self._handoff_streaming:
-                        # the request ends HERE (EOS / 1-token budget):
-                        # any segments already streamed have no final —
-                        # tell the decode peer to release them
-                        self._abort_handoff_stream(
-                            tgt.req.qid, reason="eos"
-                        )
-                    continue
-                row.cur_token = int(tok_i)
-                row.budget_left = tgt.max_new - 1
-                if (row.req.metadata or {}).get("handoff_to"):
-                    # prefill-role handoff: park RIGHT AFTER the fill +
-                    # first token instead of decoding — the worker
-                    # exports the parked row's blocks to the decode
-                    # server and the continuation resumes THERE.  The
-                    # device-side row length must be stamped here (a
-                    # normal park inherits it from its decode chunks).
-                    row.no_eos = True
-                    self.kv_lengths = self.kv_lengths.at[
-                        np.array([tgt.row_id], np.int32)
-                    ].set(plen)
-                    self._finish(tgt.row_id, row, park=True)
-                    if self._handoff_streaming:
-                        # streamed mode: the final segment (tail block +
-                        # first token + host state) replaces the
-                        # monolithic export — emitted now, row released
-                        self._emit_final_handoff_segment(tgt.row_id, row)
-                    continue
-                self._epoch_counter += 1
-                row.epoch = self._epoch_counter
-                activation.append(
-                    (tgt.row_id, int(tok_i), tgt.max_new - 1, plen, row)
-                )
-        # a resume target activated EARLIER in this loop is the youngest
-        # active row, so a LATER target's tail-block allocation may have
-        # preempted it (rows[rid] is None again, its table zeroed):
-        # activating its slot anyway would scatter KV into pool block 0
-        # and corrupt another row (code-review r5 #1) — apply only entries
-        # whose row object still occupies its slot
-        activation = [
-            a for a in activation if self.rows[a[0]] is a[4]
+    def _activate_filled_rows(self, sample_targets, resumed, sampled):
+        """The rows of a distribution start decoding, on the device
+        (``_activate_rows``): the fresh targets in ONE program with the
+        tokens sampled for them, which the host has not seen
+        (``first_on_its_way`` until ``_fold_first_tokens``); resumed rows,
+        where a preemption left any, in a call of their own with what they
+        were preempted with (so ``_sample_rows`` and ``_activate_rows``
+        meet the counts they were built at, whatever resumes beside the
+        fresh ones).  Returns the fresh targets with their rows."""
+        targets, entries = [], []
+        for f, tgt, _ in sample_targets:
+            row = self.rows[tgt.row_id]
+            assert row is not None and row.filling
+            row.filling = False
+            row.first_on_its_way = True
+            row.budget_left = tgt.max_new - 1
+            self._epoch_counter += 1
+            row.epoch = self._epoch_counter
+            entries.append(
+                (tgt.row_id, 1, 0, tgt.max_new - 1, len(f.tokens),
+                 _qid_seed(tgt.req.qid))
+            )
+            targets.append((tgt, row))
+        if entries:
+            self._start_rows(sampled[0], entries)
+        # a resume target activated EARLIER in the distribution is the
+        # youngest active row, so a LATER target's tail-block allocation
+        # may have preempted it (rows[rid] is None again, its table
+        # zeroed): activating its slot anyway would scatter KV into pool
+        # block 0 and corrupt another row (code-review r5 #1) — apply only
+        # entries whose row object still occupies its slot
+        entries = [
+            (rid, 0, cur, budget, plen, _qid_seed(row.req.qid))
+            for rid, cur, budget, plen, row in resumed
+            if self.rows[rid] is row
         ]
-        if activation:
-            # a power-of-two count, the last row named again (the same
-            # values twice): one set of scatter programs a bucket, not one
-            # a count.  Siblings that arrive over two steps activate as 3
-            # and 5 where 8 was warmed, and a count first met inside a
-            # benchmark's window compiled there (my chip run, PR 31)
-            n_pad = 1 << (len(activation) - 1).bit_length()
-            activation = activation + [activation[-1]] * (
-                n_pad - len(activation)
+        if entries:
+            n_pad = 1 << (len(entries) - 1).bit_length()
+            self._start_rows(
+                self._on_device(np.zeros((n_pad,), np.int32)), entries
             )
-            ids = np.array([a[0] for a in activation], np.int32)
-            curs = np.array([a[1] for a in activation], np.int32)
-            buds = np.array([a[2] for a in activation], np.int32)
-            lens = np.array([a[3] for a in activation], np.int32)
-            seeds = np.array(
-                [_qid_seed(a[4].req.qid) for a in activation], np.int32
-            )
-            self.cur_tokens = self.cur_tokens.at[ids].set(curs)
-            self.active = self.active.at[ids].set(True)
-            self.budgets = self.budgets.at[ids].set(buds)
-            self.kv_lengths = self.kv_lengths.at[ids].set(lens)
-            self.row_seeds = self.row_seeds.at[ids].set(seeds)
+        return targets
+
+    def _start_rows(self, tokens, entries):
+        """``_activate_rows`` over ``entries`` (row id, fresh, cur, budget,
+        length, seed), padded to ``tokens``' count: padding names a row
+        past the batch and writes nothing."""
+        columns = np.zeros((6, tokens.shape[0]), np.int32)
+        columns[0] = self.max_batch
+        columns[:, : len(entries)] = np.reshape(entries, (-1, 6)).T
+        (self.cur_tokens, self.active, self.budgets, self.kv_lengths,
+         self.row_seeds) = _activate_rows(
+            *self._row_arrays(), tokens, jnp.asarray(columns),
+            stop_tokens=self.stop_tokens,
+        )
 
     def _admit_paged(self) -> Tuple[int, int]:
         """Returns (rows admitted, those whose fill starts behind a
@@ -4243,7 +4411,7 @@ class ContinuousBatchingEngine:
             if row is None or row.parked or row.filling:
                 continue
             n_pend = pend_counts.get(row_id, 0)
-            host_len = len(row.prompt) + len(row.generated) + 1 + n_pend * W
+            host_len = row.n_tokens + 1 + n_pend * W
             need = -(-(host_len + W) // self.page_size)
             need = min(need, self.blocks_per_row)
             while True:
@@ -4283,7 +4451,7 @@ class ContinuousBatchingEngine:
                     or row.parked
                 ):
                     break  # this very row finished/parked during the drain
-                host_len = len(row.prompt) + len(row.generated) + 1
+                host_len = row.n_tokens + 1
                 need = min(
                     -(-(host_len + W) // self.page_size),
                     self.blocks_per_row,
@@ -4293,9 +4461,7 @@ class ContinuousBatchingEngine:
                 # ring have only added to it): its window has passed the
                 # pages before that, whoever else still holds them
                 wrow = self._win.rows[row_id]
-                self._win.release_behind(
-                    wrow, len(row.prompt) + len(row.generated) - 1, row_id
-                )
+                self._win.release_behind(wrow, row.n_tokens - 1, row_id)
                 self._win.row_pages_max = max(
                     self._win.row_pages_max, self._win.held(wrow)
                 )
@@ -4358,12 +4524,12 @@ class ContinuousBatchingEngine:
         )
         self.tracer.event(
             row.req.qid, "engine.preempt", row=row_id,
-            cached_tokens=len(row.prompt) + len(row.generated),
+            cached_tokens=row.n_tokens,
         )
         logger.info(
             "preempted row %d (qid=%s, %d cached tokens) under pool "
             "pressure",
-            row_id, row.req.qid, len(row.prompt) + len(row.generated),
+            row_id, row.req.qid, row.n_tokens,
         )
 
     def _count_dispatch(self, span, snapshot, chunk_size: int):
@@ -4380,10 +4546,7 @@ class ContinuousBatchingEngine:
         while a profiler session records them."""
         if not span.is_enabled():
             return
-        ctx = [
-            len(self.rows[i].prompt) + len(self.rows[i].generated)
-            for i, _ in snapshot
-        ]
+        ctx = [self.rows[i].n_tokens for i, _ in snapshot]
         page = self.page_size if self.paged else self.kv_cache_len
         # the unit is the kernel's to name, from the shapes it sees
         tile = (
@@ -4746,6 +4909,7 @@ class ContinuousBatchingEngine:
         )
         t_first = time.monotonic()  # first tokens materialized on host
         self.tokens_emitted_total += len(to_admit)
+        self.first_tokens_blocking_total += len(to_admit)
         started_ids, started_curs, started_budgets = [], [], []
         started_seeds = []
         for (row_id, req, prompt, max_new), tok_i, logp in zip(
@@ -4800,9 +4964,7 @@ class ContinuousBatchingEngine:
         out.version_end = self.version
         self.gen_tokens_total += len(row.generated)
         if self._keep_routed and row.routed is not None:
-            while len(self._routed_done) >= self._keep_routed:
-                del self._routed_done[next(iter(self._routed_done))]
-            self._routed_done[row.req.qid] = np.concatenate(row.routed)
+            self._keep_routing(row.req.qid, np.concatenate(row.routed))
         if started and self.paged and row_id >= 0:
             # cached KV covers prompt + generated[:-1] (the final token is
             # the pending cur; its KV was never written).  Inserting on
@@ -4837,6 +4999,19 @@ class ContinuousBatchingEngine:
             ev = self._result_events.get(row.req.qid)
         if ev:
             ev.set()
+
+    def _keep_routing(self, qid: str, routing: np.ndarray):
+        """A finished request's routing, the oldest out first.  The room
+        is memory's: ``keep_routed_experts`` sequences as long as the
+        cache holds (``kv_cache_len`` positions each), so at least the
+        last that many requests are kept, and more where they are
+        shorter.  (Kept by COUNT, an engine that finishes its requests
+        sooner lost the routing of a reader's oldest ones: PR 48.)"""
+        kept = self._routed_done
+        self._routed_done_positions += len(routing) - len(kept.pop(qid, ()))
+        kept[qid] = routing
+        while self._routed_done_positions > self._keep_routed * self.kv_cache_len:
+            self._routed_done_positions -= len(kept.pop(next(iter(kept))))
 
     def routed_experts(self, qid: str) -> Optional[np.ndarray]:
         """``[prompt + generated - 1, L, K]`` int16: every layer's routed
@@ -4929,16 +5104,24 @@ class ContinuousBatchingEngine:
         )
         if jax_compat.start_host_copies(arrs):
             self.async_fetches_total += 1
+        # the first chunk to decode the rows activated since the last one:
+        # their first tokens are folded at its harvest, ahead of its own
         self._ring.append(
-            _InflightChunk(arrs=arrs, snapshot=snapshot, spec_meta=spec_meta)
+            _InflightChunk(
+                arrs=arrs, snapshot=snapshot, spec_meta=spec_meta,
+                first_tokens=self._first_tokens,
+            )
         )
+        self._first_tokens = []
 
     def _drain_ring(self) -> int:
-        """Harvest EVERY in-flight chunk, oldest first (pipeline flush:
-        pause, weight swap, preemption — host rows exact afterwards)."""
+        """Harvest EVERY in-flight chunk, oldest first, and settle the
+        first tokens on their way (pipeline flush: pause, weight swap,
+        preemption, cancel — host rows exact afterwards)."""
         n = 0
         while self._ring:
             n += self._harvest_oldest()
+        self._settle_first_tokens()  # (those no chunk had taken up)
         return n
 
     def _harvest_oldest(self) -> int:
@@ -4950,6 +5133,16 @@ class ContinuousBatchingEngine:
         if not self._ring:
             return 0
         chunk = self._ring.popleft()
+        if chunk.first_tokens:
+            # rows this chunk was the first to decode: their first tokens,
+            # sampled by programs queued before it, are there before its
+            # own outputs and go to their rows as soon as they are
+            with self._phases.phase("areal.engine.harvest.wait"):
+                for record in chunk.first_tokens:
+                    for x in record.arrays or ():
+                        x.block_until_ready()
+            for record in chunk.first_tokens:
+                self._fold_first_tokens(record)
         arrs = chunk.arrs
         # time attribution: block_until_ready isolates the wait for device
         # compute from the device_get transfer that follows (the transfer
@@ -5195,6 +5388,10 @@ class ContinuousBatchingEngine:
                     not dispatched and self._ring
                 ):
                     self._harvest_oldest()
+                if self._first_tokens:
+                    # no chunk took them up (nothing was worth
+                    # dispatching): the device has little else to do
+                    self._settle_first_tokens()
                 return self._tokens_harvested_total - h0
             finally:
                 self._ledger_sync_host_buffers()

@@ -1134,7 +1134,11 @@ TRACE_TABLE = [
         "areal.engine.fill.dispatch",
         "phase",
         "One batched prefill chunk built on the host and dispatched "
-        "(counts: prompts, f_pad, c, tokens; for a model with recurrent "
+        "(counts: prompts, f_pad, c, tokens; the running totals "
+        "first_tokens_deferred = fresh targets whose first token went to "
+        "its row on the device and reached the host at a later harvest, "
+        "first_tokens_blocking = those the host fetched at once; for a "
+        "model with recurrent "
         "state also the running totals state_copies = copies of a "
         "fill's end state to the siblings queued on it, "
         "state_late_joins = requests admitted after their prompt's fill "
@@ -1160,16 +1164,21 @@ TRACE_TABLE = [
     TraceSpec(
         "areal.engine.fill.first_token_wait",
         "phase",
-        "The blocking fetch of a completed prefill's sampled first "
-        "tokens: it waits for the fill program behind every decode "
-        "chunk already queued on the device (counts: rows)",
+        "The pick-up of a distribution's sampled first tokens where the "
+        "host folds them: at the harvest of the first chunk that "
+        "decoded their rows, after its wait for the sample program and "
+        "before its wait for the chunk, so next to nothing; a wait for the "
+        "fill program only where they are settled early (fetched at "
+        "once, a ring drain, a step that dispatched nothing) and at the "
+        "non-paged admission (counts: rows)",
     ),
     TraceSpec(
         "areal.engine.fill.activate",
         "phase",
-        "A completed fill handed to its rows, in two spans beside the "
-        "first-token fetch: blocks shared, tail pages copied and "
-        "sampling dispatched before it, rows activated after it",
+        "A completed fill handed to its rows: blocks shared, tail pages "
+        "copied, sampling and the one activation program dispatched; "
+        "and, once the first tokens have reached the host, their fold "
+        "into the rows",
     ),
     TraceSpec(
         "areal.engine.ensure_blocks",

@@ -1729,6 +1729,14 @@ class GenerationServerWorker(worker_base.Worker):
                     for name, sec in eng.phase_seconds().items()
                 ),
             )
+            slo = eng.slo_stats()
+            self.logger.info(
+                "first tokens: first_tokens_deferred=%d, "
+                "first_tokens_blocking=%d, ttft_s=%s, tpot_s=%s",
+                eng.first_tokens_deferred_total,
+                eng.first_tokens_blocking_total,
+                slo["ttft_s"], slo["tpot_s"],
+            )
             if eng.moe_fill_tokens_total:
                 self.logger.info(
                     "expert layers at fill: moe_fill_tokens=%d, "

@@ -322,7 +322,8 @@ def test_dispatch_span_counts_the_tiles_the_kernel_reads(mode, kw, page, tile):
     while eng.n_decoding < 3:
         eng.step()
     snapshot = [(i, r.epoch) for i, r in enumerate(eng.rows) if r is not None]
-    ctx = [len(eng.rows[i].prompt) + len(eng.rows[i].generated) for i, _ in snapshot]
+    # (a first token on its way to the host is among a row's tokens)
+    ctx = [eng.rows[i].n_tokens for i, _ in snapshot]
     assert len(ctx) == 3 and min(ctx) > 5
     span = _Span()
     eng._count_dispatch(span, snapshot, eng.chunk_size)

@@ -98,12 +98,37 @@ def test_siblings_of_one_fill_decode_as_if_each_had_prefilled_alone(model):
     # ONE prefill of the prompt, its end state copied to the two siblings
     assert eng.prefill_tokens_total == 13 and eng.state_copies_total == 2
     assert eng.state_reprefills_total == 0
-    # the routing of the last two to finish is kept, the first's is gone
-    assert eng.routed_experts("a0") is None
-    assert_reference(model[1], {q: out[q] for q in ("a1", "a2")}, eng=eng)
+    # the routing is kept by ROOM (two sequences of the cache's 64
+    # positions): three of 18-20 positions are all there
+    assert_reference(model[1], out, eng=eng)
     assert_reference(model[1], out)
     # a row never parks: its slot is free when it finishes
     assert eng.n_parked == 0 and eng.state_slots_live == 0
+
+
+def test_routing_is_kept_in_the_room_of_that_many_whole_sequences(model):
+    """``keep_routed_experts`` = 2 with a cache of 64 positions: room for
+    128 positions of routing, the oldest request out first.  At least the
+    last two are always there; of shorter sequences, as many as fit (a
+    count alone lost the oldest of a reader's requests when the engine
+    got faster: the benchmark's toy windows, PR 48)."""
+    eng = make_engine(model, keep_routed_experts=2)
+
+    def finish(qid, n):
+        eng._keep_routing(qid, np.zeros((n, 2, 2), np.int16))
+
+    for i in range(6):
+        finish(f"s{i}", 20)
+    assert list(eng._routed_done) == [f"s{i}" for i in range(6)]  # 120
+    finish("s6", 20)  # 140 positions: the oldest goes
+    assert eng.routed_experts("s0") is None
+    assert eng.routed_experts("s1") is not None
+    finish("s1", 8)  # the same request again: its new routing, at the end
+    assert len(eng.routed_experts("s1")) == 8
+    finish("long0", 64)
+    finish("long1", 64)  # two whole sequences: everything else has gone
+    assert list(eng._routed_done) == ["long0", "long1"]
+    assert eng._routed_done_positions == 128
 
 
 def test_a_reused_slot_starts_from_zero_and_a_late_sibling_reprefills(model):

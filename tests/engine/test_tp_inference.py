@@ -20,6 +20,7 @@ from areal_tpu.api.model_api import (
     GenerationHyperparameters,
 )
 from areal_tpu.base.topology import MeshSpec
+from areal_tpu.engine import inference_server
 from areal_tpu.engine.inference_server import ContinuousBatchingEngine
 from areal_tpu.engine.sampling import SamplingParams
 from areal_tpu.engine.spec_decode import SpecDecodeParams
@@ -127,7 +128,13 @@ def test_tp2_paged_engine_matches_single_device(model):
     assert tp.paged
     # the KV pool's head axis is genuinely sharded over the model axis
     assert tp.k_pool.sharding.shard_shape(tp.k_pool.shape) != tp.k_pool.shape
+    built = inference_server._activate_rows._cache_size()
     got = _generate(tp)
+    # the rows' arrays and the sampled tokens live whole on every device
+    # of the mesh, where the engine's start placed the warm-up's: serving
+    # built no activation program (one met under load compiles there)
+    assert inference_server._activate_rows._cache_size() == built
+    assert tp.first_tokens_deferred_total > 0
     _assert_output_parity(ref, got)
 
 

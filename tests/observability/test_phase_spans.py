@@ -163,7 +163,21 @@ def test_engine_spans_in_a_cpu_profile(tmp_path):
     assert last["tokens_emitted_total"] == eng.tokens_emitted_total
     assert sum(c["rows_admitted"] for c in counts("areal.engine.admit")) == 6
     fills = counts("areal.engine.fill.dispatch")
-    assert all(set(c) == {"prompts", "f_pad", "c", "tokens"} for c in fills)
+    assert all(
+        set(c) == {
+            "prompts", "f_pad", "c", "tokens", "first_tokens_deferred",
+            "first_tokens_blocking",
+        }
+        for c in fills
+    )
+    # (running totals, from none before the first fill to all six by the
+    # fills that recompute rows after the swap; none was fetched at once)
+    deferred = [c["first_tokens_deferred"] for c in fills]
+    assert deferred == sorted(deferred) and deferred[0] == 0
+    assert deferred[-1] == eng.first_tokens_deferred_total == 6
+    assert eng.first_tokens_blocking_total == 0 == fills[-1][
+        "first_tokens_blocking"
+    ]
     # two unique prompts prefilled once each, and once more for the rows
     # the swap recomputed
     assert sum(c["tokens"] for c in fills) == eng.prefill_tokens_total
