@@ -1845,7 +1845,8 @@ def hybrid_fill_chunk(
     )
     scale = _attn_scale(cfg)
     plan = paged._prefix_plan(
-        C, cfg.n_q_heads, k_pool, tables, read_lens, use_kernel
+        C, cfg.n_q_heads, k_pool, tables, read_lens, use_kernel,
+        masked=cfg.is_indexed,
     )
     window = cfg.sliding_window if cfg.n_window_layers else None
     # a latent window layer's widths are its own (``cfg.window_latent``)
@@ -1980,9 +1981,10 @@ def hybrid_fill_chunk(
                 )
                 cached = before.shape[-1]
             with region("areal.attn.sparse"):
-                acc, m, lsum = sparse.masked_latent_partials(
-                    q_abs, k_pool, j, tables, read_lens,
-                    chosen[..., :cached], lcfg.kv_lora_rank, lscale,
+                acc, m, lsum = paged._prefix_partials(
+                    q_abs, k_pool, None, tables, read_lens, j, use_kernel,
+                    plan=plan, scale=lscale, value_dim=lcfg.kv_lora_rank,
+                    mask=chosen[..., :cached],
                 )
             mask = chosen[..., cached:]
             kept_index = (ki[:, :, None, :].astype(v_pool.dtype),)

@@ -246,10 +246,11 @@ def _shard_pool_shape(k_pool, mesh=None, kv_axis=None):
 
 def _prefix_plan(
     n_queries, n_q_heads, k_pool, tables, lengths, use_kernel,
-    mesh=None, kv_axis=None, quantized=False, window=None,
+    mesh=None, kv_axis=None, quantized=False, window=None, masked=False,
 ):
     """The paged kernel's page plan for every :func:`_prefix_partials`
-    call over these ``tables`` and ``lengths`` (under this ``window``):
+    call over these ``tables`` and ``lengths`` (under this ``window``;
+    ``masked``: calls that bring a selection, ``mask``):
     made ONCE, before the layer scan (and the decode chunk's step loop),
     because XLA leaves it inside them otherwise.  None without the
     kernel."""
@@ -258,7 +259,7 @@ def _prefix_plan(
     shards, shard_shape = _shard_pool_shape(k_pool, mesh, kv_axis)
     group = page_group(
         n_queries, n_q_heads // shards, shard_shape, k_pool.dtype,
-        quantized, tables.shape[1],
+        quantized, tables.shape[1], masked,
     )
     return plan_pages(tables, lengths, shard_shape[1], group, window)
 
@@ -273,7 +274,7 @@ def kernel_tile_tokens(k_pool, mesh=None, kv_axis=None) -> int:
 def _prefix_partials(
     q, k_pool, v_pool, tables, lengths, layer, use_kernel,
     mesh=None, kv_axis=None, k_scale=None, v_scale=None, plan=None,
-    scale=None, value_dim=None, window=None, window_shift=None,
+    scale=None, value_dim=None, window=None, window_shift=None, mask=None,
 ):
     """Paged-attention partials over each row's cached prefix.  ``q`` is
     [B, Q, Hq, hd]; returns (acc, m, l) with Q query tokens per row.
@@ -284,6 +285,9 @@ def _prefix_partials(
     ``window``: query ``t`` of a row attends the cached positions ``j``
     with ``length + window_shift + t - j < window`` only (``window_shift``:
     a decode chunk's step, over the plan made at its start).
+    ``mask`` [B, Q, MB * BS]: a selection, query ``t`` attends cached
+    position ``s`` of its row only where ``mask[b, t, s]`` (an indexed
+    latent layer's fill: the kernel takes it as one more operand).
 
     ``k_scale``/``v_scale`` mark an int8-quantized pool: both the kernel
     and the jnp reference dequantize (multiply by the per-(block, head,
@@ -304,7 +308,7 @@ def _prefix_partials(
                 layer=layer, interpret=interp, k_scale=k_scale,
                 v_scale=v_scale, plan=plan, scale=scale,
                 value_dim=value_dim, window=window,
-                window_shift=window_shift,
+                window_shift=window_shift, mask=mask,
             )
         assert value_dim is None, "latent pages under a serving mesh"
         assert window is None, "a windowed call under a serving mesh"
@@ -360,6 +364,7 @@ def _prefix_partials(
             q, kl, None, tables, lengths, scale=scale, value_dim=value_dim,
             window=window,
             window_shift=0 if window_shift is None else window_shift,
+            mask=mask,
         )
     vl = jax.lax.dynamic_index_in_dim(v_pool, layer, 0, keepdims=False)
     ksl = vsl = None

@@ -1335,9 +1335,9 @@ TRACE_TABLE = [
         "region",
         "Inside an INDEXED latent layer's half: attention over the "
         "chosen cached entries alone (a decode step gathers them from "
-        "the pool; a fill attends its prefix under the mask, page by "
-        "page) and, in a decode step, its merge with the chunk's own "
-        "tokens",
+        "the pool; a fill attends its paged prefix under the mask in "
+        "the paged kernel, Mosaic paged_mla_masked_fill) and, in a "
+        "decode step, its merge with the chunk's own tokens",
     ),
     TraceSpec(
         "areal.attn.cross",

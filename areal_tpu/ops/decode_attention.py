@@ -80,7 +80,7 @@ def _dot_bf16(a, b, dims):
 
 
 def softmax_block_update(
-    q, k, v, s_acc, s_m, s_l, *, base, length, scale, first=None
+    q, k, v, s_acc, s_m, s_l, *, base, length, scale, first=None, chosen=None
 ):
     """One KV block's online-softmax update over (rows, hd) queries —
     the SINGLE definition of the decode-attention numerics, used by both
@@ -105,6 +105,11 @@ def softmax_block_update(
     maximum stays at its initial value: ``exp(s - m)`` of a masked entry
     would read 1 there).
 
+    ``chosen`` ((rows, BS) bool, or None): a selection, which of the
+    block's positions each query row attends at all (an indexer's choice);
+    what is not chosen is masked and its probability set to 0 in the same
+    way, for the same reason (a block may hold nothing a row chose).
+
     Decode is NOT so HBM-bound that HIGHEST's passes are free: widening
     every bf16 K and V tile to float32 and splitting it back into bf16
     terms held the paged kernel to 458 GB/s on a v5e with every page
@@ -121,13 +126,15 @@ def softmax_block_update(
     valid = pos < length
     if first is not None:
         valid &= pos >= first
+    if chosen is not None:
+        valid &= chosen
     s = jnp.where(valid, s, _NEG_INF)
 
     m_prev = s_m[:, 0]  # (rows,)
     m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
     alpha = jnp.exp(m_prev - m_cur)
     p = jnp.exp(s - m_cur[:, None])  # (rows, BS)
-    if first is not None:
+    if first is not None or chosen is not None:
         p = jnp.where(valid, p, 0.0)
     if bf16:
         rows = p.shape[0]

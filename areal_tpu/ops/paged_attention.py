@@ -84,6 +84,22 @@ two: everything that was hard to get right here (which page a stream
 addresses when it has nothing to fetch, the visiting order, the bf16
 routes of the two dots) is the latent form's too, and a second kernel
 would have had to copy it.
+
+**A selection** (``mask`` ``[B, Q, MB * BS]``): query ``t`` of row ``b``
+attends cached position ``s`` iff ``mask[b, t, s]`` (and ``s < length``):
+an indexed latent layer's fill, whose indexer chose ``index_topk``
+positions a query (``ops/sparse_attention.chosen_mask``).  One more
+operand, one row a query TOKEN: it is laid out ``[B, QB, pages, QT, BS]``
+in 32-bit words (:func:`_group_selection`), a grid step's block is its G
+pages of the row's query tile, ``(1, 1, G, QT, BS)``, through the pipeline
+as ``q``'s is (past a row's last page its last block again, which is not
+fetched anew), and a page's ``[QT, BS]`` is repeated down the sublanes
+over the ``r`` query heads of each token and joins the length mask in
+:func:`softmax_block_update` (``chosen``).  A page's scores of a query
+tile, ``[QT * r, BS]`` float32, never leave VMEM; the XLA page loop this
+replaced wrote them out and read them back several times a page.  A
+static branch on the operand's presence: a call without it is the program
+it was.  The Mosaic call is named ``paged_mla_masked_fill``.
 """
 
 from __future__ import annotations
@@ -156,7 +172,7 @@ VMEM_BUDGET_BYTES = 40 << 20
 
 def vmem_bytes_needed(
     Hkv: int, BS: int, hd: int, kv_itemsize: int, quantized: bool,
-    page_group: int, q_rows: int,
+    page_group: int, q_rows: int, selected_tokens: int = 0,
 ) -> int:
     """VMEM one grid cell of :func:`paged_flash_attention` needs, from its
     shapes: the two page/scale buffers of each of the G streams,
@@ -164,7 +180,10 @@ def vmem_bytes_needed(
     temporaries of :func:`softmax_block_update` (scores and
     probabilities [q_rows, BS], the page's f32 copies, and the bf16
     splits HIGHEST precision makes of each dot operand).  A page copied
-    as far as it is filled needs the buffers of a whole one."""
+    as far as it is filled needs the buffers of a whole one.  Under a
+    selection (``selected_tokens``: the query tokens of a cell) its
+    double-buffered block of G pages, a sublane tile of tokens at least,
+    and a page's selection laid out by query row, as words and as a mask."""
     page = Hkv * BS * hd * kv_itemsize
     pages = 2 * page_group * 2 * page  # k+v, G streams, two buffers each
     if quantized:
@@ -174,6 +193,9 @@ def vmem_bytes_needed(
     state = Hkv * q_rows * (hd + 256) * 4  # acc + m + l
     outs_and_scratch = 3 * state  # double-buffered outs + scratch
     temps = 3 * q_rows * BS * 4 + 4 * BS * hd * 4 + 2 * q_rows * hd * 4
+    if selected_tokens:
+        temps += 2 * page_group * max(selected_tokens, 8) * BS * 4
+        temps += 2 * q_rows * BS * 4
     return pages + q_tile + outs_and_scratch + temps
 
 
@@ -196,10 +218,11 @@ def _tile_tokens(Hkv: int, BS: int, hd: int, kv_itemsize: int) -> int:
 
 def _plan_tiles(
     Q: int, r: int, Hkv: int, BS: int, hd: int, kv_itemsize: int,
-    quantized: bool, MB: int,
+    quantized: bool, MB: int, masked: bool = False,
 ) -> Tuple[int, int, int]:
     """(page_group G, query tokens per cell QT, tokens a tile) for these
-    shapes: start from PAGE_GROUP pages and MAX_Q_ROWS rows and give up
+    shapes (``masked``: with a selection's blocks beside them): start
+    from PAGE_GROUP pages and MAX_Q_ROWS rows and give up
     query rows, then pages, until :func:`vmem_bytes_needed` fits the
     budget; the tile is :func:`_tile_tokens`'s.  Raises when even one
     page and one sublane tile of queries do not fit — the caller must
@@ -215,7 +238,8 @@ def _plan_tiles(
         while True:
             if (
                 vmem_bytes_needed(
-                    Hkv, BS, hd, kv_itemsize, quantized, G, QT * r
+                    Hkv, BS, hd, kv_itemsize, quantized, G, QT * r,
+                    QT if masked else 0,
                 )
                 <= VMEM_BUDGET_BYTES
             ):
@@ -224,11 +248,14 @@ def _plan_tiles(
                 break
             QT = max(step, (QT // 2) // step * step)
         if G == 1:
+            need = vmem_bytes_needed(
+                Hkv, BS, hd, kv_itemsize, quantized, 1, step * r,
+                step if masked else 0,
+            )
             raise ValueError(
                 "paged_flash_attention: one page of "
                 f"[Hkv={Hkv}, page={BS}, head_dim={hd}] x {kv_itemsize} B "
-                f"with {step * r} query rows needs "
-                f"{vmem_bytes_needed(Hkv, BS, hd, kv_itemsize, quantized, 1, step * r)} "
+                f"with {step * r} query rows needs {need} "
                 f"bytes of VMEM, over the {VMEM_BUDGET_BYTES}-byte budget; "
                 "use a smaller page_size or shard kv heads over more chips"
             )
@@ -274,8 +301,8 @@ def _kernel(
     # a window [2]: the layer, and how far the queries stand past ``length``
     order_ref,  # scalar prefetch [B]: visit_order, for the maps
     *refs,  # [firsts (scalar prefetch [B]) under a window,] q (1, 1, Hkv,
-    # QR, hd), pools in HBM, 3 outs, page buffers, 3 scratch, slots,
-    # semaphores
+    # QR, hd), [the selection (1, 1, G, QT, BS),] pools in HBM, 3 outs,
+    # page buffers, 3 scratch, slots, semaphores
     block_size: int,
     tile: int,
     scale: float,
@@ -286,12 +313,15 @@ def _kernel(
     value_dim: Optional[int] = None,  # latent pages: no v pool
     window: Optional[int] = None,
     q_per_kv: int = 1,  # query heads a kv head: a q tile's row t*r + i
+    masked: bool = False,  # a selection rides in behind q
 ):
     G, S = page_group, block_size // tile
-    firsts_ref = None
+    firsts_ref = selection_ref = None
     if window is not None:
         firsts_ref, refs = refs[0], refs[1:]
     q_ref, refs = refs[0], refs[1:]
+    if masked:
+        selection_ref, refs = refs[0], refs[1:]
     # the arrays a page is made of: K, V (none for latent pages, whose
     # values ride the K page) and an int8 pool's two scale arrays
     n_arrays = (1 if value_dim is not None else 2) * (2 if quantized else 1)
@@ -445,13 +475,18 @@ def _kernel(
                     ks, vs = bufs[2][slot, g], bufs[3][slot, g]
                     k_all = k_all.astype(jnp.float32) * ks[:, :, None]
                     v_all = v_all.astype(jnp.float32) * vs[:, :, None]
+                chosen = None
+                if masked:
+                    # this page's selection, a row a query TOKEN: every
+                    # query head of the token takes it (q tile row t*r + i)
+                    chosen = jnp.repeat(selection_ref[0, 0, g], q_per_kv, 0) != 0
                 for h in range(n_kv_heads):
                     softmax_block_update(
                         q_ref[0, 0, h], k_all[h], v_all[h],
                         s_acc.at[h], s_m.at[h], s_l.at[h],
                         base=stream_col(b, j, g, G, firsts_ref) * block_size,
                         length=length,
-                        scale=scale, first=first,
+                        scale=scale, first=first, chosen=chosen,
                     )
 
         each_stream(_stream)
@@ -536,14 +571,15 @@ def plan_pages(
 
 def page_group(
     n_queries: int, n_q_heads: int, pool_shape, kv_dtype, quantized: bool,
-    max_blocks: int,
+    max_blocks: int, masked: bool = False,
 ) -> int:
     """Pages a grid step streams for a call of these shapes (``pool_shape``
-    as the kernel sees it: one shard's, under a TP mesh)."""
+    as the kernel sees it: one shard's, under a TP mesh; ``masked``: a
+    call with a selection)."""
     Hkv, BS, hd = pool_shape[-3:]
     return _plan_tiles(
         n_queries, n_q_heads // Hkv, Hkv, BS, hd,
-        jnp.dtype(kv_dtype).itemsize, quantized, max_blocks,
+        jnp.dtype(kv_dtype).itemsize, quantized, max_blocks, masked,
     )[0]
 
 
@@ -560,6 +596,30 @@ def _row_map(b, qb, j, lengths_ref, ids_ref, layer_ref, order_ref, *firsts):
     """Query and output tiles of step (b, qb, .): those of the row this
     step works on."""
     return (order_ref[b], qb, 0, 0, 0)
+
+
+def _selection_map(
+    b, qb, j, lengths_ref, ids_ref, layer_ref, order_ref, *, span: int
+):
+    """The selection's block of step (b, qb, j): that of the step's G
+    pages (``span`` positions) for the row's query tile; at the steps past
+    the row's last page its last block again, which the pipeline does not
+    fetch anew."""
+    last = jnp.maximum((lengths_ref[b] + span - 1) // span - 1, 0)
+    return (order_ref[b], qb, jnp.minimum(j, last), 0, 0)
+
+
+def _group_selection(mask, QT: int, QB: int, pages: int, BS: int):
+    """A selection ``[B, Q, MB * BS]`` as the grid reads it: ``[B, QB,
+    pages, QT, BS]`` int32 (a block's last two dimensions whole: the query
+    tokens of a tile by the positions of ONE page; 32-bit words, a row of
+    which the kernel lays down the sublanes of a token's query heads), 0
+    where the query axis and the table were padded."""
+    B, Q, S = mask.shape
+    mask = jnp.pad(
+        mask.astype(jnp.int32), ((0, 0), (0, QB * QT - Q), (0, pages * BS - S))
+    )
+    return mask.reshape(B, QB, QT, pages, BS).transpose(0, 1, 3, 2, 4)
 
 
 def _group_queries(q, Hkv, r, QT):
@@ -628,6 +688,7 @@ def paged_flash_attention(
     value_dim: Optional[int] = None,  # latent pages: values = k[..., :value_dim]
     window: Optional[int] = None,  # attend i - j < window only
     window_shift: jax.Array | None = None,  # [] int32: see below
+    mask: jax.Array | None = None,  # [B, Q, MB * BS] bool: a selection
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Un-normalized online-softmax attention partials over paged KV.
 
@@ -664,6 +725,10 @@ def paged_flash_attention(
 
     ``v_pool=None`` with ``value_dim``: latent pages (module docstring);
     ``acc`` is then ``[B, Q, Hq, value_dim]``.
+
+    ``mask``: a SELECTION (module docstring): query ``t`` of row ``b``
+    attends cached position ``s`` iff ``s < length`` and ``mask[b, t,
+    s]``; a query that chose nothing returns ``l=0``.  Not under a window.
     """
     B, Q, Hq, hd = q.shape
     latent = v_pool is None
@@ -678,10 +743,13 @@ def paged_flash_attention(
     r = Hq // Hkv
     quantized = k_scale is not None
     assert not (latent and quantized), "int8 latent pages are not written"
+    masked = mask is not None
+    assert not (masked and window is not None), "a selection under a window"
     # tile the query axis (QT tokens per grid cell, QT*r rows of scratch)
     # and pick the page group from the VMEM these shapes need
     G, QT, tile = _plan_tiles(
-        Q, r, Hkv, BS, hd, jnp.dtype(k_pool.dtype).itemsize, quantized, MB
+        Q, r, Hkv, BS, hd, jnp.dtype(k_pool.dtype).itemsize, quantized, MB,
+        masked,
     )
     qg, QB = _group_queries(q, Hkv, r, QT)
     if window is not None and window_shift is None:
@@ -711,6 +779,17 @@ def paged_flash_attention(
         pltpu.VMEM((2, G) + p.shape[k_pool.ndim - 3:], p.dtype)  # a page
         for p in pools
     ]
+    # the selection arrives as q does, a block a step through the pipeline
+    selection, selection_spec = [], []
+    if masked:
+        assert mask.shape == (B, Q, MB * BS), (mask.shape, B, Q, MB, BS)
+        selection = [_group_selection(mask, QT, QB, grid[2] * G, BS)]
+        selection_spec = [
+            pl.BlockSpec(
+                (1, 1, G, QT, BS),
+                functools.partial(_selection_map, span=G * BS),
+            )
+        ]
     acc, m, l = pl.pallas_call(
         functools.partial(
             _kernel,
@@ -724,11 +803,13 @@ def paged_flash_attention(
             value_dim=value_dim,
             window=window,
             q_per_kv=r,
+            masked=masked,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=[pl.BlockSpec((1, 1, Hkv, QT * r, hd), _row_map)]
+            + selection_spec
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
             out_specs=[
                 pl.BlockSpec((1, 1, Hkv, QT * r, vd), _row_map),
@@ -763,14 +844,16 @@ def paged_flash_attention(
         interpret=interpret,
         # the trace names the Mosaic call after this: one query row a
         # sequence is a decode step, a query tile a prefill chunk; a
-        # windowed call apart from one over the whole prefix
+        # windowed call apart from one over the whole prefix, and one
+        # under a selection apart from both
         name=(
             ("paged_mla_" if window is None else "paged_mla_window_")
             if latent
             else "paged_attn_" if window is None else "paged_window_"
         )
+        + ("masked_" if masked else "")
         + ("decode" if Q == 1 else "fill"),
-    )(*prefetch, qg, *pools)
+    )(*prefetch, qg, *selection, *pools)
 
     return _ungroup_outputs(acc, m, l, B, QB, QT, Hkv, r, Q, Hq, vd)
 
@@ -794,11 +877,12 @@ def gather_paged_kv(
 
 def reference_paged_partials(
     q, k_pool, v_pool, tables, lengths, k_scale=None, v_scale=None,
-    scale=None, value_dim=None, window=None, window_shift=0,
+    scale=None, value_dim=None, window=None, window_shift=0, mask=None,
 ):
     """jnp reference for :func:`paged_flash_attention` (same contract;
     ``v_pool=None`` with ``value_dim``: latent pages; ``window``: query
-    ``t`` attends ``[length + t - window + 1, length)``).
+    ``t`` attends ``[length + t - window + 1, length)``; ``mask`` [B, Q,
+    MB * BS]: query ``t`` attends ``s`` only where ``mask[b, t, s]``).
 
     ``k_scale``/``v_scale`` ([NB, Hkv, BS]) mark an int8 pool: the
     gathered pages are multiplied by their per-(head, slot) scales right
@@ -822,15 +906,17 @@ def reference_paged_partials(
     s = jnp.einsum("bqkrd,bksd->bqkrs", qg, k.astype(jnp.float32))
     s = s / np.sqrt(hd) if scale is None else s * scale
     pos = jnp.arange(S)[None, None, None, None, :]
-    mask = pos < lengths[:, None, None, None, None]
+    valid = pos < lengths[:, None, None, None, None]
     if window is not None:
         first = (
             lengths[:, None] + window_shift + jnp.arange(Q)[None, :]
         ) - (window - 1)
-        mask = mask & (pos >= first[:, :, None, None, None])
-    s = jnp.where(mask, s, _NEG_INF)
+        valid = valid & (pos >= first[:, :, None, None, None])
+    if mask is not None:
+        valid = valid & mask[:, :, None, None, :]
+    s = jnp.where(valid, s, _NEG_INF)
     m = jnp.max(s, axis=-1)
-    p = jnp.where(mask, jnp.exp(s - m[..., None]), 0.0)
+    p = jnp.where(valid, jnp.exp(s - m[..., None]), 0.0)
     l = jnp.sum(p, axis=-1)
     acc = jnp.einsum("bqkrs,bksd->bqkrd", p, v.astype(jnp.float32))
     return (

@@ -3,7 +3,10 @@ scores over a row's pages, the exact choice of the ``k`` largest, and
 latent attention over the chosen entries alone (DeepSeek-V3.2-Exp's
 sparse attention, as ``dots3_note``'s full layers state it).
 
-Three steps a full layer, all XLA (no Mosaic call of this module's own):
+Three steps a full layer.  The first two are XLA in every program and so
+is a decode step's third; a FILL's third is the paged kernel's
+(``ops/paged_attention.py`` under a selection), and this module has no
+Mosaic call of its own:
 
 * :func:`paged_index_scores`: ``I(t, s) = sum_j w_j(t) relu(q_j(t) .
   k(s))`` of every cached position ``s`` of a row, from the pool of index
@@ -23,10 +26,14 @@ Three steps a full layer, all XLA (no Mosaic call of this module's own):
 * a decode step READS THE CHOSEN ENTRIES and not the context
   (:func:`sparse_latent_partials`, XLA: a gather of ``k`` rows a sequence
   from the pool as it lies, then the absorbed products over them); a fill
-  chunk attends its paged prefix under the mask, page by page
-  (:func:`masked_latent_partials`, XLA).  Both return the un-normalised
-  ``(acc, m, l)`` that ``paged.window_attention`` / ``chunk_attention``
-  merge with the chunk's own tokens, as the paged kernel's do.
+  chunk attends its paged prefix under :func:`chosen_mask`'s mask in the
+  paged kernel's latent mode, the mask one more operand
+  (``paged_flash_attention(mask=)``, Mosaic ``paged_mla_masked_fill``: a
+  page's scores of a query tile stay in VMEM; the XLA page loop that stood
+  here wrote them out: 58 ms a layer and chunk in the cell's traced slice
+  where the kernel takes 33, PERF.md PR 50).  Both return the
+  un-normalised ``(acc, m, l)`` that ``paged.window_attention`` /
+  ``chunk_attention`` merge with the chunk's own tokens.
 """
 
 from __future__ import annotations
@@ -180,71 +187,3 @@ def sparse_latent_partials(
         preferred_element_type=F32,
     )
     return acc[:, None], m[:, None], jnp.sum(p, axis=-1)[:, None]
-
-
-#: query tokens :func:`masked_latent_partials` holds scores of at a time
-MASKED_QUERY_BLOCK = 256
-
-
-def masked_latent_partials(
-    q, pool, layer, tables, lengths, mask, value_dim: int, scale: float
-):
-    """Absorbed latent attention of a fill chunk's queries ``q`` [F, C, H,
-    width] over each row's cached prefix UNDER ``mask`` [F, C, MB * BS]
-    (a query attends position ``s`` of its row iff ``mask[f, c, s]``),
-    page by page with the online softmax; pages past the longest row are
-    not visited.  Returns un-normalised ``(acc [F, C, H, value_dim], m
-    [F, C, H], l [F, C, H])`` float32."""
-    F, C, H, width = q.shape
-    _, NB, _, BS, _ = pool.shape
-    flat = pool.reshape(-1, BS, width)  # [L * NB, BS, width]: a bitcast
-    n_pages = jnp.max((lengths + BS - 1) // BS).astype(jnp.int32)
-    CQ = min(C, MASKED_QUERY_BLOCK)
-    pad = -C % CQ
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        mask = jnp.pad(mask, ((0, 0), (0, pad), (0, 0)))
-    nq = (C + pad) // CQ
-
-    def block(args):
-        qb, mb = args  # [F, CQ, H, width], [F, CQ, MB * BS]
-
-        def page(p, st):
-            acc, m, l = st
-            ent = flat[layer * NB + tables[:, p]]  # [F, BS, width]
-            mk = jax.lax.dynamic_slice_in_dim(mb, p * BS, BS, axis=2)
-            mk = mk[:, :, None, :]
-            s = jnp.einsum(
-                "fchd,fsd->fchs", qb.astype(ent.dtype), ent,
-                preferred_element_type=F32,
-            ) * scale
-            m_new = jnp.maximum(m, jnp.max(jnp.where(mk, s, NEG), axis=-1))
-            pr = jnp.where(mk, jnp.exp(s - m_new[..., None]), 0.0)
-            alpha = jnp.exp(m - m_new)
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "fchs,fsv->fchv", pr.astype(ent.dtype), ent[..., :value_dim],
-                preferred_element_type=F32,
-            )
-            return acc, m_new, l * alpha + jnp.sum(pr, axis=-1)
-
-        return jax.lax.fori_loop(
-            0, n_pages, page,
-            (
-                jnp.zeros((F, CQ, H, value_dim), F32),
-                jnp.full((F, CQ, H), NEG, F32),
-                jnp.zeros((F, CQ, H), F32),
-            ),
-        )
-
-    acc, m, l = jax.lax.map(
-        block,
-        (
-            q.reshape(F, nq, CQ, H, width).swapaxes(0, 1),
-            mask.reshape(F, nq, CQ, -1).swapaxes(0, 1),
-        ),
-    )  # [nq, F, CQ, ...]
-
-    def back(x):
-        return x.swapaxes(0, 1).reshape((F, nq * CQ) + x.shape[3:])[:, :C]
-
-    return back(acc), back(m), back(l)

@@ -1,9 +1,10 @@
 """ops/sparse_attention.py: the index scores over a row's pages against
 the scores of the same keys at hand, the exact choice against
-``lax.top_k``, the gather of chosen entries and the masked page loop
-against dense masked attention; and the paged kernel's latent mode UNDER
-THE WINDOW (the two static branches together) against its reference, once
-more with every byte it must not read NaN.  Tolerances: 2e-5 at float32
+``lax.top_k``, the gather of chosen entries against dense masked
+attention; the paged kernel's latent mode UNDER A SELECTION (a fill's
+masked prefix) and UNDER THE WINDOW (the two static branches together)
+against their reference, each once more with every byte it must not read
+NaN.  Tolerances: 2e-5 at float32
 (sums in another order); the choice is compared as a SET, exactly."""
 
 import jax
@@ -107,23 +108,92 @@ def test_a_decode_step_reads_its_chosen_entries_and_nothing_else():
     assert float(jnp.abs(again[0] - acc).max()) == 0.0
 
 
-@pytest.mark.parametrize("C", [5, 300])
-def test_a_fill_attends_its_prefix_under_the_mask_page_by_page(C, monkeypatch):
-    monkeypatch.setattr(sa, "MASKED_QUERY_BLOCK", 128)  # (300: three blocks)
-    F, H, width, vd, L, NB, BS, MB = 2, 3, 24, 16, 2, 16, 8, 4
-    ks = jax.random.split(jax.random.PRNGKey(C), 4)
+#: a fill's masked prefix, by case: (chunk C, lengths, what the selection is)
+MASKED_FILLS = {
+    "C5": (5, [19, 0], "random"),
+    "C300": (300, [19, 0], "random"),  # three query tiles of 128
+    "C1024": (1024, [45, 19], "random"),  # the cell's chunk: eight tiles
+    "prefix_0_and_a_dead_row": (70, [0, 0], "random"),
+    "nothing_in_a_page": (9, [48, 33], "page_1_bare"),
+    "shorter_than_k": (9, [21, 8], "all"),  # the mask is the length mask
+    "siblings": (9, [29, 29, 29, 11], "random"),  # rows 0-2 share their pages
+    "page_boundaries": (3, [7, 8, 9, 15, 16, 17, 47, 48], "random"),
+}
+
+
+@pytest.mark.parametrize("case", list(MASKED_FILLS))
+def test_a_fill_attends_its_prefix_under_the_mask_in_the_paged_kernel(case):
+    """``paged_flash_attention(mask=)``, the latent mode under a SELECTION
+    (Mosaic ``paged_mla_masked_fill``; tests/ops/test_tpu_compile.py holds
+    the name), against ``reference_paged_partials(mask=)``, the one
+    definition of the masked prefix outside the kernel, and that against
+    dense masked attention written out here."""
+    C, lens, kind = MASKED_FILLS[case]
+    F, H, width, vd, L, NB, BS, MB = len(lens), 4, 128, 96, 2, 48, 8, 6
+    ks = jax.random.split(jax.random.PRNGKey(C + F), 3)
     pool = _pool(ks[0], L, NB, BS, width)
     q = jax.random.normal(ks[1], (F, C, H, width))
-    tables, lengths = _tables(F, MB, NB, 2), jnp.asarray([19, 0], jnp.int32)
+    tables, lengths = _tables(F, MB, NB, 2), jnp.asarray(lens, jnp.int32)
+    if case == "siblings":
+        tables = tables.at[1:3].set(tables[0])
+    pos = jnp.arange(MB * BS)
+    held = pos < lengths[:, None, None]
     mask = jax.random.bernoulli(ks[2], 0.4, (F, C, MB * BS))
-    mask = mask & (jnp.arange(MB * BS) < lengths[:, None, None])
+    if kind == "page_1_bare":
+        mask &= pos // BS != 1
+    elif kind == "all":
+        mask |= True
     mask = mask.at[0, 0].set(False)  # a query that chose nothing cached
-    acc, m, l = sa.masked_latent_partials(q, pool, jnp.int32(0), tables, lengths, mask, vd, 0.3)
-    dense = pool[0][tables, 0].reshape(F, MB * BS, width)
-    want = _dense_partials(q, dense, mask, vd, 0.3)
-    assert acc.shape == (F, C, H, vd)
-    assert float(jnp.abs(_normalised(acc, m, l) - _normalised(*want)).max()) < 2e-5
-    assert float(l[0, 0].max()) == 0.0 and float(l[1].max()) == 0.0
+    # the mask is handed over as it is: what it says past a row's length
+    # is the kernel's and the reference's to leave out
+    got = pa.paged_flash_attention(
+        q, pool, None, tables, lengths, layer=jnp.int32(1), interpret=True,
+        scale=0.3, value_dim=vd, mask=mask,
+    )
+    want = pa.reference_paged_partials(
+        q, pool[1], None, tables, lengths, scale=0.3, value_dim=vd, mask=mask
+    )
+    dense = pool[1][tables, 0].reshape(F, MB * BS, width)
+    plain = _dense_partials(q, dense, mask & held, vd, 0.3)
+    assert got[0].shape == (F, C, H, vd)
+    live = np.asarray(plain[2]) > 0
+    assert (np.asarray(got[2]) > 0).tolist() == live.tolist()
+    assert (np.asarray(want[2]) > 0).tolist() == live.tolist()
+    assert not live[0, 0].any() and live.any() == (max(lens) > 0)
+    for other in (want, plain):
+        a, b = np.asarray(_normalised(*got)), np.asarray(_normalised(*other))
+        assert np.abs(a - b)[live].max(initial=0.0) < 5e-5
+        mass = [np.asarray(x[2] * jnp.exp(x[1]))[live] for x in (got, other)]
+        assert np.abs(mass[0] / mass[1] - 1).max(initial=0.0) < 5e-5
+    assert float(jnp.abs(got[0][0, 0]).max()) == 0.0
+    if kind == "all":  # the call without the operand, to the bit
+        bare = pa.paged_flash_attention(
+            q[:, 1:], pool, None, tables, lengths, layer=jnp.int32(1),
+            interpret=True, scale=0.3, value_dim=vd,
+        )
+        for g, w in zip(got, bare):
+            assert (np.asarray(g[:, 1:]) == np.asarray(w)).all()
+
+
+def test_a_selection_rides_beside_key_and_value_pools_too():
+    """The operand is the kernel's, not the latent mode's: K and V pools of
+    two kv heads, three query heads each (a tile's row ``t * 3 + i``)."""
+    B, Q, Hq, Hkv, hd, NB, BS, MB = 2, 11, 6, 2, 128, 16, 8, 4
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    kp = jax.random.normal(ks[0], (NB, Hkv, BS, hd))
+    vp = jax.random.normal(ks[1], (NB, Hkv, BS, hd))
+    q = jax.random.normal(ks[2], (B, Q, Hq, hd))
+    tables, lengths = _tables(B, MB, NB, 4), jnp.asarray([27, 5], jnp.int32)
+    mask = jax.random.bernoulli(ks[3], 0.5, (B, Q, MB * BS))
+    got = pa.paged_flash_attention(q, kp, vp, tables, lengths, interpret=True, mask=mask)
+    want = pa.reference_paged_partials(q, kp, vp, tables, lengths, mask=mask)
+    live = np.asarray(want[2]) > 0
+    a, b = np.asarray(_normalised(*got)), np.asarray(_normalised(*want))
+    assert live.mean() > 0.9 and np.abs(a - b)[live].max() < 5e-5
+    with pytest.raises(AssertionError, match="window"):
+        pa.paged_flash_attention(
+            q, kp, vp, tables, lengths, interpret=True, mask=mask, window=9
+        )
 
 
 @pytest.mark.parametrize("Q,shift", [(1, 0), (1, 3), (6, 0)])
@@ -161,6 +231,42 @@ def test_the_paged_kernels_latent_mode_under_the_window_is_its_reference(Q, shif
 #: depths with a third of the rows dead; page and tile boundaries; anything
 NAN_AUDIT_ROWS = {"siblings": 10, "dead_rows": 11, "boundaries": 12, "any": 13}
 
+#: the interpreter as the audits run it: what the kernel has not written
+#: reads NaN, a copy lands when it is WAITED for, races are looked for
+NAN_AUDIT = dict(
+    uninitialized_memory="nan", detect_races=True, dma_execution_mode="on_wait"
+)
+
+
+def _nan_pool_but_for(rng, lens, L, layer, BS, MB, width, tile, window=None):
+    """``(pool, tables)``: a pool of NaN in which each row of ``lens``
+    cached positions holds numbers in the tiles the kernel copies for it
+    and nowhere else (from its window's first page on, as far as the last
+    tile that holds a cached position), and tables whose other columns
+    name pages at random (stale ids: NaN pages)."""
+    B = len(lens)
+    NB = 3 * B + 1
+    pool = np.full((L, NB, 1, BS, width), np.nan, np.float32)
+    tables = rng.integers(0, NB, (B, MB)).astype(np.int32)
+    free = list(rng.permutation(NB))
+    for b, n in enumerate(lens):
+        first = 0 if window is None else max(n - (window - 1), 0) // BS
+        for c in range(first, -(-n // BS)):
+            tables[b, c] = pid = free.pop()
+            upto = min(BS, -(-(n - c * BS) // tile) * tile)
+            pool[layer, pid, 0, :upto] = rng.standard_normal((upto, width)) * 0.3
+    return pool, jnp.asarray(tables)
+
+
+def _assert_finite_equal_and_no_race(got, want, live, case):
+    for g, w, tol in zip(got, want, (5e-5, 1e-6, 5e-4)):
+        g, w = np.asarray(g)[live], np.asarray(w)[live]
+        assert np.isfinite(g).all(), (case, int((~np.isfinite(g)).sum()))
+        assert np.abs(g - w).max() < tol, (case, float(np.abs(g - w).max()))
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+
+    assert not interpret_pallas_call.races.races_found
+
 
 @pytest.mark.parametrize("case", list(NAN_AUDIT_ROWS))
 def test_the_windowed_latent_kernel_reads_only_what_it_copied(case):
@@ -193,25 +299,17 @@ def test_the_windowed_latent_kernel_reads_only_what_it_copied(case):
         lens = np.asarray([0, 1, 255, 256, 257, 511, 512, 513, 768, 1024, 1025, 2048])
     else:
         lens = rng.integers(0, 3, B) * BS + rng.integers(0, BS, B)
-    NB, layer, shift = 3 * B + 1, int(rng.integers(0, L)), int(rng.integers(0, 8))
-    pool = np.full((L, NB, 1, BS, width), np.nan, np.float32)
-    tables = rng.integers(0, NB, (B, MB)).astype(np.int32)
-    free = list(rng.permutation(NB))
-    for b, n in enumerate(lens.tolist()):
-        for c in range(max(n - (W - 1), 0) // BS, -(-n // BS)):
-            tables[b, c] = pid = free.pop()
-            upto = min(BS, -(-(n - c * BS) // tile) * tile)
-            pool[layer, pid, 0, :upto] = rng.standard_normal((upto, width)) * 0.3
+    layer, shift = int(rng.integers(0, L)), int(rng.integers(0, 8))
+    pool, tables = _nan_pool_but_for(
+        rng, lens.tolist(), L, layer, BS, MB, width, tile, window=W
+    )
     q = jnp.asarray(rng.standard_normal((B, 1, H, width)) * 0.3, jnp.bfloat16)
-    tables, lengths = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+    lengths = jnp.asarray(lens, jnp.int32)
     got = pa.paged_flash_attention(
         q, jnp.asarray(pool, jnp.bfloat16), None, tables, lengths,
         layer=jnp.int32(layer), scale=0.05, value_dim=vd, window=W,
         window_shift=jnp.int32(shift),
-        interpret=pltpu.InterpretParams(
-            uninitialized_memory="nan", detect_races=True,
-            dma_execution_mode="on_wait",
-        ),
+        interpret=pltpu.InterpretParams(**NAN_AUDIT),
     )
     want = pa.reference_paged_partials(
         q, jnp.asarray(np.nan_to_num(pool[layer]), jnp.bfloat16), None, tables,
@@ -219,10 +317,44 @@ def test_the_windowed_latent_kernel_reads_only_what_it_copied(case):
     )
     live = np.asarray(lens) > 0
     assert live.sum() >= 6
-    for g, w, tol in zip(got, want, (5e-5, 1e-6, 5e-4)):
-        g, w = np.asarray(g)[live], np.asarray(w)[live]
-        assert np.isfinite(g).all(), (case, int((~np.isfinite(g)).sum()))
-        assert np.abs(g - w).max() < tol, (case, float(np.abs(g - w).max()))
-    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    _assert_finite_equal_and_no_race(got, want, live, case)
 
-    assert not interpret_pallas_call.races.races_found
+
+def test_the_masked_latent_fill_reads_only_what_it_copied():
+    """The same audit of ``paged_mla_masked_fill``: the cell's pages and
+    tiles, a chunk of three query tiles (the last one padded), rows that
+    end on and beside a page and a tile boundary, two siblings on the same
+    pages, a dead row FIRST and one in the middle, a row that needs a
+    second page step.  The selection is a block a step through the
+    pipeline: the steps past a row's last page repeat its last block, a
+    dead row reads its first, and what either holds past the row's length
+    (here: everything chosen) is not attended.  One query chose nothing."""
+    pltpu.reset_tpu_interpret_mode_state()
+    H, width, vd, BS, MB, L, C = 8, 640, 512, 512, 8, 2, 150
+    lens = [0, 1, 256, 257, 512, 513, 0, 1100, 2304, 2304]
+    B = len(lens)
+    tile = pa.page_tile((L, 3 * B + 1, 1, BS, width), jnp.bfloat16)
+    assert tile == 256 and pa._plan_tiles(C, H, 1, BS, width, 2, False, MB, True)[:2] == (4, 64)
+    rng = np.random.default_rng(B)
+    pool, tables = _nan_pool_but_for(rng, lens, L, 1, BS, MB, width, tile)
+    tables = tables.at[9].set(tables[8])
+    q = jnp.asarray(rng.standard_normal((B, C, H, width)) * 0.3, jnp.bfloat16)
+    mask = jnp.asarray(rng.random((B, C, MB * BS)) < 0.3)
+    mask = mask.at[:, :, 2400:].set(True).at[7, 5].set(False)
+    lengths = jnp.asarray(lens, jnp.int32)
+    got = pa.paged_flash_attention(
+        q, jnp.asarray(pool, jnp.bfloat16), None, tables, lengths,
+        layer=jnp.int32(1), scale=0.05, value_dim=vd, mask=mask,
+        interpret=pltpu.InterpretParams(**NAN_AUDIT),
+    )
+    want = pa.reference_paged_partials(
+        q, jnp.asarray(np.nan_to_num(pool[1]), jnp.bfloat16), None, tables,
+        lengths, scale=0.05, value_dim=vd, mask=mask,
+    )
+    live = np.asarray(want[2]) > 0
+    assert not live[7, 5].any() and not live[[0, 6]].any() and live[8:].all()
+    assert ((np.asarray(got[2]) > 0) == live).all()
+    # who chose nothing cached has no mass and no value, not a NaN
+    assert all(np.isfinite(np.asarray(g)).all() for g in got)
+    assert float(jnp.abs(got[0][7, 5]).max()) == 0.0
+    _assert_finite_equal_and_no_race(got, want, live, "masked_fill")

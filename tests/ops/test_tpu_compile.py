@@ -991,6 +991,41 @@ def test_latent_kernel_under_the_window_compiles_at_the_cells_tile_plan(
     assert ("paged_mla_window_decode" if Q == 1 else "paged_mla_window_fill") in text
 
 
+@pytest.mark.parametrize("B", [1, 4])
+def test_latent_kernel_under_a_selection_compiles_at_the_cells_tile_plan(
+    one_chip, B
+):
+    """The paged kernel's latent mode WITH a selection: a fill chunk of
+    1,024 queries of 128 heads (four tokens a query tile) over pages of
+    512 x 640, the selection ``[B, 1024, 18432]`` laid out a block of four
+    pages a step; the call without the operand keeps its name."""
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    plan = pa._plan_tiles(1024, 128, 1, 512, 640, 2, False, 36, True)
+    assert plan == pa._plan_tiles(1024, 128, 1, 512, 640, 2, False, 36) == (4, 4, 256)
+
+    def call(q, pool, tables, lengths, layer, mask):
+        return pa.paged_flash_attention(
+            q, pool, None, tables, lengths, layer=layer, scale=0.07,
+            value_dim=512, mask=mask,
+        )
+
+    args = (
+        s((B, 1024, 128, 640), jnp.bfloat16),
+        s((2, 896, 1, 512, 640), jnp.bfloat16), s((B, 36), jnp.int32),
+        s((B,), jnp.int32), s((1,), jnp.int32),
+    )
+    compiled = jax.jit(call).lower(*args, s((B, 1024, 18432), jnp.bool_)).compile()
+    _assert_kernel(compiled)
+    assert "paged_mla_masked_fill" in compiled.as_text()
+    if B == 1:
+        bare = jax.jit(lambda *a: call(*a, None)).lower(*args).compile()
+        assert "paged_mla_fill" in bare.as_text()
+        assert "masked" not in bare.as_text()
+
+
 def _assert_sparse_program_fits(compiled, pools):
     for kind in pools:
         for pool in pools[kind]:
@@ -1013,9 +1048,10 @@ def test_sparse_fill_program_fits_beside_weights_and_three_pools(
 ):
     """``hybrid_fill_chunk`` whole at the sparse cell's shapes: the window
     layers' prefix part is ``paged_mla_window_fill`` (the name the readers
-    match; NO whole-prefix latent call: a full layer scores its index
-    pages and attends under its mask in XLA), every pool an operand in
-    its own layout, and
+    match), a full layer's is ``paged_mla_masked_fill`` (its index scores
+    and its mask are XLA's, the masked prefix the paged kernel's under the
+    selection: no loop over pages in XLA, and no UNMASKED whole-prefix
+    call), every pool an operand in its own layout, and
     5.15 GB of weights + 2.3 GB of pools + the chunk's temporaries (the
     in-chunk scores of 128 heads: 0.54 GB a row in float32) fit one
     chip."""
@@ -1029,7 +1065,7 @@ def test_sparse_fill_program_fits_beside_weights_and_three_pools(
         use_kernel=True, win_pools=pools["window"], win_tables=table,
     ).compile()
     text = compiled.as_text()
-    assert "paged_mla_window_fill" in text
+    assert "paged_mla_window_fill" in text and "paged_mla_masked_fill" in text
     assert "paged_mla_fill" not in text and "paged_attn" not in text
     assert "ragged-dot" not in text
     total, temp = _assert_sparse_program_fits(compiled, pools)
