@@ -89,7 +89,11 @@ from areal_tpu.observability.hbm_ledger import (
     tree_nbytes,
 )
 from areal_tpu.observability.latency import LatencyDigest, LatencyRecord
-from areal_tpu.observability.table import ENGINE_PHASES
+from areal_tpu.observability.table import (
+    ENGINE_PHASES,
+    STEP_DELTAS,
+    admit_stop,
+)
 from areal_tpu.observability.tracing import PhaseClock, get_tracer
 
 #: ``cache_mode="auto"``: dense rows below this ``kv_cache_len`` (short
@@ -1147,7 +1151,24 @@ class ContinuousBatchingEngine:
         # trace when one is being taken) whose self seconds also add up
         # here, always.  ``timing_split()`` reads its host/device/fetch
         # split off these totals.
-        self._phases = PhaseClock(ENGINE_PHASES)
+        # And a record a step, always (``tracing.PhaseClock``'s laps):
+        # ``_count_step`` notes the step's counts; ``tracing.step_logs()``
+        # and the server's ``steps.<worker>.jsonl`` give the window-long
+        # account that a traced slice of a step or two cannot.
+        self._phases = PhaseClock(ENGINE_PHASES, log="engine")
+        self._phases.about = dict(
+            max_batch=self.max_batch, chunk_size=self.chunk_size,
+            pipeline_depth=self.pipeline_depth,
+        )
+        #: why the last admission left the queue standing
+        #: (``table.ADMIT_STOPS``)
+        self._admit_stopped_by = admit_stop("queue_empty")
+        # running totals a step's record differences (``_step_totals``)
+        self.rows_admitted_total = 0
+        self.rows_finished_total = 0
+        self.chunks_dispatched_total = 0
+        self.decode_rows_dispatched_total = 0
+        self.fill_slots_total = 0  # f_pad x c of every fill program
         self.chunks_total = 0
         #: every token handed to a row, first tokens included, counted
         #: where it is handed over (``gen_tokens_total`` moves only when
@@ -1178,6 +1199,7 @@ class ContinuousBatchingEngine:
         # True = decode only, admit nothing (drain-before-update servers)
         self.hold_admissions = False
         self._step_seq = 0  # deterministic clock (one tick per step())
+        self._counted = self._step_totals()  # at the last step's record
         self._epoch_counter = 0  # admission/resume stamp source
         # lifetime tokens folded in by harvests; step() reports its own
         # delta of this so tokens harvested by MID-STEP ring drains
@@ -3739,45 +3761,36 @@ class ContinuousBatchingEngine:
         C = bucket_len(max(take for _, take in batch))
         F_pad = 1 << (len(batch) - 1).bit_length()
         self.fill_shapes_run[F_pad, C] += 1
+        self.fill_slots_total += F_pad * C
+        # the counts of THIS event (the running totals a fill moves that
+        # no reader of the trace reads are engine attributes, logged once
+        # when the server exits)
         counts = dict(
             prompts=len(batch), f_pad=F_pad, c=C,
             tokens=sum(take for _, take in batch),
-            # running totals: first tokens that reached their rows on the
-            # device first, and those the host fetched at once
-            first_tokens_deferred=self.first_tokens_deferred_total,
-            first_tokens_blocking=self.first_tokens_blocking_total,
         )
         if self._stateful:
-            # running totals: sibling copies of a fill's end state, late
-            # siblings that prefilled their prompt again, and those that
-            # joined its kept fill (KeptFills.counts)
+            # running totals the drivers' window records read: sibling
+            # copies of a fill's end state, and late siblings that
+            # prefilled their prompt again
             counts.update(
                 state_copies=self.state_copies_total,
                 state_reprefills=self.state_reprefills_total,
-                **self._kept.counts(),
             )
         grouped = False
         if self._by_kind and self.cfg.n_experts:
-            # running totals, this batch's tokens among them: the host
-            # knows from the batch's shape which product its experts take
+            # the host knows from the batch's shape which product its
+            # experts take
             grouped = bool(moe.group_rows(self.cfg, F_pad * C))
             self.moe_fill_tokens_total += counts["tokens"]
             if grouped:
                 self.moe_fill_tokens_grouped_total += counts["tokens"]
             self._add_fill_rounds_that_arrived()
-            counts.update(
-                moe_fill_tokens=self.moe_fill_tokens_total,
-                moe_fill_tokens_grouped=self.moe_fill_tokens_grouped_total,
-                moe_fill_extra_rounds=self.moe_fill_extra_rounds_total,
-            )
         if self._by_kind:
             self.fill_tail_positions_saved_total += (
                 self.fill_tail_layers * (F_pad * C - F_pad)
             )
-            counts.update(
-                tail_layers=self.fill_tail_layers,
-                fill_tail_positions_saved=self.fill_tail_positions_saved_total,
-            )
+            counts.update(tail_layers=self.fill_tail_layers)
         with self._phases.phase("areal.engine.fill.dispatch", **counts):
             toks = np.zeros((F_pad, C), np.int32)
             starts = np.zeros((F_pad,), np.int32)
@@ -4296,8 +4309,12 @@ class ContinuousBatchingEngine:
         """Returns (rows admitted, those whose fill starts behind a
         cached prefix)."""
         if self.hold_admissions:
+            self._admit_stopped_by = admit_stop("held")
             return 0, 0
         admitted = prefix_hits = 0
+        # (what stopped the preempted rows' queue stands unless the
+        # requests' queue stops on something too)
+        stopped_by = admit_stop("queue_empty")
         for row_id, row in enumerate(self.rows):
             if row is not None and row.parked and (
                 self._step_seq - row.park_step > self.park_ttl_steps
@@ -4338,12 +4355,14 @@ class ContinuousBatchingEngine:
             seq = (row.prompt + row.generated)[:-1]
             rid = take_row()
             if rid is None:
+                stopped_by = admit_stop("no_slot")
                 break
             with self._lock:
                 queued = {r.qid for r in self._pending}
             fill = self._new_fill(seq, keep_qids=queued)
             if fill is None:
                 free.insert(0, rid)
+                stopped_by = admit_stop("no_pages")
                 break
             self._preempted.pop(0)
             fill.state_slot = rid
@@ -4396,6 +4415,7 @@ class ContinuousBatchingEngine:
                 # joins its prompt's kept fill at the next step)
                 with self._lock:
                     self._pending.insert(0, req)
+                stopped_by = admit_stop("late_join_cap")
                 break
             if fill is None and self._maybe_pull_prefix(req, prompt):
                 # fleet pull in flight: requeue step-keyed until the
@@ -4403,11 +4423,13 @@ class ContinuousBatchingEngine:
                 # fails closed and the next pass re-prefills plainly)
                 with self._lock:
                     self._pending.insert(0, req)
+                stopped_by = admit_stop("prefix_pull")
                 break
             rid = take_row()
             if rid is None:
                 with self._lock:
                     self._pending.insert(0, req)
+                stopped_by = admit_stop("no_slot")
                 break
             if fill is None:
                 # a late sibling of a stateful stack's prompt: the fill
@@ -4431,6 +4453,7 @@ class ContinuousBatchingEngine:
                     free.insert(0, rid)
                     with self._lock:
                         self._pending.insert(0, req)
+                    stopped_by = admit_stop("no_pages")
                     break
                 self._filling.append(fill)
                 fill.state_slot = rid
@@ -4461,6 +4484,7 @@ class ContinuousBatchingEngine:
             self._slo_admitted(row)
             self.rows[rid] = row
             admitted += 1
+        self._admit_stopped_by = stopped_by
         return admitted, prefix_hits
 
     def _ensure_decode_blocks(self) -> Tuple[int, int]:
@@ -4950,7 +4974,9 @@ class ContinuousBatchingEngine:
     def _admit(self) -> int:
         """Returns the rows admitted."""
         if self.hold_admissions:
+            self._admit_stopped_by = admit_stop("held")
             return 0
+        self._admit_stopped_by = admit_stop("queue_empty")
         # expired parked rows first: a row parked past the TTL is likely
         # abandoned (rollout dropped, or the group finished elsewhere)
         for row_id, row in enumerate(self.rows):
@@ -4977,6 +5003,7 @@ class ContinuousBatchingEngine:
                 if evicted is None:
                     with self._lock:
                         self._pending.insert(0, req)
+                    self._admit_stopped_by = admit_stop("no_slot")
                     break
                 free.append(evicted)
             # input_ids = prompt + previously generated tokens (chunked
@@ -5060,6 +5087,7 @@ class ContinuousBatchingEngine:
         self, row_id: int, row: _Row, started: bool = True, park: bool = False
     ):
         self._slo_finish(row)
+        self.rows_finished_total += 1
         out = model_api.APIGenerateOutput.from_input(row.req)
         out.output_ids = list(row.generated)
         out.output_logprobs = list(row.logprobs)
@@ -5232,6 +5260,8 @@ class ContinuousBatchingEngine:
         )
         if jax_compat.start_host_copies(arrs):
             self.async_fetches_total += 1
+        self.chunks_dispatched_total += 1
+        self.decode_rows_dispatched_total += len(snapshot)
         # the first chunk to decode the rows activated since the last one:
         # their first tokens are folded at its harvest, ahead of its own
         self._ring.append(
@@ -5467,6 +5497,11 @@ class ContinuousBatchingEngine:
             # sleep is outside the span: it would read as host overhead)
             with self._phases.phase("areal.engine.step") as span:
                 n = self._drain_ring()
+                # (a pause holds admissions too)
+                self._admit_stopped_by = (
+                    admit_stop("held") if self._pending
+                    else admit_stop("queue_empty")
+                )
                 self._count_step(span)
             if n == 0:
                 time.sleep(0.01)
@@ -5479,6 +5514,7 @@ class ContinuousBatchingEngine:
                 if self.paged:
                     with self._phases.phase("areal.engine.admit") as sp:
                         admitted, hits = self._admit_paged()
+                        self.rows_admitted_total += admitted
                         sp.set_metadata(
                             rows_admitted=admitted, prefix_hits=hits
                         )
@@ -5507,9 +5543,9 @@ class ContinuousBatchingEngine:
                             dispatched = True
                 else:
                     with self._phases.phase("areal.engine.admit") as sp:
-                        sp.set_metadata(
-                            rows_admitted=self._admit(), prefix_hits=0
-                        )
+                        admitted = self._admit()
+                        self.rows_admitted_total += admitted
+                        sp.set_metadata(rows_admitted=admitted, prefix_hits=0)
                     dispatched = False
                     if (
                         self.n_decoding > 0
@@ -5531,23 +5567,57 @@ class ContinuousBatchingEngine:
                 self._ledger_sync_host_buffers()
                 self._count_step(span)
 
+    def _step_totals(self) -> Tuple[int, ...]:
+        """The running totals whose movement over a step is in its record,
+        in ``STEP_DELTAS``' order."""
+        return (
+            self.tokens_emitted_total, self.rows_admitted_total,
+            self.rows_finished_total, self.preempted_total,
+            self.chunks_dispatched_total, self.decode_rows_dispatched_total,
+            self.prefill_calls, self.prefill_tokens_total,
+            self.fill_slots_total, self._kept.late_joins_total,
+        )
+
     def _count_step(self, span):
-        """The engine's state at the end of a step, as the step span's
-        counts (read only while a profiler session records them)."""
-        if not span.is_enabled():
-            return
-        decoding = filling = 0
+        """The one place that counts a step, traced or not: the engine's
+        state at the step's end and what moved since the last step's
+        count (the totals that move between two steps, a cancel's drain,
+        are in the later one's), as the step span's counts and as the
+        notes of the clock's record (``table.ENGINE_STEP_RECORD``).  One
+        pass over the rows; nothing per token or per page, nothing from
+        the device."""
+        decoding = filling = parked = empty = 0
         for r in self.rows:
-            if r is not None and not r.parked:
-                if r.filling:
-                    filling += 1
-                else:
-                    decoding += 1
+            if r is None:
+                empty += 1
+            elif r.parked:
+                parked += 1
+            elif r.filling:
+                filling += 1
+            else:
+                decoding += 1
+        pending, ring = len(self._pending), len(self._ring)
         span.set_metadata(
-            step=self._step_seq,
-            rows_decoding=decoding,
-            rows_filling=filling,
-            pending=len(self._pending),
-            ring=len(self._ring),
+            step=self._step_seq, rows_decoding=decoding,
+            rows_filling=filling, pending=pending, ring=ring,
             tokens_emitted_total=self.tokens_emitted_total,
         )
+        totals = self._step_totals()
+        self._phases.note(
+            step=self._step_seq, slots_decoding=decoding,
+            slots_filling=filling, slots_parked=parked, slots_empty=empty,
+            pending=pending, admit_stopped_by=self._admit_stopped_by,
+            ring=ring, chunk_size=self.chunk_size, version=self.version,
+            **{
+                name: now - before for name, now, before
+                in zip(STEP_DELTAS, totals, self._counted)
+            },
+        )
+        if (
+            totals == self._counted
+            and not (decoding or filling or ring)
+        ):
+            # nothing moved and nothing is in flight: an idle engine's
+            # poll, a pause; one record for the whole stretch of them
+            self._phases.quiet()
+        self._counted = totals

@@ -41,7 +41,8 @@ from areal_tpu.engine import batching
 from areal_tpu.engine.optimizer import OptimizerConfig, make_optimizer
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import param_pspecs, takes_flash
-from areal_tpu.observability.tracing import phase, region
+from areal_tpu.observability.table import TRAIN_PHASES
+from areal_tpu.observability.tracing import PhaseClock, region
 from areal_tpu.ops import flash_attention
 from areal_tpu.ops import loss as loss_ops
 
@@ -162,6 +163,15 @@ class TrainEngine:
         self._loss_head_products: Dict[Tuple, int] = {}
         self._fwd_step_cache: Dict[int, Tuple[Callable, Callable]] = {}
         self.version = 0
+        # what the trainer's thread is doing: a batch's phases are spans
+        # in any profiler capture, their self seconds add up here always,
+        # and each batch leaves a record (``tracing.PhaseClock``'s laps;
+        # ``table.TRAIN_BATCH_RECORD``).  The time BETWEEN two batches,
+        # what the interface, the worker and the caller did with the
+        # device waiting, is a record's ``t0`` less the one before's ``t1``.
+        self._phases = PhaseClock(TRAIN_PHASES, log=f"train.{self.name}")
+        self._phases.about = dict(model=self.name)
+        self.batches_total = 0
 
         # observability: step time / token throughput / MFU, scraped off the
         # hosting worker's /metrics endpoint
@@ -393,8 +403,9 @@ class TrainEngine:
 
         assert self.tx is not None, "engine built without an optimizer"
         tik = time.perf_counter()
-        with phase("areal.train.batch") as span:
-            with phase("areal.train.pack"):
+        clock = self._phases
+        with clock.phase("areal.train.batch") as span:
+            with clock.phase("areal.train.pack"):
                 plan = plan_layout(
                     self.model_cfg,
                     sample.seqlens[token_key],
@@ -405,7 +416,7 @@ class TrainEngine:
                 )
                 stacked, pbs = self._stack_batches(sample, plan, token_key)
             rows, row_len = pbs[0].shape
-            with phase("areal.train.upload"):
+            with clock.phase("areal.train.upload"):
                 batch = self._upload_stacked(stacked, rows)
             n_mbs = next(iter(batch.values())).shape[0]  # bucketed count
             # padding waste of this step's device layout: stacked
@@ -429,13 +440,14 @@ class TrainEngine:
             )
             self.attn_blocks_run_total += blocks_run
             self.attn_blocks_causal_total += blocks_causal
-            span.set_metadata(
+            counts = dict(
                 real_tokens=real_tokens, padded_slots=slots, n_mbs=n_mbs,
                 rows=rows, row_len=row_len, attn_blocks_run=blocks_run,
                 attn_blocks_causal=blocks_causal,
             )
+            span.set_metadata(**counts)
             step = self._get_train_step(loss_fn, n_mbs)
-            with phase("areal.train.dispatch"):
+            with clock.phase("areal.train.dispatch"):
                 self.params, self.opt_state, out = step(
                     self.params, self.opt_state, batch
                 )
@@ -445,7 +457,11 @@ class TrainEngine:
                 ]
             )
             self.version += 1
-            with phase("areal.train.sync"):
+            self.batches_total += 1
+            clock.note(
+                batch=self.batches_total, version=self.version, **counts
+            )
+            with clock.phase("areal.train.sync"):
                 out = jax.device_get(out)  # ONE host sync per train step
         elapsed = time.perf_counter() - tik
         denom_f = float(out["denom"])

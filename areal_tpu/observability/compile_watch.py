@@ -18,7 +18,9 @@ module watches two signals:
   ``areal_xla_compile_seconds`` histogram plus an ``fn="backend"``
   counter row.  One module-level listener dispatches to every live
   watch — jax offers registration but no unregistration, so instances
-  enroll in a WeakSet instead of stacking dead listeners.
+  enroll in a WeakSet instead of stacking dead listeners.  It also keeps
+  the process's own pair (:func:`backend_compiles`), which a
+  ``PhaseClock`` differences over each lap: WHICH step compiled.
 
 **Steady-state guard**: after ``GenServerConfig.compile_quiet_after_steps``
 engine steps the watch is marked steady; any compile on a watched
@@ -34,7 +36,7 @@ from __future__ import annotations
 
 import threading
 import weakref
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 from areal_tpu.observability.registry import get_registry
 from areal_tpu.observability.tracing import get_tracer
@@ -44,11 +46,16 @@ from areal_tpu.observability.tracing import get_tracer
 _active_watches: "weakref.WeakSet[CompileWatch]" = weakref.WeakSet()
 _listener_lock = threading.Lock()
 _listener_installed = False
+#: [backend compiles, their seconds] of the process since the listener
+#: went in (written by whichever thread compiles; read without a lock)
+_process_compiles = [0, 0.0]
 
 
 def _on_jax_event_duration(name: str, secs: float, **kw) -> None:
     if "backend_compile" not in name:
         return
+    _process_compiles[0] += 1
+    _process_compiles[1] += float(secs)
     for watch in list(_active_watches):
         watch._note_backend_compile(float(secs))
 
@@ -71,6 +78,14 @@ def _install_monitoring_listener() -> bool:
             return False
         _listener_installed = True
         return True
+
+
+def backend_compiles() -> Tuple[int, float]:
+    """(count, seconds) of the process's backend compiles so far; installs
+    the listener on first use ((0, 0.0) for good without jax.monitoring)."""
+    if not _listener_installed:
+        _install_monitoring_listener()
+    return _process_compiles[0], _process_compiles[1]
 
 
 class CompileWatch:

@@ -1114,7 +1114,9 @@ TRACE_TABLE = [
         "phase",
         "One engine step, the paused branch's sleep excluded (counts at "
         "its end: step, rows_decoding, rows_filling, pending, ring, "
-        "tokens_emitted_total)",
+        "tokens_emitted_total); one lap of the engine's PhaseClock, "
+        "whose record (ENGINE_STEP_RECORD) holds the same numbers and "
+        "more, traced or not",
     ),
     TraceSpec(
         "areal.engine.swap",
@@ -1134,32 +1136,16 @@ TRACE_TABLE = [
         "areal.engine.fill.dispatch",
         "phase",
         "One batched prefill chunk built on the host and dispatched "
-        "(counts: prompts, f_pad, c, tokens; the running totals "
-        "first_tokens_deferred = fresh targets whose first token went to "
-        "its row on the device and reached the host at a later harvest, "
-        "first_tokens_blocking = those the host fetched at once; for a "
-        "model with recurrent "
-        "state also the running totals state_copies = copies of a "
-        "fill's end state to the siblings queued on it, "
-        "state_late_joins = requests admitted after their prompt's fill "
-        "had ended that joined the KEPT fill (end state in a snapshot "
-        "slot, pages by reference, last logits row: no fill program ran "
-        "for them), state_reprefills = those whose prompt a live row "
-        "carries and whose kept fill was gone, so that they prefilled "
-        "it again (state_late_joins / (state_late_joins + "
-        "state_reprefills) is the kept fills' hit share), "
-        "state_fills_kept, and state_fills_evicted_slots / _pages / "
-        "_swap = kept fills let go for a newer fill's snapshot slot, "
-        "for a live row's page, at a weight swap; for a model "
-        "that holds a share of the experts the running totals "
-        "moe_fill_tokens, moe_fill_tokens_grouped = those in a batch "
-        "whose shape takes the grouped product, moe_fill_extra_rounds = "
-        "rounds past the first of the fills whose programs have run; "
-        "for a stack stated by kind tail_layers = layers of its "
-        "keep-nothing tail, which a fill runs on each row's last "
-        "position alone, and the running total "
-        "fill_tail_positions_saved = tail_layers x (f_pad x c - f_pad) "
-        "summed over the fills)",
+        "(counts: prompts, f_pad, c, tokens; for a model with "
+        "recurrent state also the running totals the drivers' window "
+        "records read, state_copies = copies of a fill's end state to "
+        "the siblings queued on it and state_reprefills = late siblings "
+        "whose prompt a live row carries and whose kept fill was gone, "
+        "so that they prefilled it again; for a stack stated by kind "
+        "tail_layers = layers of its keep-nothing tail, which a fill "
+        "runs on each row's last position alone).  The other running "
+        "totals a fill moves are engine attributes, logged once when "
+        "the server exits",
     ),
     TraceSpec(
         "areal.engine.fill.first_token_wait",
@@ -1237,17 +1223,19 @@ TRACE_TABLE = [
     TraceSpec(
         "areal.phase.begin",
         "phase",
-        "No length: a phase of a PhaseClock (the areal.engine.* spans) "
-        "begins on this thread.  The profiler drops a span still open "
-        "when its session stops; this one survives (counts: of = the "
-        "phase's name)",
+        "No length: a phase of a PhaseClock (the areal.engine.* and "
+        "areal.train.batch.. spans) begins on this thread.  The profiler "
+        "drops a span still open when its session stops; this one "
+        "survives (counts: of = the phase's name, t = "
+        "time.perf_counter() at the mark, seq = the lap's, its record's)",
     ),
     TraceSpec(
         "areal.phase.end",
         "phase",
         "No length: a phase of a PhaseClock has ended on this thread; "
         "survives where the phase began before the session (counts: of "
-        "= the phase's name, seconds = how long it lasted)",
+        "= the phase's name, seconds = how long it lasted, t and seq as "
+        "at its begin)",
     ),
     # -- phase spans: gserver manager thread ----------------------------------
     TraceSpec(
@@ -1269,13 +1257,16 @@ TRACE_TABLE = [
         "phase",
         "One TrainEngine.train_batch call (counts: real_tokens, "
         "padded_slots, n_mbs, rows, row_len, attn_blocks_run, "
-        "attn_blocks_causal, loss_head_products)",
+        "attn_blocks_causal, loss_head_products); one lap of the "
+        "trainer's PhaseClock, whose record (TRAIN_BATCH_RECORD) holds "
+        "the same counts, traced or not",
     ),
     TraceSpec(
         "areal.train.pack",
         "phase",
         "The sample split into micro-batches and laid out as stacked "
-        "numpy arrays",
+        "numpy arrays (a phase of the trainer's PhaseClock, like the "
+        "three below: self seconds always, marks in a capture)",
     ),
     TraceSpec(
         "areal.train.upload",
@@ -1432,6 +1423,137 @@ TRACE_TABLE = [
         "optimizer's update, the parameters' update",
     ),
 ]
+
+#: names of the trainer thread's phases (``TrainEngine``'s clock): a lap
+#: is one ``areal.train.batch``
+TRAIN_PHASES = tuple(
+    s.name
+    for s in TRACE_TABLE
+    if s.kind == "phase"
+    and s.name.startswith("areal.train.")
+    and s.name != "areal.train.step"  # (the interface's, around the batches)
+)
+
+
+@dataclasses.dataclass(frozen=True)
+class AdmitStopSpec:
+    """One value of a step record's ``admit_stopped_by``."""
+
+    name: str
+    help: str
+
+
+#: why an engine step's admission left the queue standing: the ONE
+#: reason it stopped on (``_admit_paged``, ``_admit``, the paused
+#: branch).  Linted both ways like the stall kinds: every literal goes
+#: through :func:`admit_stop` at its site.
+ADMIT_STOP_TABLE = [
+    AdmitStopSpec("queue_empty", "Nothing was queued, or all of it was admitted"),
+    AdmitStopSpec(
+        "no_slot",
+        "Every slot holds a row and no parked row could be evicted",
+    ),
+    AdmitStopSpec(
+        "no_pages",
+        "A slot was free but the pool (or the snapshot slots) had no "
+        "room for the prompt's fill (_new_fill gave None)",
+    ),
+    AdmitStopSpec(
+        "held",
+        "Admissions are held (hold_admissions: a drain before a weight "
+        "update), or the engine is paused with requests queued",
+    ),
+    AdmitStopSpec(
+        "late_join_cap",
+        "The next request is a late sibling of a kept fill and this "
+        "step's distribution is full (LATE_JOINS_A_STEP)",
+    ),
+    AdmitStopSpec(
+        "prefix_pull",
+        "The next request waits for a prefix being pulled from a peer",
+    ),
+]
+
+ADMIT_STOPS = tuple(s.name for s in ADMIT_STOP_TABLE)
+
+
+def admit_stop(reason: str) -> str:
+    """Validate-and-return an ``admit_stopped_by`` value (the marker the
+    lint collects, as :func:`stall_kind`)."""
+    if reason not in ADMIT_STOPS:
+        raise ValueError(
+            f"unknown admit stop {reason!r}; add it to "
+            "table.ADMIT_STOP_TABLE (and docs) first"
+        )
+    return reason
+
+
+#: a step record's counts that are the movement of a running total over
+#: the step, in the order of ``ContinuousBatchingEngine._step_totals``
+STEP_DELTAS = (
+    "tokens_emitted", "rows_admitted", "rows_finished", "rows_preempted",
+    "decode_chunks", "decode_rows", "fill_programs", "fill_tokens",
+    "fill_slots", "late_joins",
+)
+
+#: what every PhaseClock record holds, whoever owns the clock
+LAP_RECORD = {
+    "seq": "The record's number, from 1 (a mark's seq names it)",
+    "t0": "time.perf_counter() when the lap's phase was entered",
+    "t1": "... and when it was left",
+    "self_s": "Self seconds by phase since the last lap's end (phases at "
+    "0 left out): a run's records sum to phase_seconds()",
+    "compiles": "The PROCESS's backend compiles since the last lap's end "
+    "(jax.monitoring's event, which wraps a load from the persistent "
+    "cache too): which step compiled, or waited for one",
+    "compile_s": "... and their seconds",
+    "quiet_laps": "Only on a record of laps in which nothing moved (an "
+    "idle engine's polls, a pause): how many were folded into it; t1, "
+    "self_s and the counts are up to the last of them",
+}
+
+#: the engine's record a step (``ContinuousBatchingEngine._count_step``)
+ENGINE_STEP_RECORD = {
+    "step": "The engine's step number (its deterministic clock)",
+    "slots_decoding": "Slots whose row decodes at the step's end; the "
+    "four slots_* sum to max_batch",
+    "slots_filling": "Slots held by a row whose prompt is still prefilling",
+    "slots_parked": "Slots held by a parked row (KV resident, no request)",
+    "slots_empty": "Slots without a row",
+    "pending": "Requests queued at the step's end",
+    "admit_stopped_by": "Why admission left the queue standing "
+    "(ADMIT_STOP_TABLE)",
+    "ring": "Chunks in flight at the step's end",
+    "chunk_size": "Decode steps a chunk runs",
+    "version": "The weights' version at the step's end",
+    "tokens_emitted": "Tokens handed to rows since the last record "
+    "(step()'s return and the first tokens folded): sums to "
+    "tokens_emitted_total",
+    "rows_admitted": "Rows given a slot",
+    "rows_finished": "Requests finished (parked or released)",
+    "rows_preempted": "Rows preempted under pool pressure",
+    "decode_chunks": "Decode chunks (or verify windows) dispatched: 0 or "
+    "1 a step, more where a drain re-dispatches",
+    "decode_rows": "Rows in those chunks' snapshots",
+    "fill_programs": "Prefill programs dispatched",
+    "fill_tokens": "Real prompt tokens in them",
+    "fill_slots": "f_pad x c positions they computed",
+    "late_joins": "Late siblings served from a kept fill",
+}
+
+#: the trainer's record a batch (``TrainEngine.train_batch``)
+TRAIN_BATCH_RECORD = {
+    "batch": "The engine's train_batch number, from 1",
+    "real_tokens": "Tokens of the sample",
+    "padded_slots": "n_mbs x rows x row_len the program ran",
+    "rows": "Rows of a micro-batch",
+    "row_len": "Their length",
+    "n_mbs": "Micro-batches stacked (the bucketed count)",
+    "attn_blocks_run": "Flash-attention block pairs the layout runs",
+    "attn_blocks_causal": "... of those under the diagonal",
+    "version": "The weights' version after the batch",
+}
+
 
 #: names of the engine thread's phases: ``engine.phase_seconds()`` and
 #: ``areal_inference_phase_seconds_total{phase=}`` carry exactly these

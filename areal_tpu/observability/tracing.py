@@ -56,6 +56,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import json
 import os
 import re
 import threading
@@ -381,7 +382,12 @@ class _TimedPhase:
     seconds begins and ends inside one.  Each phase therefore also says
     when it begins and when it has ended, in two spans of no length that
     survive: a reader that finds one without the other knows which phase
-    the session's edge cut, from the trace alone."""
+    the session's edge cut, from the trace alone.  Both marks carry the
+    host clock (``t = time.perf_counter()``) and the lap's ``seq`` (the
+    record it leaves; a quiet lap that is folded into the record before
+    it carries the next one's): one
+    mark in a capture puts everything the program stamps with that clock,
+    the clock's records first, on the clock of the device operations."""
 
     __slots__ = ("_clock", "_name", "_span", "_t0", "_children_s")
 
@@ -390,7 +396,10 @@ class _TimedPhase:
         self._name = name
         # an annotation's time starts when it is made: the mark first
         if _recording():
-            with phase("areal.phase.begin", of=name):
+            with phase(
+                "areal.phase.begin", of=name, t=time.perf_counter(),
+                seq=clock.laps + 1,
+            ):
                 pass
         self._span = _annotation(name, counts)
 
@@ -402,17 +411,41 @@ class _TimedPhase:
         return self._span
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
+        t1 = time.perf_counter()
+        dt = t1 - self._t0
         clock = self._clock
+        seq = clock.laps + 1
         clock._open.pop()
         clock.seconds[self._name] += dt - self._children_s
         if clock._open:
             clock._open[-1]._children_s += dt
+        elif self._name == clock.lap:
+            clock._close_lap(self._t0, t1)
         self._span.__exit__(*exc)
         if _recording():
-            with phase("areal.phase.end", of=self._name, seconds=dt):
+            with phase(
+                "areal.phase.end", of=self._name, seconds=dt,
+                t=time.perf_counter(), seq=seq,
+            ):
                 pass
         return False
+
+
+#: laps a clock keeps; the oldest go first, counted
+LAPS_KEPT = 8192
+
+_backend_compiles = None
+
+
+def _compiles() -> tuple:
+    """(backend compiles, their seconds) of the whole process so far
+    (``compile_watch`` imports this module: looked up on first use)."""
+    global _backend_compiles
+    if _backend_compiles is None:
+        from areal_tpu.observability.compile_watch import backend_compiles
+
+        _backend_compiles = backend_compiles
+    return _backend_compiles()
 
 
 class PhaseClock:
@@ -420,19 +453,120 @@ class PhaseClock:
     the cumulative SELF seconds of each phase (a span's time less its
     child spans'), so the totals of nested phases add up to the wall time
     of the outermost.  ``names`` are all declared up front: ``seconds``
-    never changes size, and another thread may copy it at any time."""
+    never changes size, and another thread may copy it at any time.
 
-    def __init__(self, names):
-        self.seconds: Dict[str, float] = {n: 0.0 for n in names}
+    And a record a LAP: one pass through the outermost phase ``lap``
+    (the first of ``names``).  While a lap is open the owner
+    may :meth:`note` counts; when it closes the clock appends
+    ``{seq, t0, t1, self_s, compiles, compile_s, **notes}`` to a ring of
+    :data:`LAPS_KEPT`: ``t0``/``t1`` on ``time.perf_counter()``,
+    ``self_s`` the self seconds by phase SINCE THE LAST LAP (phases that
+    ran between two laps are in the later one's, so a run's records sum
+    to ``seconds``; phases at 0 are left out), ``compiles``/``compile_s``
+    the process's backend compiles over the same stretch.  A lap its
+    owner calls :meth:`quiet` (nothing moved: a server polls an idle
+    engine a few hundred times a second) is FOLDED into the record
+    before it where that one is quiet too: one record an idle stretch,
+    ``quiet_laps`` long, its ``t1``, seconds and notes the last lap's, so
+    that no idle minute pushes a window's steps out of the ring.  A
+    clock given a ``log`` name is found by it in :func:`step_logs` until a newer
+    clock takes the name, its owner closed or not."""
+
+    def __init__(self, names, log: str = ""):
+        self.names = tuple(names)
+        self.seconds: Dict[str, float] = {n: 0.0 for n in self.names}
         self._open: List[_TimedPhase] = []
+        self.lap = self.names[0]
+        self.log = log
+        #: what the owner says of itself once (a log file's first line)
+        self.about: Dict[str, Any] = {}
+        self.laps = 0  # records appended; a record's ``seq`` counts from 1
+        self.dropped = 0
+        self._lapped = dict(self.seconds)  # ``seconds`` at the last lap's end
+        self._compiled = _compiles()
+        self._notes: Dict[str, Any] = {}
+        self._quiet = False
+        self._records: Deque[Dict[str, Any]] = deque(maxlen=LAPS_KEPT)
+        self._lock = threading.Lock()
+        if log:
+            with _default_lock:
+                _step_logs[log] = self
 
     def phase(self, name: str, **counts) -> _TimedPhase:
         return _TimedPhase(self, name, counts)
 
-    def reset(self):
-        """Zero the totals (a benchmark arm that times one stretch)."""
-        for name in self.seconds:
-            self.seconds[name] = 0.0
+    def note(self, **counts):
+        """Counts of the lap that is open (of the next, between two)."""
+        self._notes.update(counts)
+
+    def quiet(self):
+        """The lap that is open moved nothing."""
+        self._quiet = True
+
+    def _close_lap(self, t0: float, t1: float):
+        lapped, self_s = self._lapped, {}
+        for name, sec in self.seconds.items():
+            if sec != lapped[name]:
+                self_s[name] = sec - lapped[name]
+                lapped[name] = sec
+        compiled = _compiles()
+        record = {
+            "seq": self.laps + 1, "t0": t0, "t1": t1, "self_s": self_s,
+            "compiles": compiled[0] - self._compiled[0],
+            "compile_s": compiled[1] - self._compiled[1],
+            **self._notes,
+        }
+        self._compiled, self._notes = compiled, {}
+        quiet, self._quiet = self._quiet, False
+        last = self._records[-1] if quiet and self._records else None
+        if last is not None and "quiet_laps" in last:
+            # (a new dict in its place: a reader may hold the old one)
+            for name, sec in last["self_s"].items():
+                self_s[name] = self_s.get(name, 0.0) + sec
+            record.update(
+                seq=last["seq"], t0=last["t0"],
+                compiles=last["compiles"] + record["compiles"],
+                compile_s=last["compile_s"] + record["compile_s"],
+                quiet_laps=last["quiet_laps"] + 1,
+            )
+            with self._lock:
+                self._records[-1] = record
+            return
+        if quiet:
+            record["quiet_laps"] = 1
+        with self._lock:
+            self.dropped += len(self._records) == self._records.maxlen
+            self._records.append(record)
+            self.laps += 1
+
+    def records(self) -> List[Dict[str, Any]]:
+        """A copy of the ring's records, oldest first (any thread; the
+        records themselves are never written again)."""
+        with self._lock:
+            return list(self._records)
+
+    def header(self) -> Dict[str, Any]:
+        return {
+            "log": self.log, "lap": self.lap, "phases": list(self.names),
+            "laps": self.laps, "dropped": self.dropped, **self.about,
+        }
+
+    def dump(self, path: str):
+        """``header()`` and the records as JSON lines."""
+        with open(path, "w") as f:
+            for line in [self.header()] + self.records():
+                f.write(json.dumps(line) + "\n")
+
+
+#: the process's clocks that keep a log, by its name: the newest of each
+_step_logs: Dict[str, PhaseClock] = {}
+
+
+def step_logs() -> Dict[str, PhaseClock]:
+    """``{log name: clock}`` of this process (``"engine"``, ``"train"``):
+    where a reader finds a thread's records after its owner is gone."""
+    with _default_lock:
+        return dict(_step_logs)
 
 
 _default_lock = threading.Lock()

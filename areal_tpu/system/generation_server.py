@@ -12,6 +12,7 @@ API is a ZMQ ROUTER socket (replacing SGLang's HTTP):
 
 from __future__ import annotations
 
+import os
 import pickle
 import threading
 import time
@@ -1730,6 +1731,19 @@ class GenerationServerWorker(worker_base.Worker):
                     for name, sec in eng.phase_seconds().items()
                 ),
             )
+            # ... and step by step: a header and a record a step
+            # (docs/observability.md, "Step records";
+            # python3 -m benchmark.lib.step_log reads it)
+            try:
+                log_dir = constants.get_log_path()
+                os.makedirs(log_dir, exist_ok=True)
+                path = os.path.join(
+                    log_dir, f"steps.{self.worker_name}.jsonl"
+                )
+                eng._phases.dump(path)
+                self.logger.info("step records: %s", path)
+            except Exception as e:  # noqa: BLE001 - never keeps a worker up
+                self.logger.warning("step records not written: %r", e)
             slo = eng.slo_stats()
             self.logger.info(
                 "first tokens: first_tokens_deferred=%d, "

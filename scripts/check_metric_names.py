@@ -400,6 +400,107 @@ def stall_vocabulary_problems(
     return problems
 
 
+def collect_admit_stop_sites(
+    sources: Dict[str, str] | None = None,
+) -> Dict[str, List[Tuple[str, int]]]:
+    """``admit_stopped_by`` literals: the first argument of
+    ``admit_stop(...)`` (``table.admit_stop``, the validate-identity
+    marker every site wraps its literal in)."""
+    return _collect(("admit_stop",), 0, bare=True, sources=sources)
+
+
+def collect_documented_row(first_cell: str, path: str = DOCS_TABLE) -> Set[str]:
+    """Every other backticked lowercase identifier on the
+    docs/observability.md table row whose first cell is
+    ```first_cell```."""
+    out: Set[str] = set()
+    if not os.path.exists(path):
+        return out
+    with open(path) as f:
+        for line in f:
+            if line.lstrip().startswith(f"| `{first_cell}`"):
+                out.update(re.findall(r"`([a-z][a-z0-9_]*)`", line))
+    return out - {first_cell}
+
+
+def admit_stop_vocabulary_problems(
+    sites: Dict[str, List[Tuple[str, int]]],
+    stops: Tuple[str, ...],
+    documented: Set[str],
+) -> List[str]:
+    """A step record's ``admit_stopped_by`` against
+    ``table.ADMIT_STOPS`` and the docs row, both ways, in
+    :func:`stall_vocabulary_problems`' manner."""
+    problems: List[str] = []
+    for stop, where_list in sorted(sites.items()):
+        where = ", ".join(f"{p}:{ln}" for p, ln in where_list)
+        if stop == "<non-literal>":
+            problems.append(
+                f"non-literal admit_stop(...) argument at {where} — "
+                "wrap each literal so the vocabulary lint can see it"
+            )
+        elif stop != "<syntax-error>" and stop not in stops:
+            problems.append(
+                f"admit stop {stop!r} ({where}) is missing from "
+                "areal_tpu/observability/table.py ADMIT_STOP_TABLE"
+            )
+    for stop in sorted(set(stops) - set(sites)):
+        problems.append(
+            f"ADMIT_STOP_TABLE entry {stop!r} is never used anywhere "
+            "under areal_tpu/ (dead vocabulary — remove it or wire it)"
+        )
+    for stop in sorted(set(stops) - documented):
+        problems.append(
+            f"admit stop {stop!r} is in ADMIT_STOP_TABLE but missing "
+            "from the docs/observability.md admit_stopped_by row"
+        )
+    for stop in sorted(documented - set(stops)):
+        problems.append(
+            f"docs/observability.md documents admit stop {stop!r}, "
+            "which is not in ADMIT_STOP_TABLE"
+        )
+    return problems
+
+
+def record_field_problems(path: str = DOCS_TABLE) -> List[str]:
+    """The fields of the step records (``table.LAP_RECORD``,
+    ``ENGINE_STEP_RECORD``, ``TRAIN_BATCH_RECORD``) against the rows of
+    the docs' "Step records" section, both ways."""
+    from areal_tpu.observability import table
+
+    declared = (
+        set(table.LAP_RECORD) | set(table.ENGINE_STEP_RECORD)
+        | set(table.TRAIN_BATCH_RECORD)
+    )
+    problems = [
+        f"STEP_DELTAS entry {name!r} is not in ENGINE_STEP_RECORD"
+        for name in table.STEP_DELTAS
+        if name not in table.ENGINE_STEP_RECORD
+    ]
+    documented: Set[str] = set()
+    in_section = False
+    if os.path.exists(path):
+        with open(path) as f:
+            for line in f:
+                if line.startswith("## "):
+                    in_section = line.strip() == "## Step records"
+                elif in_section and line.startswith("| `"):
+                    documented.update(
+                        re.findall(r"`([a-z][a-z0-9_]*)`", line.split("|")[1])
+                    )
+    for name in sorted(declared - documented):
+        problems.append(
+            f"step record field {name} is declared in table.py but has "
+            'no row in docs/observability.md, "Step records"'
+        )
+    for name in sorted(documented - declared):
+        problems.append(
+            f'docs/observability.md, "Step records", has a row for '
+            f"{name}, which no record in table.py declares"
+        )
+    return problems
+
+
 def run_lint() -> List[str]:
     """Returns a list of violation messages (empty = clean)."""
     sys.path.insert(0, REPO_ROOT)
@@ -472,6 +573,18 @@ def run_lint() -> List[str]:
             collect_documented_stall_kinds(),
         )
     )
+
+    # -- a step record's admit_stopped_by, and the records' fields ----------
+    from areal_tpu.observability.table import ADMIT_STOPS
+
+    problems.extend(
+        admit_stop_vocabulary_problems(
+            collect_admit_stop_sites(),
+            ADMIT_STOPS,
+            collect_documented_row("admit_stopped_by"),
+        )
+    )
+    problems.extend(record_field_problems())
 
     # -- trace span/event vocabulary (same discipline, second table) --------
     from areal_tpu.observability.table import TRACE_TABLE

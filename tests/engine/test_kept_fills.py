@@ -252,6 +252,35 @@ def _keeping_and_joining_build_no_program_after_the_start(stack):
     assert [f._cache_size() for f in programs] == built
 
 
+def _the_ninth_late_sibling_of_a_step_waits_for_the_next(stack):
+    """One distribution serves ``LATE_JOINS_A_STEP`` late siblings: the
+    step's record says what left the ninth queued, and the next step
+    serves it."""
+    from areal_tpu.engine.inference_server import LATE_JOINS_A_STEP
+
+    (p,) = _prompts(18, 37)
+    eng = _engine(stack)
+    eng.submit(_req("s0", p, 40))
+    _step_until(eng, lambda: eng.state_fills_kept_total == 1)
+    for i in range(LATE_JOINS_A_STEP + 1):
+        eng.submit(_req(f"late{i}", p, 3))
+    with jax.default_matmul_precision("highest"):
+        eng.step()
+        first = eng._phases.records()[-1]
+        eng.step()
+        second = eng._phases.records()[-1]
+    assert (first["admit_stopped_by"], first["pending"]) == ("late_join_cap", 1)
+    assert first["late_joins"] == first["rows_admitted"] == LATE_JOINS_A_STEP
+    assert (second["admit_stopped_by"], second["pending"]) == ("queue_empty", 0)
+    assert second["late_joins"] == second["rows_admitted"] == 1
+    assert eng.state_late_joins_total == LATE_JOINS_A_STEP + 1
+    assert first["fill_programs"] == second["fill_programs"] == 0
+    out = _run(eng)
+    assert len(out) == LATE_JOINS_A_STEP + 2
+    records = eng._phases.records()
+    assert sum(r["late_joins"] for r in records) == eng.state_late_joins_total
+
+
 def _a_stateless_stack_keeps_nothing(stack):
     """Its prefix cache serves the late sibling, as before: no snapshot
     slot, no kept fill, and the counters of the stateful path stay 0."""
@@ -288,6 +317,8 @@ CASES = [
     # (nothing of these two is a stack's own: one stack each)
     ("parallel", "built_at_the_start",
      _keeping_and_joining_build_no_program_after_the_start),
+    ("hybrid", "late_join_cap",
+     _the_ninth_late_sibling_of_a_step_waits_for_the_next),
     ("stateless", "keeps_nothing", _a_stateless_stack_keeps_nothing),
 ]
 

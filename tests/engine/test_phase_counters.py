@@ -186,10 +186,13 @@ class _RecordingSpan:
 
 
 def test_train_engine_cumulative_slots_are_the_sum_of_each_calls(monkeypatch):
-    from areal_tpu.engine import train_engine
+    from areal_tpu.observability import tracing
 
     monkeypatch.setattr(_RecordingSpan, "seen", [])
-    monkeypatch.setattr(train_engine, "phase", _RecordingSpan)
+    # (the trainer's spans are its PhaseClock's, which makes them here)
+    monkeypatch.setattr(
+        tracing, "_annotation", lambda name, c: _RecordingSpan(name, **c)
+    )
     cfg = tiny_config(vocab_size=64)
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     mesh = MeshSpec(data=1, fsdp=1, model=1).make_mesh(jax.devices()[:1])
@@ -228,6 +231,15 @@ def test_train_engine_cumulative_slots_are_the_sum_of_each_calls(monkeypatch):
         # the SFT loss is a sum over tokens: its step programs take each
         # chunk's gradient in place, three head products a token
         assert c["loss_head_products"] == 3
+    # ... and the clock's record of each call holds the same counts,
+    # traced or not
+    records = eng._phases.records()
+    assert [r["batch"] for r in records] == [1, 2, 3]
+    for r, s in zip(records, spans):
+        assert {k: r[k] for k in r if k in s.counts} == {
+            k: v for k, v in s.counts.items() if k != "loss_head_products"
+        }
+        assert r["version"] == r["batch"]
 
 
 @pytest.mark.parametrize("pack", [True, False])
@@ -239,11 +251,13 @@ def test_train_batch_counts_the_attention_block_pairs_its_layout_runs(
     own rule, ``ops/flash_attention.block_ranges``, on the host's ids):
     the pairs under the diagonal in which a q and a kv slot share an id, by
     brute force, of ``n (n + 1) / 2`` a row; the engine keeps their sums."""
-    from areal_tpu.engine import train_engine
+    from areal_tpu.observability import tracing
     from tests.ops.test_flash_attention import pairs_that_meet
 
     monkeypatch.setattr(_RecordingSpan, "seen", [])
-    monkeypatch.setattr(train_engine, "phase", _RecordingSpan)
+    monkeypatch.setattr(
+        tracing, "_annotation", lambda name, c: _RecordingSpan(name, **c)
+    )
     cfg = tiny_config(vocab_size=64, max_position_embeddings=2048)
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     mesh = MeshSpec(data=1, fsdp=1, model=1).make_mesh(jax.devices()[:1])

@@ -118,10 +118,9 @@ def assert_no_fill_leaves_a_tail_position_out(eng, req, run):
     run(eng)
     assert len(eng.drain_results()) == 1
     assert fills and eng.fill_tail_layers == 0
-    assert all(
-        c["tail_layers"] == 0 and c["fill_tail_positions_saved"] == 0
-        for c in fills
-    )
+    assert all(c["tail_layers"] == 0 for c in fills)
+    # (the running total is the engine's attribute alone)
+    assert all("fill_tail_positions_saved" not in c for c in fills)
     assert eng.fill_tail_positions_saved_total == 0
 
 

@@ -163,21 +163,26 @@ def test_engine_spans_in_a_cpu_profile(tmp_path):
     assert last["tokens_emitted_total"] == eng.tokens_emitted_total
     assert sum(c["rows_admitted"] for c in counts("areal.engine.admit")) == 6
     fills = counts("areal.engine.fill.dispatch")
-    assert all(
-        set(c) == {
-            "prompts", "f_pad", "c", "tokens", "first_tokens_deferred",
-            "first_tokens_blocking",
-        }
-        for c in fills
-    )
-    # (running totals, from none before the first fill to all six by the
-    # fills that recompute rows after the swap; none was fetched at once)
-    deferred = [c["first_tokens_deferred"] for c in fills]
-    assert deferred == sorted(deferred) and deferred[0] == 0
-    assert deferred[-1] == eng.first_tokens_deferred_total == 6
-    assert eng.first_tokens_blocking_total == 0 == fills[-1][
-        "first_tokens_blocking"
+    # the counts of ITS event; the running totals a fill moves are the
+    # engine's attributes (all six first tokens reached their rows on the
+    # device, none was fetched at once)
+    assert all(set(c) == {"prompts", "f_pad", "c", "tokens"} for c in fills)
+    records = eng._phases.records()
+    assert eng.first_tokens_deferred_total == 6
+    assert eng.first_tokens_blocking_total == 0
+    # the step span's counts are the record's numbers
+    assert [r["step"] for r in records] == [
+        c["step"] for c in counts("areal.engine.step")
     ]
+    for r, c in zip(records, counts("areal.engine.step")):
+        assert (r["slots_decoding"], r["slots_filling"], r["pending"],
+                r["ring"]) == (c["rows_decoding"], c["rows_filling"],
+                               c["pending"], c["ring"])
+    assert sum(r["fill_programs"] for r in records) == len(fills)
+    assert sum(r["fill_tokens"] for r in records) == eng.prefill_tokens_total
+    assert sum(r["fill_slots"] for r in records) == sum(
+        c["f_pad"] * c["c"] for c in fills
+    )
     # two unique prompts prefilled once each, and once more for the rows
     # the swap recomputed
     assert sum(c["tokens"] for c in fills) == eng.prefill_tokens_total
@@ -213,7 +218,7 @@ def test_engine_spans_in_a_cpu_profile(tmp_path):
     open_, closed = [], []
     for m in sorted(marks):
         if m[2] == "areal.phase.begin":
-            assert m[3].keys() == {"of"}
+            assert m[3].keys() == {"of", "t", "seq"}
             open_.append(m)
         else:
             begin = open_.pop()
