@@ -1168,6 +1168,7 @@ class ContinuousBatchingEngine:
         self.rows_finished_total = 0
         self.chunks_dispatched_total = 0
         self.decode_rows_dispatched_total = 0
+        self.decode_rows_planned_total = 0
         self.fill_slots_total = 0  # f_pad x c of every fill program
         self.chunks_total = 0
         #: every token handed to a row, first tokens included, counted
@@ -4648,8 +4649,9 @@ class ContinuousBatchingEngine:
         paged kernel skips), and the tiles the paged kernel copies of
         those pages (a row's last page only as far as it is filled:
         ctx_tokens_sum / (tiles_attended x tile_tokens) is the share of
-        what the kernel reads that is attended).  Read only
-        while a profiler session records them."""
+        what the kernel reads that is attended); ``rows_planned``
+        (:meth:`_rows_planned`).  Read only while a profiler session
+        records them."""
         if not span.is_enabled():
             return
         ctx = [self.rows[i].n_tokens for i, _ in snapshot]
@@ -4663,6 +4665,7 @@ class ContinuousBatchingEngine:
         )
         counts = dict(
             rows=len(snapshot),
+            rows_planned=self._rows_planned(snapshot),
             ctx_tokens_sum=sum(ctx),
             chunk_size=chunk_size,
             pages_attended=sum(-(-c // page) for c in ctx),
@@ -4705,6 +4708,15 @@ class ContinuousBatchingEngine:
             # position of the window
             counts["latent_window_tokens_sum"] = counts["window_tokens_sum"]
         span.set_metadata(**counts)
+
+    def _rows_planned(self, snapshot) -> int:
+        """The rows of a chunk's snapshot that hold a cached position:
+        those the paged kernel's decode grid visits (its plan leaves out a
+        row without pages, ``ops/paged_attention.LiveRows``), so that 1 -
+        rows_planned / slots is the share of grid steps the chunk was
+        spared.  From the host's own rows, no device sync: a row that a
+        chunk still in the ring has ended counts until its harvest."""
+        return sum(self.rows[i].n_tokens > 1 for i, _ in snapshot)
 
     def _dispatch_chunk_paged(self):
         snapshot = [
@@ -5262,6 +5274,7 @@ class ContinuousBatchingEngine:
             self.async_fetches_total += 1
         self.chunks_dispatched_total += 1
         self.decode_rows_dispatched_total += len(snapshot)
+        self.decode_rows_planned_total += self._rows_planned(snapshot)
         # the first chunk to decode the rows activated since the last one:
         # their first tokens are folded at its harvest, ahead of its own
         self._ring.append(
@@ -5574,7 +5587,8 @@ class ContinuousBatchingEngine:
             self.tokens_emitted_total, self.rows_admitted_total,
             self.rows_finished_total, self.preempted_total,
             self.chunks_dispatched_total, self.decode_rows_dispatched_total,
-            self.prefill_calls, self.prefill_tokens_total,
+            self.decode_rows_planned_total, self.prefill_calls,
+            self.prefill_tokens_total,
             self.fill_slots_total, self._kept.late_joins_total,
         )
 

@@ -252,8 +252,9 @@ def _prefix_plan(
     call over these ``tables`` and ``lengths`` (under this ``window``;
     ``masked``: calls that bring a selection, ``mask``):
     made ONCE, before the layer scan (and the decode chunk's step loop),
-    because XLA leaves it inside them otherwise.  None without the
-    kernel."""
+    because XLA leaves it inside them otherwise.  A decode call's plan
+    (one query a row) says which rows hold pages: the kernel's grid holds
+    those only.  None without the kernel."""
     if not use_kernel:
         return None
     shards, shard_shape = _shard_pool_shape(k_pool, mesh, kv_axis)
@@ -261,7 +262,9 @@ def _prefix_plan(
         n_queries, n_q_heads // shards, shard_shape, k_pool.dtype,
         quantized, tables.shape[1], masked,
     )
-    return plan_pages(tables, lengths, shard_shape[1], group, window)
+    return plan_pages(
+        tables, lengths, shard_shape[1], group, window, decode=n_queries == 1
+    )
 
 
 def kernel_tile_tokens(k_pool, mesh=None, kv_axis=None) -> int:

@@ -1182,6 +1182,8 @@ TRACE_TABLE = [
         "areal.engine.decode.dispatch",
         "phase",
         "One decode chunk (or verify window) dispatched (counts: rows, "
+        "rows_planned = the rows that hold a cached position, which the "
+        "paged kernel's decode grid visits, "
         "ctx_tokens_sum = prompt + generated known to the host over the "
         "dispatched rows, chunk_size, pages_attended, page_slots = "
         "batch slots x pages a slot's table holds, tiles_attended = "
@@ -1492,8 +1494,8 @@ def admit_stop(reason: str) -> str:
 #: the step, in the order of ``ContinuousBatchingEngine._step_totals``
 STEP_DELTAS = (
     "tokens_emitted", "rows_admitted", "rows_finished", "rows_preempted",
-    "decode_chunks", "decode_rows", "fill_programs", "fill_tokens",
-    "fill_slots", "late_joins",
+    "decode_chunks", "decode_rows", "rows_planned", "fill_programs",
+    "fill_tokens", "fill_slots", "late_joins",
 )
 
 #: what every PhaseClock record holds, whoever owns the clock
@@ -1535,6 +1537,9 @@ ENGINE_STEP_RECORD = {
     "decode_chunks": "Decode chunks (or verify windows) dispatched: 0 or "
     "1 a step, more where a drain re-dispatches",
     "decode_rows": "Rows in those chunks' snapshots",
+    "rows_planned": "... of which hold a cached position: the rows the "
+    "paged kernel's decode grid visits (1 - rows_planned / (decode_chunks "
+    "x max_batch): the share of grid steps spared)",
     "fill_programs": "Prefill programs dispatched",
     "fill_tokens": "Real prompt tokens in them",
     "fill_slots": "f_pad x c positions they computed",

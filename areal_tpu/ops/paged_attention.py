@@ -12,7 +12,8 @@ between a handful of 32k rows fitting one chip and dozens.
 
 Kernel shape:
 
-* grid ``(B, QB, ceil(MB/G))`` — MB is the static per-row block
+* grid ``(B, QB, ceil(MB/G))`` (a decode call's: ``(live rows, 1,
+  ceil(MB/G))``) — MB is the static per-row block
   capacity, G pages stream per step (PAGE_GROUP), QB tiles the query
   axis so VMEM scratch stays bounded at prefill-chunk shapes; the steps
   run in order on one core, so online-softmax state (m/l/acc) lives in
@@ -46,7 +47,13 @@ Kernel shape:
 * the grid visits the rows in falling order of their page counts
   (:func:`visit_order`; q and the outputs are addressed through it), so
   that the copies of the next row overlap the dots of this one and dead
-  rows come last, where a step costs one branch;
+  rows come last, where a step costs one branch; a DECODE call (one query
+  a row) does not visit them at all: its grid's first extent is the number
+  of rows that hold a page (:class:`LiveRows`, a traced scalar made with
+  the plan), and what the unvisited rows return is selected after the
+  call (a dead slot cost 0.14 us a layer and step, 6 us of a 58 us call
+  at 22 live rows of 64; a traced extent costs what a static one of the
+  same size does: PERF.md PR 52);
 * queries are GQA-grouped AND chunk-grouped: ``q`` carries Q query
   tokens per row (Q=1 for decode; Q=chunk for chunked prefill's
   prefix attention) and every query row of a (b, qb) cell shares one
@@ -521,10 +528,24 @@ def visit_order(lengths, block_size: int, firsts=None):
     and the next row's copies: rows of equal page counts side by side
     keep both busy, and dead rows in a block at the end start no copy
     between two live rows."""
-    pages = -(-lengths.astype(jnp.int32) // block_size)
-    if firsts is not None:
-        pages = pages - firsts
+    pages = pages_to_visit(lengths, block_size, firsts)
     return jnp.argsort(-pages, stable=True).astype(jnp.int32)
+
+
+def pages_to_visit(lengths, block_size: int, firsts=None):
+    """The pages the grid visits of each row: those that hold a cached
+    position, from ``firsts`` on under a window.  0: a dead row (or one
+    whose window reaches no cached position)."""
+    pages = -(-lengths.astype(jnp.int32) // block_size)
+    return pages if firsts is None else pages - firsts
+
+
+class LiveRows(NamedTuple):
+    """The rows of a DECODE call that hold a page to visit
+    (:func:`pages_to_visit`): its grid runs over them and over no other."""
+
+    count: jax.Array  # [] int32: how many, at least 1 (the grid's extent)
+    mask: jax.Array  # [B] bool, in the rows' OWN order (not the grid's)
 
 
 class PagePlan(NamedTuple):
@@ -534,6 +555,7 @@ class PagePlan(NamedTuple):
     lengths: jax.Array  # [B] valid prefix per row
     page_ids: jax.Array  # [B, MB'] the rows' tables, a whole number of steps wide
     order: jax.Array  # [B] visit_order
+    live: Optional[LiveRows] = None  # a decode call's (``decode=True``)
 
 
 class WindowPagePlan(NamedTuple):
@@ -544,29 +566,39 @@ class WindowPagePlan(NamedTuple):
     page_ids: jax.Array
     order: jax.Array
     firsts: jax.Array  # [B] window_first_pages, in visiting order
+    live: Optional[LiveRows] = None
 
 
 def plan_pages(
-    tables, lengths, block_size: int, group: int, window: Optional[int] = None
+    tables, lengths, block_size: int, group: int, window: Optional[int] = None,
+    decode: bool = False,
 ):
     """The plan :func:`paged_flash_attention` makes for itself unless it
     is handed one.  A caller that runs the kernel many times over the
     same rows (every layer of every step of a decode chunk) makes it
     once, with the ``group`` :func:`page_group` names for its shapes:
     XLA does not hoist the sort out of those loops.  Under ``window`` the
-    plan is of ``[length - window + 1, length)`` of each row."""
+    plan is of ``[length - window + 1, length)`` of each row.  ``decode``:
+    the plan of calls with ONE query a row, whose grid holds the live rows
+    only (:class:`LiveRows`); a fill's plan is what it was."""
     firsts = None
     if window is not None:
         firsts = window_first_pages(lengths, block_size, window)
     order = visit_order(lengths, block_size, firsts)
+    live = None
+    if decode:
+        # a grid of no step at all is not one Mosaic is asked for: where
+        # every row is dead the one step is a dead row's
+        held = pages_to_visit(lengths, block_size, firsts) > 0
+        live = LiveRows(jnp.maximum(jnp.sum(held, dtype=jnp.int32), 1), held)
     tables = tables.astype(jnp.int32)[order]
     short = -tables.shape[1] % group
     if short:  # the steps past a row's table hold no cached position
         tables = jnp.pad(tables, ((0, 0), (0, short)))
     lengths = lengths.astype(jnp.int32)[order]
     if firsts is None:
-        return PagePlan(lengths, tables, order)
-    return WindowPagePlan(lengths, tables, order, firsts[order])
+        return PagePlan(lengths, tables, order, live)
+    return WindowPagePlan(lengths, tables, order, firsts[order], live)
 
 
 def page_group(
@@ -757,15 +789,21 @@ def paged_flash_attention(
     layer_arr = _layer_scalar(layer, window_shift if window is not None else None)
     # a row's page steps: its table's, or no more than a window can touch
     pages = MB if window is None else min(MB, window_span_pages(BS, window))
-    grid = (B, QB, -(-pages // G))
     # lengths and page ids in the order the grid visits the rows; q and
     # the outputs stay where they are and are addressed through the order
+    decode = Q == 1
     if plan is None:
-        plan = plan_pages(tables, lengths, BS, G, window)
+        plan = plan_pages(tables, lengths, BS, G, window, decode)
     assert plan.page_ids.shape == (B, -(-MB // G) * G), (
         plan.page_ids.shape, B, MB, G,
     )
     assert isinstance(plan, WindowPagePlan) == (window is not None), window
+    assert (plan.live is not None) == decode, (Q, plan.live)
+    # a decode call's grid holds the rows that have pages to visit and no
+    # other (they come first in visiting order, and the kernel reads its
+    # extent from the grid): a slot that does not decode costs no step.
+    # Mosaic and both interpreters take the traced extent
+    grid = (plan.live.count if decode else B, QB, -(-pages // G))
     prefetch = [plan.lengths, plan.page_ids, layer_arr, plan.order]
     if window is not None:
         prefetch.append(plan.firsts)
@@ -855,7 +893,15 @@ def paged_flash_attention(
         + ("decode" if Q == 1 else "fill"),
     )(*prefetch, qg, *selection, *pools)
 
-    return _ungroup_outputs(acc, m, l, B, QB, QT, Hkv, r, Q, Hq, vd)
+    acc, m, l = _ungroup_outputs(acc, m, l, B, QB, QT, Hkv, r, Q, Hq, vd)
+    if decode:
+        # the blocks of the rows the grid left out were never written:
+        # whatever they hold, a row without pages reads as the contract says
+        keep = plan.live.mask[:, None, None]
+        acc = jnp.where(keep[..., None], acc, 0.0)
+        m = jnp.where(keep, m, _NEG_INF)
+        l = jnp.where(keep, l, 0.0)
+    return acc, m, l
 
 
 def gather_paged_kv(

@@ -193,7 +193,7 @@ def test_engine_spans_in_a_cpu_profile(tmp_path):
     assert swap["version"] == 1 and swap["rows_recomputed"] > 0
     disp = counts("areal.engine.decode.dispatch")
     assert all(
-        c["chunk_size"] == 8 and c["rows"] > 0
+        c["chunk_size"] == 8 and c["rows"] == c["rows_planned"] > 0
         and c["ctx_tokens_sum"] >= 20 * c["rows"]
         and c["pages_attended"] >= c["rows"]
         and c["page_slots"] >= c["pages_attended"]

@@ -215,6 +215,7 @@ def test_a_runs_records_sum_to_its_totals(mode):
     assert sum(r["rows_admitted"] for r in records) == 6
     assert sum(r["rows_finished"] for r in records) == 6
     assert sum(r["decode_chunks"] for r in records) == eng.chunks_total
+    assert sum(r["rows_planned"] for r in records) == eng.decode_rows_planned_total > 0
     assert sum(r["fill_programs"] for r in records) == eng.prefill_calls
     assert sum(r["fill_tokens"] for r in records) == eng.prefill_tokens_total
     for r in records:
@@ -224,6 +225,9 @@ def test_a_runs_records_sum_to_its_totals(mode):
         ) == eng.max_batch
         assert r["decode_chunks"] in (0, 1) and r["chunk_size"] == 8
         assert r["decode_rows"] <= eng.max_batch * r["decode_chunks"]
+        # every row of a snapshot holds its prompt: the paged kernel's
+        # decode grid visits them all, and no other slot
+        assert r["rows_planned"] == r["decode_rows"]
         assert r["admit_stopped_by"] in ADMIT_STOPS
         assert set(r) <= set(LAP_RECORD) | set(ENGINE_STEP_RECORD)
         assert set(STEP_DELTAS) <= set(r)

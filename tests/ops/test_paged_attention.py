@@ -161,26 +161,38 @@ def _held_table(lengths, page, MB=4):
     return np.where(held, tables, 0), held
 
 
-def _walk_the_grid(lengths, page, tile, group, query_tiles=1, MB=4):
+def _walk_the_grid(lengths, page, tile, group, query_tiles=1, MB=4, tables=None,
+                   window=None, decode=False):
     """What the kernel copies over a whole grid, by the functions the
     kernel itself calls (``stream_page``, ``page_fetched``) on the plan
     ``plan_pages`` makes: ``{(row in slot order, page id): [tiles copied,
-    one entry a copy]}``."""
+    one entry a copy]}``.  ``decode``: the grid of a decode call, which
+    stops at the plan's live rows; ``window``: the windowed plan, a row's
+    steps as many as a window's pages."""
+    from areal_tpu.ops.paged_attention import window_span_pages
+
     lengths = np.asarray(lengths)
-    tables, _ = _held_table(lengths, page, MB)
-    plan = plan_pages(jnp.asarray(tables), jnp.asarray(lengths), page, group)
-    lens, ids, order = (np.asarray(x) for x in plan)
-    assert ids.shape == (len(lens), -(-MB // group) * group)
-    held = np.arange(ids.shape[1])[None, :] * page < lens[:, None]
-    np.testing.assert_array_equal(  # every held page under its own id
-        ids[held], tables[order][np.arange(MB)[None, :] * page < lens[:, None]]
+    if tables is None:
+        tables, _ = _held_table(lengths, page, MB)
+    plan = plan_pages(
+        jnp.asarray(tables), jnp.asarray(lengths), page, group, window, decode
     )
+    lens, ids, order = (np.asarray(x) for x in plan[:3])
+    firsts = None if window is None else np.asarray(plan.firsts)
+    assert ids.shape == (len(lens), -(-MB // group) * group)
+    if window is None:
+        held = np.arange(ids.shape[1])[None, :] * page < lens[:, None]
+        np.testing.assert_array_equal(  # every held page under its own id
+            ids[held],
+            np.asarray(tables)[order][np.arange(MB)[None, :] * page < lens[:, None]],
+        )
+    pages = MB if window is None else min(MB, window_span_pages(page, window))
     copied, before = {}, None
-    for b in range(len(lens)):
+    for b in range(int(plan.live.count) if decode else len(lens)):
         for _qb in range(query_tiles):
-            for j in range(ids.shape[1] // group):
+            for j in range(-(-pages // group)):
                 here = [
-                    stream_page(lens, ids, b, j, g, group, page, tile)
+                    stream_page(lens, ids, b, j, g, group, page, tile, firsts)
                     for g in range(group)
                 ]
                 for this, was in zip(here, before or here):
@@ -264,7 +276,7 @@ def test_handed_plan_equals_own_plan():
     )
     group = page_group(1, 4, kp.shape, kp.dtype, False, tables.shape[1])
     assert group == PAGE_GROUP
-    plan = plan_pages(tables, lens, BS, group)
+    plan = plan_pages(tables, lens, BS, group, decode=True)
     own = paged_flash_attention(q, kp, vp, tables, lens, interpret=True)
     handed = paged_flash_attention(
         q, kp, vp, tables, lens, interpret=True, plan=plan
@@ -509,7 +521,7 @@ def test_latent_pages_of_a_layer_stacked_pool_and_a_handed_plan():
 
     q, pool, tables, lens = _latent_setup(6, 1, LATENT_ROWS, L=3, seed=3)
     G = page_group(1, 16, pool.shape, pool.dtype, False, tables.shape[1])
-    plan = plan_pages(tables, lens, BS, G)
+    plan = plan_pages(tables, lens, BS, G, decode=True)
     for layer in (0, 2):
         got = paged_flash_attention(
             q, pool, None, tables, lens, layer=jnp.int32(layer), interpret=True,
@@ -529,3 +541,144 @@ def test_latent_mode_wants_its_value_width_and_no_v_pool():
         paged_flash_attention(
             q, pool, pool, tables, lens, interpret=True, value_dim=128
         )
+
+
+#: a decode batch at its emptiest and its fullest, and the shapes between
+#: (ISSUE 52): name -> (lengths, window)
+LIVE_ROWS = {
+    "every_row_dead": ([0] * 8, None),
+    "one_live_row_of_64": ([0] * 37 + [300] + [0] * 26, None),
+    "every_row_live": (RAGGED["one_page_beside_four"], None),
+    "dead_between_live": (RAGGED["dead_between_live"], None),
+    "dead_first_and_last": (RAGGED["dead_first_and_last"], None),
+    # a window of ONE position holds no cached position at all: rows 0 and
+    # 3 end ON a page's edge and their window starts on the page after
+    # their last; rows 1 and 4 keep the page their window starts inside
+    "a_window_that_leaves_a_row_no_page": ([256, 300, 0, 128, 1], 1),
+    "every_row_dead_under_a_window": ([0] * 4, 200),
+    "dead_rows_under_a_window": ([0, 700, 0, 0, 130, 384, 0], 260),
+}
+
+
+def _decode_call(case, pages, seed=41):
+    """``(args, kwargs, lens, window)`` of a decode call over ``case``'s
+    rows: K/V or latent pages."""
+    lengths, window = LIVE_ROWS[case]
+    B = len(lengths)
+    kw = {} if window is None else dict(window=window)
+    if pages == "latent":
+        q, pool, tables, lens = _latent_setup(
+            B, 1, lengths, MB=6, NB=6 * B + 2, H=8, seed=seed
+        )
+        return (q, pool, None, tables, lens), dict(kw, scale=0.2, value_dim=128), lens
+    q, kp, vp, tables, lens = _setup(
+        B=B, Hq=4, Hkv=2, MB=6, NB=6 * B + 2, lengths=lengths, seed=seed,
+        q_dtype=jnp.bfloat16, engine_tables=True,
+    )
+    return (q, kp, vp, tables, lens), kw, lens
+
+
+def _assert_dead_rows_read_the_constants(got, dead):
+    acc, m, l = (np.asarray(x) for x in got)
+    assert (acc[dead] == 0).all() and (l[dead] == 0).all()
+    assert (m[dead] == np.float32(-1e30)).all()
+
+
+@pytest.mark.parametrize("pages", ["kv", "latent"])
+@pytest.mark.parametrize("case", list(LIVE_ROWS))
+def test_a_decode_grid_holds_the_rows_that_have_pages(case, pages):
+    """A DECODE call's plan counts the rows that hold a page to visit (1
+    at least), the grid that stops there copies every filled tile of
+    theirs once and nothing of any other row, and every row the grid left
+    out returns what the contract names: ``acc = 0, l = 0, m = -1e30``."""
+    from areal_tpu.ops import paged_attention as pa
+
+    args, kw, lens = _decode_call(case, pages)
+    lengths, window = LIVE_ROWS[case]
+    tables = args[3]
+    G = page_group(1, args[0].shape[2], args[1].shape, args[1].dtype, False, 6)
+    plan = plan_pages(tables, lens, BS, G, window, decode=True)
+    firsts = None if window is None else np.asarray(
+        pa.window_first_pages(lens, BS, window)
+    )
+    held = np.asarray(pa.pages_to_visit(lens, BS, firsts))
+    assert int(plan.live.count) == max((held > 0).sum(), 1)
+    np.testing.assert_array_equal(np.asarray(plan.live.mask), held > 0)
+    # the rows the grid visits are the live ones, and they come first
+    visited = np.asarray(plan.order)[: int(plan.live.count)]
+    assert set(visited[held[visited] > 0]) == set(np.flatnonzero(held > 0))
+    assert plan_pages(tables, lens, BS, G, window).live is None  # a fill's
+    # the bounded walk, by the kernel's own page arithmetic: every page
+    # of a live row from its window's first on, whole, once; no other
+    _, copied = _walk_the_grid(
+        lengths, BS, BS, G, MB=6, tables=tables, window=window, decode=True
+    )
+    assert copied == {
+        (r, int(tables[r, c])): [1]
+        for r, n in enumerate(lengths)
+        for c in range(0 if firsts is None else firsts[r], -(-n // BS))
+    }
+    got = paged_flash_attention(*args, interpret=True, plan=plan, **kw)
+    _assert_dead_rows_read_the_constants(got, held == 0)
+    if (held > 0).any():
+        want = reference_paged_partials(*args, **kw)
+        live = np.asarray(want[2])[:, 0, 0] > 0
+        _assert_matches_reference(
+            tuple(np.asarray(x)[live] for x in got),
+            tuple(np.asarray(x)[live] for x in want), np.asarray(lens)[live],
+        )
+        # a live row whose window holds nothing: summed nothing
+        assert (np.asarray(got[2])[~live] == 0).all()
+
+
+@pytest.mark.parametrize(
+    "case,pages",
+    [
+        ("dead_between_live", "kv"),
+        ("dead_first_and_last", "latent"),
+        ("every_row_dead", "kv"),
+        ("a_window_that_leaves_a_row_no_page", "kv"),
+        ("dead_rows_under_a_window", "latent"),
+    ],
+)
+def test_an_unvisited_rows_blocks_never_reach_the_caller(case, pages):
+    """The traced extent under Pallas' TPU interpreter with everything the
+    kernel has not written made NaN, the output blocks of the rows the
+    grid never visits among it: they return ``0, -1e30, 0`` all the same,
+    the live rows are the plain interpreter's to the last bit, and a pool
+    whose every page outside the live rows' is NaN (the pages a dead
+    row's stale table names too) leaves no NaN anywhere."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    pltpu.reset_tpu_interpret_mode_state()
+    args, kw, lens = _decode_call(case, pages)
+    lengths, window = LIVE_ROWS[case]
+    q, kp, vp, tables, _ = args
+    # NaN wherever no live row holds a page; stale ids in dead rows' tables
+    keep = np.zeros(kp.shape[0], bool)
+    for r, n in enumerate(lengths):
+        keep[np.asarray(tables)[r, : -(-n // BS)]] = True
+    stale = np.flatnonzero(~keep)
+    tables = jnp.where(
+        (jnp.arange(6)[None, :] * BS < lens[:, None]), tables, int(stale[0])
+    )
+    nan = lambda p: None if p is None else jnp.where(
+        jnp.asarray(keep).reshape((-1,) + (1,) * (p.ndim - 1)), p, jnp.nan
+    )
+    got = paged_flash_attention(
+        q, nan(kp), nan(vp), tables, lens,
+        interpret=pltpu.InterpretParams(
+            uninitialized_memory="nan", detect_races=True,
+            dma_execution_mode="on_wait",
+        ),
+        **kw,
+    )
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+
+    jax.block_until_ready(got)  # (the interpreter's state is the process's)
+    assert not interpret_pallas_call.races.races_found
+    want = paged_flash_attention(q, kp, vp, tables, lens, interpret=True, **kw)
+    for g, w in zip(got, want):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    _assert_dead_rows_read_the_constants(got, np.asarray(lengths) == 0)

@@ -81,7 +81,7 @@ def test_a_decode_chunks_later_steps_shift_the_window_over_one_plan():
     every step, and a page that step 0 still read may be wholly masked."""
     q, k, v, tables, lens = _setup([700, 384, 259], Hq=8, Hkv=4)
     G = page_group(1, 8, k.shape, k.dtype, False, tables.shape[1])
-    plan = plan_pages(tables, lens, BS, G, window=260)
+    plan = plan_pages(tables, lens, BS, G, window=260, decode=True)
     for shift in (0, 5, 130):
         got = paged_flash_attention(
             q, k, v, tables, lens, interpret=True, plan=plan, window=260,
@@ -142,7 +142,7 @@ def test_pages_wholly_before_the_window_are_never_copied():
     lens, window, group, MB = np.asarray([700, 384, 50, 0, 1024]), 300, 2, 8
     tables = np.arange(5 * MB, dtype=np.int32).reshape(5, MB) + 1
     plan = plan_pages(jnp.asarray(tables), jnp.asarray(lens), BS, group, window)
-    ln, ids, order, firsts = (np.asarray(x) for x in plan)
+    ln, ids, order, firsts = (np.asarray(x) for x in plan[:4])
     steps = -(-window_span_pages(BS, window) // group)
     copied, before = {}, None
     for b in range(len(ln)):
@@ -180,7 +180,7 @@ def test_a_window_no_longer_than_a_page_reads_two_pages_at_most(page, window):
     _assert_same(got, reference_paged_partials(q, k, v, tables, lens, window=window))
     # later steps of a decode chunk over the plan made at its start
     G = page_group(1, 8, k.shape, k.dtype, False, tables.shape[1])
-    plan = plan_pages(tables, lens, page, G, window=window)
+    plan = plan_pages(tables, lens, page, G, window=window, decode=True)
     assert list(np.asarray(plan.firsts)[np.argsort(np.asarray(plan.order))]) == [
         max(n - (window - 1), 0) // page for n in lengths
     ]

@@ -172,6 +172,117 @@ def test_latent_paged_kernel_compiles(one_chip, B, Q):
     assert ("paged_mla_decode" if Q == 1 else "paged_mla_fill") in compiled.as_text()
 
 
+def _lowered_programs_script():
+    """``scripts/lowered_programs.py`` as a module: what parses a Mosaic
+    body out of a lowered program and hashes one without its locations."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "lowered_programs",
+        os.path.join(os.path.dirname(__file__), "../../scripts/lowered_programs.py"),
+    )
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    return script
+
+
+def _mosaic_grids(lowered):
+    """The ``iteration_bounds`` of each Mosaic call in a lowered program
+    (None: an extent the call is handed at run time)."""
+    script = _lowered_programs_script()
+    grids = []
+    for body in script._BODY.finditer(lowered.as_text()):
+        (bounds,) = re.findall(
+            r"iteration_bounds = array<i64: ([^>]*)>",
+            script._without_locations(body.group(2)),
+        )
+        grids.append(
+            tuple(None if int(b) < 0 else int(b) for b in bounds.split(","))
+        )
+    return grids
+
+
+#: mode -> (q heads, pool, pages a row, keywords): the four decode calls
+#: Mosaic names, at their cells' shapes
+DECODE_MODES = {
+    "paged_attn_decode": (12, (LAYERS, NB, 2, PAGE, HD), MB, {}),
+    "paged_window_decode": (28, (6, 64, 4, 512, HD), 16, dict(window=4096)),
+    "paged_mla_decode": (
+        64, (5, 640, 1, 512, 640), 10, dict(scale=0.1447, value_dim=512),
+    ),
+    "paged_mla_window_decode": (
+        64, (3, 256, 1, 512, 1152), 36,
+        dict(scale=1 / 16.0, value_dim=1024, window=513),
+    ),
+}
+
+
+@pytest.mark.parametrize("name", list(DECODE_MODES))
+def test_a_decode_calls_first_extent_is_the_live_rows(one_chip, name):
+    """A decode call (one query a row) hands Mosaic its grid's first
+    extent at run time, the rows that hold pages, in the K/V, windowed,
+    latent and windowed-latent modes alike, under the name it had; the
+    other two extents are static, and it compiles for the described v5e."""
+    Hq, pool_shape, pages, kw = DECODE_MODES[name]
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = s(pool_shape, jnp.bfloat16)
+
+    def call(q, pool, tables, lengths, layer):
+        return pa.paged_flash_attention(
+            q, pool, None if "value_dim" in kw else pool, tables, lengths,
+            layer=layer, **kw,
+        )
+
+    lowered = jax.jit(call).lower(
+        s((64, 1, Hq, pool_shape[-1]), jnp.bfloat16), pool,
+        s((64, pages), jnp.int32), s((64,), jnp.int32), s((1,), jnp.int32),
+    )
+    span = pages if "window" not in kw else min(
+        pages, pa.window_span_pages(pool_shape[-2], kw["window"])
+    )
+    G = pa.page_group(1, Hq, pool_shape, jnp.bfloat16, False, pages)
+    assert _mosaic_grids(lowered) == [(None, 1, -(-span // G))]
+    assert name in lowered.compile().as_text()
+
+
+#: scripts/lowered_programs.digest of a fill call of the paged kernel, as
+#: the parent of PR 52 lowered it (commit f173069; the same function run
+#: in a copy of that tree): a decode call's grid is bounded by its live
+#: rows since, and a fill call is the program it was.  A change that MEANS
+#: to move a fill call refreshes these with docs/lowered_programs.txt.
+FILL_CALLS = {
+    "paged_attn_fill": "9627b1d18d2d8e732a89024d608dfd7fb01b4f43",
+    "paged_mla_fill": "9454cf3f912cdf768b439ac7afa23c0bc6c4472b",
+}
+
+
+@pytest.mark.parametrize("name", list(FILL_CALLS))
+def test_a_fill_calls_lowered_text_is_what_it_was(one_chip, name):
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    if name == "paged_attn_fill":
+        def place(shape, dtype, _spec):
+            return s(shape, dtype)
+
+        lowered = jax.jit(_call_kernel).lower(
+            *_paged_args("qwen2.5-1.5b", 16, 256, False, place)
+        )
+    else:
+        Hq, pool_shape, pages, kw = DECODE_MODES["paged_mla_decode"]
+
+        def call(q, pool, tables, lengths, layer):
+            return pa.paged_flash_attention(
+                q, pool, None, tables, lengths, layer=layer, **kw
+            )
+
+        lowered = jax.jit(call).lower(
+            s((1, 1024, Hq, 640), jnp.bfloat16), s(pool_shape, jnp.bfloat16),
+            s((1, pages), jnp.int32), s((1,), jnp.int32), s((1,), jnp.int32),
+        )
+    assert name in lowered.as_text()
+    assert _lowered_programs_script().digest(lowered) == FILL_CALLS[name]
+
+
 @pytest.mark.parametrize(
     "model,quantized",
     [("qwen2.5-1.5b", False), ("qwen2.5-7b", False), ("qwen2.5-7b", True)],
