@@ -56,6 +56,19 @@ def cast_floating(params, dtype):
         )
 
 
+def refuse_unserved(model_cfg: TransformerConfig):
+    """Raise, by name, for a stack no generation server is written for."""
+    if model_cfg.is_hybrid and model_cfg.window_has_own_widths:
+        raise NotImplementedError(
+            "a generation server cannot serve a stack whose window layers "
+            f"have {model_cfg.swa_n_q_heads} query heads and a rope rule of "
+            f"their own beside full layers of {model_cfg.n_q_heads}: the "
+            "paged programs read both kinds from one parameter stack at one "
+            "head count (such a stack is TRAINED, on the `train` backend; "
+            "ROADMAP R9 has what serving it needs)"
+        )
+
+
 def make_model(
     cfg: ModelAbstraction,
     name: ModelName,
@@ -131,6 +144,7 @@ def make_model(
                 else "deepseek_v3" if model_cfg.is_latent
                 else "phi4flash" if model_cfg.is_mamba1
                 else "falcon_h1" if model_cfg.n_parallel_layers
+                else "laguna" if model_cfg.window_has_own_widths
                 else "smallthinker" if model_cfg.n_window_layers
                 else "granitemoehybrid"
             )

@@ -720,6 +720,9 @@ class ContinuousBatchingEngine:
         # are reused, rows park.)
         self._by_kind = cfg.is_hybrid  # models/hybrid.py runs the stack
         self._stateful = cfg.n_mamba_layers > 0
+        from areal_tpu.engine.backend import refuse_unserved
+
+        refuse_unserved(cfg)
         # the routing of the last finished requests, at least
         # ``keep_routed_experts`` of them (:meth:`routed_experts`,
         # ``_keep_routing``): what a routing-replay trainer or a parity

@@ -41,7 +41,10 @@ from areal_tpu.engine import batching
 from areal_tpu.engine.optimizer import OptimizerConfig, make_optimizer
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import param_pspecs, takes_flash
-from areal_tpu.observability.table import TRAIN_PHASES
+from areal_tpu.observability.table import (
+    TRAIN_BATCH_RECORD_BY_KIND,
+    TRAIN_PHASES,
+)
 from areal_tpu.observability.tracing import PhaseClock, region
 from areal_tpu.ops import flash_attention
 from areal_tpu.ops import loss as loss_ops
@@ -75,9 +78,11 @@ def plan_layout(
     """The ``[n, rows, T]`` a minibatch trains at
     (:func:`batching.plan_minibatch`): the rows that cost the model's
     forward pass least, padding counted, by ``flops_counter``'s arithmetic
-    (a linear term a slot, an attention term that grows with T^2 a row).
-    Rows grow past the longest sequence only where the model's attention
-    takes the flash kernel (:func:`takes_flash`)."""
+    (a linear term a slot, an attention term that grows with T^2 a row, or
+    with ``T min(T, window)`` in a window layer of a stack stated by
+    kind).  Rows grow past the longest sequence only where the model's
+    attention takes the flash kernel (:func:`takes_flash`: every attention
+    kind of the stack)."""
     from areal_tpu.system import flops_counter
 
     return batching.plan_minibatch(
@@ -265,32 +270,19 @@ class TrainEngine:
         transformer.set_ambient_mesh(self.mesh)  # for ring attention tracing
         key = (_fn_key(loss_fn), n_mbs)
         if key not in self._train_step_cache:
-
-            def grad_of(params, mb):
-                def scalar_loss(p):
-                    loss_sum, denom, stats = loss_fn(p, self.model_cfg, mb)
-                    return loss_sum, (denom, stats)
-
-                # runs when the program is TRACED: what its loss makes of
-                # the head goes on the span of every batch the program steps
-                with loss_ops.head_products_traced() as seen:
-                    (loss_sum, (denom, stats)), grads = jax.value_and_grad(
-                        scalar_loss, has_aux=True
-                    )(params)
-                self._loss_head_products[key] = max(seen, default=0)
-                return grads, loss_sum, denom, stats
+            grad_of = functools.partial(self._grad_of, loss_fn, key)
 
             def train_step(params, opt_state, batch):
                 if n_mbs == 1:
                     mb = jax.tree.map(lambda x: x[0], batch)
-                    grads, loss_sum, denom, stats = grad_of(params, mb)
+                    grads, loss_sum, denom, stats, _ = grad_of(params, mb)
                 else:
                     mb0 = jax.tree.map(lambda x: x[0], batch)
-                    carry = grad_of(params, mb0)
+                    carry = grad_of(params, mb0)[:4]
 
                     def body(carry, mb):
                         g_acc, loss_acc, denom_acc, stats_acc = carry
-                        g, ls, dn, st = grad_of(params, mb)
+                        g, ls, dn, st, _ = grad_of(params, mb)
                         with region("areal.optimizer"):
                             g_acc = jax.tree.map(jnp.add, g_acc, g)
                         return (
@@ -310,6 +302,7 @@ class TrainEngine:
                         grads,
                     )
                     gnorm = optax.global_norm(grads)
+                    group_norms = self._grad_norms_by_group(grads)
                     updates, opt_state = self.tx.update(
                         grads, opt_state, params
                     )
@@ -319,6 +312,7 @@ class TrainEngine:
                     "loss_sum": loss_sum,
                     "denom": denom,
                     "grad_norm": gnorm,
+                    **group_norms,
                 }
                 return params, opt_state, out
 
@@ -327,6 +321,98 @@ class TrainEngine:
                 loss_fn,
             )
         return self._train_step_cache[key][0]
+
+    def _grad_of(self, loss_fn: LossFn, key, params, mb):
+        """One micro-batch's ``(grads, loss_sum, denom, stats, kept)``:
+        the function the step program scans and :meth:`grad_batch` calls.
+        ``kept``: what the loss put under ``stats["per_microbatch"]``
+        (not a sum over micro-batches: each token's routed experts), which
+        the step drops."""
+
+        def scalar_loss(p):
+            loss_sum, denom, stats = loss_fn(p, self.model_cfg, mb)
+            stats = dict(stats)
+            kept = stats.pop("per_microbatch", None)
+            return loss_sum, (denom, stats, kept)
+
+        # runs when the program is TRACED: what its loss makes of
+        # the head goes on the span of every batch the program steps
+        with loss_ops.head_products_traced() as seen:
+            (loss_sum, (denom, stats, kept)), grads = jax.value_and_grad(
+                scalar_loss, has_aux=True
+            )(params)
+        self._loss_head_products[key] = max(seen, default=0)
+        return grads, loss_sum, denom, stats, kept
+
+    def grad_batch(
+        self,
+        sample: SequenceSample,
+        loss_fn: LossFn,
+        mb_spec: MicroBatchSpec,
+        token_key: str = "packed_input_ids",
+        params=None,
+    ):
+        """The gradient :meth:`train_batch` would hand its optimizer for
+        ``sample`` (same layout, the same micro-batch function, summed and
+        divided by the batch's denominator, before clipping) at ``params``
+        (the engine's own if None), and nothing applied: ``(grads, out,
+        stacked)`` with ``out`` = ``{loss_sum, denom, stats,
+        per_microbatch}`` (the last stacked over the micro-batches) and
+        ``stacked`` the numpy batch the program saw.  For a check (the
+        long train cell reads each token's ROUTING from it, which the step
+        program drops, and the gradient from the step program's own first
+        moment); it compiles a program of its own."""
+        from areal_tpu.models import transformer
+
+        transformer.set_ambient_mesh(self.mesh)
+        plan = plan_layout(
+            self.model_cfg, sample.seqlens[token_key], mb_spec,
+            mesh=self.mesh, row_quantum=self.row_quantum,
+            pack=self.pack_sequences,
+        )
+        stacked, pbs = self._stack_batches(sample, plan, token_key)
+        batch = self._upload_stacked(stacked, pbs[0].shape[0])
+        n_mbs = next(iter(batch.values())).shape[0]
+        key = (_fn_key(loss_fn), n_mbs, "grad_batch")
+
+        def grads_of(params, batch):
+            per = [
+                self._grad_of(
+                    loss_fn, key, params, jax.tree.map(lambda x: x[i], batch)
+                )
+                for i in range(n_mbs)
+            ]
+            grads, loss_sum, denom, stats = (
+                jax.tree.map(lambda *a: sum(a), *[p[k] for p in per])
+                for k in range(4)
+            )
+            grads = jax.tree.map(
+                lambda g: g / jnp.maximum(denom, 1e-8).astype(g.dtype), grads
+            )
+            kept = None
+            if per[0][4] is not None:
+                kept = jax.tree.map(lambda *a: jnp.stack(a), *[p[4] for p in per])
+            return grads, {
+                "loss_sum": loss_sum, "denom": denom, "stats": stats,
+                "per_microbatch": kept,
+            }
+
+        grads, out = jax.jit(grads_of)(
+            self.params if params is None else params, batch
+        )
+        return grads, jax.device_get(out), stacked
+
+    def _grad_norms_by_group(self, grads) -> Dict[str, Any]:
+        """``{"grad_norms": {group: norm}}`` of a stack stated by kind
+        (``hybrid.grad_group``: attention by kind, gates, router, held
+        experts, shared expert, dense MLP, embedding, head, norms), before
+        clipping; nothing for the dense stack, whose step program stays
+        what it was."""
+        if not self.model_cfg.is_hybrid:
+            return {}
+        from areal_tpu.models import hybrid
+
+        return {"grad_norms": hybrid.grad_norms_by_group(grads)}
 
     def _stack_batches(
         self,
@@ -445,6 +531,12 @@ class TrainEngine:
                 rows=rows, row_len=row_len, attn_blocks_run=blocks_run,
                 attn_blocks_causal=blocks_causal,
             )
+            if self.model_cfg.is_hybrid and self.model_cfg.n_window_layers:
+                # a window layer's pairs, of the same triangle
+                counts["attn_window_blocks_run"], _ = flash_attention.blocks_run(
+                    stacked["seg_ids"].reshape(-1, row_len),
+                    window=self.model_cfg.sliding_window,
+                )
             span.set_metadata(**counts)
             step = self._get_train_step(loss_fn, n_mbs)
             with clock.phase("areal.train.dispatch"):
@@ -463,6 +555,20 @@ class TrainEngine:
             )
             with clock.phase("areal.train.sync"):
                 out = jax.device_get(out)  # ONE host sync per train step
+            if "grad_norms" in out:
+                # a stack stated by kind: the batch's gradient by group and
+                # its expert layers' counts go on its record too
+                clock.note(
+                    grad_norms={
+                        k: float(v) for k, v in out["grad_norms"].items()
+                    },
+                    grad_norm=float(out["grad_norm"]),
+                    **{
+                        k[: -len("_sum")]: float(v)
+                        for k, v in out["stats"].items()
+                        if k[: -len("_sum")] in TRAIN_BATCH_RECORD_BY_KIND
+                    },
+                )
         elapsed = time.perf_counter() - tik
         denom_f = float(out["denom"])
         self._record_step_metrics(sample, token_key, elapsed, denom_f)
@@ -474,6 +580,8 @@ class TrainEngine:
                 p.key if hasattr(p, "key") else str(p) for p in k
             )
             host_stats[name] = float(v)
+        for group, norm in out.get("grad_norms", {}).items():
+            host_stats[f"grad_norm/{group}"] = float(norm)
         host_stats.update(
             loss=float(out["loss_sum"]) / max(denom_f, 1e-8),
             grad_norm=float(out["grad_norm"]),

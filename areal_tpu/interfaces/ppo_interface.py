@@ -435,6 +435,17 @@ def _actor_loss_of_hidden(params, cfg, batch, iface, hidden, moe_aux):
         aux_total = moe_aux["moe_aux_loss"] + moe_aux["moe_z_loss"]
         loss_sum = loss_sum + aux_total * real
         stats["moe_aux_loss_sum"] = moe_aux["moe_aux_loss"] * real
+        # a stack stated by kind: its expert layers' counts (held pairs,
+        # the busiest expert's, extra rounds), summed like the rest
+        stats.update(
+            {k: v for k, v in moe_aux.items() if k.endswith("_sum")}
+        )
+        if "routed_experts" in moe_aux:
+            # not a sum: the engine keeps it a micro-batch where a caller
+            # asks for the gradient itself (TrainEngine.grad_batch)
+            stats["per_microbatch"] = {
+                "routed_experts": moe_aux["routed_experts"]
+            }
     return loss_sum, count, stats
 
 

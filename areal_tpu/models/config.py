@@ -21,6 +21,11 @@ LAYER_KINDS = (
 )
 
 
+#: the kinds that are plain attention over per-head K and V, full or
+#: windowed: what the trainer's flash kernels (and so its backward) take
+PLAIN_ATTENTION_KINDS = ("attention", "window")
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     n_layers: int
@@ -135,6 +140,16 @@ class TransformerConfig:
     swa_qk_rope_head_dim: int = 0
     swa_v_head_dim: int = 0
     swa_rotary_base: Optional[float] = None
+    # a plain "window" layer at a query-head count OF ITS OWN
+    # (``swa_n_q_heads`` > 0 beside kind "window": laguna's 64 against 48
+    # on the same 8 KV heads of 128) has a parameter stack of its own
+    # too, plain RoPE over its whole head at ``swa_rotary_base`` and the
+    # gate ``swa_attention_gate`` says (``window_plain()``), while the
+    # "attention" layers keep ``rotary_base``, YaRN where
+    # ``rope_yarn_factor`` is set and ``rope_partial_dim``: the leading
+    # columns of a head that are rotated (``partial_rotary_factor`` times
+    # ``head_dim``; 0 = the whole head)
+    rope_partial_dim: int = 0
     # the learned indexer of the "latent" layers (DeepSeek-V3.2-Exp's
     # lightning indexer): ``index_n_heads`` query heads of
     # ``index_head_dim`` from the query latent, ONE key of that width a
@@ -269,6 +284,13 @@ class TransformerConfig:
                 assert self.kv_lora_rank > 0 and self.qk_rope_head_dim > 0
             if kinds & {"window", "latent_window"}:
                 assert self.sliding_window and self.sliding_window > 1
+            if self.window_has_own_widths:
+                assert self.swa_n_q_heads % self.n_kv_heads == 0, (
+                    self.swa_n_q_heads, self.n_kv_heads,
+                )
+            assert 0 <= self.rope_partial_dim <= self.head_dim and (
+                self.rope_partial_dim % 2 == 0
+            ), self.rope_partial_dim
             if "latent_window" in kinds:
                 assert (
                     self.swa_n_q_heads > 0 and self.swa_kv_lora_rank > 0
@@ -389,6 +411,22 @@ class TransformerConfig:
         if self.layer_types is None:
             return 0
         return sum(t in ("window", "latent_window") for t in self.layer_types)
+
+    @property
+    def window_has_own_widths(self) -> bool:
+        """The plain "window" layers have a query-head count, a rope rule
+        and a parameter stack of their own (``window_plain()``)."""
+        return (
+            self.layer_types is not None
+            and "window" in self.layer_types
+            and self.swa_n_q_heads > 0
+        )
+
+    def window_plain(self) -> "TransformerConfig":
+        """This config as a plain "window" layer's mixer reads it: itself,
+        or with the window's own head count, RoPE base (plain, over the
+        whole head) and gate in the places of the full layers'."""
+        return _window_plain(self) if self.window_has_own_widths else self
 
     @property
     def n_mamba_layers(self) -> int:
@@ -535,6 +573,17 @@ def _window_latent(cfg: TransformerConfig) -> TransformerConfig:
         rotary_base=cfg.swa_rotary_base or cfg.rotary_base,
         attention_gate=cfg.swa_attention_gate,
         index_n_heads=0, index_head_dim=0, index_topk=0,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _window_plain(cfg: TransformerConfig) -> TransformerConfig:
+    return dataclasses.replace(
+        cfg,
+        n_q_heads=cfg.swa_n_q_heads,
+        rotary_base=cfg.swa_rotary_base or cfg.rotary_base,
+        rope_yarn_factor=None, rope_partial_dim=0,
+        attention_gate=cfg.swa_attention_gate,
     )
 
 

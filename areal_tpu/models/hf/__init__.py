@@ -6,6 +6,7 @@ from areal_tpu.models.hf import (  # noqa: F401
     falcon_h1,
     gpt2,
     granitemoehybrid,
+    laguna,
     llama_like,
     mixtral,
     phi4flash,

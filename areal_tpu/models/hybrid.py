@@ -5,7 +5,8 @@ CROSS attention over another layer's pages, a gated memory unit over
 another layer's scan output, or attention AND Mamba-2 side by side on one
 input) and an MLP (a dense one on the leading ``cfg.n_dense_layers``
 layers, else the expert block this program's share of the experts gives)
-(granitemoehybrid, deepseek_v3, smallthinker, phi4flash, falcon_h1).
+(granitemoehybrid, deepseek_v3, smallthinker, phi4flash, falcon_h1,
+dots3_note, laguna).
 
     h0 = embed_scale * embed[tokens]
     per layer:  h += r * mixer(norm(h));  m = norm(h)
@@ -41,6 +42,23 @@ layer's pages from the one that holds the window's first position
 ``cfg.layer_ropes`` (smallthinker's global layers have no position term),
 and an expert layer's router reads the mixer's input where
 ``cfg.moe_router_input == "attn"``.
+
+A stack may state its window layers' widths apart
+(``cfg.window_has_own_widths``: laguna's 64 query heads against the full
+layers' 48 on the same KV heads, plain RoPE over the whole head against
+YaRN over half of it, ``cfg.window_plain()``): they then have a parameter
+stack of their own (``params["window"]``) and each kind its rope tables
+(:func:`plain_rope_tables`); either kind may carry a headwise gate.  Such
+a stack is TRAINED and not served (``engine/backend.refuse_unserved``).
+
+**The trainer's form** is :func:`hidden_states` itself: packed rows for a
+stack of attention kinds alone, the flash kernels by kind (a window layer's
+under ``window=``), each run scanning its layers' own parameters, a layer's
+halves rematerialised under ``cfg.remat``, the grouped product over the
+held experts with its derivative (``moe.grouped_expert_train``).
+``transformer.hidden_states`` hands a configuration with ``layer_types``
+here and refuses by name the kinds whose backward does not exist
+(:func:`refuse_untrainable`).
 
 **Parallel layers** (kind ``"parallel"``: falcon_h1): ``h += attention(a) +
 mamba2(a)`` with ONE ``a = norm(h)``.  Such a layer is an attention mixer
@@ -156,12 +174,14 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from areal_tpu.engine.sampling import sample_and_advance
 from areal_tpu.models import paged
-from areal_tpu.models.config import TransformerConfig
+from areal_tpu.models.config import PLAIN_ATTENTION_KINDS, TransformerConfig
 from areal_tpu.models import quantize
 from areal_tpu.models import moe
+from areal_tpu.models import remat as remat_names
 from areal_tpu.models.moe import held_moe_mlp, n_pair_counts
 from areal_tpu.models.moe import layer_of as _at
 from areal_tpu.models.transformer import (
@@ -209,10 +229,23 @@ class Run(NamedTuple):
     first_of_state: int = 0
 
 
-def _param_kind(kind: str) -> str:
+def _param_kind(kind: str, cfg: TransformerConfig) -> str:
     """Whose parameter stack a layer's mixer lies in ("latent_window"
-    layers have one of their own: their widths differ)."""
+    layers have one of their own: their widths differ, and so have
+    "window" layers at a head count of their own)."""
+    if kind == "window" and cfg.window_has_own_widths:
+        return kind
     return "attention" if kind in ("window", "parallel") else kind
+
+
+#: the parameter stack of each mixer kind that has one of its own
+PARAM_STACKS = {"attention": "attn"}
+
+
+def _stack_of(kind: str, cfg: TransformerConfig) -> str:
+    """The key of ``params`` under which a kind's mixers are stacked."""
+    kind = _param_kind(kind, cfg)
+    return PARAM_STACKS.get(kind, kind)
 
 
 #: the layer kinds whose pages live in the window pool
@@ -239,14 +272,14 @@ def layer_plan(cfg: TransformerConfig) -> Tuple[Run, ...]:
         else:
             runs.append(
                 Run(
-                    kind, mlp, l, seen.get(_param_kind(kind), 0),
+                    kind, mlp, l, seen.get(_param_kind(kind, cfg), 0),
                     seen.get(mlp, 0), 1, rope,
                     seen.get("pool:" + _pool_kind(kind), 0),
                     first_of_state=seen.get("mamba", 0)
                     if kind == "parallel" else 0,
                 )
             )
-        for name in (_param_kind(kind), "pool:" + _pool_kind(kind), mlp) + (
+        for name in (_param_kind(kind, cfg), "pool:" + _pool_kind(kind), mlp) + (
             ("mamba",) if kind == "parallel" else ()
         ):
             seen[name] = seen.get(name, 0) + 1
@@ -513,8 +546,11 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     L, Le, Ld = cfg.n_layers, cfg.n_expert_layers, cfg.n_dense_layers
     Lm = 0 if cfg.is_mamba1 else cfg.n_mamba_layers
     Ll = cfg.layer_types.count("latent")
-    # attention and window layers: one stack
+    # attention and window layers: one stack, but for window layers at
+    # widths of their own (``_init_plain_kinds``)
     La = cfg.n_attn_layers - Ll - cfg.n_latent_window_layers
+    if cfg.window_has_own_widths:
+        La -= cfg.n_window_layers
     D, E, Eh = cfg.hidden_dim, cfg.n_experts, cfg.n_held_experts
     Fe, Fs = cfg.moe_intermediate_dim, cfg.shared_expert_dim
     Hq, Hkv, hd = cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim
@@ -618,6 +654,7 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         }
     _init_newer_kinds(cfg, params, jax.random.fold_in(key, 2))
     _init_sparse_kinds(cfg, params, jax.random.fold_in(key, 3))
+    _init_plain_kinds(cfg, params, jax.random.fold_in(key, 4))
     return params
 
 
@@ -793,6 +830,85 @@ def _init_sparse_kinds(cfg: TransformerConfig, params: Params, key):
             },
             index_w={"w": mat(Ll, (D, Hi), D)},
         )
+
+
+def _path_keys(path) -> Tuple[str, ...]:
+    """A tree path (``jax.tree_util``'s) as its dictionary keys."""
+    return tuple(k.key if hasattr(k, "key") else str(k) for k in path)
+
+
+def param_pspecs(cfg: TransformerConfig, params: Params) -> Params:
+    """PartitionSpecs of :func:`init_params`' tree for the trainer's mesh:
+    the held experts' axis over ``expert`` where it divides, every other
+    leaf whole on every chip.  A stack stated by kind trains on ONE chip's
+    share of a stated deployment (the chips that share a layer hold other
+    experts and their own batches; ROADMAP R9 has experts across chips)."""
+    from jax.sharding import PartitionSpec as P
+
+    def spec_for(path, leaf):
+        if "experts" in _path_keys(path) and leaf.ndim == 4:
+            return P(None, "expert")
+        return P()
+
+    return jax.tree_util.tree_map_with_path(spec_for, params)
+
+
+def grad_group(keys: Tuple[str, ...]) -> str:
+    """The group a leaf of :func:`init_params`' tree (or of its gradient)
+    is counted in, from its path: the trainer's record and the benchmark's
+    check read a gradient's norm by these."""
+    if keys[0] == "embed":
+        return "embed"
+    if keys[0] == "lm_head":
+        return "head"
+    if "gate" in keys and keys[0] in ("attn", "window", "latent", "latent_window"):
+        return "gate"
+    if keys[0] == "attn":
+        return "attention"
+    if keys[0] == "layers" and "mlp" in keys:
+        return next(k for k in ("router", "experts", "shared") if k in keys)
+    if keys[0] == "layers" or keys[0] == "final_norm" or "norm" in keys[-2]:
+        return "norms"
+    return keys[0]  # "window", "dense", "mamba", "latent", ...
+
+
+def grad_norms_by_group(grads: Params):
+    """``{group: l2 norm}`` of a gradient tree, by :func:`grad_group`."""
+    squares = {}
+    for path, g in jax.tree_util.tree_leaves_with_path(grads):
+        group = grad_group(_path_keys(path))
+        squares[group] = squares.get(group, 0.0) + jnp.sum(
+            jnp.square(g.astype(F32))
+        )
+    return {group: jnp.sqrt(s) for group, s in squares.items()}
+
+
+def _init_plain_kinds(cfg: TransformerConfig, params: Params, key):
+    """What plain "window" layers at widths of their own and a headwise
+    gate on plain attention add to :func:`init_params`' tree, from a
+    stream of their own (a seed's weights of the older kinds stay what
+    they were)."""
+    dt = jnp.dtype(cfg.dtype)
+    D, Hkv, hd = cfg.hidden_dim, cfg.n_kv_heads, cfg.head_dim
+    keys = iter(jax.random.split(key, 8))
+
+    def mat(n, shape, fan_in):
+        return _uniform_stack(next(keys), n, shape, 1.0 / np.sqrt(fan_in), dt)
+
+    if cfg.window_has_own_widths:
+        wcfg, Lw = cfg.window_plain(), cfg.n_window_layers
+        Hq = wcfg.n_q_heads
+        params["window"] = {
+            "q": {"w": mat(Lw, (D, Hq * hd), D)},
+            "k": {"w": mat(Lw, (D, Hkv * hd), D)},
+            "v": {"w": mat(Lw, (D, Hkv * hd), D)},
+            "o": {"w": mat(Lw, (Hq * hd, D), Hq * hd)},
+        }
+        if wcfg.attention_gate:
+            params["window"]["gate"] = {"w": mat(Lw, (D, Hq), D)}
+    if "attn" in params and cfg.attention_gate:
+        La = params["attn"]["q"]["w"].shape[0]
+        params["attn"]["gate"] = {"w": mat(La, (D, cfg.n_q_heads), D)}
 
 
 def state_zeros(cfg: TransformerConfig, slots: int):
@@ -1246,11 +1362,55 @@ def _heads_q(cfg: TransformerConfig, ap: Params, h, positions, run: Run):
     ).reshape(B, T, cfg.n_q_heads, 2 * cfg.head_dim)
 
 
-def _heads_qkv(cfg: TransformerConfig, ap: Params, h, positions, run: Run):
+@region("areal.attn")
+def plain_rope_tables(cfg: TransformerConfig, positions):
+    """``(cos, sin)`` [B, T, 1, rot / 2] float32 of a plain attention
+    kind as ``cfg`` states it (``cfg.window_plain()`` for a window layer
+    at widths of its own): over the leading ``cfg.rope_partial_dim``
+    columns of a head (the whole head at 0), at :func:`rope_inv_freq`'s
+    frequencies (YaRN's blend where the kind has a factor), cos and sin
+    times YaRN's attention factor ``0.1 mscale ln(factor) + 1``."""
+    rot = cfg.rope_partial_dim or cfg.head_dim
+    angles = positions[..., None].astype(F32) * jnp.asarray(
+        rope_inv_freq(cfg, rot)
+    )
+    m = 1.0
+    if cfg.rope_yarn_factor:
+        m = yarn_mscale(cfg.rope_yarn_factor, cfg.rope_yarn_mscale)
+    return (
+        (jnp.cos(angles) * m)[:, :, None, :],
+        (jnp.sin(angles) * m)[:, :, None, :],
+    )
+
+
+def _rope_leading(x, rope_cs):
+    """``x`` [.., head_dim] with its leading ``2 x`` the tables' width
+    columns rotated and the rest as they were."""
+    rot = 2 * rope_cs[0].shape[-1]
+    if rot == x.shape[-1]:
+        return rope_apply(x, *rope_cs)
+    return jnp.concatenate(
+        [rope_apply(x[..., :rot], *rope_cs), x[..., rot:]], axis=-1
+    )
+
+
+def _heads_qkv(
+    cfg: TransformerConfig, ap: Params, h, positions, run: Run, rope_cs=None
+):
     """``(q, k, v)`` of an attention or window layer, k and v ``[B, T,
     pool_kv_heads, pool_head_dim]`` as a page holds them (a differential
     pair's ``[k1 | k2]`` and ``[v1 | v2]`` are adjacent heads' columns: a
-    reshape)."""
+    reshape).  ``rope_cs``: the kind's own tables
+    (:func:`plain_rope_tables`, made once a program), where the stack
+    states a rope rule by kind; ``cfg`` is then the kind's."""
+    if rope_cs is not None:
+        q, k, v = _attn_qkv(
+            dataclasses.replace(cfg, use_rope=False), {"attn": ap},
+            _scaled(h, cfg.attn_in_scale), positions, None,
+        )
+        if run.rope:
+            q, k = _rope_leading(q, rope_cs), _rope_leading(k, rope_cs)
+        return q, k, v
     if not cfg.diff_attention:
         return _attn_qkv(
             _rope_cfg(cfg, run), {"attn": ap}, _scaled(h, cfg.attn_in_scale),
@@ -1272,10 +1432,14 @@ def _attn_dtype(cfg: TransformerConfig, dtype):
     return F32 if cfg.diff_attention else dtype
 
 
-def _heads_out(cfg: TransformerConfig, ap: Params, l, attn, dtype):
+def _heads_out(cfg: TransformerConfig, ap: Params, l, attn, dtype, a=None):
     """Attention's output ``[B, T, Hq * pool_head_dim]`` through the
-    pairs' difference, weight and norm (differential heads) and ``W_o``."""
+    pairs' difference, weight and norm (differential heads), or the
+    headwise gate of the layer's normed input ``a`` where the kind has
+    one (:func:`latent_out`'s rule), and ``W_o``."""
     if not cfg.diff_attention:
+        if "gate" in ap:
+            return _scaled(latent_out(cfg, ap, a, attn), cfg.attn_out_scale)
         return _scaled(_proj(ap["o"], attn), cfg.attn_out_scale)
     B, T, _ = attn.shape
     o = attn.reshape(B, T, cfg.n_q_heads // 2, 2, 2 * cfg.head_dim).astype(F32)
@@ -1503,27 +1667,38 @@ def latent_values_out(cfg: TransformerConfig, ap: Params, o_lat, dtype=None):
 
 @region("areal.mlp")
 def _mlp_half(
-    cfg: TransformerConfig, params: Params, run: Run, l, e, x, valid, a=None
+    cfg: TransformerConfig, params: Params, run: Run, l, e, x, valid, a=None,
+    lp: Optional[Params] = None,
 ):
     """The second half of layer ``l`` (number ``e`` among its MLP kind);
     ``a``: the mixer's input, which the router reads where
-    ``cfg.moe_router_input == "attn"``.  Returns ``(x, pairs, routed [B,
-    T, K], extra rounds)``, the last three None after a dense MLP and the
-    last one wherever the experts took the product over every held one:
-    see ``moe.held_moe_mlp``."""
-    h = _norm(x, _at(params["layers"]["mlp_norm"], l), cfg)
+    ``cfg.moe_router_input == "attn"``; ``lp``: the layer's OWN parameters
+    where the caller scans them (``{"mlp_norm", "mlp"}``: see
+    :func:`_run_params`), else they are cut out of ``params``' stacks
+    here.  Returns ``(x, pairs, routed [B, T, K], extra rounds)``, the
+    last three None after a dense MLP and the last one wherever the
+    experts took the product over every held one: see
+    ``moe.held_moe_mlp``."""
+    h = _norm(
+        x, lp["mlp_norm"] if lp else _at(params["layers"]["mlp_norm"], l), cfg
+    )
     if run.mlp == "dense":
-        dp = _at(params["dense"], e)
+        dp = lp["mlp"] if lp else _at(params["dense"], e)
         m_gate, m_down = cfg.mlp_scales or (None, None)
         hid = _activation(
             _scaled(_proj(dp["gate"], h), m_gate), cfg.activation
         ) * _proj(dp["up"], h)
         out = _scaled(_proj(dp["down"], hid), m_down)
+        if lp:  # the trainer's form: a remat preset may keep it
+            out = checkpoint_name(out, remat_names.MLP_OUT)
         return _res(cfg, x, out), None, None, None
     out, pairs, routed, rounds = held_moe_mlp(
-        cfg, h, params["layers"]["mlp"], valid=valid,
-        router_input=a if cfg.moe_router_input == "attn" else None, layer=e,
+        cfg, h, lp["mlp"] if lp else params["layers"]["mlp"], valid=valid,
+        router_input=a if cfg.moe_router_input == "attn" else None,
+        layer=None if lp else e,
     )
+    if lp:
+        out = checkpoint_name(out, remat_names.MLP_OUT)
     return _res(cfg, x, out), pairs, routed, rounds
 
 
@@ -1565,29 +1740,115 @@ def _add_pairs(pairs, p):
 # ---------------------------------------------------------------------------
 
 
+#: the mixer kinds whose backward exists: the flash kernels' (windowed or
+#: not) and the dense form's; a scan's, latent and sparse attention's do not
+TRAINABLE_KINDS = PLAIN_ATTENTION_KINDS
+
+
+def refuse_untrainable(cfg: TransformerConfig):
+    """Raise, by name, for a stack the trainer cannot take."""
+    kinds = sorted(set(cfg.layer_types) - set(TRAINABLE_KINDS))
+    if kinds or cfg.diff_attention:
+        raise NotImplementedError(
+            f"the trainer cannot run a stack with layer kinds {kinds}"
+            f"{' and differential heads' if cfg.diff_attention else ''}: "
+            f"the backward exists for {TRAINABLE_KINDS} (packed rows, the "
+            "flash kernels, the grouped product over held experts); a "
+            "recurrent state has no packing and its scans, latent and sparse "
+            "attention have no backward here (ROADMAP R9)"
+        )
+
+
+def _run_params(params: Params, cfg: TransformerConfig, run: Run) -> Params:
+    """A run's layers cut out of the stacks, to be SCANNED: ``{"attn_norm",
+    "mlp_norm", "mixer", "mlp"[, "mamba"]}``, each leaf ``[run.count,
+    ...]``.  A scan over the layers' own parameters stacks their gradients
+    as it goes; a body that indexes the whole stacks by a scanned number
+    would add a stack-sized cotangent a layer."""
+
+    def take(tree, first, stride):
+        return jax.tree.map(
+            lambda a: a[first : first + run.count * stride : stride], tree
+        )
+
+    layers = params["layers"]
+    lp = {
+        "attn_norm": take(layers["attn_norm"], run.first_layer, run.every),
+        "mlp_norm": take(layers["mlp_norm"], run.first_layer, run.every),
+        "mixer": take(
+            params[_stack_of(run.kind, cfg)], run.first_of_kind, run.strides[0]
+        ),
+        "mlp": take(
+            params["dense"] if run.mlp == "dense" else layers["mlp"],
+            run.first_of_mlp, run.strides[1],
+        ),
+    }
+    if run.kind == "parallel":
+        lp["mamba"] = take(params["mamba"], run.first_of_state, run.strides[3])
+    return lp
+
+
+def _stats_zero():
+    """The expert layers' counts of :func:`hidden_states`, at nothing."""
+    z = jnp.zeros((), F32)
+    return {
+        "moe_held_pairs_sum": z, "moe_busiest_pairs_sum": z,
+        "moe_extra_rounds_sum": z,
+    }
+
+
 def hidden_states(
-    params: Params, cfg: TransformerConfig, tokens, positions, seg_ids
+    params: Params, cfg: TransformerConfig, tokens, positions, seg_ids,
+    with_stats: bool = False,
 ):
-    """Final-norm hidden states [B, T, D] of ONE segment a row
-    (``seg_ids`` 1 on a prefix, 0 on the padding after it): a recurrent
-    state has no packing of several sequences in a row."""
+    """Final-norm hidden states [B, T, D] of whole rows, and the form the
+    TRAINER differentiates (``transformer.hidden_states`` hands a stack
+    stated by kind here): every run scans its layers' own parameters
+    (:func:`_run_params`), under ``cfg.remat`` a layer is rematerialised
+    by the presets of ``models/remat.py``, and attention runs in the flash
+    kernels, windowed or not, wherever ``transformer.takes_flash`` holds
+    (float32 ``[T, T]`` scores under the mask elsewhere: the parity tests'
+    form).  A stack of attention kinds alone takes PACKED rows (several
+    segments a row, ``seg_ids`` 1..k, 0 padding); a stack with a recurrent
+    state takes ONE segment a row (``seg_ids`` 1 on a prefix): a state has
+    no packing.  ``with_stats``: also the expert layers' counts summed
+    over layers (float32 scalars: the valid pairs the held experts took,
+    the busiest held expert's pairs a layer, the grouped product's rounds
+    past the first) and ``"routed_experts"`` [expert layers, B, T, K], each
+    token's routed experts by their published numbers, for a check that
+    follows the trainer's choices."""
+    from areal_tpu.models import transformer
+
     B, T = tokens.shape
     n_valid = jnp.sum(seg_ids != 0, axis=1, dtype=jnp.int32)
     valid = seg_ids != 0
     x = _embed(params, cfg, tokens, positions)
-    mask = make_attention_mask(seg_ids, positions, seg_ids, positions)
-    mask_window = mask
-    if cfg.n_window_layers:
-        mask_window = make_attention_mask(
-            seg_ids, positions, seg_ids, positions, cfg.sliding_window
-        )
+    wcfg = cfg.window_plain()
+    flash = transformer.takes_flash(cfg, T, transformer._AMBIENT_MESH)
+    mask = mask_window = None
+    if not flash:
+        mask = make_attention_mask(seg_ids, positions, seg_ids, positions)
+        mask_window = mask
+        if cfg.n_window_layers:
+            mask_window = make_attention_mask(
+                seg_ids, positions, seg_ids, positions, cfg.sliding_window
+            )
     s0 = jnp.zeros((B, cfg.mamba_d_state, cfg.mamba_d_inner), F32)
     tail0 = jnp.zeros((B, cfg.mamba_d_conv - 1, cfg.mamba_conv_dim), x.dtype)
     scale = _attn_scale(cfg)
     rope_cs = latent_rope_tables(cfg, positions) if cfg.is_latent else None
     if cfg.is_latent_window:
-        wcfg = cfg.window_latent()
-        rope_cs_win = latent_rope_tables(wcfg, positions)
+        lwcfg = cfg.window_latent()
+        rope_cs_win = latent_rope_tables(lwcfg, positions)
+    # a rope rule by kind: the kinds' own tables, made once
+    plain_cs = {}
+    if cfg.window_has_own_widths or cfg.rope_partial_dim or (
+        cfg.rope_yarn_factor and not cfg.is_latent
+    ):
+        plain_cs = {
+            "attention": plain_rope_tables(cfg, positions),
+            "window": plain_rope_tables(wcfg, positions),
+        }
 
     def attend(q, k, v, mask, scale=scale):
         """Causal attention of whole rows: q [B, T, Hq, hd], k [B, T,
@@ -1604,40 +1865,47 @@ def hidden_states(
         )
         return o.reshape(B, T, -1).astype(_attn_dtype(cfg, x.dtype))
 
+    def attend_plain(kcfg, q, k, v, window):
+        """A plain attention kind's rows: the flash kernels under the
+        kind's window, or :func:`attend` under its mask."""
+        if not flash:
+            return attend(q, k, v, mask if window is None else mask_window)
+        out = transformer._flash_attention(q, k, v, seg_ids, kcfg, window)
+        return out.reshape(B, T, -1)
+
     def keeps_memory(run: Run):
         return _place_in(run, cfg.memory_layer) is not None
 
-    def mixer(run: Run, h, l, j):
+    def mixer(run: Run, h, l, ap):
         """``(the mixer's output, what later layers read of it)``: the K
         and V the cross layers attend, the scan output the gated memory
         units gate, else None."""
         if run.kind == "mamba":
-            out, _, _ = mamba_chunk(
-                cfg, _at(params["mamba"], j), h, n_valid, s0, tail0
-            )
+            out, _, _ = mamba_chunk(cfg, ap, h, n_valid, s0, tail0)
             return out, None
         if run.kind == "mamba1":
-            out, _, _, y = mamba1_chunk(
-                cfg, _at(params["mamba1"], j), h, n_valid, s0, tail0
-            )
+            out, _, _, y = mamba1_chunk(cfg, ap, h, n_valid, s0, tail0)
             return out, y if keeps_memory(run) else None
         if run.kind == "gmu":
-            return gmu(_at(params["gmu"], j), h, shared["memory"]), None
+            return gmu(ap, h, shared["memory"]), None
         if run.kind == "cross":
-            ap = _at(params["cross"], j)
             q = _heads_q(cfg, ap, h, positions, run)
             attn = attend(q, *shared["kv"], mask)
             return _heads_out(cfg, ap, l, attn, h.dtype), None
         if run.kind in ("attention", "window"):
-            ap = _at(params["attn"], j)
-            q, k, v = _heads_qkv(cfg, ap, h, positions, run)
-            m = mask_window if run.kind == "window" else mask
-            out = _heads_out(cfg, ap, l, attend(q, k, v, m), h.dtype)
+            kcfg = wcfg if run.kind == "window" else cfg
+            q, k, v = _heads_qkv(
+                kcfg, ap, h, positions, run, plain_cs.get(run.kind)
+            )
+            window = cfg.sliding_window if run.kind == "window" else None
+            attn = checkpoint_name(
+                attend_plain(kcfg, q, k, v, window), remat_names.ATTN_OUT
+            )
+            out = _heads_out(kcfg, ap, l, attn, h.dtype, h)
             return out, (k, v) if cfg.n_cross_layers else None
         if run.kind == "latent_window":
-            ap = _at(params["latent_window"], j)
-            return latent_whole(wcfg, ap, h, rope_cs_win, mask_window), None
-        return latent_whole(cfg, _at(params["latent"], j), h, rope_cs, mask), None
+            return latent_whole(lwcfg, ap, h, rope_cs_win, mask_window), None
+        return latent_whole(cfg, ap, h, rope_cs, mask), None
 
     def latent_whole(lcfg, ap, h, cs, mask):
         """The latent mixer over whole rows, keys and values expanded;
@@ -1660,35 +1928,79 @@ def hidden_states(
 
     shared = {}  # what one layer leaves for later ones to read
 
-    def parallel_mixer(run: Run, h, l, j, js):
+    def parallel_mixer(run: Run, h, l, lp):
         """Both mixers on the one normed input, summed."""
         with region("areal.attn"):
-            ap = _at(params["attn"], j)
+            ap = lp["mixer"]
             q, k, v = _heads_qkv(cfg, ap, h, positions, run)
             out = _heads_out(cfg, ap, l, attend(q, k, v, mask), h.dtype)
         with region("areal.ssm"):
-            out_m, _, _ = mamba_chunk(
-                cfg, _at(params["mamba"], js), h, n_valid, s0, tail0
-            )
+            out_m, _, _ = mamba_chunk(cfg, lp["mamba"], h, n_valid, s0, tail0)
         return out + out_m
 
-    def body(x, idx, run):
-        l, j, e = idx[:3]
+    def mixer_half(x, idx, lp, run):
+        """The layer's first half: ``(x, the mixer's input, what later
+        layers read of the mixer)``."""
         with _mixer_region(run):
-            a = _norm(x, _at(params["layers"]["attn_norm"], l), cfg)
+            a = _norm(x, lp["attn_norm"], cfg)
             if run.kind == "parallel":
-                out, left = parallel_mixer(run, a, l, j, idx[4]), None
+                out, left = parallel_mixer(run, a, idx[0], lp), None
             else:
-                out, left = mixer(run, a, l, j)
-            x = _res(cfg, x, out)
-        x, _, _, _ = _mlp_half(cfg, params, run, l, e, x, valid, a)
-        return x, left
+                out, left = mixer(run, a, idx[0], lp["mixer"])
+            return _res(cfg, x, out), a, left
 
+    def mlp_half(x, a, idx, lp, run):
+        return _mlp_half(cfg, params, run, idx[0], idx[2], x, valid, a, lp=lp)
+
+    def body(carry, xs, run):
+        x, stats = carry
+        idx, lp = xs
+        halves = partial(mixer_half, run=run), partial(mlp_half, run=run)
+        if cfg.remat:
+            # each half rematerialised on its own: the backward of one
+            # holds that half's intermediates alone
+            policy = remat_names.policy_for(cfg.remat_policy)
+            halves = [jax.checkpoint(h, policy=policy) for h in halves]
+        x, a, left = halves[0](x, idx, lp)
+        reads_a = cfg.moe_router_input == "attn" and run.mlp == "experts"
+        x, pairs, routed, rounds = halves[1](x, a if reads_a else None, idx, lp)
+        if pairs is not None:
+            held = pairs[: cfg.n_held_experts].astype(F32)
+            stats = {
+                "moe_held_pairs_sum": stats["moe_held_pairs_sum"] + held.sum(),
+                "moe_busiest_pairs_sum": stats["moe_busiest_pairs_sum"]
+                + held.max(),
+                "moe_extra_rounds_sum": stats["moe_extra_rounds_sum"]
+                + (0.0 if rounds is None else rounds.astype(F32)),
+            }
+        return (x, stats), (left, routed if with_stats else None)
+
+    def xs_of(run: Run):
+        return _run_indices(run), _run_params(params, cfg, run)
+
+    carry = (x, _stats_zero())
+    routed = {}  # an expert run's first layer -> its layers' [n, B, T, K]
     for period in plan_periods(cfg):
-        x, lefts = _scan_period(body, x, period, _run_indices)
-        for run, left in zip(period, lefts):
+        carry, kept = _scan_period(body, carry, period, xs_of)
+        for run, (left, ids) in zip(period, kept):
             _keep_shared(cfg, run, shared, left)
-    return _final_norm(params, cfg, x)
+            if ids is not None:
+                routed[run.first_of_mlp] = (run, ids)
+    x, stats = carry
+    x = _final_norm(params, cfg, x)
+    if not with_stats:
+        return x
+    if routed:
+        # in the expert layers' order (a period's runs interleave)
+        Le = cfg.n_expert_layers
+        ids = jnp.zeros((Le,) + tokens.shape + (cfg.n_experts_per_tok,), jnp.int32)
+        for run, got in routed.values():
+            ids = ids.at[
+                run.first_of_mlp : run.first_of_mlp + run.count * run.strides[1]
+                : run.strides[1]
+            ].set(got)
+        stats["routed_experts"] = ids
+    return x, stats
 
 
 def _keep_shared(cfg: TransformerConfig, run: Run, shared: dict, left):

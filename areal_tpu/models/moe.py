@@ -9,6 +9,12 @@ the CUDA ``grouped_gemm`` dependency.  Expert parallelism shards the [E, ...]
 expert-weight dimension over the ``expert`` mesh axis (transformer.param_pspecs;
 SURVEY §2.9 EP — a capability beyond the reference's local-only MoE).
 
+The held experts' grouped product (:func:`grouped_expert_compute`: rounds
+of plain batched dots over the routed pairs, a ``while_loop``) has a form
+of its own for the trainer, with its derivative
+(:func:`grouped_expert_train`: the held pairs in tiles, expert after
+expert, none dropped, and one gather a call).
+
 Two EP regimes:
 
 * Training leaves the partitioning to XLA's SPMD partitioner over the
@@ -30,6 +36,7 @@ Two EP regimes:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -382,18 +389,7 @@ def grouped_expert_compute(
     latent cell's choice bias) would fall back in most calls."""
     N, D = x.shape
     K = local.shape[1]
-    is_held = (local >= 0) & (local < held)
-    if valid is not None:
-        is_held = is_held & valid[:, None]
-    hit = is_held[:, :, None] & (
-        local[:, :, None] == jnp.arange(held)[None, None, :]
-    )  # [N, K, E_held]; top-k names an expert once a token
-    # tokens of expert e up to and with token n, and each pair's rank
-    cum = jnp.cumsum(jnp.any(hit, axis=1).astype(jnp.int32), axis=0)
-    rank = jnp.sum(jnp.where(hit, cum[:, None, :], 0), axis=2) - 1  # [N, K]
-    count = cum[-1]  # [E_held]
-    rounds = (jnp.max(count) + cap - 1) // cap
-    first_slot = jnp.where(is_held, local, 0) * cap  # [N, K]
+    is_held, cum, rank, rounds, first_slot = _pair_ranks(local, valid, held, cap)
     wf = w.astype(jnp.float32)
 
     def one_round(carry):
@@ -425,6 +421,233 @@ def grouped_expert_compute(
         (jnp.int32(0), jnp.zeros((N, D), jnp.float32)),
     )
     return acc.astype(x.dtype), jnp.maximum(rounds - 1, 0).astype(jnp.int32)
+
+
+def _pair_ranks(local, valid, held: int, cap: int):
+    """The grouped product's layout of the pairs ``local`` [N, K]:
+    ``(is_held [N, K], cum [N, E_held], rank [N, K], rounds, first_slot
+    [N, K])``: which pairs take part (a held expert's, of a valid token),
+    the tokens of expert e up to and with token n, each pair's rank among
+    its expert's pairs in token order, the rounds of ``cap`` ranks the
+    busiest expert needs, and ``cap`` times a pair's expert."""
+    is_held = (local >= 0) & (local < held)
+    if valid is not None:
+        is_held = is_held & valid[:, None]
+    hit = is_held[:, :, None] & (
+        local[:, :, None] == jnp.arange(held)[None, None, :]
+    )  # [N, K, E_held]; top-k names an expert once a token
+    cum = jnp.cumsum(jnp.any(hit, axis=1).astype(jnp.int32), axis=0)
+    rank = jnp.sum(jnp.where(hit, cum[:, None, :], 0), axis=2) - 1  # [N, K]
+    rounds = (jnp.max(cum[-1]) + cap - 1) // cap
+    first_slot = jnp.where(is_held, local, 0) * cap  # [N, K]
+    return is_held, cum, rank, rounds, first_slot
+
+
+#: tiles of one pass of the trainer's loop over the held pairs
+#: (:func:`grouped_expert_train`): a pass multiplies ``TRAIN_TILES x cap``
+#: rows, so the passes a call takes follow its held pairs in steps of
+#: 2,048 rows at the ``GROUP_ROWS`` it is called with
+TRAIN_TILES = 8
+
+
+def _tile_layout(cum, cap: int):
+    """The trainer's layout of the held pairs in TILES of ``cap`` rows,
+    expert after expert: ``(count [E_held], first tile [E_held], tile
+    after the last [E_held])``.  An expert with no pair takes no tile and
+    the others round up to whole tiles, so the rows laid out are the held
+    pairs and at most ``cap - 1`` more an expert, whatever the busiest
+    expert took."""
+    count = cum[-1]
+    ends = jnp.cumsum((count + cap - 1) // cap)
+    return count, ends - (count + cap - 1) // cap, ends
+
+
+def _tile_rows(N: int, K: int, held: int, cap: int) -> int:
+    """Rows of the buffer that holds every tile of a call at ANY routing:
+    ``N K`` pairs and a ragged end an expert, in whole passes."""
+    tiles = -(-N * K // cap) + held
+    return -(-tiles // TRAIN_TILES) * TRAIN_TILES * cap
+
+
+def _pass_tiles(c, cap: int, count, starts, ends, cum_t):
+    """Pass ``c`` of the tile layout: ``(expert [G], live [G, cap], token
+    [G, cap])`` of its ``G = TRAIN_TILES`` tiles: which expert a tile
+    belongs to, which of its rows hold a pair, and the token of each (the
+    first whose count passes the row's rank; any token where no pair
+    is)."""
+    held, N = cum_t.shape
+    t = c * TRAIN_TILES + jnp.arange(TRAIN_TILES)
+    e = jnp.minimum(jnp.sum(ends[None, :] <= t[:, None], axis=1), held - 1)
+    j = (t - starts[e])[:, None] * cap + jnp.arange(cap)[None, :]  # ranks
+    live = (t < ends[-1])[:, None] & (j < count[e][:, None])
+    tok = jnp.sum(cum_t[e][:, :, None] <= j[:, None, :], axis=1)
+    return e, live, jnp.minimum(tok, N - 1)
+
+
+def _pair_rows(local, is_held, rank, starts, cap: int):
+    """``[N, K]``: the row of the tile layout that holds each pair (0
+    where the pair is not held: masked by the caller)."""
+    first = starts[jnp.where(is_held, local, 0)] * cap
+    return jnp.where(is_held, first + rank, 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def grouped_expert_train(
+    x, local, w, valid, gate_w, up_w, down_w, held: int, act_kind: str,
+    cap: int,
+):
+    """What :func:`grouped_expert_compute` gives, over ONE layer's
+    ``[E_held, F, D]`` arrays in hand (a trainer's masters, cast to
+    ``x``'s dtype here), in the form a TRAINER's long calls take, WITH a
+    derivative: ``(out [N, D], the busiest expert's groups of cap past
+    the first)``.
+
+    The held pairs are laid out in tiles of ``cap`` rows, expert after
+    expert (:func:`_tile_layout`); a loop multiplies ``TRAIN_TILES`` tiles
+    a pass by plain batched dots over the tiles' experts' weights and
+    writes the products into ONE buffer of rows (in ``x``'s dtype: 0.57 GB
+    at 16,384 tokens x 8 in bfloat16), and after the loop every pair
+    gathers its row ONCE, weights it and sums a token's in float32.  So a call costs its held pairs (in passes
+    of ``TRAIN_TILES x cap`` rows) and one gather of ``N x K`` rows,
+    whatever the busiest expert took: the rounds of the serving form cost
+    that gather EACH, and their count follows the busiest expert, which
+    under a choice bias drawn by seed swung a 16,384-token step's time by
+    a hundredth from seed to seed (my chip runs, PR 53).  No pair is
+    dropped at any routing: the loop runs until the last tile is done.
+    The loop is a ``while_loop`` (no reverse mode), so the backward is
+    written out (:func:`_grouped_bwd`)."""
+    N, D = x.shape
+    K = local.shape[1]
+    dt = x.dtype
+    f32 = jnp.float32
+    is_held, cum, rank, rounds, _ = _pair_ranks(local, valid, held, cap)
+    count, starts, ends = _tile_layout(cum, cap)
+    cum_t = cum.T
+    gate_w, up_w, down_w = (a.astype(dt) for a in (gate_w, up_w, down_w))
+    rows = TRAIN_TILES * cap
+
+    def one_pass(c, ys):
+        e, _, tok = _pass_tiles(c, cap, count, starts, ends, cum_t)
+        xg = x[tok]  # [G, cap, D]
+        g = jnp.einsum("gcd,gfd->gcf", xg, gate_w[e])
+        u = jnp.einsum("gcd,gfd->gcf", xg, up_w[e])
+        y = jnp.einsum(
+            "gcf,gfd->gcd", _activation(g, act_kind) * u, down_w[e],
+            preferred_element_type=f32,
+        )
+        return jax.lax.dynamic_update_slice(
+            ys, y.reshape(rows, D).astype(dt), (c * rows, 0)
+        )
+
+    with region("areal.moe.experts"):
+        ys = jax.lax.fori_loop(
+            0, (ends[-1] + TRAIN_TILES - 1) // TRAIN_TILES, one_pass,
+            jnp.zeros((_tile_rows(N, K, held, cap), D), dt),
+        )
+        row = _pair_rows(local, is_held, rank, starts, cap)
+        wf = w.astype(f32)
+        acc = jnp.zeros((N, D), f32)
+        for k in range(K):
+            acc = acc + jnp.where(
+                is_held[:, k, None], ys[row[:, k]].astype(f32) * wf[:, k, None], 0.0
+            )
+    return acc.astype(dt), jnp.maximum(rounds - 1, 0).astype(jnp.int32)
+
+
+def _grouped_fwd(x, local, w, valid, gate_w, up_w, down_w, held, act_kind, cap):
+    out = grouped_expert_train(
+        x, local, w, valid, gate_w, up_w, down_w, held, act_kind, cap
+    )
+    return out, (x, local, w, valid, gate_w, up_w, down_w)
+
+
+@region("areal.moe.experts")
+def _grouped_bwd(held: int, act_kind: str, cap: int, kept, cts):
+    """:func:`grouped_expert_train`'s backward, for the held pairs only
+    and without dropping any: the forward's passes again, each computing
+    by plain batched dots its tiles' share of the three ``dW`` (added in
+    float32 to the tiles' experts, handed back in the masters' dtype) and
+    writing its rows of ``dx`` and of ``dw`` (a pair's ``dout[n] . y``,
+    ``y`` the pair's unweighted float32 down product, computed again) into
+    a buffer each, from which every pair gathers its row once after the
+    loop.  A row past its expert's last pair carries a weight of 0 and so
+    adds nothing to any ``dW``.  The routing (``local``, ``valid``) is
+    integers and carries no gradient."""
+    x, local, w, valid, gate_m, up_m, down_m = kept
+    f32 = jnp.float32
+    dt = x.dtype
+    N, D = x.shape
+    K = local.shape[1]
+    dout = cts[0].astype(f32)
+    gate_w, up_w, down_w = (a.astype(dt) for a in (gate_m, up_m, down_m))
+    is_held, cum, rank, _, _ = _pair_ranks(local, valid, held, cap)
+    count, starts, ends = _tile_layout(cum, cap)
+    cum_t = cum.T
+    rows = TRAIN_TILES * cap
+    # each token's weight for each held expert, 0 where not routed
+    w_tok = jnp.sum(
+        jnp.where(
+            is_held[:, :, None]
+            & (local[:, :, None] == jnp.arange(held)[None, None, :]),
+            w.astype(f32)[:, :, None], 0.0,
+        ),
+        axis=1,
+    )  # [N, E_held]
+
+    def one_pass(c, carry):
+        dxs, dws, d_gate, d_up, d_down = carry
+        e, live, tok = _pass_tiles(c, cap, count, starts, ends, cum_t)
+        ge, ue, de = gate_w[e], up_w[e], down_w[e]
+        xg = x[tok]
+        g = jnp.einsum("gcd,gfd->gcf", xg, ge)
+        u = jnp.einsum("gcd,gfd->gcf", xg, ue)
+        hid, act_vjp = jax.vjp(lambda g, u: _activation(g, act_kind) * u, g, u)
+        do = dout[tok]  # [G, cap, D] float32
+        dy = (do * jnp.where(live, w_tok[tok, e[:, None]], 0.0)[..., None]).astype(dt)
+        y = jnp.einsum("gcf,gfd->gcd", hid, de, preferred_element_type=f32)
+        d_down = d_down.at[e].add(
+            jnp.einsum("gcf,gcd->gfd", hid, dy, preferred_element_type=f32)
+        )
+        dg, du = act_vjp(jnp.einsum("gcd,gfd->gcf", dy, de))
+        d_gate = d_gate.at[e].add(
+            jnp.einsum("gcf,gcd->gfd", dg, xg, preferred_element_type=f32)
+        )
+        d_up = d_up.at[e].add(
+            jnp.einsum("gcf,gcd->gfd", du, xg, preferred_element_type=f32)
+        )
+        dxg = jnp.einsum(
+            "gcf,gfd->gcd", dg, ge, preferred_element_type=f32
+        ) + jnp.einsum("gcf,gfd->gcd", du, ue, preferred_element_type=f32)
+        dxs = jax.lax.dynamic_update_slice(
+            dxs, dxg.reshape(rows, D).astype(dt), (c * rows, 0)
+        )
+        dws = jax.lax.dynamic_update_slice(
+            dws, jnp.sum(do * y, axis=-1).reshape(rows), (c * rows,)
+        )
+        return dxs, dws, d_gate, d_up, d_down
+
+    zeros = lambda a: jnp.zeros(a.shape, f32)
+    n_rows = _tile_rows(N, K, held, cap)
+    dxs, dws, d_gate, d_up, d_down = jax.lax.fori_loop(
+        0, (ends[-1] + TRAIN_TILES - 1) // TRAIN_TILES, one_pass,
+        (
+            jnp.zeros((n_rows, D), dt), jnp.zeros((n_rows,), f32),
+            zeros(gate_m), zeros(up_m), zeros(down_m),
+        ),
+    )
+    row = _pair_rows(local, is_held, rank, starts, cap)
+    dx = jnp.zeros((N, D), f32)
+    for k in range(K):
+        dx = dx + jnp.where(is_held[:, k, None], dxs[row[:, k]].astype(f32), 0.0)
+    dw = jnp.where(is_held, dws[row], 0.0)
+    return (
+        dx.astype(dt), None, dw.astype(w.dtype), None,
+        d_gate.astype(gate_m.dtype), d_up.astype(up_m.dtype),
+        d_down.astype(down_m.dtype),
+    )
+
+
+grouped_expert_train.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def group_limited_choice(cfg: TransformerConfig, choice: jax.Array):
@@ -558,10 +781,17 @@ def held_moe_mlp(
             quantize.leaf_weight(ex[k], h.dtype) for k in ("gate", "up", "down")
         )
 
-    if cap:
+    flat_valid = None if valid is None else valid.reshape(-1)
+    if cap and layer is None and not isinstance(experts["gate"], dict):
+        # one layer's arrays in hand (a trainer scans the stack itself):
+        # the held pairs in tiles, with their derivative
+        out, rounds = grouped_expert_train(
+            x, local, w, flat_valid, experts["gate"], experts["up"],
+            experts["down"], held, cfg.activation, cap,
+        )
+    elif cap:
         out, rounds = grouped_expert_compute(
-            x, local, w, None if valid is None else valid.reshape(-1),
-            weights, held, cfg.activation, cap,
+            x, local, w, flat_valid, weights, held, cfg.activation, cap,
         )
     else:
         with region("areal.moe.route"):

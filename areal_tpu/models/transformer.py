@@ -41,7 +41,7 @@ from jax.sharding import PartitionSpec as P
 from areal_tpu.base import logging_
 from areal_tpu.engine.sampling import sample_and_advance
 from areal_tpu.models import quantize
-from areal_tpu.models.config import TransformerConfig
+from areal_tpu.models.config import PLAIN_ATTENTION_KINDS, TransformerConfig
 from areal_tpu.observability.tracing import region
 
 logger = logging_.getLogger("transformer")
@@ -149,6 +149,10 @@ def param_pspecs(
     runs the shard_map pipeline (areal_tpu/parallel/pipeline.py) instead of
     the plain layer scan.
     """
+    if cfg.is_hybrid:
+        from areal_tpu.models import hybrid
+
+        return hybrid.param_pspecs(cfg, params)
     lp = "pipe" if pipe else None  # stacked layer axis
 
     def spec_for(path: Tuple, leaf) -> P:
@@ -389,12 +393,23 @@ def _pipe_mesh():
 def takes_flash(cfg: TransformerConfig, T: int, mesh) -> bool:
     """Whether self-attention over segment-id rows of ``T`` slots runs the
     Pallas flash kernel under ``mesh`` (O(T) memory a row).  The one
-    predicate of ``_attention_dispatch`` and of the trainer's layout
+    predicate of ``_attention_dispatch``, of a stack stated by kind's
+    whole-row form (``hybrid.hidden_states``) and of the trainer's layout
     (``engine/train_engine.plan_layout``), which lengthens rows only where
     this holds: the jnp path keeps [T, T] scores, and a ``seq`` axis
-    splits T (ring / Ulysses)."""
+    splits T (ring / Ulysses).  A window runs in the kernels
+    (``flash_attention(window=)``); a stack stated by kind takes them when
+    EVERY attention kind of it does: plain heads at the kernels' own
+    softmax scale, full or windowed."""
     from areal_tpu.ops import flash_attention as fa
 
+    if cfg.is_hybrid and (
+        set(cfg.layer_types) - set(PLAIN_ATTENTION_KINDS)
+        or cfg.diff_attention
+        or cfg.attention_scale is not None
+        or (cfg.rope_yarn_factor and cfg.rope_yarn_mscale_all_dim)
+    ):
+        return False
     return (
         (mesh is None or mesh.shape.get("seq", 1) == 1)
         and jax.default_backend() == "tpu"
@@ -446,15 +461,16 @@ def _attention_dispatch(
         and q.shape[1] == k.shape[1]
         and takes_flash(cfg, q.shape[1], _AMBIENT_MESH)
     ):
-        return _flash_attention(q, k, v, seg_ids, cfg)
+        return _flash_attention(q, k, v, seg_ids, cfg, cfg.sliding_window)
     _warn_dense_fallback(
         q.shape[1], k.shape[1], cfg.sliding_window, seg_ids is None
     )
     return reference_attention(q, k, v, mask)
 
 
-def _flash_attention(q, k, v, seg_ids, cfg: TransformerConfig):
-    """The Pallas flash kernel, per shard on a multi-device trainer mesh.
+def _flash_attention(q, k, v, seg_ids, cfg: TransformerConfig, window=None):
+    """The Pallas flash kernel (under ``window``: ``i - j < window``), per
+    shard on a multi-device trainer mesh.
 
     A Mosaic kernel has no SPMD partitioning rule ("Mosaic kernels cannot
     be automatically partitioned"), so under the engine's sharded jit it
@@ -467,14 +483,15 @@ def _flash_attention(q, k, v, seg_ids, cfg: TransformerConfig):
     from areal_tpu.ops import flash_attention as fa
 
     mesh = _AMBIENT_MESH
+    attend = partial(fa.flash_attention, window=window)
     if mesh is None or mesh.devices.size == 1 or _pipe_mesh() is not None:
-        return fa.flash_attention(q, k, v, seg_ids)
+        return attend(q, k, v, seg_ids)
     batch_axes = tuple(a for a in ("data", "fsdp") if a in mesh.shape)
     tp = mesh.shape.get("model", 1)
     head_axis = "model" if tp > 1 and cfg.n_kv_heads % tp == 0 else None
     qkv_spec = P(batch_axes, None, head_axis, None)
     return jax.shard_map(
-        fa.flash_attention,
+        attend,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, P(batch_axes, None)),
         out_specs=qkv_spec,
@@ -1245,7 +1262,24 @@ def hidden_states(
 
     ``with_aux=True`` additionally returns the MoE router losses summed over
     layers ({"moe_aux_loss", "moe_z_loss"}, zeros for dense) so training
-    losses can include them."""
+    losses can include them.
+
+    A stack stated by kind goes through its layer plan
+    (``hybrid.hidden_states``: packed rows, the flash kernels by kind, the
+    grouped product over the held experts); its routers carry no auxiliary
+    loss, and its ``aux`` holds the expert layers' counts (``*_sum``)
+    beside the two zeros."""
+    if cfg.is_hybrid:
+        from areal_tpu.models import hybrid
+
+        hybrid.refuse_untrainable(cfg)
+        if not with_aux:
+            return hybrid.hidden_states(params, cfg, tokens, positions, seg_ids)
+        x, stats = hybrid.hidden_states(
+            params, cfg, tokens, positions, seg_ids, with_stats=True
+        )
+        zero = jnp.zeros((), jnp.float32)
+        return x, {"moe_aux_loss": zero, "moe_z_loss": zero, **stats}
     x = _embed(params, cfg, tokens, positions)
     mask = make_attention_mask(
         seg_ids, positions, seg_ids, positions, cfg.sliding_window

@@ -1560,6 +1560,23 @@ TRAIN_BATCH_RECORD = {
 }
 
 
+#: what a stack stated by kind adds to a batch's record (its expert
+#: layers' counts summed over layers and micro-batches, its gradient by
+#: group, before clipping)
+TRAIN_BATCH_RECORD_BY_KIND = {
+    "attn_window_blocks_run": "Flash-attention block pairs a WINDOW layer "
+    "runs on the layout, of attn_blocks_causal",
+    "moe_held_pairs": "Valid (token, k) pairs the held experts took",
+    "moe_busiest_pairs": "The busiest held expert's pairs, summed over "
+    "layers (over moe_held_pairs / held experts: max over mean)",
+    "moe_extra_rounds": "Groups of moe.GROUP_ROWS pairs the busiest held "
+    "expert takes past a layer's first (the rounds the serving form would "
+    "add: the trainer's tiles cost the held pairs, not these)",
+    "grad_norm": "The batch's global gradient norm",
+    "grad_norms": "... by group (hybrid.grad_group)",
+}
+
+
 #: names of the engine thread's phases: ``engine.phase_seconds()`` and
 #: ``areal_inference_phase_seconds_total{phase=}`` carry exactly these
 ENGINE_PHASES = tuple(

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 from typing import Sequence
 
-from areal_tpu.models.config import TransformerConfig
+from areal_tpu.models.config import PLAIN_ATTENTION_KINDS, TransformerConfig
 
 
 def matmul_params_per_layer(cfg: TransformerConfig) -> int:
@@ -33,6 +33,40 @@ def matmul_params_per_layer(cfg: TransformerConfig) -> int:
     return attn + n_mats * cfg.hidden_dim * cfg.intermediate_dim
 
 
+def _forward_flops_by_kind(
+    cfg: TransformerConfig, seqlens: Sequence[int], with_head: bool
+) -> int:
+    """:func:`forward_flops` of a stack of plain attention kinds
+    (``layer_types`` of "attention" and "window"): each layer at its
+    kind's own head count, a window layer's scores and values over
+    ``min(t, window)`` positions a query, a leading dense MLP or the
+    activated experts and the shared one."""
+    total_tokens = sum(seqlens)
+    D, flops = cfg.hidden_dim, 0
+    wcfg = cfg.window_plain()
+    for l, kind in enumerate(cfg.layer_types):
+        k = wcfg if kind == "window" else cfg
+        mats = D * (k.q_dim + 2 * k.kv_dim) + k.q_dim * D
+        if k.attention_gate:
+            mats += D * k.n_q_heads
+        if l < cfg.n_dense_layers or not cfg.is_moe:
+            mats += 3 * D * cfg.intermediate_dim
+        else:
+            inter = cfg.moe_intermediate_dim or cfg.intermediate_dim
+            mats += 3 * D * (
+                cfg.n_experts_per_tok * inter + cfg.shared_expert_dim
+            ) + D * cfg.n_experts
+        flops += 2 * mats * total_tokens
+        for t in seqlens:
+            w = min(t, cfg.sliding_window) if kind == "window" else t
+            # sum over queries of 4 q_dim min(i, w): the triangle, then
+            # the band
+            flops += 2 * k.q_dim * (w * w + 2 * w * (t - w))
+    if with_head:
+        flops += 2 * D * cfg.vocab_size * total_tokens
+    return flops
+
+
 def forward_flops(
     cfg: TransformerConfig,
     seqlens: Sequence[int],
@@ -43,6 +77,8 @@ def forward_flops(
     Per token: 2 * (matmul params) for the projections, plus causal
     attention ~ 2 * 2 * (t/2) * q_dim accumulated over each sequence of
     length t, plus the output head."""
+    if cfg.is_hybrid and set(cfg.layer_types) <= set(PLAIN_ATTENTION_KINDS):
+        return _forward_flops_by_kind(cfg, seqlens, with_head)
     total_tokens = sum(seqlens)
     flops = 2 * matmul_params_per_layer(cfg) * cfg.n_layers * total_tokens
     # causal attention: sum_t 4 * q_dim * t/2 = q_dim * t*(t+1) ~= q_dim*t^2

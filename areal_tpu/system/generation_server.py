@@ -208,7 +208,11 @@ class GenerationServerWorker(worker_base.Worker):
         self.worker_name = config.worker_name
         self.logger = logging_.getLogger(self.worker_name)
 
-        from areal_tpu.engine.backend import cast_floating, make_model
+        from areal_tpu.engine.backend import (
+            cast_floating,
+            make_model,
+            refuse_unserved,
+        )
         from areal_tpu.engine.inference_server import ContinuousBatchingEngine
         from areal_tpu.engine.sampling import SamplingParams
         from areal_tpu.engine.spec_decode import resolve_spec_params
@@ -301,6 +305,7 @@ class GenerationServerWorker(worker_base.Worker):
         # cast there to the serving dtype, so the engine's placement is
         # the first and only copy on its chip(s)
         model = make_model(config.model, None, None, tokenizer=tokenizer)
+        refuse_unserved(model.model_cfg)
         model.init_params = cast_floating(
             model.init_params, model.model_cfg.dtype
         )

@@ -464,13 +464,15 @@ def admit_stop_vocabulary_problems(
 
 def record_field_problems(path: str = DOCS_TABLE) -> List[str]:
     """The fields of the step records (``table.LAP_RECORD``,
-    ``ENGINE_STEP_RECORD``, ``TRAIN_BATCH_RECORD``) against the rows of
+    ``ENGINE_STEP_RECORD``, ``TRAIN_BATCH_RECORD`` and what a stack stated
+    by kind adds to it, ``TRAIN_BATCH_RECORD_BY_KIND``) against the rows of
     the docs' "Step records" section, both ways."""
     from areal_tpu.observability import table
 
     declared = (
         set(table.LAP_RECORD) | set(table.ENGINE_STEP_RECORD)
         | set(table.TRAIN_BATCH_RECORD)
+        | set(table.TRAIN_BATCH_RECORD_BY_KIND)
     )
     problems = [
         f"STEP_DELTAS entry {name!r} is not in ENGINE_STEP_RECORD"

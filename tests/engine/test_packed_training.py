@@ -242,17 +242,25 @@ def test_layout_by_slots_on_a_four_shard_mesh(layout_as_on_the_chip):
 
 
 def test_layout_by_slots_holds_the_row_length_under_a_sliding_window(
-    layout_as_on_the_chip,
+    layout_as_on_the_chip, monkeypatch,
 ):
     """The jnp attention path holds [T, T] scores: no longer rows there,
-    whatever the padding costs."""
+    whatever the padding costs.  A sliding window is no such path since
+    the flash kernels take one (``flash_attention(window=)``): its rows
+    grow as the unwindowed model's do; off the chip they hold."""
     cfg = tiny_config(sliding_window=8, **WIDE)
     plan = plan_layout(cfg, [[l] for l in OVER], BUDGET)
-    assert plan.row_len == batching.row_len(max(OVER)) == 1024
-    assert (plan.n_stacked, plan.rows) == (2, 2)
-    assert plan_layout(
+    assert plan.row_len == 1536 == plan_layout(
         tiny_config(**WIDE), [[l] for l in OVER], BUDGET
-    ).row_len == 1536
+    ).row_len
+    from areal_tpu.engine import train_engine
+    from areal_tpu.models import transformer
+
+    with monkeypatch.context() as m:  # this backend's own dispatch: the CPU's
+        m.setattr(train_engine, "takes_flash", transformer.takes_flash)
+        held = plan_layout(cfg, [[l] for l in OVER], BUDGET)
+    assert held.row_len == batching.row_len(max(OVER)) == 1024
+    assert (held.n_stacked, held.rows) == (2, 2)
     # nor on a mesh with a ``seq`` axis, which splits T
     seq = MeshSpec(seq=2).make_mesh(jax.devices()[:2])
     assert plan_layout(

@@ -257,3 +257,128 @@ def test_a_stack_with_recurrent_state_multiplies_every_held_expert(shape):
         want = _every_held_expert(cfg, h, p, None)
     assert rounds is None
     assert np.abs(np.asarray(got - want)).max() < 1e-5
+
+
+#: case -> (stack, [B, T], padding, the router's twist): the grouped
+#: product's BACKWARD against the gradient of the product over every held
+#: expert; "skew" gives one expert every token (four rounds at 1,024)
+BACKWARD = {
+    "latent-held-elsewhere-padding": ("latent", (4, 256), True, None),
+    "window-all-held-relu": ("window", (1, 1024), False, None),
+    "hybrid-shared-expert-two-rounds": ("hybrid", (1, 1100), False, None),
+    "busiest-expert-needs-extra-rounds": ("window", (1, 1024), False, "skew"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BACKWARD))
+def test_grouped_backward_is_the_gradient_of_every_held_expert(case):
+    """``dx``, the three ``dW`` of each held expert, the router's and the
+    shared expert's gradients of the grouped form (a layer's slice in
+    hand, as the trainer's scan hands it) against ``jax.grad`` of the
+    dense form, under a routing that needs extra rounds too: no pair is
+    dropped backward either."""
+    stack, (B, T), padded, twist = BACKWARD[case]
+    cfg = _cfg(**STACKS[stack])
+    p = _layer(cfg, jax.random.PRNGKey(7))
+    kh, ka, kc = jax.random.split(jax.random.PRNGKey(13), 3)
+    h = jax.random.normal(kh, (B, T, D))
+    a = jax.random.normal(ka, (B, T, D)) if cfg.moe_router_input == "attn" else None
+    if twist == "skew":
+        p["router"]["w"] = p["router"]["w"].at[:, 5].set(0.0)
+        h = h.at[..., 0].set(1.0)
+        a = None if a is None else a.at[..., 0].set(1.0)
+        p["router"]["w"] = p["router"]["w"].at[0].set(
+            jnp.zeros((cfg.n_experts,)).at[5].set(50.0)
+        )
+    valid = None
+    if padded:
+        lens = jnp.asarray([T, T // 3, 0, 7][:B])
+        valid = jnp.arange(T)[None, :] < lens[:, None]
+    keep = jnp.ones((B, T), bool) if valid is None else valid
+    ct = jax.random.normal(kc, (B, T, D)) * keep[..., None]
+
+    def grouped(h, p):
+        out, _, _, rounds = moe.held_moe_mlp(
+            cfg, h, p, valid=valid, router_input=a
+        )
+        return jnp.sum(out * ct), rounds
+
+    def dense(h, p):
+        return jnp.sum(_every_held_expert(cfg, h, p, a) * ct)
+
+    with jax.default_matmul_precision("highest"):
+        (_, rounds), got = jax.jit(
+            jax.value_and_grad(grouped, (0, 1), has_aux=True)
+        )(h, p)
+        want = jax.jit(jax.grad(dense, (0, 1)))(h, p)
+    if twist == "skew":
+        assert int(rounds) == B * T // moe.GROUP_ROWS - 1 > 0
+    flat_got = jax.tree_util.tree_leaves_with_path(got)
+    flat_want = jax.tree.leaves(want)
+    assert len(flat_got) == len(flat_want)
+    for (path, g), w in zip(flat_got, flat_want):
+        g, w = np.asarray(g), np.asarray(w)
+        if g.ndim == 3 and g.shape[:2] == (B, T):
+            # padding's dx: the dense form multiplies padding's rows too
+            g, w = g[np.asarray(keep)], w[np.asarray(keep)]
+        scale = max(np.abs(w).max(), 1e-6)
+        assert np.abs(g - w).max() < 2e-5 * scale + 1e-6, (path, np.abs(g - w).max(), scale)
+    if "bias" in p["router"]:
+        # the choice bias takes part in the choice alone: no gradient
+        assert not np.asarray(got[1]["router"]["bias"]).any()
+
+
+#: case -> (tokens, k, held, of experts, rows a tile, the routing's twist)
+TILES = {
+    "even": (512, 4, 8, 16, 32, None),
+    "one-expert-takes-every-token": (512, 4, 8, 16, 32, "skew"),
+    "padding-and-an-idle-expert": (300, 2, 4, 16, 16, "padded"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILES))
+def test_the_trainers_tiles_hold_each_held_pair_once_and_cost_the_pairs(case):
+    """The layout ``grouped_expert_train`` loops over: every held pair of a
+    valid token lies in exactly one live row of its expert's tiles, where
+    ``_pair_rows`` says; the tiles are the pairs rounded up an expert,
+    whatever the busiest expert took."""
+    N, K, held, E, cap, twist = TILES[case]
+    rng = np.random.default_rng(3)
+    local = np.stack([rng.permutation(E)[:K] for _ in range(N)]).astype(np.int32)
+    valid = None
+    if twist == "skew":
+        local[:, 0] = 5
+        local[:, 1:] = np.where(local[:, 1:] == 5, 15, local[:, 1:])
+    if twist == "padded":
+        valid = np.arange(N) < 200
+        local = np.where(local == 2, 9, local)  # held expert 2 idles
+    is_held, cum, rank, _, _ = moe._pair_ranks(
+        jnp.asarray(local), None if valid is None else jnp.asarray(valid), held, cap
+    )
+    count, starts, ends = moe._tile_layout(cum, cap)
+    count = np.asarray(count)
+    pairs = int(np.asarray(is_held).sum())
+    assert count.sum() == pairs
+    tiles = int(ends[-1])
+    assert tiles == sum(-(-c // cap) for c in count)
+    assert pairs <= tiles * cap < pairs + held * cap
+    assert tiles * cap <= moe._tile_rows(N, K, held, cap)
+    row = np.asarray(moe._pair_rows(jnp.asarray(local), is_held, rank, starts, cap))
+    seen = {}
+    for c in range(-(-tiles // moe.TRAIN_TILES)):
+        e, live, tok = (
+            np.asarray(a)
+            for a in moe._pass_tiles(c, cap, jnp.asarray(count), starts, ends, cum.T)
+        )
+        for g in range(moe.TRAIN_TILES):
+            for r in np.nonzero(live[g])[0]:
+                at = (c * moe.TRAIN_TILES + g) * cap + r
+                assert at not in seen
+                seen[at] = (int(tok[g, r]), int(e[g]))
+    held_pairs = {
+        int(row[n, k]): (n, int(local[n, k]))
+        for n, k in zip(*np.nonzero(np.asarray(is_held)))
+    }
+    assert seen == held_pairs
+    if twist == "skew":
+        assert count[5] == N and tiles * cap < 2 * pairs

@@ -25,8 +25,9 @@ def _row(T, *lens, ids=None):
     return row
 
 
-def _compare(seg, Hq=4, Hkv=2, hd=128, dtype=jnp.float32, seed=0):
-    """Kernel and reference on one draw: ``(out, grads)`` of each, float32."""
+def _compare(seg, Hq=4, Hkv=2, hd=128, dtype=jnp.float32, seed=0, window=None):
+    """Kernel and reference on one draw: ``(out, grads)`` of each, float32
+    (under ``window``: both attend ``i - j < window``)."""
     seg = jnp.asarray(seg, jnp.int32)
     B, T = seg.shape
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
@@ -41,6 +42,8 @@ def _compare(seg, Hq=4, Hkv=2, hd=128, dtype=jnp.float32, seed=0):
     mask = (seg[:, :, None] == seg[:, None, :]) & (
         at[:, None] >= at[None, :]
     )
+    if window is not None:
+        mask = mask & (at[:, None] - at[None, :] < window)
 
     def both(attend):
         def loss(q, k, v):
@@ -52,7 +55,11 @@ def _compare(seg, Hq=4, Hkv=2, hd=128, dtype=jnp.float32, seed=0):
         )
         return out, [g.astype(jnp.float32) for g in grads]
 
-    got = both(lambda q, k, v: fa.flash_attention(q, k, v, seg, interpret=True))
+    got = both(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, seg, interpret=True, window=window
+        )
+    )
     want = both(lambda q, k, v: reference_attention(q, k, v, mask))
     return got, want
 
@@ -189,10 +196,59 @@ def test_the_count_equals_thepairs_that_meet_on_the_trainers_layouts(mode, T):
         assert run < causal  # be it padding's with a sequence it follows
 
 
+#: windows smaller than, equal to and larger than a block of 512 (and one
+#: past the row): (window, the band's blocks at T = 2048)
+WINDOWS = {100: 2, 512: 2, 513: 2, 514: 3, 700: 3, 1537: 4, 4096: 4}
+
+
+@pytest.mark.parametrize("window", sorted(WINDOWS))
+def test_windowed_matches_the_masked_reference_forward_dq_dkv(window):
+    """Forward, dq and dkv under ``i - j < window`` beside the segment
+    rule, on a packed row whose sequences' ends fall inside blocks (a
+    window then crosses a segment edge in most blocks) and on one
+    sequence a row."""
+    assert fa.band_blocks(2048, window) == WINDOWS[window]
+    seg = np.stack(
+        LAYOUTS["packed_across_block_edges"] + LAYOUTS["one_sequence"]
+    )
+    _assert_close(*_compare(seg, window=window), tol=2e-5)
+
+
+def test_windowed_at_64_q_heads_on_8_kv_heads_in_bfloat16():
+    """A window layer's grouping (64 on 8 heads of 128, window 512) at the
+    trainer's dtype."""
+    seg = np.stack([_row(1024, 300, 600)])
+    _assert_close(
+        *_compare(seg, Hq=64, Hkv=8, dtype=jnp.bfloat16, window=512), tol=3e-2
+    )
+
+
+def test_a_window_keeps_the_band_and_the_segments_rule():
+    seg = np.stack(LAYOUTS["one_sequence"])
+    # window 512 at blocks of 512: a q block meets itself and the one before
+    kv_lo, q_hi = fa.block_ranges(seg, 512, xp=np, window=512)
+    assert kv_lo.tolist() == [[0, 0, 1, 2]]
+    assert q_hi.tolist() == [[1, 2, 3, 3]]
+    assert fa.blocks_run(seg, window=512) == (7, 10)
+    # a q block's first position reaches the last of the block two back
+    # from a window of 514 on
+    assert fa.blocks_run(seg, window=513) == (7, 10)
+    assert fa.blocks_run(seg, window=514) == (9, 10)
+    assert fa.blocks_run(seg, window=1) == (4, 10)
+    # the segments' rule still holds under the band: blocks {1}, {1, 2},
+    # {2, 3}, {3, padding}
+    packed = np.stack(LAYOUTS["packed_across_block_edges"])
+    assert fa.blocks_run(packed, window=2048) == (7, 10)
+    assert fa.blocks_run(packed, window=512) == (7, 10)
+    dev = fa.block_ranges(jnp.asarray(seg), 512, window=512)
+    np.testing.assert_array_equal(dev[0], kv_lo)
+    np.testing.assert_array_equal(dev[1], q_hi)
+
+
 def test_unsupported_row_lengths_fall_to_the_dense_path():
     assert fa.supported(8192, 8192, None) and fa.supported(384, 384, None)
     assert not fa.supported(640, 640, None)  # not whole blocks of 512
     assert not fa.supported(200, 200, None)  # not whole lane tiles
     assert not fa.supported(64, 64, None)
-    assert not fa.supported(1024, 1024, 256)  # sliding window
+    assert fa.supported(1024, 1024, 256)  # a window runs in the kernels
     assert fa.blocks_run(np.ones((2, 640), np.int32)) == (0, 0)

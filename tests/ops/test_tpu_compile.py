@@ -364,6 +364,183 @@ def test_flash_attention_fwd_bwd_compiles(one_chip, model, B, T):
         assert name in text, name
 
 
+#: scripts/lowered_programs.digest of the UNWINDOWED flash programs
+#: (forward + backward at the dense train cell's head layout), as the parent
+#: of PR 53 lowered them (commit d228c68; the same function run in a copy of
+#: that tree): the kernels took a ``window`` since, and with ``window=None``
+#: they are the programs they were, bit for bit
+UNWINDOWED_FLASH = {
+    (2, 2048): "0fd5e0cbddfdf017d16d8f397cc644498e6a837f",
+    (1, 8192): "adf4b5b708a26a84037ec7023cd5b11b45333e27",
+}
+
+
+def _flash_loss(window):
+    def loss(q, k, v, seg):
+        out = fa.flash_attention(q, k, v, seg, window=window)
+        return out.astype(jnp.float32).sum()
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+
+def _flash_shapes(one_chip, B, T, Hq, Hkv):
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    return (
+        s((B, T, Hq, HD), jnp.bfloat16), s((B, T, Hkv, HD), jnp.bfloat16),
+        s((B, T, Hkv, HD), jnp.bfloat16), s((B, T), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("B,T", sorted(UNWINDOWED_FLASH))
+def test_the_unwindowed_flash_programs_lowered_text_is_the_parents(one_chip, B, T):
+    lowered = jax.jit(_flash_loss(None)).lower(
+        *_flash_shapes(one_chip, B, T, *HEADS["qwen2.5-1.5b"])
+    )
+    assert (
+        _lowered_programs_script().digest(lowered) == UNWINDOWED_FLASH[B, T]
+    )
+
+
+@pytest.mark.parametrize(
+    "Hq,T,window,band",
+    [(64, 16384, 512, 2), (64, 9216, 512, 2), (48, 16384, None, 32), (64, 2048, 1300, 4)],
+)
+def test_windowed_flash_fwd_bwd_compiles_at_the_long_train_cells_heads(
+    one_chip, Hq, T, window, band
+):
+    """laguna-xs.2's two kinds (64 window and 48 full heads on 8 KV heads
+    of 128) at the train cell's row lengths: under a window the grid's
+    minor axis is the band's blocks, and the three calls carry names of
+    their own."""
+    lowered = jax.jit(_flash_loss(window)).lower(
+        *_flash_shapes(one_chip, 1, T, Hq, 8)
+    )
+    assert fa.band_blocks(T, window) == band
+    assert _mosaic_grids(lowered) == [(1, Hq, T // 512, band)] * 3
+    compiled = lowered.compile()
+    _assert_kernel(compiled)
+    text = compiled.as_text()
+    stem = "flash_attn_window" if window else "flash_attn"
+    for name in ("_fwd", "_bwd_dq", "_bwd_dkv"):
+        assert stem + name in text, name
+    if window:
+        assert "flash_attn_fwd" not in text
+
+
+def test_grouped_backward_compiles_at_the_long_train_cells_widths(one_chip):
+    """The grouped product's backward over 32 held experts of 512 x 2,048
+    at a 16,384-token micro-batch: passes of plain batched dots over the
+    held pairs' tiles, float32 accumulators of the three dW, one buffer of
+    rows for ``dx`` (0.57 GB in bfloat16), no pair dropped."""
+    N, D, F, E, K = 16384, 2048, 512, 32, 8
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def loss(x, w, gate, up, down, local):
+        out, rounds = moe.grouped_expert_train(
+            x, local, w, None, gate, up, down, E, "silu", moe.GROUP_ROWS
+        )
+        return out.astype(jnp.float32).sum(), rounds
+
+    compiled = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))
+        .lower(
+            s((N, D), jnp.bfloat16), s((N, K), jnp.float32),
+            *[s((E, F, D), jnp.float32)] * 3, s((N, K), jnp.int32),
+        )
+        .compile()
+    )
+    m = compiled.memory_analysis()
+    total = (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+    print(f"grouped backward: total {total / 1e9:.2f} GB, temporaries {m.temp_size_in_bytes / 1e9:.2f} GB")
+    assert total < 4e9, total
+
+
+@pytest.mark.slow  # a minute of compiling: past tier-1's 60 s a test
+def test_the_long_train_cells_largest_step_program_fits_one_chip(one_chip, monkeypatch):
+    """``train-long-expert.laguna-xs.2``'s largest step program (ONE
+    micro-batch of one 16,384-slot row: forward, backward through the flash
+    kernels by kind and the grouped product, fused optimizer step over
+    691.6 M parameters at 16 B each) at published widths.  The compiler
+    admits it (it REFUSES a program past 15.75 G of hbm by its own count:
+    two such micro-batches in one program read 16.90 G, PR 53); the count
+    of ``memory_analysis`` is recorded."""
+    import dataclasses
+    import json
+    import os
+
+    import optax
+
+    from areal_tpu.engine.optimizer import OptimizerConfig, make_optimizer
+    from areal_tpu.interfaces import ppo_interface
+    from benchmark.lib.program import model_config
+
+    root = os.path.join(os.path.dirname(__file__), "../..")
+    with open(os.path.join(root, "benchmark/configs/laguna-xs.2.json")) as f:
+        cfg = model_config(json.load(f), "train")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert transformer.takes_flash(cfg, 16384, None)
+    iface = ppo_interface.PPOActorInterface(
+        n_minibatches=2, kl_ctl=0.0, disable_value=True,
+        use_decoupled_loss=True, behav_imp_weight_cap=5.0, adv_norm=False,
+    )
+    tx = make_optimizer(OptimizerConfig(lr=1e-6), 10**6)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    place = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    params = place(
+        jax.eval_shape(
+            lambda k: hybrid.init_params(
+                dataclasses.replace(cfg, dtype="float32"), k
+            ),
+            jax.random.PRNGKey(0),
+        )
+    )
+    opt_state = place(jax.eval_shape(tx.init, params))
+    T, S, f32, i32 = 16384, 8, jnp.float32, jnp.int32
+    batch = {
+        **{k: s((1, T), i32) for k in ("tokens", "positions", "seg_ids")},
+        **{k: s((S,), i32) for k in ("seg_rows", "seg_starts", "seg_lens")},
+        **{
+            k: s((1, T), f32)
+            for k in ("packed_logprobs", "prox_logp", "advantages", "ppo_loss_mask")
+        },
+        "seq_lens": s((1,), i32), "prompt_mask": s((1, T), jnp.bool_),
+    }
+
+    def step(params, opt_state, batch):
+        def scalar(p):
+            loss_sum, denom, stats = ppo_interface._actor_loss(p, cfg, batch, iface)
+            return loss_sum, (denom, stats["moe_held_pairs_sum"])
+
+        (loss_sum, (denom, pairs)), grads = jax.value_and_grad(
+            scalar, has_aux=True
+        )(params)
+        grads = jax.tree.map(lambda g: g / jnp.maximum(denom, 1e-8), grads)
+        norms = hybrid.grad_norms_by_group(grads)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, (loss_sum, pairs, norms)
+
+    compiled = (
+        jax.jit(step, donate_argnums=(0, 1)).lower(params, opt_state, batch).compile()
+    )
+    m = compiled.memory_analysis()
+    n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
+    print(
+        f"laguna step [1, 16384]: {n / 1e6:.1f} M parameters, arguments "
+        f"{m.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+        f"{m.temp_size_in_bytes / 1e9:.2f} GB by memory_analysis"
+    )
+    assert abs(n - 691.6e6) < 0.1e6
+    text = compiled.as_text()
+    for name in (
+        "flash_attn_fwd", "flash_attn_bwd_dkv", "flash_attn_window_fwd",
+        "flash_attn_window_bwd_dq", "flash_attn_window_bwd_dkv",
+    ):
+        assert name in text, name
+
+
 @pytest.mark.parametrize("axes", [(1, 2, 1), (1, 1, 2)])
 def test_flash_attention_compiles_on_a_two_chip_trainer_mesh(topo, axes):
     """A Mosaic kernel cannot be partitioned automatically: on the
