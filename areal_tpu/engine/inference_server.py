@@ -95,6 +95,7 @@ from areal_tpu.observability.table import (
     admit_stop,
 )
 from areal_tpu.observability.tracing import PhaseClock, get_tracer
+from areal_tpu.ops import sparse_attention
 
 #: ``cache_mode="auto"``: dense rows below this ``kv_cache_len`` (short
 #: prefixes amortize no paging), the paged block pool at and above it
@@ -271,9 +272,11 @@ class _Row:
     #: layer's routed experts, one entry a position the model has READ:
     #: the prompt's from its fill, then each decode chunk's emitted steps
     routed: Optional[List[np.ndarray]] = None
-    #: ``keep_chosen_sets``: the positions every indexed layer attended at
-    #: the row's last decode steps, ``[L_indexed, K]`` a step (-1: none),
-    #: and at its prompt's last positions (``_fill_chosen_sets``)
+    #: ``keep_chosen_sets``: what every indexed layer attended at the
+    #: row's last decode steps, a step an entry ``(as the decode program
+    #: hands it out, the row's cached length when its chunk began)``
+    #: (``_decode_chosen_sets`` makes positions of them when asked), and
+    #: at its prompt's last positions (``_fill_chosen_sets``)
     chosen: Optional[collections.deque] = None
     chosen_fill: Optional[Tuple[np.ndarray, np.ndarray]] = None
     #: the row decodes and its FIRST token has not reached the host yet
@@ -766,7 +769,9 @@ class ContinuousBatchingEngine:
                 "(keep_routed_experts)"
             )
         self._keep_chosen = int(keep_chosen_sets)
-        self._chosen_done: Dict[str, Tuple[np.ndarray, np.ndarray]] = {}  # oldest first
+        #: qid -> (positions read, the fill's sets, the last decode steps
+        #: as the program handed them out), oldest first
+        self._chosen_done: Dict[str, Tuple[int, Any, list]] = {}
         #: positions the indexed layers' decode steps scored and, of
         #: those, attended (a layer each; the host's context at dispatch)
         self.index_positions_scored_total = 0
@@ -1026,6 +1031,13 @@ class ContinuousBatchingEngine:
         # into the areal_inference_spec_accept_rate histogram
         self._spec_accept_samples: Deque[float] = deque(maxlen=1024)
 
+        #: how a decode step under an indexer attends its chosen set, which
+        #: the program decides from the table's shape when it is traced
+        #: (``sparse_attention.decode_reads_masked``): "masked" in the
+        #: paged kernel, "gather" from the pool; None without an indexer.
+        #: For the log line and the step records' header only: what the
+        #: program hands out of a step says its form itself
+        self.sparse_decode_path: Optional[str] = None
         with jax.default_device(device) if device is not None else _nullctx():
             # ONE fixed base key for every sampling draw: draws are keyed
             # on (request seed, position) from it, so streams are
@@ -1163,6 +1175,8 @@ class ContinuousBatchingEngine:
             max_batch=self.max_batch, chunk_size=self.chunk_size,
             pipeline_depth=self.pipeline_depth,
         )
+        if self.sparse_decode_path:
+            self._phases.about["sparse_decode_path"] = self.sparse_decode_path
         #: why the last admission left the queue standing
         #: (``table.ADMIT_STOPS``)
         self._admit_stopped_by = admit_stop("queue_empty")
@@ -1266,6 +1280,12 @@ class ContinuousBatchingEngine:
         BS = page_size
         self.page_size = BS
         self.blocks_per_row = -(-self.kv_cache_len // BS)  # MB
+        if cfg.is_indexed:
+            self.sparse_decode_path = (
+                "masked" if sparse_attention.decode_reads_masked(
+                    self.blocks_per_row * BS, cfg.index_topk
+                ) else "gather"
+            )
         pool_tokens = kv_pool_tokens or max_batch * self.kv_cache_len
         self.prefill_chunk_tokens = prefill_chunk_tokens
         # TPU: the Pallas kernel (shard_mapped over the kv-head axis under
@@ -4120,13 +4140,13 @@ class ContinuousBatchingEngine:
         f.chosen_last = None
         masks = np.asarray(masks)[:, i]  # [L_indexed, keep, cached + C]
         n = min(masks.shape[1], take)
-        cached = self.blocks_per_row * self.page_size
+        table = self.blocks_per_row * self.page_size
         sets = np.full((n, masks.shape[0], self.cfg.index_topk), -1, np.int32)
         for layer, rows in enumerate(masks[:, masks.shape[1] - n :]):
             for q, row in enumerate(rows):
                 cols = np.flatnonzero(row)
-                sets[q, layer, : len(cols)] = np.where(
-                    cols < cached, cols, cols - cached + start
+                sets[q, layer, : len(cols)] = sparse_attention.row_positions(
+                    cols, table, start
                 )
         return np.arange(start + take - n, start + take, dtype=np.int32), sets
 
@@ -5117,14 +5137,13 @@ class ContinuousBatchingEngine:
             kept.pop(row.req.qid, None)
             while len(kept) >= self._keep_routed:
                 del kept[next(iter(kept))]
-            # step i of the last n read the token at position
-            # prompt + generated - 1 - n + i
-            n, read = len(row.chosen), len(row.prompt) + len(row.generated) - 1
-            at, sets = np.arange(read - n, read, dtype=np.int32), np.stack(row.chosen)
-            if row.chosen_fill is not None:
-                at = np.concatenate([row.chosen_fill[0], at])
-                sets = np.concatenate([row.chosen_fill[1], sets])
-            kept[row.req.qid] = (at, sets)
+            # as the row leaves them (:meth:`chosen_sets` makes positions
+            # of them for whoever asks): the last steps read the tokens up
+            # to position prompt + generated - 1
+            kept[row.req.qid] = (
+                len(row.prompt) + len(row.generated) - 1, row.chosen_fill,
+                list(row.chosen),
+            )
         if started and self.paged and row_id >= 0:
             # cached KV covers prompt + generated[:-1] (the final token is
             # the pending cur; its KV was never written).  Inserting on
@@ -5160,6 +5179,23 @@ class ContinuousBatchingEngine:
         if ev:
             ev.set()
 
+    def _decode_chosen_sets(self, kept) -> np.ndarray:
+        """``[n, L_indexed, K]`` int32 positions (-1: none) of a row's kept
+        decode steps, in :meth:`chosen_sets`' form.  A step left ``(what the
+        decode program handed out of it [L_indexed, .], the row's cached
+        length when its chunk began)``; ``sparse_attention.kept_positions``
+        reads either form the program hands out, by what it is."""
+        table = self.blocks_per_row * self.page_size
+        return np.stack([
+            np.stack([
+                sparse_attention.kept_positions(
+                    row, table, cached, self.cfg.index_topk
+                )
+                for row in step
+            ])
+            for step, cached in kept
+        ])
+
     def _keep_routing(self, qid: str, routing: np.ndarray):
         """A finished request's routing, the oldest out first.  The room
         is memory's: ``keep_routed_experts`` sequences as long as the
@@ -5182,7 +5218,16 @@ class ContinuousBatchingEngine:
         where no fill of this engine's handed them out) and then at its
         last ``keep_chosen_sets`` decode steps (which read the tokens up
         to the one before the last generated).  None unless kept."""
-        return self._chosen_done.get(qid)
+        kept = self._chosen_done.get(qid)
+        if kept is None:
+            return None
+        read, fill, steps = kept
+        # step i of the last n read the token at position read - n + i
+        at = np.arange(read - len(steps), read, dtype=np.int32)
+        sets = self._decode_chosen_sets(steps)
+        if fill is not None:
+            at, sets = np.concatenate([fill[0], at]), np.concatenate([fill[1], sets])
+        return at, sets
 
     def routed_experts(self, qid: str) -> Optional[np.ndarray]:
         """``[prompt + generated - 1, L, K]`` int16: every layer's routed
@@ -5381,6 +5426,18 @@ class ContinuousBatchingEngine:
             cols = emitted[row_id]
             toks = out_t[row_id][cols].tolist()
             lps = out_l[row_id][cols].tolist()
+            if len(fetched) > 7:
+                # [W, L_indexed, B, .] -> this row's emitted steps, the
+                # last ``keep_chosen_sets`` of its life
+                if row.chosen is None:
+                    row.chosen = collections.deque(maxlen=self._keep_chosen)
+                # (with the row's cached length now, where the chunk's
+                # first token stands: a step's mask counts its chunk's own
+                # tokens from there)
+                cached = len(row.prompt) + len(row.generated) - 1
+                row.chosen.extend(
+                    (step, cached) for step in fetched[7][cols, :, row_id]
+                )
             row.generated.extend(toks)
             row.logprobs.extend(lps)
             if len(fetched) > 6 and row.routed is not None:
@@ -5388,12 +5445,6 @@ class ContinuousBatchingEngine:
                 row.routed.append(
                     fetched[6][cols, :, :, row_id].astype(np.int16)
                 )
-            if len(fetched) > 7:
-                # [W, L_indexed, B, K] -> this row's emitted steps, the
-                # last ``keep_chosen_sets`` of its life
-                if row.chosen is None:
-                    row.chosen = collections.deque(maxlen=self._keep_chosen)
-                row.chosen.extend(fetched[7][cols, :, row_id])
             row.budget_left -= len(toks)
             n_tokens += len(toks)
             if toks and self._slo_enabled:

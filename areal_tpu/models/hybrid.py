@@ -130,8 +130,9 @@ The three are the same mathematics (``tests/model/test_latent.py``).
 **Under an indexer** (``cfg.is_indexed``: dots3_note's full layers) a query
 of a ``"latent"`` layer attends the ``cfg.index_topk`` positions of its
 context that ``sum_j w_j relu(q^I_j . k^I)`` scores highest, EXACTLY, and
-nothing else, in all three forms (``ops/sparse_attention.py``: a decode
-step reads the chosen entries alone, a fill applies the set as a mask);
+nothing else, in all three forms (``ops/sparse_attention.py``: a fill and
+a decode step over a short table apply the set as a mask in the paged
+kernel, a decode step over a long one gathers the chosen entries alone);
 each token's index key is cached beside its latent entry (the V side of
 the whole-context pool).  **A ``"latent_window"`` layer** is the same
 mixer at widths OF ITS OWN (``cfg.window_latent()``: heads, ranks, head
@@ -2497,7 +2498,13 @@ def hybrid_decode_chunk(
     experts at each step, for the position
     the step READ (row b's entry of step i means something where
     ``emitted[b, i]``; the row axis last, so that the array pads little
-    on the chip)."""
+    on the chip).  ``keep_chosen`` (a stack with an indexer): one output
+    more, before ``win_pools``: every indexed layer's chosen set at each
+    step, ``[W, L_indexed, B, .]``: where the steps attend under a mask
+    (``sparse_attention.decode_reads_masked`` of the table's shape) the
+    mask over the table's ``MB * BS`` positions and then the chunk's ``W``,
+    as ``sparse_attention.packed_mask`` words; where they gather, the
+    ``index_topk`` positions of the row (int32, -1: none)."""
     B = cur_tokens.shape[0]
     W = chunk_size
     _, _, Hkv, _, hd = k_pool.shape
@@ -2511,8 +2518,13 @@ def hybrid_decode_chunk(
     base_lens = lengths
     read_lens = jnp.where(active, base_lens, 0)
     scale = _attn_scale(cfg)
+    # how a step under an indexer attends its chosen set, from the table's
+    # shape: under a mask in the paged kernel, or gathered (a long table)
+    masked = indexed and sparse.decode_reads_masked(
+        tables.shape[1] * k_pool.shape[3], cfg.index_topk
+    )
     plan = paged._prefix_plan(
-        1, cfg.n_q_heads, k_pool, tables, read_lens, use_kernel
+        1, cfg.n_q_heads, k_pool, tables, read_lens, use_kernel, masked=masked
     )
     window = cfg.sliding_window if cfg.n_window_layers else None
     if window:
@@ -2537,11 +2549,15 @@ def hybrid_decode_chunk(
             k_pool.dtype,
         )
     if keep_chosen:
-        # every indexed layer's chosen positions at each step (-1: none)
+        # every indexed layer's chosen set at each step: the step's mask
+        # over the table's positions and then the chunk's own, 32 to a
+        # word (the host makes positions of the rows it keeps), or on the
+        # gathering path the positions themselves (-1: none)
         assert indexed, "keep_chosen: a stack with an indexer"
-        chosen = jnp.full(
-            (W, La, B, min(cfg.index_topk, tables.shape[1] * k_pool.shape[3] + W)),
-            -1, jnp.int32,
+        scored = tables.shape[1] * k_pool.shape[3] + W
+        chosen = (
+            jnp.zeros((W, La, B, -(-scored // 32)), jnp.uint32) if masked
+            else jnp.full((W, La, B, min(cfg.index_topk, scored)), -1, jnp.int32)
         )
     if cfg.n_cross_layers:
         # the shared layer's number in the chunk's own KV (the attention
@@ -2646,8 +2662,10 @@ def hybrid_decode_chunk(
         def indexed_mixer(h, wk, wv, chosen, j):
             """A latent layer under its indexer: scores over the row's
             index pages and the chunk's own keys, the exact choice, and
-            the absorbed products over the CHOSEN entries alone (the
-            cached ones gathered from the pool, not the context read)."""
+            the absorbed products over the CHOSEN entries alone: the
+            cached prefix attended under the choice's mask in the paged
+            kernel, or over a long table the chosen entries gathered from
+            the pool and not the context read (``masked``)."""
             ap = _at(params["latent"], j)
             c_q = latent_cq(cfg, ap, h)
             q, wk = latent_start(cfg, ap, h, rope_cs, wk, j, c_q)
@@ -2665,28 +2683,40 @@ def hybrid_decode_chunk(
                 )  # [B, 1, W]
             with region("areal.attn.select"):
                 cached = before.shape[-1]
-                idx, live = sparse.select(
-                    jnp.concatenate([before, own], axis=-1)[:, 0],
-                    cfg.index_topk,
-                )  # [B, K]
-                own_chosen = jnp.any(
-                    (idx[:, :, None] == cached + jnp.arange(W)) & live[:, :, None],
-                    axis=1,
-                )  # [B, W]
-                # kept as positions of the row: a step of the chunk stands
-                # at the cached length + its number
-                if keep_chosen:
+                scores = jnp.concatenate([before, own], axis=-1)[:, 0]
+                if masked:
+                    picked = sparse.chosen_mask(scores, cfg.index_topk)
+                    own_chosen = picked[:, cached:]  # [B, W]
+                    kept = sparse.packed_mask(picked) if keep_chosen else None
+                else:
+                    idx, live = sparse.select(scores, cfg.index_topk)  # [B, K]
+                    own_chosen = jnp.any(
+                        (idx[:, :, None] == cached + jnp.arange(W))
+                        & live[:, :, None],
+                        axis=1,
+                    )
+                    # kept as positions of the row: a step of the chunk
+                    # stands at the cached length + its number
                     at = jnp.where(
                         idx < cached, idx, idx - cached + base_lens[:, None]
                     )
+                    kept = jnp.where(live, at, -1)
+                if keep_chosen:
                     chosen = jax.lax.dynamic_update_slice(
-                        chosen, jnp.where(live, at, -1)[None, None], (i, j, 0, 0)
+                        chosen, kept[None, None], (i, j, 0, 0)
                     )
             with region("areal.attn.sparse"):
-                prefix = sparse.sparse_latent_partials(
-                    q, k_pool, j, tables, idx, live & (idx < cached),
-                    cfg.kv_lora_rank, scale,
-                )
+                if masked:
+                    prefix = paged._prefix_partials(
+                        q, k_pool, None, tables, read_lens, j, use_kernel,
+                        plan=plan, scale=scale, value_dim=cfg.kv_lora_rank,
+                        mask=picked[:, None, :cached],
+                    )
+                else:
+                    prefix = sparse.sparse_latent_partials(
+                        q, k_pool, j, tables, idx, live & (idx < cached),
+                        cfg.kv_lora_rank, scale,
+                    )
                 out = latent_finish(
                     cfg, ap, h, q, wk, j, prefix,
                     mask_win & own_chosen[:, None, None, None, :],

@@ -94,8 +94,9 @@ would have had to copy it.
 
 **A selection** (``mask`` ``[B, Q, MB * BS]``): query ``t`` of row ``b``
 attends cached position ``s`` iff ``mask[b, t, s]`` (and ``s < length``):
-an indexed latent layer's fill, whose indexer chose ``index_topk``
-positions a query (``ops/sparse_attention.chosen_mask``).  One more
+an indexed latent layer's fill, and its decode step over a table short
+enough (``ops/sparse_attention.decode_reads_masked``), whose indexer chose
+``index_topk`` positions a query (``ops/sparse_attention.chosen_mask``).  One more
 operand, one row a query TOKEN: it is laid out ``[B, QB, pages, QT, BS]``
 in 32-bit words (:func:`_group_selection`), a grid step's block is its G
 pages of the row's query tile, ``(1, 1, G, QT, BS)``, through the pipeline
@@ -106,7 +107,13 @@ over the ``r`` query heads of each token and joins the length mask in
 tile, ``[QT * r, BS]`` float32, never leave VMEM; the XLA page loop this
 replaced wrote them out and read them back several times a page.  A
 static branch on the operand's presence: a call without it is the program
-it was.  The Mosaic call is named ``paged_mla_masked_fill``.
+it was.  The Mosaic call is named ``paged_mla_masked_fill``; at ONE query
+a row ``paged_mla_masked_decode``: the block is one token's, ``(1, 1, G,
+1, BS)``, the grid the live rows' (the selection is addressed through the
+visiting order, as ``q`` is), and every cached position of a row is
+multiplied whatever the selection keeps: 4.4-4.5 us a 1,024 positions and
+live row at 128 heads on a v5e (PERF.md PR 54), which beats a gather of the
+chosen rows while the table is short.
 """
 
 from __future__ import annotations

@@ -3,10 +3,11 @@ scores over a row's pages, the exact choice of the ``k`` largest, and
 latent attention over the chosen entries alone (DeepSeek-V3.2-Exp's
 sparse attention, as ``dots3_note``'s full layers state it).
 
-Three steps a full layer.  The first two are XLA in every program and so
-is a decode step's third; a FILL's third is the paged kernel's
-(``ops/paged_attention.py`` under a selection), and this module has no
-Mosaic call of its own:
+Three steps a full layer.  The first two are XLA in every program; the
+third is the paged kernel's (``ops/paged_attention.py`` under a selection)
+in a fill and in a decode step over a table of up to
+:data:`MASKED_DECODE_MAX_RATIO` times ``k`` positions, XLA's in a decode
+step over a longer one, and this module has no Mosaic call of its own:
 
 * :func:`paged_index_scores`: ``I(t, s) = sum_j w_j(t) relu(q_j(t) .
   k(s))`` of every cached position ``s`` of a row, from the pool of index
@@ -18,20 +19,27 @@ Mosaic call of its own:
   and step at the cell's sizes); a Mosaic kernel that scored them page by
   page where they lie was written and taken out again before it was
   served (PR 49: PERF.md section 7 has its design and why).
-* :func:`select` (XLA): ``lax.top_k`` over the scores, EXACT (no
-  ``approx_max_k``); fewer valid positions than ``k`` choose them all.
-  :func:`chosen_mask` states the same set as a mask over the scores for a
-  fill, which applies it to the attention it computes anyway (ties at the
-  ``k``-th score fall as ``top_k`` lets them: the lower position first).
-* a decode step READS THE CHOSEN ENTRIES and not the context
-  (:func:`sparse_latent_partials`, XLA: a gather of ``k`` rows a sequence
-  from the pool as it lies, then the absorbed products over them); a fill
-  chunk attends its paged prefix under :func:`chosen_mask`'s mask in the
+* the choice, EXACT (no ``approx_max_k``), in one of two forms:
+  :func:`chosen_mask` states the set as a mask over the scores (the
+  ``k``-th largest found bit by bit, no sort; ties at it fall as ``top_k``
+  lets them: the lower position first) for a path that attends under a
+  mask; :func:`select` (``lax.top_k``: two sorts) states it as positions
+  for the path that gathers.  Fewer valid positions than ``k`` choose them
+  all.
+* the attention over the chosen entries, ONE algorithm in the form that is
+  cheaper for how much of the table the set is (:func:`decode_reads_masked`,
+  from the table's shape when a program is traced): UNDER THE MASK in the
   paged kernel's latent mode, the mask one more operand
-  (``paged_flash_attention(mask=)``, Mosaic ``paged_mla_masked_fill``: a
-  page's scores of a query tile stay in VMEM; the XLA page loop that stood
-  here wrote them out: 58 ms a layer and chunk in the cell's traced slice
-  where the kernel takes 33, PERF.md PR 50).  Both return the
+  (``paged_flash_attention(mask=)``: every cached position of the row is
+  multiplied and the chosen ones kept; a page's scores of a query tile stay
+  in VMEM).  That is every fill chunk (Mosaic ``paged_mla_masked_fill``;
+  the XLA page loop that stood here took 58 ms a layer and chunk where the
+  kernel takes 33, PERF.md PR 50) and, since PR 54, a decode step whose
+  table is short (``paged_mla_masked_decode``: the cell's 18,432 = 9 x
+  ``k``).  Over a LONG table a decode step READS THE CHOSEN ENTRIES and not
+  the context (:func:`sparse_latent_partials`, XLA: a gather of ``k`` rows
+  a sequence from the pool as it lies, then the absorbed products over
+  them: a cost that does not grow with the context).  Both return the
   un-normalised ``(acc, m, l)`` that ``paged.window_attention`` /
   ``chunk_attention`` merge with the chunk's own tokens.
 """
@@ -42,10 +50,41 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG = -1e30
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
+
+#: a decode step attends its chosen set UNDER A MASK in the paged kernel
+#: (every cached position of the row multiplied) while its table holds at
+#: most this many times ``index_topk`` positions, and GATHERS the chosen
+#: rows beyond.  Both paths alone on a v5e at the sparse cell's widths (128
+#: heads, pages of 512 x 640, ``index_topk`` 2,048, 64 slots, rows filled
+#: to 70% of the table; ``scripts/sparse_decode_paths.py``, my chip run,
+#: PR 54), milliseconds a layer and step, mask + kernel | sorts + gather:
+#:
+#:   table    ratio   49 live rows                  64 live rows
+#:   18,432     9     0.40 + 2.82 | 1.23 + 4.38     0.38 + 3.66 | 1.23 + 4.15
+#:   36,864    18     0.40 + 5.43 | 2.67 + 4.29     0.41 + 7.06 | 2.69 + 4.07
+#:   55,296    27     0.41 + 8.04 | 3.57 + 4.29     0.39 + 10.47 | 3.59 + 4.07
+#:
+#: The masked path is 0.6 ms + 0.29 (49 rows) or 0.38 (64) a unit of the
+#: ratio (4.4-4.5 us a 1,024 cached positions and live row), the gathering
+#: one 4.3-4.6 ms + 0.125 whatever the rows (the gather is the same
+#: ``index_topk`` rows of every slot; the sorts grow with the table): they
+#: cross at 23.9 with 49 rows live and at 14.6 with all 64.  A program
+#: knows its slots and not how many will be live, so the constant sits by
+#: the full engine's crossing: at 16 the masked path is 5% behind with
+#: every slot live and 20% ahead with three quarters.
+MASKED_DECODE_MAX_RATIO = 16
+
+
+def decode_reads_masked(table_positions: int, k: int) -> bool:
+    """Whether a decode step over tables of ``table_positions`` (``MB *
+    BS``, static) attends its ``k`` chosen positions under a mask in the
+    paged kernel, or gathers them (module docstring)."""
+    return table_positions <= MASKED_DECODE_MAX_RATIO * k
 
 
 def index_scores(q, w, keys):
@@ -149,6 +188,51 @@ def chosen_mask(scores: jax.Array, k: int) -> jax.Array:
     # ``need``-th tied position itself
     last = jax.lax.fori_loop(0, bits, position_bit, jnp.zeros(lead, jnp.int32))
     return (above | (tied & (pos <= last[..., None]))) & valid
+
+
+def packed_mask(mask: jax.Array) -> jax.Array:
+    """``mask`` [.., N] bool as ``[.., ceil(N / 32)]`` uint32, position
+    ``s`` bit ``s % 32`` of word ``s // 32``: what a decode step hands the
+    host of its selection (:func:`positions_of_packed` reads it)."""
+    N = mask.shape[-1]
+    words = -(-N // 32)
+    bits = jnp.pad(mask, [(0, 0)] * (mask.ndim - 1) + [(0, words * 32 - N)])
+    bits = bits.reshape(mask.shape[:-1] + (words, 32)).astype(jnp.uint32)
+    return jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=-1, dtype=jnp.uint32)
+
+
+def positions_of_packed(words: np.ndarray) -> np.ndarray:
+    """The set positions of ONE :func:`packed_mask` row, ascending (on the
+    host)."""
+    bits = np.unpackbits(
+        np.ascontiguousarray(words, "<u4").view(np.uint8), bitorder="little"
+    )
+    return np.flatnonzero(bits).astype(np.int32)
+
+
+def row_positions(cols: np.ndarray, table: int, own: int) -> np.ndarray:
+    """Columns of a mask over ``[the table's positions | a chunk's own
+    tokens]`` as positions of the row: the chunk's first token stands at
+    position ``own`` (on the host)."""
+    return np.where(cols < table, cols, cols - table + own)
+
+
+def kept_positions(kept: np.ndarray, table: int, own: int, k: int) -> np.ndarray:
+    """ONE decode step's chosen set of one layer as positions of the row,
+    int32 (-1: none), from what the decode program handed out
+    (``hybrid_decode_chunk(keep_chosen=True)``), whichever it was: uint32
+    is a step that attended under its mask (:func:`packed_mask` words over
+    the table's ``table`` positions and then its chunk's own tokens, the
+    first at position ``own``: ``[k]`` comes back); int32 is a step that
+    gathered, the positions themselves.  The one place that knows both
+    forms (on the host)."""
+    if kept.dtype != np.uint32:
+        assert kept.dtype == np.int32, kept.dtype
+        return kept
+    cols = positions_of_packed(kept)
+    out = np.full(k, -1, np.int32)
+    out[: len(cols)] = row_positions(cols, table, own)
+    return out
 
 
 def _flat_entries(pool, layer, tables, positions):

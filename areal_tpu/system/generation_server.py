@@ -353,6 +353,12 @@ class GenerationServerWorker(worker_base.Worker):
             keep_routed_experts=getattr(config, "keep_routed_experts", 0),
             keep_chosen_sets=getattr(config, "keep_chosen_sets", 0),
         )
+        if self.engine.sparse_decode_path:
+            # (static: the decode program's path follows the table's shape)
+            self.logger.info(
+                "indexed layers at decode: sparse_decode_path=%s",
+                self.engine.sparse_decode_path,
+            )
 
         self._ctx = zmq.Context.instance()
         self._sock = None
@@ -1757,6 +1763,11 @@ class GenerationServerWorker(worker_base.Worker):
                 eng.first_tokens_blocking_total,
                 slo["ttft_s"], slo["tpot_s"],
             )
+            if eng.sparse_decode_path:
+                self.logger.info(
+                    "indexed layers at decode: sparse_decode_path=%s",
+                    eng.sparse_decode_path,
+                )
             if eng.moe_fill_tokens_total:
                 self.logger.info(
                     "expert layers at fill: moe_fill_tokens=%d, "

@@ -258,6 +258,28 @@ def test_what_the_index_pool_refuses_is_refused_by_name(model, feature, option):
         assert "index pool" in str(err.value)
 
 
+def test_the_decode_path_follows_the_tables_ratio_to_the_chosen_set(model, eng):
+    """The engines above hold 96 positions a row, 16 x ``index_topk``: their
+    decode steps attend under the mask (every test above), and say so in
+    the step records' header.  One of 104 gathers, its kept sets the
+    positions the program hands out, and its completions the reference's
+    as well."""
+    cfg, params = model
+    assert eng.sparse_decode_path == "masked"
+    assert eng._phases.header()["sparse_decode_path"] == "masked"
+    longer = ContinuousBatchingEngine(
+        cfg, params, max_batch=2, kv_cache_len=104, chunk_size=CHUNK,
+        sampling=SamplingParams(temperature=1.0), cache_mode="paged",
+        page_size=BS, prefill_chunk_tokens=8, keep_routed_experts=4,
+        keep_chosen_sets=5,
+    )
+    assert longer.sparse_decode_path == "gather"
+    assert longer._phases.header()["sparse_decode_path"] == "gather"
+    longer.submit(_req("g", _prompts(5, 21)[0], 9))
+    run_until_done(longer)
+    assert_reference(params, longer.drain_results(), longer)
+
+
 def test_keeping_chosen_sets_needs_an_indexer_and_the_kept_routing(model):
     cfg, params = model
     with pytest.raises(ValueError):

@@ -189,61 +189,106 @@ def test_the_mask_form_is_top_k_with_its_ties():
 
 
 # (d) fill in chunks, then decode, through both pools and the index pool
-@pytest.mark.parametrize("use_kernel", [False, True])
-@pytest.mark.parametrize("piece", [5, 13])
-def test_fill_in_chunks_then_decode_through_pages_is_the_reference(
-    model, use_kernel, piece
-):
-    cfg, params = model
-    S, BS, MB, slot = 4, 8, 8, 2
+S, BS, SLOT = 4, 8, 2
+PAGES, WIN_PAGES = [3, 5, 7, 9], [2, 4, 6, 8]
+
+
+def _fill_prompt(cfg, params, prompt, piece, use_kernel):
+    """The prompt prefilled ``piece`` tokens at a time into dirty pools
+    (row 0 of a fill batch of two, for state slot ``SLOT``): ``(the last
+    chunk's logits, k_pool, v_pool, ssm, conv, win pools)``."""
     k_pool, v_pool = paged.pool_zeros(cfg, 16, BS)
     win = paged.pool_zeros(cfg, 12, BS, layers=3)
     ssm, conv = hybrid.state_zeros(cfg, S)
     k_pool, v_pool = k_pool + 3.0, v_pool - 2.0  # dirty pages
     win = (win[0] + 1.5, win[1])
+    tables, wtables = np.zeros((2, 8), np.int32), np.zeros((2, 8), np.int32)
+    tables[0, :4], wtables[0, :4] = PAGES, WIN_PAGES
+    pos = 0
+    while pos < len(prompt):
+        take = min(piece, len(prompt) - pos)
+        toks = np.zeros((2, 16), np.int32)
+        toks[0, :take] = prompt[pos : pos + take]
+        (logits, k_pool, v_pool, ssm, conv, pairs, r, _,
+         win) = hybrid.hybrid_fill_chunk(
+            params, k_pool, v_pool, ssm, conv, cfg, jnp.asarray(toks),
+            jnp.asarray([pos, 0], jnp.int32), jnp.asarray([take, 0], jnp.int32),
+            jnp.asarray(tables), jnp.asarray([SLOT, 0], jnp.int32),
+            use_kernel=use_kernel, win_pools=win,
+            win_tables=jnp.asarray(wtables),
+        )
+        assert int(pairs[:-1].sum()) == take * 3 * 4 and len(win) == 2
+        pos += take
+    return logits, k_pool, v_pool, ssm, conv, win
+
+
+def _decode_chunks(cfg, params, state, first, n_cached, use_kernel, MB, chunks=3):
+    """``chunks`` decode chunks of 4 steps of the row in ``SLOT`` over
+    tables of ``MB`` pages: ``(tokens, log-probabilities, kept sets [steps,
+    full layers, 6] as POSITIONS of the row, the pools)``.  The kept sets
+    come as the path hands them out: masks over the table and then the
+    chunk's own tokens, 32 to a word, where the steps attend under a mask
+    (``sparse.decode_reads_masked``: a table of up to 16 x 6 = 96
+    positions), positions where they gather."""
+    k_pool, v_pool, ssm, conv, win = state
+    full, wfull = np.zeros((S, MB), np.int32), np.zeros((S, MB), np.int32)
+    full[SLOT, :4], wfull[SLOT, :4] = PAGES, WIN_PAGES
+    onehot = np.arange(S) == SLOT
+    lens = jnp.asarray(np.where(onehot, n_cached, 0), jnp.int32)
+    cur = jnp.asarray(np.where(onehot, first, 0), jnp.int32)
+    act = jnp.asarray(onehot)
+    bud = jnp.asarray(np.where(onehot, 9, 0), jnp.int32)
+    masked = sparse.decode_reads_masked(MB * BS, cfg.index_topk)
+    toks, lps, sets = [], [], []
+    for _ in range(chunks):
+        cached = int(lens[SLOT])
+        (k_pool, v_pool, ssm, conv, lens, out_t, out_l, em, cur, act, bud,
+         _, pairs, r, chosen, win) = hybrid.hybrid_decode_chunk(
+            params, k_pool, v_pool, ssm, conv, cfg, jnp.asarray(full), lens,
+            cur, act, bud, jax.random.PRNGKey(0), 4, _greedy, _never_stop,
+            use_kernel=use_kernel, max_len=64, win_pools=win,
+            win_tables=jnp.asarray(wfull), keep_chosen=True,
+        )
+        e = np.asarray(em[SLOT])
+        toks += list(np.asarray(out_t[SLOT])[e])
+        lps += list(np.asarray(out_l[SLOT])[e])
+        kept = np.asarray(chosen)[e][:, :, SLOT]
+        assert kept.dtype == (np.uint32 if masked else np.int32)
+        if masked:
+            assert kept.shape[-1] == -(-(MB * BS + 4) // 32)
+        # either form, read by what it is (as the engine reads them)
+        sets += [
+            np.stack([
+                sparse.kept_positions(row, MB * BS, cached, cfg.index_topk)
+                for row in step
+            ])
+            for step in kept
+        ]
+    return toks, lps, np.stack(sets), (k_pool, v_pool, win)
+
+
+#: tables of 8 pages of 8 (64 positions: under the mask) and of 13 (104:
+#: past 16 x the 6 chosen, gathered)
+@pytest.mark.parametrize(
+    "piece,use_kernel,MB",
+    [(5, False, 8), (5, True, 8), (13, False, 8), (13, True, 8),
+     (13, False, 13), (13, True, 13)],
+)
+def test_fill_in_chunks_then_decode_through_pages_is_the_reference(
+    model, use_kernel, piece, MB
+):
+    cfg, params = model
     prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (13,), 3, 64))
-    tables, wtables = np.zeros((2, MB), np.int32), np.zeros((2, MB), np.int32)
-    tables[0, :4], wtables[0, :4] = [3, 5, 7, 9], [2, 4, 6, 8]
     with jax.default_matmul_precision("highest"):
-        pos = 0
-        while pos < len(prompt):
-            take = min(piece, len(prompt) - pos)
-            toks = np.zeros((2, 16), np.int32)
-            toks[0, :take] = prompt[pos : pos + take]
-            (logits, k_pool, v_pool, ssm, conv, pairs, r, _,
-             win) = hybrid.hybrid_fill_chunk(
-                params, k_pool, v_pool, ssm, conv, cfg, jnp.asarray(toks),
-                jnp.asarray([pos, 0], jnp.int32), jnp.asarray([take, 0], jnp.int32),
-                jnp.asarray(tables), jnp.asarray([slot, 0], jnp.int32),
-                use_kernel=use_kernel, win_pools=win,
-                win_tables=jnp.asarray(wtables),
-            )
-            assert int(pairs[:-1].sum()) == take * 3 * 4 and len(win) == 2
-            pos += take
+        logits, *state = _fill_prompt(cfg, params, prompt, piece, use_kernel)
         want_logits = ref.forward_logits(HF, params, prompt)
         assert np.abs(np.asarray(logits[0]) - np.asarray(want_logits[-1])).max() < 2e-5
         lp0 = jax.nn.log_softmax(logits[0])
         first = int(jnp.argmax(lp0))
-        full, wfull = np.zeros((S, MB), np.int32), np.zeros((S, MB), np.int32)
-        full[slot, :4], wfull[slot, :4] = [3, 5, 7, 9], [2, 4, 6, 8]
-        onehot = np.arange(S) == slot
-        lens = jnp.asarray(np.where(onehot, 13, 0), jnp.int32)
-        cur = jnp.asarray(np.where(onehot, first, 0), jnp.int32)
-        act = jnp.asarray(onehot)
-        bud = jnp.asarray(np.where(onehot, 9, 0), jnp.int32)
-        seq, lps, sets = list(prompt) + [first], [float(lp0[first])], []
-        for _ in range(3):
-            (k_pool, v_pool, ssm, conv, lens, out_t, out_l, em, cur, act, bud,
-             _, pairs, r, chosen, win) = hybrid.hybrid_decode_chunk(
-                params, k_pool, v_pool, ssm, conv, cfg, jnp.asarray(full), lens,
-                cur, act, bud, jax.random.PRNGKey(0), 4, _greedy, _never_stop,
-                use_kernel=use_kernel, max_len=64, win_pools=win,
-                win_tables=jnp.asarray(wfull), keep_chosen=True,
-            )
-            e = np.asarray(em[slot])
-            seq += list(np.asarray(out_t[slot])[e])
-            lps += list(np.asarray(out_l[slot])[e])
-            sets.append(np.asarray(chosen)[e][:, :, slot])
+        toks, lps, sets, (k_pool, v_pool, win) = _decode_chunks(
+            cfg, params, state, first, 13, use_kernel, MB
+        )
+    seq, lps = list(prompt) + [first] + toks, [float(lp0[first])] + lps
     assert len(seq) == 13 + 10
     # pages of other rows were never touched, in any of the three pools
     assert float(jnp.abs(k_pool[:, [0, 1, 2, 4, 6, 8]] - 3.0).max()) == 0.0
@@ -258,12 +303,52 @@ def test_fill_in_chunks_then_decode_through_pages_is_the_reference(
     assert np.abs(np.asarray(lps) - want[12:]).max() < 2e-5
     # the chosen sets are the reference's: 6 positions a step and layer,
     # none after the query's own, and nothing else was attended
-    sets = np.concatenate(sets)  # [steps, full layers, K]
     assert sets.shape == (9, 2, 6) and scores.shape == (2, 9, 23)
     for i, t in enumerate(at):
         for f in range(2):
             row = ref.selection_agreement(scores[f, i], sets[i, f], int(t), 6, 0.0)
             assert row["agree"] == 1.0 and row["within"], (t, f, row)
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("scores", ["drawn", "all_tied"])
+def test_a_decode_chunk_under_the_mask_is_the_gathering_chunk_step_for_step(
+    model, use_kernel, scores
+):
+    """The two forms of ONE algorithm, picked by the table's shape: the
+    same pools under tables of 8 pages (64 positions, under 16 x 6: the
+    steps attend under the mask, in the paged kernel) and of 13 (104: they
+    gather): the same tokens, the same log-probabilities, the same kept
+    sets as positions, ``top_k``'s, where every index score is the same
+    too (the heads' weights zeroed: the LOWEST positions are chosen)."""
+    cfg, params = model
+    if scores == "all_tied":
+        lat = dict(params["latent"])
+        lat["index_w"] = jax.tree.map(jnp.zeros_like, lat["index_w"])
+        params = dict(params, latent=lat)
+    prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (13,), 3, 64))
+    assert sparse.decode_reads_masked(8 * BS, cfg.index_topk)
+    assert not sparse.decode_reads_masked(13 * BS, cfg.index_topk)
+    with jax.default_matmul_precision("highest"):
+        logits, *state = _fill_prompt(cfg, params, prompt, 13, use_kernel)
+        first = int(jnp.argmax(logits[0]))
+        # (a decode chunk is given its pools and state to keep: a copy each)
+        masked, gather = (
+            _decode_chunks(
+                cfg, params, jax.tree.map(jnp.copy, state), first, 13,
+                use_kernel, MB, 2,
+            )
+            for MB in (8, 13)
+        )
+    assert len(masked[0]) == 8 and masked[0] == gather[0]
+    assert np.abs(np.asarray(masked[1]) - np.asarray(gather[1])).max() < 2e-5
+    assert masked[2].shape == gather[2].shape == (8, 2, 6)
+    assert (np.sort(masked[2], axis=-1) == np.sort(gather[2], axis=-1)).all()
+    assert (masked[2] >= 0).all()
+    if scores == "all_tied":
+        assert (masked[2] == np.arange(6)).all()
+    for a, b in zip(masked[3][:2], gather[3][:2]):
+        assert float(jnp.abs(a - b).max()) < 2e-5
 
 
 # (e) the sixteen shares add up to the uncut layer (router without groups)

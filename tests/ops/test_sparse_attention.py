@@ -2,7 +2,7 @@
 the scores of the same keys at hand, the exact choice against
 ``lax.top_k``, the gather of chosen entries against dense masked
 attention; the paged kernel's latent mode UNDER A SELECTION (a fill's
-masked prefix) and UNDER THE WINDOW (the two static branches together)
+masked prefix and a decode step's) and UNDER THE WINDOW (the two static branches together)
 against their reference, each once more with every byte it must not read
 NaN.  Tolerances: 2e-5 at float32
 (sums in another order); the choice is compared as a SET, exactly."""
@@ -173,6 +173,147 @@ def test_a_fill_attends_its_prefix_under_the_mask_in_the_paged_kernel(case):
         )
         for g, w in zip(got, bare):
             assert (np.asarray(g[:, 1:]) == np.asarray(w)).all()
+
+
+#: a decode step's masked prefix, by case: (lengths a slot, the selection)
+MASKED_DECODES = {
+    "dead_rows_in_between": ([0, 19, 0, 0, 45, 8, 0, 33], "random"),
+    "shorter_than_k": ([21, 8, 0, 3], "all"),  # the mask is the length mask
+    "nothing_in_a_page": ([48, 0, 33, 17], "page_1_bare"),
+    "siblings": ([29, 29, 0, 29, 11], "random"),  # rows 0, 1, 3 share pages
+    "every_row_dead": ([0, 0, 0], "random"),
+    "page_boundaries": ([7, 8, 9, 15, 16, 17, 47, 48], "random"),
+}
+
+
+@pytest.mark.parametrize("case", list(MASKED_DECODES))
+def test_a_decode_step_attends_its_prefix_under_the_mask_in_the_paged_kernel(case):
+    """``paged_flash_attention(mask=)`` at ONE query a row (Mosaic
+    ``paged_mla_masked_decode``): the selection's block is one token's, the
+    grid holds the live rows only and addresses the selection through the
+    visiting order as it does ``q``; a stacked pool with a TRACED layer;
+    against ``reference_paged_partials(mask=)`` and dense masked attention."""
+    lens, kind = MASKED_DECODES[case]
+    B, H, width, vd, L, NB, BS, MB = len(lens), 4, 128, 96, 2, 64, 8, 6
+    ks = jax.random.split(jax.random.PRNGKey(B), 3)
+    pool = _pool(ks[0], L, NB, BS, width)
+    q = jax.random.normal(ks[1], (B, 1, H, width))
+    tables, lengths = _tables(B, MB, NB, 6), jnp.asarray(lens, jnp.int32)
+    if case == "siblings":
+        tables = tables.at[1].set(tables[0]).at[3].set(tables[0])
+    pos = jnp.arange(MB * BS)
+    held = pos < lengths[:, None, None]
+    mask = jax.random.bernoulli(ks[2], 0.4, (B, 1, MB * BS))
+    if kind == "page_1_bare":
+        mask &= pos // BS != 1
+    elif kind == "all":
+        mask |= True
+    chose_nothing = int(np.argmax(lens))
+    mask = mask.at[chose_nothing].set(False)  # a LIVE row that chose nothing cached
+
+    @jax.jit
+    def step(layer, mask):
+        return pa.paged_flash_attention(
+            q, pool, None, tables, lengths, layer=layer, interpret=True,
+            scale=0.3, value_dim=vd, mask=mask,
+        )
+
+    got = step(jnp.int32(1), mask)
+    want = pa.reference_paged_partials(
+        q, pool[1], None, tables, lengths, scale=0.3, value_dim=vd, mask=mask
+    )
+    dense = pool[1][tables, 0].reshape(B, MB * BS, width)
+    plain = _dense_partials(q, dense, mask & held, vd, 0.3)
+    assert got[0].shape == (B, 1, H, vd)
+    live = np.asarray(plain[2]) > 0
+    assert (np.asarray(got[2]) > 0).tolist() == live.tolist()
+    assert live.any(axis=(1, 2)).tolist() == [
+        n > 0 and b != chose_nothing for b, n in enumerate(lens)
+    ]
+    for other in (want, plain):
+        a, b = np.asarray(_normalised(*got)), np.asarray(_normalised(*other))
+        assert np.abs(a - b)[live].max(initial=0.0) < 5e-5
+        mass = [np.asarray(x[2] * jnp.exp(x[1]))[live] for x in (got, other)]
+        assert np.abs(mass[0] / mass[1] - 1).max(initial=0.0) < 5e-5
+    # a row without pages, and one that chose nothing: acc 0, l 0
+    for b in np.flatnonzero(~live.any(axis=(1, 2))):
+        assert float(jnp.abs(got[0][b]).max()) == 0.0 == float(got[2][b].max())
+    if kind == "all":  # the call without the operand, to the bit
+        bare = pa.paged_flash_attention(
+            q, pool, None, tables, lengths, layer=jnp.int32(1),
+            interpret=True, scale=0.3, value_dim=vd,
+        )
+        rest = np.arange(B) != chose_nothing
+        for g, w in zip(got, bare):
+            assert (np.asarray(g)[rest] == np.asarray(w)[rest]).all()
+    # the other layer's pages are another answer: the layer is read
+    if max(lens):
+        again = step(jnp.int32(0), mask)
+        assert float(jnp.abs(again[0] - got[0]).max()) > 1e-3
+
+
+@pytest.mark.parametrize("N", [1, 31, 32, 33, 100])
+def test_a_mask_packed_32_positions_to_a_word_reads_back_as_its_positions(N):
+    mask = jax.random.bernoulli(jax.random.PRNGKey(N), 0.3, (3, 2, N))
+    words = np.asarray(sa.packed_mask(mask))
+    assert words.shape == (3, 2, -(-N // 32)) and words.dtype == np.uint32
+    for b in range(3):
+        for r in range(2):
+            got = sa.positions_of_packed(words[b, r])
+            assert got.dtype == np.int32
+            assert got.tolist() == np.flatnonzero(np.asarray(mask[b, r])).tolist()
+
+
+@pytest.mark.parametrize("form", ["packed", "positions"])
+def test_a_kept_step_reads_as_positions_of_the_row_by_its_own_form(form):
+    """What a decode program hands out of a step says its form itself:
+    packed words are a mask over the table's 40 positions and then the
+    chunk's own tokens (the first at the row's cached length, 23 here);
+    int32 are the positions already."""
+    if form == "positions":
+        kept = np.asarray([3, 24, 7, -1, -1], np.int32)
+        assert sa.kept_positions(kept, 40, 23, 5) is kept
+        return
+    mask = np.zeros(44, bool)
+    mask[[3, 7, 22, 41, 43]] = True  # 41, 43: the chunk's second and fourth
+    words = np.asarray(sa.packed_mask(jnp.asarray(mask)))
+    got = sa.kept_positions(words, 40, 23, 6)
+    assert got.dtype == np.int32 and got.tolist() == [3, 7, 22, 24, 26, -1]
+    assert sa.row_positions(np.asarray([0, 39, 40]), 40, 23).tolist() == [0, 39, 23]
+    with pytest.raises(AssertionError):  # neither form: refused, not read
+        sa.kept_positions(words.astype(np.int64), 40, 23, 6)
+
+
+def test_the_decode_path_follows_the_tables_ratio_to_the_chosen_set():
+    k, r = 2048, sa.MASKED_DECODE_MAX_RATIO
+    assert sa.decode_reads_masked(18432, k)  # the sparse cell's table: 9 x
+    assert sa.decode_reads_masked(r * k, k) and not sa.decode_reads_masked(r * k + 512, k)
+    assert not sa.decode_reads_masked(131072, k)  # a 128k table gathers
+
+
+def test_the_timing_scripts_crossing_is_where_its_two_lines_meet():
+    """``scripts/sparse_decode_paths.py`` (the constant's source, a chip
+    run): the ratio at which the line through the masked path's times
+    meets the gathering path's, and None where the masked path's does not
+    rise faster."""
+    import importlib.util
+    import os
+
+    path = os.path.join(
+        os.path.dirname(__file__), "..", "..", "scripts", "sparse_decode_paths.py"
+    )
+    spec = importlib.util.spec_from_file_location("sparse_decode_paths", path)
+    script = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(script)
+    rows = [
+        {"ratio": r, "gathered_ms": 4.5 + 0.125 * r, "masked_ms": 0.5 + 0.375 * r}
+        for r in (9.0, 18.0, 27.0)
+    ]
+    fit = script.crossing(rows)
+    assert abs(fit["crossing_ratio"] - 16.0) < 1e-6
+    assert abs(fit["masked_ms"]["per_ratio"] - 0.375) < 1e-9
+    flat = [dict(r, masked_ms=1.0) for r in rows]
+    assert script.crossing(flat)["crossing_ratio"] is None
 
 
 def test_a_selection_rides_beside_key_and_value_pools_too():

@@ -218,32 +218,63 @@ DECODE_MODES = {
 }
 
 
-@pytest.mark.parametrize("name", list(DECODE_MODES))
+#: scripts/lowered_programs.digest of each of them as the parent of PR 54
+#: lowered it (commit e4a7bca; the same function run in a copy of that
+#: tree): a decode call that brings no selection is the program it was
+DECODE_CALLS = {
+    "paged_attn_decode": "db68fac14af563c6d3d2e7dcc05039e22914b12d",
+    "paged_window_decode": "79b2c004392c01b487b11ea7ad6e451265231c97",
+    "paged_mla_decode": "ef295906c06cacd879d61d448daa64bbc7d03328",
+    "paged_mla_window_decode": "22127f336e711ee98714270c279b1c18a0408f18",
+}
+
+#: the latent mode UNDER A SELECTION at the sparse cell's shapes (128 heads
+#: on the stream, 36 pages of 512 x 640 a row: width 576 in 640, values the
+#: first 512): (q heads, pool, pages a row, keywords)
+MASKED_LATENT = (
+    128, (2, 896, 1, 512, 640), 36, dict(scale=0.07, value_dim=512),
+)
+
+
+def _lower_decode_call(one_chip, mode, masked=False):
+    Hq, pool_shape, pages, kw = mode
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def call(q, pool, tables, lengths, layer, *mask):
+        return pa.paged_flash_attention(
+            q, pool, None if "value_dim" in kw else pool, tables, lengths,
+            layer=layer, **kw, **({"mask": mask[0]} if mask else {}),
+        )
+
+    return jax.jit(call).lower(
+        s((64, 1, Hq, pool_shape[-1]), jnp.bfloat16), s(pool_shape, jnp.bfloat16),
+        s((64, pages), jnp.int32), s((64,), jnp.int32), s((1,), jnp.int32),
+        *([s((64, 1, pages * pool_shape[-2]), jnp.bool_)] if masked else []),
+    )
+
+
+@pytest.mark.parametrize("name", list(DECODE_MODES) + ["paged_mla_masked_decode"])
 def test_a_decode_calls_first_extent_is_the_live_rows(one_chip, name):
     """A decode call (one query a row) hands Mosaic its grid's first
     extent at run time, the rows that hold pages, in the K/V, windowed,
-    latent and windowed-latent modes alike, under the name it had; the
-    other two extents are static, and it compiles for the described v5e."""
-    Hq, pool_shape, pages, kw = DECODE_MODES[name]
-    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    pool = s(pool_shape, jnp.bfloat16)
-
-    def call(q, pool, tables, lengths, layer):
-        return pa.paged_flash_attention(
-            q, pool, None if "value_dim" in kw else pool, tables, lengths,
-            layer=layer, **kw,
-        )
-
-    lowered = jax.jit(call).lower(
-        s((64, 1, Hq, pool_shape[-1]), jnp.bfloat16), pool,
-        s((64, pages), jnp.int32), s((64,), jnp.int32), s((1,), jnp.int32),
-    )
+    latent and windowed-latent modes alike, under the name it had, and
+    under a selection (the sparse cell's decode step: the selection's
+    block is one token's, ``(1, 1, G, 1, 512)``); the other two extents
+    are static, and it compiles for the described v5e.  A call without a
+    selection lowers to the text it had."""
+    masked = name not in DECODE_MODES
+    Hq, pool_shape, pages, kw = mode = MASKED_LATENT if masked else DECODE_MODES[name]
+    lowered = _lower_decode_call(one_chip, mode, masked)
     span = pages if "window" not in kw else min(
         pages, pa.window_span_pages(pool_shape[-2], kw["window"])
     )
-    G = pa.page_group(1, Hq, pool_shape, jnp.bfloat16, False, pages)
+    G = pa.page_group(1, Hq, pool_shape, jnp.bfloat16, False, pages, masked)
     assert _mosaic_grids(lowered) == [(None, 1, -(-span // G))]
     assert name in lowered.compile().as_text()
+    if masked:
+        assert pa._plan_tiles(1, 128, 1, 512, 640, 2, False, 36, True) == (4, 1, 256)
+    else:
+        assert _lowered_programs_script().digest(lowered) == DECODE_CALLS[name]
 
 
 #: scripts/lowered_programs.digest of a fill call of the paged kernel, as
@@ -254,6 +285,9 @@ def test_a_decode_calls_first_extent_is_the_live_rows(one_chip, name):
 FILL_CALLS = {
     "paged_attn_fill": "9627b1d18d2d8e732a89024d608dfd7fb01b4f43",
     "paged_mla_fill": "9454cf3f912cdf768b439ac7afa23c0bc6c4472b",
+    # (the sparse cell's fill under its selection, as the parent of PR 54
+    # lowered it: a decode step's selection rides the same branch)
+    "paged_mla_masked_fill": "8453d29c7d868149c8f93e5b33fb1fada6cda467",
 }
 
 
@@ -268,16 +302,21 @@ def test_a_fill_calls_lowered_text_is_what_it_was(one_chip, name):
             *_paged_args("qwen2.5-1.5b", 16, 256, False, place)
         )
     else:
-        Hq, pool_shape, pages, kw = DECODE_MODES["paged_mla_decode"]
+        masked = name == "paged_mla_masked_fill"
+        Hq, pool_shape, pages, kw = (
+            MASKED_LATENT if masked else DECODE_MODES["paged_mla_decode"]
+        )
 
-        def call(q, pool, tables, lengths, layer):
+        def call(q, pool, tables, lengths, layer, *mask):
             return pa.paged_flash_attention(
-                q, pool, None, tables, lengths, layer=layer, **kw
+                q, pool, None, tables, lengths, layer=layer, **kw,
+                **({"mask": mask[0]} if mask else {}),
             )
 
         lowered = jax.jit(call).lower(
             s((1, 1024, Hq, 640), jnp.bfloat16), s(pool_shape, jnp.bfloat16),
             s((1, pages), jnp.int32), s((1,), jnp.int32), s((1,), jnp.int32),
+            *([s((1, 1024, pages * 512), jnp.bool_)] if masked else []),
         )
     assert name in lowered.as_text()
     assert _lowered_programs_script().digest(lowered) == FILL_CALLS[name]
@@ -1361,37 +1400,55 @@ def test_sparse_fill_program_fits_beside_weights_and_three_pools(
     print(f"sparse fill F={F} C={C}: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
 
 
+@pytest.mark.parametrize("pages", [36, 72])
 def test_sparse_decode_program_fits_and_reads_every_pool_in_place(
-    one_chip, monkeypatch
+    one_chip, monkeypatch, pages
 ):
-    """``hybrid_decode_chunk`` whole (64 rows, 8 steps): the window layers
-    read ``paged_mla_window_decode`` (the ONE Mosaic call of the program:
-    the index scores are XLA's), and NO call reads a full layer's whole
-    context (no ``paged_mla_decode``): the chosen entries are gathered
-    from the pool as it lies (no copy of it: the gather's operand is a
-    bitcast of the pool)."""
+    """``hybrid_decode_chunk`` whole (64 rows, 8 steps), keeping its chosen
+    sets as the cell runs it: the window layers read
+    ``paged_mla_window_decode``, and no call reads a full layer's whole
+    context without a selection (no ``paged_mla_decode``).  Over the
+    cell's table (36 pages: 9 x ``index_topk``,
+    ``sparse_attention.decode_reads_masked``) a full layer attends its
+    cached prefix UNDER ITS SELECTION in ``paged_mla_masked_decode`` (the
+    index scores and the mask are XLA's): the program holds NO sort over
+    the scores (no ``top_k``) and NO view of the latent pool as rows to
+    gather.  Over a table twice as long (18 x) the chosen entries are
+    gathered from the pool as it lies (no copy of it: the gather's operand
+    is a bitcast of the pool), behind ``top_k``'s sorts."""
     monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
     cfg, params, pools, ssm, conv, place = _sparse_cell_args(one_chip)
 
     def rows(dtype):
         return place((SPARSE_ROWS,), dtype)
 
-    table = place((SPARSE_ROWS, SPARSE_CTX // SPARSE_PAGE), jnp.int32)
+    table = place((SPARSE_ROWS, pages), jnp.int32)
     compiled = hybrid.hybrid_decode_chunk.lower(
         params, *pools["global"], ssm, conv, cfg, table, rows(jnp.int32),
         rows(jnp.int32), rows(jnp.bool_), rows(jnp.int32),
         place((2,), jnp.uint32), chunk_size=SPARSE_CHUNK,
         sample_fn=_keyed_greedy, stop_fn=_never_stop, use_kernel=True,
-        max_len=SPARSE_CTX, row_seeds=rows(jnp.int32),
-        win_pools=pools["window"], win_tables=table,
+        max_len=pages * SPARSE_PAGE, row_seeds=rows(jnp.int32),
+        win_pools=pools["window"], win_tables=table, keep_chosen=True,
     ).compile()
     text = compiled.as_text()
+    masked = pages * SPARSE_PAGE == SPARSE_CTX
     assert "paged_mla_window_decode" in text
+    assert ("paged_mla_masked_decode" in text) == masked
     assert "paged_mla_decode" not in text and "paged_attn" not in text
     assert "ragged-dot" not in text
+    # (the sorts that stay under the mask: the page plans' over 64 rows,
+    # the routers' over 256 experts)
+    scored = f"[{SPARSE_ROWS},{pages * SPARSE_PAGE + SPARSE_CHUNK}]"
+    sorts_of_scores = [
+        name for _, name, result, op in _instructions(compiled)
+        if op == "sort" and scored in result
+    ]
+    rows_of_the_pool = f"[{2 * 896 * SPARSE_PAGE},640]"
+    assert bool(sorts_of_scores) == (rows_of_the_pool in text) == (not masked)
     total, temp = _assert_sparse_program_fits(compiled, pools)
-    assert total < 10.5e9, total
-    print(f"sparse decode: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
+    assert total < (10.5e9 if masked else 11.5e9), total
+    print(f"sparse decode, {pages} pages a row: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
 
 
 # --- a decoder-hybrid-decoder stack WHOLE: three cache kinds, one pool layer read by eight ---
