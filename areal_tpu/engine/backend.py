@@ -148,6 +148,13 @@ def make_model(
                 else "smallthinker" if model_cfg.n_window_layers
                 else "granitemoehybrid"
             )
+        elif model_cfg.sandwich_norm:
+            # a looped dense stack (``ouro``): made in the model's dtype
+            # where jax's default device is, as a stack stated by kind
+            from areal_tpu.models.transformer import init_params_in_dtype
+
+            params = init_params_in_dtype(model_cfg, jax.random.PRNGKey(seed))
+            backend_name = "ouro"
         else:
             from areal_tpu.models.transformer import init_params
 

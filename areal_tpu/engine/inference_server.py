@@ -385,6 +385,11 @@ class _InflightChunk:
     first_tokens: List[_FirstTokens] = dataclasses.field(default_factory=list)
 
 
+#: the most a fill batch's stacked keys and values may take
+#: (``_run_fill_batch``)
+FILL_KV_TEMP_BYTES = 1 << 30
+
+
 @partial(
     jax.jit,
     static_argnames=("cfg", "sampling", "mesh"),
@@ -706,9 +711,12 @@ class ContinuousBatchingEngine:
         self._prefix_cache_capacity_frac = prefix_cache_capacity_frac
         self._prefix_cache_min_tokens = prefix_cache_min_tokens
         self._prefix_cache_host_bytes = max(0, int(prefix_cache_host_bytes))
+        # (a looped stack's rows are paged at any length: dense rows hold
+        # ``max_batch x kv_cache_len`` positions of EVERY pass's cache,
+        # 38.7 GB for 16 rows of 1,536 at 192 cache layers of 16 heads)
         self.paged = cache_mode == "paged" or (
             cache_mode == "auto"
-            and kv_cache_len >= PAGED_MIN_CACHE_LEN
+            and (kv_cache_len >= PAGED_MIN_CACHE_LEN or cfg.loop_steps > 1)
             and cfg.sliding_window is None
         )
         # the second cache kind: one recurrent-state slot a batch row (SSM
@@ -844,6 +852,16 @@ class ContinuousBatchingEngine:
             kv_cache_dtype = "auto"
         self.kv_cache_dtype = kv_cache_dtype
         self._kv_quant = kv_cache_dtype == "int8"
+        #: what a looped stack holds a token, set once (on the span
+        #: ``areal.engine.fill.dispatch``; logged when the server exits)
+        self.loop_counts = dict(
+            loop_steps=cfg.loop_steps, cache_layers=cfg.n_attn_layers,
+            kv_bytes_per_token=sum(
+                paged.kv_pool_layout_bytes(
+                    cfg, 1, 1, kv_cache_dtype=kv_cache_dtype
+                )
+            ),
+        )
         assert serving_weight_dtype in ("auto", "int8"), serving_weight_dtype
         self.serving_weight_dtype = serving_weight_dtype
         self._weight_quant = serving_weight_dtype == "int8"
@@ -1180,6 +1198,10 @@ class ContinuousBatchingEngine:
         #: why the last admission left the queue standing
         #: (``table.ADMIT_STOPS``)
         self._admit_stopped_by = admit_stop("queue_empty")
+        #: engine steps that ended with a request queued, a slot free and
+        #: no page for it (``admit_stopped_by`` "no_pages"): where PAGES
+        #: bound the batch and not slots, as under a looped stack's cache
+        self.admission_page_waits_total = 0
         # running totals a step's record differences (``_step_totals``)
         self.rows_admitted_total = 0
         self.rows_finished_total = 0
@@ -3773,8 +3795,17 @@ class ContinuousBatchingEngine:
             # of it: one more row may double it.  Four budgets of padded
             # positions at most (what four rows of a whole chunk each
             # come to); the fill goes into the next batch
+            # ... and every layer's keys and values of all of it leave
+            # the program's layer loop stacked, a temporary: a GiB at most
+            # (no stack but a looped one comes near: 192 cache layers of
+            # 16 heads are 1.57 MB a position, 0.8 GB for 512)
             width = bucket_len(max([take] + [t for _, t in batch]))
-            if batch and (1 << len(batch).bit_length()) * width > 4 * budget:
+            slots = (1 << len(batch).bit_length()) * width
+            if batch and (
+                slots > 4 * budget
+                or slots * self.loop_counts["kv_bytes_per_token"]
+                > FILL_KV_TEMP_BYTES
+            ):
                 break
             batch.append((f, take))
             left -= take
@@ -3800,6 +3831,13 @@ class ContinuousBatchingEngine:
             counts.update(
                 state_copies=self.state_copies_total,
                 state_reprefills=self.state_reprefills_total,
+            )
+        if self.cfg.loop_steps > 1:
+            # a looped stack: what it holds a token (set once), and the
+            # running total of the steps admission waited for PAGES
+            counts.update(
+                self.loop_counts,
+                admission_page_waits=self.admission_page_waits_total,
             )
         grouped = False
         if self._by_kind and self.cfg.n_experts:
@@ -5644,6 +5682,7 @@ class ContinuousBatchingEngine:
             self.decode_rows_planned_total, self.prefill_calls,
             self.prefill_tokens_total,
             self.fill_slots_total, self._kept.late_joins_total,
+            self.admission_page_waits_total,
         )
 
     def _count_step(self, span):
@@ -5665,6 +5704,8 @@ class ContinuousBatchingEngine:
             else:
                 decoding += 1
         pending, ring = len(self._pending), len(self._ring)
+        if pending and self._admit_stopped_by == "no_pages":
+            self.admission_page_waits_total += 1
         span.set_metadata(
             step=self._step_seq, rows_decoding=decoding,
             rows_filling=filling, pending=pending, ring=ring,

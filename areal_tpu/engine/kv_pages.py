@@ -355,7 +355,14 @@ class StatefulModelUnsupported(CacheKindRefuses):
 #: the error raised)
 STATE_SLOTS, WINDOW_POOL, BY_KIND = "state slots", "window pool", "by kind"
 INDEX_POOL = "index pool"
+LOOPED = "looped stack"
 _KINDS = {
+    LOOPED: (
+        "the loop refuses it (loop_steps > 1: a verify window's rewinding "
+        "of loop_steps caches a layer is held by no test, and a draft "
+        "costs loop_steps passes of every layer: engine/spec_decode.py)",
+        CacheKindRefuses,
+    ),
     INDEX_POOL: (
         "the index pool refuses it (each latent page has a page of index "
         "keys of another width beside it, which only the two programs of "
@@ -385,7 +392,7 @@ _KINDS = {
 REFUSED = {
     "the dense (unpaged) KV cache": (STATE_SLOTS, BY_KIND),
     "a tensor- or expert-parallel serving mesh": (STATE_SLOTS, INDEX_POOL, BY_KIND),
-    "speculative verify": (STATE_SLOTS, BY_KIND),
+    "speculative verify": (STATE_SLOTS, BY_KIND, LOOPED),
     "int8 KV storage": (STATE_SLOTS, INDEX_POOL, BY_KIND),
     "int8 serving weights": (STATE_SLOTS, BY_KIND),
     "prefix-cache host spill": (STATE_SLOTS, INDEX_POOL, WINDOW_POOL),
@@ -410,6 +417,10 @@ def kinds_held(cfg) -> Dict[str, str]:
         held[WINDOW_POOL] = (
             "a stack with latent window layers" if cfg.is_latent_window
             else "a stack with window layers"
+        )
+    if cfg.loop_steps > 1:
+        held[LOOPED] = (
+            f"a stack run {cfg.loop_steps} times with the same weights"
         )
     if cfg.is_hybrid:
         held[BY_KIND] = (

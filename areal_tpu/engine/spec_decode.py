@@ -274,6 +274,11 @@ def paged_verify_chunk(
     an int8 pool, like any fill); positions at/beyond ``max_len`` are
     masked (never clipped into a foreign block).
     """
+    if cfg.loop_steps > 1:
+        raise NotImplementedError(
+            f"speculative verify with loop_steps {cfg.loop_steps}: refused "
+            "(engine/kv_pages.py, LOOPED)"
+        )
     B = cur_tokens.shape[0]
     C = max_draft + 1
     window = jnp.concatenate([cur_tokens[:, None], draft_tokens], axis=1)

@@ -50,6 +50,24 @@ class TransformerConfig:
     embed_scale: Optional[float] = None  # gemma multiplies embeddings
     abs_position_embedding: bool = False  # gpt2
     sliding_window: Optional[int] = None  # mistral
+    # a LOOPED dense stack (ouro ``total_ut_steps``): the ``n_layers``
+    # layers are run ``loop_steps`` times with the SAME weights before a
+    # token's logits exist, the final norm after every pass; the attention
+    # of pass r, layer l reads what pass r, layer l wrote for the earlier
+    # tokens, so the cache holds ``n_layers x loop_steps`` layers
+    # (``n_attn_layers``; cache layer ``r * n_layers + l``)
+    loop_steps: int = 1
+    # sandwich norms (ouro): a norm on each branch's OUTPUT before the
+    # residual add (``attn_post_norm`` / ``mlp_post_norm``), beside the
+    # norm on its input
+    sandwich_norm: bool = False
+    # the looped stack's exit gate ([hidden -> 1] after every pass's
+    # norm): a token leaves at the first pass whose cumulated exit
+    # probability reaches ``loop_exit_threshold``.  At the published 1
+    # that is the last pass for every token, and the gate's weights are
+    # held and converted but never evaluated; any other value is refused
+    loop_exit_gate: bool = False
+    loop_exit_threshold: float = 1.0
 
     # MoE (mixtral / qwen3-moe); n_experts=0 disables
     n_experts: int = 0
@@ -315,6 +333,19 @@ class TransformerConfig:
                 assert "mamba1" in self.layer_types[
                     : self.layer_types.index("gmu")
                 ], "a gmu layer gates the scan output of a mamba1 layer"
+        assert self.loop_steps >= 1, self.loop_steps
+        if self.loop_steps > 1:
+            assert self.layer_types is None and not self.is_moe, (
+                "loop_steps > 1: a dense stack of models/transformer.py "
+                "(no stack stated by kind and no expert layer loops)"
+            )
+        if self.loop_exit_threshold != 1.0:
+            raise NotImplementedError(
+                f"early_exit_threshold {self.loop_exit_threshold}: only the "
+                "published 1 is served (every token leaves at the last "
+                "pass); below it rows leave the loop at different passes, "
+                "and a step no longer costs every row the same"
+            )
         if self.n_mamba_layers and not self.is_mamba1:
             # a B/C group serves a whole number of heads
             assert self.mamba_n_heads % self.mamba_n_groups == 0, (
@@ -393,12 +424,14 @@ class TransformerConfig:
 
     @property
     def n_attn_layers(self) -> int:
-        """Layers that WRITE per-token KV (every layer of a dense stack;
-        "attention", "window", "latent", "latent_window" and "parallel"
-        layers of a stack by kind: a "cross" layer reads another's, a "gmu"
-        layer has none)."""
+        """CACHE layers: layers that WRITE per-token KV (every layer of a
+        dense stack, once for every pass of a looped one: ``n_layers x
+        loop_steps``; "attention", "window", "latent", "latent_window" and
+        "parallel" layers of a stack by kind: a "cross" layer reads
+        another's, a "gmu" layer has none).  What every pool shape, page
+        byte count and handoff reads; the WEIGHT layers are ``n_layers``."""
         if self.layer_types is None:
-            return self.n_layers
+            return self.n_layers * self.loop_steps
         return sum(
             t in ("attention", "window", "latent", "latent_window", "parallel")
             for t in self.layer_types

@@ -9,6 +9,7 @@ from areal_tpu.models.hf import (  # noqa: F401
     laguna,
     llama_like,
     mixtral,
+    ouro,
     phi4flash,
     qwen3_moe,
     smallthinker,

@@ -196,6 +196,7 @@ from areal_tpu.models.transformer import (
     make_attention_mask,
     rope_apply,
     scan_layers,
+    uniform_stack as _uniform_stack,
     window_put,
 )
 from areal_tpu.observability.tracing import region
@@ -488,23 +489,6 @@ def _rope_cfg(cfg: TransformerConfig, run: Run) -> TransformerConfig:
 # ---------------------------------------------------------------------------
 # parameters
 # ---------------------------------------------------------------------------
-
-
-def _uniform_stack(key, n: int, shape, bound: float, dtype):
-    """``[n, *shape]`` uniform in ``(-bound, bound)``, made one layer at a
-    time in float32 and kept in ``dtype``: the float32 transient is one
-    layer's, not the stack's."""
-
-    @jax.jit
-    def make(keys):
-        return jax.lax.map(
-            lambda k: jax.random.uniform(
-                k, shape, F32, -bound, bound
-            ).astype(dtype),
-            keys,
-        )
-
-    return make(jax.random.split(key, n))
 
 
 #: rms of the random embedding of a TIED head (see :func:`init_params`)

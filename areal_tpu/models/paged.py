@@ -12,6 +12,10 @@ allocator; this module owns the device-side compute:
   contiguous HBM extent) — NB fixed-size blocks shared by all rows; a
   row's cache is the ordered block list in its table row ``[MB]`` (pool
   block id per logical block);
+  ``L`` counts CACHE layers (``cfg.n_attn_layers``): a looped stack's
+  every pass writes a layer of its own, ``r * n_layers + l``, and both
+  programs run their layers through ``transformer.loop_layers``, which
+  is the plain layer scan where nothing loops;
 * :func:`paged_fill_chunk` runs ONE chunk of prompt prefill for a batch of
   filling rows: in-chunk causal self-attention merged online with
   paged-kernel partials over each row's already-cached prefix — so a 16k
@@ -66,8 +70,8 @@ from areal_tpu.models.transformer import (
     _embed,
     _head,
     _mlp_half,
+    loop_layers,
     rope_tables,
-    scan_layers,
     window_put,
 )
 from areal_tpu.observability.tracing import region
@@ -621,8 +625,8 @@ def paged_window_forward(
         x, _ = _mlp_half(cfg, lp, x, seg_ids=seg_ids, mesh=mesh)
         return x, kept
 
-    x, window_kv = scan_layers(
-        body, x, (params["layers"], jnp.arange(L))
+    x, window_kv = loop_layers(
+        params, cfg, body, x, (params["layers"], jnp.arange(L))
     )
     pools = (k_pool, v_pool)
     if k_scale is not None:
@@ -798,8 +802,9 @@ def paged_decode_chunk(
             x, _ = _mlp_half(cfg, lp, x, mesh=mesh)
             return (x, wk, wv), None
 
-        (x, wk, wv), _ = scan_layers(
-            body, (x, wk, wv), (params["layers"], jnp.arange(L))
+        (x, wk, wv), _ = loop_layers(
+            params, cfg, body, (x, wk, wv),
+            (params["layers"], jnp.arange(L)),
         )
         logits = _head(params, cfg, x)[:, 0]
         (new_lengths, tok, active, budgets, out_t, out_l, emitted,

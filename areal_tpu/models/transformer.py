@@ -119,6 +119,14 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         },
         "final_norm": norm_params((D,)),
     }
+    if cfg.sandwich_norm:
+        params["layers"]["attn_post_norm"] = norm_params((L, D))
+        params["layers"]["mlp_post_norm"] = norm_params((L, D))
+    if cfg.loop_exit_gate:
+        params["exit_gate"] = {
+            "w": _dense_init(next(keys), (D, 1)),
+            "b": jnp.zeros((1,), jnp.float32),
+        }
     if cfg.abs_position_embedding:
         params["pos_embed"] = {
             "weight": _dense_init(
@@ -129,6 +137,86 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         params["value_head"] = {"w": _dense_init(next(keys), (D, 1))}
     elif not cfg.tied_embedding:
         params["lm_head"] = {"w": _dense_init(next(keys), (D, cfg.vocab_size))}
+    return params
+
+
+def uniform_stack(key, n: int, shape, bound: float, dtype):
+    """``[n, *shape]`` uniform in ``(-bound, bound)``, made one layer at a
+    time in float32 and kept in ``dtype``: the float32 transient is one
+    layer's, not the stack's."""
+
+    @jax.jit
+    def make(keys):
+        return jax.lax.map(
+            lambda k: jax.random.uniform(
+                k, shape, jnp.float32, -bound, bound
+            ).astype(dtype),
+            keys,
+        )
+
+    return make(jax.random.split(key, n))
+
+
+def init_params_in_dtype(cfg: TransformerConfig, key: jax.Array) -> Params:
+    """Seeded random weights of a dense stack with sandwich norms (a
+    looped one: ``ouro``) in ``cfg.dtype``, made where jax's default device
+    is (for a server: its chip), a layer at a time: a float32 host copy of
+    2.67 B parameters is 10.7 GB and most of a minute.  The tree is
+    :func:`init_params`'; matrices are uniform in ``+-1/sqrt(fan_in)``,
+    the embedding of rms 0.5; norm scales AROUND 1, not at 1 (a scale read
+    from the wrong layer, or a norm left out, has to show against the
+    reference), and the exit gate's bias off 0."""
+    assert not (
+        cfg.is_hybrid or cfg.is_moe or cfg.use_attention_bias
+        or cfg.use_qk_norm or cfg.use_mlp_bias or cfg.abs_position_embedding
+        or cfg.tied_embedding or cfg.is_critic or cfg.norm_type != "rms"
+    ) and cfg.gated_mlp, "init_params_in_dtype: the plain gated dense layer"
+    dt = jnp.dtype(cfg.dtype)
+    L, D, F = cfg.n_layers, cfg.hidden_dim, cfg.intermediate_dim
+    keys = iter(jax.random.split(key, 24))
+
+    def mat(shape, fan_in, n=L):
+        return uniform_stack(next(keys), n, shape, 1.0 / np.sqrt(fan_in), dt)
+
+    def scale(*shape):
+        return {
+            "scale": jax.random.uniform(
+                next(keys), shape, jnp.float32, 0.75, 1.25
+            ).astype(dt)
+        }
+
+    layers: Params = {
+        "attn_norm": scale(L, D),
+        "attn": {
+            "q": {"w": mat((D, cfg.q_dim), D)},
+            "k": {"w": mat((D, cfg.kv_dim), D)},
+            "v": {"w": mat((D, cfg.kv_dim), D)},
+            "o": {"w": mat((cfg.q_dim, D), cfg.q_dim)},
+        },
+        "mlp_norm": scale(L, D),
+        "mlp": {
+            "gate": {"w": mat((D, F), D)},
+            "up": {"w": mat((D, F), D)},
+            "down": {"w": mat((F, D), F)},
+        },
+    }
+    if cfg.sandwich_norm:
+        layers["attn_post_norm"] = scale(L, D)
+        layers["mlp_post_norm"] = scale(L, D)
+    params: Params = {
+        # rms 0.5: uniform in +-0.5 sqrt(3)
+        "embed": {"weight": mat((cfg.vocab_size, D), 4.0 / 3.0, n=1)[0]},
+        "layers": layers,
+        "final_norm": scale(D),
+        "lm_head": {"w": mat((D, cfg.vocab_size), D, n=1)[0]},
+    }
+    if cfg.loop_exit_gate:
+        params["exit_gate"] = {
+            "w": mat((D, 1), D, n=1)[0],
+            "b": jax.random.uniform(
+                next(keys), (1,), jnp.float32, -0.5, 0.5
+            ).astype(dt),
+        }
     return params
 
 
@@ -173,6 +261,8 @@ def param_pspecs(
             return P("fsdp", None)
         if keys[0] == "final_norm":
             return P(None)
+        if keys[0] == "exit_gate":
+            return P()
         # inside "layers": leading dim is the stacked layer axis
         if "router" in keys or "experts" in keys:
             if "router" in keys:
@@ -544,7 +634,8 @@ def _warn_dense_fallback(
 
 @dataclasses.dataclass
 class KVCache:
-    """Decode-time KV cache: stacked over layers.
+    """Decode-time KV cache: stacked over the CACHE layers (a looped
+    stack's every pass has its own: ``cfg.n_attn_layers``).
 
     k/v: [L, B, Hkv, S, hd] — HEAD-major so decode attention reads the cache
     in its stored layout (seq-major forced a whole-cache transpose copy per
@@ -560,7 +651,9 @@ class KVCache:
     @classmethod
     def zeros(cls, cfg: TransformerConfig, batch: int, max_len: int, dtype=None):
         dtype = dtype or jnp.dtype(cfg.dtype)
-        shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
+        shape = (
+            cfg.n_attn_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim
+        )
         return cls(
             k=jnp.zeros(shape, dtype),
             v=jnp.zeros(shape, dtype),
@@ -623,7 +716,10 @@ def _attn_half(cfg: TransformerConfig, lp: Params, x, positions, rope_cs,
     h = _norm(x, lp["attn_norm"], cfg)
     q, k, v = _attn_qkv(cfg, lp, h, positions, rope_cs)
     attn, kept = attend(q, k, v)
-    return x + _proj(lp["attn"]["o"], attn), kept
+    out = _proj(lp["attn"]["o"], attn)
+    if cfg.sandwich_norm:  # the branch's OUTPUT is normed, then added
+        out = _norm(out, lp["attn_post_norm"], cfg)
+    return x + out, kept
 
 
 @region("areal.mlp")
@@ -632,7 +728,10 @@ def _mlp_half(cfg: TransformerConfig, lp: Params, x, seg_ids=None, mesh=None):
     :func:`_mlp_block`, the residual add.  Returns ``(x, aux)``."""
     h = _norm(x, lp["mlp_norm"], cfg)
     mlp_out, aux = _mlp_block(cfg, lp, h, seg_ids=seg_ids, mesh=mesh)
-    return x + checkpoint_name(mlp_out, "mlp_out"), aux
+    mlp_out = checkpoint_name(mlp_out, "mlp_out")
+    if cfg.sandwich_norm:
+        mlp_out = _norm(mlp_out, lp["mlp_post_norm"], cfg)
+    return x + mlp_out, aux
 
 
 @region("areal.kv_write")
@@ -654,6 +753,66 @@ def scan_layers(body, init, xs):
     and values, the weights' gradients) OUTSIDE the body's scopes, so they
     would carry no region.  What the body names keeps its own."""
     return jax.lax.scan(body, init, xs)
+
+
+def loop_layers(params: Params, cfg: TransformerConfig, body, carry, xs):
+    """The dense stack's layer loop, for EVERY program that runs it:
+    :func:`scan_layers` of ``body`` over ``xs``, ``cfg.loop_steps`` times
+    over with the same weights (an outer ``lax.scan``, so the layer body
+    is compiled once), the final norm on the hidden states after every
+    pass but the last, whose norm is the head's (``_head`` /
+    ``_final_norm``).  At ``loop_steps`` 1 it IS ``scan_layers``: no outer
+    scan, no norm, no ``areal.loop`` scope.
+
+    A leaf of ``xs`` whose leading axis is ``n_layers`` long is the
+    weights' and is read again in every pass; one that is ``n_layers x
+    loop_steps`` long is per CACHE layer (``r * n_layers + l``: the cache
+    layers' index, a dense cache's keys and values) and pass ``r`` reads
+    its ``r``-th ``n_layers``.  What the bodies leave (a fill's keys and
+    values) comes back stacked over the cache layers.  ``carry`` is the
+    hidden states or a tuple that begins with them."""
+    R, L = cfg.loop_steps, cfg.n_layers
+    if R == 1:
+        return scan_layers(body, carry, xs)
+    leaves, treedef = jax.tree.flatten(xs)
+    by_pass = [a.shape[0] == R * L for a in leaves]
+    assert all(p or a.shape[0] == L for a, p in zip(leaves, by_pass)), (
+        [a.shape for a in leaves], L, R,
+    )
+
+    def pass_norm(r, carry):
+        with region("areal.loop.norm"):
+            x = carry[0] if isinstance(carry, tuple) else carry
+            x = jax.lax.cond(
+                r + 1 < R,
+                lambda x: _norm(x, params["final_norm"], cfg),
+                lambda x: x,
+                x,
+            )
+        return (x,) + carry[1:] if isinstance(carry, tuple) else x
+
+    def one_pass(carry, at):
+        r, sliced = at
+        sliced = iter(sliced)
+        xs_r = treedef.unflatten(
+            [next(sliced) if p else a for a, p in zip(leaves, by_pass)]
+        )
+        carry, ys = scan_layers(body, carry, xs_r)
+        return pass_norm(r, carry), ys
+
+    with region("areal.loop"):
+        carry, ys = jax.lax.scan(
+            one_pass, carry,
+            (
+                jnp.arange(R),
+                [
+                    a.reshape((R, L) + a.shape[1:])
+                    for a, p in zip(leaves, by_pass) if p
+                ],
+            ),
+        )
+        ys = jax.tree.map(lambda y: y.reshape((R * L,) + y.shape[2:]), ys)
+    return carry, ys
 
 
 def _mlp_block(cfg: TransformerConfig, lp: Params, h, seg_ids=None,
@@ -725,10 +884,12 @@ def _layer(
 
 
 def _scan_layers(cfg: TransformerConfig, stacked_lp, x, positions, mask,
-                 seg_ids, rope_cs):
+                 seg_ids, rope_cs, params=None):
     """``lax.scan`` of :func:`_layer` over stacked layer params (with the
-    configured rematerialisation).  Returns ``(y, aux_layers)`` where
-    aux_layers is the per-layer MoE loss stack (None for dense)."""
+    configured rematerialisation), ``cfg.loop_steps`` times over where
+    ``params`` (for the norm between passes) is given.  Returns ``(y,
+    aux_layers)`` where aux_layers is the per-layer MoE loss stack (None
+    for dense)."""
 
     def body(carry, lp):
         y, _, aux = _layer(
@@ -747,7 +908,9 @@ def _scan_layers(cfg: TransformerConfig, stacked_lp, x, positions, mask,
             body = jax.checkpoint(body)
         else:
             body = jax.checkpoint(body, policy=policy)
-    return scan_layers(body, x, stacked_lp)
+    if params is None:
+        return scan_layers(body, x, stacked_lp)
+    return loop_layers(params, cfg, body, x, stacked_lp)
 
 
 def _run_layers_pipelined(
@@ -760,6 +923,13 @@ def _run_layers_pipelined(
     from jax.sharding import NamedSharding
     from areal_tpu.parallel import pipeline
 
+    if cfg.loop_steps > 1:
+        raise NotImplementedError(
+            f"loop_steps {cfg.loop_steps} on a pipeline mesh: a stage holds "
+            "a slice of the layers and a pass needs them all in turn, so "
+            "every pass would go round the stages again (_run_layers_"
+            "pipelined runs ONE pass)"
+        )
     B = x.shape[0]
     p = mesh.shape["pipe"]
     assert cfg.n_layers % p == 0, (
@@ -860,7 +1030,8 @@ def _run_layers(
         )
         return (x, aux_total) if with_aux else x
     x, aux_layers = _scan_layers(
-        cfg, params["layers"], x, positions, mask, seg_ids, rope_cs
+        cfg, params["layers"], x, positions, mask, seg_ids, rope_cs,
+        params=params,
     )
     if not with_aux:
         return x
@@ -970,8 +1141,8 @@ def prefill(
         )
         return y, (k_full, v_full)
 
-    x, (new_k, new_v) = scan_layers(
-        body, x, (params["layers"], cache.k, cache.v)
+    x, (new_k, new_v) = loop_layers(
+        params, cfg, body, x, (params["layers"], cache.k, cache.v)
     )
     new_lengths = cache.lengths + jnp.sum(seg_ids != 0, axis=1).astype(jnp.int32)
     if last_pos is not None:
@@ -1037,10 +1208,10 @@ def decode_step(
         x, _ = _mlp_half(cfg, lp, x, mesh=mesh)
         return (x, k_all, v_all), None
 
-    (x, new_k, new_v), _ = scan_layers(
-        body,
+    (x, new_k, new_v), _ = loop_layers(
+        params, cfg, body,
         (x, cache.k, cache.v),
-        (params["layers"], jnp.arange(cfg.n_layers)),
+        (params["layers"], jnp.arange(cfg.n_attn_layers)),
     )
     logits = _head(params, cfg, x)[:, 0]
     new_lengths = cache.lengths + active.astype(jnp.int32)
@@ -1097,7 +1268,7 @@ def decode_chunk(
     S = cache.max_len
     Sa = S if attn_len is None else min(attn_len, S)
     W = chunk_size
-    L, Hkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    L, Hkv, hd = cfg.n_attn_layers, cfg.n_kv_heads, cfg.head_dim
     base_lens = cache.lengths  # frozen: main-cache valid region per row
 
     # window-gather dispatch: pays 2x window of copy traffic once per chunk
@@ -1209,8 +1380,8 @@ def decode_chunk(
             x, _ = _mlp_half(cfg, lp, x, mesh=mesh)
             return (x, wk, wv), None
 
-        (x, wk, wv), _ = scan_layers(
-            body,
+        (x, wk, wv), _ = loop_layers(
+            params, cfg, body,
             (x, wk, wv),
             (params["layers"], jnp.arange(L), attn_k, attn_v),
         )

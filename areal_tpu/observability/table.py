@@ -1143,9 +1143,13 @@ TRACE_TABLE = [
         "whose prompt a live row carries and whose kept fill was gone, "
         "so that they prefilled it again; for a stack stated by kind "
         "tail_layers = layers of its keep-nothing tail, which a fill "
-        "runs on each row's last position alone).  The other running "
-        "totals a fill moves are engine attributes, logged once when "
-        "the server exits",
+        "runs on each row's last position alone; for a LOOPED dense "
+        "stack loop_steps, cache_layers = n_layers x loop_steps and "
+        "kv_bytes_per_token over all of them, each set once, and the "
+        "running total admission_page_waits = engine steps that ended "
+        "with a request queued, a slot free and no page for it).  The "
+        "other running totals a fill moves are engine attributes, "
+        "logged once when the server exits",
     ),
     TraceSpec(
         "areal.engine.fill.first_token_wait",
@@ -1378,6 +1382,21 @@ TRACE_TABLE = [
         "their region",
     ),
     TraceSpec(
+        "areal.loop",
+        "region",
+        "A looped stack's passes (loop_steps > 1): the scan over the "
+        "passes around the layer loop, i.e. the cache layers' index and "
+        "a dense cache's keys and values sliced a pass, the carry, what "
+        "the passes leave restacked over the cache layers; what the "
+        "layer loop and a layer's halves name keeps their region",
+    ),
+    TraceSpec(
+        "areal.loop.norm",
+        "region",
+        "The final norm BETWEEN passes of a looped stack (after every "
+        "pass but the last, whose norm is areal.head's)",
+    ),
+    TraceSpec(
         "areal.mlp",
         "region",
         "A layer's MLP half: norm, dense MLP, residual (around an "
@@ -1495,7 +1514,7 @@ def admit_stop(reason: str) -> str:
 STEP_DELTAS = (
     "tokens_emitted", "rows_admitted", "rows_finished", "rows_preempted",
     "decode_chunks", "decode_rows", "rows_planned", "fill_programs",
-    "fill_tokens", "fill_slots", "late_joins",
+    "fill_tokens", "fill_slots", "late_joins", "admission_page_waits",
 )
 
 #: what every PhaseClock record holds, whoever owns the clock
@@ -1544,6 +1563,9 @@ ENGINE_STEP_RECORD = {
     "fill_tokens": "Real prompt tokens in them",
     "fill_slots": "f_pad x c positions they computed",
     "late_joins": "Late siblings served from a kept fill",
+    "admission_page_waits": "1 where the step ended with a request "
+    "queued, a slot free and no page for it (admit_stopped_by no_pages): "
+    "sums to admission_page_waits_total, what page_wait_share reads",
 }
 
 #: the trainer's record a batch (``TrainEngine.train_batch``)

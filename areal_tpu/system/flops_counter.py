@@ -33,6 +33,12 @@ def matmul_params_per_layer(cfg: TransformerConfig) -> int:
     return attn + n_mats * cfg.hidden_dim * cfg.intermediate_dim
 
 
+def layer_passes(cfg: TransformerConfig) -> int:
+    """Layers a token goes through before its logits exist: a looped
+    stack's ``n_layers``, ``loop_steps`` times over (and ONE head)."""
+    return cfg.n_layers * cfg.loop_steps
+
+
 def _forward_flops_by_kind(
     cfg: TransformerConfig, seqlens: Sequence[int], with_head: bool
 ) -> int:
@@ -80,10 +86,11 @@ def forward_flops(
     if cfg.is_hybrid and set(cfg.layer_types) <= set(PLAIN_ATTENTION_KINDS):
         return _forward_flops_by_kind(cfg, seqlens, with_head)
     total_tokens = sum(seqlens)
-    flops = 2 * matmul_params_per_layer(cfg) * cfg.n_layers * total_tokens
+    passes = layer_passes(cfg)
+    flops = 2 * matmul_params_per_layer(cfg) * passes * total_tokens
     # causal attention: sum_t 4 * q_dim * t/2 = q_dim * t*(t+1) ~= q_dim*t^2
     for t in seqlens:
-        flops += 2 * cfg.n_layers * cfg.q_dim * t * t
+        flops += 2 * passes * cfg.q_dim * t * t
     if with_head:
         out_dim = 1 if cfg.is_critic else cfg.vocab_size
         flops += 2 * cfg.hidden_dim * out_dim * total_tokens
@@ -102,14 +109,15 @@ def generate_flops(
 ) -> int:
     """Prefill of each prompt + per-token decode over the growing cache."""
     flops = forward_flops(cfg, prompt_lens, with_head=False)
-    per_tok_mats = 2 * matmul_params_per_layer(cfg) * cfg.n_layers
+    passes = layer_passes(cfg)
+    per_tok_mats = 2 * matmul_params_per_layer(cfg) * passes
     out_dim = 1 if cfg.is_critic else cfg.vocab_size
     head = 2 * cfg.hidden_dim * out_dim
     for p, g in zip(prompt_lens, gen_lens):
         # decode token i attends to p+i cached positions
         avg_ctx = p + g / 2.0
         flops += int(
-            g * (per_tok_mats + head + 4 * cfg.n_layers * cfg.q_dim * avg_ctx)
+            g * (per_tok_mats + head + 4 * passes * cfg.q_dim * avg_ctx)
         )
     return flops
 

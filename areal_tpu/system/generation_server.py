@@ -1783,6 +1783,13 @@ class GenerationServerWorker(worker_base.Worker):
                     eng.fill_tail_layers,
                     eng.fill_tail_positions_saved_total,
                 )
+            if eng.cfg.loop_steps > 1:
+                self.logger.info(
+                    "looped stack: loop_steps=%d, cache_layers=%d, "
+                    "kv_bytes_per_token=%d, admission_page_waits=%d",
+                    *eng.loop_counts.values(),
+                    eng.admission_page_waits_total,
+                )
             if eng._stateful:
                 self.logger.info(
                     "kept fills: state_late_joins=%d, state_reprefills=%d, "

@@ -1967,3 +1967,126 @@ def test_snapshot_slots_fit_and_are_copied_a_slot_at_a_time(one_chip, cell):
     kept += (rows // 8) * cfg.vocab_size * 4
     print(f"{cell}: a slot {slot_bytes / 1e6:.1f} MB, kept {kept / 1e9:.3f} GB")
     assert resident + kept < USABLE_HBM_BYTES
+
+
+# -- the looped cell (ouro-2.6b): 192 cache layers over 48 weight layers -----
+
+LOOP_ROWS, LOOP_CTX, LOOP_CHUNK = 5, 1536, 8
+LOOP_PAGE, LOOP_POOL_TOKENS = 128, 4864
+
+
+def _loop_cell_args(one_chip):
+    import json
+    import os
+
+    from benchmark.lib.program import model_config
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs", "ouro-2.6b.json")) as f:
+        cfg = model_config(json.load(f), "serve")
+    with open(os.path.join(root, "benchmark", "traffic", "rollout-full-loop.json")) as f:
+        eng = json.load(f)["engine"]
+    # the shapes below ARE the cell's
+    assert (
+        eng["max_concurrent_batch"], eng["kv_cache_len"], eng["chunk_size"],
+        eng["page_size"], eng["kv_pool_tokens"],
+    ) == (LOOP_ROWS, LOOP_CTX, LOOP_CHUNK, LOOP_PAGE, LOOP_POOL_TOKENS)
+
+    def place(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params_in_dtype(cfg, jax.random.PRNGKey(0))
+    )
+    params = jax.tree.map(lambda a: place(a.shape, a.dtype), shapes)
+    k_shape, v_shape = paged.pool_shapes(
+        cfg, LOOP_POOL_TOKENS // LOOP_PAGE, LOOP_PAGE
+    )
+    assert k_shape == (192, LOOP_POOL_TOKENS // LOOP_PAGE, 16, LOOP_PAGE, HD)
+    pools = place(k_shape, jnp.bfloat16), place(v_shape, jnp.bfloat16)
+    return cfg, params, pools, place
+
+
+def _loop_program_total(compiled, pool):
+    """No copy of the pool (3.8 GB a side); the bytes the program stands
+    at and its temporaries, by the compiler's count."""
+    assert _pool_copies(compiled, pool.shape) == []
+    m = compiled.memory_analysis()
+    total = (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+    assert total < USABLE_HBM_BYTES, (total, m.temp_size_in_bytes)
+    return total, m.temp_size_in_bytes
+
+
+def test_paged_kernel_compiles_at_one_query_head_a_kv_head(one_chip):
+    """The paged kernel's decode call at the looped cell's tile plan: 16 KV
+    heads of 128 at ONE query head each, pages of 128 tokens out of a
+    192-layer pool, the cell's 5 rows; and its fill call at 512 queries a
+    row."""
+    cfg, _, pools, place = _loop_cell_args(one_chip)
+    assert pa.page_tile(pools[0].shape[-3:], jnp.bfloat16) == LOOP_PAGE
+    for B, Q in ((LOOP_ROWS, 1), (1, 512)):
+        compiled = jax.jit(_call_kernel).lower(
+            place((B, Q, 16, HD), jnp.bfloat16), *pools,
+            place((B, LOOP_CTX // LOOP_PAGE), jnp.int32),
+            place((B,), jnp.int32), place((), jnp.int32),
+        ).compile()
+        _assert_kernel(compiled)
+
+
+# one prompt's chunk of 512, and the widest batches the engine's GiB of
+# stacked keys and values lets through (inference_server.FILL_KV_TEMP_BYTES)
+@pytest.mark.parametrize("F,C", [(1, 512), (2, 256)])
+def test_loop_fill_program_fits_beside_weights_and_a_192_layer_pool(
+    one_chip, monkeypatch, F, C
+):
+    """``paged_fill_chunk`` whole at the looped cell's shapes: 5.34 GB of
+    weights + 7.65 GB of pages + the chunk's temporaries (every cache
+    layer's keys and values stacked: 0.8 GB for 512 positions; two weight
+    stacks the compiler lays out again for its loop, 0.8 GB) fit one chip;
+    ONE outer loop over the passes, the prefix part ``paged_attn_fill``;
+    no copy of the pool."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    cfg, params, pools, place = _loop_cell_args(one_chip)
+    compiled = paged.paged_fill_chunk.lower(
+        params, *pools, cfg, place((F, C), jnp.int32), place((F,), jnp.int32),
+        place((F,), jnp.int32), place((F, LOOP_CTX // LOOP_PAGE), jnp.int32),
+        use_kernel=True,
+    ).compile()
+    assert "paged_attn_fill" in compiled.as_text()
+    total, temp = _loop_program_total(compiled, pools[0])
+    assert 14.5e9 < total and temp < 2.1e9, (total, temp)
+    print(f"loop fill F={F} C={C}: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
+
+
+def test_loop_decode_program_fits_and_reads_the_pool_in_place(
+    one_chip, monkeypatch
+):
+    """``paged_decode_chunk`` whole (5 rows, 8 steps): ``paged_attn_decode``
+    at one query head a KV head by name, once in the text (the layer body is
+    compiled ONCE inside the loop over the passes, not four times); the
+    chunk's window over the 192 cache layers is 63 MB; no copy of the pool.
+    What the compiler does copy, once a chunk, is three of the four square
+    weight stacks ``[48, 2048, 2048]`` into the layout its loop wants (1.2
+    GB of temporaries: PERF.md section 7)."""
+    monkeypatch.setattr(paged, "kernel_interpret", lambda: False)
+    cfg, params, pools, place = _loop_cell_args(one_chip)
+
+    def rows(dtype):
+        return place((LOOP_ROWS,), dtype)
+
+    compiled = paged.paged_decode_chunk.lower(
+        params, *pools, cfg,
+        place((LOOP_ROWS, LOOP_CTX // LOOP_PAGE), jnp.int32),
+        rows(jnp.int32), rows(jnp.int32), rows(jnp.bool_), rows(jnp.int32),
+        place((2,), jnp.uint32), chunk_size=LOOP_CHUNK,
+        sample_fn=_keyed_greedy, stop_fn=_never_stop, use_kernel=True,
+        max_len=LOOP_CTX, row_seeds=rows(jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "paged_attn_decode" in text
+    total, temp = _loop_program_total(compiled, pools[0])
+    assert 14.0e9 < total and temp < 1.5e9, (total, temp)
+    print(f"loop decode: total {total / 1e9:.2f} GB, temporaries {temp / 1e9:.2f} GB")
