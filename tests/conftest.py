@@ -21,12 +21,22 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 
+#: an xdist worker's sentinel (tests/helpers/runtime_guard.py)
+_SENTINEL = pytest.StashKey["subprocess.Popen"]()
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: heavy tests excluded from the tier-1 budget "
         "(run with `-m slow` or no marker filter)",
     )
+    if hasattr(config, "workerinput"):
+        # a worker that dies takes its children with it, so that its pipe
+        # closes and the controller fails ONE test and goes on
+        from tests.helpers.runtime_guard import start_sentinel
+
+        config.stash[_SENTINEL] = start_sentinel()
 
 
 #: what a whole run starts with, in this order
@@ -76,11 +86,48 @@ def pytest_runtest_makereport(item, call):
 LEFTOVER_GRACE_S = 3.0
 #: the controller's list of what its workers' sessions left behind
 _LEFTOVERS = pytest.StashKey[list]()
+#: what the files of this process noted, and on the controller its workers'
+_FILE_NOTES = pytest.StashKey[list]()
 
 
-def _session_leftovers(where):
+@pytest.fixture(scope="module", autouse=True)
+def _give_back_what_the_file_compiled(request):
+    """After a file's last test, release what it compiled.  Every
+    program XLA's CPU backend compiles costs the process memory
+    mappings, and past ``vm.max_map_count`` the next compile segfaults
+    the worker; ``jax.clear_caches()`` followed by a collection gives
+    them back (only a ``Compiled`` that something still references keeps
+    its own).  Under ``--dist loadfile`` a worker holds a file whole, so
+    nothing inside a file compiles twice.  The counts and the seconds are
+    noted for the summary and the mappings guard
+    (tests/helpers/runtime_guard.py)."""
+    import gc
+    import time
+
+    from tests.helpers.runtime_guard import mappings_now
+
+    config = request.config
+    note = {
+        "worker": getattr(config, "workerinput", {}).get(
+            "workerid", "the controller"
+        ),
+        "file": request.node.nodeid,
+        "start": mappings_now(),
+    }
+    began = time.monotonic()
+    yield
+    note["seconds"] = round(time.monotonic() - began, 3)
+    note["end"] = mappings_now()
+    jax.clear_caches()
+    gc.collect()
+    note["after"] = mappings_now()
+    config.stash.setdefault(_FILE_NOTES, []).append(note)
+
+
+def _session_leftovers(where, sentinel):
     """What this process would take into its exit: (message or None)
-    after killing the children it names."""
+    after killing the children it names.  The ``sentinel`` is ended and
+    not counted."""
     import threading
     import time
 
@@ -89,6 +136,9 @@ def _session_leftovers(where):
     from tests.helpers.runtime_guard import leftovers_message
 
     deadline = time.monotonic() + LEFTOVER_GRACE_S
+    if sentinel is not None:
+        sentinel.kill()
+        sentinel.wait()
     _, alive = psutil.wait_procs(
         psutil.Process().children(recursive=True), timeout=LEFTOVER_GRACE_S
     )
@@ -112,10 +162,15 @@ def _session_leftovers(where):
 
 def pytest_testnodedown(node, error):
     """The controller's half: keep what a worker's session-end guard
-    sent with its last message."""
-    msg = getattr(node, "workeroutput", {}).get("leftovers")
-    if msg:
-        node.config.stash.setdefault(_LEFTOVERS, []).append(msg)
+    and its files' notes sent with its last message."""
+    output = getattr(node, "workeroutput", {})
+    if output.get("leftovers"):
+        node.config.stash.setdefault(_LEFTOVERS, []).append(
+            output["leftovers"]
+        )
+    node.config.stash.setdefault(_FILE_NOTES, []).extend(
+        output.get("file_notes", [])
+    )
 
 
 @pytest.hookimpl(trylast=True)
@@ -128,22 +183,45 @@ def pytest_sessionfinish(session, exitstatus):
     summary line, and the failure mode is an opaque rc=124.  Here each is
     named, the children are killed, and the run fails at once
     (tests/helpers/runtime_guard.py).  A worker sends its message to the
-    controller; the controller looks last, when its workers are down."""
+    controller; the controller looks last, when its workers are down,
+    and there holds their files' notes to the mappings guard too: a
+    worker that stood above half of ``vm.max_map_count`` fails the run
+    by the files that added most."""
+    from tests.helpers.runtime_guard import mappings_limit, mappings_message
+
     config = session.config
     worker_id = getattr(config, "workerinput", {}).get("workerid")
-    msg = _session_leftovers(worker_id or "the controller")
+    msg = _session_leftovers(
+        worker_id or "the controller", config.stash.get(_SENTINEL, None)
+    )
     if worker_id is not None:
         if msg:
             config.workeroutput["leftovers"] = msg
+        config.workeroutput["file_notes"] = config.stash.get(_FILE_NOTES, [])
         return
     found = config.stash.get(_LEFTOVERS, []) + ([msg] if msg else [])
+    crept = mappings_message(
+        config.stash.get(_FILE_NOTES, []), mappings_limit()
+    )
+    if crept:
+        found.append(crept)
     if not found:
         return
     reporter = config.pluginmanager.get_plugin("terminalreporter")
+    reporter.ensure_newline()
     for m in found:
         for line in m.splitlines():
             reporter.write_line(line, red=True)
     session.exitstatus = pytest.ExitCode.TESTS_FAILED
+
+
+def pytest_terminal_summary(terminalreporter, config):
+    """Where the mappings and the seconds went: what the next writer
+    reads from the log instead of guessing."""
+    from tests.helpers.runtime_guard import mappings_tables
+
+    for line in mappings_tables(config.stash.get(_FILE_NOTES, [])):
+        terminalreporter.write_line(line)
 
 
 @pytest.fixture(autouse=True)

@@ -18,6 +18,8 @@ from areal_tpu.models import transformer
 from areal_tpu.models.config import tiny_config
 from areal_tpu.observability import tracing
 from areal_tpu.observability.latency import (
+    SLO_BUCKET_LO,
+    SLO_BUCKET_RATIO,
     SLO_REL_ERROR_BOUND,
     LatencyDigest,
 )
@@ -239,6 +241,12 @@ def test_two_engines_digests_merge_within_bound_of_pooled_records(mode):
         assert fleet.count == len(raw) > 0
         for q in (0.5, 0.95, 0.99):
             emp = raw[max(0, math.ceil(q * len(raw)) - 1)]
+            if emp < SLO_BUCKET_LO / SLO_BUCKET_RATIO:
+                # under the covered range (a tiny engine's 28 us between
+                # two tokens) a value clamps into the lowest bucket: what
+                # observability/latency.py documents there
+                assert fleet.quantile(q) <= SLO_BUCKET_LO, (field, q)
+                continue
             assert abs(fleet.quantile(q) - emp) <= (
                 SLO_REL_ERROR_BOUND * emp + 1e-12
             ), (field, q)
