@@ -134,41 +134,6 @@ class RolloutWorkerConfig:
 
 
 @dataclasses.dataclass
-class SpecDecodeConfig:
-    """Self-speculative decoding on the paged serving path (default OFF).
-
-    Each row drafts its own continuation by n-gram / prompt-lookup over
-    its prompt+output history (no draft model — RL math/code traces are
-    repetitive enough), and one batched paged-prefill pass verifies up
-    to ``max_draft_tokens`` drafts per step.  Output is token-identical
-    to plain GREEDY decode (the engine silently disables the feature
-    under non-greedy sampling or a dense cache); rows whose
-    acceptance-rate EMA falls below ``min_accept_rate`` drop back to
-    plain chunked decode, bounding the worst case.  See
-    ``engine/spec_decode.py`` and docs/async_pipeline.md."""
-
-    enabled: bool = False
-    # drafts proposed per verify step (the verify window is this + 1:
-    # the pending token rides along); each step emits 1..this+1 tokens.
-    # Keep at 2^n - 1: windows bucket to powers of two, so 8 drafts pad
-    # every verify to 16 positions and double its compute for nothing
-    max_draft_tokens: int = 7
-    # n-gram sizes tried for the history lookup (longest first)
-    ngram_max: int = 3
-    ngram_min: int = 1
-    # acceptance-rate EMA below which a row falls back to plain decode;
-    # None = engine/spec_decode.py's DEFAULT_SPEC_MIN_ACCEPT_RATE
-    min_accept_rate: Optional[float] = None
-    ema_decay: float = 0.9
-    # verifies before the fallback threshold may fire
-    warmup_verifies: int = 4
-    # cost of one verify pass in plain-decode-step units (the per-step
-    # batch vote's c); None = engine/spec_decode.py's
-    # DEFAULT_SPEC_VERIFY_COST (no chip run has measured it)
-    verify_cost_over_decode_step: Optional[float] = None
-
-
-@dataclasses.dataclass
 class GenServerConfig:
     worker_name: str
     model: ModelAbstraction = None
@@ -182,8 +147,8 @@ class GenServerConfig:
     # granularity
     chunk_size: int = 64
     temperature: float = 1.0
-    # greedy (argmax) decoding server-wide; required for spec_decode's
-    # exactness guarantee (eval servers, deterministic replay)
+    # greedy (argmax) decoding server-wide (eval servers, deterministic
+    # replay)
     greedy: bool = False
     # KV layout: "auto" uses the paged block pool at kv_cache_len >= 2k
     # (global-attention models), dense per-row cache below; see
@@ -299,12 +264,6 @@ class GenServerConfig:
     # manager's kv_fabric_min_prefix_tokens gates the hint fleet-side;
     # this is the engine's own floor.
     prefix_pull_min_tokens: int = 256
-    # self-speculative n-gram decoding on the paged path (default off);
-    # maps SGLang's ngram speculative mode / vLLM's ngram
-    # speculative_config — see SpecDecodeConfig + docs
-    spec_decode: SpecDecodeConfig = dataclasses.field(
-        default_factory=SpecDecodeConfig
-    )
     # request-level SLO plane (observability/latency.py): per-request
     # latency decomposition (schedule/admission wait, TTFT, TPOT,
     # swap/preempt stall) streamed into mergeable percentile digests and

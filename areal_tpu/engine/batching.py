@@ -42,24 +42,12 @@ from areal_tpu.base import datapack
 
 DEFAULT_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384, 32768)
 
-#: speculative-decode verify windows are tiny (pending token + a handful
-#: of drafts); their own bucket ladder keeps the compile count at
-#: log2(max window) while a short-draft dispatch never pays a full
-#: max-window forward
-SPEC_WINDOW_BUCKETS = (2, 4, 8, 16, 32, 64)
-
 
 def bucket_len(n: int, buckets: Sequence[int] = DEFAULT_BUCKETS) -> int:
     for b in buckets:
         if n <= b:
             return b
     raise ValueError(f"sequence length {n} exceeds largest bucket")
-
-
-def spec_window_bucket(n: int) -> int:
-    """Bucketed verify-window width (pending token + drafts) for the
-    speculative-decode dispatch; distinct widths compile once each."""
-    return bucket_len(n, SPEC_WINDOW_BUCKETS)
 
 
 def pad_rows(n: int, multiple: int) -> int:

@@ -121,7 +121,7 @@ def generate_loop(
     # sampling is keyed on (row, absolute position of the sampled token):
     # the random stream is a pure function of (rng, row, position), never
     # of how many sampling calls preceded it — the same contract as the
-    # serving engine's, so chunking/speculation cannot perturb streams
+    # serving engine's, so chunking cannot perturb streams
     rows = jnp.arange(B, dtype=jnp.int32)
     n_prev0 = jnp.zeros((B,), jnp.int32)
     first_tok, first_logp = sample_logits_keyed(

@@ -28,16 +28,9 @@ Design:
   pause -> load -> resume semantics).  ``version_start``/``version_end``
   record the weight versions a request sampled under (decoupled PPO's
   staleness bookkeeping).
-* ``spec_decode_params`` (paged + greedy) turns on SELF-SPECULATIVE
-  decoding: rows draft their own continuations by n-gram lookup over
-  their token history and one batched paged-prefill VERIFY pass scores
-  up to ``max_draft_tokens`` drafts per step (engine/spec_decode.py) —
-  token-identical to plain greedy decode, with a measured per-step
-  batch vote and per-row acceptance-EMA fallback bounding the worst
-  case at the plain chunked path.  Sampling randomness is keyed on
-  (request seed, absolute position) from a fixed base key, so
-  chunking / row placement /
-  pipelining / acceptance length can never perturb sampled streams.
+* Sampling randomness is keyed on (request seed, absolute position) from
+  a fixed base key, so chunking / row placement / pipelining can never
+  perturb sampled streams.
 * ``cache_mode="paged"`` (auto at >= 2k context, always for a stack stated
   by kind): a shared BLOCK POOL + per-row block tables (models/paged.py —
   the paged/radix-cache role of the reference's SGLang server).  Pages are
@@ -68,8 +61,7 @@ import numpy as np
 
 from areal_tpu.api import model_api
 from areal_tpu.base import jax_compat, logging_
-from areal_tpu.engine import spec_decode
-from areal_tpu.engine.batching import bucket_len, spec_window_bucket
+from areal_tpu.engine.batching import bucket_len
 from areal_tpu.engine.kv_pages import (  # noqa: F401 - callers name it here
     GONE,
     KeptFills,
@@ -253,10 +245,6 @@ class _Row:
     # freed-and-reused between dispatch and harvest (park->resume, or
     # finish->new admission) carries a different epoch and is skipped
     epoch: int = 0
-    # speculative decoding: the row's n-gram draft index + acceptance EMA
-    # (lazily created; survives park/resume/preempt — history never
-    # rewrites).  None until the row first drafts.
-    spec: Optional[spec_decode.SpecRowState] = None
     # SLO latency decomposition (monotonic-clock stamps; telemetry only —
     # never read by dispatch decisions, so SPMD lockstep is untouched):
     # submit -> admit = admission wait, submit -> first token = TTFT,
@@ -369,17 +357,10 @@ class _InflightChunk:
     it.  ``snapshot`` is the dispatch-time ``(row_id, epoch)`` occupancy:
     the harvest folds outputs ONLY into rows whose epoch still matches
     (a slot freed-and-reused mid-ring carries a different epoch and is
-    skipped — the harvest-identity invariant).
-
-    ``spec_meta`` marks a speculative VERIFY chunk: ``{row_id: (qid,
-    n_drafted)}`` for its participants.  Verify chunks share the decode
-    chunks' output signature/semantics, so the harvest folds them in
-    identically — the meta only drives acceptance bookkeeping (EMA,
-    counters, the ``decode.verify`` span)."""
+    skipped — the harvest-identity invariant)."""
 
     arrs: Tuple[Any, ...]
     snapshot: List[Tuple[int, int]]
-    spec_meta: Optional[Dict[int, Tuple[str, int]]] = None
     #: first tokens of the rows that this chunk is the first to decode:
     #: folded at its harvest, before its own outputs are waited for
     first_tokens: List[_FirstTokens] = dataclasses.field(default_factory=list)
@@ -482,7 +463,7 @@ def _decode_chunk(
     # position-keyed sampling: ``rng`` is the engine's FIXED base key and
     # each draw is keyed on (request seed, absolute position), so the
     # random stream never depends on how many chunk dispatches produced
-    # a position (pipeline depth / chunk size / speculative tail steps)
+    # a position (pipeline depth / chunk size)
     # nor on which cache row the request landed in
     def keyed_sample(logits, _sub, positions, seeds):
         return sample_logits_keyed(
@@ -583,7 +564,6 @@ class ContinuousBatchingEngine:
         prefix_cache_capacity_frac: float = 0.5,
         prefix_cache_min_tokens: int = 1,
         prefix_cache_host_bytes: int = 0,
-        spec_decode_params: Optional[spec_decode.SpecDecodeParams] = None,
         slo_tracking: bool = True,
         server_name: str = "",
         handoff_streaming: bool = False,
@@ -614,17 +594,9 @@ class ContinuousBatchingEngine:
         (request seed, absolute position) from a fixed base key
         (sampling.py
         ``sample_logits_keyed``), so the stream is a pure function of
-        the seed — how many chunk/speculative dispatches produced a
-        position cannot perturb it.
+        the seed — how many chunk dispatches produced a position cannot
+        perturb it.
 
-        ``spec_decode_params`` (paged + greedy only) enables
-        self-speculative decoding: rows draft their own continuations by
-        n-gram lookup over their token history and a batched paged
-        verify pass (engine/spec_decode.py) scores up to
-        ``max_draft_tokens`` drafts per step at prefill cost — output is
-        token-identical to plain greedy decode, and rows whose
-        acceptance EMA drops below the dispatch threshold fall back to
-        plain chunked decode.
         ``kv_pool_tokens`` sizes the paged pool (default: dense-equivalent
         ``max_batch * kv_cache_len``; set smaller to serve long contexts a
         dense cache could never reserve).  ``kv_window_pool_tokens`` sizes
@@ -654,7 +626,7 @@ class ContinuousBatchingEngine:
         HBM per cached token, so ~2x live rows / prefix-cache capacity
         at the same pool budget, at the cost of storage-rounding error
         (reads dequantize inline; attention math stays in model dtype).
-        Every pool path carries the scales: fill/decode/verify writes
+        Every pool path carries the scales: fill/decode writes
         quantize at the scatter, COW tail copies, host-tier spills, and
         swap-ins move int8 bytes + scales together.
         tests/engine/test_kv_quant.py pins the token-quality delta;
@@ -792,11 +764,9 @@ class ContinuousBatchingEngine:
         self._routed_prompts: Dict[Tuple[int, ...], np.ndarray] = {}
         # what an option asks for that one of the model's cache kinds rules
         # out refuses by name here, where the option is set
-        spec = spec_decode_params is not None and spec_decode_params.enabled
         asked = {
             "the dense (unpaged) KV cache": cache_mode == "dense",
             "a tensor- or expert-parallel serving mesh": mesh is not None,
-            "speculative verify": spec,
             "int8 KV storage": kv_cache_dtype == "int8",
             "int8 serving weights": serving_weight_dtype == "int8",
             "prefix-cache host spill": prefix_cache_host_bytes > 0,
@@ -1019,36 +989,6 @@ class ContinuousBatchingEngine:
         self.stop_tokens = tuple(sorted(stop))
         self.version = 0
 
-        # speculative decoding: paged-path + greedy-exactness gates
-        self._spec: Optional[spec_decode.SpecDecodeParams] = None
-        if spec_decode_params is not None and spec_decode_params.enabled:
-            if not self.paged:
-                logger.warning(
-                    "spec_decode requested but cache_mode resolved to "
-                    "dense; speculative decoding runs on the paged path "
-                    "only — disabled"
-                )
-            elif not self.sampling.greedy:
-                logger.warning(
-                    "spec_decode requested with non-greedy sampling; "
-                    "draft verification is exact under greedy decode "
-                    "only — disabled"
-                )
-            else:
-                self._spec = spec_decode_params
-        self.spec_drafted_total = 0
-        self.spec_accepted_total = 0
-        self.spec_rejected_total = 0
-        self.spec_verify_chunks_total = 0
-        self.spec_fallback_rows_total = 0
-        # (row, verify) participations WITH drafts — the denominator for
-        # per-row emitted-tokens-per-pass (a verify chunk batches many
-        # rows, so verify_chunks_total is the wrong unit for that)
-        self.spec_draft_row_passes_total = 0
-        # recent per-verify acceptance fractions, drained by the worker
-        # into the areal_inference_spec_accept_rate histogram
-        self._spec_accept_samples: Deque[float] = deque(maxlen=1024)
-
         #: how a decode step under an indexer attends its chosen set, which
         #: the program decides from the table's shape when it is traced
         #: (``sparse_attention.decode_reads_masked``): "masked" in the
@@ -1059,8 +999,7 @@ class ContinuousBatchingEngine:
         with jax.default_device(device) if device is not None else _nullctx():
             # ONE fixed base key for every sampling draw: draws are keyed
             # on (request seed, position) from it, so streams are
-            # invariant to
-            # chunking / pipeline depth / speculative acceptance length
+            # invariant to chunking / pipeline depth
             self._seed = seed
             self._sample_base_rng = _sample_base_rng(seed)
             if self.paged:
@@ -1243,8 +1182,8 @@ class ContinuousBatchingEngine:
         self._epoch_counter = 0  # admission/resume stamp source
         # lifetime tokens folded in by harvests; step() reports its own
         # delta of this so tokens harvested by MID-STEP ring drains
-        # (speculative re-drafting, weight swaps, preemption flushes)
-        # are never lost from the step's return value
+        # (weight swaps, preemption flushes) are never lost from the
+        # step's return value
         self._tokens_harvested_total = 0
         # the in-flight chunk ring: dispatched-but-unharvested decode
         # chunks, FIFO, at most ``pipeline_depth`` deep
@@ -4047,10 +3986,9 @@ class ContinuousBatchingEngine:
 
     def _first_tokens_at_once(self, targets) -> bool:
         """Whether a distribution's first tokens are fetched before
-        anything else is dispatched: speculation drafts from a row's
-        tokens at its first dispatch, and a request that is handed off
-        parks (and is exported) on its first token."""
-        return self._spec is not None or any(
+        anything else is dispatched: a request that is handed off parks
+        (and is exported) on its first token."""
+        return any(
             (tgt.req.metadata or {}).get("handoff_to") for _, tgt, _ in targets
         )
 
@@ -4557,11 +4495,6 @@ class ContinuousBatchingEngine:
         vLLM's recompute preemption).  Returns (blocks allocated, rows
         preempted)."""
         W = self.chunk_size
-        if self._spec is not None:
-            # a speculative verify window may write up to max_draft + 1
-            # slots in one dispatch; coverage must hold for whichever
-            # chunk kind this step dispatches
-            W = max(W, self._spec.max_draft_tokens + 1)
         # every un-harvested chunk that snapshot a row may advance it by
         # up to W more tokens the host has not folded in yet (row_id
         # match only: the device does not know epochs — any chunk
@@ -4870,180 +4803,6 @@ class ContinuousBatchingEngine:
             out_t, out_l, emitted, self.active, self.cur_tokens, snapshot
         )
 
-    # -- speculative decoding (paged path) -----------------------------------
-
-    def _spec_row_state(self, row: _Row) -> spec_decode.SpecRowState:
-        if row.spec is None:
-            row.spec = spec_decode.SpecRowState()
-        return row.spec
-
-    def _dispatch_spec_step(self) -> bool:
-        """One speculative dispatch round, decided by a per-step BATCH
-        VOTE: either every live row rides ONE verify window (rows with
-        drafts verify them; draftless/fallback rows ride along with a
-        0-length draft, whose position-0 correction IS a plain decode
-        step), or every live row takes a plain decode chunk — never a
-        mix, because a mixed step serializes a full W-step chunk with
-        each verify pass and fragments the batch both dispatches live
-        on.  The vote is measured-dispatch logic (engine/dispatch.py):
-        a verify pass costs ``verify_cost_over_decode_step`` plain
-        steps, so it wins iff the EMA-expected emission beats that per
-        live row.  Rows that keep missing are excluded by the per-row
-        EMA fallback and draft-miss cooldowns, so a non-repetitive wave
-        quickly votes plain every step and keeps the spec-off pipeline
-        (including its full ring depth — the quiesce below only fires
-        when a row actually wants to draft).  Returns True if anything
-        was dispatched."""
-        assert self._spec is not None
-        spec = self._spec
-        candidates = {
-            rid for rid, r in enumerate(self.rows)
-            if r is not None and not r.parked and not r.filling
-            and self._spec_row_state(r).wants_draft(self._step_seq)
-        }
-        # drafting reads the exact host history: fold in any un-harvested
-        # chunk covering a row that is about to draft
-        while self._ring and any(
-            rid in candidates
-            for ch in self._ring
-            for rid, _ in ch.snapshot
-        ):
-            self._harvest_oldest()
-        live: List[int] = []
-        drafts: Dict[int, List[int]] = {}
-        attempted: List[int] = []
-        expected = 0.0
-        for rid, row in enumerate(self.rows):
-            if row is None or row.parked or row.filling:
-                continue
-            live.append(rid)
-            st = self._spec_row_state(row)
-            if rid in candidates:
-                attempted.append(rid)
-                d = st.draft(row.prompt + row.generated, spec)
-                if d:
-                    drafts[rid] = d
-                    expected += 1.0 + st.ema * len(d)
-                    continue
-            expected += 1.0
-        if not live:
-            return False
-        spec_won = bool(drafts) and (
-            expected >= spec.verify_cost_over_decode_step * len(live)
-        )
-        # a draft attempt was "productive" only if it hit AND the batch
-        # voted spec: misses and vote losses both cool the row down, so
-        # a lone drafter in a spec-hostile batch stops forcing the ring
-        # quiesce every step (the pipeline keeps its depth)
-        for rid in attempted:
-            self.rows[rid].spec.note_draft_result(
-                spec_won and rid in drafts, self._step_seq
-            )
-        if spec_won:
-            # a verify window attends each row's context once
-            with self._phases.phase("areal.engine.decode.dispatch") as span:
-                self._count_dispatch(span, [(i, 0) for i in live], 1)
-                self._dispatch_verify_chunk(live, drafts)
-        else:
-            self._dispatch_chunk_paged()
-        return True
-
-    def _dispatch_verify_chunk(
-        self, live_rows: List[int], drafts: Dict[int, List[int]]
-    ):
-        """Dispatch ONE batched verify window over every live row
-        (engine/spec_decode.paged_verify_chunk): rows in ``drafts``
-        verify their proposals; the rest ride with a 0-length draft
-        (their correction token is exactly one plain decode step, so
-        nobody stalls).  The window width buckets to the longest draft
-        this step, the outputs enter the ring as an ordinary chunk
-        (async fetch started at dispatch), and acceptance bookkeeping
-        happens at harvest."""
-        snapshot = [(i, self.rows[i].epoch) for i in live_rows]
-        C = spec_window_bucket(
-            1 + max(len(d) for d in drafts.values())
-        )
-        draft_arr = np.zeros((self.max_batch, C - 1), np.int32)
-        draft_lens = np.zeros((self.max_batch,), np.int32)
-        parts = np.zeros((self.max_batch,), bool)
-        meta: Dict[int, Tuple[str, int]] = {}
-        for rid in live_rows:
-            parts[rid] = True
-            d = drafts.get(rid)
-            if not d:
-                continue
-            d = d[: C - 1]
-            draft_arr[rid, : len(d)] = d
-            draft_lens[rid] = len(d)
-            qid = self.rows[rid].req.qid
-            meta[rid] = (qid, len(d))
-            self.tracer.event(qid, "decode.draft", row=rid, tokens=len(d))
-            self.tracer.span_begin(
-                qid, "decode.verify", row=rid, drafted=len(d)
-            )
-        tables = self._pages.upload()
-        out = spec_decode.paged_verify_chunk(
-            self.params,
-            self.k_pool,
-            self.v_pool,
-            self.cfg,
-            tables,
-            self.kv_lengths,
-            self.cur_tokens,
-            jnp.asarray(draft_arr),
-            jnp.asarray(draft_lens),
-            jnp.asarray(parts),
-            self.active,
-            self.budgets,
-            max_draft=C - 1,
-            stop_tokens=self.stop_tokens,
-            sampling=self.sampling,
-            use_kernel=self._use_paged_kernel,
-            max_len=self.kv_cache_len,
-            mesh=self.mesh,
-            kv_axis=getattr(self, "_kv_axis", None),
-            k_scale=self.k_scale,
-            v_scale=self.v_scale,
-        )
-        if self._kv_quant:
-            self.k_scale, self.v_scale = out[9], out[10]
-        (
-            self.k_pool,
-            self.v_pool,
-            self.kv_lengths,
-            out_t,
-            out_l,
-            emitted,
-            cur,
-            self.active,
-            self.budgets,
-        ) = out[:9]
-        self.cur_tokens = cur
-        self.spec_verify_chunks_total += 1
-        self.spec_drafted_total += int(draft_lens.sum())
-        self._enqueue_chunk(
-            out_t, out_l, emitted, self.active, self.cur_tokens, snapshot,
-            spec_meta=meta,
-        )
-
-    def spec_stats(self) -> Dict[str, int]:
-        """Cumulative speculative-decoding counters (worker scrape)."""
-        return {
-            "drafted_total": self.spec_drafted_total,
-            "accepted_total": self.spec_accepted_total,
-            "rejected_total": self.spec_rejected_total,
-            "verify_chunks_total": self.spec_verify_chunks_total,
-            "draft_row_passes_total": self.spec_draft_row_passes_total,
-            "fallback_rows_total": self.spec_fallback_rows_total,
-        }
-
-    def drain_spec_accept_samples(self) -> List[float]:
-        """Pop the recent per-verify acceptance fractions (the worker
-        feeds them to the acceptance-rate histogram)."""
-        out = list(self._spec_accept_samples)
-        self._spec_accept_samples.clear()
-        return out
-
     def _admit(self) -> int:
         """Returns the rows admitted."""
         if self.hold_admissions:
@@ -5340,8 +5099,7 @@ class ContinuousBatchingEngine:
         )
 
     def _enqueue_chunk(
-        self, out_t, out_l, emitted, active_dev, cur_dev, snapshot,
-        spec_meta=None, extra=(),
+        self, out_t, out_l, emitted, active_dev, cur_dev, snapshot, extra=(),
     ):
         """Append a dispatched chunk to the in-flight ring and START its
         device->host output copy.  The copy rides under the device time
@@ -5365,7 +5123,7 @@ class ContinuousBatchingEngine:
         # their first tokens are folded at its harvest, ahead of its own
         self._ring.append(
             _InflightChunk(
-                arrs=arrs, snapshot=snapshot, spec_meta=spec_meta,
+                arrs=arrs, snapshot=snapshot,
                 first_tokens=self._first_tokens,
             )
         )
@@ -5451,15 +5209,11 @@ class ContinuousBatchingEngine:
         snapshot = chunk.snapshot
         n_tokens = 0
         t_harvest = time.monotonic()  # chunk's tokens reach the host NOW
-        spec_meta = chunk.spec_meta
         for row_id, epoch in snapshot:
             row = self.rows[row_id]
             # skip freed-and-reused slots: the dispatch-time occupant is
             # gone and this chunk says nothing about the new one
             if row is None or row.parked or row.epoch != epoch:
-                if spec_meta is not None and row_id in spec_meta:
-                    qid, _ = spec_meta[row_id]
-                    self.tracer.span_end(qid, "decode.verify", emitted=0)
                 continue
             cols = emitted[row_id]
             toks = out_t[row_id][cols].tolist()
@@ -5488,23 +5242,6 @@ class ContinuousBatchingEngine:
             if toks and self._slo_enabled:
                 self._slo_first_token(row, now=t_harvest)
                 row.t_last = t_harvest
-            if spec_meta is not None and row_id in spec_meta:
-                qid, drafted = spec_meta[row_id]
-                # every emitted token but the last is a confirmed draft;
-                # the last is the verifier's own (correction or bonus)
-                n_acc = max(0, len(toks) - 1)
-                self.spec_draft_row_passes_total += 1
-                self.spec_accepted_total += n_acc
-                self.spec_rejected_total += max(0, drafted - n_acc)
-                self._spec_accept_samples.append(n_acc / max(drafted, 1))
-                if row.spec is not None and row.spec.observe(
-                    n_acc, drafted, self._spec
-                ):
-                    self.spec_fallback_rows_total += 1
-                self.tracer.span_end(
-                    qid, "decode.verify",
-                    accepted=n_acc, emitted=len(toks),
-                )
             if toks:
                 self._stream_push(row, toks)
                 self.tracer.event(
@@ -5593,8 +5330,8 @@ class ContinuousBatchingEngine:
         controllers replaying the command stream take identical branches.
         Returns the number of tokens emitted — every token any harvest
         folded in during this step, including mid-step ring drains
-        (speculative re-drafting, weight swaps, preemption flushes); 0
-        on ring-filling warm-up steps."""
+        (weight swaps, preemption flushes); 0 on ring-filling warm-up
+        steps."""
         self._step_seq += 1
         h0 = self._tokens_harvested_total
         if self._paused.is_set():
@@ -5641,11 +5378,8 @@ class ContinuousBatchingEngine:
                         and len(self._ring) < self.pipeline_depth
                         and self._worth_dispatching()
                     ):
-                        if self._spec is not None:
-                            dispatched = self._dispatch_spec_step()
-                        else:
-                            self._dispatch_chunk_paged()
-                            dispatched = True
+                        self._dispatch_chunk_paged()
+                        dispatched = True
                 else:
                     with self._phases.phase("areal.engine.admit") as sp:
                         admitted = self._admit()

@@ -346,23 +346,16 @@ class StatefulModelUnsupported(CacheKindRefuses):
     asked of a model whose layers also keep a recurrent state per
     sequence (``cfg.n_mamba_layers > 0``): that state exists at the end
     of what was computed and nowhere else, so it cannot be cut at a page
-    boundary, rewound after a rejected draft, or rebuilt from KV pages
-    another server sends.  Its message lists the cache kinds the model
-    holds (the state slots first: they are what refuses)."""
+    boundary or rebuilt from KV pages another server sends.  Its message
+    lists the cache kinds the model holds (the state slots first: they
+    are what refuses)."""
 
 
 #: the cache kinds that rule something out -> (why, as the message says it;
 #: the error raised)
 STATE_SLOTS, WINDOW_POOL, BY_KIND = "state slots", "window pool", "by kind"
 INDEX_POOL = "index pool"
-LOOPED = "looped stack"
 _KINDS = {
-    LOOPED: (
-        "the loop refuses it (loop_steps > 1: a verify window's rewinding "
-        "of loop_steps caches a layer is held by no test, and a draft "
-        "costs loop_steps passes of every layer: engine/spec_decode.py)",
-        CacheKindRefuses,
-    ),
     INDEX_POOL: (
         "the index pool refuses it (each latent page has a page of index "
         "keys of another width beside it, which only the two programs of "
@@ -386,13 +379,12 @@ _KINDS = {
 }
 
 #: feature -> the cache kinds that rule it out (the first one held refuses).
-#: The first five are what the two programs of a stack stated by kind do
+#: The first four are what the two programs of a stack stated by kind do
 #: not write, whatever its kinds; the last three move or keep whole rows'
 #: pages outside their pool, which assumes ONE table of per-token blocks.
 REFUSED = {
     "the dense (unpaged) KV cache": (STATE_SLOTS, BY_KIND),
     "a tensor- or expert-parallel serving mesh": (STATE_SLOTS, INDEX_POOL, BY_KIND),
-    "speculative verify": (STATE_SLOTS, BY_KIND, LOOPED),
     "int8 KV storage": (STATE_SLOTS, INDEX_POOL, BY_KIND),
     "int8 serving weights": (STATE_SLOTS, BY_KIND),
     "prefix-cache host spill": (STATE_SLOTS, INDEX_POOL, WINDOW_POOL),
@@ -417,10 +409,6 @@ def kinds_held(cfg) -> Dict[str, str]:
         held[WINDOW_POOL] = (
             "a stack with latent window layers" if cfg.is_latent_window
             else "a stack with window layers"
-        )
-    if cfg.loop_steps > 1:
-        held[LOOPED] = (
-            f"a stack run {cfg.loop_steps} times with the same weights"
         )
     if cfg.is_hybrid:
         held[BY_KIND] = (

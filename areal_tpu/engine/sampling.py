@@ -9,13 +9,12 @@ Two samplers share the filtering/logprob math:
   stream depends on HOW MANY sampling calls preceded this one — fine for
   the static-batch generator, a hazard for the serving engine where the
   number of dispatches producing a position varies (pipeline depth,
-  chunked continuations, speculative tail steps).
+  chunked continuations).
 * :func:`sample_logits_keyed` — the key for each row is derived from
   ``(base_key, row, absolute_position)`` by ``fold_in``, so the draw for
   "row r's token at position p" is a pure function of the seed: the
-  stream is invariant to chunk size, pipeline depth, and how many
-  speculative/verify steps produced the position.  Sampling uses the
-  Gumbel-max trick over the same filtered logits ``sample_logits``
+  stream is invariant to chunk size and pipeline depth.  Sampling uses
+  the Gumbel-max trick over the same filtered logits ``sample_logits``
   samples from (``categorical`` is Gumbel-max internally), so the two
   samplers draw from identical distributions.
 """
@@ -120,9 +119,9 @@ def sample_logits_keyed(
     """Position-keyed sampling: identity r's draw at position p depends
     only on ``(base_rng, r, p)`` — never on how many prior sampling
     calls the run happened to make.  This is what makes the serving
-    engine's random stream invariant to chunk size / pipeline depth /
-    speculative acceptance length (the split-sequence hazard the engine
-    docstring used to carry).  Same distribution as
+    engine's random stream invariant to chunk size / pipeline depth
+    (the split-sequence hazard the engine docstring used to carry).
+    Same distribution as
     :func:`sample_logits` (Gumbel-max over the identically filtered
     logits).
 

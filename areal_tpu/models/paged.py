@@ -551,16 +551,14 @@ def paged_window_forward(
     """Forward a short token WINDOW for F rows over their cached paged
     prefixes: in-window causal self-attention merged online with the
     paged kernel's partials over ``[0, start)``, window KV written into
-    the rows' pool blocks (invalid positions dropped).  Shared core of
-    chunked prefill (:func:`paged_fill_chunk`) and the speculative-decode
-    verify step (engine/spec_decode.py) — verify IS a batched paged
-    prefill of the draft window, so both paths ride the same attention
-    math and the same pool write.  Returns ``(x [F, C, D], k_pool,
-    v_pool, k_scale, v_scale)`` with ``x`` the final hidden states
-    (pre-head); the scales pass through as None on unquantized pools.
+    the rows' pool blocks (invalid positions dropped).  The core of
+    chunked prefill (:func:`paged_fill_chunk`).  Returns ``(x [F, C, D],
+    k_pool, v_pool, k_scale, v_scale)`` with ``x`` the final hidden
+    states (pre-head); the scales pass through as None on unquantized
+    pools.
 
     ``valid`` is a PREFIX mask: row f's first ``valid[f].sum()`` window
-    positions, which is what both callers build.
+    positions, which is what the caller builds.
 
     The pools are read-only operands of the layer scan, in the layout
     the kernel reads them in: no layer reads what this window writes
@@ -579,7 +577,7 @@ def paged_window_forward(
     L, _, _, _, hd = k_pool.shape
     positions = starts[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     # masked rows must stream zero prefix blocks (their ``starts`` may be
-    # any live length — e.g. non-participant rows of a verify window)
+    # any live length)
     read_lens = jnp.where(valid[:, 0], starts, 0)
     x = _embed(params, cfg, tokens, positions)
     rope_cs = (

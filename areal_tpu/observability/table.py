@@ -213,43 +213,6 @@ METRIC_TABLE = [
         "Prefix-cache blocks currently resident in the host tier",
     ),
     MetricSpec(
-        "areal_inference_spec_draft_tokens_total",
-        "counter",
-        "Draft tokens proposed by self-speculative n-gram drafting "
-        "(per verify window, before verification)",
-    ),
-    MetricSpec(
-        "areal_inference_spec_accepted_tokens_total",
-        "counter",
-        "Draft tokens confirmed by the batched paged verify pass "
-        "(each saves one full decode step)",
-    ),
-    MetricSpec(
-        "areal_inference_spec_rejected_tokens_total",
-        "counter",
-        "Draft tokens the verify pass diverged from (truncated at the "
-        "first mismatch; the verifier's own token is emitted instead)",
-    ),
-    MetricSpec(
-        "areal_inference_spec_verify_chunks_total",
-        "counter",
-        "Speculative verify windows dispatched (each is one batched "
-        "paged prefill over the participating rows' drafts)",
-    ),
-    MetricSpec(
-        "areal_inference_spec_fallback_rows_total",
-        "counter",
-        "Rows whose acceptance-rate EMA fell below the spec-decode "
-        "threshold and dropped back to plain chunked decode",
-    ),
-    MetricSpec(
-        "areal_inference_spec_accept_rate",
-        "histogram",
-        "Per-verify-window acceptance fraction (accepted / drafted) — "
-        "the live readout of whether self-drafting pays on this "
-        "workload",
-    ),
-    MetricSpec(
         "areal_inference_kv_quant_storage_bits",
         "gauge",
         "Bits per stored KV element in the serving cache (8 = int8 "
@@ -960,19 +923,6 @@ TRACE_TABLE = [
         "(attrs: row, epoch, n_tokens, step)",
     ),
     TraceSpec(
-        "decode.draft",
-        "event",
-        "Self-speculative n-gram draft proposed for a row "
-        "(attrs: row, tokens)",
-    ),
-    TraceSpec(
-        "decode.verify",
-        "span",
-        "One speculative verify window, dispatch to harvest: a batched "
-        "paged prefill of the row's draft (attrs: row, drafted, "
-        "accepted, emitted)",
-    ),
-    TraceSpec(
         "swap.stage",
         "span",
         "Staged weight restore on the generation server: snapshot "
@@ -1185,7 +1135,7 @@ TRACE_TABLE = [
     TraceSpec(
         "areal.engine.decode.dispatch",
         "phase",
-        "One decode chunk (or verify window) dispatched (counts: rows, "
+        "One decode chunk dispatched (counts: rows, "
         "rows_planned = the rows that hold a cached position, which the "
         "paged kernel's decode grid visits, "
         "ctx_tokens_sum = prompt + generated known to the host over the "
@@ -1553,8 +1503,8 @@ ENGINE_STEP_RECORD = {
     "rows_admitted": "Rows given a slot",
     "rows_finished": "Requests finished (parked or released)",
     "rows_preempted": "Rows preempted under pool pressure",
-    "decode_chunks": "Decode chunks (or verify windows) dispatched: 0 or "
-    "1 a step, more where a drain re-dispatches",
+    "decode_chunks": "Decode chunks dispatched: 0 or 1 a step, more where "
+    "a drain re-dispatches",
     "decode_rows": "Rows in those chunks' snapshots",
     "rows_planned": "... of which hold a cached position: the rows the "
     "paged kernel's decode grid visits (1 - rows_planned / (decode_chunks "

@@ -78,7 +78,7 @@ def format_server_registration(
 def parse_server_registration(
     value: str,
 ) -> Tuple[str, int, str, str, str]:
-    """``(addr, mesh_devices, mesh_spec_str, role, transport)`` from a
+    """``(addr, mesh_devices, mesh spec string, role, transport)`` from a
     registration value; bare-address values (older registrations) parse
     as one device, registrations without a role field parse as
     ``unified``, and ones without a transport capability parse as
@@ -215,14 +215,11 @@ class GenerationServerWorker(worker_base.Worker):
         )
         from areal_tpu.engine.inference_server import ContinuousBatchingEngine
         from areal_tpu.engine.sampling import SamplingParams
-        from areal_tpu.engine.spec_decode import resolve_spec_params
         from areal_tpu.observability import tracing
 
         # configure BEFORE the engine is built: the engine binds the
         # process tracer at construction
-        tracing.configure(
-            getattr(config, "trace", None), worker=config.worker_name
-        )
+        tracing.configure(config.trace, worker=config.worker_name)
 
         tokenizer = None
         if config.tokenizer_path:
@@ -239,7 +236,7 @@ class GenerationServerWorker(worker_base.Worker):
         # driven per-request by the ``handoff_to`` metadata the client
         # copies from its schedule response, so a unified fleet never
         # pays anything for the feature existing)
-        self._role = getattr(config, "role", "unified") or "unified"
+        self._role = config.role or "unified"
         if self._role not in SERVER_ROLES:
             raise ValueError(
                 f"unknown server role {self._role!r}; expected "
@@ -258,10 +255,7 @@ class GenerationServerWorker(worker_base.Worker):
         # fleet KV fabric: the segment transport this server registers
         # (negotiated through the registration value — the manager only
         # routes segment traffic between servers on the same transport)
-        self._transport_name = (
-            getattr(config, "segment_transport", "host-numpy")
-            or "host-numpy"
-        )
+        self._transport_name = config.segment_transport or "host-numpy"
         self._segment_transport = make_segment_transport(
             self._transport_name, self
         )
@@ -310,8 +304,7 @@ class GenerationServerWorker(worker_base.Worker):
             model.init_params, model.model_cfg.dtype
         )
         sampling = SamplingParams(
-            temperature=config.temperature,
-            greedy=getattr(config, "greedy", False),
+            temperature=config.temperature, greedy=config.greedy
         )
         self.engine = ContinuousBatchingEngine(
             model.model_cfg,
@@ -326,32 +319,21 @@ class GenerationServerWorker(worker_base.Worker):
             cache_mode=config.cache_mode,
             page_size=config.page_size,
             kv_pool_tokens=config.kv_pool_tokens,
-            kv_window_pool_tokens=getattr(
-                config, "kv_window_pool_tokens", None
-            ),
-            kv_cache_dtype=getattr(config, "kv_cache_dtype", "auto"),
-            serving_weight_dtype=getattr(
-                config, "serving_weight_dtype", "auto"
-            ),
+            kv_window_pool_tokens=config.kv_window_pool_tokens,
+            kv_cache_dtype=config.kv_cache_dtype,
+            serving_weight_dtype=config.serving_weight_dtype,
             prefill_chunk_tokens=config.prefill_chunk_tokens,
             pipeline_depth=config.pipeline_depth,
             prefix_cache=config.prefix_cache,
             prefix_cache_capacity_frac=config.prefix_cache_capacity_frac,
             prefix_cache_min_tokens=config.prefix_cache_min_match_tokens,
-            prefix_cache_host_bytes=getattr(
-                config, "prefix_cache_host_bytes", 0
-            ),
-            spec_decode_params=resolve_spec_params(
-                getattr(config, "spec_decode", None)
-            ),
-            slo_tracking=getattr(config, "slo_tracking", True),
+            prefix_cache_host_bytes=config.prefix_cache_host_bytes,
+            slo_tracking=config.slo_tracking,
             server_name=config.worker_name,
-            handoff_streaming=getattr(config, "handoff_streaming", True),
-            prefix_pull_min_tokens=getattr(
-                config, "prefix_pull_min_tokens", 256
-            ),
-            keep_routed_experts=getattr(config, "keep_routed_experts", 0),
-            keep_chosen_sets=getattr(config, "keep_chosen_sets", 0),
+            handoff_streaming=config.handoff_streaming,
+            prefix_pull_min_tokens=config.prefix_pull_min_tokens,
+            keep_routed_experts=config.keep_routed_experts,
+            keep_chosen_sets=config.keep_chosen_sets,
         )
         if self.engine.sparse_decode_path:
             # (static: the decode program's path follows the table's shape)
@@ -459,9 +441,7 @@ class GenerationServerWorker(worker_base.Worker):
         # the stream dead (remaining segments dropped — the decode
         # side's TTL sweep releases its partial blocks; the continuation
         # re-prefills there).
-        self._handoff_streaming = bool(
-            getattr(config, "handoff_streaming", True)
-        )
+        self._handoff_streaming = bool(config.handoff_streaming)
         self._segment_reply_idents = []  # clients awaiting segment import
         self._stream_push: Dict[str, Dict] = {}
         # fleet KV fabric: in-flight peer prefix pulls.  Each pull runs
@@ -494,9 +474,7 @@ class GenerationServerWorker(worker_base.Worker):
 
         eng = self.engine
         self._compile_watch = CompileWatch(
-            quiet_after_steps=getattr(
-                config, "compile_quiet_after_steps", 0
-            ),
+            quiet_after_steps=config.compile_quiet_after_steps,
             on_steady_compile=_force_inflight_roots,
         )
         if eng.paged:
@@ -574,21 +552,6 @@ class GenerationServerWorker(worker_base.Worker):
             ),
             "prefix_host_dropped": reg.counter(
                 "areal_inference_prefix_host_dropped_blocks_total"
-            ),
-            "spec_drafted": reg.counter(
-                "areal_inference_spec_draft_tokens_total"
-            ),
-            "spec_accepted": reg.counter(
-                "areal_inference_spec_accepted_tokens_total"
-            ),
-            "spec_rejected": reg.counter(
-                "areal_inference_spec_rejected_tokens_total"
-            ),
-            "spec_verify_chunks": reg.counter(
-                "areal_inference_spec_verify_chunks_total"
-            ),
-            "spec_fallback_rows": reg.counter(
-                "areal_inference_spec_fallback_rows_total"
             ),
             "kv_quant_checks": reg.counter(
                 "areal_inference_kv_quant_divergence_checks_total"
@@ -703,10 +666,6 @@ class GenerationServerWorker(worker_base.Worker):
             "areal_gateway_preemptions_total"
         )
         self._obs_preempt_class_last: Dict[str, int] = {}
-        self._obs_accept_hist = reg.histogram(
-            "areal_inference_spec_accept_rate",
-            buckets=(0.0, 0.125, 0.25, 0.375, 0.5, 0.625, 0.75, 0.875, 1.0),
-        )
         # request-level SLO digests: each family is a histogram over the
         # FIXED log buckets (latency.SLO_BUCKETS), so the master-side
         # aggregator can rebuild and EXACTLY merge per-worker digests
@@ -732,7 +691,6 @@ class GenerationServerWorker(worker_base.Worker):
     def _export_engine_metrics(self):
         eng = self.engine
         pstats = eng.prefix_cache_stats()
-        sstats = eng.spec_stats()
         qstats = eng.kv_quant_stats()
         wstats = eng.weight_quant_stats()
         hstats = eng.handoff_stats()
@@ -756,11 +714,6 @@ class GenerationServerWorker(worker_base.Worker):
             "prefix_host_dropped": float(
                 pstats["host_dropped_blocks_total"]
             ),
-            "spec_drafted": float(sstats["drafted_total"]),
-            "spec_accepted": float(sstats["accepted_total"]),
-            "spec_rejected": float(sstats["rejected_total"]),
-            "spec_verify_chunks": float(sstats["verify_chunks_total"]),
-            "spec_fallback_rows": float(sstats["fallback_rows_total"]),
             "kv_quant_checks": float(qstats["divergence_checks_total"]),
             "kv_quant_diverged": float(
                 qstats["divergence_diverged_total"]
@@ -817,8 +770,6 @@ class GenerationServerWorker(worker_base.Worker):
                 # "class" is a Python keyword: pass the label via **
                 self._obs_preempt_class.inc(delta, **{"class": cls})
                 self._obs_preempt_class_last[cls] = total
-        for frac in eng.drain_spec_accept_samples():
-            self._obs_accept_hist.observe(frac)
         for rec in eng.drain_slo_records():
             w = rec.workload
             self._obs_slo["admission_wait_s"].observe(
@@ -1328,7 +1279,7 @@ class GenerationServerWorker(worker_base.Worker):
         format grounds."""
         import os as _os
 
-        want = getattr(self.config, "serving_weight_dtype", "auto")
+        want = self.config.serving_weight_dtype
         if want != "int8":
             return "full", path, None
         qinfo = ((manifest or {}).get("serving_quant") or {}).get("int8")
@@ -1399,9 +1350,7 @@ class GenerationServerWorker(worker_base.Worker):
                 restored = checkpoint.load_params_staged(
                     template,
                     restore_path,
-                    chunk_bytes=getattr(
-                        self.config, "stage_chunk_bytes", None
-                    ),
+                    chunk_bytes=self.config.stage_chunk_bytes,
                     # staged_weights attribution grows chunk by chunk —
                     # the mid-restore footprint is visible, not just the
                     # final stage_weights total
@@ -1591,12 +1540,6 @@ class GenerationServerWorker(worker_base.Worker):
             **{
                 f"prefix_cache_{k}": v
                 for k, v in self.engine.prefix_cache_stats().items()
-            },
-            # self-speculative decoding: draft/accept volume, verify
-            # passes, EMA fallbacks
-            **{
-                f"spec_{k}": v
-                for k, v in self.engine.spec_stats().items()
             },
             # quantized KV storage: dtype bits, quantized block
             # residency, measured divergence-check counters

@@ -4,11 +4,11 @@ chunk, and the host folds them at that chunk's harvest
 (``_fold_first_tokens``).  THIS file is the CPU gate that the deferral
 changes nothing a client can see: the tokens, log-probabilities and
 finish reasons are those of the path that fetches the first tokens at
-once (what a request with ``handoff_to`` or an engine with speculation
-still takes), at every pipeline depth, on a dense paged stack, a stateful
-one with late joins and a window one.  A page boundary, a row that its
-first token ends, and everything that drains the ring with a token on its
-way: ``test_first_tokens_settled.py`` (two files, because one test process
+once (what a request with ``handoff_to`` still takes), at every pipeline
+depth, on a dense paged stack, a stateful one with late joins and a
+window one.  A page boundary, a row that its first token ends, and
+everything that drains the ring with a token on its way:
+``test_first_tokens_settled.py`` (two files, because one test process
 can hold only so many engines' programs: ``/proc/self/maps``)."""
 
 import json
@@ -57,9 +57,9 @@ def _engine(stacks, name, at_once=False, **kw):
     else:
         eng = window.make_engine(model, **kw)
     if at_once:
-        # the path a handed-off request and a speculating engine take:
-        # every distribution's first tokens fetched before anything else
-        # is dispatched, as every distribution's were before
+        # the path a handed-off request takes: every distribution's
+        # first tokens fetched before anything else is dispatched, as
+        # every distribution's were before
         eng._first_tokens_at_once = lambda targets: True
     return eng
 

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from areal_tpu.api.model_api import APIGenerateInput, GenerationHyperparameters
-from areal_tpu.engine import spec_decode
 from areal_tpu.engine.inference_server import ContinuousBatchingEngine
 from areal_tpu.engine.sampling import SamplingParams
 from areal_tpu.models import hybrid, moe
@@ -148,8 +147,6 @@ def test_more_requests_than_rows_queue_and_every_one_is_the_reference(model):
     "feature,kw",
     [
         ("int8 KV storage", dict(kv_cache_dtype="int8")),
-        ("speculative verify",
-         dict(spec_decode_params=spec_decode.SpecDecodeParams(enabled=True))),
         ("int8 serving weights", dict(serving_weight_dtype="int8")),
         ("the dense (unpaged) KV cache", dict(cache_mode="dense")),
     ],

@@ -11,9 +11,8 @@ This file pins, on CPU:
   prefix repatriates it for free; dropping a resident node with spilled
   children drops the orphaned subtree; flush() empties BOTH tiers;
 * engine-level spill -> match -> swap-in replay is token-identical to a
-  fresh engine (plain paged+prefix arm AND the spec-decode arm), with
-  spills and restores demonstrably happening and zero block / host-byte
-  leaks after flush;
+  fresh engine, with spills and restores demonstrably happening and
+  zero block / host-byte leaks after flush;
 * weight swaps invalidate the host tier too (stale KV across a swap
   stays impossible, host copies included);
 * on a replay that overflows the HBM cache, the tier ON serves strictly
@@ -317,47 +316,6 @@ def test_weight_swap_flushes_host_tier():
     fresh.submit(_req("fresh", conv, 8))
     run_until_done(fresh)
     assert got.output_ids == fresh.drain_results()["fresh"].output_ids
-
-
-def test_spec_decode_arm_parity_with_host_tier():
-    """Self-speculative decoding over a spilled-and-restored prefix stays
-    token-identical to plain greedy decode without any cache tier at
-    all (the verify path reads restored pool blocks like any others)."""
-    from areal_tpu.engine.spec_decode import SpecDecodeParams
-
-    spec = SpecDecodeParams(enabled=True, max_draft_tokens=7)
-    # repetitive conversation seed so n-gram drafting engages
-    motif = [7, 8, 9, 10] * 6
-    eng, *_ = _pressure_engine(spec_decode_params=spec)
-    plain, *_ = make_engine(kv_pool_tokens=2048, prefix_cache=False)
-    outs = {}
-    for name, e in (("spec", eng), ("plain", plain)):
-        e.park_ttl_steps = 0
-        conv = list(motif)
-        for t in range(2):
-            qid = f"{name}t{t}"
-            e.submit(_req(qid, conv, 10))
-            run_until_done(e, max_steps=3000)
-            out = e.drain_results()[qid]
-            outs[(name, t)] = list(out.output_ids)
-            conv = conv + list(out.output_ids) + motif[:8]
-            if name == "spec" and t == 0:
-                # force turn 1's prefix out of HBM: turn 2 must come
-                # back through a host-tier swap-in under spec decode
-                e.step()
-                e.step()  # TTL-evict the parked row first
-                e._prefix_cache.evict(
-                    e.prefix_cache_stats()["blocks_held"]
-                )
-                assert (
-                    e.prefix_cache_stats()["host_blocks_held"] > 0
-                )
-    assert outs[("spec", 0)] == outs[("plain", 0)]
-    assert outs[("spec", 1)] == outs[("plain", 1)]
-    st = eng.prefix_cache_stats()
-    assert st["spilled_blocks_total"] > 0
-    assert st["restored_blocks_total"] > 0
-    assert eng.spec_verify_chunks_total > 0  # drafting really engaged
 
 
 def test_host_tier_serves_more_from_cache_and_prefills_less():

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from areal_tpu.api.model_api import APIGenerateInput, GenerationHyperparameters
-from areal_tpu.engine import spec_decode
 from areal_tpu.engine.inference_server import (
     ContinuousBatchingEngine,
     StatefulModelUnsupported,
@@ -251,10 +250,6 @@ def test_weight_swap_recomputes_state_and_pages_under_the_new_weights(model):
 
 
 REFUSED_AT_CONSTRUCTION = {
-    "speculative verify": dict(
-        sampling=SamplingParams(greedy=True),
-        spec_decode_params=spec_decode.SpecDecodeParams(enabled=True),
-    ),
     "prefix-cache host spill": dict(prefix_cache_host_bytes=1 << 20),
     "int8 KV storage": dict(kv_cache_dtype="int8"),
     "int8 serving weights": dict(serving_weight_dtype="int8"),

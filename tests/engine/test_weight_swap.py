@@ -8,10 +8,10 @@ the old weights, prefix cache flushed, in-flight KV recomputed, version
 stamps intact — while the interrupting window shrinks to the pointer
 flip.  Around that core: the version-consistent commit barrier (commit
 of a different version than staged must fail before anything flips),
-interplay with chunked prefill and speculative verify windows in
-flight, staged restore through an actual published orbax snapshot, and
-the 2-chip-mesh arm restoring straight onto serving shardings
-(slow-marked: tier-1 keeps the single-chip arms).
+interplay with chunked prefill in flight, staged restore through an
+actual published orbax snapshot, and the 2-chip-mesh arm restoring
+straight onto serving shardings (slow-marked: tier-1 keeps the
+single-chip arms).
 """
 
 import os
@@ -25,7 +25,7 @@ from areal_tpu.api.model_api import (
     APIGenerateInput,
     GenerationHyperparameters,
 )
-from areal_tpu.engine import checkpoint, spec_decode
+from areal_tpu.engine import checkpoint
 from areal_tpu.engine.generation import generate_tokens
 from areal_tpu.engine.inference_server import ContinuousBatchingEngine
 from areal_tpu.engine.sampling import SamplingParams
@@ -199,7 +199,7 @@ def test_staged_commit_matches_full_reload_stream(mode):
     assert run(staged) == run(full)
 
 
-# -- interplay: chunked prefill / spec verify / prefix cache ------------------
+# -- interplay: chunked prefill / prefix cache --------------------------------
 
 
 def test_staged_commit_mid_chunked_prefill_restarts_fill_under_v1():
@@ -229,27 +229,6 @@ def test_staged_commit_mid_chunked_prefill_restarts_fill_under_v1():
     run_until_done(fresh)
     assert got.output_ids == fresh.wait_result("f0", timeout=5).output_ids
     assert got.version_end == 1
-
-
-def test_staged_commit_mid_spec_verify_emits_nothing_stale():
-    """Commit while a speculative verify window is in flight: the window
-    folds in under v0, the continuation decodes under v1."""
-    spec = spec_decode.SpecDecodeParams(enabled=True, max_draft_tokens=7)
-    eng = make_engine(spec_decode_params=spec)
-    prompt = [7, 8, 9, 10] * 5
-    eng.submit(_req("q0", prompt, 24))
-    for _ in range(30):
-        eng.step()
-        if eng.spec_verify_chunks_total > 0 and eng.inflight_chunks:
-            break
-    assert eng.inflight_chunks >= 1
-    eng.stage_weights(_params2, version=1)
-    assert eng.commit_staged(expected_version=1) == 1
-    run_until_done(eng)
-    out = eng.wait_result("q0", timeout=5)
-    assert out.version_start == 0 and out.version_end == 1
-    split = assert_v0_prefix_v1_tail(list(out.output_ids), prompt, 24)
-    assert 0 < split < len(out.output_ids)
 
 
 def test_staged_commit_flushes_prefix_cache_and_fresh_replay_matches():

@@ -382,7 +382,7 @@ def test_a_loop_is_one_outer_scan_around_one_layer_scan(model):
         assert any(s.endswith("areal.loop.norm") for s in scopes), name
 
 
-# -- what a looped stack refuses, by name ------------------------------------
+# -- what a looped stack refuses, by name, and what it does not ---------------
 
 
 def test_any_other_exit_threshold_is_refused_by_its_key():
@@ -397,23 +397,12 @@ def test_a_pipeline_mesh_is_refused(model):
         tf._run_layers_pipelined(params, cfg, x, None, None, None, None, None)
 
 
-def test_speculation_is_refused(model):
-    cfg, params = model
-    from areal_tpu.engine import spec_decode
-    from areal_tpu.engine.inference_server import ContinuousBatchingEngine
-    from areal_tpu.engine.sampling import SamplingParams
-
+def test_a_looped_stack_holds_no_cache_kind_that_refuses(model):
+    cfg, _ = model
     held = kv_pages.kinds_held(cfg)
-    assert list(held) == [kv_pages.LOOPED]
-    with pytest.raises(kv_pages.CacheKindRefuses, match="speculative verify"):
-        ContinuousBatchingEngine(
-            cfg, params, max_batch=2, kv_cache_len=64, chunk_size=4,
-            sampling=SamplingParams(greedy=True), cache_mode="paged",
-            page_size=8, prefill_chunk_tokens=16,
-            spec_decode_params=spec_decode.SpecDecodeParams(enabled=True),
-        )
-    # the other features stand: a page id names its slice of ALL layers
-    for feature in ("prefix-cache host spill", "P/D handoff", "prefix pulls"):
+    assert held == {}
+    # every feature stands: a page id names its slice of ALL layers
+    for feature in kv_pages.REFUSED:
         kv_pages.refuse(feature, held)
 
 

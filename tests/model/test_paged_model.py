@@ -373,12 +373,13 @@ def test_fill_write_after_the_scan_matches_scatter_in_scan(
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
-def test_verify_window_write_matches_scatter_in_scan(cfg, params, quantized):
-    """A spec-decode verify window (engine/spec_decode.py builds the same
-    mask) over live prefixes: rows that take part with drafts of several
-    lengths, one whose window crosses a page, one cut by ``max_len``, one
-    that does not take part: hidden states and pools bit-equal to the
-    in-scan scatter's."""
+def test_window_over_live_prefixes_matches_scatter_in_scan(
+    cfg, params, quantized
+):
+    """A short window over live prefixes: rows that take part with
+    windows of several lengths, one whose window crosses a page, one cut
+    by ``max_len``, one that is left out: hidden states and pools
+    bit-equal to the in-scan scatter's."""
     rng = np.random.RandomState(13)
     lens = np.array([14, 30, 9, 62, 21], np.int32)
     prompts = [list(rng.randint(0, cfg.vocab_size, n)) for n in lens]
@@ -399,13 +400,13 @@ def test_verify_window_write_matches_scatter_in_scan(cfg, params, quantized):
     )
     pools = list(out[1:]) + [None] * (5 - len(out))
     window = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, C)), jnp.int32)
-    draft_lens = jnp.asarray([4, 2, 0, 4, 3], jnp.int32)
+    last = jnp.asarray([4, 2, 0, 4, 3], jnp.int32)
     takes_part = jnp.asarray([True, True, True, True, False])
     iot = jnp.arange(C, dtype=jnp.int32)
     starts = jnp.asarray(lens)
     valid = (
         takes_part[:, None]
-        & (iot[None, :] <= draft_lens[:, None])
+        & (iot[None, :] <= last[:, None])
         & ((starts[:, None] + iot[None, :]) < max_len)
     )
     assert [int(n) for n in valid.sum(1)] == [5, 3, 1, 2, 0]
